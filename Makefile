@@ -6,8 +6,10 @@
 #                (<5 min, virtual CPU mesh, `-m "not slow"`) — what the
 #                pre-push hook runs
 #   make test    full unit suite on the 8-device virtual CPU mesh
-#   make smoke   perf regression gate on the real chip
-#                (benchmarks/smoke.py vs committed expected.json, +-10%)
+#   make smoke   bring-up check ON THE CHIP: `python chip_smoke.py`
+#                (kernels, 8 steps of GPT-2 1.3B, a 4-request server).
+#                Refuses anything but a TPU — run it where the chip is,
+#                e.g. through the chip tool; never part of a CPU gate
 #   make chaos   fault-injection suite: torn/failed checkpoint writes,
 #                preemption grace saves, crash-loop detection, elastic
 #                topology resume (8->4 / 4->8 kill-and-reshard), the
@@ -76,13 +78,13 @@
 #                3-sigma band (benchmarks/communication/
 #                hierarchical_exchange_results.json); nonzero exit past
 #                either bound
-#   make check   test + smoke-if-hot-paths-changed — the full gate
+#   make check   test, plus a reminder to run the chip smoke when hot
+#                paths changed (no chip on a developer machine)
 #   make hooks   install the committed .githooks (pre-push runs
-#                `make quick` + conditional smoke)
+#                `make quick`)
 
 PY ?= python
-# hot paths whose changes require the perf gate (the r3 regression lesson:
-# a timing change in any of these shipped unnoticed for a round)
+# hot paths whose changes call for a run of chip_smoke.py on the chip
 HOT_PATHS := deepspeed_tpu/runtime/engine.py deepspeed_tpu/models \
              deepspeed_tpu/ops deepspeed_tpu/utils/timer.py \
              deepspeed_tpu/inference/engine.py \
@@ -120,7 +122,7 @@ test:
 	$(PY) -m pytest tests/ -q
 
 smoke:
-	$(PY) benchmarks/smoke.py
+	$(PY) chip_smoke.py
 
 # includes the elastic 8->4 / 4->8 topology-resume scenarios (train on N
 # virtual devices, kill mid-epoch, resume on N' — docs/recovery.md
@@ -230,9 +232,9 @@ hot-changed:
 	fi
 
 check: test
-	@if $(MAKE) -s hot-changed; then $(MAKE) smoke; else \
-	  echo "skipping smoke (no hot-path changes)"; fi
+	@if $(MAKE) -s hot-changed; then \
+	  echo "hot paths changed: run 'python chip_smoke.py' on the chip"; fi
 
 hooks:
 	git config core.hooksPath .githooks
-	@echo "hooks installed: pre-push runs 'make quick' + conditional smoke"
+	@echo "hooks installed: pre-push runs 'make quick'"

@@ -58,7 +58,7 @@ if "xla_force_host_platform_device_count" not in \
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
 from deepspeed_tpu.comm import comm as dist  # noqa: E402
@@ -133,7 +133,7 @@ def measure_exchange(grads, fmt: str, mesh, block: int = 512,
             lambda x: dist.all_reduce(x.astype(wire), AXIS), g)
 
     mapped = shard_map(exchange, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                       check_rep=False)
+                       check_vma=False)
     was_enabled, was_all = comms_logger.enabled, comms_logger.prof_all
     comms_logger.reset()
     comms_logger.enabled = True

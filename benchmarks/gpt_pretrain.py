@@ -34,8 +34,11 @@ from deepspeed_tpu.models.transformer_lm import GPT, gpt2_config, num_params
 BASELINE_TFLOPS = 30.0  # ZeRO-Offload, 1x V100: docs/_pages/training.md:293
 
 
-def run(model_name="gpt2-1.3b", seq=1024, micro=6, steps=6,
-        remat_policy="full"):
+def build(model_name="gpt2-1.3b", seq=1024, micro=6, remat_policy="full",
+          zero_stage=1, topology=None, **model_overrides):
+    """``(engine, batch, cfg)`` for the headline config: the engine through
+    ``deepspeed_tpu.initialize`` and one seeded global batch. Shared with
+    ``chip_smoke.py`` so the smoke drives exactly what the bench times."""
     # measured on the v5e chip (micro x policy x flash sweep): flash + full
     # remat + micro 6 = 102.4 TFLOPS (micro 4: 97.0; micro 7/8 OOM;
     # selective remat OOMs at any micro). Without flash the best was
@@ -46,7 +49,8 @@ def run(model_name="gpt2-1.3b", seq=1024, micro=6, steps=6,
     cfg = gpt2_config(
         model_name, n_positions=seq, dtype=jnp.bfloat16,
         param_dtype=jnp.bfloat16, scan_layers=True, remat=True,
-        remat_policy=remat_policy, use_flash_attention="auto")
+        remat_policy=remat_policy, use_flash_attention="auto",
+        **model_overrides)
     model = GPT(cfg)
     ds_config = {
         "train_micro_batch_size_per_gpu": micro,
@@ -56,15 +60,23 @@ def run(model_name="gpt2-1.3b", seq=1024, micro=6, steps=6,
         "optimizer": {"type": "FusedAdam",
                       "params": {"lr": 2e-4, "betas": [0.9, 0.95],
                                  "weight_decay": 0.1}},
-        "zero_optimization": {"stage": 1},
+        "zero_optimization": {"stage": zero_stage},
         "steps_per_print": 10 ** 9,
     }
-    engine, _, _, _ = deepspeed_tpu.initialize(model=model, config=ds_config)
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model, config=ds_config, topology=topology)
     gb = micro * engine.topology.data_parallel_size
     rng = np.random.RandomState(0)
     batch = {"input_ids": rng.randint(0, cfg.vocab_size,
                                       size=(gb, seq)).astype(np.int32)}
     batch["labels"] = batch["input_ids"]
+    return engine, batch, cfg
+
+
+def run(model_name="gpt2-1.3b", seq=1024, micro=6, steps=6,
+        remat_policy="full"):
+    engine, batch, cfg = build(model_name, seq, micro, remat_policy)
+    gb = batch["input_ids"].shape[0]
     dt = time_train_steps(engine, batch, steps=steps)
 
     n_params = num_params(cfg)

@@ -35,7 +35,7 @@ import time
 
 sys.path.insert(0, __file__.rsplit("/", 3)[0])
 
-from benchmarks._util import backend_preflight, run_with_retry  # noqa: E402
+from benchmarks._util import run_with_retry  # noqa: E402
 
 
 def _emit(obj):
@@ -199,18 +199,6 @@ def main():
     a = p.parse_args()
     if a.quick:
         a.slots, a.requests, a.max_new = 4, 6, 8
-
-    pre = backend_preflight()
-    _emit({"event": "backend_preflight", **pre})
-    if not pre["ok"]:
-        # evidence out, rc!=0: the partial JSON is the point
-        path = a.out or os.path.join(
-            os.path.dirname(os.path.abspath(__file__)),
-            "serving_bench_results.json")
-        with open(path, "w") as f:
-            json.dump({"partial": True, "preflight": pre}, f, indent=2)
-            f.write("\n")
-        sys.exit(1)
 
     res, err = run_with_retry(lambda: run(a), "serving_bench", retries=0)
     if res is None:

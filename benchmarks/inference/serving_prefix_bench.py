@@ -37,7 +37,7 @@ import time
 
 sys.path.insert(0, __file__.rsplit("/", 3)[0])
 
-from benchmarks._util import backend_preflight, run_with_retry  # noqa: E402
+from benchmarks._util import run_with_retry  # noqa: E402
 from benchmarks.inference.prefix_trace import (  # noqa: E402
     make_bursty_prefix_trace)
 
@@ -210,18 +210,10 @@ def main():
     if a.quick:
         a.slots, a.requests, a.max_new, a.burst = 4, 8, 8, 2
 
-    pre = backend_preflight()
-    _emit({"event": "backend_preflight", **pre})
     here = os.path.dirname(os.path.abspath(__file__))
     path = a.out or os.path.join(here, "serving_bench_prefix_results.json")
     if a.quick and a.out is None:
         path = os.path.join(here, "serving_bench_prefix_quick.json")
-    if not pre["ok"]:
-        with open(path, "w") as f:
-            json.dump({"partial": True, "preflight": pre}, f, indent=2)
-            f.write("\n")
-        sys.exit(1)
-
     t0 = time.monotonic()
     res, err = run_with_retry(lambda: run(a), "serving_prefix_bench",
                               retries=0)

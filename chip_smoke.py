@@ -1,0 +1,770 @@
+#!/usr/bin/env python3
+"""Does the system still start on the chip?
+
+Drives the main path once, through the entry points a user calls, at the
+full width of GPT-2 1.3B, and checks what comes out by the repo's own
+means. With no arguments (there are none) it is the strict run:
+
+1. ``kernels``  every Pallas kernel once, compiled, at a production
+   shape, forward and backward, against its plain ``jnp`` reference.
+2. ``train``    ``deepspeed_tpu.initialize`` -> ``engine.train_batch``:
+   the config ``benchmarks/gpt_pretrain.py`` builds (1.3B, seq 1024,
+   micro 6, bf16, full remat, flash "auto", FusedAdam, ZeRO-1, default
+   telemetry/sentinel), 8 optimizer steps on one chip on a seeded batch.
+3. ``train_warm``  the same phase again in a fresh process: its compile
+   seconds next to the first run's show the persistent compile cache.
+4. ``serve``    ``init_inference(dtype="bf16")`` -> ``build_serving``
+   -> ``ContinuousBatchingScheduler.run``: 4 seeded prompts of 128-512
+   tokens, 32 greedy tokens each, checked against ``engine.generate``.
+5. ``four_chip``  only where JAX reports >= 4 devices: the trainer under
+   ZeRO-3 over fsdp=4, and what the server does on four devices.
+
+One process per chip: this parent never imports JAX. It runs the phases
+as children, one after another, each in a fresh interpreter that holds
+the chip alone and releases it by exiting. On a host with several chips
+the one-chip phases see only the first (``ONE_CHIP_ENV``). A phase that fails ends the run
+with a nonzero exit code; nothing is retried and nothing is skipped. Every
+phase first checks the device and exits nonzero unless it is a TPU whose
+``device_kind`` is in the peak table — there is no CPU fallback. Inputs
+and weights come from seeds; no git, no network.
+
+The observations printed per phase (compile seconds, ms per step, peak
+bytes) are smoke observations, not benchmark numbers.
+
+Tests import this module and call the phase functions at tiny shapes with
+``strict=False`` (CPU, Pallas interpret mode); the command line has no
+switch that weakens the run.
+"""
+
+import functools
+import json
+import os
+import signal
+import subprocess
+import sys
+import threading
+import time
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+TOTAL_BUDGET_S = 1150.0  # the caller allows 1200 s, compilation included
+PHASES = ("kernels", "train", "train_warm", "serve", "four_chip")
+
+# relative L2 error against the f32 reference, by input dtype (bf16 has
+# ~3 decimal digits; the same bound tests/unit/test_ops.py uses)
+REL_L2_TOL = {"bfloat16": 3e-2, "float32": 2e-3}
+# two runs of the same seeded bf16 training (fresh process, or ZeRO-3 over
+# four chips on the same effective batch) may differ by reduction order
+LOSS_TRAJECTORY_TOL = 0.1
+# what restricts a child to the host's first chip (libtpu reads these at
+# start-up); set only on hosts where the first child saw more than one
+ONE_CHIP_ENV = {"TPU_VISIBLE_CHIPS": "0", "TPU_VISIBLE_DEVICES": "0",
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
+def emit(obj: dict) -> None:
+    print(json.dumps(obj), flush=True)
+
+
+# ---------------------------------------------------------------------------
+# device check + compile accounting (children only: these import JAX)
+# ---------------------------------------------------------------------------
+def device_report() -> dict:
+    """Print the device as JAX reports it plus versions and the compile
+    cache directory; raise SystemExit unless it is a TPU in the peak
+    table. Runs before any model is built."""
+    import jax
+    import jaxlib
+
+    from deepspeed_tpu.profiling.step_profiler import peak_tflops
+    from deepspeed_tpu.utils.compile_cache import ensure_compile_cache
+
+    from importlib.metadata import PackageNotFoundError, version
+
+    try:
+        libtpu = version("libtpu")
+    except PackageNotFoundError:  # reported as null; no TPU without it
+        libtpu = None
+    devs = jax.devices()
+    info = {
+        "device": {"platform": devs[0].platform,
+                   "kind": devs[0].device_kind, "count": len(devs)},
+        "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+        "libtpu": libtpu,
+        "compile_cache_dir": ensure_compile_cache(),
+        "compile_cache_env": os.environ.get("JAX_COMPILATION_CACHE_DIR"),
+    }
+    emit(info)
+    if devs[0].platform != "tpu":
+        raise SystemExit(
+            f"chip_smoke: JAX platform is {devs[0].platform!r}, not 'tpu' "
+            "— refusing to run (there is no CPU fallback)")
+    peak_tflops(devs[0])  # raises on a device_kind the table lacks
+    return info
+
+
+class CompileCounter:
+    """Counts XLA backend compilations and persistent-cache hits through
+    ``jax.monitoring`` (a hit still fires the compile event, with the
+    retrieval time as its duration)."""
+
+    def __init__(self):
+        import jax
+
+        self.compiles = 0
+        self.compile_s = 0.0
+        self.cache_hits = 0
+        self.by_name = {}
+        jax.monitoring.register_event_duration_secs_listener(self._on_secs)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_secs(self, name, secs, **kw):
+        if name == "/jax/core/compile/backend_compile_duration":
+            self.compiles += 1
+            self.compile_s += secs
+            fn = kw.get("fun_name", "?")
+            self.by_name[fn] = self.by_name.get(fn, 0) + 1
+
+    def _on_event(self, name, **kw):
+        if name == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+
+    def snapshot(self) -> dict:
+        return {"n_compiles": self.compiles,
+                "compile_s": round(self.compile_s, 2),
+                "persistent_cache_hits": self.cache_hits}
+
+
+def _memory(device=None) -> dict:
+    import jax
+
+    # live buffers and XLA's per-program temporaries are two pools on the
+    # TPU allocator: peak_bytes_in_use does not include the latter
+    stats = (device or jax.local_devices()[0]).memory_stats() or {}
+    return {k: stats.get(k) for k in (
+        "peak_bytes_in_use", "peak_bytes_reserved", "bytes_limit")}
+
+
+def _rel_l2(got, ref) -> float:
+    import numpy as np
+
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    return float(np.linalg.norm(got - ref) / max(np.linalg.norm(ref), 1e-30))
+
+
+def _mosaic_calls(hlo_text: str) -> int:
+    return hlo_text.count('custom_call_target="tpu_custom_call"')
+
+
+# ---------------------------------------------------------------------------
+# phase: kernels
+# ---------------------------------------------------------------------------
+def _attention_vs_reference(what, attn, ref_attn, q, k, v, w, strict):
+    """Forward and backward of kernel ``attn(q, k, v)`` against
+    ``ref_attn`` on the f32 upcast of the same inputs (loss = sum(o * w)).
+    Returns (rel-L2 errors of o/dq/dk/dv, their tolerance, Mosaic calls in
+    the compiled kernel step); raises when an error exceeds the tolerance
+    or, if ``strict``, when fwd + dq + dkv are not three Mosaic calls."""
+    import jax
+    import jax.numpy as jnp
+
+    def loss(fn, q, k, v):
+        o = fn(q, k, v)
+        return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32)), o
+
+    def value_and_grads(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: loss(fn, q, k, v), argnums=(0, 1, 2),
+            has_aux=True))
+
+    step = value_and_grads(attn)
+    (_, o), grads = step(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        (_, o_ref), grads_ref = value_and_grads(ref_attn)(
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+    errs = {"o": _rel_l2(o, o_ref)}
+    errs.update({f"d{n}": _rel_l2(g, gr)
+                 for n, g, gr in zip("qkv", grads, grads_ref)})
+    tol = REL_L2_TOL[jnp.dtype(q.dtype).name]
+    bad = {n: e for n, e in errs.items() if not e < tol}
+    if bad:
+        raise AssertionError(f"{what}: rel-L2 {bad} > {tol}")
+    mosaic = _mosaic_calls(step.lower(q, k, v).compile().as_text())
+    if strict and mosaic < 3:
+        raise AssertionError(
+            f"{what}: expected fwd+dq+dkv Mosaic custom calls in the "
+            f"compiled HLO, found {mosaic}")
+    return {n: round(e, 5) for n, e in errs.items()}, tol, mosaic
+
+
+def _check_flash(t, d, heads, batch, dtype, segments: bool, strict: bool):
+    """flash fwd+bwd (blocks from get_flash_blocks) vs f32 einsum."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas.autotune import get_flash_blocks
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    rng = np.random.RandomState(t + d + int(segments))
+    shape = (batch, t, heads, d)
+    q, k, v, w = (jnp.asarray(rng.randn(*shape), dtype) for _ in range(4))
+    seg = None
+    if segments:
+        # packed rows: ragged documents plus trailing padding (segment 0)
+        cuts = np.sort(rng.randint(1, t - t // 8, size=(batch, 3)), axis=1)
+        pos = np.arange(t)[None, :]
+        seg_np = 1 + (pos >= cuts[:, :1]) + (pos >= cuts[:, 1:2]) \
+            + (pos >= cuts[:, 2:3])
+        seg_np = np.where(pos >= t - t // 8, 0, seg_np)
+        seg = jnp.asarray(seg_np, jnp.int32)
+
+    def ref_attn(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+        keep = jnp.tril(jnp.ones((t, t), bool))[None, None]
+        if seg is not None:
+            keep = keep & (seg[:, None, :, None] == seg[:, None, None, :])
+        p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+    errs, tol, mosaic = _attention_vs_reference(
+        f"flash t={t} d={d} segments={segments}",
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        segment_ids=seg),
+        ref_attn, q, k, v, w, strict)
+    return {"kernel": "flash", "seq": t, "head_dim": d, "heads": heads,
+            "batch": batch, "dtype": jnp.dtype(dtype).name,
+            "segments": segments,
+            "blocks": list(get_flash_blocks(t, d, dtype, True)),
+            "mosaic_calls": mosaic, "tol": tol, "rel_l2": errs}
+
+
+def _check_fused_adam(shape, strict: bool):
+    """Pallas fused AdamW on one bf16 leaf vs the same update in jnp."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas.fused_adam import fused_adamw_update
+
+    rng = np.random.RandomState(7)
+    p = jnp.asarray(rng.randn(*shape) * 0.02, jnp.bfloat16)
+    g = jnp.asarray(rng.randn(*shape) * 1e-2, jnp.float32)
+    m = jnp.asarray(rng.randn(*shape) * 1e-3, jnp.float32)
+    v = jnp.asarray(rng.rand(*shape) * 1e-5, jnp.float32)
+    hp = dict(b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1)
+    lr, step = 2e-4, 3
+
+    fused = jax.jit(lambda p, g, m, v: fused_adamw_update(
+        p, g, m, v, lr, step, **hp))
+    pn, mn, vn = fused(p, g, m, v)
+
+    def ref(p, g, m, v):
+        m2 = hp["b1"] * m + (1 - hp["b1"]) * g
+        v2 = hp["b2"] * v + (1 - hp["b2"]) * g * g
+        upd = (m2 / (1 - hp["b1"] ** step)) / (
+            jnp.sqrt(v2 / (1 - hp["b2"] ** step)) + hp["eps"])
+        p32 = p.astype(jnp.float32)
+        return p32 - lr * (upd + hp["weight_decay"] * p32), m2, v2
+
+    pr, mr, vr = jax.jit(ref)(p, g, m, v)
+    # m and v stay f32; the param comes back rounded to bf16, so it can
+    # only agree with the f32 result to bf16 resolution
+    errs = {"m": _rel_l2(mn, mr), "v": _rel_l2(vn, vr),
+            "p": _rel_l2(pn, pr)}
+    tols = {"m": REL_L2_TOL["float32"], "v": REL_L2_TOL["float32"],
+            "p": 2.0 ** -8}
+    bad = {n: e for n, e in errs.items() if not e < tols[n]}
+    if bad:
+        raise AssertionError(f"fused adamw {shape}: rel-L2 {bad} > {tols}")
+    mosaic = _mosaic_calls(fused.lower(p, g, m, v).compile().as_text())
+    if strict and mosaic < 1:
+        raise AssertionError("fused adamw: no Mosaic custom call in HLO")
+    return {"kernel": "fused_adamw", "shape": list(shape),
+            "mosaic_calls": mosaic, "tol": tols,
+            "rel_l2": {n: round(e, 6) for n, e in errs.items()}}
+
+
+def _check_splash(t, block, heads, d, dtype, strict: bool):
+    """The block-sparse (splash) kernel on the BigBird layout
+    benchmarks/smoke.py trains BERT-L under, vs the masked-dense path."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.sparse_attention import BigBirdSparsityConfig
+    from deepspeed_tpu.ops.sparse_attention.sparse_self_attention import (
+        block_sparse_attention,
+        dense_blocksparse_attention,
+    )
+
+    layout = np.asarray(BigBirdSparsityConfig(
+        num_heads=heads, block=block, num_random_blocks=1,
+        num_sliding_window_blocks=3, num_global_blocks=1,
+    ).make_layout(t))
+    rng = np.random.RandomState(11)
+    shape = (1, t, heads, d)
+    q, k, v, w = (jnp.asarray(rng.randn(*shape), dtype) for _ in range(4))
+
+    kw = dict(layout=layout, block=block, causal=False)
+    errs, tol, mosaic = _attention_vs_reference(
+        f"splash t={t}", functools.partial(block_sparse_attention, **kw),
+        functools.partial(dense_blocksparse_attention, **kw),
+        q, k, v, w, strict)
+    return {"kernel": "splash_bigbird", "seq": t, "block": block,
+            "heads": heads, "head_dim": d, "dtype": jnp.dtype(dtype).name,
+            "active_blocks": int(layout.sum()), "mosaic_calls": mosaic,
+            "tol": tol, "rel_l2": errs}
+
+
+def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
+                  adam_shape=(2048, 8192), splash=(4096, 128, 16, 64),
+                  dtype=None, strict=True) -> dict:
+    """Each Pallas kernel once at a production shape, forward and
+    backward, against plain ``jnp``. ``flash_shapes`` rows are
+    ``(seq, head_dim, heads, batch)``; the first also runs with
+    ``segment_ids``. ``splash`` is ``(seq, block, heads, head_dim)``."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas.common import interpret
+
+    dtype = dtype or jnp.bfloat16
+    counter = CompileCounter()
+    t0 = time.time()
+    if strict and interpret():
+        raise AssertionError("Pallas interpret() is True on the chip")
+    checks = []
+    for i, (t, d, heads, batch) in enumerate(flash_shapes):
+        checks.append(_check_flash(t, d, heads, batch, dtype, False, strict))
+        if i == 0:
+            checks.append(
+                _check_flash(t, d, heads, batch, dtype, True, strict))
+    checks.append(_check_fused_adam(adam_shape, strict))
+    checks.append(_check_splash(*splash, dtype, strict))
+    for c in checks:
+        emit({"phase": "kernels", "check": c})
+    return {"phase": "kernels", "ok": True, "n_checks": len(checks),
+            "interpret": interpret(), "wall_s": round(time.time() - t0, 1),
+            **counter.snapshot(), **_memory()}
+
+
+# ---------------------------------------------------------------------------
+# phase: train
+# ---------------------------------------------------------------------------
+def _train(name, *, model, seq, micro, steps, zero_stage, devices, tile_rows,
+           strict, model_overrides):
+    import numpy as np
+
+    from benchmarks.gpt_pretrain import build
+    from deepspeed_tpu.ops.pallas.common import interpret
+    from deepspeed_tpu.parallel.mesh import MeshTopology
+    from deepspeed_tpu.runtime.dataloader import RepeatingLoader
+
+    counter = CompileCounter()
+    t0 = time.time()
+    # default mesh (dp=-1) over the devices this phase owns; ZeRO-3 moves
+    # dp onto fsdp itself (runtime/layout.py)
+    topology = MeshTopology(devices=list(devices))
+    engine, batch, cfg = build(model, seq, micro, zero_stage=zero_stage,
+                               topology=topology, **(model_overrides or {}))
+    if tile_rows:
+        # every data shard sees the SAME `micro` seeded rows: the mean loss
+        # and mean gradient equal the one-chip run's, so the trajectories
+        # must agree to reduction-order noise
+        rows = batch["input_ids"][:micro]
+        reps = batch["input_ids"].shape[0] // micro
+        batch = {"input_ids": np.tile(rows, (reps, 1))}
+        batch["labels"] = batch["input_ids"]
+    it = iter(RepeatingLoader([batch]))
+    losses, step_s, compiles_per_step = [], [], []
+    for _ in range(steps):
+        n0, t1 = counter.compiles, time.time()
+        losses.append(float(engine.train_batch(it)))
+        step_s.append(time.time() - t1)
+        compiles_per_step.append(counter.compiles - n0)
+    if not all(np.isfinite(losses)):
+        raise AssertionError(f"{name}: non-finite loss in {losses}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"{name}: loss did not fall: {losses}")
+    if strict and interpret():
+        raise AssertionError("Pallas interpret() is True on the chip")
+    programs = engine.compiled_step_programs()
+    hlo = programs["train_step"].as_text()
+    mosaic = _mosaic_calls(hlo)
+    sample = next((ln.strip()[:300] for ln in hlo.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in ln), None)
+    mem = engine.compiled_step_memory() or {}
+    if strict and mosaic < 1:
+        raise AssertionError(
+            f"{name}: attention is not the Mosaic custom call — no "
+            "tpu_custom_call in the compiled train step's HLO")
+    out = {
+        "phase": name, "ok": True, "model": model, "seq": seq,
+        "micro": micro, "global_batch": int(batch["input_ids"].shape[0]),
+        "n_layer": cfg.n_layer, "n_embd": cfg.n_embd,
+        "zero_stage": zero_stage, "mesh": str(engine.topology),
+        "steps": steps, "first_loss": round(losses[0], 4),
+        "last_loss": round(losses[-1], 4),
+        "losses": [round(x, 4) for x in losses],
+        "first_step_s": round(step_s[0], 2),
+        "steady_ms_per_step": round(
+            1e3 * float(np.mean(step_s[2:] or step_s[-1:])), 1),
+        "compiles_per_train_batch": compiles_per_step,
+        "train_step_compiles": counter.by_name.get("jit(train_step)", 0),
+        "mosaic_calls_in_step_hlo": mosaic, "mosaic_call_sample": sample,
+        "interpret": interpret(),
+        "compiled_step_bytes": {
+            k: int(mem[f"train_step_{k}"]) for k in (
+                "argument_bytes", "output_bytes", "temp_bytes",
+                "alias_bytes") if f"train_step_{k}" in mem},
+        "wall_s": round(time.time() - t0, 1),
+        **counter.snapshot(), **_memory(devices[0]),
+    }
+    return out, engine, hlo
+
+
+def phase_train(name="train", model="gpt2-1.3b", seq=1024, micro=6, steps=8,
+                strict=True, **model_overrides) -> dict:
+    """The item-1 trainer on ONE chip (the first device), whatever the
+    host holds."""
+    import jax
+
+    return _train(name, model=model, seq=seq, micro=micro, steps=steps,
+                  zero_stage=1, devices=jax.devices()[:1], tile_rows=False,
+                  strict=strict, model_overrides=model_overrides)[0]
+
+
+# ---------------------------------------------------------------------------
+# phase: serve
+# ---------------------------------------------------------------------------
+def _placement(tree) -> dict:
+    """Where a pytree of jax Arrays lives: bytes per device id, and the
+    distinct sharding specs."""
+    import jax
+
+    per_dev, specs = {}, set()
+    for leaf in jax.tree.leaves(tree):
+        if not isinstance(leaf, jax.Array):
+            continue
+        specs.add(str(getattr(leaf.sharding, "spec", leaf.sharding)))
+        for sh in leaf.addressable_shards:
+            per_dev[sh.device.id] = per_dev.get(sh.device.id, 0) \
+                + sh.data.nbytes
+    return {"bytes_per_device": {str(k): v for k, v in sorted(
+        per_dev.items())}, "specs": sorted(specs)}
+
+
+def _serve_requests(sched, prompts, new_tokens, vocab):
+    for p in prompts:
+        sched.submit(p, max_new_tokens=new_tokens)
+    t0 = time.time()
+    stats = sched.run()
+    wall = time.time() - t0
+    got = {c.request_id: c.tokens for c in stats.completions}
+    if len(got) != len(prompts):
+        raise AssertionError(
+            f"serve: {len(got)} of {len(prompts)} requests completed")
+    for rid, toks in got.items():
+        if len(toks) != new_tokens or not all(
+                0 <= int(t) < vocab for t in toks):
+            raise AssertionError(
+                f"serve: request {rid} returned {len(toks)} tokens "
+                f"(want {new_tokens}) or an out-of-vocabulary id: {toks}")
+    ids = sorted(got)
+    return [got[i] for i in ids], wall, stats
+
+
+def _solo_generate(engine, prompt, bucket, new_tokens):
+    """``engine.generate`` on one prompt, padded to the scheduler's prompt
+    bucket under an attention mask (generate left-aligns it), which is the
+    geometry admission prefill uses — the repo's own parity check
+    (tests/unit/test_serving.py)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    L = -(-len(prompt) // bucket) * bucket
+    ids = np.zeros((1, L), np.int32)
+    mask = np.zeros((1, L), bool)
+    ids[0, :len(prompt)] = prompt
+    mask[0, :len(prompt)] = True
+    out = engine.generate(jnp.asarray(ids), max_new_tokens=new_tokens,
+                          attention_mask=jnp.asarray(mask))
+    return np.asarray(out)[0].tolist()
+
+
+def _run_server(engine, sched, cfg, *, slots, prompt_range, new_tokens):
+    """One seeded prompt per slot, lengths drawn from ``prompt_range``:
+    served twice (cold, then warm) and checked against ``generate``."""
+    import numpy as np
+
+    rng = np.random.default_rng(0)
+    prompt_lens = np.random.default_rng(1).integers(
+        prompt_range[0], prompt_range[1] + 1, size=slots)
+    prompts = [rng.integers(0, cfg.vocab_size, size=int(n)).tolist()
+               for n in prompt_lens]
+    cold, cold_s, _ = _serve_requests(sched, prompts, new_tokens,
+                                      cfg.vocab_size)
+    warm, warm_s, stats = _serve_requests(sched, prompts, new_tokens,
+                                          cfg.vocab_size)
+    if warm != cold:
+        raise AssertionError("serve: a second pass over the same requests "
+                             "returned different greedy tokens")
+    solo = [_solo_generate(engine, p, sched.prompt_bucket, new_tokens)
+            for p in prompts]
+    matched = [i for i, (a, b) in enumerate(zip(cold, solo)) if a == b]
+    # greedy bf16 decode at batch `slots` and at batch 1 are different
+    # programs: one rounding flip on a near-tie forks the sequence for good
+    first_diff = [next((j for j, (x, y) in enumerate(zip(a, b)) if x != y),
+                       None) for a, b in zip(cold, solo)]
+    if not matched:
+        raise AssertionError(
+            "serve: no request's tokens equal engine.generate on the same "
+            f"prompt (first differing position per request: {first_diff})")
+    return {
+        "requests": len(prompts), "slots": slots,
+        "prompt_lens": [int(n) for n in prompt_lens],
+        "new_tokens": new_tokens,
+        "matched_generate": matched,
+        "first_token_differing_from_generate": first_diff,
+        "decode_steps": stats.decode_steps,
+        "cold_run_s": round(cold_s, 2),
+        "steady_ms_per_token": round(
+            1e3 * warm_s / (len(prompts) * new_tokens), 2),
+        "steady_ms_per_decode_step": round(
+            1e3 * warm_s / max(stats.decode_steps, 1), 2),
+    }
+
+
+def _serve_model(model, seq, model_overrides):
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.transformer_lm import GPT, gpt2_config
+
+    cfg = gpt2_config(model, n_positions=seq, dtype=jnp.bfloat16,
+                      param_dtype=jnp.bfloat16, scan_layers=True,
+                      use_flash_attention="auto", **(model_overrides or {}))
+    return GPT(cfg), cfg
+
+
+def phase_serve(model="gpt2-1.3b", seq=1024, slots=4, prompt_range=(128, 512),
+                new_tokens=32, strict=True, **model_overrides) -> dict:
+    """The same architecture through init_inference -> build_serving ->
+    ContinuousBatchingScheduler.run."""
+    import deepspeed_tpu
+    from deepspeed_tpu import serving
+
+    counter = CompileCounter()
+    t0 = time.time()
+    module, cfg = _serve_model(model, seq, model_overrides)
+    engine = deepspeed_tpu.init_inference(module, dtype="bf16", seed=0)
+    if strict and engine.topology.num_devices != 1:
+        raise AssertionError(
+            "serve: this is the one-chip phase but the engine took "
+            f"{engine.topology} — ONE_CHIP_ENV did not take on this host")
+    sched = serving.build_serving(engine, {"slots": slots})
+    res = _run_server(engine, sched, cfg, slots=slots,
+                      prompt_range=prompt_range, new_tokens=new_tokens)
+    return {"phase": "serve", "ok": True, "model": model,
+            "n_layer": cfg.n_layer, "n_embd": cfg.n_embd,
+            "mesh": str(engine.topology), **res,
+            "params": _placement(engine.params),
+            "wall_s": round(time.time() - t0, 1),
+            **counter.snapshot(), **_memory()}
+
+
+# ---------------------------------------------------------------------------
+# phase: four_chip
+# ---------------------------------------------------------------------------
+def _collectives(hlo_text: str) -> dict:
+    ops = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+           "collective-permute")
+    return {op: hlo_text.count(f" {op}(") + hlo_text.count(f" {op}-start(")
+            for op in ops}
+
+
+def _mosaic_result_batches(hlo_text: str) -> list:
+    """Leading dims of what each Mosaic call returns (the kernels work on
+    [batch*heads, seq, head_dim]): one chip's rows, or everybody's."""
+    import re
+
+    dims = set()
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            m = re.search(r"= \(?\w+\[(\d+),", line)
+            if m:
+                dims.add(int(m.group(1)))
+    return sorted(dims)
+
+
+def phase_four_chip(model="gpt2-1.3b", seq=1024, micro=6, steps=8, slots=4,
+                    prompt_range=(128, 512), new_tokens=32, n_devices=4,
+                    strict=True, **model_overrides) -> dict:
+    """ZeRO-3 over fsdp=n_devices at the item-1 shape, then what the
+    4-request server does on the same devices."""
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+
+    import deepspeed_tpu
+    from deepspeed_tpu import serving
+    from deepspeed_tpu.inference.scheduler import ContinuousBatchingScheduler
+
+    devices = jax.devices()[:n_devices]
+    out, engine, hlo = _train(
+        "four_chip", model=model, seq=seq, micro=micro, steps=steps,
+        zero_stage=3, devices=devices, tile_rows=True, strict=strict,
+        model_overrides=model_overrides)
+    heads = engine.module.config.n_head
+    out.update({
+        "params": _placement(engine.params),
+        "opt_state": _placement(engine.optimizer_adapter.state),
+        "peak_bytes_in_use_per_device": [
+            (d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in devices],
+        "collectives_in_step_hlo": _collectives(hlo),
+        "mosaic_result_leading_dims": _mosaic_result_batches(hlo),
+        "per_chip_batch_x_heads": micro * heads,
+    })
+    if strict and out["mosaic_result_leading_dims"] != [micro * heads]:
+        raise AssertionError(
+            "four_chip: the flash custom calls do not work on exactly one "
+            f"chip's rows (leading dims {out['mosaic_result_leading_dims']}"
+            f", micro*heads = {micro * heads}): operands are being "
+            "gathered, or the HLO could not be read")
+    del engine, hlo
+    gc.collect()
+
+    # the server on the same devices: build_serving must refuse a mesh
+    # whose data axes exceed 1 (lanes and caches are not sharded; every
+    # chip would redo every lane) ...
+    module, cfg = _serve_model(model, seq, model_overrides)
+    inf = deepspeed_tpu.init_inference(module, dtype="bf16", seed=0)
+    refused = None
+    try:
+        serving.build_serving(inf, {"slots": slots})
+    except NotImplementedError as e:
+        refused = str(e)
+    if strict and refused is None:
+        raise AssertionError(
+            "four_chip: build_serving accepted a dp>1 mesh on TPU")
+    # ... and driving the scheduler directly shows what it refuses
+    sched = ContinuousBatchingScheduler(inf, slots=slots)
+    res = _run_server(inf, sched, cfg, slots=slots,
+                      prompt_range=prompt_range, new_tokens=new_tokens)
+    out["server"] = {
+        "mesh": str(inf.topology), "build_serving_refused": refused,
+        **res, "params": _placement(inf.params),
+        "lane_cache_empty": _placement(sched._empty_cache()),
+        "lane_cache_prefilled": _placement(inf._chunked_prefill(
+            jnp.zeros((1, sched.prompt_bucket), jnp.int32),
+            jnp.ones((1, sched.prompt_bucket), bool))[1]),
+        "lane_tokens": "host numpy [slots]; handed to each decode step "
+                       "as an uncommitted array",
+    }
+    return out
+
+
+# ---------------------------------------------------------------------------
+# children and parent
+# ---------------------------------------------------------------------------
+def _child(name: str) -> int:
+    """Run one phase in this (fresh) process. Any exception propagates:
+    the traceback goes to stderr and the exit code is nonzero."""
+    sys.path.insert(0, REPO)
+    info = device_report()
+    if name == "four_chip" and info["device"]["count"] < 4:
+        emit({"phase": "four_chip", "ok": True, "ran": False,
+              "reason": f"JAX reports {info['device']['count']} device(s); "
+                        "the four-chip phase needs 4"})
+        return 0
+    fn = {"kernels": phase_kernels, "train": phase_train,
+          "train_warm": lambda: phase_train(name="train_warm"),
+          "serve": phase_serve, "four_chip": phase_four_chip}[name]
+    emit(fn())
+    return 0
+
+
+def _run_child(name: str, deadline: float, one_chip: bool):
+    """Spawn one phase, echo its stdout, return (exit code, JSON lines).
+    The child leads its own process group, which is killed on the way out
+    whatever happens, so nothing it started outlives this run."""
+    env = dict(os.environ)
+    if one_chip:
+        env.update(ONE_CHIP_ENV)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env["PYTHONUNBUFFERED"] = "1"
+    code = f"import sys, chip_smoke; sys.exit(chip_smoke._child({name!r}))"
+    proc = subprocess.Popen([sys.executable, "-c", code], cwd=REPO, env=env,
+                            stdout=subprocess.PIPE, text=True,
+                            start_new_session=True)
+    lines = []
+    timer = threading.Timer(max(deadline - time.time(), 1.0),
+                            lambda: os.killpg(proc.pid, signal.SIGKILL))
+    try:
+        timer.start()
+        for line in proc.stdout:
+            sys.stdout.write(line)
+            sys.stdout.flush()
+            if line.startswith("{"):
+                try:
+                    lines.append(json.loads(line))
+                except ValueError:
+                    pass
+        return proc.wait(), lines
+    finally:
+        timer.cancel()
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+        proc.wait()
+
+
+def main() -> int:
+    deadline = time.time() + TOTAL_BUDGET_S
+    device, results = None, {}
+    for name in PHASES:
+        # the first child reports the host's devices; after that the
+        # one-chip phases of a multi-chip host see only the first chip
+        one_chip = (device is not None and device["count"] > 1
+                    and name != "four_chip")
+        rc, lines = _run_child(name, deadline, one_chip)
+        for obj in lines:
+            if "device" in obj and "jax" in obj and not one_chip:
+                device = obj["device"]
+            if obj.get("phase") == name and "ok" in obj:
+                results[name] = obj
+        if rc != 0 or name not in results:
+            print(f"chip_smoke: phase {name!r} failed (exit code {rc})",
+                  file=sys.stderr)
+            return rc or 1
+    cold, warm = results["train"], results["train_warm"]
+    emit({"phase": "compile_cache",
+          "train_compile_s": cold["compile_s"],
+          "train_warm_compile_s": warm["compile_s"],
+          "train_first_step_s": cold["first_step_s"],
+          "train_warm_first_step_s": warm["first_step_s"],
+          "train_warm_persistent_cache_hits": warm["persistent_cache_hits"]})
+    if max(abs(a - b) for a, b in zip(warm["losses"], cold["losses"])) \
+            > LOSS_TRAJECTORY_TOL:
+        print("chip_smoke: the warm train run left the cold run's loss "
+              f"trajectory: {warm['losses']} vs {cold['losses']}",
+              file=sys.stderr)
+        return 1
+    four = results["four_chip"]
+    if four.get("ran", True):
+        drift = max(abs(a - b) for a, b in zip(four["losses"],
+                                               cold["losses"]))
+        emit({"phase": "four_chip_vs_one_chip", "max_abs_loss_diff": drift,
+              "one_chip": cold["losses"], "four_chip": four["losses"]})
+        if drift > LOSS_TRAJECTORY_TOL:
+            print("chip_smoke: ZeRO-3 on four chips left the one-chip loss "
+                  f"trajectory (max |diff| {drift})", file=sys.stderr)
+            return 1
+    emit({"ok": True, "device": device})
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -10,10 +10,6 @@ pipeline, expert, and sequence parallelism are PartitionSpecs over mesh axes
 
 from deepspeed_tpu.version import __version__  # noqa: F401
 
-# install jax version shims (jax.shard_map spelling) before any submodule
-# traces a program
-from deepspeed_tpu.utils import jax_compat  # noqa: F401
-
 from deepspeed_tpu import comm  # noqa: F401
 from deepspeed_tpu.runtime.config import DeepSpeedConfig  # noqa: F401
 from deepspeed_tpu.runtime.sentinel import DivergenceError  # noqa: F401

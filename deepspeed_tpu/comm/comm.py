@@ -225,16 +225,7 @@ def init_distributed(
             except OSError:
                 addr = ""
     if addr:
-        try:
-            jax.config.update("jax_cross_host_transfer_socket_address", addr)
-        except Exception as e:
-            # missing flag (old jax) or malformed address: cross-host
-            # device_puts (pipeline inter-stage) will not work — say so
-            # instead of hanging silently later
-            logger.warning(
-                f"cross-host transfer server not configured ({e}); "
-                "host-level cross-mesh transfers (pipeline pp across "
-                "hosts) will be unavailable")
+        jax.config.update("jax_cross_host_transfer_socket_address", addr)
     elif os.environ.get("DS_TPU_TRANSFER_ADDR") is None:
         # not explicitly disabled, yet no address could be derived (e.g.
         # pod auto-detection with no coordinator given, or probe failure)
@@ -248,12 +239,7 @@ def init_distributed(
     # multi-process jit — including the virtual-mesh tests — aborts with
     # "Multiprocess computations aren't implemented on the CPU backend".
     # Must be set BEFORE backend init; harmless for TPU/GPU platforms.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception as e:  # old jax without the option, or no gloo build
-        logger.warning(
-            f"could not enable gloo CPU collectives ({e}); multi-process "
-            "runs on the CPU backend will not work")
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     # log_dist is unusable before the rendezvous: it queries
     # jax.process_index(), which initialises the XLA backend and makes

@@ -69,9 +69,12 @@ def feature_table():
                      f"{format_bytes(cap)} ({cap_src})" if cap is not None
                      else cap_src,
                      GREEN_OK if cap is not None else RED_NO))
-        peak, peak_src = peak_tflops(devs[0])
-        rows.append(("peak bf16 TFLOPS table", f"{peak:g} ({peak_src})",
-                     RED_NO if "unrecognised" in peak_src else GREEN_OK))
+        try:
+            peak, peak_src = peak_tflops(devs[0])
+            rows.append(("peak bf16 TFLOPS table", f"{peak:g} ({peak_src})",
+                         GREEN_OK))
+        except ValueError as e:
+            rows.append(("peak bf16 TFLOPS table", str(e), RED_NO))
         if n_live == 0 and cap is None:
             rows.append(("memory accounting",
                          f"{backend} backend exposes neither memory_stats() "

@@ -328,6 +328,17 @@ class VocabEmbed(nn.Embed):
         return super().__call__(inputs)
 
 
+def _island_batch_axes(topo, batch: int):
+    """The mesh axes a ``shard_map`` island may split a batch of ``batch``
+    rows over (a ``PartitionSpec`` entry). shard_map needs the dim evenly
+    divisible by its axes; when it is not (e.g. batch-1 serving on a dp>1
+    mesh, where the array is replicated anyway) the dim stays unsharded."""
+    b0 = topo.batch_spec()[0]
+    b_axes = b0 if isinstance(b0, tuple) else ((b0,) if b0 else ())
+    b_size = int(np.prod([topo.size(a) for a in b_axes])) if b_axes else 1
+    return b0 if batch % b_size == 0 else None
+
+
 def _vocab_parallel_lookup(ids, embedding, topo, dtype):
     """Masked local-gather + psum over the tp axis (shard_map island)."""
     from jax.sharding import PartitionSpec as P
@@ -335,14 +346,7 @@ def _vocab_parallel_lookup(ids, embedding, topo, dtype):
     tp = topo.size("tp")
     vocab, _ = embedding.shape
     shard = vocab // tp
-    # shard_map needs the batch dims evenly divisible by their mesh axes;
-    # when they are not (e.g. batch-1 serving on a dp>1 mesh, where the
-    # array is replicated anyway), declare them unsharded
-    b0 = topo.batch_spec()[0]
-    b_axes = b0 if isinstance(b0, tuple) else ((b0,) if b0 else ())
-    b_size = int(np.prod([topo.size(a) for a in b_axes])) if b_axes else 1
-    if ids.shape[0] % max(b_size, 1) != 0:
-        b0 = None
+    b0 = _island_batch_axes(topo, ids.shape[0])
     # mirror engine._put_batch: the sequence dim rides sp when it divides
     sp = topo.size("sp")
     t_ax = "sp" if (sp > 1 and ids.shape[1] % sp == 0) else None
@@ -363,6 +367,43 @@ def _vocab_parallel_lookup(ids, embedding, topo, dtype):
         out_specs=P(b0, t_ax, None),
         check_vma=False,
     )(ids, embedding)
+
+
+def _mesh_flash_attention(q, k, v, segment_ids, *, causal, autotune):
+    """The Pallas flash kernel as a ``shard_map`` island over the mesh's
+    batch and head axes.
+
+    GSPMD cannot partition a Mosaic custom call: left bare inside the
+    jitted step it gathers q/k/v and every chip runs the kernel on the
+    whole global batch. Attention is independent per (row, head), so each
+    device runs it on its local ``[B/b, T, H/tp, D]`` block with no
+    collective. Dims the mesh does not divide stay unsharded (batch-1
+    serving on a dp>1 mesh, where the array is replicated anyway)."""
+    from jax.sharding import PartitionSpec as P
+
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    from deepspeed_tpu.parallel.mesh import get_default_topology
+
+    topo = get_default_topology()
+    B, _, H, _ = q.shape
+    b0 = _island_batch_axes(topo, B)
+    tp = topo.size("tp")
+    h_ax = "tp" if (tp > 1 and H % tp == 0) else None
+
+    def local(q, k, v, seg=None):
+        return flash_attention(q, k, v, causal=causal, segment_ids=seg,
+                               autotune=autotune)
+
+    args = (q, k, v) if segment_ids is None else (q, k, v, segment_ids)
+    if (b0 is None and h_ax is None) or topo.size("pp") > 1:
+        # nothing to split, or a pipeline stage jitted over a sub-mesh
+        # (a shard_map bound to the full mesh cannot run there)
+        return local(*args)
+    qkv = P(b0, None, h_ax, None)
+    return jax.shard_map(
+        local, mesh=topo.mesh,
+        in_specs=(qkv, qkv, qkv, P(b0, None))[:len(args)], out_specs=qkv,
+        check_vma=False)(*args)
 
 
 class CausalSelfAttention(nn.Module):
@@ -724,12 +765,9 @@ class CausalSelfAttention(nn.Module):
                      and T % 128 == 0 and not cfg.alibi
                      and (cfg.dropout == 0.0 or deterministic))
         if use_flash:
-            from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-
-            y = flash_attention(q, k, v, causal=cfg.causal,
-                                segment_ids=segment_ids,
-                                autotune=True if cfg.flash_autotune
-                                else None)
+            y = _mesh_flash_attention(
+                q, k, v, segment_ids, causal=cfg.causal,
+                autotune=True if cfg.flash_autotune else None)
         else:
             scale = 1.0 / np.sqrt(D)
             att = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale

@@ -11,11 +11,12 @@ default cannot know.
 Resolution order for :func:`get_flash_blocks` (first hit wins):
 
 1. in-memory cache (one lookup per process per key)
-2. on-disk JSON cache — ``$DS_TPU_PALLAS_CACHE`` or
-   ``~/.cache/deepspeed_tpu/flash_blocks.json``, keyed by
-   ``device_kind|seq|head_dim|dtype|causal``; written by a previous
-   autotune run on this host. A corrupt/unreadable file falls through
-   (warn once) and is overwritten by the next tuned write.
+2. on-disk JSON cache — only where ``$DS_TPU_PALLAS_CACHE`` names a
+   file (no default location: what a program compiles depends on
+   tracked files, not on what an earlier run left in a home directory),
+   keyed by ``device_kind|seq|head_dim|dtype|causal``; written by a
+   previous autotune run. A corrupt/unreadable file falls through (warn
+   once) and is overwritten by the next tuned write.
 3. shipped pretuned table (:data:`PRETUNED`) — seeds for the shapes the
    1.3B benchmark config hits, derived from the kernel's VMEM/pruning
    model (docs/performance.md); refreshed in place by live autotunes.
@@ -71,10 +72,10 @@ _mem_cache: Dict[str, Tuple[int, int]] = {}
 _disk_warned = False
 
 
-def cache_path() -> str:
-    return os.environ.get(_CACHE_ENV) or os.path.join(
-        os.path.expanduser("~"), ".cache", "deepspeed_tpu",
-        "flash_blocks.json")
+def cache_path() -> Optional[str]:
+    """The disk cache file, or None when ``$DS_TPU_PALLAS_CACHE`` is unset
+    (then nothing is read from or written to disk)."""
+    return os.environ.get(_CACHE_ENV) or None
 
 
 def cache_key(device_kind: str, t: int, d: int, dtype, causal: bool) -> str:
@@ -85,7 +86,7 @@ def cache_key(device_kind: str, t: int, d: int, dtype, causal: bool) -> str:
 def _load_disk_cache() -> Dict[str, List[int]]:
     global _disk_warned
     path = cache_path()
-    if not os.path.exists(path):
+    if path is None or not os.path.exists(path):
         return {}
     try:
         with open(path) as f:
@@ -105,7 +106,9 @@ def _load_disk_cache() -> Dict[str, List[int]]:
 
 def _store_disk_cache(key: str, blocks: Tuple[int, int]) -> None:
     path = cache_path()
-    os.makedirs(os.path.dirname(path), exist_ok=True)
+    if path is None:
+        return
+    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     data = _load_disk_cache()
     data[key] = [int(blocks[0]), int(blocks[1])]
     tmp = f"{path}.tmp.{os.getpid()}"
@@ -195,10 +198,7 @@ def get_flash_blocks(t: int, d: int, dtype, causal: bool, *,
     """
     heuristic = (largest_divisor_block(t, want_q),
                  largest_divisor_block(t, want_k))
-    try:
-        device_kind = jax.devices()[0].device_kind
-    except Exception:
-        return heuristic
+    device_kind = jax.devices()[0].device_kind
     key = cache_key(device_kind, t, d, dtype, causal)
 
     with _lock:

@@ -2,15 +2,25 @@
 
 import jax
 
+from deepspeed_tpu.utils.logging import warning_once
+
 NEG_INF = -1e30
 # logsumexp rows carry 8 broadcast sublane copies to satisfy TPU tiling
 LSE_LANES = 8
 
 
 def interpret() -> bool:
-    """Run kernels in interpreter mode off-TPU so the CPU test mesh
-    exercises the same code path."""
-    return jax.default_backend() != "tpu"
+    """Whether Pallas kernels run in interpreter mode: on every backend but
+    TPU, so the CPU test mesh exercises the same kernel bodies. Logged once
+    when it engages — a job meant for the chip whose backend came up as
+    CPU would otherwise interpret every kernel without a word."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    warning_once(
+        f"Pallas kernels run in INTERPRET mode: backend is {backend!r}, not "
+        "'tpu' (correct results, none of the chip's speed)")
+    return True
 
 
 def largest_divisor_block(t: int, want: int = 128) -> int:

@@ -23,8 +23,12 @@ to match TPU tiling with no in-kernel transpose:
 
 * ``seg_r [bh, t, LSE_LANES]`` — row layout, sliced like q/lse blocks to
   give the query-side segment id column;
-* ``seg_c [bh, LSE_LANES, t]`` — column layout, sliced along the lane
-  axis to give the key-side segment id row.
+* ``seg_c [bh, LSE_LANES, t]`` — column layout: the key-side segment id
+  row. The dkv kernel takes its own k block of it through a BlockSpec; the
+  fwd and dq kernels, which walk the k blocks in a loop, get it regrouped
+  as ``[bh, t/block_k, LSE_LANES, block_k]`` and index the block on the
+  leading axis (Mosaic lowers no ``dynamic_slice`` of a value, and a
+  leading-axis ref index needs no lane alignment proof).
 
 Masking uses the same finite ``NEG_INF`` as the causal path: a masked
 score contributes ``exp(-1e30) == 0.0`` exactly to both softmax and its
@@ -71,7 +75,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
     q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
     if has_seg:
         q_seg = sq_ref[...][:, :1]  # [bq, 1]
-        k_seg_row = sk_ref[...]     # [LSE_LANES, t]
 
     def body(j, carry):
         m, l, acc = carry
@@ -86,8 +89,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
                 jnp.int32, (bq, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         if has_seg:
-            k_seg = jax.lax.dynamic_slice(
-                k_seg_row, (0, j * block_k), (1, block_k))
+            k_seg = sk_ref[j][:1, :]  # [1, block_k]
             s = jnp.where(q_seg == k_seg, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -111,6 +113,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
     lse_ref[...] = jnp.broadcast_to(m + jnp.log(l), (bq, LSE_LANES))
 
 
+def _seg_by_k_block(seg_c, block_k):
+    """``[bh, LSE_LANES, t]`` -> ``[bh, t/block_k, LSE_LANES, block_k]``
+    with its whole-array BlockSpec (see the module docstring)."""
+    bh, lanes, t = seg_c.shape
+    nk = t // block_k
+    grouped = seg_c.reshape(bh, lanes, nk, block_k).transpose(0, 2, 1, 3)
+    return grouped, pl.BlockSpec((None, nk, lanes, block_k),
+                                 lambda i, j: (i, 0, 0, 0))
+
+
 def _fwd(q, k, v, seg, scale, causal, block_q, block_k):
     b, t, h, d = q.shape
     bh = b * h
@@ -127,11 +139,12 @@ def _fwd(q, k, v, seg, scale, causal, block_q, block_k):
     operands = [qf, kf, vf]
     if seg is not None:
         seg_r, seg_c = seg
+        seg_ck, seg_ck_spec = _seg_by_k_block(seg_c, block_k)
         in_specs += [
             pl.BlockSpec((None, block_q, LSE_LANES), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, LSE_LANES, t), lambda i, j: (i, 0, 0)),
+            seg_ck_spec,
         ]
-        operands += [seg_r, seg_c]
+        operands += [seg_r, seg_ck]
 
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -173,7 +186,6 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
     if has_seg:
         q_seg = sq_ref[...][:, :1]  # [bq, 1]
-        k_seg_row = sk_ref[...]     # [LSE_LANES, t]
 
     def body(j, dq):
         k_blk = k_ref[pl.ds(j * block_k, block_k), :]
@@ -186,8 +198,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                 jnp.int32, (bq, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         if has_seg:
-            k_seg = jax.lax.dynamic_slice(
-                k_seg_row, (0, j * block_k), (1, block_k))
+            k_seg = sk_ref[j][:1, :]  # [1, block_k]
             s = jnp.where(q_seg == k_seg, s, NEG_INF)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(
@@ -298,11 +309,12 @@ def _bwd_impl(scale, causal, block_q, block_k, q, k, v, o, lse, do,
     dkv_operands = [qf, kf, vf, dof, lse, delta]
     if seg is not None:
         seg_r, seg_c = seg
+        seg_ck, seg_ck_spec = _seg_by_k_block(seg_c, block_k)
         dq_in_specs += [
             pl.BlockSpec((None, block_q, LSE_LANES), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, LSE_LANES, t), lambda i, j: (i, 0, 0)),
+            seg_ck_spec,
         ]
-        dq_operands += [seg_r, seg_c]
+        dq_operands += [seg_r, seg_ck]
         # dkv slices the row layout by q block in-kernel and takes its own
         # k block from the column layout
         dkv_in_specs += [

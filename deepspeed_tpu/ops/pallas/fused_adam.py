@@ -18,9 +18,7 @@ import jax.numpy as jnp
 import optax
 from jax.experimental import pallas as pl
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from deepspeed_tpu.ops.pallas.common import interpret as _interpret
 
 
 def _adamw_kernel(lr_ref, c1_ref, c2_ref, p_ref, g_ref, m_ref, v_ref,

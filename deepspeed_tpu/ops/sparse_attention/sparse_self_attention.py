@@ -597,8 +597,7 @@ class SparseSelfAttention:
     """Module-level API of reference ``sparse_self_attention.py:11``.
 
     Computes scaled dot-product attention under the config's block-sparsity
-    layout. Routes to the streaming Pallas kernel when no element-level masks
-    are given, and to the XLA dense-masked path otherwise.
+    layout through the selected ``impl`` (see ``__init__``).
     """
 
     def __init__(self, sparsity_config, key_padding_mask_mode: str = "add",
@@ -654,16 +653,13 @@ class SparseSelfAttention:
             if key_padding_mask is None and attn_mask is None:
                 return block_sparse_attention(
                     query, key, value, layout, block=block, causal=causal)
-            # the streaming kernel takes no element-level masks; an explicit
-            # pallas selection degrading to the quadratic masked-dense path
-            # must not happen silently (O(T^2) scores at long seq)
-            import warnings
-
-            warnings.warn(
-                "sparse_attention kernel='pallas' with an element mask "
-                "falls back to masked DENSE attention (full [T, T] "
-                "scores); use the default 'gather' impl for masked "
-                "inputs", stacklevel=2)
+            # the streaming kernel takes no element-level masks, and a
+            # kernel entry point does not give way to the quadratic
+            # masked-dense reference (O(T^2) scores at long seq)
+            raise ValueError(
+                "sparse_attention kernel='pallas' takes no element mask "
+                "(key_padding_mask / attn_mask); use the default 'gather' "
+                "impl for masked inputs")
         return dense_blocksparse_attention(
             query, key, value, layout, block=block,
             causal=causal, key_padding_mask=key_padding_mask,

@@ -128,35 +128,20 @@ class MeshTopology:
         the 'collectives ride ICI, not DCN' layout. Plain reshape off-TPU."""
         is_tpu = bool(devices) and getattr(
             devices[0], "platform", "cpu") == "tpu"
-        slice_ids = ({getattr(d, "slice_index", None) or 0 for d in devices}
-                     if is_tpu else set())
-        try:
-            from jax.experimental import mesh_utils
-        except Exception:
-            if len(slice_ids) > 1:
-                # the plain-reshape fallback is exactly the silent
-                # DCN-crossing layout the multi-slice branch exists to
-                # reject — fail loudly instead
-                raise RuntimeError(
-                    "multi-slice TPU job but jax.experimental.mesh_utils "
-                    "is unavailable: cannot build the hybrid ICI x DCN "
-                    "mesh; a plain reshape would route tp/sp collectives "
-                    "over DCN")
+        if not is_tpu:
             return np.array(devices).reshape(shape)
-        if is_tpu:
-            if len(slice_ids) > 1:
-                # multi-slice must not silently fall back: a plain reshape
-                # would route tp/sp collectives over DCN
-                dcn_shape = MeshTopology._derive_dcn_shape(
-                    shape, len(slice_ids))
-                per_slice = tuple(s // d for s, d in zip(shape, dcn_shape))
-                return mesh_utils.create_hybrid_device_mesh(
-                    per_slice, dcn_shape, devices=devices)
-            try:
-                return mesh_utils.create_device_mesh(shape, devices=devices)
-            except Exception:
-                pass
-        return np.array(devices).reshape(shape)
+        from jax.experimental import mesh_utils
+
+        slice_ids = {getattr(d, "slice_index", None) or 0 for d in devices}
+        if len(slice_ids) > 1:
+            # a plain reshape would route tp/sp collectives over DCN
+            dcn_shape = MeshTopology._derive_dcn_shape(shape, len(slice_ids))
+            per_slice = tuple(s // d for s, d in zip(shape, dcn_shape))
+            return mesh_utils.create_hybrid_device_mesh(
+                per_slice, dcn_shape, devices=devices)
+        # no reshape fallback on the chip: a mesh_utils failure is a layout
+        # the ICI topology cannot host, and it must be seen
+        return mesh_utils.create_device_mesh(shape, devices=devices)
 
     # -- size queries (parity: groups.get_data_parallel_world_size etc.) ---
     def size(self, axis: str) -> int:

@@ -34,19 +34,22 @@ def params_count(params) -> int:
                    for p in jax.tree.leaves(params)))
 
 
-def cost_analysis(fn: Callable, *args, **kwargs) -> Dict[str, float]:
-    """Compile ``fn`` for the given args and return HLO cost metrics:
-    flops, bytes accessed, and the compiler's optimal-seconds estimate."""
-    jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
-    compiled = jitted.lower(*args, **kwargs).compile()
+def cost_of(compiled) -> Dict[str, float]:
+    """HLO cost metrics of an already-compiled program
+    (``jax.stages.Compiled``): flops, bytes accessed, and the compiler's
+    optimal-seconds estimate."""
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per computation
-        ca = ca[0] if ca else {}
     return {
         "flops": _num(ca.get("flops", 0)),
         "bytes_accessed": _num(ca.get("bytes accessed", 0)),
         "optimal_seconds": _num(ca.get("optimal_seconds", 0)),
     }
+
+
+def cost_analysis(fn: Callable, *args, **kwargs) -> Dict[str, float]:
+    """Compile ``fn`` for the given args and return its :func:`cost_of`."""
+    jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+    return cost_of(jitted.lower(*args, **kwargs).compile())
 
 
 def measure_latency(fn: Callable, *args, warmup: int = 1, iters: int = 5,

@@ -70,25 +70,24 @@ HW_PEAK_BF16_TFLOPS = (
 def peak_tflops(device=None, override: Optional[float] = None):
     """``(peak_bf16_tflops, source)`` for ``device`` (default: devices()[0]).
 
-    ``override`` (the config's ``peak_tflops``) wins over the table; an
-    unrecognised device kind falls back to the v5e figure so MFU is still
-    emitted (flagged via the source string) rather than crashing the run.
+    ``override`` (the config's ``peak_tflops``) wins over the table. A
+    ``device_kind`` that is not in the table raises: a utilization figure
+    against a guessed denominator is worse than no figure.
     """
     if override:
         return float(override), "config override"
-    kind = ""
-    try:
-        if device is None:
-            import jax
+    if device is None:
+        import jax
 
-            device = jax.devices()[0]
-        kind = str(getattr(device, "device_kind", device)).lower()
-    except Exception:  # pragma: no cover - backend-less host
-        return 197.0, "unknown device (v5e default)"
+        device = jax.devices()[0]
+    kind = str(getattr(device, "device_kind", device)).lower()
     for sub, peak in HW_PEAK_BF16_TFLOPS:
         if sub in kind:
             return peak, f"device_kind={kind!r}"
-    return 197.0, f"unrecognised device_kind={kind!r} (v5e default)"
+    raise ValueError(
+        f"no peak-TFLOPS entry for device_kind={kind!r}: add it to "
+        "HW_PEAK_BF16_TFLOPS with its source, or set "
+        "step_profiler.peak_tflops in the config")
 
 
 # Reusable no-op context manager returned on every non-profiled step:

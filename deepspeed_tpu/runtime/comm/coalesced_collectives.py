@@ -19,15 +19,6 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def _axis_size(axis: str) -> int:
-    """Static size of a bound mesh axis. ``lax.axis_size`` only exists in
-    newer JAX; ``psum(1, axis)`` is the portable spelling — a literal psum
-    constant-folds to the axis size at trace time, so shapes stay static."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
 def _flatten_pad(tensors: Sequence[jnp.ndarray], world: int
                  ) -> Tuple[jnp.ndarray, List[Tuple[int, Any, Any]]]:
     """Concat raveled tensors; pad total to a multiple of ``world``.
@@ -49,7 +40,7 @@ def reduce_scatter_coalesced(tensors: Sequence[jnp.ndarray], axis: str
     Must run inside shard_map/jit with ``axis`` bound. The caller unpacks
     shard-local slices with :func:`shard_layout`.
     """
-    world = _axis_size(axis)
+    world = lax.axis_size(axis)
     flat, _ = _flatten_pad(tensors, world)
     return lax.psum_scatter(flat, axis, tiled=True)
 
@@ -66,7 +57,7 @@ def all_gather_coalesced(shards: Sequence[jnp.ndarray], axis: str
     partitioning ZeRO-3 uses — the caller reshapes/unpads). Memory is 1x
     the gathered size; the reslice compiles to static slices of the single
     gathered buffer."""
-    world = _axis_size(axis)
+    world = lax.axis_size(axis)
     sizes = [int(s.size) for s in shards]
     flat = jnp.concatenate([s.ravel() for s in shards])
     per = flat.size

@@ -28,7 +28,6 @@ import shutil
 import signal as signal_module
 import threading
 import time
-from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -65,6 +64,7 @@ from deepspeed_tpu.runtime.optimizer import (
     build_optimizer,
     is_compressed_optimizer,
 )
+from deepspeed_tpu.utils.compile_cache import ensure_compile_cache
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 
@@ -76,6 +76,18 @@ STEP_MICRO_TIMER = "step_microstep"
 import contextlib as _contextlib
 
 _NULL_PROF_CTX = _contextlib.nullcontext()
+
+
+def _avals_like(tree):
+    """Avals that lower to the SAME executable jit already dispatched for
+    ``tree``: shape, dtype and — for committed arrays — the sharding.
+    ``jitted.lower(avals).compile()`` is then a hit in jit's own lowering
+    cache; an aval without the sharding is a different cache key and
+    costs a second full XLA compile of the step."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None),
+        tree)
 
 
 def initialize(
@@ -119,6 +131,7 @@ def initialize(
 
     if dist_init_required is None or dist_init_required:
         comm.init_distributed()
+    ensure_compile_cache()
 
     # Pipeline-module dispatch (reference __init__.py:123-147)
     from deepspeed_tpu.runtime.pipe import PipelineModule  # lazy, avoids cycle
@@ -733,7 +746,9 @@ class DeepSpeedEngine:
         model's streamable leaves to the accelerator host's memory
         (``pinned_host``). The model's scan streams one layer back into
         HBM per iteration (ops/streaming.py), so device memory never holds
-        the full parameter set."""
+        the full parameter set. On TPU the streamed leaves must be 32-bit
+        (the toolchain aborts on bf16 host stacks); on the virtual CPU
+        mesh the params stay in device memory (structure only)."""
         if self._offload_param_device != "cpu":
             raise NotImplementedError(
                 "offload_param device must be 'cpu' (pinned host memory) "
@@ -761,13 +776,14 @@ class DeepSpeedEngine:
             raise ValueError(
                 "offload_param is configured but the model marks no params "
                 "as streamable (is the model's param_offload flag set?)")
-        platform = jax.devices()[0].platform
-        if platform != "tpu":
+        if jax.default_backend() != "tpu":
             log_dist(
-                f"offload_param: backend {platform!r} does not support "
-                "host-memory placement under SPMD; params stay in device "
-                "memory (structure-only mode for tests)", ranks=[0])
+                f"offload_param: backend {jax.default_backend()!r} does not "
+                "support host-memory placement under SPMD; params stay in "
+                "device memory (structure-only mode for tests)", ranks=[0])
             return
+        from deepspeed_tpu.ops.streaming import check_streamable
+
         threshold = self._config.zero_config.param_persistence_threshold
         n_off = [0, 0]
 
@@ -781,6 +797,7 @@ class DeepSpeedEngine:
 
         for p, sd in flat:
             if is_offloaded(p, sd):
+                check_streamable(sd.dtype)
                 n_off[0] += 1
                 n_off[1] += int(np.prod(sd.shape))
         self._param_shardings = jax.tree_util.tree_map_with_path(
@@ -858,13 +875,11 @@ class DeepSpeedEngine:
             self._opt_state = jax.jit(
                 self._tx.init, out_shardings=self._opt_shardings
             )(self._params)
-        self._acc_grads = jax.jit(
-            lambda p: jax.tree.map(
-                lambda x: jnp.zeros(
-                    ((self._comp_k,) + x.shape) if self._compressed_mode
-                    else x.shape, jnp.float32), p),
-            out_shardings=self._grad_shardings,
-        )(self._params)
+        # the step returns the loss-scale state as a mesh-typed array; an
+        # un-placed initial value has a different aval type, and the second
+        # step would retrace and recompile the whole program
+        self._ls_state = jax.device_put(
+            self._ls_state, self.topology.replicated())
         self._initialized = True
         n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(self._params))
         log_dist(
@@ -872,6 +887,21 @@ class DeepSpeedEngine:
             f"{time.time()-t0:.1f}s (zero stage {self.zero_stage})",
             ranks=[0],
         )
+
+    def _ensure_acc_grads(self):
+        """The fp32 gradient accumulator of the split fwd/bwd + apply path,
+        allocated on its first use. The fused gas == 1 step never reads it,
+        and at 1.3B it is 5.25 GB: resident beside bf16 params and moments
+        it put the one-chip step at 16.79 of the v5e's 16.91 GB (chip run,
+        PR 21)."""
+        if self._acc_grads is None:
+            self._acc_grads = jax.jit(
+                lambda p: jax.tree.map(
+                    lambda x: jnp.zeros(
+                        ((self._comp_k,) + x.shape) if self._compressed_mode
+                        else x.shape, jnp.float32), p),
+                out_shardings=self._grad_shardings,
+            )(self._params)
 
     # ------------------------------------------------------------------
     # compressed gradient exchange (1-bit optimizers / int8 grad comm)
@@ -1540,8 +1570,7 @@ class DeepSpeedEngine:
             return jax.device_put(x, target)
 
         device_batch = jax.tree.map(put, batch)
-        self._last_batch_aval = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), device_batch)
+        self._last_batch_aval = _avals_like(device_batch)
         return device_batch
 
     # ------------------------------------------------------------------
@@ -1593,42 +1622,53 @@ class DeepSpeedEngine:
             self._live_mem_sampling = False
         return stats
 
+    def compiled_step_programs(self) -> Optional[Dict[str, Any]]:
+        """The optimizer step's compiled executable(s) by program name
+        (``jax.stages.Compiled``): ``{"train_step"}`` on the fused path,
+        ``{"fwd_bwd", "apply"}`` on the split one, or None before the
+        step has run. Re-lowering with :func:`_avals_like` avals hits the
+        lowering cache of the executable jit already dispatched — no XLA
+        compile — so HLO text, cost and memory analysis all describe the
+        program that actually runs."""
+        if self._last_batch_aval is None or not self._initialized:
+            return None
+        aval = _avals_like
+        scale = self._ls_state.scale if self.fp16_enabled else self._unit_scale
+        lr_factor = jnp.float32(1.0)
+        if self._train_step_fn is not None:
+            return {"train_step": self._train_step_fn.lower(
+                aval(self._params), aval(self._opt_state),
+                aval(self._ls_state), self._last_batch_aval,
+                aval(self._rng), self.micro_steps, lr_factor).compile()}
+        if self._fwd_bwd_fn is None or self._apply_fn is None:
+            return None
+        return {
+            "fwd_bwd": self._fwd_bwd_fn.lower(
+                aval(self._params), aval(self._acc_grads),
+                self._last_batch_aval, aval(self._rng), self.micro_steps,
+                aval(scale)).compile(),
+            "apply": self._apply_fn.lower(
+                aval(self._params), aval(self._opt_state),
+                aval(self._acc_grads), aval(self._ls_state),
+                lr_factor).compile(),
+        }
+
     def compiled_step_memory(self) -> Optional[Dict[str, float]]:
         """XLA ``memory_analysis()`` of one optimizer step's compiled
         program(s): per-program argument/output/temp/aliased bytes plus
         the headline ``peak_working_set_bytes`` (max over sequentially-run
-        programs), or None before the step has compiled. Same aval
-        discipline as :meth:`compiled_step_cost` — lowering the live
-        shapes is a compile-cache hit, captured once per program set."""
+        programs), or None before the step has compiled."""
         from deepspeed_tpu.telemetry.memory import (
-            compiled_memory_analysis,
+            memory_analysis_of,
             summarize_program_memory,
         )
 
-        aval = partial(jax.tree.map,
-                       lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype))
-        if self._last_batch_aval is None or not self._initialized:
-            return None
-        scale = self._ls_state.scale if self.fp16_enabled else self._unit_scale
-        lr_factor = jnp.float32(1.0)
         try:
-            if self._train_step_fn is not None:
-                mem = compiled_memory_analysis(
-                    self._train_step_fn, aval(self._params),
-                    aval(self._opt_state), aval(self._ls_state),
-                    self._last_batch_aval, aval(self._rng),
-                    self.micro_steps, lr_factor)
-                return summarize_program_memory({"train_step": mem})
-            if self._fwd_bwd_fn is None or self._apply_fn is None:
+            programs = self.compiled_step_programs()
+            if programs is None:
                 return None
-            fwd = compiled_memory_analysis(
-                self._fwd_bwd_fn, aval(self._params), aval(self._acc_grads),
-                self._last_batch_aval, aval(self._rng), self.micro_steps,
-                aval(scale))
-            app = compiled_memory_analysis(
-                self._apply_fn, aval(self._params), aval(self._opt_state),
-                aval(self._acc_grads), aval(self._ls_state), lr_factor)
-            return summarize_program_memory({"fwd_bwd": fwd, "apply": app})
+            return summarize_program_memory(
+                {k: memory_analysis_of(c) for k, c in programs.items()})
         except Exception as e:
             logger.warning(f"compiled_step_memory unavailable: {e}")
             return None
@@ -1636,37 +1676,20 @@ class DeepSpeedEngine:
     def compiled_step_cost(self) -> Optional[Dict[str, float]]:
         """XLA cost analysis of one optimizer step's compiled program(s):
         ``{"flops", "bytes_accessed", "optimal_seconds"}`` per device, or
-        None before the step has compiled. The fused path lowers the
-        single step program; the unfused path charges the fwd/bwd program
-        once per micro step plus the apply program (the honest per-step
-        total). Used by the step profiler and the bench harnesses in
-        place of hand-derived FLOP counts."""
-        from deepspeed_tpu.profiling.flops_profiler.profiler import (
-            cost_analysis)
+        None before the step has compiled. The unfused path charges the
+        fwd/bwd program once per micro step plus the apply program (the
+        honest per-step total). Used by the step profiler and the bench
+        harnesses in place of hand-derived FLOP counts."""
+        from deepspeed_tpu.profiling.flops_profiler.profiler import cost_of
 
-        aval = partial(jax.tree.map,
-                       lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype))
-        if self._last_batch_aval is None or not self._initialized:
-            return None
-        scale = self._ls_state.scale if self.fp16_enabled else self._unit_scale
-        lr_factor = jnp.float32(1.0)
         try:
-            if self._train_step_fn is not None:
-                return cost_analysis(
-                    self._train_step_fn, aval(self._params),
-                    aval(self._opt_state), aval(self._ls_state),
-                    self._last_batch_aval, aval(self._rng),
-                    self.micro_steps, lr_factor)
-            if self._fwd_bwd_fn is None or self._apply_fn is None:
+            programs = self.compiled_step_programs()
+            if programs is None:
                 return None
+            if "train_step" in programs:
+                return cost_of(programs["train_step"])
             gas = self.gradient_accumulation_steps
-            fwd = cost_analysis(
-                self._fwd_bwd_fn, aval(self._params), aval(self._acc_grads),
-                self._last_batch_aval, aval(self._rng), self.micro_steps,
-                aval(scale))
-            app = cost_analysis(
-                self._apply_fn, aval(self._params), aval(self._opt_state),
-                aval(self._acc_grads), aval(self._ls_state), lr_factor)
+            fwd, app = cost_of(programs["fwd_bwd"]), cost_of(programs["apply"])
             return {k: fwd[k] * gas + app[k] for k in fwd}
         except Exception as e:
             logger.warning(f"compiled_step_cost unavailable: {e}")
@@ -1733,6 +1756,7 @@ class DeepSpeedEngine:
             batch = self._apply_curriculum(batch)
         if not self._initialized:
             self._init_state(batch)
+        self._ensure_acc_grads()
         compile_pending = self._fwd_bwd_fn is None
         if compile_pending:
             self._fwd_bwd_fn = self._build_fwd_bwd()

@@ -21,8 +21,9 @@ Resolution order for :func:`get_step_config` — the exact
 mem -> disk -> PRETUNED -> live chain of ``ops/pallas/autotune.py``:
 
 1. in-memory cache (one lookup per process per key)
-2. on-disk JSON cache — ``$DS_TPU_STEP_AUTOTUNE_CACHE`` or
-   ``~/.cache/deepspeed_tpu/step_configs.json``, keyed
+2. on-disk JSON cache — only where ``$DS_TPU_STEP_AUTOTUNE_CACHE``
+   names a file (no default location: the engine's step must not depend
+   on what an earlier run left in a home directory), keyed
    ``device_kind|nN|model|seq|dtype`` (N = device count, so an elastic
    topology change re-tunes); corrupt files warn once and fall
    through, overwritten by the next tuned write.
@@ -119,10 +120,10 @@ class StepCandidate:
 # cache plumbing (the ops/pallas/autotune.py pattern)
 # ---------------------------------------------------------------------------
 
-def cache_path() -> str:
-    return os.environ.get(_CACHE_ENV) or os.path.join(
-        os.path.expanduser("~"), ".cache", "deepspeed_tpu",
-        "step_configs.json")
+def cache_path() -> Optional[str]:
+    """The disk cache file, or None when ``$DS_TPU_STEP_AUTOTUNE_CACHE`` is
+    unset (then nothing is read from or written to disk)."""
+    return os.environ.get(_CACHE_ENV) or None
 
 
 def cache_key(device_kind: str, model: str, seq: int, dtype,
@@ -140,7 +141,7 @@ def cache_key(device_kind: str, model: str, seq: int, dtype,
 def _load_disk_cache() -> Dict[str, Dict[str, Any]]:
     global _disk_warned
     path = cache_path()
-    if not os.path.exists(path):
+    if path is None or not os.path.exists(path):
         return {}
     try:
         with open(path) as f:
@@ -160,7 +161,9 @@ def _load_disk_cache() -> Dict[str, Dict[str, Any]]:
 
 def _store_disk_cache(key: str, entry: Dict[str, Any]) -> None:
     path = cache_path()
-    os.makedirs(os.path.dirname(path), exist_ok=True)
+    if path is None:
+        return
+    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     data = _load_disk_cache()
     data[key] = entry
     tmp = f"{path}.tmp.{os.getpid()}"
@@ -665,15 +668,9 @@ def get_step_config(model: str, seq: int, dtype=None, *,
 
     dtype = dtype or jnp.bfloat16
     if device_kind is None:
-        try:
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            return None
+        device_kind = jax.devices()[0].device_kind
     if num_devices is None:
-        try:
-            num_devices = jax.device_count()
-        except Exception:
-            num_devices = 1
+        num_devices = jax.device_count()
     key = cache_key(device_kind, model, seq, dtype, num_devices)
 
     with _lock:

@@ -134,6 +134,23 @@ def build_serving(engine, config: Optional[Dict[str, Any]] = None,
     live engine, not a knob) plus ``spec_k`` turn on exact-greedy
     speculative decoding in the scheduler.
     """
+    import jax
+
+    shards = engine.topology.data_parallel_size
+    if shards > 1 and jax.default_backend() == "tpu":
+        # The scheduler shards neither lanes nor caches: on a mesh with
+        # data axes every prefill and decode step runs REPLICATED — N chips
+        # each do all of one chip's work and each holds every lane's cache
+        # (seen on four v5e chips, PR 21). What a later PR must do: let
+        # InferenceEngine take a device subset so one process runs N
+        # one-chip replicas behind PrefixRouter, or shard the lane axis
+        # over dp. Until then say so instead of wasting the chips.
+        raise NotImplementedError(
+            f"build_serving on a mesh with {shards} data shards "
+            f"({engine.topology}): the continuous-batching scheduler does "
+            "not shard lanes or caches, so every chip would redo every "
+            "lane. Serve with tensor parallelism over all chips "
+            "(init_inference(mp_size=N)) or run one process per chip.")
     cfg = dict(config or {})
     slots = int(cfg.pop("slots", 8))
     prompt_bucket = cfg.pop("prompt_bucket", None)

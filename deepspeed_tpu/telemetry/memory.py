@@ -5,9 +5,8 @@ Three sources, in decreasing order of authority:
 1. **Compiled-step ``memory_analysis()``** — XLA's own accounting of the
    already-compiled step executable (``CompiledMemoryStats``): argument /
    output / temp bytes, donation-aliased bytes, generated-code size.
-   Captured ONCE at compile (the ``cost_analysis`` pattern in
-   ``profiling/flops_profiler``); lowering with avals of the live state
-   is a compile-cache hit, so this never recompiles.
+   Read from the executable the engine already dispatched
+   (``DeepSpeedEngine.compiled_step_programs``), so it never recompiles.
 2. **Live ``device.memory_stats()`` watermarks** — the PJRT allocator's
    ``bytes_in_use`` / ``peak_bytes_in_use``. A host-local runtime query,
    NOT a device sync, but still sampled only where the step profiler has
@@ -88,12 +87,9 @@ def live_memory_stats(device=None) -> Optional[Dict[str, int]]:
     return out or None
 
 
-def compiled_memory_analysis(fn, *args) -> Dict[str, float]:
-    """XLA memory analysis of ``fn(*args)`` (args may be avals).
-
-    Mirrors ``flops_profiler.cost_analysis``: jit (no-op when ``fn`` is
-    already jitted), lower, compile — a cache hit for an already-compiled
-    step — then read ``CompiledMemoryStats``. Returns bytes::
+def memory_analysis_of(compiled) -> Dict[str, float]:
+    """``CompiledMemoryStats`` of an already-compiled program
+    (``jax.stages.Compiled``), as bytes::
 
         {"argument_bytes", "output_bytes", "temp_bytes", "alias_bytes",
          "generated_code_bytes", "peak_working_set_bytes"}
@@ -103,10 +99,6 @@ def compiled_memory_analysis(fn, *args) -> Dict[str, float]:
     per-device HBM ceiling of running this program, excluding whatever
     else the process keeps resident.
     """
-    import jax
-
-    jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
-    compiled = jitted.lower(*args).compile()
     ma = compiled.memory_analysis()
     if ma is None:  # pragma: no cover - backend without the API
         raise RuntimeError("backend returned no memory_analysis()")
@@ -123,6 +115,17 @@ def compiled_memory_analysis(fn, *args) -> Dict[str, float]:
         "generated_code_bytes": code,
         "peak_working_set_bytes": max(0.0, arg + out + tmp - alias),
     }
+
+
+def compiled_memory_analysis(fn, *args) -> Dict[str, float]:
+    """:func:`memory_analysis_of` ``fn(*args)`` (args may be avals): jit
+    (no-op when ``fn`` is already jitted), lower, compile. A hit in jit's
+    own cache only when the avals carry the shardings of the arrays the
+    step was dispatched with; otherwise a fresh XLA compile."""
+    import jax
+
+    jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+    return memory_analysis_of(jitted.lower(*args).compile())
 
 
 def memory_analysis_of_call(jitted_fn, *concrete_args) -> Dict[str, float]:

@@ -52,8 +52,7 @@ def main():
 
     if args.mode == "int8_tp":
         # ~350M params: the size where weight-only int8 starts WINNING
-        # (below ~200M decode is dispatch-bound and int8 measures slower;
-        # benchmarks/inference/int8_results.json)
+        # (below ~200M decode is dispatch-bound and int8 measures slower)
         cfg = GPTConfig(vocab_size=50257, n_positions=256, n_embd=1024,
                         n_layer=24, n_head=16, dtype=jnp.bfloat16)
         engine = deepspeed_tpu.init_inference(

@@ -282,9 +282,8 @@ class TestInt8Serving:
         assert out.shape == (2, 6)
 
     def test_small_model_int8_warns_once(self, caplog):
-        """dtype=int8 below the measured win threshold logs the measured
-        loss (int8_results.json: 0.84-0.96x at 125M) instead of silently
-        serving slower."""
+        """dtype=int8 below the measured win threshold says so once
+        instead of silently serving slower."""
         import logging
 
         from deepspeed_tpu.utils.logging import _warn_once_cached

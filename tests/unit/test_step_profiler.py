@@ -51,10 +51,10 @@ class TestPeakTable:
         assert peak == expected
         assert "device_kind" in src
 
-    def test_unknown_kind_falls_back_flagged(self):
-        peak, src = peak_tflops(device="quantum abacus")
-        assert peak == 197.0
-        assert "unrecognised" in src
+    @pytest.mark.parametrize("kind", ["TPU v9", "quantum abacus"])
+    def test_unknown_kind_raises(self, kind):
+        with pytest.raises(ValueError, match="no peak-TFLOPS entry"):
+            peak_tflops(device=kind)
 
 
 # ---------------------------------------------------------------------------
@@ -297,7 +297,7 @@ class TestWireBytes:
         eval_shape and compare ring-accounted wire bytes."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         from deepspeed_tpu.comm import comm as dist
@@ -309,7 +309,7 @@ class TestWireBytes:
 
         def traced_bytes(fn):
             mapped = shard_map(fn, mesh=mesh, in_specs=(P(),),
-                               out_specs=P(), check_rep=False)
+                               out_specs=P(), check_vma=False)
             comms_logger.reset()
             comms_logger.enabled = True
             comms_logger.prof_all = True
@@ -342,42 +342,39 @@ class TestWireBytes:
 # bench preflight / retry helpers
 # ---------------------------------------------------------------------------
 class TestBenchHelpers:
-    def test_preflight_retries_then_succeeds(self):
+    def test_preflight_accepts_only_tpu(self):
+        from benchmarks._util import backend_preflight
+
+        events = []
+        r = backend_preflight(emit=events.append,
+                              _runner=lambda: (True, "tpu 1"))
+        assert r == {"ok": True, "backend": "tpu 1"} and not events
+
+    def test_preflight_rejects_cpu_probe(self):
+        """BENCH_r06: the old preflight accepted "cpu 1" and the bench
+        timed the CPU until its timeout."""
+        from benchmarks._util import backend_preflight
+
+        events = []
+        r = backend_preflight(emit=events.append,
+                              _runner=lambda: (True, "cpu 1"))
+        assert r["ok"] is False and "cpu 1" in r["error"]
+        assert [e["event"] for e in events] == ["backend_preflight_failure"]
+        # the real in-process probe on this (CPU) backend is refused too
+        assert backend_preflight(emit=lambda e: None)["ok"] is False
+
+    def test_preflight_failed_backend_is_one_failed_attempt(self):
         from benchmarks._util import backend_preflight
 
         calls, events = [], []
 
         def probe():
             calls.append(1)
-            if len(calls) == 1:
-                return False, "transient init error"
-            return True, "tpu 8"
+            raise RuntimeError("backend failed to initialise")
 
-        r = backend_preflight(max_tries=2, backoff_s=0.0,
-                              emit=events.append, _runner=probe)
-        assert r == {"ok": True, "attempts": 2, "backend": "tpu 8"}
-        assert len(events) == 1
-        assert events[0]["event"] == "backend_preflight_failure"
-
-    def test_preflight_hard_failure_emits_evidence(self):
-        from benchmarks._util import backend_preflight
-
-        events = []
-        r = backend_preflight(max_tries=2, backoff_s=0.0,
-                              emit=events.append,
-                              _runner=lambda: (False, "backend down"))
-        assert r["ok"] is False and r["error"] == "backend down"
-        assert len(events) == 2  # every attempt left a JSON line
-
-    def test_preflight_survives_raising_probe(self):
-        from benchmarks._util import backend_preflight
-
-        def probe():
-            raise OSError("probe exploded")
-
-        r = backend_preflight(max_tries=1, backoff_s=0.0,
-                              emit=lambda e: None, _runner=probe)
-        assert r["ok"] is False and "probe exploded" in r["error"]
+        r = backend_preflight(emit=events.append, _runner=probe)
+        assert r["ok"] is False and "failed to initialise" in r["error"]
+        assert len(calls) == 1 and len(events) == 1  # no retry
 
     def test_run_with_retry(self):
         from benchmarks._util import run_with_retry
