@@ -29,8 +29,51 @@ from deepspeed_tpu.module_inject import policy_for
 from deepspeed_tpu.parallel.mesh import MeshTopology, set_default_topology
 from deepspeed_tpu.runtime.checkpoint_engine import MsgpackCheckpointEngine
 from deepspeed_tpu.runtime.zero.sharding import ZeroShardingRules
+from deepspeed_tpu.telemetry.scopes import (
+    SCOPE_SAMPLE,
+    DispatchedProgram,
+    scope_table,
+)
 from deepspeed_tpu.utils.compile_cache import ensure_compile_cache
 from deepspeed_tpu.utils.logging import log_dist
+
+
+# the names of the serving programs as a profiler trace has them (its
+# ``XLA Modules`` events) and as ``program_scopes()`` keys them
+PROGRAM_PREFILL = "jit_prefill"
+PROGRAM_PREFILL_MORE = "jit_prefill_more"
+PROGRAM_DECODE_K = "jit_decode_k"
+
+
+def kv_leaf_shapes(tree):
+    """The shapes a whole KV-cache leaf takes inside a program, from any
+    pytree of arrays or avals that holds caches (the leaves named
+    ``cached_key`` / ``cached_value`` / ``cached_*_scale`` of the model's
+    ``cache`` collection): the leaf as stored, and without its leading
+    layer axis where ``nn.scan`` stacked it (what one turn of the layer
+    loop sees)."""
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        name = str(getattr(path[-1], "key", ""))
+        if not name.startswith("cached_"):
+            continue
+        shape = tuple(leaf.shape)
+        shapes.add(shape)
+        if len(shape) > (3 if name.endswith("_scale") else 4):
+            shapes.add(shape[1:])
+    return shapes
+
+
+def programs_scope_table(programs):
+    """``scope_table`` of ``DispatchedProgram``s, with ``kv_cache_carry``
+    for the KV-cache leaves among their arguments and results."""
+    lowered = [(avals, low) for prog in programs
+               for avals, low in zip(prog.avals.values(), prog.lowered())]
+    carry = set()
+    for avals, low in lowered:
+        carry |= kv_leaf_shapes((avals, low.out_info))
+    return scope_table((low.compile().as_text() for _, low in lowered),
+                       carry)
 
 
 def _conform_host_quantized(host, shapes):
@@ -487,7 +530,9 @@ class InferenceEngine:
             def greedy(_):
                 return jnp.argmax(logits, axis=-1)
 
-            next_tok = jax.lax.cond(temperature > 0, sample, greedy, rng)
+            with jax.named_scope(SCOPE_SAMPLE):
+                next_tok = jax.lax.cond(
+                    temperature > 0, sample, greedy, rng)
             return next_tok.astype(jnp.int32), vars_out["cache"]
 
         def decode_k(params, token, cache, rng, temperature, k):
@@ -526,11 +571,36 @@ class InferenceEngine:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
                 vars_out["cache"]
 
-        self._prefill_fn = jax.jit(prefill)
-        self._prefill_more_fn = jax.jit(prefill_more, donate_argnums=(3,))
-        self._decode_k_fn = jax.jit(decode_k, static_argnums=(5,),
-                                    donate_argnums=(2,))
-        self._verify_greedy_fn = jax.jit(verify_greedy, donate_argnums=(2,))
+        # each remembers the avals of the first dispatch per prompt bucket
+        # (ids' shape) or scan length, for program_scopes()
+        self._prefill_fn = DispatchedProgram(
+            jax.jit(prefill), key=lambda a: a[1].shape)
+        self._prefill_more_fn = DispatchedProgram(
+            jax.jit(prefill_more, donate_argnums=(3,)),
+            key=lambda a: a[1].shape)
+        self._decode_k_fn = DispatchedProgram(
+            jax.jit(decode_k, static_argnums=(5,), donate_argnums=(2,)),
+            key=lambda a: (a[1].shape, a[5]))
+        self._verify_greedy_fn = DispatchedProgram(
+            jax.jit(verify_greedy, donate_argnums=(2,)),
+            key=lambda a: a[1].shape)
+
+    def step_programs(self):
+        """The serving programs built so far (``DispatchedProgram``s)."""
+        if self._prefill_fn is None:
+            return []
+        return [self._prefill_fn, self._prefill_more_fn, self._decode_k_fn,
+                self._verify_greedy_fn]
+
+    def program_scopes(self) -> Dict[str, Dict[str, Optional[str]]]:
+        """``{program_name: {hlo_instruction_name: op_name_path}}`` of the
+        prefill and decode programs this engine has dispatched, one entry
+        per program name over all the prompt buckets it ran
+        (telemetry/scopes.py). Operations that no named scope owns and
+        whose result is a whole KV-cache leaf are tagged
+        ``kv_cache_carry``. Re-lowers (a cache hit) and parses HLO text:
+        call it after the measured window, never inside it."""
+        return programs_scope_table(self.step_programs())
 
     def _chunked_prefill(self, input_ids, attention_mask):
         """Prefill ``input_ids`` exactly: one pass when that is exact,
@@ -642,11 +712,13 @@ class InferenceEngine:
         logits_last, cache = self._chunked_prefill(input_ids,
                                                    attention_mask)
         rng, sub = jax.random.split(rng)
-        if temperature > 0:
-            tok = jax.random.categorical(
-                sub, logits_last / temperature, axis=-1).astype(jnp.int32)
-        else:
-            tok = jnp.argmax(logits_last, axis=-1).astype(jnp.int32)
+        with jax.named_scope(SCOPE_SAMPLE):
+            if temperature > 0:
+                tok = jax.random.categorical(
+                    sub, logits_last / temperature,
+                    axis=-1).astype(jnp.int32)
+            else:
+                tok = jnp.argmax(logits_last, axis=-1).astype(jnp.int32)
         out = [tok[:, None]]
         temp = jnp.float32(temperature)
         # chunked scan decode, binary-decomposed: each dispatch runs the

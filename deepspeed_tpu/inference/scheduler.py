@@ -49,8 +49,30 @@ import numpy as np
 from deepspeed_tpu.inference.engine import (
     continuation_chunk_spans,
     prefill_chunk_spans,
+    programs_scope_table,
 )
 from deepspeed_tpu.parallel.mesh import set_default_topology
+from deepspeed_tpu.telemetry.scopes import SCOPE_SAMPLE, DispatchedProgram
+from deepspeed_tpu.telemetry.spans import (
+    SERVE_ADMIT,
+    SERVE_DECODE_READ,
+    SERVE_DECODE_STEP,
+    SERVE_EMIT,
+    SERVE_FIRST_TOKEN_READ,
+    SERVE_ITERATION,
+    SERVE_PREFILL,
+    SERVE_SPLICE,
+    SERVE_STATS,
+    span,
+)
+
+# the names of the scheduler's own programs as a profiler trace has them
+# (its ``XLA Modules`` events) and as ``program_scopes()`` keys them
+PROGRAM_SPLICE = "jit_splice"
+
+
+def _first_leaf_shape(tree):
+    return jax.tree.leaves(tree)[0].shape
 
 
 class AdmissionRejected(RuntimeError):
@@ -580,7 +602,9 @@ class ContinuousBatchingScheduler:
 
                 return jax.tree.map(one, full, sub)
 
-            self._splice_fn = jax.jit(splice, donate_argnums=(0,))
+            self._splice_fn = DispatchedProgram(
+                jax.jit(splice, donate_argnums=(0,)),
+                key=lambda a: _first_leaf_shape(a[1]))
         return self._splice_fn(cache, sub_cache, jnp.int32(lane))
 
     def _copy_tree(self, tree):
@@ -589,8 +613,12 @@ class ContinuousBatchingScheduler:
         the snapshot taken at a promotion boundary must be fresh buffers —
         extending a cached tree in place would invalidate the cache."""
         if self._copy_fn is None:
-            self._copy_fn = jax.jit(
-                lambda t: jax.tree.map(jnp.copy, t))
+
+            def copy_tree(t):
+                return jax.tree.map(jnp.copy, t)
+
+            self._copy_fn = DispatchedProgram(
+                jax.jit(copy_tree), key=lambda a: _first_leaf_shape(a[0]))
         return self._copy_fn(tree)
 
     def _rewind(self, snapshot, cache, delta):
@@ -652,8 +680,25 @@ class ContinuousBatchingScheduler:
 
                 return walk(c0, c1, True)
 
-            self._rewind_fn = jax.jit(rewind, donate_argnums=(1,))
+            self._rewind_fn = DispatchedProgram(
+                jax.jit(rewind, donate_argnums=(1,)),
+                key=lambda a: _first_leaf_shape(a[1]))
         return self._rewind_fn(snapshot, cache, delta)
+
+    def program_scopes(self) -> Dict[str, Dict[str, Optional[str]]]:
+        """``{program_name: {hlo_instruction_name: op_name_path}}`` of every
+        program this scheduler has dispatched: the engine's prefill and
+        decode programs (and the draft engine's), and its own splice, copy
+        and rewind programs (telemetry/scopes.py; see
+        ``InferenceEngine.program_scopes``). ``_empty_cache`` fills its
+        leaves eagerly and has no program of its own. After the window,
+        never inside it."""
+        programs = list(self.engine.step_programs())
+        if self.draft_engine is not None:
+            programs += self.draft_engine.step_programs()
+        programs += [p for p in (self._splice_fn, self._copy_fn,
+                                 self._rewind_fn) if p is not None]
+        return programs_scope_table(programs)
 
     def _draft_prefill(self, ids: np.ndarray, mask: np.ndarray,
                        req: Request):
@@ -675,12 +720,12 @@ class ContinuousBatchingScheduler:
                     jnp.asarray(rep_mask[:, s - Lp:e - Lp]), sub)
         return sub
 
-    def _admit_prefill(self, req: Request):
+    def _admit_prefill(self, req: Request, Lp: int):
         """Exact (chunked when needed) prefill of one prompt on a
-        ``[1, Lp]`` batch; returns (first sampled token, sub cache,
-        draft sub cache — None without speculative decoding)."""
+        ``[1, Lp]`` batch, dispatched and not waited for; returns (first
+        sampled token: a device array, or the hand-off's host int; sub
+        cache; draft sub cache — None without speculative decoding)."""
         eng = self.engine
-        Lp = self._bucketed_len(len(req.prompt))
         ids = np.zeros((1, Lp), np.int32)
         mask = np.zeros((1, Lp), bool)
         ids[0, Lp - len(req.prompt):] = req.prompt
@@ -695,7 +740,7 @@ class ContinuousBatchingScheduler:
             # before splicing — the producer may fan the same entry out
             # to several decode lanes, and _splice donates.
             first_tok, sub_cache = req.kv_handoff
-            return int(first_tok), self._copy_tree(sub_cache), draft_sub
+            return first_tok, self._copy_tree(sub_cache), draft_sub
         if self.prefix_cache is not None:
             logits_last, sub_cache = self._prefix_prefill(
                 ids, mask, req.request_id)
@@ -716,12 +761,21 @@ class ContinuousBatchingScheduler:
                     eng._params, jnp.asarray(rep_ids[:, s - Lp:e - Lp]),
                     jnp.asarray(rep_mask[:, s - Lp:e - Lp]), sub_cache)
         eng._rng, sub = jax.random.split(eng._rng)
-        if self.temperature > 0:
-            tok = jax.random.categorical(
-                sub, logits_last / self.temperature, axis=-1)
-        else:
-            tok = jnp.argmax(logits_last, axis=-1)
-        return int(np.asarray(tok)[0]), sub_cache, draft_sub
+        with jax.named_scope(SCOPE_SAMPLE):
+            if self.temperature > 0:
+                tok = jax.random.categorical(
+                    sub, logits_last / self.temperature, axis=-1)
+            else:
+                tok = jnp.argmax(logits_last, axis=-1)
+        return tok, sub_cache, draft_sub
+
+    def _prefill_passes(self, Lp: int, req: Request) -> int:
+        """Prefill programs the cold path dispatches for this bucket (a
+        prefix-cache hit dispatches fewer; a hand-off none)."""
+        if req.kv_handoff is not None:
+            return 0
+        spans = prefill_chunk_spans(self._mcfg, Lp)
+        return 1 if spans is None else len(spans)
 
     def _prefix_prefill(self, ids: np.ndarray, mask: np.ndarray,
                         request_id):
@@ -868,10 +922,13 @@ class ContinuousBatchingScheduler:
             out["kv_cache"] = self.kv_cache_stats()
         return out
 
-    def _publish_stats(self, stats: "ServingStats", lanes) -> None:
+    def _publish_stats(self, stats: "ServingStats", lanes,
+                       static: Dict[str, Any]) -> None:
         """One ``serve.stats`` snapshot per scheduler iteration — queue
         depth, lane occupancy, shed counters, prefix hit-rate and fleet
-        health, so dashboards see front-door pressure without polling."""
+        health, so dashboards see front-door pressure without polling.
+        ``static`` holds the keys that cannot change during a ``run()``
+        (the KV geometry), computed once by it."""
         from deepspeed_tpu.telemetry.bus import KIND_SERVE_STATS, publish
 
         self._lanes_active = sum(1 for l in lanes if l is not None)
@@ -889,11 +946,7 @@ class ContinuousBatchingScheduler:
         if self.health_provider is not None and \
                 hasattr(self.health_provider, "states"):
             payload["health"] = dict(self.health_provider.states())
-        if self._empty_cache_shapes is not None:
-            kv = self.kv_cache_stats()
-            payload["kv_resident_bytes"] = kv["resident_bytes"]
-            payload["kv_unquantized_bytes"] = kv["unquantized_bytes"]
-        publish(KIND_SERVE_STATS, **payload)
+        publish(KIND_SERVE_STATS, **payload, **static)
 
     # ------------------------------------------------------------------
     def run(self, poll_fn: Optional[Callable[[], None]] = None
@@ -925,6 +978,11 @@ class ContinuousBatchingScheduler:
             draft_cache = self._empty_cache(de)
             de._rng, draft_rng = jax.random.split(de._rng)
         t_run0 = time.monotonic()
+        # the KV geometry cannot change during a run: read it once for
+        # every serve.stats event (kv_cache_stats also asks for the HBM size)
+        kv = self.kv_cache_stats()
+        stats_static = {"kv_resident_bytes": kv["resident_bytes"],
+                        "kv_unquantized_bytes": kv["unquantized_bytes"]}
 
         from deepspeed_tpu.telemetry.bus import (
             KIND_SERVE_ADMIT,
@@ -942,29 +1000,35 @@ class ContinuousBatchingScheduler:
                     lane=lane_no, tokens=lane.emitted,
                     queue_depth=len(self._pending))
 
-        def emit(lane_no: int, lane: _Lane, token: int) -> bool:
-            """Record one token; returns True when the sequence is done."""
-            now = time.monotonic()
-            lane.comp.tokens.append(token)
-            lane.emitted += 1
-            if lane.comp.t_first_token == 0.0:
-                lane.comp.t_first_token = now
-                # replays do not republish serve.first_token: the client
-                # saw its first token on the replica that died, and a
-                # replay-time sample would bias the admission p95 window
-                if lane.req.replay_tokens is None:
-                    publish(KIND_SERVE_FIRST_TOKEN,
-                            request_id=lane.req.request_id, lane=lane_no,
-                            ttft_s=now - lane.comp.t_submit)
-            done = (lane.emitted >= lane.req.max_new_tokens
-                    or (lane.req.eos_token_id is not None
-                        and token == lane.req.eos_token_id))
-            if self.journal is not None:
-                self.journal.record_token(
-                    lane.req.request_id, token, done=done)
-            if lane.req.stream_callback is not None:
-                lane.req.stream_callback(lane.req.request_id, token, done)
-            return done
+        def emit(lane_no: int, lane: _Lane, token: int) -> None:
+            """Record one token and hand it to the journal and the stream
+            callback; a sequence that is done frees its lane."""
+            with span(SERVE_EMIT, request_id=lane.req.request_id):
+                now = time.monotonic()
+                lane.comp.tokens.append(token)
+                lane.emitted += 1
+                if lane.comp.t_first_token == 0.0:
+                    lane.comp.t_first_token = now
+                    # replays do not republish serve.first_token: the
+                    # client saw its first token on the replica that died,
+                    # and a replay-time sample would bias the admission
+                    # p95 window
+                    if lane.req.replay_tokens is None:
+                        publish(KIND_SERVE_FIRST_TOKEN,
+                                request_id=lane.req.request_id,
+                                lane=lane_no,
+                                ttft_s=now - lane.comp.t_submit)
+                done = (lane.emitted >= lane.req.max_new_tokens
+                        or (lane.req.eos_token_id is not None
+                            and token == lane.req.eos_token_id))
+                if self.journal is not None:
+                    self.journal.record_token(
+                        lane.req.request_id, token, done=done)
+                if lane.req.stream_callback is not None:
+                    lane.req.stream_callback(
+                        lane.req.request_id, token, done)
+                if done:
+                    finish(lane_no, lane)
 
         while True:
             if poll_fn is not None:
@@ -975,108 +1039,138 @@ class ContinuousBatchingScheduler:
                     break  # queue left intact for journal hand-off
             elif not (self._pending or active):
                 break
-            # admissions: fill every free lane from the queue. A request
-            # that completes AT admission (max_new 1, or first token is
-            # EOS) frees its lane for the next pending request immediately.
-            # An expired deadline sheds here — before the prefill, so a
-            # doomed request never occupies a lane. Draining admits none.
-            for lane_no in range(self.slots if not self._draining else 0):
-                while lanes[lane_no] is None and self._pending:
-                    req, t_submit = self._pending.popleft()
-                    if req.t_deadline is not None and \
-                            time.monotonic() > req.t_deadline:
-                        self._shed_expired(req, t_submit)
-                        continue
-                    replayed = len(req.replay_tokens or ())
-                    comp = Completion(request_id=req.request_id,
-                                      tokens=list(req.replay_tokens or ()),
-                                      prompt_len=len(req.prompt),
-                                      t_submit=t_submit)
-                    comp.t_admit = time.monotonic()
-                    publish(KIND_SERVE_ADMIT, request_id=req.request_id,
-                            lane=lane_no, prompt_len=len(req.prompt),
-                            replayed=replayed,
-                            queue_wait_s=comp.t_admit - t_submit,
-                            queue_depth=len(self._pending))
-                    first_tok, sub_cache, draft_sub = \
-                        self._admit_prefill(req)
-                    cache = self._splice(cache, sub_cache, lane_no)
-                    if draft_sub is not None:
-                        draft_cache = self._splice(
-                            draft_cache, draft_sub, lane_no)
-                    tok[lane_no] = first_tok
-                    lane = _Lane(req=req, comp=comp, emitted=replayed)
-                    lanes[lane_no] = lane
-                    if emit(lane_no, lane, first_tok):
-                        finish(lane_no, lane)
+            with span(SERVE_ITERATION, decode_steps=stats.decode_steps):
+                # admissions: fill every free lane from the queue. A
+                # request that completes AT admission (max_new 1, or first
+                # token is EOS) frees its lane for the next pending request
+                # immediately. An expired deadline sheds here — before the
+                # prefill, so a doomed request never occupies a lane.
+                # Draining admits none.
+                for lane_no in range(
+                        self.slots if not self._draining else 0):
+                    while lanes[lane_no] is None and self._pending:
+                        req, t_submit = self._pending.popleft()
+                        if req.t_deadline is not None and \
+                                time.monotonic() > req.t_deadline:
+                            self._shed_expired(req, t_submit)
+                            continue
+                        replayed = len(req.replay_tokens or ())
+                        comp = Completion(request_id=req.request_id,
+                                          tokens=list(req.replay_tokens or ()),
+                                          prompt_len=len(req.prompt),
+                                          t_submit=t_submit)
+                        comp.t_admit = time.monotonic()
+                        bucket = self._bucketed_len(len(req.prompt))
+                        queue_wait_s = comp.t_admit - t_submit
+                        # inline, not a helper closure: the same admission
+                        # through a nested function cost the ramp 1.2 s of
+                        # 12 on the chip (PERF.md, PR 24)
+                        with span(SERVE_ADMIT, request_id=req.request_id,
+                                  lane=lane_no, prompt_len=len(req.prompt),
+                                  bucket=bucket,
+                                  queue_wait_us=int(queue_wait_s * 1e6),
+                                  queue_depth=len(self._pending)):
+                            publish(KIND_SERVE_ADMIT,
+                                    request_id=req.request_id, lane=lane_no,
+                                    prompt_len=len(req.prompt),
+                                    bucket=bucket, replayed=replayed,
+                                    queue_wait_s=queue_wait_s,
+                                    queue_depth=len(self._pending))
+                            with span(SERVE_PREFILL,
+                                      chunks=self._prefill_passes(
+                                          bucket, req)):
+                                first_tok, sub_cache, draft_sub = \
+                                    self._admit_prefill(req, bucket)
+                            with span(SERVE_FIRST_TOKEN_READ):
+                                first_tok = int(
+                                    np.asarray(first_tok).reshape(-1)[0])
+                            with span(SERVE_SPLICE):
+                                cache = self._splice(
+                                    cache, sub_cache, lane_no)
+                                if draft_sub is not None:
+                                    draft_cache = self._splice(
+                                        draft_cache, draft_sub, lane_no)
+                            tok[lane_no] = first_tok
+                            lane = _Lane(req=req, comp=comp, emitted=replayed)
+                            lanes[lane_no] = lane
+                            emit(lane_no, lane, first_tok)
 
-            self._publish_stats(stats, lanes)
-            if not any(l is not None for l in lanes):
-                continue  # everything admitted finished at token 1
+                with span(SERVE_STATS):
+                    self._publish_stats(stats, lanes, stats_static)
+                if not any(l is not None for l in lanes):
+                    continue  # everything admitted finished at token 1
 
-            if use_spec:
-                # speculative step: the draft proposes k greedy tokens
-                # per lane (k sequential cheap steps), the target
-                # verifies them in ONE [slots, k+1] forward, and both
-                # caches rewind past each lane's first mismatch.
-                # m_eff = min(m, k-1): no bonus token — accepting all k
-                # would need the draft's k-th proposal in ITS cache,
-                # which the proposal loop never wrote. Every emitted
-                # token is a target argmax given the emitted prefix, so
-                # the stream is exactly sequential greedy.
-                k = self.spec_k
-                de = self.draft_engine
-                snap = self._copy_tree(cache)
-                draft_snap = self._copy_tree(draft_cache)
-                props, _, draft_cache, draft_rng = de._decode_k_fn(
-                    de._params, jnp.asarray(tok), draft_cache, draft_rng,
-                    jnp.float32(0.0), k)
-                cols = jnp.concatenate(
-                    [jnp.asarray(tok)[:, None], props], axis=1)
-                g, cache = eng._verify_greedy_fn(eng._params, cols, cache)
-                stats.decode_steps += 1
-                g_np = np.asarray(g)
-                props_np = np.asarray(props)
-                matches = props_np == g_np[:, :k]
-                m = np.where(matches.all(axis=1), k,
-                             matches.argmin(axis=1))
-                m_eff = np.minimum(m, k - 1).astype(np.int64)
-                cache = self._rewind(
-                    snap, cache, jnp.asarray((k - m_eff).astype(np.int32)))
-                draft_cache = self._rewind(
-                    draft_snap, draft_cache,
-                    jnp.asarray((k - 1 - m_eff).astype(np.int32)))
-                live = [ln for ln in range(self.slots)
-                        if lanes[ln] is not None]
-                self.spec_proposed += k * len(live)
-                accepted_now = int(sum(int(m_eff[ln]) for ln in live))
-                self.spec_accepted += accepted_now
-                publish(KIND_SERVE_SPEC_ACCEPT, k=k, lanes=len(live),
-                        proposed=k * len(live), accepted=accepted_now,
-                        proposed_total=self.spec_proposed,
-                        accepted_total=self.spec_accepted)
-                for lane_no in live:
-                    lane = lanes[lane_no]
-                    for j in range(int(m_eff[lane_no]) + 1):
-                        if emit(lane_no, lane, int(g_np[lane_no, j])):
-                            finish(lane_no, lane)
-                            break
-                tok = g_np[np.arange(self.slots), m_eff] \
-                    .astype(np.int32).copy()
-            else:
-                # ONE fixed-shape decode step for all lanes (garbage
-                # lanes included — row-independent attention keeps them
-                # harmless)
-                toks, _, cache, rng = eng._decode_k_fn(
-                    eng._params, jnp.asarray(tok), cache, rng, temp, 1)
-                stats.decode_steps += 1
-                tok = np.asarray(toks[:, 0]).astype(np.int32).copy()
-                for lane_no in range(self.slots):
-                    lane = lanes[lane_no]
-                    if lane is None:
-                        continue
-                    if emit(lane_no, lane, int(tok[lane_no])):
-                        finish(lane_no, lane)
+                if use_spec:
+                    # speculative step: the draft proposes k greedy tokens
+                    # per lane (k sequential cheap steps), the target
+                    # verifies them in ONE [slots, k+1] forward, and both
+                    # caches rewind past each lane's first mismatch.
+                    # m_eff = min(m, k-1): no bonus token — accepting all
+                    # k would need the draft's k-th proposal in ITS cache,
+                    # which the proposal loop never wrote. Every emitted
+                    # token is a target argmax given the emitted prefix,
+                    # so the stream is exactly sequential greedy.
+                    k = self.spec_k
+                    de = self.draft_engine
+                    with span(SERVE_DECODE_STEP,
+                              lanes_active=self._lanes_active):
+                        snap = self._copy_tree(cache)
+                        draft_snap = self._copy_tree(draft_cache)
+                        props, _, draft_cache, draft_rng = de._decode_k_fn(
+                            de._params, jnp.asarray(tok), draft_cache,
+                            draft_rng, jnp.float32(0.0), k)
+                        cols = jnp.concatenate(
+                            [jnp.asarray(tok)[:, None], props], axis=1)
+                        g, cache = eng._verify_greedy_fn(
+                            eng._params, cols, cache)
+                        stats.decode_steps += 1
+                        with span(SERVE_DECODE_READ):
+                            g_np = np.asarray(g)
+                            props_np = np.asarray(props)
+                        matches = props_np == g_np[:, :k]
+                        m = np.where(matches.all(axis=1), k,
+                                     matches.argmin(axis=1))
+                        m_eff = np.minimum(m, k - 1).astype(np.int64)
+                        cache = self._rewind(
+                            snap, cache,
+                            jnp.asarray((k - m_eff).astype(np.int32)))
+                        draft_cache = self._rewind(
+                            draft_snap, draft_cache,
+                            jnp.asarray((k - 1 - m_eff).astype(np.int32)))
+                    live = [ln for ln in range(self.slots)
+                            if lanes[ln] is not None]
+                    self.spec_proposed += k * len(live)
+                    accepted_now = int(sum(int(m_eff[ln]) for ln in live))
+                    self.spec_accepted += accepted_now
+                    publish(KIND_SERVE_SPEC_ACCEPT, k=k, lanes=len(live),
+                            proposed=k * len(live), accepted=accepted_now,
+                            proposed_total=self.spec_proposed,
+                            accepted_total=self.spec_accepted)
+                    for lane_no in live:
+                        lane = lanes[lane_no]
+                        for j in range(int(m_eff[lane_no]) + 1):
+                            emit(lane_no, lane, int(g_np[lane_no, j]))
+                            if lanes[lane_no] is None:
+                                break
+                    tok = g_np[np.arange(self.slots), m_eff] \
+                        .astype(np.int32).copy()
+                else:
+                    # ONE fixed-shape decode step for all lanes (garbage
+                    # lanes included — row-independent attention keeps
+                    # them harmless)
+                    with span(SERVE_DECODE_STEP,
+                              lanes_active=self._lanes_active):
+                        toks, _, cache, rng = eng._decode_k_fn(
+                            eng._params, jnp.asarray(tok), cache, rng,
+                            temp, 1)
+                        stats.decode_steps += 1
+                        with span(SERVE_DECODE_READ):
+                            tok = np.asarray(toks[:, 0]).astype(
+                                np.int32).copy()
+                    for lane_no in range(self.slots):
+                        lane = lanes[lane_no]
+                        if lane is not None:
+                            emit(lane_no, lane, int(tok[lane_no]))
 
         stats.wall_s = time.monotonic() - t_run0
         return stats

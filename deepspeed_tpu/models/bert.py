@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.models.transformer_lm import VocabEmbed
+from deepspeed_tpu.telemetry.scopes import SCOPE_ATTN_CORE, SCOPE_MLM_HEAD
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,15 +98,19 @@ class BertSelfAttention(nn.Module):
             kpm = None
             if mask is not None:
                 kpm = jnp.where(mask, 0.0, jnp.finfo(jnp.float32).min)
-            y = sa(q, k, v, key_padding_mask=kpm).reshape(B, T, C)
+            with jax.named_scope(SCOPE_ATTN_CORE):
+                y = sa(q, k, v, key_padding_mask=kpm).reshape(B, T, C)
         else:
-            att = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
-            if mask is not None:
-                att = jnp.where(mask[:, None, None, :], att,
-                                jnp.finfo(att.dtype).min)
-            att = jax.nn.softmax(att.astype(jnp.float32), axis=-1).astype(cfg.dtype)
-            att = nn.Dropout(cfg.dropout)(att, deterministic=deterministic)
-            y = jnp.einsum("bhqk,bkhd->bqhd", att, v).reshape(B, T, C)
+            with jax.named_scope(SCOPE_ATTN_CORE):
+                att = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
+                if mask is not None:
+                    att = jnp.where(mask[:, None, None, :], att,
+                                    jnp.finfo(att.dtype).min)
+                att = jax.nn.softmax(
+                    att.astype(jnp.float32), axis=-1).astype(cfg.dtype)
+                att = nn.Dropout(cfg.dropout)(
+                    att, deterministic=deterministic)
+                y = jnp.einsum("bhqk,bkhd->bqhd", att, v).reshape(B, T, C)
         y = nn.Dense(C, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                      name="output")(y)
         y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
@@ -241,25 +246,26 @@ class BertForPreTraining(nn.Module):
         x = BertEncoder(cfg, name="encoder")(x, attention_mask, deterministic,
                                              pld_theta=pld_theta)
 
-        # MLM transform + tied decoder
-        h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
-                     param_dtype=cfg.param_dtype, name="mlm_dense")(x)
-        h = nn.gelu(h, approximate=cfg.approximate_gelu)
-        h = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, name="mlm_ln")(h)
-        # bf16 operands + fp32 accumulation: full MXU rate on the vocab
-        # projection (fp32 matmul would run ~8x slower)
-        logits = jax.lax.dot_general(
-            h.astype(cfg.dtype), tok.embedding.astype(cfg.dtype),
-            (((h.ndim - 1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if cfg.use_mlm_bias:
-            logits = logits + self.param(
-                "mlm_bias", nn.initializers.zeros, (cfg.vocab_size,),
-                cfg.param_dtype).astype(logits.dtype)
-
-        if labels is None:
-            return logits
-        return masked_lm_loss(logits, labels)
+        # MLM transform + tied decoder (and the loss) under one scope
+        with jax.named_scope(SCOPE_MLM_HEAD):
+            h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
+                         param_dtype=cfg.param_dtype, name="mlm_dense")(x)
+            h = nn.gelu(h, approximate=cfg.approximate_gelu)
+            h = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                             name="mlm_ln")(h)
+            # bf16 operands + fp32 accumulation: full MXU rate on the vocab
+            # projection (fp32 matmul would run ~8x slower)
+            logits = jax.lax.dot_general(
+                h.astype(cfg.dtype), tok.embedding.astype(cfg.dtype),
+                (((h.ndim - 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if cfg.use_mlm_bias:
+                logits = logits + self.param(
+                    "mlm_bias", nn.initializers.zeros, (cfg.vocab_size,),
+                    cfg.param_dtype).astype(logits.dtype)
+            if labels is None:
+                return logits
+            return masked_lm_loss(logits, labels)
 
 
 def masked_lm_loss(logits, labels):

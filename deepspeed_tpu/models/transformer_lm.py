@@ -22,6 +22,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.telemetry.scopes import (
+    SCOPE_ATTN_CORE,
+    SCOPE_KV_CACHE_READ,
+    SCOPE_KV_CACHE_WRITE,
+    SCOPE_LM_HEAD,
+    SCOPE_LM_HEAD_CE,
+)
+
 
 @dataclasses.dataclass(frozen=True)
 class GPTConfig:
@@ -551,44 +559,48 @@ class CausalSelfAttention(nn.Module):
                 else:
                     kc, vc = k.astype(cfg.dtype), v.astype(cfg.dtype)
                 rows = jnp.arange(B)[:, None]
-                for slots in (ring_slot, glob_slot):
-                    cached_k.value = cached_k.value.at[rows, slots].set(
-                        kc, mode="drop")
-                    cached_v.value = cached_v.value.at[rows, slots].set(
-                        vc, mode="drop")
-                    if kv_int8:
-                        k_scale.value = k_scale.value.at[rows, slots].set(
-                            ksc, mode="drop")
-                        v_scale.value = v_scale.value.at[rows, slots].set(
-                            vsc, mode="drop")
-                    cache_valid.value = cache_valid.value.at[
-                        rows, slots].set(write_valid, mode="drop")
-                    slot_pos.value = slot_pos.value.at[rows, slots].set(
-                        pos, mode="drop")
-                cache_index.value = idx + T
-                k_all, v_all = read_kv(cached_k, cached_v, k_scale, v_scale)
+                with jax.named_scope(SCOPE_KV_CACHE_WRITE):
+                    for slots in (ring_slot, glob_slot):
+                        cached_k.value = cached_k.value.at[rows, slots].set(
+                            kc, mode="drop")
+                        cached_v.value = cached_v.value.at[rows, slots].set(
+                            vc, mode="drop")
+                        if kv_int8:
+                            k_scale.value = k_scale.value.at[
+                                rows, slots].set(ksc, mode="drop")
+                            v_scale.value = v_scale.value.at[
+                                rows, slots].set(vsc, mode="drop")
+                        cache_valid.value = cache_valid.value.at[
+                            rows, slots].set(write_valid, mode="drop")
+                        slot_pos.value = slot_pos.value.at[rows, slots].set(
+                            pos, mode="drop")
+                    cache_index.value = idx + T
+                with jax.named_scope(SCOPE_KV_CACHE_READ):
+                    k_all, v_all = read_kv(cached_k, cached_v, k_scale,
+                                           v_scale)
+                    q_pos = pos[:, :, None]                   # [B, T, 1]
+                    ps = slot_pos.value[:, None, :]           # [B, 1, S]
+                    s_idx = jnp.arange(S)[None, None, :]
+                    is_glob = s_idx < g_tok
+                    in_window = (ps // blk) >= (q_pos // blk) - w_blk
+                    visible = ((ps >= 0) & (ps <= q_pos)
+                               & (is_glob | (in_window & (ps >= g_tok))))
+                    visible = (visible[:, None, None]         # [B,1,1,T,S]
+                               & cache_valid.value[:, None, None, None, :])
 
                 G = H // Hkv
                 qg = q.reshape(B, T, Hkv, G, D)
                 scale = 1.0 / np.sqrt(D)
-                att = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_all) * scale
-                q_pos = pos[:, :, None]                       # [B, T, 1]
-                ps = slot_pos.value[:, None, :]               # [B, 1, S]
-                s_idx = jnp.arange(S)[None, None, :]
-                is_glob = s_idx < g_tok
-                in_window = (ps // blk) >= (q_pos // blk) - w_blk
-                visible = ((ps >= 0) & (ps <= q_pos)
-                           & (is_glob | (in_window & (ps >= g_tok))))
-                visible = (visible[:, None, None]             # [B,1,1,T,S]
-                           & cache_valid.value[:, None, None, None, :])
-                att = jnp.where(visible, att, jnp.finfo(att.dtype).min)
-                # NaN-safe: an all-pad chunk row (ragged left-padded batch)
-                # has an empty visible set; its output is masked out later
-                # but must not produce NaN
-                att = jax.nn.softmax(
-                    att.astype(jnp.float32), axis=-1,
-                    where=visible).astype(cfg.dtype)
-                y = jnp.einsum("bhgqk,bkhd->bqhgd", att, v_all)
+                with jax.named_scope(SCOPE_ATTN_CORE):
+                    att = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_all) * scale
+                    att = jnp.where(visible, att, jnp.finfo(att.dtype).min)
+                    # NaN-safe: an all-pad chunk row (ragged left-padded
+                    # batch) has an empty visible set; its output is masked
+                    # out later but must not produce NaN
+                    att = jax.nn.softmax(
+                        att.astype(jnp.float32), axis=-1,
+                        where=visible).astype(cfg.dtype)
+                    y = jnp.einsum("bhgqk,bkhd->bqhgd", att, v_all)
                 y = y.reshape(B, T, C)
                 return nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
                                 param_dtype=cfg.param_dtype,
@@ -634,24 +646,31 @@ class CausalSelfAttention(nn.Module):
                 # after its apply_rotary_pos_emb kernel
                 q, k = rope(q, pos), rope(k, pos)
             rows = jnp.arange(B)[:, None]
-            if kv_int8:
-                (kc, ksc), (vc, vsc) = quantize_kv(k), quantize_kv(v)
-                k_scale.value = k_scale.value.at[rows, pos].set(
-                    ksc, mode="drop")
-                v_scale.value = v_scale.value.at[rows, pos].set(
-                    vsc, mode="drop")
-            else:
-                kc, vc = k.astype(cfg.dtype), v.astype(cfg.dtype)
-            cached_k.value = cached_k.value.at[rows, pos].set(
-                kc, mode="drop")
-            cached_v.value = cached_v.value.at[rows, pos].set(
-                vc, mode="drop")
             write_valid = (mask.astype(jnp.bool_) if mask is not None
                            else jnp.ones((B, T), jnp.bool_))
-            cache_valid.value = cache_valid.value.at[rows, pos].set(
-                write_valid, mode="drop")
-            cache_index.value = idx + T
-            k_all, v_all = read_kv(cached_k, cached_v, k_scale, v_scale)
+            with jax.named_scope(SCOPE_KV_CACHE_WRITE):
+                if kv_int8:
+                    (kc, ksc), (vc, vsc) = quantize_kv(k), quantize_kv(v)
+                    k_scale.value = k_scale.value.at[rows, pos].set(
+                        ksc, mode="drop")
+                    v_scale.value = v_scale.value.at[rows, pos].set(
+                        vsc, mode="drop")
+                else:
+                    kc, vc = k.astype(cfg.dtype), v.astype(cfg.dtype)
+                cached_k.value = cached_k.value.at[rows, pos].set(
+                    kc, mode="drop")
+                cached_v.value = cached_v.value.at[rows, pos].set(
+                    vc, mode="drop")
+                cache_valid.value = cache_valid.value.at[rows, pos].set(
+                    write_valid, mode="drop")
+                cache_index.value = idx + T
+            q_pos = pos[:, :, None]                         # [B, T, 1]
+            k_pos = jnp.arange(cfg.n_positions)[None, :]    # [1, max]
+            with jax.named_scope(SCOPE_KV_CACHE_READ):
+                k_all, v_all = read_kv(cached_k, cached_v, k_scale, v_scale)
+                visible = (k_pos[None] <= q_pos)            # [B, T, max]
+                visible = (visible[:, None, None]           # [B,1,1,T,max]
+                           & cache_valid.value[:, None, None, None, :])
 
             # grouped attention: query heads contract directly against the
             # un-repeated KV cache ([B, max, Hkv, D] stays in place — no
@@ -659,20 +678,16 @@ class CausalSelfAttention(nn.Module):
             G = H // Hkv
             qg = q.reshape(B, T, Hkv, G, D)
             scale = 1.0 / np.sqrt(D)
-            att = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_all) * scale
-            q_pos = pos[:, :, None]                         # [B, T, 1]
-            k_pos = jnp.arange(cfg.n_positions)[None, :]    # [1, max]
-            if cfg.alibi:
-                slopes = jnp.asarray(alibi_slopes(H)).reshape(Hkv, G)
-                att = att + (slopes[:, :, None, None]
-                             * k_pos[None].astype(att.dtype))
-            visible = (k_pos[None] <= q_pos)                # [B, T, max]
-            visible = (visible[:, None, None]               # [B,1,1,T,max]
-                       & cache_valid.value[:, None, None, None, :])
-            att = jnp.where(visible, att, jnp.finfo(att.dtype).min)
-            att = jax.nn.softmax(att.astype(jnp.float32), axis=-1).astype(
-                cfg.dtype)
-            y = jnp.einsum("bhgqk,bkhd->bqhgd", att, v_all)
+            with jax.named_scope(SCOPE_ATTN_CORE):
+                att = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_all) * scale
+                if cfg.alibi:
+                    slopes = jnp.asarray(alibi_slopes(H)).reshape(Hkv, G)
+                    att = att + (slopes[:, :, None, None]
+                                 * k_pos[None].astype(att.dtype))
+                att = jnp.where(visible, att, jnp.finfo(att.dtype).min)
+                att = jax.nn.softmax(
+                    att.astype(jnp.float32), axis=-1).astype(cfg.dtype)
+                y = jnp.einsum("bhgqk,bkhd->bqhgd", att, v_all)
             y = y.reshape(B, T, C)
             return nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
                             param_dtype=cfg.param_dtype, name="c_proj")(y)
@@ -701,7 +716,8 @@ class CausalSelfAttention(nn.Module):
             kpm = None
             if mask is not None:
                 kpm = jnp.where(mask, 0.0, jnp.finfo(jnp.float32).min)
-            y = sa(q, k, v, key_padding_mask=kpm, causal=cfg.causal)
+            with jax.named_scope(SCOPE_ATTN_CORE):
+                y = sa(q, k, v, key_padding_mask=kpm, causal=cfg.causal)
             y = y.reshape(B, T, C)
             y = nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype, name="c_proj")(y)
@@ -721,7 +737,8 @@ class CausalSelfAttention(nn.Module):
             if get_default_topology().size("sp") > 1:
                 attn_fn = {"ring": ring_attention,
                            "ulysses": ulysses_attention}[cfg.sequence_parallel]
-                y = attn_fn(q, k, v, causal=cfg.causal)
+                with jax.named_scope(SCOPE_ATTN_CORE):
+                    y = attn_fn(q, k, v, causal=cfg.causal)
                 y = y.reshape(B, T, C)
                 y = nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
                              param_dtype=cfg.param_dtype, name="c_proj")(y)
@@ -745,8 +762,9 @@ class CausalSelfAttention(nn.Module):
                 and T % eff_chunk == 0 and T > eff_chunk):
             from deepspeed_tpu.ops.chunked_attention import chunked_attention
 
-            y = chunked_attention(q, k, v, causal=cfg.causal,
-                                  chunk=eff_chunk)
+            with jax.named_scope(SCOPE_ATTN_CORE):
+                y = chunked_attention(q, k, v, causal=cfg.causal,
+                                      chunk=eff_chunk)
             y = y.reshape(B, T, C)
             y = nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype, name="c_proj")(y)
@@ -764,35 +782,39 @@ class CausalSelfAttention(nn.Module):
         use_flash = (want_flash and mask is None
                      and T % 128 == 0 and not cfg.alibi
                      and (cfg.dropout == 0.0 or deterministic))
-        if use_flash:
-            y = _mesh_flash_attention(
-                q, k, v, segment_ids, causal=cfg.causal,
-                autotune=True if cfg.flash_autotune else None)
-        else:
-            scale = 1.0 / np.sqrt(D)
-            att = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-            if cfg.alibi:
-                # bias slopes_h * k_pos (HF BLOOM formula; equivalent to
-                # slopes * (k - q) under softmax's row-shift invariance)
-                slopes = jnp.asarray(alibi_slopes(H))
-                att = att + (slopes[None, :, None, None]
-                             * jnp.arange(T, dtype=att.dtype)[None, None,
-                                                              None, :])
-            if cfg.causal:
-                tri = jnp.tril(jnp.ones((T, T), dtype=bool))
-                att = jnp.where(tri[None, None, :, :], att,
-                                jnp.finfo(att.dtype).min)
-            if mask is not None:
-                att = jnp.where(mask[:, None, None, :], att, jnp.finfo(att.dtype).min)
-            if segment_ids is not None:
-                # NaN-safe: the causal diagonal is always same-segment, so
-                # no row's visible set is ever empty
-                same = (segment_ids[:, None, :, None]
-                        == segment_ids[:, None, None, :])
-                att = jnp.where(same, att, jnp.finfo(att.dtype).min)
-            att = jax.nn.softmax(att.astype(jnp.float32), axis=-1).astype(cfg.dtype)
-            att = nn.Dropout(cfg.dropout)(att, deterministic=deterministic)
-            y = jnp.einsum("bhqk,bkhd->bqhd", att, v)
+        with jax.named_scope(SCOPE_ATTN_CORE):
+            if use_flash:
+                y = _mesh_flash_attention(
+                    q, k, v, segment_ids, causal=cfg.causal,
+                    autotune=True if cfg.flash_autotune else None)
+            else:
+                scale = 1.0 / np.sqrt(D)
+                att = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+                if cfg.alibi:
+                    # bias slopes_h * k_pos (HF BLOOM formula; equivalent to
+                    # slopes * (k - q) under softmax's row-shift invariance)
+                    slopes = jnp.asarray(alibi_slopes(H))
+                    att = att + (slopes[None, :, None, None]
+                                 * jnp.arange(T, dtype=att.dtype)[None, None,
+                                                                  None, :])
+                if cfg.causal:
+                    tri = jnp.tril(jnp.ones((T, T), dtype=bool))
+                    att = jnp.where(tri[None, None, :, :], att,
+                                    jnp.finfo(att.dtype).min)
+                if mask is not None:
+                    att = jnp.where(mask[:, None, None, :], att,
+                                    jnp.finfo(att.dtype).min)
+                if segment_ids is not None:
+                    # NaN-safe: the causal diagonal is always same-segment, so
+                    # no row's visible set is ever empty
+                    same = (segment_ids[:, None, :, None]
+                            == segment_ids[:, None, None, :])
+                    att = jnp.where(same, att, jnp.finfo(att.dtype).min)
+                att = jax.nn.softmax(
+                    att.astype(jnp.float32), axis=-1).astype(cfg.dtype)
+                att = nn.Dropout(cfg.dropout)(
+                    att, deterministic=deterministic)
+                y = jnp.einsum("bhqk,bkhd->bqhd", att, v)
         y = y.reshape(B, T, C)
         y = nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
                      param_dtype=cfg.param_dtype, name="c_proj")(y)
@@ -1214,11 +1236,12 @@ class GPT(nn.Module):
                              (cfg.vocab_size,), cfg.param_dtype)
                   if cfg.lm_head_bias else None)
         if labels is None:
-            logits = jax.lax.dot_general(
-                x.astype(cfg.dtype), head_w, head_dims,
-                preferred_element_type=jnp.float32)
-            if head_b is not None:
-                logits = logits + head_b.astype(logits.dtype)
+            with jax.named_scope(SCOPE_LM_HEAD):
+                logits = jax.lax.dot_general(
+                    x.astype(cfg.dtype), head_w, head_dims,
+                    preferred_element_type=jnp.float32)
+                if head_b is not None:
+                    logits = logits + head_b.astype(logits.dtype)
             return logits
         # training path: the shift is expressed by zero-weighting the last
         # position instead of slicing, which keeps every tensor tile-aligned
@@ -1246,18 +1269,20 @@ class GPT(nn.Module):
             # bool first: True is an int and would read as chunk=1
             chunk = (fused if isinstance(fused, int)
                      and not isinstance(fused, bool) else 2048)
-            loss = fused_linear_cross_entropy(
-                cfg.tie_word_embeddings, chunk, flat, head_w, head_b,
-                targets.reshape(-1), wts.reshape(-1))
+            with jax.named_scope(SCOPE_LM_HEAD_CE):
+                loss = fused_linear_cross_entropy(
+                    cfg.tie_word_embeddings, chunk, flat, head_w, head_b,
+                    targets.reshape(-1), wts.reshape(-1))
         else:
             # unfused: materialize compute-dtype logits, fused CE math
             # (f32 reductions inside the fusion, bf16 cotangent)
-            logits = jax.lax.dot_general(
-                x.astype(cfg.dtype), head_w, head_dims)
-            if head_b is not None:
-                logits = logits + head_b.astype(logits.dtype)
-            loss = cross_entropy_loss(logits, labels, attention_mask,
-                                      segment_ids)
+            with jax.named_scope(SCOPE_LM_HEAD_CE):
+                logits = jax.lax.dot_general(
+                    x.astype(cfg.dtype), head_w, head_dims)
+                if head_b is not None:
+                    logits = logits + head_b.astype(logits.dtype)
+                loss = cross_entropy_loss(logits, labels, attention_mask,
+                                          segment_ids)
         if cfg.is_moe:
             # load-balance aux loss, averaged over layers (reference adds the
             # per-MoE-layer l_aux into the training loss with a coefficient)

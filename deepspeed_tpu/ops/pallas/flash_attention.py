@@ -50,6 +50,13 @@ from deepspeed_tpu.ops.pallas.common import (
     largest_divisor_block as _block,
 )
 
+# the kernels' names in a profiler trace and in the lowered HLO
+# (``kernel_name`` of the tpu_custom_call): a reader tells forward from
+# backward by these, not by the call's signature
+KERNEL_FWD = "flash_fwd"
+KERNEL_BWD_DQ = "flash_bwd_dq"
+KERNEL_BWD_DKV = "flash_bwd_dkv"
+
 
 # ---------------------------------------------------------------------------
 # forward
@@ -160,6 +167,7 @@ def _fwd(q, k, v, seg, scale, causal, block_q, block_k):
             jax.ShapeDtypeStruct((bh, t, LSE_LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name=KERNEL_FWD,
     )(*operands)
     return o, lse
 
@@ -331,6 +339,7 @@ def _bwd_impl(scale, causal, block_q, block_k, q, k, v, o, lse, do,
         out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         interpret=_interpret(),
+        name=KERNEL_BWD_DQ,
     )(*dq_operands)
 
     dk, dv = pl.pallas_call(
@@ -347,6 +356,7 @@ def _bwd_impl(scale, causal, block_q, block_k, q, k, v, o, lse, do,
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         ],
         interpret=_interpret(),
+        name=KERNEL_BWD_DKV,
     )(*dkv_operands)
 
     def unflat(x):

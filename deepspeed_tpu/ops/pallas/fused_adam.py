@@ -20,6 +20,9 @@ from jax.experimental import pallas as pl
 
 from deepspeed_tpu.ops.pallas.common import interpret as _interpret
 
+# the kernel's name in a profiler trace and in the lowered HLO
+KERNEL_NAME = "fused_adam"
+
 
 def _adamw_kernel(lr_ref, c1_ref, c2_ref, p_ref, g_ref, m_ref, v_ref,
                   po_ref, mo_ref, vo_ref,
@@ -85,6 +88,7 @@ def fused_adamw_update(p, g, m, v, lr, step, *, b1=0.9, b2=0.999, eps=1e-8,
         ],
         input_output_aliases={3: 0, 5: 1, 6: 2},
         interpret=_interpret(),
+        name=KERNEL_NAME,
     )(lr_arr, c1_arr, c2_arr, pf, gf, mf, vf)
 
     def unflat(x, dtype):

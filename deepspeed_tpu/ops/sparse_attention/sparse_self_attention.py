@@ -254,6 +254,7 @@ def _build_op(layout, num_heads, scale, causal, block):
                 jax.ShapeDtypeStruct((bh, t, LSE_LANES), jnp.float32),
             ],
             interpret=_interpret(),
+            name="sparse_flash_fwd",
         )(flat(q), flat(k), flat(v), jnp.asarray(kidx))
         return o, lse
 
@@ -303,6 +304,7 @@ def _build_op(layout, num_heads, scale, causal, block):
             out_specs=pl.BlockSpec((None, block, d), lambda i, j: (i, j, 0)),
             out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
             interpret=_interpret(),
+            name="sparse_flash_bwd_dq",
         )(qf, kf, vf, dof, lse, delta, jnp.asarray(kidx))
 
         dk, dv = pl.pallas_call(
@@ -328,6 +330,7 @@ def _build_op(layout, num_heads, scale, causal, block):
                 jax.ShapeDtypeStruct((bh, t, d), q.dtype),
             ],
             interpret=_interpret(),
+            name="sparse_flash_bwd_dkv",
         )(qf, kf, vf, dof, lse, delta, jnp.asarray(qidx))
 
         def unflat(x):

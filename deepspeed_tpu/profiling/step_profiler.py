@@ -194,9 +194,14 @@ class StepProfiler:
     def phase(self, name: str):
         """Context manager attributing its span (host + device work it
         dispatched) to ``name``. A strict no-op outside the window."""
-        if not self._window_active or self._finalized:
+        if not self.in_window:
             return _NULL_CTX
         return self._phase_ctx(name)
+
+    @property
+    def in_window(self) -> bool:
+        """Whether phases are being measured right now."""
+        return self._window_active and not self._finalized
 
     @contextlib.contextmanager
     def _phase_ctx(self, name: str):

@@ -64,6 +64,15 @@ from deepspeed_tpu.runtime.optimizer import (
     build_optimizer,
     is_compressed_optimizer,
 )
+from deepspeed_tpu.telemetry.scopes import (
+    SCOPE_GRAD_CAST,
+    SCOPE_GRAD_NORM_CLIP,
+    SCOPE_OPTIMIZER,
+    SCOPE_OVERFLOW_CHECK,
+    avals_like as _avals_like,
+    scope_table,
+)
+from deepspeed_tpu.telemetry.spans import TRAIN_PHASE, span
 from deepspeed_tpu.utils.compile_cache import ensure_compile_cache
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer, ThroughputTimer
@@ -71,23 +80,45 @@ from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer, ThroughputTime
 FORWARD_MICRO_TIMER = "fwd_bwd_microstep"
 STEP_MICRO_TIMER = "step_microstep"
 
-# shared no-op context for `_prof_phase` when the step profiler is off:
-# the healthy path must gain zero device syncs and near-zero host work
-import contextlib as _contextlib
-
-_NULL_PROF_CTX = _contextlib.nullcontext()
+# the names of the step programs as a profiler trace has them (its
+# ``XLA Modules`` events) and as ``program_scopes()`` keys them
+PROGRAM_TRAIN_STEP = "jit_train_step"
 
 
-def _avals_like(tree):
-    """Avals that lower to the SAME executable jit already dispatched for
-    ``tree``: shape, dtype and — for committed arrays — the sharding.
-    ``jitted.lower(avals).compile()`` is then a hit in jit's own lowering
-    cache; an aval without the sharding is a different cache key and
-    costs a second full XLA compile of the step."""
-    return jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=x.sharding if x.committed else None),
-        tree)
+class _PhaseSpan:
+    """One phase of a step, as one context object: the profiler span
+    ``ds:train.<name>`` (silent unless a profiler session records), the
+    host time of the phase for the flight recorder when it is on
+    (perf_counter only: the recorder never adds a fence), and the step
+    profiler's fenced phase inside its window. The healthy path gains no
+    device sync."""
+
+    __slots__ = ("_engine", "_name", "_span", "_inner", "_t0")
+
+    def __init__(self, engine, name):
+        self._engine = engine
+        self._name = name
+
+    def __enter__(self):
+        eng = self._engine
+        self._span = span(TRAIN_PHASE + self._name, step=eng.global_steps)
+        self._span.__enter__()
+        self._t0 = (time.perf_counter()
+                    if eng.flight_recorder is not None else None)
+        prof = eng.step_profiler
+        self._inner = (prof.phase(self._name)
+                       if prof is not None and prof.in_window else None)
+        if self._inner is not None:
+            self._inner.__enter__()
+
+    def __exit__(self, *exc):
+        if self._inner is not None:
+            self._inner.__exit__(*exc)
+        if self._t0 is not None:
+            self._engine.flight_recorder.add_phase_time(
+                self._name, time.perf_counter() - self._t0)
+        self._span.__exit__(*exc)
+        return False
 
 
 def initialize(
@@ -1235,9 +1266,11 @@ class DeepSpeedEngine:
         update are cond-skipped with the error-feedback buffers and the
         optimizer count untouched (reference fp16+onebit skip semantics,
         fp16/onebit/adam.py:10)."""
-        overflow = (has_overflow(grads) if self._check_overflow
-                    else jnp.bool_(False))
+        with jax.named_scope(SCOPE_OVERFLOW_CHECK):
+            overflow = (has_overflow(grads) if self._check_overflow
+                        else jnp.bool_(False))
 
+        @jax.named_scope(SCOPE_OPTIMIZER)
         def do_update(operand):
             params, opt_state, grads = operand
             return core(params, opt_state, grads, lr_factor)
@@ -1255,7 +1288,8 @@ class DeepSpeedEngine:
         core = self._compressed_apply_core()
 
         def apply_step(params, opt_state, acc_grads, ls_state, lr_factor):
-            grads = jax.tree.map(lambda g: g / ls_state.scale, acc_grads)
+            with jax.named_scope(SCOPE_GRAD_CAST):
+                grads = jax.tree.map(lambda g: g / ls_state.scale, acc_grads)
             new_params, new_opt, new_ls, overflow, grad_norm = \
                 self._guarded_compressed_update(
                     core, params, opt_state, grads, ls_state, lr_factor)
@@ -1272,8 +1306,9 @@ class DeepSpeedEngine:
                        lr_factor):
             grads, loss = self._grouped_grads(
                 params, batch, rng, step, ls_state.scale)
-            grads = jax.tree.map(
-                lambda g: g.astype(jnp.float32) / ls_state.scale, grads)
+            with jax.named_scope(SCOPE_GRAD_CAST):
+                grads = jax.tree.map(
+                    lambda g: g.astype(jnp.float32) / ls_state.scale, grads)
             new_params, new_opt, new_ls, overflow, grad_norm = \
                 self._guarded_compressed_update(
                     core, params, opt_state, grads, ls_state, lr_factor)
@@ -1362,14 +1397,18 @@ class DeepSpeedEngine:
         ls_config = self._ls_config
 
         def apply_step(params, opt_state, acc_grads, ls_state, lr_factor):
-            grads = jax.tree.map(lambda g: g / ls_state.scale, acc_grads)
-            overflow = (has_overflow(grads) if check_overflow
-                        else jnp.bool_(False))
-            grad_norm = optax.global_norm(grads)
-            if clip and clip > 0:
-                factor = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
-                grads = jax.tree.map(lambda g: g * factor, grads)
+            with jax.named_scope(SCOPE_GRAD_CAST):
+                grads = jax.tree.map(lambda g: g / ls_state.scale, acc_grads)
+            with jax.named_scope(SCOPE_OVERFLOW_CHECK):
+                overflow = (has_overflow(grads) if check_overflow
+                            else jnp.bool_(False))
+            with jax.named_scope(SCOPE_GRAD_NORM_CLIP):
+                grad_norm = optax.global_norm(grads)
+                if clip and clip > 0:
+                    factor = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
+                    grads = jax.tree.map(lambda g: g * factor, grads)
 
+            @jax.named_scope(SCOPE_OPTIMIZER)
             def do_update(operand):
                 params, opt_state, grads = operand
                 # grads ride in f32 for overflow/clip math; the optimizer
@@ -1432,15 +1471,19 @@ class DeepSpeedEngine:
                 return loss * ls_state.scale, loss
 
             grads, loss = jax.grad(loss_fn, has_aux=True)(params)
-            grads = jax.tree.map(
-                lambda g: g.astype(jnp.float32) / ls_state.scale, grads)
-            overflow = has_overflow(grads) if check_overflow \
-                else jnp.bool_(False)
-            grad_norm = optax.global_norm(grads)
-            if clip and clip > 0:
-                factor = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
-                grads = jax.tree.map(lambda g: g * factor, grads)
+            with jax.named_scope(SCOPE_GRAD_CAST):
+                grads = jax.tree.map(
+                    lambda g: g.astype(jnp.float32) / ls_state.scale, grads)
+            with jax.named_scope(SCOPE_OVERFLOW_CHECK):
+                overflow = has_overflow(grads) if check_overflow \
+                    else jnp.bool_(False)
+            with jax.named_scope(SCOPE_GRAD_NORM_CLIP):
+                grad_norm = optax.global_norm(grads)
+                if clip and clip > 0:
+                    factor = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
+                    grads = jax.tree.map(lambda g: g * factor, grads)
 
+            @jax.named_scope(SCOPE_OPTIMIZER)
             def do_update(operand):
                 params, opt_state, grads = operand
                 # see _build_apply.do_update: optimizer math in param dtype
@@ -1577,15 +1620,8 @@ class DeepSpeedEngine:
     # train API (reference forward/backward/step protocol)
     # ------------------------------------------------------------------
     def _prof_phase(self, name: str):
-        """Step-profiler phase context; when the flight recorder is on it
-        wraps the same context to accumulate host dispatch time per phase
-        (perf_counter only — the recorder never adds a fence). The shared
-        no-op when both are off (one attribute check, no syncs)."""
-        inner = (None if self.step_profiler is None
-                 else self.step_profiler.phase(name))
-        if self.flight_recorder is not None:
-            return self.flight_recorder.phase(name, inner)
-        return inner if inner is not None else _NULL_PROF_CTX
+        """The context of one step phase (see :class:`_PhaseSpan`)."""
+        return _PhaseSpan(self, name)
 
     def _prof_begin_step(self):
         if self.step_profiler is not None:
@@ -1652,6 +1688,16 @@ class DeepSpeedEngine:
                 aval(self._acc_grads), aval(self._ls_state),
                 lr_factor).compile(),
         }
+
+    def program_scopes(self) -> Dict[str, Dict[str, Optional[str]]]:
+        """``{program_name: {hlo_instruction_name: op_name_path}}`` of the
+        step program(s) this engine has dispatched (telemetry/scopes.py):
+        what joins a profiler trace, whose device events carry only the
+        instruction's name, to the source scopes. Empty before the first
+        step. Re-lowers (a cache hit) and parses HLO text: call it after
+        the measured window, never inside it."""
+        programs = self.compiled_step_programs() or {}
+        return scope_table(c.as_text() for c in programs.values())
 
     def compiled_step_memory(self) -> Optional[Dict[str, float]]:
         """XLA ``memory_analysis()`` of one optimizer step's compiled
@@ -1936,9 +1982,26 @@ class DeepSpeedEngine:
                 self._watchdog.disarm()
 
     def _post_step_bookkeeping(self, overflow, step_losses):
-        """Host tail shared by the fused and unfused step paths: overflow
-        accounting, lr schedule, PLD, MoQ, sentinel verdict, progress +
-        monitor events."""
+        """Host tail shared by the fused and unfused step paths, as three
+        phases one after another: the bookkeeping proper
+        (:meth:`_step_bookkeeping`), the sentinel's verdict, the health
+        plane's hook."""
+        with self._prof_phase("post_step_bookkeeping"):
+            update_skipped, host_loss = self._step_bookkeeping(
+                overflow, step_losses)
+        if self.flight_recorder is not None:
+            self._record_flight_step(host_loss, update_skipped)
+        if self.sentinel is not None:
+            with self._prof_phase("sentinel"):
+                self._sentinel_observe(update_skipped, host_loss)
+        if self.health_plane is not None:
+            self._health_step_hook()
+        if self._preempt_signum is not None:
+            self._graceful_shutdown()
+
+    def _step_bookkeeping(self, overflow, step_losses):
+        """Overflow accounting, lr schedule, PLD, MoQ, progress + monitor
+        events; returns ``(update_skipped, host_loss)``."""
         update_skipped = self._check_overflow and bool(overflow)
         if update_skipped:
             self.skipped_steps += 1
@@ -2024,15 +2087,7 @@ class DeepSpeedEngine:
                 [("Train/Samples/train_loss", host_loss,
                   self.global_samples)]
             )
-        if self.flight_recorder is not None:
-            self._record_flight_step(host_loss, update_skipped)
-        if self.sentinel is not None:
-            with self._prof_phase("sentinel"):
-                self._sentinel_observe(update_skipped, host_loss)
-        if self.health_plane is not None:
-            self._health_step_hook()
-        if self._preempt_signum is not None:
-            self._graceful_shutdown()
+        return update_skipped, host_loss
 
     def _record_flight_step(self, host_loss, update_skipped):
         """Append this optimizer step to the flight recorder ring —

@@ -5,7 +5,9 @@ Import layering matters here: ``bus``, ``flight_recorder`` and
 ``crash_report`` are stdlib-only (no jax) so supervisors — the elastic
 agent, the launcher, worker wrapper scripts — can import them without
 initializing a backend, the same discipline ``runtime/sentinel.py``
-established. ``memory`` touches jax only inside its functions.
+established. ``memory``, ``spans`` (host spans on the profiler's clock) and
+``scopes`` (which source scope a device operation belongs to) touch jax
+only inside their functions.
 """
 
 from deepspeed_tpu.telemetry.bus import TelemetryBus, publish, telemetry_bus
@@ -20,11 +22,13 @@ from deepspeed_tpu.telemetry.flight_recorder import (
     FlightRecorder,
     install_crash_handlers,
 )
+from deepspeed_tpu.telemetry.spans import span
 
 __all__ = [
     "TelemetryBus",
     "telemetry_bus",
     "publish",
+    "span",
     "FlightRecorder",
     "install_crash_handlers",
     "BLACKBOX_SCHEMA",

@@ -35,7 +35,6 @@ module to read dumps without dragging in jax.
 """
 
 import atexit
-import contextlib
 import json
 import os
 import signal as signal_module
@@ -119,25 +118,10 @@ class FlightRecorder:
         self._step_t0 = time.perf_counter()
         self._phase_acc = {}
 
-    def phase(self, name: str, inner=None):
-        """Context manager accumulating host dispatch time for ``name``
-        into the current step record; wraps ``inner`` (the step
-        profiler's fenced phase context or its shared nullcontext) so
-        the engine keeps one ``with`` per phase."""
-        return self._phase_ctx(name, inner)
-
-    @contextlib.contextmanager
-    def _phase_ctx(self, name: str, inner):
-        t0 = time.perf_counter()
-        try:
-            if inner is not None:
-                with inner:
-                    yield
-            else:
-                yield
-        finally:
-            dt = time.perf_counter() - t0
-            self._phase_acc[name] = self._phase_acc.get(name, 0.0) + dt
+    def add_phase_time(self, name: str, seconds: float) -> None:
+        """Add host time of phase ``name`` to the current step's record
+        (the engine's phase context measures it; perf_counter only)."""
+        self._phase_acc[name] = self._phase_acc.get(name, 0.0) + seconds
 
     def record_step(self, step: int, loss: Optional[float] = None,
                     grad_norm: Optional[float] = None,

@@ -1,0 +1,361 @@
+"""Which source scope each device operation belongs to.
+
+A TPU profiler trace names a device operation by its HLO instruction
+(``%fusion.304 = ...``) and carries no ``op_name``; the compiled program's
+own HLO text does (``metadata={op_name="jit(train_step)/.../optimizer/mul"}``).
+This module joins the two:
+
+* :func:`scope_table` turns the ``as_text()`` of compiled executables into
+  ``{program_name: {hlo_instruction_name: op_name_path}}`` (the engines'
+  ``program_scopes()`` call it with the executables they have dispatched);
+* :func:`time_by_scope` lays a trace's ``XLA Ops`` events against such a
+  table;
+* ``python -m deepspeed_tpu.telemetry.scopes <trace> <table.json>`` prints
+  the time per scope, for an operator who captured a trace and dumped
+  ``program_scopes()`` next to it.
+
+A scope path keeps JAX's own components (``jvp(GPT)``, ``transpose(...)``,
+``checkpoint`` / ``rematted_computation`` for rematerialised operations);
+:func:`components` drops only what says nothing about the source: the
+``jit(...)`` wrappers, loop and branch structure, and the primitive's name
+at the end. stdlib only at import; jax is imported where a trace is read.
+"""
+import collections
+import gzip
+import json
+import re
+import sys
+
+# named scopes the program sets (jax.named_scope), by the name a reader
+# looks for among a path's components
+SCOPE_OPTIMIZER = "optimizer"
+SCOPE_GRAD_CAST = "grad_cast"
+SCOPE_OVERFLOW_CHECK = "overflow_check"
+SCOPE_GRAD_NORM_CLIP = "grad_norm_clip"
+SCOPE_LM_HEAD = "lm_head"
+SCOPE_LM_HEAD_CE = "lm_head_ce"
+SCOPE_MLM_HEAD = "mlm_head"
+SCOPE_ATTN_CORE = "attn_core"
+SCOPE_KV_CACHE_WRITE = "kv_cache_write"
+SCOPE_KV_CACHE_READ = "kv_cache_read"
+SCOPE_SAMPLE = "sample"
+# not a named scope: the tag of an instruction that no scope above owns and
+# whose result is a whole KV-cache leaf (XLA's own copies of a loop's carry,
+# the slices and updates of the stacked cache around the layer scan)
+SCOPE_KV_CACHE_CARRY = "kv_cache_carry"
+# JAX's own name-stack component of a rematerialised (recomputed) operation;
+# ``checkpoint`` alone is also on the backward pass of a checkpointed region
+SCOPE_REMAT = "rematted_computation"
+
+_CARRY_FREE = frozenset((
+    SCOPE_OPTIMIZER, SCOPE_GRAD_CAST, SCOPE_OVERFLOW_CHECK,
+    SCOPE_GRAD_NORM_CLIP, SCOPE_LM_HEAD, SCOPE_LM_HEAD_CE, SCOPE_MLM_HEAD,
+    SCOPE_ATTN_CORE, SCOPE_KV_CACHE_WRITE, SCOPE_KV_CACHE_READ, SCOPE_SAMPLE))
+_STRUCTURE = re.compile(
+    r"^(jit\(.*\)|pjit\(.*\)|while|body|cond|branch_\d+_fun|closed_call|"
+    r"core_call|custom_jvp_call|custom_vjp_call|custom_vjp_call_jaxpr)$")
+_CONTAINERS = ("while", "conditional", "call")
+
+_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_OPCODE = re.compile(r"[\]})] ([a-z][\w\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_SHAPE = re.compile(r"^\(?\w+\[([\d,]*)\]")
+_EVENT_NAME = re.compile(r"^%?(\S+) = ")
+
+
+def split_path(path):
+    """The components of an ``op_name`` path. ``/`` inside parentheses
+    (``jit(a/b)``) does not split."""
+    out, depth, cur = [], 0, []
+    for ch in path:
+        if ch == "/" and depth == 0:
+            out.append("".join(cur))
+            cur = []
+            continue
+        depth += ch == "("
+        depth -= ch == ")"
+        cur.append(ch)
+    out.append("".join(cur))
+    return [c for c in out if c]
+
+
+def components(path):
+    """The scope components of a path: what is left of it without the
+    ``jit(...)`` wrappers, the loop and branch structure and the primitive
+    at the end. Empty when the path names nothing deeper than its
+    program's root (or is None)."""
+    if not path:
+        return []
+    return [c for c in split_path(path)[:-1] if not _STRUCTURE.match(c)]
+
+
+def has_scope(path, *names):
+    """Whether one of ``names`` is a component of ``path``, bare or under
+    JAX's transform wrappers (``transpose(jvp(optimizer))``)."""
+    for c in components(path):
+        bare = c.rsplit("(", 1)[-1].rstrip(")") if "(" in c else c
+        if bare in names:
+            return True
+    return False
+
+
+def parse_hlo(text):
+    """``(module_name, instructions, fused)`` of an HLO module's text.
+    ``instructions`` maps each instruction outside fused computations (the
+    ones a trace has events for) to ``(op_name, opcode, dims, callee)``:
+    ``dims`` is the result's shape (of the first array of a tuple),
+    ``callee`` a fusion's computation. ``fused`` maps each fused
+    computation to the op_names of its instructions."""
+    module = None
+    comps = {}    # computation -> [(name, op_name, opcode, dims, callee)]
+    cur = None
+    for line in text.splitlines():
+        if module is None:
+            m = _MODULE.match(line)
+            if m:
+                module = m.group(1)
+                continue
+        if cur is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                cur = comps.setdefault(m.group(1), [])
+            continue
+        if line.startswith("}"):
+            cur = None
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, rest = m.groups()
+        op = _OPCODE.search(" " + rest)
+        opcode = op.group(1) if op else "?"
+        on = _OP_NAME.search(rest)
+        shape = _SHAPE.match(rest)
+        dims = tuple(int(d) for d in shape.group(1).split(",") if d) \
+            if shape else None
+        callee = None
+        if opcode == "fusion":
+            c = _CALLS.search(rest)
+            callee = c.group(1) if c else None
+        # XLA joins the op_names of instructions it merged with ";"
+        op_name = on.group(1).split(";")[0] if on else None
+        cur.append((name, op_name, opcode, dims, callee))
+    fused = {row[4] for rows in comps.values() for row in rows if row[4]}
+    out = {row[0]: row[1:] for comp, rows in comps.items()
+           if comp not in fused for row in rows}
+    return module, out, {c: [r[1] for r in comps.get(c, ())] for c in fused}
+
+
+def _tag_carry(path, module, opcode):
+    parts = split_path(path) if path else ["jit(%s)" % module, opcode]
+    return "/".join(parts[:-1] + [SCOPE_KV_CACHE_CARRY, parts[-1]])
+
+
+def instruction_scopes(text, carry_shapes=()):
+    """``(program_name, {instruction: path})`` from one executable's
+    ``as_text()``. A fusion takes its own ``op_name``; where it has none,
+    the most common one among the instructions of its fused computation.
+    An instruction that none of the program's named scopes owns and whose
+    result has one of ``carry_shapes`` (tuples of ints) gets the
+    ``kv_cache_carry`` component. An instruction with no scope at all is
+    kept, mapped to None."""
+    module, parsed, fused = parse_hlo(text)
+    carry = {tuple(s) for s in carry_shapes}
+    table = {}
+    for name, (op_name, opcode, dims, callee) in parsed.items():
+        path = op_name
+        if path is None and callee:
+            inner = collections.Counter(
+                p for p in fused.get(callee, ()) if p)
+            if inner:
+                path = inner.most_common(1)[0][0]
+        if dims in carry and opcode not in _CONTAINERS \
+                and not has_scope(path, *_CARRY_FREE):
+            path = _tag_carry(path, module, opcode)
+        table[name] = path
+    return module, table
+
+
+def avals_like(tree):
+    """Avals that lower to the SAME executable jit already dispatched for
+    ``tree``: shape, dtype and, for committed arrays, the sharding.
+    ``jitted.lower(avals).compile()`` is then a hit in jit's own lowering
+    cache; an aval without the sharding is a different cache key and
+    costs a second full XLA compile. Leaves that are no arrays (static
+    arguments) stay as they are."""
+    import jax
+
+    def aval(x):
+        if not hasattr(x, "shape") or not hasattr(x, "dtype"):
+            return x
+        sharding = x.sharding if getattr(x, "committed", False) else None
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    return jax.tree.map(aval, tree)
+
+
+class DispatchedProgram:
+    """A jitted step program that remembers what it was dispatched on.
+
+    Calls go straight through to ``fn``. The first call under each
+    ``key(args)`` (the shapes that select a specialisation: a prompt
+    bucket, a scan length) also keeps the arguments' avals, so that
+    :meth:`lowered` can hand out exactly the executables that ran, after
+    the fact and off the hot path."""
+
+    __slots__ = ("fn", "key", "avals")
+
+    def __init__(self, fn, key):
+        self.fn = fn
+        self.key = key
+        self.avals = {}
+
+    def __call__(self, *args):
+        k = self.key(args)
+        if k not in self.avals:
+            self.avals[k] = avals_like(args)
+        return self.fn(*args)
+
+    def lowered(self):
+        """``jax.stages.Lowered`` of every specialisation run (its
+        ``compile()`` is a hit in jit's own cache)."""
+        return [self.fn.lower(*a) for a in self.avals.values()]
+
+
+def _common_path(a, b):
+    if a is None or b is None:
+        return None
+    pa, pb = split_path(a), split_path(b)
+    n = 0
+    while n < min(len(pa), len(pb)) - 1 and pa[n] == pb[n]:
+        n += 1
+    # the agreed components, then a primitive slot so that components()
+    # drops nothing real
+    return "/".join(pa[:n] + ["*"]) if n else None
+
+
+def scope_table(texts, carry_shapes=()):
+    """``{program_name: {instruction: path}}`` from the ``as_text()`` of
+    several executables. Executables of one name (a prefill program per
+    prompt bucket) share one entry: where they disagree on an instruction,
+    the entry keeps the components they agree on, so a joined trace is
+    never told a scope that one of them does not have."""
+    table = {}
+    for text in texts:
+        program, scopes = instruction_scopes(text, carry_shapes)
+        into = table.setdefault(program, {})
+        for name, path in scopes.items():
+            into[name] = _common_path(into[name], path) \
+                if name in into and into[name] != path else path
+    return table
+
+
+# ---------------------------------------------------------------------------
+# joining a trace
+# ---------------------------------------------------------------------------
+def load_trace(path):
+    """A ``ProfileData`` from an ``.xplane.pb`` or ``.xplane.pb.gz`` file."""
+    from jax.profiler import ProfileData
+
+    if path.endswith(".gz"):
+        with gzip.open(path, "rb") as f:
+            return ProfileData.from_serialized_xspace(f.read())
+    return ProfileData.from_file(path)
+
+
+def time_by_scope(profile, table, window=None):
+    """Rows ``{"program", "instruction", "path", "seconds", "count"}``: the
+    device time of every executed HLO instruction (``XLA Ops`` of the
+    ``/device:TPU:<n>`` planes, containers left out, clipped to ``window``
+    = (start_ns, end_ns) when given), averaged over the chips, with the
+    program it ran in (the enclosing ``XLA Modules`` event) and its path in
+    ``table`` (None when the table lacks the program or the instruction)."""
+    import bisect
+
+    sums, counts, chips = {}, {}, 0
+    for plane in profile.planes:
+        if not re.match(r"^/device:TPU:\d+$", plane.name):
+            continue
+        chips += 1
+        modules, ops = [], None
+        for line in plane.lines:
+            if line.name == "XLA Modules":
+                modules = sorted(
+                    (e.start_ns, e.start_ns + e.duration_ns,
+                     e.name.split("(")[0]) for e in line.events)
+            elif line.name == "XLA Ops":
+                ops = line.events
+        starts = [m[0] for m in modules]
+        opcodes = {}
+        for e in ops or ():
+            text = e.name
+            if text not in opcodes:
+                nm = _EVENT_NAME.match(text)
+                op = _OPCODE.search(text, nm.end() - 1) if nm else None
+                opcodes[text] = (nm.group(1) if nm else text.lstrip("%"),
+                                 op.group(1) if op else "?")
+            name, opcode = opcodes[text]
+            if opcode in _CONTAINERS:
+                continue
+            a, b = e.start_ns, e.start_ns + e.duration_ns
+            if window:
+                a, b = max(a, window[0]), min(b, window[1])
+                if b <= a:
+                    continue
+            i = bisect.bisect_right(starts, e.start_ns) - 1
+            program = modules[i][2] \
+                if i >= 0 and e.start_ns < modules[i][1] else "?"
+            key = (program, name)
+            sums[key] = sums.get(key, 0.0) + (b - a)
+            counts[key] = counts.get(key, 0) + 1
+    chips = max(1, chips)
+    return [{"program": p, "instruction": n,
+             "path": table.get(p, {}).get(n),
+             "seconds": v / chips / 1e9, "count": counts[(p, n)]}
+            for (p, n), v in sums.items()]
+
+
+def share(rows, keep, of=None):
+    """Percent of the rows' device time (of those ``of`` keeps, when given)
+    spent in the rows ``keep`` keeps; None when there is no time."""
+    base = [r for r in rows if of is None or of(r)]
+    whole = sum(r["seconds"] for r in base)
+    if not whole:
+        return None
+    return 100.0 * sum(r["seconds"] for r in base if keep(r)) / whole
+
+
+def attributed(row):
+    return bool(components(row["path"]))
+
+
+def by_scope(rows):
+    """``[(scope, seconds)]``, longest first; a scope is the row's
+    components joined by ``/``, ``(unattributed)`` when it has none."""
+    sums = {}
+    for r in rows:
+        key = "/".join(components(r["path"])) or "(unattributed)"
+        sums[key] = sums.get(key, 0.0) + r["seconds"]
+    return sorted(sums.items(), key=lambda kv: -kv[1])
+
+
+def main(argv):
+    if len(argv) != 2:
+        print("usage: python -m deepspeed_tpu.telemetry.scopes "
+              "<trace.xplane.pb[.gz]> <table.json>", file=sys.stderr)
+        return 2
+    with open(argv[1]) as f:
+        table = json.load(f)
+    rows = time_by_scope(load_trace(argv[0]), table)
+    whole = sum(r["seconds"] for r in rows) or 1.0
+    print(f"{'seconds':>10}  {'share':>6}  scope")
+    for scope, secs in by_scope(rows):
+        print(f"{secs:10.6f}  {100 * secs / whole:5.1f}%  {scope}")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

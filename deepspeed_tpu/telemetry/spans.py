@@ -1,0 +1,37 @@
+"""Host spans on the profiler's clock.
+
+``span`` is the only way the program writes a span. It returns a
+``jax.profiler.TraceAnnotation``, which does nothing measurable while no
+profiler session is recording and, while one is (``jax.profiler.start_trace``
+/ ``start_server``, or a benchmark's traced run), writes the span into the
+same ``.xplane.pb``, on the same clock, as the device's events. So there is
+no switch, no configuration key and no second trace format: whoever records
+a trace gets the spans (docs/observability.md "Profiler spans and scopes").
+
+Attributes are host scalars the caller already holds (the bus's contract);
+they come back as the event's stats. A span never publishes a bus event and
+never waits for the device.
+"""
+
+SPAN_PREFIX = "ds:"
+
+# span names (without the prefix); the names are the contract with whoever
+# reads a trace
+SERVE_ITERATION = "serve.iteration"
+SERVE_ADMIT = "serve.admit"
+SERVE_PREFILL = "serve.prefill"
+SERVE_FIRST_TOKEN_READ = "serve.first_token_read"
+SERVE_SPLICE = "serve.splice"
+SERVE_EMIT = "serve.emit"
+SERVE_STATS = "serve.stats"
+SERVE_DECODE_STEP = "serve.decode_step"
+SERVE_DECODE_READ = "serve.decode_read"
+TRAIN_PHASE = "train."      # + the engine's phase name
+
+
+def span(name, **attrs):
+    """A context manager that records ``ds:<name>`` with ``attrs`` while a
+    profiler session is active and is a no-op otherwise."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(SPAN_PREFIX + name, **attrs)

@@ -154,19 +154,21 @@ class TestFlightRecorder:
     def test_phase_accumulation(self):
         rec = FlightRecorder()
         rec.begin_step(3)
-        with rec.phase("compiled_step", None):
-            pass
-        with rec.phase("compiled_step", None):
-            pass
-        with rec.phase("h2d", None):
-            pass
+        rec.add_phase_time("compiled_step", 0.25)
+        rec.add_phase_time("compiled_step", 0.5)
+        rec.add_phase_time("h2d", 0.125)
         r = rec.record_step(3)
         assert r["total_s"] >= 0
-        assert set(r["phases_s"]) == {"compiled_step", "h2d"}
+        assert r["phases_s"] == {"compiled_step": 0.75, "h2d": 0.125}
         # accumulator closed: next record has no stale phases
         assert "phases_s" not in rec.record_step(4)
 
-    def test_phase_wraps_inner_context(self):
+    def test_engine_phase_wraps_step_profiler_phase(self):
+        """The engine's one phase context enters the step profiler's
+        phase inside its window only, and times the phase (profiler's
+        fence included) for the recorder."""
+        from deepspeed_tpu.runtime.engine import _PhaseSpan
+
         entered = []
 
         class Inner:
@@ -176,11 +178,27 @@ class TestFlightRecorder:
             def __exit__(self, *a):
                 entered.append("out")
 
-        rec = FlightRecorder()
-        rec.begin_step(1)
-        with rec.phase("p", Inner()):
+        class Prof:
+            in_window = True
+
+            def phase(self, name):
+                entered.append(name)
+                return Inner()
+
+        class Eng:
+            global_steps = 1
+            step_profiler = Prof()
+            flight_recorder = FlightRecorder()
+
+        Eng.flight_recorder.begin_step(1)
+        with _PhaseSpan(Eng, "p"):
             entered.append("body")
-        assert entered == ["in", "body", "out"]
+        assert entered == ["p", "in", "body", "out"]
+        assert set(Eng.flight_recorder.record_step(1)["phases_s"]) == {"p"}
+        Prof.in_window = False
+        with _PhaseSpan(Eng, "q"):
+            entered.append("body")
+        assert entered[4:] == ["body"]
 
     def test_bus_events_ring(self):
         bus = TelemetryBus(rank=1)
