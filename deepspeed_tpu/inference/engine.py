@@ -50,8 +50,9 @@ def kv_leaf_shapes(tree):
     pytree of arrays or avals that holds caches (the leaves named
     ``cached_key`` / ``cached_value`` / ``cached_*_scale`` of the model's
     ``cache`` collection): the leaf as stored, and without its leading
-    layer axis where ``nn.scan`` stacked it (what one turn of the layer
-    loop sees)."""
+    layer axis where ``ScannedBlocks`` stacked it (one layer's slice, which
+    a turn of the layer loop reads inside its attention fusions and should
+    never produce)."""
     shapes = set()
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         name = str(getattr(path[-1], "key", ""))
@@ -493,8 +494,11 @@ class InferenceEngine:
     # generation (prefill + greedy/sampled decode over the KV cache)
     # ------------------------------------------------------------------
     def _build_decode_fns(self):
-        """Compiled once per input shape (jit's shape cache); the cache
-        buffer is donated so decode steps update KV in place."""
+        """Compiled once per input shape (jit's shape cache). The cache is
+        donated, and stays one buffer per leaf through the scan over ``k``
+        and the model's layer loop (models/transformer_lm.py
+        ScannedBlocks): a decode step writes its new rows into it and
+        produces no other whole leaf."""
         model = self.module
 
         def prefill(params, ids, mask):
@@ -598,7 +602,8 @@ class InferenceEngine:
         per program name over all the prompt buckets it ran
         (telemetry/scopes.py). Operations that no named scope owns and
         whose result is a whole KV-cache leaf are tagged
-        ``kv_cache_carry``. Re-lowers (a cache hit) and parses HLO text:
+        ``kv_cache_carry`` (none, while the cache crosses the layer loop
+        in place). Re-lowers (a cache hit) and parses HLO text:
         call it after the measured window, never inside it."""
         return programs_scope_table(self.step_programs())
 

@@ -419,7 +419,7 @@ class CausalSelfAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, mask=None, segment_ids=None, positions=None,
-                 deterministic=True, decode=False):
+                 deterministic=True, decode=False, cache_layer=None):
         cfg = self.config
         B, T, C = x.shape
         H, D = cfg.n_head, cfg.head_dim
@@ -469,41 +469,87 @@ class CausalSelfAttention(nn.Module):
             if not cfg.causal:
                 raise NotImplementedError(
                     "decode path requires a causal model")
+            # KV-cache append + attend (the reference's softmax_context
+            # kernel with its inference_context.h cache management,
+            # csrc/transformer/inference/). Chunk-aware: prefill writes T
+            # tokens at once, decode steps write one. Ragged batches:
+            # LEFT-padded prompts pass ``mask``, and a per-slot validity
+            # cache excludes pad slots from every later step's attention
+            # (reference inference_context.h masked decode). Left padding
+            # keeps valid keys physically contiguous, so rotary (relative
+            # offsets) and ALiBi (row-constant shift under softmax) stay
+            # exact without per-sequence position bookkeeping here.
+            #
             # int8 KV cache (GPTConfig.kv_cache_dtype): values are stored
             # quantized with per-(row, slot, kv-head) f32 scales and
             # dequantized on read — XLA fuses the int8->f32 convert +
             # scale multiply into the attention einsums, so per-step HBM
             # cache traffic stays int8
             kv_int8 = cfg.kv_cache_dtype == "int8"
-            kv_store_dtype = jnp.int8 if kv_int8 else cfg.dtype
-
-            def quantize_kv(t):
-                from deepspeed_tpu.ops.quantizer import quantize_blockwise
-
-                q, s = quantize_blockwise(t, D)
-                return q, s[..., 0]          # [B, T, Hkv, 1] -> [B, T, Hkv]
-
-            def read_kv(ck, cv, ks, vs):
-                if not kv_int8:
-                    return ck.value, cv.value
-                from deepspeed_tpu.ops.quantizer import dequantize_blockwise
-
-                return (dequantize_blockwise(ck.value, ks.value, cfg.dtype),
-                        dequantize_blockwise(cv.value, vs.value, cfg.dtype))
             # layout-aware compact KV cache: when the sparse layout is a
             # causal window (+ leading globals), decode retains ONLY the
             # slots the layout can ever attend — a block-granular ring —
             # and reproduces the TRAINING block-sparse visibility exactly
-            # (the dense-cache path below attends strictly more keys than
-            # a window-trained model saw). See GPTConfig.sparse_kv_cache.
+            # (the dense cache attends strictly more keys than a
+            # window-trained model saw). See GPTConfig.sparse_kv_cache.
             from deepspeed_tpu.ops.sparse_attention. \
                 sparse_attention_utils import ring_engaged, ring_storage_len
 
             ring = ring_engaged(cfg)
-            if ring is not None:
+            if ring is None:
+                S = cfg.n_positions
+            else:
                 w_blk, g_tok, blk = ring
                 ring_len = ring_storage_len(cfg, ring)
                 S = g_tok + ring_len
+            # leaf -> (shape, virgin value, dtype)
+            spec = dict.fromkeys(
+                ("cached_key", "cached_value"),
+                ((B, S, Hkv, D), 0, jnp.int8 if kv_int8 else cfg.dtype))
+            if kv_int8:
+                spec.update(dict.fromkeys(
+                    ("cached_key_scale", "cached_value_scale"),
+                    ((B, S, Hkv), 0, jnp.float32)))
+            spec["valid"] = ((B, S), False, jnp.bool_)
+            if ring is not None:
+                spec["slot_pos"] = ((B, S), -1, jnp.int32)  # nothing cached
+            spec["cache_index"] = ((B,), 0, jnp.int32)
+            cache = {name: self.variable("cache", name, jnp.full, *leaf_spec)
+                     for name, leaf_spec in spec.items()}
+
+            # Under ScannedBlocks the leaves are the stacked
+            # [n_layer, B, S, ...] buffers that the layer loop carries,
+            # and this call is layer ``cache_layer`` of them: it writes
+            # its new rows at [cache_layer, row, slot] and reads its
+            # [B, S, ...] slice, so no whole leaf is produced but by the
+            # in-place row update. Without a layer (scan_layers=False) a
+            # leaf is this layer's own.
+            def leaf(name):
+                v = cache[name].value
+                return v if cache_layer is None else \
+                    jax.lax.dynamic_index_in_dim(v, cache_layer, 0,
+                                                 keepdims=False)
+
+            def put(name, index, val):
+                if cache_layer is not None:
+                    index = (cache_layer,) + index
+                cache[name].value = cache[name].value.at[index].set(
+                    val, mode="drop")
+
+            # PER-ROW write index (and, in the ring, slot positions):
+            # continuous-batching admissions splice a freshly prefilled
+            # [1, ...] cache into one batch lane, so every row carries its
+            # own clock (lockstep generate just advances them together)
+            idx = leaf("cache_index")                       # [B]
+            pos = idx[:, None] + jnp.arange(T)[None, :]     # [B, T]
+            if cfg.rotary:
+                # rotate before the cache write: cached keys are
+                # position-baked, exactly like the reference's KV cache
+                # after its apply_rotary_pos_emb kernel
+                q, k = rope(q, pos), rope(k, pos)
+            if ring is None:
+                slot_sets = (pos,)
+            else:
                 if T > ring_len:
                     raise ValueError(
                         f"ring KV prefill got {T} tokens in one pass but "
@@ -516,165 +562,59 @@ class CausalSelfAttention(nn.Module):
                         "InferenceEngine.generate and the continuous-"
                         "batching scheduler do this automatically "
                         "(inference/engine.py prefill_chunk_spans).")
-                cached_k = self.variable(
-                    "cache", "cached_key", jnp.zeros,
-                    (B, S, Hkv, D), kv_store_dtype)
-                cached_v = self.variable(
-                    "cache", "cached_value", jnp.zeros,
-                    (B, S, Hkv, D), kv_store_dtype)
-                k_scale = v_scale = None
-                if kv_int8:
-                    k_scale = self.variable(
-                        "cache", "cached_key_scale", jnp.zeros,
-                        (B, S, Hkv), jnp.float32)
-                    v_scale = self.variable(
-                        "cache", "cached_value_scale", jnp.zeros,
-                        (B, S, Hkv), jnp.float32)
-                cache_valid = self.variable(
-                    "cache", "valid", jnp.zeros, (B, S), jnp.bool_)
-                # PER-ROW slot positions and write index: continuous-
-                # batching admissions splice a freshly prefilled [1, ...]
-                # cache into one batch lane, so every row carries its own
-                # clock (lockstep generate just advances them together)
-                slot_pos = self.variable(
-                    "cache", "slot_pos",
-                    lambda: jnp.full((B, S), -1, jnp.int32))
-                cache_index = self.variable(
-                    "cache", "cache_index",
-                    lambda: jnp.zeros((B,), jnp.int32))
-                idx = cache_index.value                       # [B]
-                pos = idx[:, None] + jnp.arange(T)[None, :]   # [B, T]
-                if cfg.rotary:
-                    q, k = rope(q, pos), rope(k, pos)
                 # every token of a (guarded, <= ring_len) pass lands in its
                 # ring slot; leading-global tokens ALSO land in their
                 # dedicated slot (the ring copy is masked out of
                 # visibility below, so nothing double-counts)
-                ring_slot = g_tok + pos % ring_len            # [B, T]
-                glob_slot = jnp.where(pos < g_tok, pos, S)    # S -> dropped
-                write_valid = (mask.astype(jnp.bool_) if mask is not None
-                               else jnp.ones((B, T), jnp.bool_))
-                if kv_int8:
-                    (kc, ksc), (vc, vsc) = quantize_kv(k), quantize_kv(v)
-                else:
-                    kc, vc = k.astype(cfg.dtype), v.astype(cfg.dtype)
-                rows = jnp.arange(B)[:, None]
-                with jax.named_scope(SCOPE_KV_CACHE_WRITE):
-                    for slots in (ring_slot, glob_slot):
-                        cached_k.value = cached_k.value.at[rows, slots].set(
-                            kc, mode="drop")
-                        cached_v.value = cached_v.value.at[rows, slots].set(
-                            vc, mode="drop")
-                        if kv_int8:
-                            k_scale.value = k_scale.value.at[
-                                rows, slots].set(ksc, mode="drop")
-                            v_scale.value = v_scale.value.at[
-                                rows, slots].set(vsc, mode="drop")
-                        cache_valid.value = cache_valid.value.at[
-                            rows, slots].set(write_valid, mode="drop")
-                        slot_pos.value = slot_pos.value.at[rows, slots].set(
-                            pos, mode="drop")
-                    cache_index.value = idx + T
-                with jax.named_scope(SCOPE_KV_CACHE_READ):
-                    k_all, v_all = read_kv(cached_k, cached_v, k_scale,
-                                           v_scale)
-                    q_pos = pos[:, :, None]                   # [B, T, 1]
-                    ps = slot_pos.value[:, None, :]           # [B, 1, S]
-                    s_idx = jnp.arange(S)[None, None, :]
-                    is_glob = s_idx < g_tok
-                    in_window = (ps // blk) >= (q_pos // blk) - w_blk
-                    visible = ((ps >= 0) & (ps <= q_pos)
-                               & (is_glob | (in_window & (ps >= g_tok))))
-                    visible = (visible[:, None, None]         # [B,1,1,T,S]
-                               & cache_valid.value[:, None, None, None, :])
-
-                G = H // Hkv
-                qg = q.reshape(B, T, Hkv, G, D)
-                scale = 1.0 / np.sqrt(D)
-                with jax.named_scope(SCOPE_ATTN_CORE):
-                    att = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_all) * scale
-                    att = jnp.where(visible, att, jnp.finfo(att.dtype).min)
-                    # NaN-safe: an all-pad chunk row (ragged left-padded
-                    # batch) has an empty visible set; its output is masked
-                    # out later but must not produce NaN
-                    att = jax.nn.softmax(
-                        att.astype(jnp.float32), axis=-1,
-                        where=visible).astype(cfg.dtype)
-                    y = jnp.einsum("bhgqk,bkhd->bqhgd", att, v_all)
-                y = y.reshape(B, T, C)
-                return nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
-                                param_dtype=cfg.param_dtype,
-                                name="c_proj")(y)
-            # KV-cache append + attend (the reference's softmax_context
-            # kernel with its inference_context.h cache management,
-            # csrc/transformer/inference/). Chunk-aware: prefill writes T
-            # tokens at once, decode steps write one. Ragged batches:
-            # LEFT-padded prompts pass ``mask``, and a per-slot validity
-            # cache excludes pad slots from every later step's attention
-            # (reference inference_context.h masked decode). Left padding
-            # keeps valid keys physically contiguous, so rotary (relative
-            # offsets) and ALiBi (row-constant shift under softmax) stay
-            # exact without per-sequence position bookkeeping here.
-            cached_k = self.variable(
-                "cache", "cached_key", jnp.zeros,
-                (B, cfg.n_positions, Hkv, D), kv_store_dtype)
-            cached_v = self.variable(
-                "cache", "cached_value", jnp.zeros,
-                (B, cfg.n_positions, Hkv, D), kv_store_dtype)
-            k_scale = v_scale = None
-            if kv_int8:
-                k_scale = self.variable(
-                    "cache", "cached_key_scale", jnp.zeros,
-                    (B, cfg.n_positions, Hkv), jnp.float32)
-                v_scale = self.variable(
-                    "cache", "cached_value_scale", jnp.zeros,
-                    (B, cfg.n_positions, Hkv), jnp.float32)
-            cache_valid = self.variable(
-                "cache", "valid", jnp.zeros,
-                (B, cfg.n_positions), jnp.bool_)
-            # PER-ROW write index (see ring branch): continuous-batching
-            # admissions splice a [1, ...] cache into one batch lane, so
-            # each row advances its own clock
-            cache_index = self.variable(
-                "cache", "cache_index",
-                lambda: jnp.zeros((B,), jnp.int32))
-            idx = cache_index.value                         # [B]
-            pos = idx[:, None] + jnp.arange(T)[None, :]     # [B, T]
-            if cfg.rotary:
-                # rotate before the cache write: cached keys are
-                # position-baked, exactly like the reference's KV cache
-                # after its apply_rotary_pos_emb kernel
-                q, k = rope(q, pos), rope(k, pos)
+                slot_sets = (g_tok + pos % ring_len,
+                             jnp.where(pos < g_tok, pos, S))  # S -> dropped
             rows = jnp.arange(B)[:, None]
             write_valid = (mask.astype(jnp.bool_) if mask is not None
                            else jnp.ones((B, T), jnp.bool_))
             with jax.named_scope(SCOPE_KV_CACHE_WRITE):
-                if kv_int8:
-                    (kc, ksc), (vc, vsc) = quantize_kv(k), quantize_kv(v)
-                    k_scale.value = k_scale.value.at[rows, pos].set(
-                        ksc, mode="drop")
-                    v_scale.value = v_scale.value.at[rows, pos].set(
-                        vsc, mode="drop")
-                else:
-                    kc, vc = k.astype(cfg.dtype), v.astype(cfg.dtype)
-                cached_k.value = cached_k.value.at[rows, pos].set(
-                    kc, mode="drop")
-                cached_v.value = cached_v.value.at[rows, pos].set(
-                    vc, mode="drop")
-                cache_valid.value = cache_valid.value.at[rows, pos].set(
-                    write_valid, mode="drop")
-                cache_index.value = idx + T
+                new = {"valid": write_valid}
+                for name, t in (("cached_key", k), ("cached_value", v)):
+                    if kv_int8:
+                        from deepspeed_tpu.ops.quantizer import \
+                            quantize_blockwise
+
+                        # scales [B, T, Hkv, 1] -> [B, T, Hkv]
+                        new[name], scale = quantize_blockwise(t, D)
+                        new[name + "_scale"] = scale[..., 0]
+                    else:
+                        new[name] = t.astype(cfg.dtype)
+                if ring is not None:
+                    new["slot_pos"] = pos
+                for slots in slot_sets:
+                    for name, val in new.items():
+                        put(name, (rows, slots), val)
+                put("cache_index", (Ellipsis,), idx + T)
             q_pos = pos[:, :, None]                         # [B, T, 1]
-            k_pos = jnp.arange(cfg.n_positions)[None, :]    # [1, max]
+            k_pos = jnp.arange(S)[None, :]                  # [1, S]
             with jax.named_scope(SCOPE_KV_CACHE_READ):
-                k_all, v_all = read_kv(cached_k, cached_v, k_scale, v_scale)
-                visible = (k_pos[None] <= q_pos)            # [B, T, max]
-                visible = (visible[:, None, None]           # [B,1,1,T,max]
-                           & cache_valid.value[:, None, None, None, :])
+                k_all, v_all = leaf("cached_key"), leaf("cached_value")
+                if kv_int8:
+                    from deepspeed_tpu.ops.quantizer import \
+                        dequantize_blockwise
+
+                    k_all = dequantize_blockwise(
+                        k_all, leaf("cached_key_scale"), cfg.dtype)
+                    v_all = dequantize_blockwise(
+                        v_all, leaf("cached_value_scale"), cfg.dtype)
+                if ring is None:
+                    visible = k_pos[None] <= q_pos          # [B, T, S]
+                else:
+                    ps = leaf("slot_pos")[:, None, :]       # [B, 1, S]
+                    in_window = (ps // blk) >= (q_pos // blk) - w_blk
+                    visible = ((ps >= 0) & (ps <= q_pos)
+                               & ((k_pos[None] < g_tok)
+                                  | (in_window & (ps >= g_tok))))
+                visible = (visible[:, None, None]           # [B,1,1,T,S]
+                           & leaf("valid")[:, None, None, None, :])
 
             # grouped attention: query heads contract directly against the
-            # un-repeated KV cache ([B, max, Hkv, D] stays in place — no
-            # [B, max, H, D] repeat materializes per step)
+            # un-repeated KV cache ([B, S, Hkv, D] stays in place — no
+            # [B, S, H, D] repeat materializes per step)
             G = H // Hkv
             qg = q.reshape(B, T, Hkv, G, D)
             scale = 1.0 / np.sqrt(D)
@@ -685,8 +625,13 @@ class CausalSelfAttention(nn.Module):
                     att = att + (slopes[:, :, None, None]
                                  * k_pos[None].astype(att.dtype))
                 att = jnp.where(visible, att, jnp.finfo(att.dtype).min)
+                # ring, NaN-safe: an all-pad chunk row (ragged left-padded
+                # batch) has an empty visible set; its output is masked
+                # out later but must not produce NaN
                 att = jax.nn.softmax(
-                    att.astype(jnp.float32), axis=-1).astype(cfg.dtype)
+                    att.astype(jnp.float32), axis=-1,
+                    where=None if ring is None else visible
+                ).astype(cfg.dtype)
                 y = jnp.einsum("bhgqk,bkhd->bqhgd", att, v_all)
             y = y.reshape(B, T, C)
             return nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
@@ -853,13 +798,15 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, mask=None, segment_ids=None, positions=None,
-                 deterministic=True, decode=False, pld_keep=None):
+                 deterministic=True, decode=False, pld_keep=None,
+                 cache_layer=None):
         cfg = self.config
         x_in = x
         a = CausalSelfAttention(cfg, name="attn")(
             _norm(cfg, "ln_1")(x),
             mask=mask, segment_ids=segment_ids, positions=positions,
-            deterministic=deterministic, decode=decode)
+            deterministic=deterministic, decode=decode,
+            cache_layer=cache_layer)
         if cfg.parallel_residual:
             # GPT-J/NeoX form: attention and MLP both read the pre-residual
             # stream; GPT-J's single shared LN is expressed by loading
@@ -1055,6 +1002,19 @@ class ScannedBlocks(nn.Module):
         cfg = self.config
         use_pld = (cfg.stochastic_mode and pld_theta is not None
                    and not deterministic)
+        # A decode call on a cache that exists carries it through the loop
+        # as ONE stacked [n_layer, ...] buffer per leaf, which each turn
+        # updates in place at its own layer index (CausalSelfAttention's
+        # cache_layer). Scanned over like the params, every turn would
+        # slice its layer's whole leaf out of the stack and write it back
+        # whole: at 1.3B with 16 lanes that was 73% of the decode step's
+        # device time (PERF.md, PR 25). A carried collection must have one
+        # structure entering and leaving a turn, so the apply that CREATES
+        # the cache (a prefill) takes the scanned form: each turn makes its
+        # layer's leaves, at positions XLA knows statically, and the loop
+        # stacks them, which measured faster than zeros made before the
+        # loop and then carried (same entry of PERF.md).
+        carried = decode and self.has_variable("cache", "block")
 
         def call_block(block, x, mask, segment_ids, positions, layer_idx):
             # deterministic/decode ride the closure so remat never sees
@@ -1063,7 +1023,8 @@ class ScannedBlocks(nn.Module):
                                              pld_theta) if use_pld else None)
             return block(x, mask=mask, segment_ids=segment_ids,
                          positions=positions, deterministic=deterministic,
-                         decode=decode, pld_keep=pld_keep)
+                         decode=decode, pld_keep=pld_keep,
+                         cache_layer=layer_idx if carried else None)
 
         if cfg.remat:
             call_block = nn.remat(call_block, prevent_cse=False,
@@ -1091,7 +1052,9 @@ class ScannedBlocks(nn.Module):
 
         scanned = nn.scan(
             body,
-            variable_axes={"params": 0, "cache": 0},
+            variable_axes={"params": 0} if carried
+            else {"params": 0, "cache": 0},
+            variable_carry="cache" if carried else False,
             split_rngs={"params": True, "dropout": True, "gating": True},
             in_axes=0,
             length=cfg.n_layer,

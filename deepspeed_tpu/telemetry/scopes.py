@@ -40,8 +40,13 @@ SCOPE_KV_CACHE_WRITE = "kv_cache_write"
 SCOPE_KV_CACHE_READ = "kv_cache_read"
 SCOPE_SAMPLE = "sample"
 # not a named scope: the tag of an instruction that no scope above owns and
-# whose result is a whole KV-cache leaf (XLA's own copies of a loop's carry,
-# the slices and updates of the stacked cache around the layer scan)
+# whose result is a whole KV-cache leaf, stacked or one layer's: a copy XLA
+# makes of a loop's carry, a layer's slice of the stacked cache that did
+# not fuse into its reader. The decode step's own row update also has a
+# whole (stacked) leaf as its result, in place; it is ``kv_cache_write``'s
+# and keeps that name. Since the layer loop carries the cache (PR 25) a
+# serving program should have no time under this tag: what shows up here
+# is a whole leaf being moved again.
 SCOPE_KV_CACHE_CARRY = "kv_cache_carry"
 # JAX's own name-stack component of a rematerialised (recomputed) operation;
 # ``checkpoint`` alone is also on the backward pass of a checkpointed region

@@ -17,35 +17,164 @@ def _cfg(**kw):
     return GPTConfig(**base)
 
 
+_WINDOW = {"mode": "local_sliding_window", "block": 16,
+           "num_sliding_window_blocks": 3}
+
+# name -> (GPTConfig overrides, sparse layout, prompt length, its bucket,
+# decode steps)
+_DECODE_VARIANTS = {
+    "dense": ({}, None, 8, 8, 7),
+    "gqa": (dict(n_kv_head=2), None, 8, 8, 7),
+    "rotary": (dict(rotary=True, learned_positions=False), None, 8, 8, 7),
+    "alibi": (dict(alibi=True, learned_positions=False), None, 8, 8, 7),
+    # learned positions count VALID predecessors: 5 tokens left-padded to 8
+    "wpe_ragged": ({}, None, 5, 8, 7),
+    "int8_kv": (dict(kv_cache_dtype="int8", rotary=True,
+                     learned_positions=False), None, 8, 8, 7),
+    # a ring of 32 slots, filled by the prompt and wrapped by the decode;
+    # 32 + 16 generated = 48 = 3 layout blocks, the least the training
+    # sparse forward (the reference) takes
+    "ring": (dict(n_positions=256), _WINDOW, 32, 32, 15),
+    "unscanned": (dict(scan_layers=False), None, 8, 8, 7),
+}
+
+
+def _decode_model(variant, **more):
+    kw, sparse, n_prompt, bucket, k = _DECODE_VARIANTS[variant]
+    model = GPT(_cfg(**kw, **more))
+    if sparse is not None:
+        from deepspeed_tpu.ops.sparse_attention.sparse_attention_utils \
+            import apply_sparse_attention
+
+        model = apply_sparse_attention(model, sparse)
+    return model, n_prompt, bucket, k
+
+
 class TestKVCacheDecode:
-    @pytest.mark.parametrize("scan_layers", [True, False])
-    def test_decode_matches_full_forward(self, scan_layers):
-        """Prefill + stepwise decode logits must equal the dense forward."""
-        cfg = _cfg(scan_layers=scan_layers)
-        model = GPT(cfg)
+    @pytest.mark.parametrize("variant", sorted(_DECODE_VARIANTS))
+    def test_decode_matches_full_forward(self, variant):
+        """``k`` steps of ``jit_decode_k`` after a prefill give the tokens
+        and the last-position logits of the cache-free forward of the
+        growing sequence; and a ``[1, ...]`` prefilled cache spliced into
+        lane 2 of a 4-lane cache decodes there as it does alone."""
+        from deepspeed_tpu.inference.scheduler import \
+            ContinuousBatchingScheduler
+
+        model, n_prompt, bucket, k = _decode_model(variant)
+        eng = deepspeed_tpu.init_inference(model, dtype="fp32", seed=0)
+        sched = ContinuousBatchingScheduler(eng, slots=4,
+                                            prompt_bucket=bucket)
+        sched._ensure_compiled()
         rng = np.random.RandomState(0)
-        ids = jnp.asarray(rng.randint(0, 128, size=(2, 10)), jnp.int32)
-        params = model.init(jax.random.PRNGKey(0), ids,
-                            deterministic=True)["params"]
+        prompt = rng.randint(1, 128, size=n_prompt).astype(np.int32)
+        ids = np.zeros((1, bucket), np.int32)
+        mask = np.zeros((1, bucket), bool)
+        ids[0, bucket - n_prompt:] = prompt      # left-padded, as admitted
+        mask[0, bucket - n_prompt:] = True
+        logits, sub = eng._prefill_fn(eng.params, jnp.asarray(ids),
+                                      jnp.asarray(mask))
+        tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        key, temp = jax.random.PRNGKey(0), jnp.float32(0.0)
 
-        full_logits = model.apply({"params": params}, ids, deterministic=True)
+        lanes = sched._splice(sched._empty_cache(), sched._copy_tree(sub), 2)
+        toks, last, cache, _ = eng._decode_k_fn(
+            eng.params, tok0, sub, key, temp, k)
+        got = np.concatenate([np.asarray(tok0), np.asarray(toks)[0]])
 
-        # prefill on the first 6 tokens, then decode 4 one by one
-        pre, cache = model.apply({"params": params}, ids[:, :6],
-                                 deterministic=True, decode=True,
-                                 mutable=["cache"])
-        cache = cache["cache"]
-        np.testing.assert_allclose(np.asarray(pre[:, -1]),
-                                   np.asarray(full_logits[:, 5]),
-                                   atol=2e-4, rtol=1e-3)
-        for t in range(6, 10):
-            step_logits, cache = model.apply(
-                {"params": params, "cache": cache}, ids[:, t:t + 1],
-                deterministic=True, decode=True, mutable=["cache"])
-            cache = cache["cache"]
-            np.testing.assert_allclose(
-                np.asarray(step_logits[:, 0]), np.asarray(full_logits[:, t]),
-                atol=2e-4, rtol=1e-3, err_msg=f"position {t}")
+        # the cache-free forward of prompt + generated tokens: under
+        # causal attention its position t is the forward of the sequence
+        # grown to t + 1 tokens
+        seq = np.concatenate([prompt, got])
+        full = np.asarray(model.apply(
+            {"params": eng.params}, jnp.asarray(seq[None]),
+            deterministic=True))[0]
+        ref = full[n_prompt - 1:]                # [k + 2, V]
+        step, _ = model.apply(
+            {"params": eng.params, "cache": cache}, last[:, None],
+            deterministic=True, decode=True, mutable=["cache"])
+        step = np.asarray(step)[0, -1]
+        if variant == "int8_kv":
+            # quantization error is real and bounded (the envelope of
+            # test_serving_disagg's int8 parity): a token may differ only
+            # where the reference's top-2 margin is inside it
+            err = np.abs(step - ref[-1]).max()
+            assert err < 0.05 * np.abs(ref).max()
+            top2 = np.sort(ref[:-1], axis=-1)
+            flips = ref[:-1].argmax(-1) != got
+            assert ((top2[:, -1] - top2[:, -2])[flips] < 2 * err).all()
+        else:
+            np.testing.assert_array_equal(got, ref[:-1].argmax(-1))
+            np.testing.assert_allclose(step, ref[-1], atol=2e-4, rtol=1e-3)
+
+        tok4 = jnp.zeros((4,), jnp.int32).at[2].set(tok0[0])
+        toks4, _, _, _ = eng._decode_k_fn(
+            eng.params, tok4, lanes, key, temp, k)
+        np.testing.assert_array_equal(np.asarray(toks4)[2], got[1:])
+
+    @pytest.mark.parametrize("variant", ["dense_bf16", "int8_kv", "ring"])
+    def test_layer_loop_carries_the_cache_in_place(self, variant):
+        """``jit_decode_k`` of a scanned model: a cache leaf crosses the
+        layer loop (and the loop over ``k``) in the carry only, never as a
+        scanned input or a stacked output, which would slice each layer's
+        whole leaf out and write it back; and the compiled program
+        aliases every cache leaf to its output."""
+        import re
+
+        from deepspeed_tpu.inference.engine import kv_leaf_shapes
+        from deepspeed_tpu.inference.scheduler import \
+            ContinuousBatchingScheduler
+
+        model, _, bucket, _ = _decode_model(
+            "dense" if variant == "dense_bf16" else variant, n_layer=3)
+        eng = deepspeed_tpu.init_inference(
+            model, dtype="bf16" if variant == "dense_bf16" else "fp32")
+        sched = ContinuousBatchingScheduler(eng, slots=3,
+                                            prompt_bucket=bucket)
+        sched._ensure_compiled()
+        cache = sched._cache_shapes()
+        n_layer = model.config.n_layer
+        stacked = jax.tree.leaves(cache["h"])
+        assert all(leaf.shape[0] == n_layer for leaf in stacked)
+        whole = kv_leaf_shapes(cache)
+        args = (eng.params, jnp.zeros((3,), jnp.int32), cache,
+                jax.random.PRNGKey(0), jnp.float32(0.0), 2)
+        decode_k = eng._decode_k_fn.fn
+
+        def scans(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "scan":
+                    yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from scans(sub)
+
+        loops = list(scans(decode_k.trace(*args).jaxpr.jaxpr))
+        assert sorted(e.params["length"] for e in loops) == [2, n_layer]
+        for eqn in loops:
+            first_x = eqn.params["num_consts"] + eqn.params["num_carry"]
+            carried = {v.aval.shape for v in eqn.invars[
+                eqn.params["num_consts"]:first_x]}
+            crossing = {v.aval.shape for v in eqn.invars[first_x:]} | {
+                v.aval.shape for v in eqn.outvars[eqn.params["num_carry"]:]}
+            assert not crossing & whole, crossing & whole
+            assert {leaf.shape for leaf in stacked} <= carried
+
+        text = decode_k.lower(*args).compile().as_text()
+        header = text[:text.index("\n")]
+        aliased = {int(n) for n in re.findall(
+            r"\{[\d, ]*\}: \((\d+), ", header[header.index(
+                "input_output_alias="):])}
+        params = re.findall(
+            r"= (\w+\[[\d,]*\])\S* parameter\((\d+)\)",
+            text[text.index("\nENTRY "):])
+        aliased_shapes = sorted(s for s, n in params if int(n) in aliased)
+        # every donated cache leaf is an aliased parameter, and nothing
+        # else is
+        want = sorted("%s[%s]" % (
+            {"bfloat16": "bf16", "float32": "f32", "int32": "s32",
+             "int8": "s8", "bool": "pred"}[leaf.dtype.name],
+            ",".join(map(str, leaf.shape)))
+            for leaf in jax.tree.leaves(cache))
+        assert aliased_shapes == want
 
 
 class TestInferenceEngine:
