@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.moe.layer import MOE_STATS
 from deepspeed_tpu.telemetry.scopes import (
     SCOPE_ATTN_CORE,
     SCOPE_KV_CACHE_READ,
@@ -66,6 +67,8 @@ class GPTConfig:
     lm_head_bias: bool = False         # GPT-J's untied head carries a bias
     parallel_residual: bool = False    # x + attn(ln_1 x) + mlp(ln_2 x)
     n_kv_head: Optional[int] = None    # grouped-query attention; None = MHA
+    qk_norm: bool = False              # RMSNorm over the whole q and k
+                                       # projections, before rotary (OLMoE)
     remat: bool = False
     # "full" recomputes everything (min memory); "selective" saves matmul
     # outputs and recomputes only elementwise ops — the TPU sweet spot:
@@ -178,6 +181,11 @@ class GPTConfig:
     moe_noisy_gate_policy: Optional[str] = None
     moe_use_rts: bool = True
     moe_gated_experts: bool = False  # SwiGLU experts (Mixtral-style)
+    # the dropless path (moe/layer.py: moe_top_k > 2 or not
+    # moe_drop_tokens): whether the k weights are divided by their sum
+    moe_norm_topk_prob: bool = False
+    # the coefficient of the router z-loss, beside moe_aux_loss_coef
+    moe_z_loss_coef: float = 0.0
 
     def __post_init__(self):
         if self.sequence_parallel not in ("none", "ring", "ulysses"):
@@ -452,6 +460,14 @@ class CausalSelfAttention(nn.Module):
         q = qkv[..., : H * D].reshape(B, T, H, D)
         k = qkv[..., H * D:(H + Hkv) * D].reshape(B, T, Hkv, D)
         v = qkv[..., (H + Hkv) * D:].reshape(B, T, Hkv, D)
+        if cfg.qk_norm:
+            def whole(t, name):
+                return nn.RMSNorm(
+                    epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, name=name)(
+                        t.reshape(B, T, -1)).reshape(t.shape)
+
+            q, k = whole(q, "q_norm"), whole(k, "k_norm")
 
         def rope(t, positions):
             from deepspeed_tpu.ops.rotary import apply_rotary_pos_emb
@@ -792,7 +808,8 @@ class MLP(nn.Module):
 class Block(nn.Module):
     """Pre-LN transformer block; MLP becomes an expert-parallel MoE layer when
     the config asks for experts (reference moe/layer.py MoE drop-in).
-    Returns ``(x, l_aux)`` — l_aux is 0 for the dense path."""
+    Returns ``(x, l_aux)`` — l_aux is the layer's auxiliary losses with
+    their coefficients, 0 for the dense path."""
 
     config: GPTConfig
 
@@ -818,7 +835,7 @@ class Block(nn.Module):
         if cfg.is_moe:
             from deepspeed_tpu.moe.layer import MoE
 
-            y, l_aux, _ = MoE(
+            y, l_aux, l_z, _ = MoE(
                 d_model=cfg.n_embd,
                 d_hidden=cfg.ffn_dim,
                 num_experts=cfg.moe_num_experts,
@@ -830,10 +847,14 @@ class Block(nn.Module):
                 drop_tokens=cfg.moe_drop_tokens,
                 use_rts=cfg.moe_use_rts,
                 gated_experts=cfg.moe_gated_experts,
+                norm_topk_prob=cfg.moe_norm_topk_prob,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 name="mlp",
             )(h, deterministic=deterministic)
+            l_aux = cfg.moe_aux_loss_coef * l_aux
+            if cfg.moe_z_loss_coef:
+                l_aux = l_aux + cfg.moe_z_loss_coef * l_z
         else:
             y = MLP(cfg, name="mlp")(h, deterministic=deterministic)
             l_aux = jnp.float32(0.0)
@@ -1052,8 +1073,8 @@ class ScannedBlocks(nn.Module):
 
         scanned = nn.scan(
             body,
-            variable_axes={"params": 0} if carried
-            else {"params": 0, "cache": 0},
+            variable_axes={"params": 0, MOE_STATS: 0} if carried
+            else {"params": 0, "cache": 0, MOE_STATS: 0},
             variable_carry="cache" if carried else False,
             split_rngs={"params": True, "dropout": True, "gating": True},
             in_axes=0,
@@ -1247,9 +1268,10 @@ class GPT(nn.Module):
                 loss = cross_entropy_loss(logits, labels, attention_mask,
                                           segment_ids)
         if cfg.is_moe:
-            # load-balance aux loss, averaged over layers (reference adds the
-            # per-MoE-layer l_aux into the training loss with a coefficient)
-            loss = loss + cfg.moe_aux_loss_coef * l_aux / cfg.n_layer
+            # the blocks' auxiliary losses (load balance and router z-loss,
+            # each with its coefficient), averaged over layers (reference
+            # adds the per-MoE-layer l_aux into the training loss)
+            loss = loss + l_aux / cfg.n_layer
         return loss
 
 

@@ -10,11 +10,16 @@ loop becomes a batched einsum on the MXU.
 from typing import Any, Callable
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 
 class StackedExperts(nn.Module):
-    """[E, C, M] -> [E, C, M] two-layer FFN, vectorized over experts.
+    """[E, C, M] -> [E, C, M] two-layer FFN, vectorized over experts; or,
+    with ``group_sizes`` ([E], summing to R), [R, M] -> [R, M] over rows
+    sorted by expert: each einsum becomes one grouped matmul over the
+    ragged groups (``jax.lax.ragged_dot``), so no row is padding and none
+    is dropped.
 
     Param shapes carry the expert axis first (``wi: [E, M, H]``,
     ``wo: [E, H, M]``) so expert-parallel sharding rules can address it
@@ -35,28 +40,42 @@ class StackedExperts(nn.Module):
     use_bias: bool = True
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, group_sizes=None):
         E, M, H = self.num_experts, self.d_model, self.d_hidden
+        if group_sizes is None:
+            def matmul(a, w):
+                return jnp.einsum("ecm,emh->ech", a, w)
+
+            def per_expert(b):
+                return b[:, None, :]
+        else:
+            def matmul(a, w):
+                return jax.lax.ragged_dot(a, w, group_sizes)
+
+            def per_expert(b):
+                return jnp.repeat(b, group_sizes, axis=0,
+                                  total_repeat_length=x.shape[0])
+
         wi = self.param("wi", nn.initializers.lecun_normal(),
                         (E, M, H), self.param_dtype)
         wo = self.param("wo", nn.initializers.lecun_normal(),
                         (E, H, M), self.param_dtype)
         x = x.astype(self.dtype)
-        h = jnp.einsum("ecm,emh->ech", x, wi.astype(self.dtype))
+        h = matmul(x, wi.astype(self.dtype))
         if self.use_bias:
             bi = self.param("bi", nn.initializers.zeros, (E, H),
                             self.param_dtype)
-            h = h + bi[:, None, :].astype(self.dtype)
+            h = h + per_expert(bi).astype(self.dtype)
         if self.gated:
             wg = self.param("wg", nn.initializers.lecun_normal(),
                             (E, M, H), self.param_dtype)
-            g = jnp.einsum("ecm,emh->ech", x, wg.astype(self.dtype))
+            g = matmul(x, wg.astype(self.dtype))
             h = self.activation(g) * h
         else:
             h = self.activation(h)
-        y = jnp.einsum("ech,ehm->ecm", h, wo.astype(self.dtype))
+        y = matmul(h, wo.astype(self.dtype))
         if self.use_bias:
             bo = self.param("bo", nn.initializers.zeros, (E, M),
                             self.param_dtype)
-            y = y + bo[:, None, :].astype(self.dtype)
+            y = y + per_expert(bo).astype(self.dtype)
         return y

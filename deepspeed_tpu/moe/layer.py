@@ -12,9 +12,19 @@ MOELayer + Experts with expert-parallel all-to-all) re-designed for SPMD:
   no special handling: the global-view jit program reduces each param over
   exactly the axes it is replicated on.
 
-The layer returns ``(y, l_aux, exp_counts)``; the model adds
-``aux_coef * l_aux`` to its loss (reference stores l_aux on the module and
-the engine collects it).
+The layer returns ``(y, l_aux, l_z, exp_counts)``; the model adds
+``aux_coef * l_aux`` (and ``z_coef * l_z``, the router z-loss) to its loss
+(reference stores l_aux on the module and the engine collects it).
+
+Two ways from tokens to experts. With a capacity (``k <= 2`` and
+``drop_tokens``): the one-hot ``[tokens, experts, capacity]`` dispatch
+above, which drops what overflows. Dropless (``drop_tokens=False`` or
+``k > 2``): softmax, top-k, a sort of the tokens * k (token, expert) pairs
+by expert, a gather, one grouped matmul per projection over the ragged
+groups, and the weighted sum back (moe/sharded_moe.py); no token is left
+out under any load, and no tensor grows with experts * capacity. On an
+``ep`` mesh the expert tensors keep their ``ep`` sharding; the sorted rows
+are not annotated yet (no dropless configuration runs expert-parallel).
 """
 
 from typing import Any, Optional
@@ -26,10 +36,26 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from deepspeed_tpu.moe.experts import StackedExperts
 from deepspeed_tpu.moe.sharded_moe import (
+    combine_rows,
     combine_tokens,
+    dispatch_rows,
     dispatch_tokens,
+    router_z_loss,
+    rows_computed,
+    sort_by_expert,
     topk_gating,
+    topk_routing,
 )
+from deepspeed_tpu.telemetry.scopes import (
+    SCOPE_MOE_COMBINE,
+    SCOPE_MOE_DISPATCH,
+    SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_ROUTER,
+)
+
+# the collection the layer sows its routing counts into; nothing is written
+# unless a caller makes it mutable (moe/utils.py publish_expert_load)
+MOE_STATS = "moe_stats"
 
 
 def _ep_constraint(x, ndim_spec):
@@ -63,6 +89,39 @@ class MoE(nn.Module):
     param_dtype: Any = jnp.float32
     gated_experts: bool = False      # SwiGLU experts (Mixtral-style)
     expert_activation: Any = None    # defaults: gelu, or silu when gated
+    # dropless path only: divide the k weights by their sum
+    norm_topk_prob: bool = False
+
+    @property
+    def dropless(self) -> bool:
+        return self.k > 2 or not self.drop_tokens
+
+    def _experts(self):
+        act = self.expert_activation or (
+            nn.silu if self.gated_experts else nn.gelu)
+        return StackedExperts(
+            num_experts=self.num_experts,
+            d_model=self.d_model,
+            d_hidden=self.d_hidden,
+            dtype=self.dtype,
+            param_dtype=self.param_dtype,
+            activation=act,
+            gated=self.gated_experts,
+            use_bias=not self.gated_experts,
+            name="experts",
+        )
+
+    def _count(self, **counters):
+        """Sow the routing counters (moe/utils.py reads them): ``routed``,
+        the (token, expert) pairs the router asked for; ``computed``
+        [experts], those whose expert output exists, counted from the
+        dispatch (one-hot path) or the grouped matmuls' output (dropless)
+        and not from the routing; ``chosen`` [tokens, k] on the dropless
+        path. (``init`` makes every collection mutable and would return
+        them beside the parameters.)"""
+        if not self.is_initializing():
+            for name, value in counters.items():
+                self.sow(MOE_STATS, name, value)
 
     @nn.compact
     def __call__(self, x, *, deterministic: bool = True):
@@ -70,11 +129,41 @@ class MoE(nn.Module):
         d_model = orig_shape[-1]
         tokens = x.reshape(-1, d_model)
 
-        # gate in fp32 (reference TopKGate casts input to float, wg fp32)
-        gate_logits = nn.Dense(
+        # gate in fp32 (reference TopKGate casts input to float, wg fp32),
+        # and a true float32 product: the TPU's default rounds float32
+        # operands to bfloat16, which would undo both. It matters little
+        # (the bfloat16 activations flip more choices than the product
+        # does: PERF.md, section 6, PR 27) and costs nothing at this width
+        gate = nn.Dense(
             self.num_experts, use_bias=False, dtype=jnp.float32,
             param_dtype=jnp.float32, name="gate",
-        )(tokens.astype(jnp.float32))
+            precision=jax.lax.Precision.HIGHEST)
+        routed = jnp.int32(tokens.shape[0] * self.k)
+
+        if self.dropless:
+            if self.noisy_gate_policy is not None:
+                raise ValueError(
+                    "the dropless path routes deterministically; "
+                    f"noisy_gate_policy={self.noisy_gate_policy!r} exists "
+                    "only with a capacity (k <= 2, drop_tokens=True)")
+            with jax.named_scope(SCOPE_MOE_ROUTER):
+                route = topk_routing(gate(tokens.astype(jnp.float32)),
+                                     self.k, self.norm_topk_prob)
+            with jax.named_scope(SCOPE_MOE_DISPATCH):
+                order, inverse = sort_by_expert(route.experts)
+                rows = dispatch_rows(tokens, order, inverse, self.k)
+            with jax.named_scope(SCOPE_MOE_EXPERTS):
+                rows = self._experts()(rows, route.exp_counts)
+            with jax.named_scope(SCOPE_MOE_COMBINE):
+                y = combine_rows(rows, route.weights, order, inverse,
+                                 dtype=x.dtype)
+            self._count(routed=routed, chosen=route.experts,
+                        computed=rows_computed(rows, route.experts, order,
+                                               self.num_experts))
+            return (y.reshape(orig_shape), route.l_aux, route.l_z,
+                    route.exp_counts)
+
+        gate_logits = gate(tokens.astype(jnp.float32))
 
         rng = None
         if not deterministic and self.has_rng("gating"):
@@ -94,22 +183,14 @@ class MoE(nn.Module):
 
         dispatched = dispatch_tokens(gout.dispatch_mask, tokens)  # [E,C,M]
         dispatched = _ep_constraint(dispatched, ("ep", None, None))
-        act = self.expert_activation or (
-            nn.silu if self.gated_experts else nn.gelu)
-        expert_out = StackedExperts(
-            num_experts=self.num_experts,
-            d_model=self.d_model,
-            d_hidden=self.d_hidden,
-            dtype=self.dtype,
-            param_dtype=self.param_dtype,
-            activation=act,
-            gated=self.gated_experts,
-            use_bias=not self.gated_experts,
-            name="experts",
-        )(dispatched)
+        expert_out = self._experts()(dispatched)
         expert_out = _ep_constraint(expert_out, ("ep", None, None))
         y = combine_tokens(gout.combine_weights, expert_out, dtype=x.dtype)
-        return y.reshape(orig_shape), gout.l_aux, gout.exp_counts
+        # pairs that found a slot, per expert
+        self._count(routed=routed, computed=jnp.sum(
+            gout.dispatch_mask, axis=(0, 2), dtype=jnp.int32))
+        return (y.reshape(orig_shape), gout.l_aux,
+                router_z_loss(gate_logits), gout.exp_counts)
 
 
 def expert_axis(path: str, ndim: int) -> Optional[int]:

@@ -19,6 +19,7 @@ TPU re-design notes:
   argmax/top-k index paths are non-differentiable in both.
 """
 
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -202,7 +203,9 @@ def topk_gating(logits, k: int, **kwargs) -> GatingOutput:
                     "(top-1-only option, see reference sharded_moe.py:278)"
                 )
         return top2_gating(logits, **kwargs)
-    raise ValueError(f"only top-1 and top-2 gating are supported, got k={k}")
+    raise ValueError(
+        f"capacity gating supports only top-1 and top-2, got k={k}; "
+        "any k routes droplessly through topk_routing + sort_by_expert")
 
 
 def dispatch_tokens(dispatch_mask: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
@@ -219,3 +222,125 @@ def combine_tokens(combine_weights: jnp.ndarray, expert_out: jnp.ndarray,
         expert_out.astype(combine_weights.dtype),
     )
     return y.astype(dtype) if dtype is not None else y
+
+
+# ---------------------------------------------------------------------------
+# dropless routing: sort the (token, expert) pairs by expert, gather their
+# rows, one grouped matmul per projection over the ragged groups
+# (moe/experts.py), weighted sum back. No capacity: every pair is computed,
+# whatever the load per expert, and every shape is static (tokens * k rows).
+# ---------------------------------------------------------------------------
+class RoutingOutput(NamedTuple):
+    l_aux: jnp.ndarray       # scalar load-balancing loss
+    l_z: jnp.ndarray         # scalar router z-loss (router_z_loss)
+    weights: jnp.ndarray     # [tokens, k] float32 combine weights
+    experts: jnp.ndarray     # [tokens, k] int32 chosen experts
+    exp_counts: jnp.ndarray  # [experts] int32 pairs routed per expert
+
+
+def router_z_loss(logits: jnp.ndarray) -> jnp.ndarray:
+    """The mean squared log-partition of the router logits (ST-MoE's
+    z-loss): it keeps the logits small, whatever the path that routes."""
+    return jnp.mean(jnp.square(
+        jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)))
+
+
+def topk_routing(logits: jnp.ndarray, k: int,
+                 renormalize: bool = False) -> RoutingOutput:
+    """Softmax over all experts, then the k largest probabilities per token
+    (any k). ``renormalize`` divides the k weights by their sum
+    (``norm_topk_prob``); without it they are the softmax's own values and
+    sum to less than one.
+
+    ``l_aux`` is the load-balancing loss ``E * sum_i f_i * P_i`` with
+    ``f_i`` the share of the tokens * k pairs routed to expert i and ``P_i``
+    the mean probability of expert i (1 when perfectly balanced, and
+    ``top1_gating``'s at k = 1); ``l_z`` is :func:`router_z_loss`.
+    Gradients flow through the probabilities; the choice of experts is not
+    differentiable."""
+    logits = logits.astype(jnp.float32)
+    num_tokens, num_experts = logits.shape
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    if renormalize:
+        weights = weights / jnp.maximum(
+            jnp.sum(weights, axis=-1, keepdims=True),
+            jnp.finfo(jnp.float32).eps)
+    exp_counts = jnp.bincount(experts.reshape(-1), length=num_experts)
+    f = exp_counts.astype(jnp.float32) / (num_tokens * k)
+    l_aux = num_experts * jnp.sum(f * jnp.mean(probs, axis=0))
+    return RoutingOutput(l_aux, router_z_loss(logits), weights,
+                         experts.astype(jnp.int32),
+                         exp_counts.astype(jnp.int32))
+
+
+def sort_by_expert(experts: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(order, inverse)`` of the tokens * k (token, expert) pairs: row r
+    of the sorted layout holds pair ``order[r]`` (token ``order[r] // k``),
+    pair p sits at row ``inverse[p]``. Stable, so one expert's rows keep
+    their token order."""
+    order = jnp.argsort(experts.reshape(-1), stable=True).astype(jnp.int32)
+    return order, jnp.argsort(order).astype(jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def dispatch_rows(tokens, order, inverse, k):
+    """[T, M] -> [T * k, M]: the rows of the pairs in sorted order (a gather
+    by ``order // k``). Its transpose is a scatter-add of k rows into each
+    token; ``inverse`` turns that into a gather and a sum over k, which the
+    TPU does at memory speed where it serialises a scatter."""
+    return jnp.take(tokens, order // k, axis=0)
+
+
+def _dispatch_fwd(tokens, order, inverse, k):
+    return dispatch_rows(tokens, order, inverse, k), inverse
+
+
+def _dispatch_bwd(k, inverse, g):
+    pairs = jnp.take(g, inverse, axis=0).reshape(-1, k, g.shape[-1])
+    return (jnp.sum(pairs.astype(jnp.float32), axis=1).astype(g.dtype),
+            None, None)
+
+
+dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def unsort_rows(rows, order, inverse):
+    """[T * k, M] sorted rows -> pair order (row t * k + j is token t's
+    j-th expert): a permutation, so its transpose is the gather by
+    ``order``."""
+    return jnp.take(rows, inverse, axis=0)
+
+
+def _unsort_fwd(rows, order, inverse):
+    return unsort_rows(rows, order, inverse), order
+
+
+def _unsort_bwd(order, g):
+    return jnp.take(g, order, axis=0), None, None
+
+
+unsort_rows.defvjp(_unsort_fwd, _unsort_bwd)
+
+
+def combine_rows(rows, weights, order, inverse, dtype=None):
+    """The weighted scatter-add of the expert outputs back onto their
+    tokens, ``out[t] = sum_j weights[t, j] * rows[inverse[t * k + j]]``,
+    as a gather and a sum over k in float32."""
+    num_tokens, k = weights.shape
+    pairs = unsort_rows(rows, order, inverse).reshape(num_tokens, k, -1)
+    out = jnp.sum(pairs.astype(jnp.float32) * weights[..., None], axis=1)
+    return out.astype(dtype or rows.dtype)
+
+
+def rows_computed(rows, experts, order, num_experts):
+    """[experts] int32: per expert, the sorted rows that came out of the
+    grouped matmuls with an output. A row they skip (``group_sizes`` that
+    do not cover it) is zeros, which an expert's output for a real row is
+    not; so this counts what was computed from the output itself and not
+    from the routing that asked for it."""
+    written = jnp.any(rows != 0, axis=-1).astype(jnp.int32)
+    return jnp.bincount(experts.reshape(-1)[order], weights=written,
+                        length=num_experts)
+

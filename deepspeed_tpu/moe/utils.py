@@ -30,3 +30,55 @@ def split_moe_params(params) -> Tuple[Any, Any]:
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
     return select(True), select(False)
+
+
+# rank of one layer's value of each counter the MoE layer sows; whatever
+# leads it (a scan's layer axis, none for a layer on its own) is flattened
+_COUNTER_RANK = {"routed": 0, "computed": 1, "chosen": 2}
+
+
+def routing_stats(model, params, batch):
+    """What the MoE layers of ``model`` counted while routing ``batch``, by
+    counter name with a leading layer axis: ``routed`` [layers] (the
+    (token, expert) pairs each router asked for), ``computed`` [layers,
+    experts] (the pairs whose expert output exists, counted from the
+    dispatch or the experts' output) and, on the dropless path, ``chosen``
+    [layers, tokens, k] (each token's chosen experts). One forward program
+    of its own, off the step."""
+    import numpy as np
+    from flax.traverse_util import flatten_dict
+
+    from deepspeed_tpu.moe.layer import MOE_STATS
+
+    def forward(params, batch):
+        _, stats = model.apply({"params": params}, **batch,
+                               mutable=[MOE_STATS])
+        return stats[MOE_STATS]
+
+    out = {}
+    for path, (value,) in sorted(flatten_dict(
+            jax.jit(forward)(params, batch)).items()):
+        value = np.asarray(value)
+        per_layer = value.shape[value.ndim - _COUNTER_RANK[path[-1]]:]
+        out.setdefault(path[-1], []).append(value.reshape(-1, *per_layer))
+    return {name: np.concatenate(v) for name, v in out.items()}
+
+
+def publish_expert_load(model, params, batch):
+    """Route ``batch`` once through ``model`` with its MoE layers' counters
+    on (:func:`routing_stats`) and publish what they counted as one
+    ``moe.load`` event on the telemetry bus (also returned):
+    ``tokens_per_expert`` ([layers][experts] pairs each expert computed),
+    ``max_over_mean`` (the fullest expert's load over the mean load, the
+    largest over the layers; 1 is balanced) and ``tokens_dropped`` (pairs
+    the routers asked for less pairs computed, over all layers; the
+    dropless path computes every pair). A training loop calls it when it
+    wants to look, never per step."""
+    from deepspeed_tpu.telemetry import publish
+
+    stats = routing_stats(model, params, batch)
+    counts = stats["computed"]
+    return publish(
+        "moe.load", tokens_per_expert=counts.tolist(),
+        max_over_mean=float((counts.max(axis=1) / counts.mean(axis=1)).max()),
+        tokens_dropped=int(stats["routed"].sum() - counts.sum()))

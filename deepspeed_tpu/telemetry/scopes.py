@@ -39,6 +39,13 @@ SCOPE_ATTN_CORE = "attn_core"
 SCOPE_KV_CACHE_WRITE = "kv_cache_write"
 SCOPE_KV_CACHE_READ = "kv_cache_read"
 SCOPE_SAMPLE = "sample"
+# the dropless MoE layer (moe/layer.py): the router's matmul, softmax and
+# top-k; the sort of the (token, expert) pairs and the gather of their rows;
+# the grouped matmuls with the activation between; the weighted sum back
+SCOPE_MOE_ROUTER = "moe_router"
+SCOPE_MOE_DISPATCH = "moe_dispatch"
+SCOPE_MOE_EXPERTS = "moe_experts"
+SCOPE_MOE_COMBINE = "moe_combine"
 # not a named scope: the tag of an instruction that no scope above owns and
 # whose result is a whole KV-cache leaf, stacked or one layer's: a copy XLA
 # makes of a loop's carry, a layer's slice of the stacked cache that did
@@ -51,6 +58,15 @@ SCOPE_KV_CACHE_CARRY = "kv_cache_carry"
 # JAX's own name-stack component of a rematerialised (recomputed) operation;
 # ``checkpoint`` alone is also on the backward pass of a checkpointed region
 SCOPE_REMAT = "rematted_computation"
+
+# XLA's TPU compiler replaces a ragged dot by Mosaic calls of its own
+# (``ragged-dot-none.N`` and the small ``ragged-dot-metadata.N``) whose
+# ``op_name`` is that name alone, not the path of the line that asked for
+# the dot. The program has one such line, the experts' grouped matmul
+# (moe/experts.py, under ``moe_experts``), so an instruction with such a
+# bare name is given that scope; whether it ran forward, backward or
+# recomputed cannot be told from it.
+_RAGGED_DOT = "ragged-dot"
 
 _CARRY_FREE = frozenset((
     SCOPE_OPTIMIZER, SCOPE_GRAD_CAST, SCOPE_OVERFLOW_CHECK,
@@ -163,6 +179,8 @@ def instruction_scopes(text, carry_shapes=()):
     """``(program_name, {instruction: path})`` from one executable's
     ``as_text()``. A fusion takes its own ``op_name``; where it has none,
     the most common one among the instructions of its fused computation.
+    The compiler's own ragged-dot calls get ``moe_experts`` (see
+    ``_RAGGED_DOT``).
     An instruction that none of the program's named scopes owns and whose
     result has one of ``carry_shapes`` (tuples of ints) gets the
     ``kv_cache_carry`` component. An instruction with no scope at all is
@@ -177,6 +195,8 @@ def instruction_scopes(text, carry_shapes=()):
                 p for p in fused.get(callee, ()) if p)
             if inner:
                 path = inner.most_common(1)[0][0]
+        if path and path.startswith(_RAGGED_DOT) and "/" not in path:
+            path = "jit(%s)/%s/%s" % (module, SCOPE_MOE_EXPERTS, path)
         if dims in carry and opcode not in _CONTAINERS \
                 and not has_scope(path, *_CARRY_FREE):
             path = _tag_carry(path, module, opcode)

@@ -86,7 +86,7 @@ class TestMoELayer:
         layer = self._layer()
         x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 16))
         params = layer.init(jax.random.PRNGKey(1), x)
-        y, l_aux, counts = layer.apply(params, x)
+        y, l_aux, _, counts = layer.apply(params, x)
         assert y.shape == x.shape
         assert jnp.isfinite(y).all()
         assert counts.shape == (4,)
@@ -102,7 +102,7 @@ class TestMoELayer:
         ex = p["params"]["experts"]
         for k in ("wi", "wo", "bi", "bo"):
             ex[k] = jnp.broadcast_to(ex[k][:1], ex[k].shape)
-        y, _, _ = layer.apply(p, x)
+        y, _, _, _ = layer.apply(p, x)
 
         # dense reference with expert-0 weights
         h = jnp.einsum("btm,mh->bth", x, ex["wi"][0]) + ex["bi"][0]
@@ -124,7 +124,7 @@ class TestMoELayer:
         assert set(ex) == {"wi", "wg", "wo"}  # biasless, with a gate tensor
         for k in ex:
             ex[k] = jnp.broadcast_to(ex[k][:1], ex[k].shape)
-        y, _, _ = layer.apply(params, x)
+        y, _, _, _ = layer.apply(params, x)
 
         h = jnp.einsum("btm,mh->bth", x, ex["wi"][0])
         g = jnp.einsum("btm,mh->bth", x, ex["wg"][0])
@@ -139,7 +139,7 @@ class TestMoELayer:
         params = layer.init(jax.random.PRNGKey(1), x)
 
         def loss_fn(p):
-            y, l_aux, _ = layer.apply(p, x)
+            y, l_aux, _, _ = layer.apply(p, x)
             return jnp.sum(y ** 2) + 0.01 * l_aux
 
         grads = jax.grad(loss_fn)(params)
