@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.moe.layer import MOE_STATS
+from deepspeed_tpu.runtime.zero.gather import gather_tree, gathered_on_use
 from deepspeed_tpu.telemetry.scopes import (
     SCOPE_ATTN_CORE,
     SCOPE_KV_CACHE_READ,
@@ -968,6 +969,23 @@ def _maybe_quantized_block(block_cls, cfg):
                                       dtype=cfg.dtype))
 
 
+# The one leaf of a block that its consumer reads as stored: the MoE router
+# multiplies in float32 whatever the compute dtype (moe/layer.py).
+_GATHERED_AS_STORED = ("mlp/gate/kernel",)
+
+
+def _maybe_gathered_block(block_cls, cfg, path, stacked=None):
+    """Under a ZeRO-3 step program over ``fsdp > 1`` (the engine's
+    ``gather_context``, read at trace time), ``block_cls`` with one layer's
+    weights cast and all-gathered where the layer reads them and their
+    gradients reduce-scattered back (runtime/zero/gather.py); under
+    ``nn.remat`` the backward pass gathers again, which is ZeRO-3's own
+    re-gather. Anywhere else: ``block_cls`` itself."""
+    return gathered_on_use(block_cls, path, cfg.dtype, stacked=stacked,
+                           uses=2 if cfg.remat else 1,
+                           keep_dtype=_GATHERED_AS_STORED)
+
+
 def pld_keep_probability(layer_idx, n_layer: int, theta):
     """Depth schedule for PLD stochastic depth: layer i survives with
     ``p_i = 1 - (i/L)(1 - theta)`` — deeper layers drop more. Shared by
@@ -1058,7 +1076,11 @@ class ScannedBlocks(nn.Module):
                                   layer_idx)
             return (x, mask, segment_ids, positions), l_aux
 
-        block_cls = _maybe_quantized_block(Block, cfg)
+        # stored form outermost: streamed in from the host, gathered over
+        # fsdp, dequantised
+        block_cls = _maybe_gathered_block(
+            _maybe_quantized_block(Block, cfg), cfg, self.path + ("block",),
+            stacked=cfg.n_layer)
         if cfg.param_offload:
             # ZeRO-Infinity param tier: the scan's per-iteration slice of
             # the (host-resident) layer stack is copied into HBM right
@@ -1142,14 +1164,19 @@ class GPT(nn.Module):
                  decode=False, pld_theta=None):
         cfg = self.config
         B, T = input_ids.shape
-        wte = VocabEmbed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype,
-                         param_dtype=cfg.param_dtype, name="wte")
+        # ZeRO-3 over fsdp > 1: the leaves outside the layer loop are
+        # gathered where this method reads them (runtime/zero/gather.py;
+        # nothing is wrapped anywhere else)
+        wte = gathered_on_use(VocabEmbed, self.path + ("wte",), cfg.dtype)(
+            cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name="wte")
         x = wte(input_ids)
         if cfg.embed_layernorm:  # BLOOM word_embeddings_layernorm
             x = _norm(cfg, "ln_embed")(x)
         if cfg.learned_positions:
-            wpe = nn.Embed(cfg.n_positions, cfg.n_embd, dtype=cfg.dtype,
-                           param_dtype=cfg.param_dtype, name="wpe")
+            wpe = gathered_on_use(nn.Embed, self.path + ("wpe",), cfg.dtype)(
+                cfg.n_positions, cfg.n_embd, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, name="wpe")
             if decode:
                 # per-sequence position counters tracked alongside the
                 # per-layer KV caches: with LEFT-padded ragged prompts the
@@ -1197,7 +1224,9 @@ class GPT(nn.Module):
             for i in range(cfg.n_layer):
                 keep = (pld_keep_probability(i, cfg.n_layer, pld_theta)
                         if use_pld else None)
-                x, aux_i = call_block(loop_block_cls(cfg, name=f"h_{i}"), x,
+                block_cls = _maybe_gathered_block(
+                    loop_block_cls, cfg, self.path + (f"h_{i}",))
+                x, aux_i = call_block(block_cls(cfg, name=f"h_{i}"), x,
                                       attention_mask, segment_ids, positions,
                                       keep)
                 l_aux = l_aux + aux_i
@@ -1208,13 +1237,16 @@ class GPT(nn.Module):
         # fp32 matmul here runs ~8x slower and is ~1/3 of the model's flops
         # at this vocab size)
         if cfg.tie_word_embeddings:
-            head_w = wte.embedding.astype(cfg.dtype)  # [V, C]
+            head_w = gather_tree(
+                wte.embedding, self.path + ("wte", "embedding"),
+                cfg.dtype, site="lm_head").astype(cfg.dtype)  # [V, C]
             head_dims = (((x.ndim - 1,), (1,)), ((), ()))
         else:
-            head_w = self.param(
+            head_w = gather_tree(self.param(
                 "lm_head",
                 nn.initializers.normal(0.02), (cfg.n_embd, cfg.vocab_size),
-                cfg.param_dtype).astype(cfg.dtype)    # [C, V]
+                cfg.param_dtype), self.path + ("lm_head",),
+                cfg.dtype).astype(cfg.dtype)    # [C, V]
             head_dims = (((x.ndim - 1,), (0,)), ((), ()))
         head_b = (self.param("lm_head_bias", nn.initializers.zeros,
                              (cfg.vocab_size,), cfg.param_dtype)

@@ -282,9 +282,11 @@ def shard_largest_dim_spec(
 
     This is the TPU-native analogue of ZeRO-3 flat-buffer partitioning
     (reference zero/partition_parameters.py:882): instead of flattening and
-    slicing bytes, we annotate a whole dimension and let XLA insert the
-    all-gather at use (and skip params below the persistence threshold,
-    mirroring stage3 param_persistence_threshold).
+    slicing bytes, we annotate a whole dimension (and skip params below
+    the persistence threshold, mirroring stage3
+    param_persistence_threshold). The annotation says where a shard lives,
+    not that the weight is gathered at its use: for stage-3 parameters
+    ``runtime/zero/gather.py`` constrains the use site.
     """
     if axis_size <= 1 or not shape:
         return PartitionSpec()

@@ -64,6 +64,7 @@ from deepspeed_tpu.runtime.optimizer import (
     build_optimizer,
     is_compressed_optimizer,
 )
+from deepspeed_tpu.runtime.zero.gather import gather_context
 from deepspeed_tpu.telemetry.scopes import (
     SCOPE_GRAD_CAST,
     SCOPE_GRAD_NORM_CLIP,
@@ -1354,12 +1355,13 @@ class DeepSpeedEngine:
             rng = jax.random.fold_in(rng, step)
 
             def loss_fn(p):
-                loss = model.apply(
-                    {"params": p}, **batch, deterministic=False,
-                    rngs={"dropout": rng,
-                          "gating": jax.random.fold_in(rng, 7)},
-                    **self._pld_model_kwargs(step // gas),
-                )
+                with gather_context(self.sharding_rules, "fwd_bwd"):
+                    loss = model.apply(
+                        {"params": p}, **batch, deterministic=False,
+                        rngs={"dropout": rng,
+                              "gating": jax.random.fold_in(rng, 7)},
+                        **self._pld_model_kwargs(step // gas),
+                    )
                 # loss scaled by 1/gas (reference engine.py:1789 -> :1596)
                 # and by the fp16 loss scale (loss_scaler.py)
                 return loss * (scale / gas), loss
@@ -1462,12 +1464,13 @@ class DeepSpeedEngine:
             rng = jax.random.fold_in(rng, step)
 
             def loss_fn(p):
-                loss = model.apply(
-                    {"params": p}, **batch, deterministic=False,
-                    rngs={"dropout": rng,
-                          "gating": jax.random.fold_in(rng, 7)},
-                    **self._pld_model_kwargs(step),
-                )
+                with gather_context(self.sharding_rules, "train_step"):
+                    loss = model.apply(
+                        {"params": p}, **batch, deterministic=False,
+                        rngs={"dropout": rng,
+                              "gating": jax.random.fold_in(rng, 7)},
+                        **self._pld_model_kwargs(step),
+                    )
                 return loss * ls_state.scale, loss
 
             grads, loss = jax.grad(loss_fn, has_aux=True)(params)
@@ -1518,7 +1521,9 @@ class DeepSpeedEngine:
         model = self.module
 
         def eval_fn(params, batch):
-            return model.apply({"params": params}, **batch, deterministic=True)
+            with gather_context(self.sharding_rules, "eval"):
+                return model.apply({"params": params}, **batch,
+                                   deterministic=True)
 
         return jax.jit(eval_fn)
 

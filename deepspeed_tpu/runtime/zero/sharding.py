@@ -17,11 +17,23 @@ stage      reference mechanism                       sharding expression
                                                      new params out)
 2          + gradient partitions via bucketed        + grad-accum buffer sharded
            reduce-scatter (stage_1_and_2.py:938)     over fsdp
-3          + param partitions, allgather-on-use,     + params sharded over fsdp;
-           prefetch coordinator                      XLA schedules per-layer
-           (partition_parameters.py:806,             all-gathers (the prefetch
-           partitioned_param_coordinator.py:237)     coordinator, for free)
+3          + param partitions, allgather-on-use,     + params sharded over fsdp,
+           prefetch coordinator                      and a constraint where a
+           (partition_parameters.py:806,             layer reads them (gather.py:
+           partitioned_param_coordinator.py:237)     all-gather in the loop body,
+                                                     reduce-scatter of the
+                                                     cotangent)
 =========  =======================================  =============================
+
+Stage 3 needs the second half. The annotation alone does not make XLA
+gather a weight: the shard is on a leaf's largest dimension, for an MLP
+kernel the feature dimension, and the partitioner of a plain GSPMD step
+read that as tensor parallelism and resharded the batch-sharded
+activations instead (five ``all-to-all`` a layer, 178 ms of a 782 ms step
+exposed on four v5e chips: PERF.md, PR 24). ``gather.py`` says at the use
+site what the stored sharding means; XLA then schedules the gathers and
+reduce-scatters under the layer's matmuls (27.7 ms exposed of 497 ms,
+PERF.md, PR 29). Prefetching layer i+1 under layer i is still not done.
 
 ``param_persistence_threshold`` (stage3, zero/config.py) maps to ``min_size``:
 small params stay replicated.

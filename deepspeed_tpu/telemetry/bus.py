@@ -57,6 +57,9 @@ KIND_SERVE_KV_TRANSFER = "serve.kv_transfer"
 KIND_SERVE_SPEC_ACCEPT = "serve.spec_accept"
 KIND_SHUTDOWN = "shutdown.graceful"
 KIND_ELASTIC_RESHARD = "elastic.reshard"
+# ZeRO-3's gather at the point of use (runtime/zero/gather.py): what a step
+# program gathers and reduce-scatters, published once when it is traced
+KIND_ZERO3_GATHER_PLAN = "zero3.gather_plan"
 # cluster health plane (runtime/health.py): peer liveness over the
 # out-of-band heartbeat mesh, step-time straggler detection, step-skew
 # desync, and SDC parameter-digest mismatches

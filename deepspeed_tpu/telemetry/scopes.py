@@ -46,6 +46,9 @@ SCOPE_MOE_ROUTER = "moe_router"
 SCOPE_MOE_DISPATCH = "moe_dispatch"
 SCOPE_MOE_EXPERTS = "moe_experts"
 SCOPE_MOE_COMBINE = "moe_combine"
+# ZeRO-3 (runtime/zero/gather.py): a layer's weights cast and gathered where
+# the layer reads them, and their cotangents reduce-scattered back
+SCOPE_ZERO3_GATHER = "zero3_gather"
 # not a named scope: the tag of an instruction that no scope above owns and
 # whose result is a whole KV-cache leaf, stacked or one layer's: a copy XLA
 # makes of a loop's carry, a layer's slice of the stacked cache that did
