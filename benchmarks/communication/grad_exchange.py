@@ -19,7 +19,7 @@ Accounting conventions (also in docs/observability.md):
   steps. The plain data path all-reduces into the replicated grad
   accumulator at every micro step (runtime/engine.py ``_fwd_bwd_fn``),
   so plain = gas x per_exchange; the compressed path ships worker grads
-  once at the boundary (``_compressed_apply_core``), so int8 = 1 x
+  once at the boundary (``runtime/grad_exchange.py`` ``update_core``), so int8 = 1 x
   per_exchange. This is the deployment-relevant ratio: at gas>=2 the
   int8 path is < 0.5x bf16 on the wire.
 

@@ -209,7 +209,7 @@ def compile_mode(extra, gas=2):
     app_hlo = engine._apply_fn.lower(
         engine._params, engine._opt_state, engine._acc_grads,
         engine._ls_state, engine._lr_factor_now()).compile().as_text()
-    plan = engine._bucket_plan
+    plan = engine._exchange.plan
     return {
         "bucket_count": plan.num_buckets if plan is not None else None,
         "micro_step": collective_structure(fwd_hlo),

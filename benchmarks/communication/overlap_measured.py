@@ -104,7 +104,7 @@ def time_mode(extra, gas=2, warmup=4, steps=30):
         loss = engine.train_batch(it)
         float(loss)  # block until the whole optimizer step retired
         per_step_ms.append((time.perf_counter() - t0) * 1e3)
-    plan = engine._bucket_plan
+    plan = engine._exchange.plan
     return {
         "bucket_count": plan.num_buckets if plan is not None else None,
         "steps": steps,

@@ -2,7 +2,7 @@
 
 The engine's compressed step keeps PER-WORKER gradients through the
 accumulation window and exchanges once at the optimizer boundary
-(``runtime/engine.py`` ``_compressed_apply_core``). Historically that
+(``runtime/grad_exchange.py`` ``update_core``). Historically that
 exchange was one collective per gradient leaf, issued in a serial chain:
 each int8 exchange is a quantize -> all_to_all -> sum -> requantize ->
 all_gather pipeline whose phases depend on each other, so leaf N+1's

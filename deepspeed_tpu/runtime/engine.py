@@ -7,15 +7,10 @@ accumulation boundaries, loss scaling, clipping, checkpoint save/load,
 throughput/wall-clock telemetry.
 
 TPU re-design (SURVEY.md §7): the hook-driven imperative engine collapses into
-two compiled SPMD programs over a named mesh —
-
-* ``_fwd_bwd``: value_and_grad of the (scaled) loss, accumulated into a grad
-  buffer whose sharding encodes ZeRO stage (replicated → psum at use; sharded
-  over fsdp → reduce-scatter), replacing the per-param backward hooks and
-  bucketed reducers of stage_1_and_2.py:832-1038.
-* ``_apply``: unscale → global-norm clip → overflow-gated optimizer update →
-  loss-scale update, all under ``lax.cond`` (reference does this host-side in
-  fused_optimizer.py:147 / stage_1_and_2.py:1744).
+two compiled SPMD programs over a named mesh, ``fwd_bwd`` and ``apply_step``
+(fused into one ``train_step`` at gas 1). They are written in
+``runtime/step.py``; this class lays out their state, dispatches them and
+does the host's part of a step.
 
 Parameter construction is jitted with output shardings (the ``zero.Init``
 equivalent — params materialize already partitioned; reference
@@ -42,7 +37,8 @@ from deepspeed_tpu.parallel.mesh import (
     set_default_topology,
 )
 from deepspeed_tpu.runtime import checkpoint_manifest as ckpt_manifest
-from deepspeed_tpu.runtime import layout, reshard
+from deepspeed_tpu.runtime import grad_exchange, layout, reshard
+from deepspeed_tpu.runtime import step as step_programs
 from deepspeed_tpu.runtime.checkpoint_engine import (
     CheckpointEngine,
     select_checkpoint_engine,
@@ -50,8 +46,6 @@ from deepspeed_tpu.runtime.checkpoint_engine import (
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.dataloader import DeepSpeedDataLoader, RepeatingLoader
 from deepspeed_tpu.runtime.loss_scaler import (
-    LossScaleState,
-    has_overflow,
     init_loss_scale,
     update_loss_scale,
 )
@@ -60,16 +54,8 @@ from deepspeed_tpu.runtime.lr_schedules import (
     build_lr_scheduler,
     schedule_fn_from_config,
 )
-from deepspeed_tpu.runtime.optimizer import (
-    build_optimizer,
-    is_compressed_optimizer,
-)
-from deepspeed_tpu.runtime.zero.gather import gather_context
+from deepspeed_tpu.runtime.optimizer import build_optimizer
 from deepspeed_tpu.telemetry.scopes import (
-    SCOPE_GRAD_CAST,
-    SCOPE_GRAD_NORM_CLIP,
-    SCOPE_OPTIMIZER,
-    SCOPE_OVERFLOW_CHECK,
     avals_like as _avals_like,
     scope_table,
 )
@@ -340,49 +326,16 @@ class DeepSpeedEngine:
         self.module = model
 
         topology = layout.build_topology(config, topology)
-        # Compressed gradient exchange (reference runtime/fp16/onebit +
-        # runtime/comm/nccl.py:51): either a 1-bit optimizer type or
-        # communication_data_type=int8. Both replace XLA's implicit grad
-        # averaging with an explicit shard_mapped exchange over the
-        # data-parallel axis, so the step keeps PER-WORKER gradients.
-        self._compressed_mode = None
-        self._comp_k = None
-        self._bucket_plan = None  # comm/bucketed.py plan, set at state init
-        self._gx_wire_dtype = jnp.bfloat16
-        self._gx_num_slices = 1  # >1 = two-level ICI/DCN exchange
-        if optimizer is None and is_compressed_optimizer(config.optimizer.type):
-            self._compressed_mode = "onebit"
-        elif config.communication_data_type == "int8":
-            self._compressed_mode = "int8"
-        elif (config.tpu.grad_exchange_config.deferred
-              and topology.size("dp") > 1):
-            # deferred bucketed exchange (comm/bucketed.py): the compressed
-            # machinery at a bf16/fp32 wire — per-worker grads through the
-            # accumulation window, ONE bucketed explicit exchange at the GAS
-            # boundary instead of XLA's implicit psum every micro step
-            self._compressed_mode = "deferred"
-        if self._compressed_mode is not None:
-            self._validate_compressed_config(config, topology)
-        elif config.tpu.grad_exchange_config.hierarchical == "on":
-            # "on" demands the two-level exchange; with no deferred
-            # exchange engaged that is a config contradiction, not a
-            # fallback case ("auto" is the degrade-quietly spelling)
-            raise ValueError(
-                "tpu.grad_exchange.hierarchical: on requires the deferred "
-                "exchange (tpu.grad_exchange.deferred: true on a dp>1 "
-                "mesh)")
-        # whether the compressed step materializes a real averaged-grad norm
-        # (int8/deferred: free from the post-exchange mean; onebit:
-        # debug-gated)
-        self._compressed_norm_available = (
-            self._compressed_mode in ("int8", "deferred")
-            or (self._compressed_mode == "onebit"
-                and config.tpu.compressed_grad_norm))
+        # how gradients cross chips: None leaves the sum to GSPMD; a 1-bit
+        # optimizer type, communication_data_type=int8 or the deferred
+        # bucketed exchange replace it with an explicit shard_mapped
+        # exchange over the dp axis (runtime/grad_exchange.py)
+        self._exchange = grad_exchange.select(config, topology, optimizer)
         # mesh/layout decisions live in runtime/layout.py so the elastic
         # reshard pass can re-derive them without an engine
         topology = layout.apply_zero_fsdp_move(
             topology, config.zero_config.stage,
-            compressed=self._compressed_mode is not None)
+            compressed=self._exchange is not None)
         self.topology = topology
         set_default_topology(topology)
         # (re)resolve the batch triad against the actual mesh; also validates
@@ -662,51 +615,6 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------
     # configuration
     # ------------------------------------------------------------------
-    def _validate_compressed_config(self, config, topology):
-        """Constraints shared by the 1-bit optimizers and int8 grad comm.
-        fp16 dynamic loss scaling composes (reference fp16/onebit/adam.py:10
-        pairs OnebitAdam with the FP16 wrapper): the compressed step
-        cond-skips the exchange+update on overflow with error-feedback
-        state carried through untouched."""
-        mode = self._compressed_mode
-        max_stage = 1 if mode == "onebit" else 0
-        if config.zero_config.stage > max_stage:
-            raise ValueError(
-                f"{mode} compressed gradient exchange requires ZeRO stage "
-                f"<= {max_stage} (got {config.zero_config.stage}); the "
-                "exchange needs the full gradient/momentum per worker — "
-                "same limitation as the reference 1-bit optimizers")
-        for ax in ("fsdp", "tp", "pp", "sp", "ep"):
-            if topology.size(ax) > 1:
-                raise ValueError(
-                    f"compressed gradient exchange runs over the dp axis "
-                    f"only; mesh axis {ax!r} has size {topology.size(ax)}")
-        off = (config.zero_config.offload_optimizer or {}).get("device", "none")
-        if off != "none":
-            raise ValueError(
-                f"{mode} compressed gradient exchange cannot combine with "
-                "offload_optimizer (the host step bypasses the exchange)")
-        if (config.tpu.grad_exchange_config.hierarchical != "off"
-                and mode != "deferred"):
-            raise ValueError(
-                "tpu.grad_exchange.hierarchical requires the deferred "
-                "bf16/fp32 exchange (grad_exchange.deferred: true); the "
-                "onebit/int8 paths own their wire format end to end and "
-                "carry error-feedback state the two-level exchange does "
-                "not")
-        if config.gradient_clipping and mode == "onebit":
-            logger.warning(
-                "gradient_clipping is ignored with the 1-bit optimizers: "
-                "they exchange sign-compressed MOMENTUM, so the averaged "
-                "gradient the clip would apply to never exists (divergence "
-                "documented in docs/DIVERGENCES.md). The int8 "
-                "communication_data_type path clips exactly.")
-        if mode == "onebit" and config.zero_config.stage == 1:
-            log_dist(
-                "OnebitAdam with ZeRO stage 1: optimizer state stays "
-                "replicated (the compressed exchange materializes the full "
-                "momentum per worker)", ranks=[0])
-
     def _configure_lr(self, lr_scheduler):
         cfg = self._config
         if lr_scheduler is None and cfg.scheduler.type is not None:
@@ -728,10 +636,8 @@ class DeepSpeedEngine:
                 "reference's torch.optim objects have no TPU meaning"
             )
         lr = self._schedule_fn  # None -> use params lr
-        kw = {}
-        if self._compressed_mode == "onebit":
-            kw = dict(compression_axis="dp",
-                      compression_axis_size=self.topology.size("dp"))
+        kw = ({} if self._exchange is None
+              else self._exchange.optimizer_kwargs())
         return build_optimizer(
             cfg.optimizer.type, cfg.optimizer.params, lr,
             use_pallas=cfg.tpu.use_pallas_optimizer, **kw,
@@ -897,8 +803,10 @@ class DeepSpeedEngine:
                           if self._offload_device == "nvme" else None))
             self._opt_shardings = None
             self._opt_state = None
-        elif self._compressed_mode is not None:
-            self._init_compressed_state(param_shapes)
+        elif self._exchange is not None:
+            (self._opt_state, self._opt_shardings,
+             self._grad_shardings) = self._exchange.init_state(
+                self._tx, self._params, param_shapes)
         else:
             opt_shapes = jax.eval_shape(self._tx.init, param_shapes)
             self._opt_shardings = self.sharding_rules.opt_sharding_tree(
@@ -927,605 +835,41 @@ class DeepSpeedEngine:
         it put the one-chip step at 16.79 of the v5e's 16.91 GB (chip run,
         PR 21)."""
         if self._acc_grads is None:
+            # an explicit exchange accumulates per-worker gradients
+            lead = () if self._exchange is None else (self._exchange.k,)
             self._acc_grads = jax.jit(
                 lambda p: jax.tree.map(
-                    lambda x: jnp.zeros(
-                        ((self._comp_k,) + x.shape) if self._compressed_mode
-                        else x.shape, jnp.float32), p),
+                    lambda x: jnp.zeros(lead + x.shape, jnp.float32), p),
                 out_shardings=self._grad_shardings,
             )(self._params)
 
     # ------------------------------------------------------------------
-    # compressed gradient exchange (1-bit optimizers / int8 grad comm)
+    # compiled programs (runtime/step.py)
     # ------------------------------------------------------------------
-    def _resolve_dcn_slices(self, gx):
-        """Inter-slice group count for the hierarchical deferred exchange
-        (1 = flat single-level). ``dcn_slices`` overrides detection so the
-        virtual CPU mesh can exercise the DCN leg; otherwise the slice
-        factor the mesh derived for the dp axis
-        (``MeshTopology.dcn_size``) is used."""
-        if gx.hierarchical == "off":
-            return 1
-        w = self.topology.size("dp")
-        n = gx.dcn_slices or self.topology.dcn_size("dp")
-        if n <= 1:
-            if gx.hierarchical == "on":
-                raise ValueError(
-                    "tpu.grad_exchange.hierarchical: on, but the dp axis "
-                    "has no slice structure (single-slice mesh and "
-                    "dcn_slices unset) — use hierarchical: auto to fall "
-                    "back to the flat exchange, or set dcn_slices")
-            return 1
-        if w % n:
-            raise ValueError(
-                f"hierarchical exchange: {n} DCN slices do not divide the "
-                f"dp axis of {w} ranks")
-        return n
-
-    def _init_compressed_state(self, param_shapes):
-        """State for the shard_mapped compressed step.
-
-        Gradients (and their accumulation buffer) carry a leading
-        ``dp``-sized group axis — each worker's UNAVERAGED gradient, which
-        the exchange consumes (the compression IS the allreduce; reference
-        runtime/comm/nccl.py:51). Per-worker error-feedback buffers shard
-        over dp; everything else is replicated.
-        """
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        mesh = self.topology.mesh
-        axis = "dp"
-        self._comp_k = self.topology.size(axis)
-        pw = NamedSharding(mesh, P(axis))
-        self._grad_shardings = jax.tree.map(lambda _: pw, param_shapes)
-        self._param_specs = jax.tree.map(lambda _: P(), param_shapes)
-        self._grad_specs = jax.tree.map(lambda _: P(axis), param_shapes)
-
-        # bucket plan for the explicit exchange (comm/bucketed.py):
-        # deferred always buckets (bucket_mb=0 -> one leaf per bucket);
-        # int8 buckets only when asked — its error-feedback buffers change
-        # shape with the plan, and the legacy per-leaf layout must stay the
-        # default for existing checkpoints
-        gx = self._config.tpu.grad_exchange_config
-        self._bucket_plan = None
-        if (self._compressed_mode == "deferred"
-                or (self._compressed_mode == "int8" and gx.bucket_mb > 0)):
-            from deepspeed_tpu.comm.bucketed import plan_for_tree
-
-            self._bucket_plan = plan_for_tree(param_shapes, gx.bucket_mb)
-        self._gx_wire_dtype = (jnp.float32
-                               if gx.wire_dtype in ("fp32", "float32")
-                               else jnp.bfloat16)
-        self._gx_num_slices = (self._resolve_dcn_slices(gx)
-                               if self._compressed_mode == "deferred" else 1)
-        if self._gx_num_slices > 1:
-            # discrete layout decision -> telemetry (docs/observability.md):
-            # the flight recorder sees which ranks pay DCN and in what wire
-            from deepspeed_tpu.telemetry.bus import (KIND_COMM_HIERARCHY,
-                                                     publish)
-
-            publish(KIND_COMM_HIERARCHY,
-                    world=int(self._comp_k),
-                    num_slices=int(self._gx_num_slices),
-                    per_slice=int(self._comp_k // self._gx_num_slices),
-                    ici_wire=str(jnp.dtype(self._gx_wire_dtype)),
-                    dcn_wire="int8",
-                    dcn_block=int(gx.dcn_block),
-                    num_buckets=int(self._bucket_plan.num_buckets
-                                    if self._bucket_plan else 0))
-
-        if self._compressed_mode == "onebit":
-            st_shape = jax.eval_shape(self._tx.init, param_shapes)
-            cls = type(st_shape)
-            rep = lambda t: jax.tree.map(lambda _: P(), t)  # noqa: E731
-            dp_ = lambda t: jax.tree.map(lambda _: P(axis), t)  # noqa: E731
-            self._opt_specs = cls(
-                count=P(), exp_avg=rep(st_shape.exp_avg),
-                exp_avg_sq=rep(st_shape.exp_avg_sq),
-                worker_error=dp_(st_shape.worker_error),
-                server_error=dp_(st_shape.server_error))
-            tx = self._tx
-
-            def init_global(params):
-                st = tx.init(params)
-                # per-worker buffers gain the leading group axis
-                return st._replace(
-                    worker_error=jax.tree.map(
-                        lambda x: x[None], st.worker_error),
-                    server_error=jax.tree.map(
-                        lambda x: x[None], st.server_error))
-
-            self._opt_state = jax.jit(jax.shard_map(
-                init_global, mesh=mesh, in_specs=(self._param_specs,),
-                out_specs=self._opt_specs, check_vma=False))(self._params)
-        elif self._compressed_mode == "deferred":
-            # bf16/fp32 wire: no quantization, no error feedback — state is
-            # just the inner optimizer (1-tuple keeps the (inner, ...) shape
-            # of the explicit-exchange family for checkpoints)
-            inner = jax.jit(self._tx.init)(self._params)
-            self._opt_state = (inner,)
-            self._opt_specs = (jax.tree.map(lambda _: P(), inner),)
-        elif self._bucket_plan is not None:
-            # bucketed int8: residuals live on the flat concatenated bucket
-            # payloads, one worker + one server buffer per BUCKET (the
-            # compensation spans exactly what each exchange quantizes)
-            from deepspeed_tpu.comm.compressed import server_shard_length
-
-            inner = jax.jit(self._tx.init)(self._params)
-            k = self._comp_k
-            sizes = self._bucket_plan.bucket_sizes()
-            err = tuple(
-                jax.jit(lambda n=n: jnp.zeros((k, n), jnp.float32),
-                        out_shardings=pw)() for n in sizes)
-            serr = tuple(
-                jax.jit(lambda m=server_shard_length(n, k): jnp.zeros(
-                    (k, m), jnp.float32), out_shardings=pw)()
-                for n in sizes)
-            self._opt_state = (inner, err, serr)
-            self._opt_specs = (
-                jax.tree.map(lambda _: P(), inner),
-                tuple(P(axis) for _ in err),
-                tuple(P(axis) for _ in serr))
-        else:  # int8 quantized grad allreduce, any optax optimizer
-            from deepspeed_tpu.comm.compressed import server_shard_length
-
-            inner = jax.jit(self._tx.init)(self._params)
-            err = jax.jit(
-                lambda p: jax.tree.map(
-                    lambda x: jnp.zeros((self._comp_k,) + x.shape,
-                                        jnp.float32), p),
-                out_shardings=self._grad_shardings)(self._params)
-            # phase-2 (server) error-feedback buffers: one reduced-shard
-            # residual per worker per leaf (reference compressed_allreduce
-            # compensates both quantization rounds, runtime/comm/nccl.py:51)
-            serr_shardings = jax.tree.map(
-                lambda x: x.sharding, err)
-            serr = jax.jit(
-                lambda p: jax.tree.map(
-                    lambda x: jnp.zeros(
-                        (self._comp_k,
-                         server_shard_length(x.size, self._comp_k)),
-                        jnp.float32), p),
-                out_shardings=serr_shardings)(self._params)
-            self._opt_state = (inner, err, serr)
-            self._opt_specs = (
-                jax.tree.map(lambda _: P(), inner),
-                jax.tree.map(lambda _: P(axis), err),
-                jax.tree.map(lambda _: P(axis), serr))
-        self._opt_shardings = jax.tree.map(
-            lambda x: x.sharding, self._opt_state)
-
-    def _compressed_apply_core(self):
-        """shard_map program: per-worker grads -> compressed exchange ->
-        optimizer update -> replicated new params."""
-        from jax.sharding import PartitionSpec as P
-
-        tx = self._tx
-        mesh = self.topology.mesh
-        k = self._comp_k
-        mode = self._compressed_mode
-        plan = self._bucket_plan
-        wire = self._gx_wire_dtype
-        num_slices = self._gx_num_slices
-        dcn_block = self._config.tpu.grad_exchange_config.dcn_block
-
-        clip = self.gradient_clipping
-        debug_norm = self._config.tpu.compressed_grad_norm
-
-        def apply_step(params, opt_state, grads_pw, lr_factor):
-            local_g = jax.tree.map(lambda g: g[0], grads_pw)  # [1,*s]->[*s]
-            if mode == "onebit":
-                if debug_norm:
-                    # debug-only exact pmean: a full fp32 allreduce beside
-                    # the compressed exchange (tpu.compressed_grad_norm)
-                    g_avg = jax.tree.map(
-                        lambda g: jax.lax.pmean(g, "dp"), local_g)
-                    grad_norm = optax.global_norm(g_avg)
-                else:
-                    grad_norm = jnp.float32(0.0)
-                st = opt_state._replace(
-                    worker_error=jax.tree.map(
-                        lambda x: x[0], opt_state.worker_error),
-                    server_error=jax.tree.map(
-                        lambda x: x[0], opt_state.server_error))
-                # grads stay f32: the 1-bit state (momentum, errors) is f32
-                updates, new_st = tx.update(local_g, st, params)
-                updates = jax.tree.map(
-                    lambda u: (u * lr_factor).astype(u.dtype), updates)
-                new_params = optax.apply_updates(params, updates)
-                new_opt = new_st._replace(
-                    worker_error=jax.tree.map(
-                        lambda x: x[None], new_st.worker_error),
-                    server_error=jax.tree.map(
-                        lambda x: x[None], new_st.server_error))
-            elif mode == "deferred":
-                from deepspeed_tpu.comm.bucketed import (
-                    bucketed_all_reduce, hierarchical_all_reduce)
-
-                (inner,) = opt_state
-                if num_slices > 1:
-                    # two-level ICI/DCN exchange: wire_dtype psum_scatter /
-                    # all_gather inside each slice, bucketed int8 EQuARX
-                    # exchange of the 1/P shard across slices
-                    mean_g = hierarchical_all_reduce(
-                        local_g, "dp", num_slices, plan,
-                        block=dcn_block, wire_dtype=wire, mean=True,
-                        log_name="hierarchical_grad_exchange")
-                else:
-                    # ONE bucketed explicit exchange at the GAS boundary:
-                    # each bucket is an independent collective XLA may
-                    # overlap with the others' cast/unpack compute
-                    # (T3-style)
-                    mean_g = bucketed_all_reduce(
-                        local_g, "dp", plan, wire_dtype=wire, mean=True,
-                        log_name="bucketed_grad_exchange")
-                new_opt_tail = ()
-            elif plan is not None:
-                from deepspeed_tpu.comm.bucketed import (
-                    bucketed_quantized_all_reduce)
-
-                inner, err, serr = opt_state
-                # per-BUCKET int8 exchange: independent collective chains
-                # (vs the serial per-leaf loop) with residuals carried on
-                # the flat bucket payloads
-                summed, e2s, se2s = bucketed_quantized_all_reduce(
-                    local_g, "dp", plan,
-                    worker_errors=[e[0] for e in err],
-                    server_errors=[se[0] for se in serr])
-                mean_g = jax.tree.map(lambda r: r / k, summed)
-                new_opt_tail = (tuple(e[None] for e in e2s),
-                                tuple(se[None] for se in se2s))
-            else:
-                from deepspeed_tpu.comm.compressed import quantized_all_reduce
-
-                inner, err, serr = opt_state
-                reduced, new_err, new_serr = [], [], []
-                flat_g, treedef = jax.tree.flatten(local_g)
-                for g, e, se in zip(flat_g, jax.tree.leaves(err),
-                                    jax.tree.leaves(serr)):
-                    r, e2, se2 = quantized_all_reduce(
-                        g + e[0], "dp", return_error=True,
-                        server_error=se[0])
-                    reduced.append(r / k)
-                    new_err.append(e2[None])
-                    new_serr.append(se2[None])
-                mean_g = jax.tree.unflatten(treedef, reduced)
-                new_opt_tail = (jax.tree.unflatten(treedef, new_err),
-                                jax.tree.unflatten(treedef, new_serr))
-            if mode != "onebit":
-                # the post-exchange mean is materialized anyway: its norm is
-                # free, and gradient_clipping gets exact semantics
-                grad_norm = optax.global_norm(mean_g)
-                if clip and clip > 0:
-                    factor = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
-                    mean_g = jax.tree.map(lambda g: g * factor, mean_g)
-                mean_g = jax.tree.map(lambda g, p: g.astype(p.dtype),
-                                      mean_g, params)
-                updates, new_inner = tx.update(mean_g, inner, params)
-                updates = jax.tree.map(
-                    lambda u: (u * lr_factor).astype(u.dtype), updates)
-                new_params = optax.apply_updates(params, updates)
-                new_opt = (new_inner,) + new_opt_tail
-            return new_params, new_opt, grad_norm
-
-        return jax.shard_map(
-            apply_step, mesh=mesh,
-            in_specs=(self._param_specs, self._opt_specs, self._grad_specs,
-                      P()),
-            out_specs=(self._param_specs, self._opt_specs, P()),
-            check_vma=False)
-
-    def _grouped_grads(self, params, batch, rng, step, loss_scale):
-        """Per-worker gradients via a vmap over dp-sized batch groups: each
-        group's gradient only depends on its batch shard, so the [k, ...]
-        output shards over dp with NO collective — the exchange in the apply
-        step is the only cross-worker traffic. Trace-level helper shared by
-        the fused and unfused compressed step builders."""
-        model = self.module
-        k = self._comp_k
-        rng = jax.random.fold_in(rng, step)
-        rngs = jax.random.split(rng, k)
-
-        pld_kwargs = self._pld_model_kwargs(
-            step // self.gradient_accumulation_steps)
-
-        def loss_fn(p, local_batch, r):
-            loss = model.apply(
-                {"params": p}, **local_batch, deterministic=False,
-                rngs={"dropout": r, "gating": jax.random.fold_in(r, 7)},
-                **pld_kwargs,
-            )
-            return loss * loss_scale, loss
-
-        grouped = jax.tree.map(
-            lambda x: x.reshape((k, x.shape[0] // k) + x.shape[1:]), batch)
-        grads, losses = jax.vmap(
-            jax.grad(loss_fn, has_aux=True), in_axes=(None, 0, 0)
-        )(params, grouped, rngs)
-        return grads, jnp.mean(losses)
-
-    def _build_fwd_bwd_compressed(self):
-        gas = self.gradient_accumulation_steps
-
-        def fwd_bwd(params, acc_grads, batch, rng, step, scale):
-            grads, loss = self._grouped_grads(
-                params, batch, rng, step, scale / gas)
-            new_acc = jax.tree.map(
-                lambda a, g: a + g.astype(jnp.float32), acc_grads, grads)
-            return new_acc, loss
-
-        return jax.jit(
-            fwd_bwd,
-            donate_argnums=(1,),
-            out_shardings=(self._grad_shardings, None),
-        )
-
-    def _guarded_compressed_update(self, core, params, opt_state, grads,
-                                   ls_state, lr_factor):
-        """Overflow-guarded compressed exchange (trace-level, shared by the
-        fused and unfused step builders): on fp16 overflow the exchange and
-        update are cond-skipped with the error-feedback buffers and the
-        optimizer count untouched (reference fp16+onebit skip semantics,
-        fp16/onebit/adam.py:10)."""
-        with jax.named_scope(SCOPE_OVERFLOW_CHECK):
-            overflow = (has_overflow(grads) if self._check_overflow
-                        else jnp.bool_(False))
-
-        @jax.named_scope(SCOPE_OPTIMIZER)
-        def do_update(operand):
-            params, opt_state, grads = operand
-            return core(params, opt_state, grads, lr_factor)
-
-        def skip_update(operand):
-            params, opt_state, _ = operand
-            return params, opt_state, jnp.float32(0.0)
-
-        new_params, new_opt, grad_norm = jax.lax.cond(
-            overflow, skip_update, do_update, (params, opt_state, grads))
-        new_ls = update_loss_scale(ls_state, overflow, self._ls_config)
-        return new_params, new_opt, new_ls, overflow, grad_norm
-
-    def _build_apply_compressed(self):
-        core = self._compressed_apply_core()
-
-        def apply_step(params, opt_state, acc_grads, ls_state, lr_factor):
-            with jax.named_scope(SCOPE_GRAD_CAST):
-                grads = jax.tree.map(lambda g: g / ls_state.scale, acc_grads)
-            new_params, new_opt, new_ls, overflow, grad_norm = \
-                self._guarded_compressed_update(
-                    core, params, opt_state, grads, ls_state, lr_factor)
-            zero_acc = jax.tree.map(jnp.zeros_like, acc_grads)
-            return (new_params, new_opt, zero_acc, new_ls,
-                    overflow, grad_norm)
-
-        return jax.jit(apply_step, donate_argnums=(0, 1, 2))
-
-    def _build_train_step_compressed(self):
-        core = self._compressed_apply_core()
-
-        def train_step(params, opt_state, ls_state, batch, rng, step,
-                       lr_factor):
-            grads, loss = self._grouped_grads(
-                params, batch, rng, step, ls_state.scale)
-            with jax.named_scope(SCOPE_GRAD_CAST):
-                grads = jax.tree.map(
-                    lambda g: g.astype(jnp.float32) / ls_state.scale, grads)
-            new_params, new_opt, new_ls, overflow, grad_norm = \
-                self._guarded_compressed_update(
-                    core, params, opt_state, grads, ls_state, lr_factor)
-            return (new_params, new_opt, new_ls, loss, overflow, grad_norm)
-
-        return jax.jit(train_step, donate_argnums=(0, 1))
-
-    # ------------------------------------------------------------------
-    # compiled programs
-    # ------------------------------------------------------------------
-    def _pld_model_kwargs(self, global_step):
-        """Extra model kwargs for stochastic-mode models under a PLD
-        schedule: ``pld_theta`` computed IN-GRAPH from the (traced) step
-        counter — theta(t) = (1 - theta)e^{-gamma t} + theta, exactly the
-        host-side ProgressiveLayerDrop schedule — so the compiled step
-        needs no per-step host transfer or recompile."""
-        if self.progressive_layer_drop is None:
-            return {}
-        if not getattr(getattr(self.module, "config", None),
-                       "stochastic_mode", False):
-            return {}
-        pc = self._config.progressive_layer_drop
-        theta = pc.theta + (1.0 - pc.theta) * jnp.exp(
-            -pc.gamma * jnp.asarray(global_step, jnp.float32))
-        return {"pld_theta": theta}
+    def _step_spec(self):
+        """What the step programs are functions of (after _init_state)."""
+        return step_programs.StepSpec(
+            model=self.module, tx=self._tx, rules=self.sharding_rules,
+            exchange=self._exchange, clip=self.gradient_clipping,
+            ls_config=self._ls_config, check_overflow=self._check_overflow,
+            gas=self.gradient_accumulation_steps,
+            pld=self.progressive_layer_drop,
+            replace_acc=self._offload_param_device != "none",
+            param_shardings=self._param_shardings,
+            opt_shardings=self._opt_shardings,
+            grad_shardings=self._grad_shardings)
 
     def _build_fwd_bwd(self):
-        if self._compressed_mode is not None:
-            return self._build_fwd_bwd_compressed()
-        model = self.module
-        gas = self.gradient_accumulation_steps
-        # offload_param: grads of streamed layers land in HOST memory
-        # (per-layer, from the streaming bwd); elementwise accumulation on
-        # host tensors is not a device op, so the buffer is REPLACED each
-        # micro step — with gas > 1 forward() accumulates host-side numpy
-        # (the grads are host-resident anyway; the host optimizer consumes
-        # them there)
-        replace_acc = self._offload_param_device != "none"
-
-        def fwd_bwd(params, acc_grads, batch, rng, step, scale):
-            # fold the step counter in HERE: a host-side jax.random.split per
-            # micro step costs a full small-op dispatch round-trip
-            rng = jax.random.fold_in(rng, step)
-
-            def loss_fn(p):
-                with gather_context(self.sharding_rules, "fwd_bwd"):
-                    loss = model.apply(
-                        {"params": p}, **batch, deterministic=False,
-                        rngs={"dropout": rng,
-                              "gating": jax.random.fold_in(rng, 7)},
-                        **self._pld_model_kwargs(step // gas),
-                    )
-                # loss scaled by 1/gas (reference engine.py:1789 -> :1596)
-                # and by the fp16 loss scale (loss_scaler.py)
-                return loss * (scale / gas), loss
-
-            grads, loss = jax.grad(loss_fn, has_aux=True)(params)
-            if replace_acc:
-                return grads, loss
-            new_acc = jax.tree.map(
-                lambda a, g: a + g.astype(jnp.float32), acc_grads, grads
-            )
-            return new_acc, loss
-
-        # replace_acc with gas > 1: the previous micro step's grad leaves
-        # stay alive until their in-flight host copies are drained
-        # (double-buffered host accumulation), so the acc_grads argument
-        # must NOT be donated out from under them. At gas == 1 the offload
-        # step consumes the grads before the next dispatch — keep donating
-        # so peak grad allocation stays at one tree.
-        no_donate = replace_acc and gas > 1
-        return jax.jit(
-            fwd_bwd,
-            donate_argnums=() if no_donate else (1,),
-            out_shardings=(self._grad_shardings, None),
-        )
+        return step_programs.build_fwd_bwd(self._step_spec())
 
     def _build_apply(self):
-        if self._compressed_mode is not None:
-            return self._build_apply_compressed()
-        tx = self._tx
-        clip = self.gradient_clipping
-        # fp16 loss-scale gating, or the sentinel's any-dtype non-finite
-        # guard: a NaN/Inf grad tree cond-skips the update either way
-        # (update_loss_scale is a no-op when fp16 dynamic scaling is off)
-        check_overflow = self._check_overflow
-        ls_config = self._ls_config
-
-        def apply_step(params, opt_state, acc_grads, ls_state, lr_factor):
-            with jax.named_scope(SCOPE_GRAD_CAST):
-                grads = jax.tree.map(lambda g: g / ls_state.scale, acc_grads)
-            with jax.named_scope(SCOPE_OVERFLOW_CHECK):
-                overflow = (has_overflow(grads) if check_overflow
-                            else jnp.bool_(False))
-            with jax.named_scope(SCOPE_GRAD_NORM_CLIP):
-                grad_norm = optax.global_norm(grads)
-                if clip and clip > 0:
-                    factor = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
-                    grads = jax.tree.map(lambda g: g * factor, grads)
-
-            @jax.named_scope(SCOPE_OPTIMIZER)
-            def do_update(operand):
-                params, opt_state, grads = operand
-                # grads ride in f32 for overflow/clip math; the optimizer
-                # consumes them in each param's dtype so moment buffers keep
-                # the dtype they were initialized with (pure-bf16 training:
-                # param_dtype=bf16 means bf16 m/v — the lax.cond skip branch
-                # must see identical state types)
-                grads = jax.tree.map(lambda g, p: g.astype(p.dtype),
-                                     grads, params)
-                updates, new_opt = tx.update(grads, opt_state, params)
-                # write-through lr: updates are linear in lr (see set_lr)
-                updates = jax.tree.map(
-                    lambda u: (u * lr_factor).astype(u.dtype), updates)
-                new_params = optax.apply_updates(params, updates)
-                return new_params, new_opt
-
-            def skip_update(operand):
-                params, opt_state, _ = operand
-                return params, opt_state
-
-            new_params, new_opt = jax.lax.cond(
-                overflow, skip_update, do_update, (params, opt_state, grads)
-            )
-            new_ls = update_loss_scale(ls_state, overflow, ls_config)
-            zero_acc = jax.tree.map(jnp.zeros_like, acc_grads)
-            return new_params, new_opt, zero_acc, new_ls, overflow, grad_norm
-
-        return jax.jit(
-            apply_step,
-            donate_argnums=(0, 1, 2),
-            out_shardings=(
-                self._param_shardings, self._opt_shardings, self._grad_shardings,
-                None, None, None,
-            ),
-        )
+        return step_programs.build_apply(self._step_spec())
 
     def _build_train_step(self):
-        """Fused fwd+bwd+optimizer in ONE compiled program (used by
-        train_batch when gas == 1): one dispatch instead of two, and XLA
-        overlaps the optimizer update with the tail of the backward."""
-        if self._compressed_mode is not None:
-            return self._build_train_step_compressed()
-        model = self.module
-        tx = self._tx
-        clip = self.gradient_clipping
-        check_overflow = self._check_overflow  # see _build_apply
-        ls_config = self._ls_config
-
-        def train_step(params, opt_state, ls_state, batch, rng, step,
-                       lr_factor):
-            rng = jax.random.fold_in(rng, step)
-
-            def loss_fn(p):
-                with gather_context(self.sharding_rules, "train_step"):
-                    loss = model.apply(
-                        {"params": p}, **batch, deterministic=False,
-                        rngs={"dropout": rng,
-                              "gating": jax.random.fold_in(rng, 7)},
-                        **self._pld_model_kwargs(step),
-                    )
-                return loss * ls_state.scale, loss
-
-            grads, loss = jax.grad(loss_fn, has_aux=True)(params)
-            with jax.named_scope(SCOPE_GRAD_CAST):
-                grads = jax.tree.map(
-                    lambda g: g.astype(jnp.float32) / ls_state.scale, grads)
-            with jax.named_scope(SCOPE_OVERFLOW_CHECK):
-                overflow = has_overflow(grads) if check_overflow \
-                    else jnp.bool_(False)
-            with jax.named_scope(SCOPE_GRAD_NORM_CLIP):
-                grad_norm = optax.global_norm(grads)
-                if clip and clip > 0:
-                    factor = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
-                    grads = jax.tree.map(lambda g: g * factor, grads)
-
-            @jax.named_scope(SCOPE_OPTIMIZER)
-            def do_update(operand):
-                params, opt_state, grads = operand
-                # see _build_apply.do_update: optimizer math in param dtype
-                grads = jax.tree.map(lambda g, p: g.astype(p.dtype),
-                                     grads, params)
-                updates, new_opt = tx.update(grads, opt_state, params)
-                # write-through lr: updates are linear in lr (see set_lr)
-                updates = jax.tree.map(
-                    lambda u: (u * lr_factor).astype(u.dtype), updates)
-                return optax.apply_updates(params, updates), new_opt
-
-            def skip_update(operand):
-                params, opt_state, _ = operand
-                return params, opt_state
-
-            new_params, new_opt = jax.lax.cond(
-                overflow, skip_update, do_update,
-                (params, opt_state, grads))
-            new_ls = update_loss_scale(ls_state, overflow, ls_config)
-            return new_params, new_opt, new_ls, loss, overflow, grad_norm
-
-        return jax.jit(
-            train_step,
-            donate_argnums=(0, 1),
-            out_shardings=(
-                self._param_shardings, self._opt_shardings,
-                None, None, None, None,
-            ),
-        )
+        return step_programs.build_train_step(self._step_spec())
 
     def _build_eval(self):
-        model = self.module
-
-        def eval_fn(params, batch):
-            with gather_context(self.sharding_rules, "eval"):
-                return model.apply({"params": params}, **batch,
-                                   deterministic=True)
-
-        return jax.jit(eval_fn)
+        return step_programs.build_eval(self.module, self.sharding_rules)
 
     # ------------------------------------------------------------------
     # data
@@ -1968,8 +1312,8 @@ class DeepSpeedEngine:
                 # gate short-circuit first: bool(overflow) on the device
                 # scalar would force a host sync every step when neither
                 # fp16 nor the sentinel's non-finite guard is on
-                if (self._compressed_mode is None
-                        or self._compressed_norm_available) and not (
+                if (self._exchange is None
+                        or self._exchange.norm_available) and not (
                         self._check_overflow and bool(overflow)):
                     self._last_grad_norm = grad_norm
             self.global_steps += 1
@@ -2200,8 +1544,8 @@ class DeepSpeedEngine:
                  grad_norm) = self._train_step_fn(
                     self._params, self._opt_state, self._ls_state, device_batch,
                     self._rng, self.micro_steps, self._lr_factor_now())
-            if (self._compressed_mode is None
-                    or self._compressed_norm_available) and not (
+            if (self._exchange is None
+                    or self._exchange.norm_available) and not (
                     self._check_overflow and bool(overflow)):
                 self._last_grad_norm = grad_norm
             self._last_loss = loss
@@ -2931,15 +2275,9 @@ class DeepSpeedEngine:
                     _, verify_s = reshard.verify_state_dict(
                         opt_sd, saved_specs["opt_state"], "optimizer")
                     reshard_phases["verify_opt_s"] = verify_s
-                if (self._compressed_mode == "int8"
-                        and isinstance(opt_sd, dict)
-                        and "2" not in opt_sd and "1" in opt_sd):
-                    # migrate pre-server-error int8 checkpoints (state was
-                    # (inner, worker_err); "2" = the phase-2 residuals):
-                    # fresh zeros are the correct cold-start for EF buffers
-                    opt_sd = dict(opt_sd)
-                    opt_sd["2"] = serialization.to_state_dict(
-                        self._opt_state[2])
+                if self._exchange is not None:
+                    opt_sd = self._exchange.migrate_state_dict(
+                        opt_sd, self._opt_state)
                 restored_opt = serialization.from_state_dict(
                     self._opt_state, opt_sd
                 )

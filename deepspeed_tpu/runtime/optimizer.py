@@ -13,6 +13,8 @@ loops already, which is the honest default.
 
 from typing import Any, Callable, Dict, Optional, Union
 
+import jax
+import jax.numpy as jnp
 import optax
 
 from deepspeed_tpu.runtime import constants as C
@@ -29,6 +31,35 @@ def is_compressed_optimizer(opt_type: Optional[str]) -> bool:
     return (opt_type or "").lower() in (
         C.ONEBIT_ADAM_OPTIMIZER, C.ZERO_ONE_ADAM_OPTIMIZER,
         C.ONEBIT_LAMB_OPTIMIZER)
+
+
+def norm_and_clip(grads, clip):
+    """``(grads, pre-clip global norm)``, clipped to ``clip`` when it is
+    set: on GSPMD's summed gradients in the step, on the post-exchange mean
+    inside an explicit exchange."""
+    grad_norm = optax.global_norm(grads)
+    if clip and clip > 0:
+        factor = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
+        grads = jax.tree.map(lambda g: g * factor, grads)
+    return grads, grad_norm
+
+
+def apply_optimizer(tx, params, opt_state, grads, lr_factor, cast=True):
+    """One update of ``tx``: ``(new_params, new_opt_state)``.
+
+    Gradients ride in f32 for overflow/clip math; the optimizer consumes
+    them in each param's dtype (``cast``) so moment buffers keep the dtype
+    they were initialized with (pure-bf16 training: param_dtype=bf16 means
+    bf16 m/v — the step's lax.cond skip branch must see identical state
+    types). The 1-bit optimizers keep them f32: their state (momentum,
+    errors) is f32."""
+    if cast:
+        grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, params)
+    updates, new_opt = tx.update(grads, opt_state, params)
+    # write-through lr: updates are linear in lr (see engine.set_lr)
+    updates = jax.tree.map(
+        lambda u: (u * lr_factor).astype(u.dtype), updates)
+    return optax.apply_updates(params, updates), new_opt
 
 
 def build_optimizer(

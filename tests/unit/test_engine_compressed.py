@@ -49,12 +49,15 @@ def _engine(opt_block, extra=None, micro=8, gas=1):
     return engine
 
 
-def _compiled_step_text(engine, batch):
-    lowered = engine._train_step_fn.lower(
+def _lowered_step(engine, batch):
+    return engine._train_step_fn.lower(
         engine._params, engine._opt_state, engine._ls_state,
         engine._put_batch(batch), engine._rng, engine.micro_steps,
         engine._lr_factor_now())
-    return lowered.compile().as_text()
+
+
+def _compiled_step_text(engine, batch):
+    return _lowered_step(engine, batch).compile().as_text()
 
 
 def _has_int8_collective(hlo_text):
@@ -196,7 +199,7 @@ class TestInt8GradComm:
         X, Y = _data()
         eng = _engine({"type": "AdamW", "params": {"lr": 5e-2}},
                       extra={"communication_data_type": "fp32"})
-        assert eng._compressed_mode is None
+        assert eng._exchange is None
 
     def test_rejects_zero_stage1(self, eight_devices):
         with pytest.raises(ValueError, match="ZeRO stage"):

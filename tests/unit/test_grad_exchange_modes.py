@@ -1,5 +1,5 @@
 """Engine-integrated bucketed/deferred gradient exchange
-(``tpu.grad_exchange`` config block -> ``_compressed_apply_core``).
+(``tpu.grad_exchange`` config block -> ``runtime/grad_exchange.py``).
 
 ``deferred: true`` keeps per-worker grads through the accumulation window
 and exchanges once, bucketed, at the optimizer boundary — same protocol as
@@ -27,11 +27,25 @@ from tests.unit.test_engine_compressed import (
     _data,
     _engine,
     _has_int8_collective,
+    _lowered_step,
 )
 
 
 def _params(eng):
     return [np.asarray(x) for x in jax.tree.leaves(eng.params)]
+
+
+def _all_reduce_operands(eng, batch):
+    """Element types of every ``all_reduce`` operand in the fused step as
+    LOWERED: the CPU backend promotes bf16 all-reduces to f32 when it
+    compiles (it has no bf16 collective), so the compiled text cannot say
+    what a TPU puts on the wire. The loss's mean over groups is a plain
+    ``jnp.mean`` outside the shard_map: the exchange's are the only ones."""
+    found = re.findall(
+        r'"stablehlo\.all_reduce".*?\}\) : \(tensor<(?:\d+x)*(\w+)>\)',
+        _lowered_step(eng, batch).as_text(), flags=re.S)
+    assert found, "no all_reduce in the lowered step"
+    return set(found)
 
 
 class TestGradExchangeConfig:
@@ -58,8 +72,7 @@ class TestGradExchangeConfig:
 class TestDeferredExchange:
     def test_default_off(self, eight_devices):
         eng = _engine({"type": "AdamW", "params": {"lr": 1e-2}})
-        assert eng._compressed_mode is None
-        assert eng._bucket_plan is None
+        assert eng._exchange is None
 
     def test_fp32_wire_matches_baseline_engine(self, eight_devices):
         """The deferred exchange is psum-of-sums instead of
@@ -81,8 +94,8 @@ class TestDeferredExchange:
             it = iter(RepeatingLoader([batch]))
             losses = [float(eng.train_batch(it)) for _ in range(12)]
             runs[name] = (losses, _params(eng), eng)
-        assert runs["deferred"][2]._compressed_mode == "deferred"
-        assert runs["deferred"][2]._bucket_plan is not None
+        assert runs["deferred"][2]._exchange.mode == "deferred"
+        assert runs["deferred"][2]._exchange.plan is not None
         np.testing.assert_allclose(runs["baseline"][0], runs["deferred"][0],
                                    rtol=1e-4)
         for b, d in zip(runs["baseline"][1], runs["deferred"][1]):
@@ -96,15 +109,16 @@ class TestDeferredExchange:
         it = iter(RepeatingLoader([batch]))
         losses = [float(eng.train_batch(it)) for _ in range(100)]
         assert losses[-1] < 0.01 * losses[0], losses[::20]
-        # the collective payload is cast to bf16 (the halved wire). The
-        # CPU backend then PROMOTES bf16 all-reduces back to f32 (no bf16
-        # collective support), so assert on the surviving bf16 converts
-        # that carry the psum metadata — on TPU the all-reduce itself
-        # stays bf16.
-        hlo = _compiled_step_text(eng, batch)
-        assert any("bf16[" in ln and "psum" in ln and "bucketed.py" in ln
-                   for ln in hlo.splitlines()), \
-            [ln for ln in hlo.splitlines() if "all-reduce" in ln][:4]
+        # the collective payload is cast to bf16 (the halved wire)
+        assert _all_reduce_operands(eng, batch) == {"bf16"}
+
+    def test_fp32_wire_is_f32_on_the_wire(self, eight_devices):
+        batch = dict(zip("xy", _data()))
+        eng = _engine({"type": "AdamW", "params": {"lr": 5e-2}},
+                      extra={"tpu": {"grad_exchange": {
+                          "deferred": True, "wire_dtype": "fp32"}}})
+        eng.train_batch(iter([batch]))
+        assert _all_reduce_operands(eng, batch) == {"f32"}
 
     def test_grad_norm_available(self, eight_devices):
         """Deferred mode materializes the averaged gradient, so the norm
@@ -128,11 +142,11 @@ class TestBucketedInt8:
                       extra={"communication_data_type": "int8",
                              "tpu": {"grad_exchange":
                                      {"bucket_mb": 0.0001}}})
-        assert eng._compressed_mode == "int8"
+        assert eng._exchange.mode == "int8"
         it = iter(RepeatingLoader([batch]))
         losses = [float(eng.train_batch(it)) for _ in range(100)]
         assert losses[-1] < 0.01 * losses[0], losses[::20]
-        assert eng._bucket_plan is not None
+        assert eng._exchange.plan is not None
         hlo = _compiled_step_text(eng, batch)
         assert re.search(r"(all-to-all|all-gather)[^\n]*s8"
                          r"|s8[^\n]*(all-to-all|all-gather)", hlo)
@@ -144,8 +158,8 @@ class TestBucketedInt8:
         X, Y = _data()
         eng = _engine({"type": "AdamW", "params": {"lr": 5e-2}},
                       extra={"communication_data_type": "int8"})
-        assert eng._compressed_mode == "int8"
-        assert eng._bucket_plan is None
+        assert eng._exchange.mode == "int8"
+        assert eng._exchange.plan is None
         it = iter(RepeatingLoader([{"x": X, "y": Y}]))
         eng.train_batch(it)
         # legacy state: worker-error tree mirrors the PARAM tree
@@ -161,7 +175,7 @@ class TestBucketedInt8:
         it = iter(RepeatingLoader([{"x": X, "y": Y}]))
         for _ in range(3):
             eng.train_batch(it)
-        plan = eng._bucket_plan
+        plan = eng._exchange.plan
         we = eng._opt_state[1]
         assert isinstance(we, tuple) and len(we) == plan.num_buckets
         # residuals are live (non-zero) after compressed steps
@@ -227,8 +241,8 @@ class TestHierarchicalExchange:
                                       "hierarchical": "auto"}}})
         it = iter(RepeatingLoader([{"x": X, "y": Y}]))
         eng.train_batch(it)  # builds the (lazy) exchange state
-        assert eng._compressed_mode == "deferred"
-        assert eng._gx_num_slices == 1
+        assert eng._exchange.mode == "deferred"
+        assert eng._exchange.num_slices == 1
 
     @pytest.mark.slow
     def test_converges_publishes_plan_and_int8_dcn_wire(
@@ -251,8 +265,8 @@ class TestHierarchicalExchange:
             first = float(eng.train_batch(it))  # lazy state init publishes
         finally:
             telemetry_bus.unsubscribe(evs.append)
-        assert eng._compressed_mode == "deferred"
-        assert eng._gx_num_slices == 2
+        assert eng._exchange.mode == "deferred"
+        assert eng._exchange.num_slices == 2
         plans = [e for e in evs if e["kind"] == KIND_COMM_HIERARCHY]
         assert len(plans) == 1, [e["kind"] for e in evs]
         assert plans[0]["world"] == 8 and plans[0]["num_slices"] == 2

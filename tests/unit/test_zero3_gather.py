@@ -19,6 +19,7 @@ from deepspeed_tpu.models.transformer_lm import (
 from deepspeed_tpu.parallel.mesh import (
     MeshTopology, reset_default_topology, set_default_topology)
 from deepspeed_tpu.runtime import engine as engine_mod
+from deepspeed_tpu.runtime import step as step_mod
 from deepspeed_tpu.runtime.zero import gather as zero3
 from deepspeed_tpu.runtime.zero.sharding import ZeroShardingRules
 from deepspeed_tpu.telemetry.bus import KIND_ZERO3_GATHER_PLAN, telemetry_bus
@@ -491,7 +492,7 @@ def test_inert_wherever_fsdp_is_one_or_the_stage_below_three(
     def never_entered(rules, program):
         yield None
 
-    monkeypatch.setattr(engine_mod, "gather_context", never_entered)
+    monkeypatch.setattr(step_mod, "gather_context", never_entered)
     assert step_lowering(engine) == with_context
 
 
@@ -506,7 +507,7 @@ def test_the_hash_does_tell_programs_apart(eight_devices, monkeypatch):
     def never_entered(rules, program):
         yield None
 
-    monkeypatch.setattr(engine_mod, "gather_context", never_entered)
+    monkeypatch.setattr(step_mod, "gather_context", never_entered)
     assert step_lowering(engine) != with_context
 
 
