@@ -19,7 +19,16 @@ its lanes:
   ``slot_pos[B, S]``), so one lane's time axis resets without touching its
   neighbors;
 * completion is per-sequence (EOS or per-request max tokens), not
-  lockstep, and every emitted token fires a streaming callback.
+  lockstep, and every emitted token fires a streaming callback;
+* the decode loop runs ONE STEP AHEAD of the host: step n+1 is dispatched
+  from the token vector step n left on the device, and only then are
+  step n's tokens read, emitted, journalled and counted, while the device
+  computes. A lane's end is therefore seen one step late: the token that
+  step n+1 computed for a sequence that ended at step n is dropped (no
+  callback, no journal record, no count) and its cache rows are garbage
+  that the lane's next admission overwrites. Speculative decoding keeps
+  the synchronous order, because its next input is the host's acceptance
+  test.
 
 Free lanes keep decoding garbage tokens — attention is row-independent and
 the masked softmax is NaN-safe, so a garbage lane costs FLOPs but never
@@ -177,6 +186,11 @@ class ServingStats:
     completions: List[Completion] = field(default_factory=list)
     wall_s: float = 0.0
     decode_steps: int = 0
+    # decode steps dispatched before the step before them was read
+    decode_steps_ahead: int = 0
+    # tokens a step computed for a lane whose request had ended by the
+    # time the host read them (a lane's end is seen one step late)
+    decode_tokens_discarded: int = 0
 
     def summary(self) -> Dict[str, Any]:
         ttfts = sorted(c.ttft_s for c in self.completions)
@@ -201,6 +215,8 @@ class ServingStats:
                 "p50": pct(sorted(pts), 0.50) * 1e3,
                 "p95": pct(sorted(pts), 0.95) * 1e3},
             "decode_steps": self.decode_steps,
+            "decode_steps_ahead": self.decode_steps_ahead,
+            "decode_tokens_discarded": self.decode_tokens_discarded,
         }
 
 
@@ -341,6 +357,7 @@ class ContinuousBatchingScheduler:
         self._pending: deque = deque()
         self._next_id = 0
         self._splice_fn = None
+        self._set_token_fn = None
         self._copy_fn = None
         self._rewind_fn = None
         self._empty_cache_shapes = None
@@ -568,17 +585,23 @@ class ContinuousBatchingScheduler:
         eval_shape the decode apply for the leaf geometry, then initialize
         by name — ``slot_pos`` is -1 (no position cached), everything else
         zeros (``valid`` bools are False, clocks are 0). ``eng`` defaults
-        to the target engine; pass the draft engine for its lane cache."""
+        to the target engine; pass the draft engine for its lane cache.
+        The leaves are made on the sharding that the splice and decode
+        programs hand back (committed, as every jitted result is when an
+        argument is): an uncommitted first cache would be a second
+        specialisation of each program that takes it."""
         if eng is None or eng is self.engine:
+            eng = self.engine
             shapes = self._cache_shapes()
         else:
             shapes = self._cache_shapes_for(eng)
+        where = eng.topology.replicated()
 
         def init_leaf(path, sd):
             name = path[-1].key if hasattr(path[-1], "key") else path[-1]
             if name == "slot_pos":
-                return jnp.full(sd.shape, -1, sd.dtype)
-            return jnp.zeros(sd.shape, sd.dtype)
+                return jnp.full(sd.shape, -1, sd.dtype, device=where)
+            return jnp.zeros(sd.shape, sd.dtype, device=where)
 
         return jax.tree_util.tree_map_with_path(init_leaf, shapes)
 
@@ -606,6 +629,26 @@ class ContinuousBatchingScheduler:
                 jax.jit(splice, donate_argnums=(0,)),
                 key=lambda a: _first_leaf_shape(a[1]))
         return self._splice_fn(cache, sub_cache, jnp.int32(lane))
+
+    def _set_token(self, tok_dev, lane, token):
+        """Write an admitted lane's first token into the ``[slots]`` token
+        vector that the decode steps hand from one to the next on the
+        device. ``token`` is the ``[1]`` array the admission's sampling
+        left on the device, so this is dispatched behind the prefill and
+        waits for no host read (a hand-off's token is a host int). Jitted
+        once, lane traced."""
+        if self._set_token_fn is None:
+
+            def set_token(vec, lane_idx, tok):
+                with jax.named_scope(SCOPE_SAMPLE):
+                    return jax.lax.dynamic_update_slice(
+                        vec, tok.astype(vec.dtype), (lane_idx,))
+
+            self._set_token_fn = DispatchedProgram(
+                jax.jit(set_token), key=lambda a: a[0].shape)
+        if not isinstance(token, jax.Array):
+            token = np.asarray([token], np.int32)
+        return self._set_token_fn(tok_dev, np.int32(lane), token)
 
     def _copy_tree(self, tree):
         """Jitted deep copy of a cache pytree. Continuation prefill DONATES
@@ -688,16 +731,17 @@ class ContinuousBatchingScheduler:
     def program_scopes(self) -> Dict[str, Dict[str, Optional[str]]]:
         """``{program_name: {hlo_instruction_name: op_name_path}}`` of every
         program this scheduler has dispatched: the engine's prefill and
-        decode programs (and the draft engine's), and its own splice, copy
-        and rewind programs (telemetry/scopes.py; see
+        decode programs (and the draft engine's), and its own splice,
+        first-token, copy and rewind programs (telemetry/scopes.py; see
         ``InferenceEngine.program_scopes``). ``_empty_cache`` fills its
         leaves eagerly and has no program of its own. After the window,
         never inside it."""
         programs = list(self.engine.step_programs())
         if self.draft_engine is not None:
             programs += self.draft_engine.step_programs()
-        programs += [p for p in (self._splice_fn, self._copy_fn,
-                                 self._rewind_fn) if p is not None]
+        programs += [p for p in (self._splice_fn, self._set_token_fn,
+                                 self._copy_fn, self._rewind_fn)
+                     if p is not None]
         return programs_scope_table(programs)
 
     def _draft_prefill(self, ids: np.ndarray, mask: np.ndarray,
@@ -962,16 +1006,48 @@ class ContinuousBatchingScheduler:
         already decoding finish normally, and the loop exits with the
         pending queue INTACT — the caller hands those (and nothing else)
         off via the journal.
+
+        The plain decode loop runs one step ahead of the host (module
+        docstring): ``stream_callback`` fires for step n's tokens while
+        the device computes step n+1. Whether ``run`` returns or raises
+        (a ``poll_fn`` or a callback may), it first waits for the step in
+        flight, whose result holds the donated cache; that step's tokens
+        are nobody's.
+
+        The loop itself is ``_run``, one Python frame further down, and
+        has to stay there: entered directly from the caller, the same
+        loop cost the benchmark's ramp (eleven prefill programs traced
+        and lowered under this frame) 2.8 s more host time on the v5e's
+        host, in this tree and in PR 31's alike (PERF.md, section 6,
+        PR 32). Rehearse ``setup_s`` on the chip after moving it.
         """
+        unread: list = []   # at most one decode step, dispatched, not read
+        try:
+            return self._run(poll_fn, unread)
+        finally:
+            for step_tok, _ in unread:
+                jax.block_until_ready(step_tok)
+
+    def _run(self, poll_fn, unread) -> ServingStats:
         self._ensure_compiled()
         eng = self.engine
         stats = ServingStats()
         lanes: List[Optional[_Lane]] = [None] * self.slots
+        use_spec = self.draft_engine is not None
+        # The plain loop's token vector lives on the device: a decode step
+        # takes the vector the step before it returned, with each
+        # admission's first token written into its lane. It and the rng
+        # start on the sharding those programs hand back, so that the
+        # first call of each is the one specialisation every later call
+        # hits. The speculative loop's next input is the host's
+        # acceptance test, so its vector stays a host array.
+        where = eng.topology.replicated()
         tok = np.zeros((self.slots,), np.int32)
+        tok_dev = None if use_spec else jax.device_put(tok, where)
         cache = self._empty_cache()
         eng._rng, rng = jax.random.split(eng._rng)
+        rng = jax.device_put(rng, where)
         temp = jnp.float32(self.temperature)
-        use_spec = self.draft_engine is not None
         draft_cache = draft_rng = None
         if use_spec:
             de = self.draft_engine
@@ -1030,6 +1106,21 @@ class ContinuousBatchingScheduler:
                 if done:
                     finish(lane_no, lane)
 
+        def deliver(step) -> None:
+            """Read a dispatched decode step's tokens, ``(its [slots]
+            token vector, the lanes as they stood at its dispatch)``, and
+            hand each to the lane it was computed for. A lane whose
+            request has ended since (it may already hold another) drops
+            its token: no callback, no journal record, no count."""
+            step_tok, owners = np.asarray(step[0]), step[1]
+            for lane_no, lane in enumerate(owners):
+                if lane is None:
+                    continue
+                if lanes[lane_no] is lane:
+                    emit(lane_no, lane, int(step_tok[lane_no]))
+                else:
+                    stats.decode_tokens_discarded += 1
+
         while True:
             if poll_fn is not None:
                 poll_fn()
@@ -1081,16 +1172,25 @@ class ContinuousBatchingScheduler:
                                           bucket, req)):
                                 first_tok, sub_cache, draft_sub = \
                                     self._admit_prefill(req, bucket)
+                                if not use_spec:
+                                    tok_dev = self._set_token(
+                                        tok_dev, lane_no, first_tok)
+                            if unread:
+                                # the device ends the step in flight before
+                                # the prefill queued behind it: the other
+                                # lanes get its tokens now, in the device's
+                                # order, not after the blocking read below
+                                deliver(unread.pop())
                             with span(SERVE_FIRST_TOKEN_READ):
                                 first_tok = int(
                                     np.asarray(first_tok).reshape(-1)[0])
                             with span(SERVE_SPLICE):
                                 cache = self._splice(
                                     cache, sub_cache, lane_no)
-                                if draft_sub is not None:
+                                if use_spec:
                                     draft_cache = self._splice(
                                         draft_cache, draft_sub, lane_no)
-                            tok[lane_no] = first_tok
+                                    tok[lane_no] = first_tok
                             lane = _Lane(req=req, comp=comp, emitted=replayed)
                             lanes[lane_no] = lane
                             emit(lane_no, lane, first_tok)
@@ -1157,20 +1257,40 @@ class ContinuousBatchingScheduler:
                 else:
                     # ONE fixed-shape decode step for all lanes (garbage
                     # lanes included — row-independent attention keeps
-                    # them harmless)
+                    # them harmless), dispatched from the token vector the
+                    # step before it left on the device and BEFORE that
+                    # step is read: its read, emits, callbacks, the stats
+                    # and the next poll run while this one computes. A
+                    # lane that turns out to have ended at the step before
+                    # has one garbage row more, as an empty lane has every
+                    # step: the row update is indexed [layer, lane, slot]
+                    # with mode="drop" (models/transformer_lm.py), so a
+                    # lane writes its own rows only and a slot past its
+                    # last position nowhere, and the lane's next splice
+                    # overwrites every row it holds.
+                    # The first step of a run is read at once: where
+                    # the decode program is compiled or loaded, it is in
+                    # that dispatch, and the tokens of a host that has
+                    # been away that long are due before the next poll.
+                    ahead = len(unread)
+                    read = ahead or stats.decode_steps == 0
                     with span(SERVE_DECODE_STEP,
-                              lanes_active=self._lanes_active):
-                        toks, _, cache, rng = eng._decode_k_fn(
-                            eng._params, jnp.asarray(tok), cache, rng,
-                            temp, 1)
+                              lanes_active=self._lanes_active, ahead=ahead):
+                        _, tok_dev, cache, rng = eng._decode_k_fn(
+                            eng._params, tok_dev, cache, rng, temp, 1)
                         stats.decode_steps += 1
-                        with span(SERVE_DECODE_READ):
-                            tok = np.asarray(toks[:, 0]).astype(
-                                np.int32).copy()
-                    for lane_no in range(self.slots):
-                        lane = lanes[lane_no]
-                        if lane is not None:
-                            emit(lane_no, lane, int(tok[lane_no]))
+                        stats.decode_steps_ahead += ahead
+                        unread.append((tok_dev, list(lanes)))
+                        if read:
+                            with span(SERVE_DECODE_READ):
+                                step = unread.pop(0)
+                                step = (np.asarray(step[0]), step[1])
+                    if read:
+                        deliver(step)
 
+        if unread:
+            # dispatched before the host read that the last lanes had
+            # ended: nobody's tokens
+            deliver(unread.pop())
         stats.wall_s = time.monotonic() - t_run0
         return stats

@@ -136,6 +136,25 @@ def test_one_admit_span_per_request_with_its_attributes(served):
         s[3]["decode_steps"] for s in iters)
 
 
+def test_decode_step_spans_say_whether_they_ran_ahead(served):
+    """``ahead`` is 1 on a step dispatched with the step before it unread,
+    whose read is then that span's child; 0 on the first step of the run,
+    which reads itself, and on the step after an admission, which read the
+    step in flight itself and has no ``serve.decode_read`` of its own."""
+    _, _, _, found = served
+    steps = [s for s in found if s[0] == spans.SERVE_DECODE_STEP]
+    reads = [s for s in found if s[0] == spans.SERVE_DECODE_READ]
+    assert {s[3]["ahead"] for s in steps} == {0, 1}
+    assert steps[0][3]["ahead"] == 0
+    for i, s in enumerate(steps):
+        assert len([r for r in reads if _inside(r, s)]) \
+            == (s[3]["ahead"] or i == 0)
+    assert sum(s[3]["ahead"] for s in steps) + 1 == len(reads)
+    for a in (s for s in found if s[0] == spans.SERVE_ADMIT):
+        later = [s for s in steps if s[1] >= a[2]]
+        assert not later or later[0][3]["ahead"] == 0
+
+
 def test_greedy_tokens_identical_with_and_without_a_session(served):
     sched, _, traced_tokens, _ = served
     _, plain = _serve(sched)

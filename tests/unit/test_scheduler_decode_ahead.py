@@ -1,0 +1,530 @@
+"""The plain decode loop runs one step ahead of the host
+(``inference/scheduler.py``): step n+1 is dispatched from the token vector
+on the device before step n's tokens are read, so a lane's end is seen one
+step late. Under greedy decoding every request must still get exactly the
+stream a plain synchronous loop gives it, whatever the lanes do meanwhile;
+and every serving program must have ONE specialisation per shape from the
+first iteration on, which a CPU can count."""
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.inference import scheduler as scheduler_mod
+from deepspeed_tpu.inference.engine import InferenceEngine
+from deepspeed_tpu.inference.scheduler import ContinuousBatchingScheduler
+from deepspeed_tpu.models.transformer_lm import GPT, GPTConfig
+from deepspeed_tpu.parallel.mesh import set_default_topology
+
+BUCKET = 8
+SLOTS = 16
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# what the speculative case below read at b1e26aa, before the plain loop
+# changed: the branch it must not touch
+SPEC_DECODE_STEPS_AT_PARENT = 5
+_compiled = []      # names of the programs XLA compiled, in order
+jax.monitoring.register_event_duration_secs_listener(
+    lambda name, secs, **kw: _compiled.append(kw.get("fun_name", "?"))
+    if name == COMPILE_EVENT else None)
+
+
+def _engine(**kw):
+    cfg = GPTConfig(vocab_size=128, n_positions=256, n_embd=32, n_layer=2,
+                    n_head=4, dtype=jnp.float32, scan_layers=True, **kw)
+    return InferenceEngine(GPT(cfg), {"dtype": "fp32"}, seed=0)
+
+
+@pytest.fixture(scope="module")
+def eng():
+    return _engine()
+
+
+@pytest.fixture(scope="module")
+def sched(eng):
+    """One scheduler for the cases that leave it reusable: a ``run``
+    starts from an empty cache and no lanes every time."""
+    return ContinuousBatchingScheduler(eng, slots=SLOTS,
+                                       prompt_bucket=BUCKET)
+
+
+_reference_memo = {}
+
+
+def reference(eng, prompt, max_new, eos=None):
+    """The stream of one request from a plain synchronous loop over a
+    batch of one: prefill the left-padded prompt, take the argmax, then
+    one decode step per token, each read before the next is dispatched,
+    until ``max_new`` tokens or ``eos``."""
+    key = (tuple(prompt), max_new)
+    if key not in _reference_memo:
+        set_default_topology(eng.topology)
+        if eng._prefill_fn is None:
+            eng._materialize(jnp.zeros((1, BUCKET), jnp.int32))
+            eng._build_decode_fns()
+        lp = -(-len(prompt) // BUCKET) * BUCKET
+        ids = np.zeros((1, lp), np.int32)
+        mask = np.zeros((1, lp), bool)
+        ids[0, lp - len(prompt):] = prompt
+        mask[0, lp - len(prompt):] = True
+        logits, cache = eng._chunked_prefill(jnp.asarray(ids),
+                                             jnp.asarray(mask))
+        tok = np.asarray(jnp.argmax(logits, axis=-1)).astype(np.int32)
+        out = [int(tok[0])]
+        rng = jax.random.PRNGKey(0)
+        while len(out) < max_new:
+            toks, _, cache, rng = eng._decode_k_fn(
+                eng._params, jnp.asarray(tok), cache, rng,
+                jnp.float32(0.0), 1)
+            tok = np.asarray(toks[:, 0]).astype(np.int32)
+            out.append(int(tok[0]))
+        _reference_memo[key] = out
+    out = _reference_memo[key]
+    return out[:out.index(eos) + 1] if eos in out else out
+
+
+def _prompts(n, seed, lo=3, hi=30):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 128, size=int(k)).tolist()
+            for k in rng.integers(lo, hi, size=n)]
+
+
+class Recorder:
+    """A stream callback that keeps every request's tokens in order and
+    refuses a token after ``done`` or for a request it was not told of."""
+
+    def __init__(self):
+        self.tokens, self.done = {}, set()
+
+    def __call__(self, rid, token, done):
+        assert rid not in self.done, f"token for {rid} after done"
+        self.tokens.setdefault(rid, []).append(int(token))
+        if done:
+            self.done.add(rid)
+
+
+def _check_streams(eng, stats, rec, wants):
+    """``wants``: request id -> (prompt, max_new, eos)."""
+    got = {c.request_id: c.tokens for c in stats.completions}
+    assert sorted(got) == sorted(wants)
+    for rid, (prompt, max_new, eos) in wants.items():
+        assert got[rid] == reference(eng, prompt, max_new, eos), rid
+        assert rec.tokens[rid] == got[rid], rid     # streamed == returned
+    assert rec.done == set(wants)
+
+
+# ---------------------------------------------------------------------------
+# (a) sixteen lanes kept full by a resubmitting callback (the closed loop)
+# ---------------------------------------------------------------------------
+def test_lanes_kept_full_by_a_resubmitting_callback(eng, sched):
+    prompts = _prompts(48, seed=1)
+    outs = [2 + (i * 5) % 11 for i in range(len(prompts))]
+    rec, wants, todo = Recorder(), {}, list(zip(prompts, outs))
+
+    def submit():
+        prompt, want = todo.pop(0)
+        wants[sched.submit(prompt, max_new_tokens=want,
+                           stream_callback=on_token)] = (prompt, want, None)
+
+    def on_token(rid, token, done):
+        rec(rid, token, done)
+        if done and todo:
+            submit()                    # from inside the callback
+
+    for _ in range(SLOTS):
+        submit()
+    stats = sched.run()
+    _check_streams(eng, stats, rec, wants)
+    assert len(wants) == len(prompts)
+    # the loop engaged: most steps left before the step before was read,
+    # and each request that ended beside a live lane cost one dropped token
+    assert stats.decode_steps_ahead > stats.decode_steps // 2
+    assert 0 < stats.decode_tokens_discarded <= len(prompts) + SLOTS
+    assert stats.summary()["decode_steps_ahead"] == stats.decode_steps_ahead
+
+
+# ---------------------------------------------------------------------------
+# (b) lanes thinning to empty, an idle poll_fn, then new arrivals (the open
+# loop's ramp and the opening of its window)
+# ---------------------------------------------------------------------------
+def test_lanes_thin_to_empty_then_new_arrivals(eng, sched):
+    first = _prompts(SLOTS, seed=2)
+    later = _prompts(10, seed=3)
+    rec, wants = Recorder(), {}
+    state = {"polls": 0, "idle_polls": 0, "waves": 0}
+
+    def submit(prompt, want):
+        wants[sched.submit(prompt, max_new_tokens=want,
+                           stream_callback=rec)] = (prompt, want, None)
+
+    def poll():
+        state["polls"] += 1
+        if len(rec.done) < len(wants):
+            return                      # lanes thin, nothing arrives
+        # the system is empty: wait here, as the open loop's generator
+        # does, then let more requests arrive; twice, then stop
+        state["idle_polls"] += 1
+        time.sleep(0.002)
+        if state["waves"] < 2:
+            state["waves"] += 1
+            for prompt in later[:3] if state["waves"] == 1 else later[3:]:
+                submit(prompt, 3 + len(prompt) % 5)
+
+    for c, prompt in enumerate(first):
+        submit(prompt, 2 * (c + 1))     # staggered: 2, 4, ... 32 tokens
+    stats = sched.run(poll_fn=poll)
+    _check_streams(eng, stats, rec, wants)
+    assert len(wants) == SLOTS + len(later) and state["idle_polls"] >= 2
+    # poll_fn ran before every iteration: one per decode step at least
+    assert state["polls"] >= stats.decode_steps
+
+
+# ---------------------------------------------------------------------------
+# (c) where a request ends: EOS at the first, a middle and the last token,
+# one token asked for
+# ---------------------------------------------------------------------------
+def _with_first_occurrence_at(eng, index, length=9):
+    """A prompt whose reference stream of ``length`` tokens holds its
+    ``index``-th token nowhere before ``index``."""
+    for seed in range(200):
+        prompt = _prompts(1, 1000 + seed)[0]
+        ref = reference(eng, prompt, length)
+        if ref.index(ref[index]) == index:
+            return prompt, ref[index]
+    raise AssertionError("no such prompt among 200")
+
+
+@pytest.mark.parametrize("where", ["first", "middle", "last"])
+def test_eos_ends_one_lane_and_no_token_follows_it(eng, sched, where):
+    length = 9
+    index = {"first": 0, "middle": 4, "last": length - 1}[where]
+    prompt, eos = _with_first_occurrence_at(eng, index, length)
+    others = _prompts(5, seed=3)
+    rec, wants = Recorder(), {}
+    ending = sched.submit(prompt, max_new_tokens=length, eos_token_id=eos,
+                          stream_callback=rec)
+    wants[ending] = (prompt, length, eos)
+    for p in others:                    # neighbours that outlive it
+        wants[sched.submit(p, max_new_tokens=length + 4,
+                           stream_callback=rec)] = (p, length + 4, None)
+    stats = sched.run()
+    _check_streams(eng, stats, rec, wants)
+    assert len(rec.tokens[ending]) == index + 1
+
+
+def test_one_token_asked_for_ends_at_admission(eng, sched):
+    prompts = _prompts(SLOTS + 6, seed=4)
+    rec, wants = Recorder(), {}
+    for i, p in enumerate(prompts):     # every third request wants one
+        want = 1 if i % 3 == 0 else 6
+        wants[sched.submit(p, max_new_tokens=want,
+                           stream_callback=rec)] = (p, want, None)
+    stats = sched.run()
+    _check_streams(eng, stats, rec, wants)
+
+
+def test_every_request_ending_at_admission_runs_no_decode_step(eng, sched):
+    rec, wants = Recorder(), {}
+    for p in _prompts(5, seed=5):
+        wants[sched.submit(p, max_new_tokens=1,
+                           stream_callback=rec)] = (p, 1, None)
+    stats = sched.run()
+    _check_streams(eng, stats, rec, wants)
+    assert stats.decode_steps == 0 and stats.decode_tokens_discarded == 0
+
+
+# ---------------------------------------------------------------------------
+# (d) begin_drain with a step in flight; no step outlives run()
+# ---------------------------------------------------------------------------
+def test_drain_with_a_step_in_flight_finishes_lanes_keeps_queue(eng):
+    sched = ContinuousBatchingScheduler(eng, slots=4, prompt_bucket=BUCKET)
+    prompts = _prompts(7, seed=6)
+    rec, wants = Recorder(), {}
+
+    def on_token(rid, token, done):
+        rec(rid, token, done)
+        # the third token of request 1 arrives while the next step runs
+        if rid == 1 and len(rec.tokens[1]) == 3:
+            sched.begin_drain("test")
+
+    rids = [sched.submit(p, max_new_tokens=8, stream_callback=on_token)
+            for p in prompts]
+    stats = sched.run()
+    for rid in rids[:4]:
+        wants[rid] = (prompts[rid], 8, None)
+    _check_streams(eng, stats, rec, wants)              # lanes finished
+    assert [r.request_id for r, _ in sched._pending] == rids[4:]    # intact
+    assert stats.decode_steps_ahead > 0
+
+
+@pytest.mark.parametrize("how", ["returns", "poll_fn_raises",
+                                 "callback_raises"])
+def test_no_step_is_left_in_flight_when_run_ends(eng, monkeypatch, how):
+    """Every token vector a decode step returned is read by the host, or
+    the step is waited for before ``run`` hands control back."""
+    sched = ContinuousBatchingScheduler(eng, slots=4, prompt_bucket=BUCKET)
+    dispatched, waited = [], []
+    sched._ensure_compiled()            # the engine builds its programs
+    real = eng._decode_k_fn
+
+    def spy(*args):
+        out = real(*args)
+        dispatched.append(out[1])
+        return out
+
+    monkeypatch.setattr(eng, "_decode_k_fn", spy)
+    real_wait = jax.block_until_ready
+    monkeypatch.setattr(
+        scheduler_mod.jax, "block_until_ready",
+        lambda x: waited.append(x) or real_wait(x))
+
+    class Stop(Exception):
+        pass
+
+    polls = {"n": 0}
+
+    def poll():
+        polls["n"] += 1
+        if how == "poll_fn_raises" and polls["n"] == 4:
+            raise Stop
+
+    def on_token(rid, token, done):
+        if how == "callback_raises" and rid == 2 and done:
+            raise Stop
+
+    for i, p in enumerate(_prompts(6, seed=7)):
+        sched.submit(p, max_new_tokens=3 + i, stream_callback=on_token)
+    if how == "returns":
+        stats = sched.run(poll_fn=poll)
+        # every step was read: the last one, dispatched for lanes that
+        # had all ended, on the way out (its tokens are nobody's)
+        assert len(stats.completions) == 6 and not waited
+        assert len(dispatched) == stats.decode_steps
+        assert stats.decode_tokens_discarded >= 1
+    else:
+        with pytest.raises(Stop):
+            sched.run(poll_fn=poll)
+        # poll_fn runs with a step dispatched and unread; a callback runs
+        # there too, or inside an admission that has just read it
+        assert how == "callback_raises" or len(waited) == 1
+    assert dispatched and all(w is dispatched[-1] for w in waited)
+
+
+# ---------------------------------------------------------------------------
+# (e) a deadline shed between two steps
+# ---------------------------------------------------------------------------
+def test_deadline_shed_between_two_steps(eng):
+    sched = ContinuousBatchingScheduler(eng, slots=2, prompt_bucket=BUCKET)
+    prompts = _prompts(5, seed=8)
+    rec, wants, shed = Recorder(), {}, []
+    sched.reject_callback = lambda rid, reason: shed.append((rid, reason))
+
+    def on_token(rid, token, done):
+        rec(rid, token, done)
+        if rid == 0 and len(rec.tokens[0]) == 2:
+            # queued behind two full lanes with a budget that is spent
+            # before either frees up: shed at the admission that pops it
+            doomed = sched.submit(prompts[4], max_new_tokens=4,
+                                  stream_callback=rec, deadline_s=1e-4)
+            shed.append(("submitted", doomed))
+            time.sleep(0.002)
+
+    for i in range(4):
+        want = 6 + i
+        wants[sched.submit(prompts[i], max_new_tokens=want,
+                           stream_callback=on_token)] = (prompts[i], want,
+                                                         None)
+    stats = sched.run()
+    _check_streams(eng, stats, rec, wants)
+    doomed = dict(shed)["submitted"]
+    assert (doomed, "deadline") in shed and doomed not in rec.tokens
+    assert sched.deadline_shed_count == 1
+
+
+# ---------------------------------------------------------------------------
+# (f) journal replay after a kill between a step's dispatch and its read
+# ---------------------------------------------------------------------------
+class Journal:
+    """What the scheduler tells a journal, held to its contract: tokens
+    only for requests it knows, none after ``done``."""
+
+    def __init__(self):
+        self.entries = {}
+
+    def record_submit(self, rid, prompt, max_new_tokens, deadline=None,
+                      emitted=()):
+        assert rid not in self.entries
+        self.entries[rid] = {"prompt": list(prompt), "max": max_new_tokens,
+                             "tokens": list(emitted), "done": False}
+
+    def record_token(self, rid, token, done=False):
+        e = self.entries[rid]
+        assert not e["done"], f"token recorded for {rid} after done"
+        e["tokens"].append(int(token))
+        e["done"] = bool(done)
+
+    def record_shed(self, rid):
+        self.entries[rid]["done"] = True
+
+
+@pytest.mark.parametrize("kill_at_poll", [3, 6, 9])
+def test_journal_replay_after_a_kill_between_dispatch_and_read(
+        eng, kill_at_poll):
+    """``poll_fn`` runs with a step dispatched and unread; a kill there
+    loses that step's tokens and nothing else, and a replay of what the
+    journal holds gives every request its reference stream: no token
+    lost, none doubled, none recorded after ``done``."""
+    prompts = _prompts(6, seed=9)
+    wants = {i: (p, 5 + 2 * i, None) for i, p in enumerate(prompts)}
+    journal = Journal()
+    first = ContinuousBatchingScheduler(eng, slots=3, prompt_bucket=BUCKET,
+                                        journal=journal)
+
+    class Killed(Exception):
+        pass
+
+    polls = {"n": 0}
+
+    def poll():
+        polls["n"] += 1
+        if polls["n"] == kill_at_poll:
+            raise Killed
+
+    for i, (p, want, _) in wants.items():
+        assert first.submit(p, max_new_tokens=want) == i
+    with pytest.raises(Killed):
+        first.run(poll_fn=poll)
+    held = {i: dict(e, tokens=list(e["tokens"]))
+            for i, e in journal.entries.items()}
+    assert any(e["tokens"] and not e["done"] for e in held.values())
+    for i, e in held.items():           # a prefix of the truth, no more
+        ref = reference(eng, *wants[i])
+        assert e["tokens"] == ref[:len(e["tokens"])]
+        assert e["done"] == (len(e["tokens"]) == len(ref))
+
+    # the survivor replays every open entry under its original budget
+    journal2 = Journal()
+    second = ContinuousBatchingScheduler(eng, slots=3, prompt_bucket=BUCKET,
+                                         journal=journal2)
+    rec, new_of = Recorder(), {}
+    for i, e in held.items():
+        if not e["done"]:
+            new_of[second.submit(e["prompt"], max_new_tokens=e["max"],
+                                 stream_callback=rec,
+                                 replay_tokens=e["tokens"])] = i
+    stats = second.run()
+    assert sorted(c.request_id for c in stats.completions) == sorted(new_of)
+    for c in stats.completions:
+        i = new_of[c.request_id]
+        ref = reference(eng, *wants[i])
+        assert c.tokens == ref                              # none lost
+        assert held[i]["tokens"] + rec.tokens.get(c.request_id, []) == ref
+        assert journal2.entries[c.request_id]["tokens"] == ref
+        assert journal2.entries[c.request_id]["done"]
+
+
+# ---------------------------------------------------------------------------
+# (g) the speculative branch stays synchronous
+# ---------------------------------------------------------------------------
+def test_speculative_branch_is_synchronous_as_before(eng):
+    draft = _engine()                   # same weights: accepts k-1 of k
+    sched = ContinuousBatchingScheduler(eng, slots=3, prompt_bucket=BUCKET,
+                                        draft_engine=draft, spec_k=4)
+    prompts = _prompts(5, seed=10)
+    rec, wants = Recorder(), {}
+    for i, p in enumerate(prompts):
+        wants[sched.submit(p, max_new_tokens=7 + i,
+                           stream_callback=rec)] = (p, 7 + i, None)
+    stats = sched.run()
+    _check_streams(eng, stats, rec, wants)
+    # as at the parent (b1e26aa, same requests): 3 accepted drafts and the
+    # target's own token a step, lanes refilled as they end
+    assert stats.decode_steps == SPEC_DECODE_STEPS_AT_PARENT
+    assert stats.decode_steps_ahead == 0
+    assert stats.decode_tokens_discarded == 0
+    assert sched._set_token_fn is None  # its token vector is the host's
+
+
+
+# ---------------------------------------------------------------------------
+# the set-up invariant: one specialisation of every program per shape
+# ---------------------------------------------------------------------------
+def test_one_specialisation_of_every_program_and_no_compile_after_warm_up():
+    """Shape (b), then shape (a), on a fresh engine. The first iteration
+    admits one request into every lane and every prefill bucket; after it
+    and two decode steps nothing compiles, and every jitted program holds
+    one executable per shape. A token vector or an rng that is uncommitted
+    on the first call and committed from the second (a jitted call's
+    results are, when an argument is) would be a second specialisation of
+    the decode program: a second lowering and a second load of the largest
+    program there is, about a second of every set-up on the chip."""
+    eng = _engine()
+    ref_eng = _engine()                 # same seed, same weights
+    sched = ContinuousBatchingScheduler(eng, slots=SLOTS,
+                                        prompt_bucket=BUCKET)
+    buckets = (8, 16, 24, 32)
+    rng = np.random.default_rng(11)
+
+    def prompt_in(bucket):
+        return rng.integers(1, 128,
+                            size=int(rng.integers(bucket - 7,
+                                                  bucket + 1))).tolist()
+
+    # (b): sixteen lanes, every bucket, staggered ends, then arrivals
+    rec, wants = Recorder(), {}
+    state = {"polls": 0, "compiled_at_warm": None, "arrived": False}
+
+    def submit(prompt, want, cb):
+        wants[sched.submit(prompt, max_new_tokens=want,
+                           stream_callback=cb)] = (prompt, want, None)
+
+    def poll():
+        state["polls"] += 1
+        if state["polls"] == 3:         # admissions + two steps are behind
+            state["compiled_at_warm"] = len(_compiled)
+        if len(rec.done) == len(wants) and not state["arrived"]:
+            state["arrived"] = True
+            for b in buckets:
+                submit(prompt_in(b), 5, rec)
+
+    for c in range(SLOTS):
+        submit(prompt_in(buckets[c % len(buckets)]), 3 * (c + 1), rec)
+    before = len(_compiled)
+    stats_b = sched.run(poll_fn=poll)
+    assert state["arrived"] and stats_b.decode_steps_ahead > 0
+
+    # (a): the same scheduler, lanes kept full from the callback
+    todo = [(prompt_in(buckets[i % len(buckets)]), 2 + i % 9)
+            for i in range(40)]
+
+    def on_token(rid, token, done):
+        rec(rid, token, done)
+        if done and todo:
+            submit(*todo.pop(0), on_token)
+
+    for _ in range(SLOTS):
+        submit(*todo.pop(0), on_token)
+    stats_a = sched.run()
+
+    after_warm = _compiled[state["compiled_at_warm"]:]
+    assert after_warm == [], after_warm
+    in_warm = _compiled[before:state["compiled_at_warm"]]
+    assert in_warm.count("jit(decode_k)") == 1, in_warm
+    assert in_warm.count("jit(splice)") == 1, in_warm
+    assert in_warm.count("jit(set_token)") == 1, in_warm
+    assert in_warm.count("jit(prefill)") == len(buckets), in_warm
+    assert list(eng._decode_k_fn.avals) == [((SLOTS,), 1)]     # k stays 1
+    assert eng._decode_k_fn.fn._cache_size() == 1
+    assert sched._splice_fn.fn._cache_size() == 1
+    assert sched._set_token_fn.fn._cache_size() == 1
+    assert sorted(eng._prefill_fn.avals) == [(1, b) for b in buckets]
+    assert eng._prefill_fn.fn._cache_size() == len(buckets)
+    assert eng._prefill_more_fn.fn._cache_size() == 0
+    # and the streams are the reference's (from the twin engine, so that
+    # its batch of one is no specialisation of this engine's programs)
+    got = {c.request_id: c.tokens
+           for c in stats_b.completions + stats_a.completions}
+    assert sorted(got) == sorted(wants)
+    for rid, (prompt, want, _) in wants.items():
+        assert got[rid] == reference(ref_eng, prompt, want), rid
