@@ -30,7 +30,9 @@ from deepspeed_tpu.parallel.mesh import MeshTopology, set_default_topology
 from deepspeed_tpu.runtime.checkpoint_engine import MsgpackCheckpointEngine
 from deepspeed_tpu.runtime.zero.sharding import ZeroShardingRules
 from deepspeed_tpu.telemetry.scopes import (
+    SCOPE_KV_CACHE_CARRY,
     SCOPE_SAMPLE,
+    SCOPE_SSM_STATE_CARRY,
     DispatchedProgram,
     scope_table,
 )
@@ -65,14 +67,37 @@ def kv_leaf_shapes(tree):
     return shapes
 
 
+def ssm_leaf_shapes(tree):
+    """The same for the Mamba-2 mixer's leaves (``ssm_state``,
+    ``conv_tail``; models/mamba2.py): the leaf as stored and, of a stacked
+    state, one layer's slice. One layer's convolution tail is what the
+    convolution itself concatenates in front of its input (kilobytes a
+    lane), so only the stacked tail counts as a whole leaf."""
+    from deepspeed_tpu.models.mamba2 import CONV_TAIL, SSM_STATE
+
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        name = str(getattr(path[-1], "key", ""))
+        if name not in (SSM_STATE, CONV_TAIL):
+            continue
+        shape = tuple(leaf.shape)
+        shapes.add(shape)
+        if name == SSM_STATE and len(shape) > 4:
+            shapes.add(shape[1:])
+    return shapes
+
+
 def programs_scope_table(programs):
     """``scope_table`` of ``DispatchedProgram``s, with ``kv_cache_carry``
-    for the KV-cache leaves among their arguments and results."""
+    for the KV-cache leaves among their arguments and results and
+    ``ssm_state_carry`` for the recurrent-state leaves."""
     lowered = [(avals, low) for prog in programs
                for avals, low in zip(prog.avals.values(), prog.lowered())]
-    carry = set()
+    carry = {SCOPE_KV_CACHE_CARRY: set(), SCOPE_SSM_STATE_CARRY: set()}
     for avals, low in lowered:
-        carry |= kv_leaf_shapes((avals, low.out_info))
+        carry[SCOPE_KV_CACHE_CARRY] |= kv_leaf_shapes((avals, low.out_info))
+        carry[SCOPE_SSM_STATE_CARRY] |= ssm_leaf_shapes(
+            (avals, low.out_info))
     return scope_table((low.compile().as_text() for _, low in lowered),
                        carry)
 
@@ -438,6 +463,18 @@ class InferenceEngine:
                 # host-placement path above exists to avoid)
                 self._params = jax.jit(
                     lambda r: quantize_block_params(init_fn(r)),
+                    out_shardings=self._param_shardings)(rng)
+            elif self.dtype in (jnp.float16, jnp.bfloat16) and any(
+                    sd.dtype != self.dtype
+                    and jnp.issubdtype(sd.dtype, jnp.floating)
+                    for sd in jax.tree.leaves(shapes)):
+                # a model whose param_dtype is wider than the serving
+                # dtype: cast inside the one jit, so that the weights are
+                # born in the serving dtype and the wide tree is an
+                # internal value XLA frees leaf by leaf (5 B parameters
+                # are 21 GB in float32 and 10.5 in bf16)
+                self._params = jax.jit(
+                    lambda r: self._cast(init_fn(r)),
                     out_shardings=self._param_shardings)(rng)
             else:
                 self._params = jax.jit(

@@ -33,7 +33,12 @@ its lanes:
 Free lanes keep decoding garbage tokens — attention is row-independent and
 the masked softmax is NaN-safe, so a garbage lane costs FLOPs but never
 contaminates a neighbor; its next admission overwrites every cache row it
-touched.
+touched. A hybrid model's recurrent state and convolution tail
+(``GPTConfig.ssm``, models/mamba2.py) are leaves of the same cache: a
+finished lane's state goes on absorbing garbage tokens, is read by nobody,
+and is overwritten whole by the splice of the lane's next admission. They
+cannot be truncated to a prefix, so such a model refuses speculative
+decoding and the prefix cache (``RecurrentStateError``).
 
 Prompts are LEFT-padded to a ``prompt_bucket`` multiple to bound prefill
 compile count (bucket is a multiple of the layout block for ring models,
@@ -114,6 +119,19 @@ class DeadlineExceededError(AdmissionRejected):
         super().__init__(message, reason="deadline")
 
 
+class RecurrentStateError(ValueError):
+    """A serving feature that truncates a cache to a shorter prefix was
+    asked of a model whose blocks hold recurrent state
+    (``GPTConfig.ssm``): keys and values of the first ``n`` positions are
+    a prefix's cache, a state after ``m > n`` tokens is not."""
+
+    def __init__(self, feature: str, why: str):
+        super().__init__(
+            f"{feature} cannot serve a model with recurrent state "
+            f"(GPTConfig.ssm): {why}")
+        self.feature = feature
+
+
 class DrainingError(AdmissionRejected):
     """Admission is closed: the scheduler is draining (SIGTERM)."""
 
@@ -179,6 +197,33 @@ class _Lane:
     req: Request
     comp: Completion
     emitted: int = 0
+
+
+class LanesAtExit:
+    """What ``run`` left on the device when it ended with a decode step in
+    flight (``ContinuousBatchingScheduler.retain_lanes``): the lane cache
+    as that step left it, and ``live``, lane number -> the ``Completion``
+    so far of the request that still held the lane.
+
+    A live lane's rows, its recurrent state included, have taken in the
+    request's prompt and every token of ``Completion.tokens``: the step in
+    flight consumed the last of them, and what it computed is nobody's.
+    That holds for a run ended from ``poll_fn``, between two steps; a
+    ``stream_callback`` that raises ends it inside a step's delivery, and
+    the lanes after its own are then one undelivered token ahead."""
+
+    def __init__(self, owners, cache):
+        self.cache = cache
+        self.live = {n: lane.comp for n, lane in enumerate(owners)
+                     if lane is not None and not lane.comp.t_done}
+
+    def recurrent_state(self, lane: int):
+        """``{"ssm_state": [layers, H, P, N], "conv_tail": [layers, K - 1,
+        C]}`` of one lane, as stored (models/mamba2.py); empty for a model
+        without a mixer."""
+        from deepspeed_tpu.models.mamba2 import lane_state
+
+        return lane_state(self.cache, lane)
 
 
 @dataclass
@@ -291,6 +336,21 @@ class ContinuousBatchingScheduler:
 
         self._ring = ring_engaged(self._mcfg) if self._mcfg is not None \
             else None
+        if getattr(self._mcfg, "ssm", None) is not None:
+            # refused here, by name, and not by a wrong answer later
+            if draft_engine is not None:
+                raise RecurrentStateError(
+                    "draft_engine (speculative decoding)",
+                    "_rewind steps the cache clocks back past the "
+                    "rejected tokens, and a state that has absorbed them "
+                    "cannot be stepped back")
+            if prefix_cache is not None:
+                raise RecurrentStateError(
+                    "prefix_cache",
+                    "an entry is a cache cut at a promotion boundary, and "
+                    "the state of a longer prompt cannot be cut there (a "
+                    "snapshot of the state at the boundary would do; "
+                    "serving/prefix_cache.py takes none)")
         if prompt_bucket is None:
             prompt_bucket = self._ring[2] if self._ring is not None else 64
         if self._ring is not None and prompt_bucket % self._ring[2] != 0:
@@ -362,6 +422,13 @@ class ContinuousBatchingScheduler:
         self._rewind_fn = None
         self._empty_cache_shapes = None
         self._kv_stats_static = None
+        self._cache_plan_published = False
+        # set to keep what a run that ends with a step in flight leaves on
+        # the device (``LanesAtExit``) in ``lanes_at_exit`` until the next
+        # run or until the holder drops it: a whole lane cache stays
+        # allocated that long, so it is off unless somebody will look
+        self.retain_lanes = False
+        self.lanes_at_exit: Optional[LanesAtExit] = None
 
     # ------------------------------------------------------------------
     def submit(self, prompt: Sequence[int], max_new_tokens: int = 32,
@@ -539,6 +606,18 @@ class ContinuousBatchingScheduler:
                 jnp.zeros((1, self._bucketed_len(t_probe)), jnp.int32))
         if eng._prefill_fn is None:
             eng._build_decode_fns()
+        if not self._cache_plan_published:
+            from deepspeed_tpu.telemetry.bus import (
+                KIND_SERVE_CACHE_PLAN,
+                publish,
+            )
+
+            self._cache_plan_published = True
+            kv = self._kv_geometry()
+            publish(KIND_SERVE_CACHE_PLAN, slots=self.slots,
+                    **{k: kv[k] for k in (
+                        "kv_bytes_per_lane", "state_bytes_per_lane",
+                        "conv_bytes_per_lane", "bytes_per_lane")})
         de = self.draft_engine
         if de is None:
             return
@@ -891,41 +970,13 @@ class ContinuousBatchingScheduler:
         factor, and with a known HBM size (telemetry/memory.hbm_bytes)
         ``lanes_at_hbm_budget`` says how many decode lanes of THIS
         per-lane footprint fit the part — the capacity number the
-        disaggregated-serving sizing tables are built from."""
+        disaggregated-serving sizing tables are built from. A hybrid
+        model's recurrent state and convolution tail are counted apart
+        from keys, values and clocks (``state_bytes``, ``conv_bytes``,
+        ``kv_bytes``, each also ``_per_lane``)."""
         from deepspeed_tpu.telemetry.memory import hbm_bytes
 
-        if self._kv_stats_static is None:
-            shapes = self._cache_shapes()
-            compute_dt = jnp.dtype(getattr(self._mcfg, "dtype",
-                                           jnp.float32))
-            resident = 0
-            unquant = 0
-
-            def acc(path, sd):
-                nonlocal resident, unquant
-                name = path[-1].key if hasattr(path[-1], "key") \
-                    else path[-1]
-                nbytes = sd.size * jnp.dtype(sd.dtype).itemsize
-                resident += nbytes
-                if name in ("cached_key", "cached_value"):
-                    unquant += sd.size * compute_dt.itemsize
-                elif name in ("cached_key_scale", "cached_value_scale"):
-                    pass  # sideband of the int8 store; the twin has none
-                else:
-                    unquant += nbytes
-
-            jax.tree_util.tree_map_with_path(acc, shapes)
-            self._kv_stats_static = {
-                "kv_cache_dtype": (getattr(self._mcfg, "kv_cache_dtype",
-                                           None) or "compute"),
-                "resident_bytes": int(resident),
-                "unquantized_bytes": int(unquant),
-                "bytes_per_lane": int(resident // self.slots),
-                "lanes": self.slots,
-                "compression_ratio": (float(unquant) / float(resident)
-                                      if resident else 1.0),
-            }
-        out = dict(self._kv_stats_static)
+        out = dict(self._kv_geometry())
         hbm, source = hbm_bytes(override_gib=hbm_override_gib)
         if hbm:
             out["hbm_bytes"] = int(hbm)
@@ -934,6 +985,55 @@ class ContinuousBatchingScheduler:
             out["lanes_at_hbm_budget"] = (int(hbm // per_lane)
                                           if per_lane else 0)
         return out
+
+    def _kv_geometry(self) -> Dict[str, Any]:
+        """What ``kv_cache_stats`` says without asking for the HBM size:
+        the bytes of the memoized leaf geometry, computed once."""
+        if self._kv_stats_static is None:
+            shapes = self._cache_shapes()
+            compute_dt = jnp.dtype(getattr(self._mcfg, "dtype",
+                                           jnp.float32))
+            resident = 0
+            unquant = 0
+            from deepspeed_tpu.models.mamba2 import CONV_TAIL, SSM_STATE
+
+            # the mixer's leaves apart from keys, values and their clocks
+            apart = {SSM_STATE: 0, CONV_TAIL: 0}
+
+            def acc(path, sd):
+                nonlocal resident, unquant
+                name = path[-1].key if hasattr(path[-1], "key") \
+                    else path[-1]
+                nbytes = sd.size * jnp.dtype(sd.dtype).itemsize
+                resident += nbytes
+                if name in apart:
+                    apart[name] += nbytes
+                if name in ("cached_key", "cached_value"):
+                    unquant += sd.size * compute_dt.itemsize
+                elif name in ("cached_key_scale", "cached_value_scale"):
+                    pass  # sideband of the int8 store; the twin has none
+                else:
+                    unquant += nbytes
+
+            jax.tree_util.tree_map_with_path(acc, shapes)
+            kv_bytes = resident - sum(apart.values())
+            self._kv_stats_static = {
+                "kv_cache_dtype": (getattr(self._mcfg, "kv_cache_dtype",
+                                           None) or "compute"),
+                "resident_bytes": int(resident),
+                "unquantized_bytes": int(unquant),
+                "bytes_per_lane": int(resident // self.slots),
+                "state_bytes": int(apart[SSM_STATE]),
+                "conv_bytes": int(apart[CONV_TAIL]),
+                "kv_bytes": int(kv_bytes),
+                "state_bytes_per_lane": int(apart[SSM_STATE] // self.slots),
+                "conv_bytes_per_lane": int(apart[CONV_TAIL] // self.slots),
+                "kv_bytes_per_lane": int(kv_bytes // self.slots),
+                "lanes": self.slots,
+                "compression_ratio": (float(unquant) / float(resident)
+                                      if resident else 1.0),
+            }
+        return self._kv_stats_static
 
     def frontdoor_stats(self) -> Dict[str, Any]:
         """Shed + prefix-cache + health counters for benches/servers."""
@@ -1012,7 +1112,10 @@ class ContinuousBatchingScheduler:
         the device computes step n+1. Whether ``run`` returns or raises
         (a ``poll_fn`` or a callback may), it first waits for the step in
         flight, whose result holds the donated cache; that step's tokens
-        are nobody's.
+        are nobody's. With ``retain_lanes`` set, that cache and the
+        requests its live lanes held stay in ``lanes_at_exit``
+        (``LanesAtExit``) for whoever inspects or hands over what the run
+        left.
 
         The loop itself is ``_run``, one Python frame further down, and
         has to stay there: entered directly from the caller, the same
@@ -1022,11 +1125,14 @@ class ContinuousBatchingScheduler:
         PR 32). Rehearse ``setup_s`` on the chip after moving it.
         """
         unread: list = []   # at most one decode step, dispatched, not read
+        self.lanes_at_exit = None
         try:
             return self._run(poll_fn, unread)
         finally:
-            for step_tok, _ in unread:
-                jax.block_until_ready(step_tok)
+            for step in unread:
+                jax.block_until_ready(step[0])
+            if self.retain_lanes and unread:
+                self.lanes_at_exit = LanesAtExit(*unread[-1][1:])
 
     def _run(self, poll_fn, unread) -> ServingStats:
         self._ensure_compiled()
@@ -1280,7 +1386,7 @@ class ContinuousBatchingScheduler:
                             eng._params, tok_dev, cache, rng, temp, 1)
                         stats.decode_steps += 1
                         stats.decode_steps_ahead += ahead
-                        unread.append((tok_dev, list(lanes)))
+                        unread.append((tok_dev, list(lanes), cache))
                         if read:
                             with span(SERVE_DECODE_READ):
                                 step = unread.pop(0)

@@ -15,7 +15,7 @@ Design is idiomatic JAX, not a translation:
 """
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -31,6 +31,49 @@ from deepspeed_tpu.telemetry.scopes import (
     SCOPE_LM_HEAD,
     SCOPE_LM_HEAD_CE,
 )
+
+
+@dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """The Mamba-2 (state-space duality) mixer that a block runs beside its
+    attention, on the same normalised input (models/mamba2.py; Falcon-H1's
+    ``mamba_*`` keys). ``n_heads * d_head`` is the mixer's inner width,
+    whatever the model's; ``multipliers`` are the muP factors on the input
+    projection's segments ``[z | x | B | C | dt]``. One form, the
+    published one: a bias on the convolution and none on the projections,
+    the gate first and then an RMS norm inside each group."""
+    n_heads: int
+    d_head: int
+    d_state: int
+    n_groups: int = 1
+    d_conv: int = 4
+    chunk: int = 128
+    in_multiplier: float = 1.0
+    out_multiplier: float = 1.0
+    multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    # the recurrent state's storage dtype in the decode cache; the
+    # arithmetic is float32 either way
+    state_dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        if self.n_heads % self.n_groups:
+            raise ValueError(
+                f"ssm n_heads ({self.n_heads}) must be divisible by "
+                f"n_groups ({self.n_groups})")
+        if len(self.multipliers) != 5:
+            raise ValueError("ssm multipliers are five: z, x, B, C, dt")
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.d_head
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.n_groups * self.d_state
+
+    @property
+    def in_proj_dim(self) -> int:
+        return self.d_inner + self.conv_dim + self.n_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,6 +230,26 @@ class GPTConfig:
     moe_norm_topk_prob: bool = False
     # the coefficient of the router z-loss, beside moe_aux_loss_coef
     moe_z_loss_coef: float = 0.0
+    # --- hybrid blocks (Falcon-H1) -----------------------------------------
+    # a Mamba-2 mixer beside attention in every block, both on ln_1's
+    # output, summed into one residual; None = attention alone. Its
+    # recurrent state and convolution tail live in the decode cache beside
+    # keys and values and cannot be rewound to a shorter prefix
+    ssm: Optional[SSMConfig] = None
+    # attention head size when it is not n_embd // n_head
+    attn_head_dim: Optional[int] = None
+    # muP multipliers, each applied where the published model applies it;
+    # 1.0 leaves the program as it is
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    mlp_gate_multiplier: float = 1.0
+    mlp_down_multiplier: float = 1.0
+    # a decode-path apply (prefill, decode step) computes the head at the
+    # last ``num_logits_to_keep`` positions only; None = every position
+    num_logits_to_keep: Optional[int] = None
 
     def __post_init__(self):
         if self.sequence_parallel not in ("none", "ring", "ulysses"):
@@ -249,7 +312,7 @@ class GPTConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.n_embd // self.n_head
+        return self.attn_head_dim or self.n_embd // self.n_head
 
     @property
     def kv_heads(self) -> int:
@@ -284,6 +347,16 @@ def gpt2_config(name: str, **overrides) -> GPTConfig:
     base = dict(GPT2_SIZES[name])
     base.update(overrides)
     return GPTConfig(**base)
+
+
+def scaled(x, multiplier):
+    """``x`` times a muP multiplier, the product taken in float32 and
+    rounded once: a bare Python float would be rounded to ``x``'s dtype
+    first (0.2% of a bf16 multiplier), and the published model multiplies
+    in float32."""
+    if multiplier == 1.0:
+        return x
+    return (x.astype(jnp.float32) * multiplier).astype(x.dtype)
 
 
 def _norm(cfg, name):
@@ -461,6 +534,7 @@ class CausalSelfAttention(nn.Module):
         q = qkv[..., : H * D].reshape(B, T, H, D)
         k = qkv[..., H * D:(H + Hkv) * D].reshape(B, T, Hkv, D)
         v = qkv[..., (H + Hkv) * D:].reshape(B, T, Hkv, D)
+        k = scaled(k, cfg.key_multiplier)
         if cfg.qk_norm:
             def whole(t, name):
                 return nn.RMSNorm(
@@ -650,7 +724,7 @@ class CausalSelfAttention(nn.Module):
                     where=None if ring is None else visible
                 ).astype(cfg.dtype)
                 y = jnp.einsum("bhgqk,bkhd->bqhgd", att, v_all)
-            y = y.reshape(B, T, C)
+            y = y.reshape(B, T, H * D)
             return nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
                             param_dtype=cfg.param_dtype, name="c_proj")(y)
 
@@ -680,7 +754,7 @@ class CausalSelfAttention(nn.Module):
                 kpm = jnp.where(mask, 0.0, jnp.finfo(jnp.float32).min)
             with jax.named_scope(SCOPE_ATTN_CORE):
                 y = sa(q, k, v, key_padding_mask=kpm, causal=cfg.causal)
-            y = y.reshape(B, T, C)
+            y = y.reshape(B, T, H * D)
             y = nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype, name="c_proj")(y)
             return nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
@@ -701,7 +775,7 @@ class CausalSelfAttention(nn.Module):
                            "ulysses": ulysses_attention}[cfg.sequence_parallel]
                 with jax.named_scope(SCOPE_ATTN_CORE):
                     y = attn_fn(q, k, v, causal=cfg.causal)
-                y = y.reshape(B, T, C)
+                y = y.reshape(B, T, H * D)
                 y = nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
                              param_dtype=cfg.param_dtype, name="c_proj")(y)
                 return nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
@@ -727,7 +801,7 @@ class CausalSelfAttention(nn.Module):
             with jax.named_scope(SCOPE_ATTN_CORE):
                 y = chunked_attention(q, k, v, causal=cfg.causal,
                                       chunk=eff_chunk)
-            y = y.reshape(B, T, C)
+            y = y.reshape(B, T, H * D)
             y = nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype, name="c_proj")(y)
             return nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
@@ -777,7 +851,7 @@ class CausalSelfAttention(nn.Module):
                 att = nn.Dropout(cfg.dropout)(
                     att, deterministic=deterministic)
                 y = jnp.einsum("bhqk,bkhd->bqhd", att, v)
-        y = y.reshape(B, T, C)
+        y = y.reshape(B, T, H * D)
         y = nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
                      param_dtype=cfg.param_dtype, name="c_proj")(y)
         y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
@@ -797,11 +871,12 @@ class MLP(nn.Module):
             # SwiGLU (LLaMA family): act(gate) * up — both column-parallel
             g = nn.Dense(cfg.ffn_dim, use_bias=cfg.use_bias, dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype, name="c_gate")(x)
-            h = act(g) * h
+            h = act(scaled(g, cfg.mlp_gate_multiplier)) * h
         else:
             h = act(h)
         h = nn.Dense(cfg.n_embd, use_bias=cfg.use_bias, dtype=cfg.dtype,
                      param_dtype=cfg.param_dtype, name="c_proj")(h)
+        h = scaled(h, cfg.mlp_down_multiplier)
         h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
         return h
 
@@ -820,11 +895,24 @@ class Block(nn.Module):
                  cache_layer=None):
         cfg = self.config
         x_in = x
+        u = _norm(cfg, "ln_1")(x)
         a = CausalSelfAttention(cfg, name="attn")(
-            _norm(cfg, "ln_1")(x),
+            scaled(u, cfg.attention_in_multiplier),
             mask=mask, segment_ids=segment_ids, positions=positions,
             deterministic=deterministic, decode=decode,
             cache_layer=cache_layer)
+        a = scaled(a, cfg.attention_out_multiplier)
+        if cfg.ssm is not None:
+            # the hybrid block: the mixer reads what attention reads and
+            # the two are summed into the one residual
+            from deepspeed_tpu.models.mamba2 import Mamba2Mixer
+
+            if segment_ids is not None:
+                raise NotImplementedError(
+                    "packed-sequence segment_ids with a recurrent mixer: "
+                    "the state would run across documents")
+            a = a + Mamba2Mixer(cfg, name="mamba")(
+                u, mask=mask, decode=decode, cache_layer=cache_layer)
         if cfg.parallel_residual:
             # GPT-J/NeoX form: attention and MLP both read the pre-residual
             # stream; GPT-J's single shared LN is expressed by loading
@@ -1171,6 +1259,7 @@ class GPT(nn.Module):
             cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="wte")
         x = wte(input_ids)
+        x = scaled(x, cfg.embedding_multiplier)
         if cfg.embed_layernorm:  # BLOOM word_embeddings_layernorm
             x = _norm(cfg, "ln_embed")(x)
         if cfg.learned_positions:
@@ -1231,6 +1320,10 @@ class GPT(nn.Module):
                                       keep)
                 l_aux = l_aux + aux_i
 
+        if decode and labels is None and cfg.num_logits_to_keep:
+            # the published ``num_logits_to_keep``: a prefill needs the
+            # head at its last position, not [T, vocab] logits
+            x = x[:, -cfg.num_logits_to_keep:]
         x = _norm(cfg, "ln_f")(x)
         # LM head (tied to wte, or a separate lm_head when untied): bf16
         # operands + fp32 accumulation keeps the MXU at full rate (a plain
@@ -1258,7 +1351,10 @@ class GPT(nn.Module):
                     preferred_element_type=jnp.float32)
                 if head_b is not None:
                     logits = logits + head_b.astype(logits.dtype)
+                logits = scaled(logits, cfg.lm_head_multiplier)
             return logits
+        # the training paths fold the head's multiplier into its input
+        x = scaled(x, cfg.lm_head_multiplier)
         # training path: the shift is expressed by zero-weighting the last
         # position instead of slicing, which keeps every tensor tile-aligned
         # (a [b, t-1, V] slice forces padded-tile reductions and a copy)
@@ -1358,11 +1454,17 @@ def num_params(config: GPTConfig) -> int:
     D, H, Hkv, F = cfg.head_dim, cfg.n_head, cfg.kv_heads, cfg.ffn_dim
     b = 1 if cfg.use_bias else 0
     ab = b if cfg.attn_bias is None else (1 if cfg.attn_bias else 0)
-    attn = C * (H + 2 * Hkv) * D + ab * (H + 2 * Hkv) * D + C * C + ab * C
+    attn = C * (H + 2 * Hkv) * D + ab * (H + 2 * Hkv) * D + H * D * C + ab * C
     mlp = (3 if cfg.gated_mlp else 2) * C * F + b * (
         (2 if cfg.gated_mlp else 1) * F + C)
     norm_p = C * (2 if (cfg.norm == "layernorm" and cfg.use_bias) else 1)
     per_layer = attn + mlp + 2 * norm_p
+    if cfg.ssm is not None:
+        m = cfg.ssm
+        # projections, convolution and its bias, A_log / dt_bias / D, norm
+        per_layer += (C * m.in_proj_dim + m.d_inner * C
+                      + (m.d_conv + 1) * m.conv_dim
+                      + 3 * m.n_heads + m.d_inner)
     total = V * C + L * per_layer + norm_p
     if cfg.learned_positions:
         total += cfg.n_positions * C

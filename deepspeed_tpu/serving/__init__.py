@@ -24,6 +24,7 @@ from deepspeed_tpu.inference.scheduler import (
     ContinuousBatchingScheduler,
     DeadlineExceededError,
     DrainingError,
+    RecurrentStateError,
     QueueFullError,
     RequestShedError,
 )
@@ -84,6 +85,7 @@ __all__ = [
     "PrefillWorker",
     "QueueFullError",
     "RECOVERING",
+    "RecurrentStateError",
     "ROLE_DECODE",
     "ROLE_PREFILL",
     "ReplicaDead",

@@ -49,6 +49,15 @@ SCOPE_MOE_COMBINE = "moe_combine"
 # ZeRO-3 (runtime/zero/gather.py): a layer's weights cast and gathered where
 # the layer reads them, and their cotangents reduce-scattered back
 SCOPE_ZERO3_GATHER = "zero3_gather"
+# the Mamba-2 mixer (models/mamba2.py): the input projection and its muP
+# vector; the depthwise causal convolution, its activation and the tail it
+# keeps; the recurrence (the state's read-modify-write and ``y``); the gate
+# and the grouped norm; the output projection
+SCOPE_SSM_IN_PROJ = "ssm_in_proj"
+SCOPE_SSM_CONV = "ssm_conv"
+SCOPE_SSM_SCAN = "ssm_scan"
+SCOPE_SSM_GATE_NORM = "ssm_gate_norm"
+SCOPE_SSM_OUT_PROJ = "ssm_out_proj"
 # not a named scope: the tag of an instruction that no scope above owns and
 # whose result is a whole KV-cache leaf, stacked or one layer's: a copy XLA
 # makes of a loop's carry, a layer's slice of the stacked cache that did
@@ -58,6 +67,11 @@ SCOPE_ZERO3_GATHER = "zero3_gather"
 # serving program should have no time under this tag: what shows up here
 # is a whole leaf being moved again.
 SCOPE_KV_CACHE_CARRY = "kv_cache_carry"
+# the same for a whole recurrent-state or convolution-tail leaf of the
+# Mamba-2 mixer: the mixer's own update of its layer's slice is
+# ``ssm_scan``'s / ``ssm_conv``'s, in place; anything else that produces a
+# whole leaf is a copy
+SCOPE_SSM_STATE_CARRY = "ssm_state_carry"
 # JAX's own name-stack component of a rematerialised (recomputed) operation;
 # ``checkpoint`` alone is also on the backward pass of a checkpointed region
 SCOPE_REMAT = "rematted_computation"
@@ -74,7 +88,9 @@ _RAGGED_DOT = "ragged-dot"
 _CARRY_FREE = frozenset((
     SCOPE_OPTIMIZER, SCOPE_GRAD_CAST, SCOPE_OVERFLOW_CHECK,
     SCOPE_GRAD_NORM_CLIP, SCOPE_LM_HEAD, SCOPE_LM_HEAD_CE, SCOPE_MLM_HEAD,
-    SCOPE_ATTN_CORE, SCOPE_KV_CACHE_WRITE, SCOPE_KV_CACHE_READ, SCOPE_SAMPLE))
+    SCOPE_ATTN_CORE, SCOPE_KV_CACHE_WRITE, SCOPE_KV_CACHE_READ, SCOPE_SAMPLE,
+    SCOPE_SSM_IN_PROJ, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE_NORM,
+    SCOPE_SSM_OUT_PROJ))
 _STRUCTURE = re.compile(
     r"^(jit\(.*\)|pjit\(.*\)|while|body|cond|branch_\d+_fun|closed_call|"
     r"core_call|custom_jvp_call|custom_vjp_call|custom_vjp_call_jaxpr)$")
@@ -173,9 +189,18 @@ def parse_hlo(text):
     return module, out, {c: [r[1] for r in comps.get(c, ())] for c in fused}
 
 
-def _tag_carry(path, module, opcode):
+def _tag_carry(path, module, opcode, tag):
     parts = split_path(path) if path else ["jit(%s)" % module, opcode]
-    return "/".join(parts[:-1] + [SCOPE_KV_CACHE_CARRY, parts[-1]])
+    return "/".join(parts[:-1] + [tag, parts[-1]])
+
+
+def _carry_tags(carry_shapes):
+    """``{shape: tag}`` from ``carry_shapes``: a mapping ``{tag: shapes}``,
+    or shapes alone, which are KV-cache leaves'."""
+    if not hasattr(carry_shapes, "items"):
+        carry_shapes = {SCOPE_KV_CACHE_CARRY: carry_shapes}
+    return {tuple(s): tag for tag, shapes in carry_shapes.items()
+            for s in shapes}
 
 
 def instruction_scopes(text, carry_shapes=()):
@@ -185,11 +210,11 @@ def instruction_scopes(text, carry_shapes=()):
     The compiler's own ragged-dot calls get ``moe_experts`` (see
     ``_RAGGED_DOT``).
     An instruction that none of the program's named scopes owns and whose
-    result has one of ``carry_shapes`` (tuples of ints) gets the
-    ``kv_cache_carry`` component. An instruction with no scope at all is
-    kept, mapped to None."""
+    result has one of ``carry_shapes`` (tuples of ints, or ``{tag:
+    shapes}``) gets the ``kv_cache_carry`` component, or the mapping's tag.
+    An instruction with no scope at all is kept, mapped to None."""
     module, parsed, fused = parse_hlo(text)
-    carry = {tuple(s) for s in carry_shapes}
+    carry = _carry_tags(carry_shapes)
     table = {}
     for name, (op_name, opcode, dims, callee) in parsed.items():
         path = op_name
@@ -202,7 +227,7 @@ def instruction_scopes(text, carry_shapes=()):
             path = "jit(%s)/%s/%s" % (module, SCOPE_MOE_EXPERTS, path)
         if dims in carry and opcode not in _CONTAINERS \
                 and not has_scope(path, *_CARRY_FREE):
-            path = _tag_carry(path, module, opcode)
+            path = _tag_carry(path, module, opcode, carry[dims])
         table[name] = path
     return module, table
 

@@ -1,0 +1,127 @@
+"""sha256 of the lowered text of the programs the benchmark's GPT and MoE
+cells run, at a small size on the CPU: ``jit_prefill`` at two prompt
+buckets, ``jit_decode_k``, the scheduler's ``splice``, and the train step of
+a dense and of a dropless-MoE decoder (PR 30's method). Run as a script it
+prints them as JSON; ``tests/unit/data/gpt_program_hashes.json`` holds what
+it printed on the parent of the PR that brought the hybrid block
+(534fd1d), and ``test_falcon_h1.py`` holds this tree to it."""
+import hashlib
+import json
+import os
+import sys
+
+if __name__ == "__main__":
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
+        " --xla_force_host_platform_device_count=8"
+    # run from a checkout's root (``python tests/unit/gpt_program_hashes.py``)
+    # to hash THAT checkout
+    sys.path.insert(0, os.getcwd())
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+def _sha(text):
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def serve_hashes():
+    """A GPT-2-shaped decoder (LayerNorm, learned positions, tied head,
+    full heads) served in bf16 on four lanes."""
+    import deepspeed_tpu
+    from deepspeed_tpu import serving
+    from deepspeed_tpu.models.transformer_lm import GPT, GPTConfig
+    from deepspeed_tpu.parallel.mesh import reset_default_topology
+
+    reset_default_topology()
+    cfg = GPTConfig(vocab_size=256, n_positions=256, n_embd=64, n_layer=3,
+                    n_head=2, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                    scan_layers=True, use_flash_attention="auto")
+    eng = deepspeed_tpu.init_inference(GPT(cfg), dtype="bf16", seed=5)
+    sched = serving.build_serving(eng, {"slots": 4, "prompt_bucket": 64})
+    sched._ensure_compiled()
+    out = {}
+    subs = {}
+    for bucket in (64, 128):
+        ids = jnp.zeros((1, bucket), jnp.int32)
+        mask = jnp.ones((1, bucket), jnp.bool_)
+        out["jit_prefill[%d]" % bucket] = _sha(
+            eng._prefill_fn.fn.lower(eng.params, ids, mask).as_text())
+        subs[bucket] = jax.eval_shape(eng._prefill_fn.fn, eng.params, ids,
+                                      mask)[1]
+    cache = sched._cache_shapes()
+    out["jit_decode_k"] = _sha(eng._decode_k_fn.fn.lower(
+        eng.params, jnp.zeros((4,), jnp.int32), cache,
+        jax.random.PRNGKey(0), jnp.float32(0.0), 1).as_text())
+    full = sched._empty_cache()
+    sub = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), subs[64])
+    sched._splice(full, sub, 1)
+    out["jit_splice"] = _sha(sched._splice_fn.fn.lower(
+        sched._cache_shapes(), subs[64], jnp.int32(1)).as_text())
+    return out
+
+
+def train_hashes():
+    """The train step of a dense GPT-2-shaped decoder and of a dropless MoE
+    decoder (OLMoE's shape in small), pure bf16 under ZeRO-1 on one
+    device, as the benchmark's one-chip training cells run."""
+    import deepspeed_tpu
+    from deepspeed_tpu.models.transformer_lm import GPT, GPTConfig
+    from deepspeed_tpu.parallel.mesh import (
+        MeshTopology,
+        reset_default_topology,
+        set_default_topology,
+    )
+    from deepspeed_tpu.runtime import engine as engine_mod
+
+    models = {
+        "dense": GPTConfig(
+            vocab_size=256, n_positions=64, n_embd=64, n_layer=3, n_head=2,
+            dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, scan_layers=True,
+            remat=True, remat_policy="full", use_flash_attention="auto"),
+        "moe": GPTConfig(
+            vocab_size=256, n_positions=64, n_embd=64, n_layer=2, n_head=4,
+            intermediate_size=32, norm="rmsnorm", activation="silu",
+            use_bias=False, rotary=True, learned_positions=False,
+            tie_word_embeddings=False, qk_norm=True, dtype=jnp.bfloat16,
+            param_dtype=jnp.bfloat16, remat=True, scan_layers=True,
+            use_flash_attention=False, moe_num_experts=8, moe_top_k=3,
+            moe_drop_tokens=False, moe_gated_experts=True,
+            moe_aux_loss_coef=0.01, moe_z_loss_coef=0.001)}
+    out = {}
+    for name, cfg in models.items():
+        reset_default_topology()
+        engine, _, _, _ = deepspeed_tpu.initialize(
+            model=GPT(cfg), topology=MeshTopology(devices=jax.devices()[:1]),
+            seed=3, config={
+                "train_micro_batch_size_per_gpu": 2,
+                "gradient_accumulation_steps": 1,
+                "bf16": {"enabled": True}, "gradient_clipping": 1.0,
+                "optimizer": {"type": "FusedAdam", "params": {
+                    "lr": 2e-4, "betas": [0.9, 0.95], "weight_decay": 0.1}},
+                "zero_optimization": {"stage": 1},
+                "steps_per_print": 10 ** 9})
+        ids = np.random.RandomState(0).randint(0, 256, (2, 64)).astype(
+            np.int32)
+        batch = {"input_ids": ids, "labels": ids}
+        set_default_topology(engine.topology)
+        engine._init_state(dict(batch))
+        engine._put_batch(dict(batch))
+        avals = engine_mod._avals_like
+        out["jit_train_step[%s]" % name] = _sha(
+            engine._build_train_step().lower(
+                avals(engine._params), avals(engine._opt_state),
+                avals(engine._ls_state), engine._last_batch_aval,
+                avals(engine._rng), engine.micro_steps,
+                jnp.float32(1.0)).as_text())
+    return out
+
+
+def all_hashes():
+    return dict(serve_hashes(), **train_hashes())
+
+
+if __name__ == "__main__":
+    print(json.dumps(all_hashes(), indent=1, sort_keys=True))
