@@ -317,13 +317,65 @@ def _check_splash(t, block, heads, d, dtype, strict: bool):
             "tol": tol, "rel_l2": errs}
 
 
+def _check_decode_attention(layers, lanes, positions, kv_heads, heads, d,
+                            dtype, strict: bool):
+    """The block-skipping decode attention over a stacked KV leaf, lanes
+    with left padding and clocks all over the cache (one with nothing
+    visible, one full), vs the masked softmax on the f32 upcast."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
+
+    rng = np.random.RandomState(13)
+    q = jnp.asarray(rng.randn(lanes, heads, d), dtype)
+    kc, vc = (jnp.asarray(rng.randn(layers, lanes, positions, kv_heads, d),
+                          dtype) for _ in range(2))
+    first = rng.randint(0, positions // 2, lanes)
+    clock = np.minimum(first + rng.randint(0, positions, lanes),
+                       positions - 1)
+    first[0], clock[0] = positions - 1, 0      # nothing visible
+    first[-1], clock[-1] = 0, positions - 1    # every row
+    valid = jnp.asarray(np.arange(positions)[None, :] >= first[:, None])
+    layer = layers - 1
+    fn = jax.jit(decode_attention)
+    got = fn(q, kc, vc, valid, jnp.asarray(clock), jnp.int32(layer))
+    visible = valid & (jnp.arange(positions)[None, :]
+                       <= jnp.asarray(clock)[:, None])
+    qg = q.astype(jnp.float32).reshape(lanes, kv_heads, heads // kv_heads, d)
+    att = jnp.einsum("bhgd,bkhd->bhgk", qg,
+                     kc[layer].astype(jnp.float32)) / np.sqrt(d)
+    att = jax.nn.softmax(
+        jnp.where(visible[:, None, None, :], att, -1e30), axis=-1)
+    ref = jnp.einsum("bhgk,bkhd->bhgd", att,
+                     vc[layer].astype(jnp.float32)).reshape(lanes, heads, d)
+    mosaic = _mosaic_calls(fn.lower(
+        q, kc, vc, valid, jnp.asarray(clock),
+        jnp.int32(layer)).compile().as_text())
+    err = _rel_l2(got[1:], ref[1:])
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
+    if not bool(jnp.isfinite(got.astype(jnp.float32)).all()) or err > tol:
+        raise AssertionError(f"decode_attention rel-L2 {err:.3e} > {tol}")
+    if strict and mosaic != 1:
+        raise AssertionError(f"decode_attention: {mosaic} Mosaic calls")
+    return {"kernel": "decode_attn", "layers": layers, "lanes": lanes,
+            "positions": positions, "kv_heads": kv_heads, "heads": heads,
+            "head_dim": d, "dtype": jnp.dtype(dtype).name,
+            "mosaic_calls": mosaic, "tol": tol, "rel_l2": round(err, 6)}
+
+
 def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
                   adam_shape=(2048, 8192), splash=(4096, 128, 16, 64),
+                  decode_shapes=((2, 16, 1024, 16, 16, 128),
+                                 (2, 8, 1408, 4, 20, 128)),
                   dtype=None, strict=True) -> dict:
     """Each Pallas kernel once at a production shape, forward and
     backward, against plain ``jnp``. ``flash_shapes`` rows are
     ``(seq, head_dim, heads, batch)``; the first also runs with
-    ``segment_ids``. ``splash`` is ``(seq, block, heads, head_dim)``."""
+    ``segment_ids``. ``splash`` is ``(seq, block, heads, head_dim)``;
+    ``decode_shapes`` rows are ``(layers, lanes, positions, kv_heads,
+    heads, head_dim)`` of a stacked KV leaf (full heads, grouped heads)."""
     import jax.numpy as jnp
 
     from deepspeed_tpu.ops.pallas.common import interpret
@@ -341,6 +393,8 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
                 _check_flash(t, d, heads, batch, dtype, True, strict))
     checks.append(_check_fused_adam(adam_shape, strict))
     checks.append(_check_splash(*splash, dtype, strict))
+    checks.extend(_check_decode_attention(*shape, dtype, strict)
+                  for shape in decode_shapes)
     for c in checks:
         emit({"phase": "kernels", "check": c})
     return {"phase": "kernels", "ok": True, "n_checks": len(checks),

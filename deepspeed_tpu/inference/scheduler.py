@@ -65,6 +65,7 @@ from deepspeed_tpu.inference.engine import (
     prefill_chunk_spans,
     programs_scope_table,
 )
+from deepspeed_tpu.ops.pallas.decode_attention import live_blocks
 from deepspeed_tpu.parallel.mesh import set_default_topology
 from deepspeed_tpu.telemetry.scopes import SCOPE_SAMPLE, DispatchedProgram
 from deepspeed_tpu.telemetry.spans import (
@@ -199,6 +200,41 @@ class _Lane:
     emitted: int = 0
 
 
+class _LaneClocks:
+    """Where each lane's rows begin and where its next query sits, kept on
+    the host from what admissions and steps do to the device's
+    ``cache_index`` and ``valid`` leaves, so that a decode step can say
+    what its attention reads without asking the device: ``step`` gives
+    the blocks read over the blocks held, all lanes, by the kernel's own
+    rule (ops/pallas/decode_attention.py ``live_blocks``), and 1.0 where
+    attention takes the einsums over every position (``block`` None).
+    A lane that holds no request keeps its clock running, as on the
+    device, and is read up to it."""
+
+    def __init__(self, stats, slots: int, positions: int, block):
+        self.stats = stats
+        self.block = block
+        self.positions = positions
+        self.first = np.zeros((slots,), np.int64)
+        self.clock = np.zeros((slots,), np.int64)
+
+    def admit(self, lane: int, bucket: int, prompt_len: int, replayed: int):
+        self.first[lane] = bucket - prompt_len
+        self.clock[lane] = bucket + replayed
+
+    def step(self) -> float:
+        share = 1.0
+        if self.block is not None:
+            lo, hi = live_blocks(
+                self.first, np.minimum(self.clock, self.positions - 1),
+                self.block)
+            share = float((hi - lo + 1).sum()) * self.block \
+                / (self.positions * len(self.clock))
+        self.clock += 1
+        self.stats.kv_blocks_read_share_sum += share
+        return share
+
+
 class LanesAtExit:
     """What ``run`` left on the device when it ended with a decode step in
     flight (``ContinuousBatchingScheduler.retain_lanes``): the lane cache
@@ -236,6 +272,9 @@ class ServingStats:
     # tokens a step computed for a lane whose request had ended by the
     # time the host read them (a lane's end is seen one step late)
     decode_tokens_discarded: int = 0
+    # over the plain loop's decode steps, the sum of each step's
+    # ``kv_blocks_read_share`` (``_LaneClocks.step``)
+    kv_blocks_read_share_sum: float = 0.0
 
     def summary(self) -> Dict[str, Any]:
         ttfts = sorted(c.ttft_s for c in self.completions)
@@ -262,6 +301,9 @@ class ServingStats:
             "decode_steps": self.decode_steps,
             "decode_steps_ahead": self.decode_steps_ahead,
             "decode_tokens_discarded": self.decode_tokens_discarded,
+            "kv_blocks_read_share": (
+                self.kv_blocks_read_share_sum / self.decode_steps
+                if self.decode_steps else 0.0),
         }
 
 
@@ -423,6 +465,7 @@ class ContinuousBatchingScheduler:
         self._empty_cache_shapes = None
         self._kv_stats_static = None
         self._cache_plan_published = False
+        self._clocks: Optional[_LaneClocks] = None     # made by each run
         # set to keep what a run that ends with a step in flight leaves on
         # the device (``LanesAtExit``) in ``lanes_at_exit`` until the next
         # run or until the holder drops it: a whole lane cache stays
@@ -614,7 +657,11 @@ class ContinuousBatchingScheduler:
 
             self._cache_plan_published = True
             kv = self._kv_geometry()
+            block = self._decode_attention_block()
             publish(KIND_SERVE_CACHE_PLAN, slots=self.slots,
+                    decode_attention="einsum" if block is None
+                    else "live_blocks",
+                    decode_attention_block=block or 0,
                     **{k: kv[k] for k in (
                         "kv_bytes_per_lane", "state_bytes_per_lane",
                         "conv_bytes_per_lane", "bytes_per_lane")})
@@ -634,6 +681,20 @@ class ContinuousBatchingScheduler:
                 jnp.zeros((1, self._bucketed_len(t_probe)), jnp.int32))
         if de._prefill_fn is None:
             de._build_decode_fns()
+
+    def _decode_attention_block(self):
+        """Positions a block of the plain loop's decode attention, None
+        where it reads every position (the model decides: models/
+        transformer_lm.py ``decode_attention_block``; a module without a
+        ``GPTConfig`` has no such kernel)."""
+        from deepspeed_tpu.models.transformer_lm import (
+            GPTConfig,
+            decode_attention_block,
+        )
+
+        if not isinstance(self._mcfg, GPTConfig):
+            return None
+        return decode_attention_block(self._mcfg)
 
     def _cache_shapes_for(self, eng):
         """Leaf geometry (jax.eval_shape, nothing materialized) of one
@@ -1151,6 +1212,8 @@ class ContinuousBatchingScheduler:
         tok = np.zeros((self.slots,), np.int32)
         tok_dev = None if use_spec else jax.device_put(tok, where)
         cache = self._empty_cache()
+        self._clocks = _LaneClocks(stats, self.slots, self._max_pos,
+                                   self._decode_attention_block())
         eng._rng, rng = jax.random.split(eng._rng)
         rng = jax.device_put(rng, where)
         temp = jnp.float32(self.temperature)
@@ -1297,6 +1360,8 @@ class ContinuousBatchingScheduler:
                                     draft_cache = self._splice(
                                         draft_cache, draft_sub, lane_no)
                                     tok[lane_no] = first_tok
+                            self._clocks.admit(lane_no, bucket,
+                                               len(req.prompt), replayed)
                             lane = _Lane(req=req, comp=comp, emitted=replayed)
                             lanes[lane_no] = lane
                             emit(lane_no, lane, first_tok)
@@ -1381,7 +1446,8 @@ class ContinuousBatchingScheduler:
                     ahead = len(unread)
                     read = ahead or stats.decode_steps == 0
                     with span(SERVE_DECODE_STEP,
-                              lanes_active=self._lanes_active, ahead=ahead):
+                              lanes_active=self._lanes_active, ahead=ahead,
+                              kv_blocks_read_share=self._clocks.step()):
                         _, tok_dev, cache, rng = eng._decode_k_fn(
                             eng._params, tok_dev, cache, rng, temp, 1)
                         stats.decode_steps += 1

@@ -496,6 +496,32 @@ def _mesh_flash_attention(q, k, v, segment_ids, *, causal, autotune):
         check_vma=False)(*args)
 
 
+def decode_attention_block(cfg, T: int = 1):
+    """How a decode call of ``T`` query tokens per lane attends over its
+    cache, told from what the call and the cache's layout show: the
+    positions in a block of the block-skipping kernel
+    (ops/pallas/decode_attention.py), which reads of each lane only the
+    blocks between its first valid row and its clock; or None where the
+    call keeps the two einsums over every position. The kernel takes one
+    query token over dense storage. More tokens at once (prefill, chunked
+    continuation, speculative verification), the ring cache of a window
+    layout, int8 storage (dequantised whole on read) and ALiBi (a bias on
+    every position) stay on the einsums, and so do heads sharded over
+    ``tp``: GSPMD cannot partition a Mosaic call. The scheduler asks the
+    same question for its counter (``kv_blocks_read_share``)."""
+    from deepspeed_tpu.ops.pallas.decode_attention import block_positions
+    from deepspeed_tpu.ops.sparse_attention.sparse_attention_utils import \
+        ring_engaged
+    from deepspeed_tpu.parallel.mesh import get_default_topology
+
+    if (T != 1 or cfg.kv_cache_dtype == "int8" or cfg.alibi
+            or ring_engaged(cfg) is not None
+            or get_default_topology().size("tp") > 1):
+        return None
+    return block_positions(cfg.n_positions, cfg.kv_heads, cfg.head_dim,
+                           jnp.dtype(cfg.dtype).itemsize)
+
+
 class CausalSelfAttention(nn.Module):
     config: GPTConfig
 
@@ -680,6 +706,25 @@ class CausalSelfAttention(nn.Module):
                     for name, val in new.items():
                         put(name, (rows, slots), val)
                 put("cache_index", (Ellipsis,), idx + T)
+            kernel_block = decode_attention_block(cfg, T)
+            if kernel_block is not None:
+                # one query token over dense storage: each lane reads the
+                # blocks between its first valid row and its clock, out
+                # of the stacked leaf where it lies (no layer's slice is
+                # made); same mask, valid & (position <= clock)
+                from deepspeed_tpu.ops.pallas.decode_attention import \
+                    decode_attention
+
+                with jax.named_scope(SCOPE_KV_CACHE_READ):
+                    valid = leaf("valid")
+                with jax.named_scope(SCOPE_ATTN_CORE):
+                    y = decode_attention(
+                        q[:, 0], cache["cached_key"].value,
+                        cache["cached_value"].value, valid, idx,
+                        cache_layer, block=kernel_block)
+                return nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
+                                param_dtype=cfg.param_dtype,
+                                name="c_proj")(y.reshape(B, T, H * D))
             q_pos = pos[:, :, None]                         # [B, T, 1]
             k_pos = jnp.arange(S)[None, :]                  # [1, S]
             with jax.named_scope(SCOPE_KV_CACHE_READ):

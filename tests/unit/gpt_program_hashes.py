@@ -4,7 +4,13 @@ buckets, ``jit_decode_k``, the scheduler's ``splice``, and the train step of
 a dense and of a dropless-MoE decoder (PR 30's method). Run as a script it
 prints them as JSON; ``tests/unit/data/gpt_program_hashes.json`` holds what
 it printed on the parent of the PR that brought the hybrid block
-(534fd1d), and ``test_falcon_h1.py`` holds this tree to it."""
+(534fd1d), and ``test_falcon_h1.py`` holds this tree to it.
+
+PR 34 moved decode attention of one query token over dense storage into a
+Pallas kernel: ``jit_decode_k`` ALONE was recorded again, on that PR's
+tree, and the other five entries stand as recorded on 534fd1d. The
+``fallback_hashes`` entries (the decode calls that kernel does not take)
+were recorded on PR 34's parent, d382f5d."""
 import hashlib
 import json
 import os
@@ -119,8 +125,57 @@ def train_hashes():
     return out
 
 
+def fallback_hashes():
+    """The decode calls that the block-skipping attention kernel (PR 34,
+    ops/pallas/decode_attention.py) leaves on the einsums: more than one
+    query token over a cache that exists (speculative verification, a
+    chunked prefill's continuation), the ring cache of a window layout,
+    int8 KV storage and ALiBi. Recorded on PR 34's parent (d382f5d)."""
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.models.transformer_lm import GPT, GPTConfig
+    from deepspeed_tpu.ops.sparse_attention.sparse_attention_utils import \
+        apply_sparse_attention
+    from deepspeed_tpu.parallel.mesh import reset_default_topology
+
+    base = dict(vocab_size=128, n_positions=256, n_embd=32, n_layer=2,
+                n_head=4, dtype=jnp.float32, scan_layers=True)
+    models = {
+        "dense": GPT(GPTConfig(**base)),
+        "ring": apply_sparse_attention(
+            GPT(GPTConfig(rotary=True, learned_positions=False, **base)),
+            {"mode": "local_sliding_window", "block": 16,
+             "num_sliding_window_blocks": 3}),
+        "int8": GPT(GPTConfig(kv_cache_dtype="int8", **base)),
+        "alibi": GPT(GPTConfig(alibi=True, learned_positions=False,
+                               **base)),
+    }
+    out = {}
+    for name, model in models.items():
+        reset_default_topology()
+        eng = InferenceEngine(model, {"dtype": "fp32"}, seed=0)
+        eng._materialize(jnp.zeros((1, 64), jnp.int32))
+        eng._build_decode_fns()
+        ids = jnp.zeros((3, 16), jnp.int32)
+        cache = jax.eval_shape(eng._prefill_fn.fn, eng.params, ids,
+                               jnp.ones((3, 16), jnp.bool_))[1]
+        if name == "dense":
+            out["jit_verify_greedy[T=3]"] = _sha(
+                eng._verify_greedy_fn.fn.lower(
+                    eng.params, jnp.zeros((3, 3), jnp.int32),
+                    cache).as_text())
+            out["jit_prefill_more[16]"] = _sha(
+                eng._prefill_more_fn.fn.lower(
+                    eng.params, ids, jnp.ones((3, 16), jnp.bool_),
+                    cache).as_text())
+            continue
+        out["jit_decode_k[%s]" % name] = _sha(eng._decode_k_fn.fn.lower(
+            eng.params, jnp.zeros((3,), jnp.int32), cache,
+            jax.random.PRNGKey(0), jnp.float32(0.0), 1).as_text())
+    return out
+
+
 def all_hashes():
-    return dict(serve_hashes(), **train_hashes())
+    return dict(serve_hashes(), **train_hashes(), **fallback_hashes())
 
 
 if __name__ == "__main__":

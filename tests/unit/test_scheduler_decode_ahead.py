@@ -528,3 +528,174 @@ def test_one_specialisation_of_every_program_and_no_compile_after_warm_up():
     assert sorted(got) == sorted(wants)
     for rid, (prompt, want, _) in wants.items():
         assert got[rid] == reference(ref_eng, prompt, want), rid
+
+
+# ---------------------------------------------------------------------------
+# (k) decode attention reads each lane's live blocks only (PR 34): served
+# tokens against a cache-free forward, the span's counter against the rule
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def live_blocks_run(tmp_path_factory):
+    """One served run, inside a profiler session, of a fresh engine whose
+    decode attention takes the block-skipping kernel at 128 positions a
+    block (two blocks of the 256-position cache; the rule alone would
+    give this small model one): ``(engine, scheduler, the streamed tokens, {request:
+    prompt}, every decode step's host clocks, the serve spans, the bus
+    events)``. Eight lanes, twenty requests: lanes are re-used, one prompt
+    fills its bucket exactly, some lanes cross the block boundary."""
+    import glob
+    import os
+
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    from deepspeed_tpu.telemetry import scopes, spans
+    from deepspeed_tpu.telemetry.bus import telemetry_bus
+
+    patch = pytest.MonkeyPatch()
+    patch.setattr(da, "_BLOCK_BYTES", 1)
+    eng = _engine()
+    sched = ContinuousBatchingScheduler(eng, slots=8, prompt_bucket=BUCKET)
+    sched.retain_lanes = True
+    steps = []
+    real_step = scheduler_mod._LaneClocks.step
+
+    def step(self):
+        first, clock = self.first.copy(), self.clock.copy()
+        share = real_step(self)
+        steps.append((first, clock, share))
+        return share
+
+    patch.setattr(scheduler_mod._LaneClocks, "step", step)
+    prompts = _prompts(19, seed=11, lo=3, hi=150)
+    prompts.insert(0, list(range(1, 2 * BUCKET + 1)))    # fills its bucket
+    outs = [3 + (i * 7) % 13 for i in range(len(prompts))]
+    events = []
+    telemetry_bus.subscribe(events.append)
+    trace_dir = str(tmp_path_factory.mktemp("live_blocks") / "trace")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        rec = Recorder()
+        ids = {sched.submit(p, max_new_tokens=n, stream_callback=rec): p
+               for p, n in zip(prompts, outs)}
+        # ended from poll_fn with lanes still decoding, so that what the
+        # run left on the device can be held against the host's clocks
+        polls = []
+
+        def poll():
+            polls.append(1)
+            if len(polls) > 14:
+                raise KeyboardInterrupt
+
+        with pytest.raises(KeyboardInterrupt):
+            sched.run(poll_fn=poll)
+    finally:
+        jax.profiler.stop_trace()
+        telemetry_bus.unsubscribe(events.append)
+        patch.undo()
+    path = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                  "*.xplane.pb"))[-1]
+    found = sorted(
+        (e.start_ns, e.name[len(spans.SPAN_PREFIX):], dict(e.stats))
+        for plane in scopes.load_trace(path).planes
+        for line in plane.lines for e in line.events
+        if e.name.startswith(spans.SPAN_PREFIX))
+    return eng, sched, rec, ids, steps, found, events
+
+
+def test_served_tokens_equal_a_cache_free_forward(live_blocks_run):
+    """Every token of every request is the argmax, at the last position,
+    of the model applied with ``decode=False`` to prompt + tokens so far:
+    no cache, no kernel, no scheduler. Requests that ended before the run
+    was stopped, and the tokens so far of the ones still in their lanes."""
+    eng, sched, rec, ids, _, _, _ = live_blocks_run
+    model, n_pos = eng.module, eng.module.config.n_positions
+
+    @jax.jit
+    def next_token(params, row, length):
+        logits = model.apply({"params": params}, row[None],
+                             deterministic=True)
+        return jnp.argmax(logits[0, length - 1])
+
+    live = {c.request_id for c in sched.lanes_at_exit.live.values()}
+    assert live and rec.done, "the run was to end with lanes decoding"
+    assert len(rec.tokens) > 8      # more requests than lanes: re-use
+    checked = 0
+    for rid, tokens in rec.tokens.items():
+        row = np.zeros((n_pos,), np.int32)
+        prompt = ids[rid]
+        row[:len(prompt)] = prompt
+        for i, tok in enumerate(tokens):
+            n = len(prompt) + i
+            assert int(next_token(eng.params, jnp.asarray(row), n)) == tok, \
+                (rid, i)
+            row[n] = tok
+            checked += 1
+    assert any(len(ids[rid]) == 2 * BUCKET for rid in rec.done)
+    assert checked > 20
+
+
+def test_the_spans_counter_is_live_blocks_on_the_hosts_clocks(
+        live_blocks_run):
+    from deepspeed_tpu.ops.pallas.decode_attention import live_blocks
+    from deepspeed_tpu.telemetry import spans
+
+    eng, sched, _, _, steps, found, _ = live_blocks_run
+    on_spans = [attrs["kv_blocks_read_share"] for _, name, attrs in found
+                if name == spans.SERVE_DECODE_STEP]
+    assert len(on_spans) == len(steps) > 10
+    n_pos, n_blocks = eng.module.config.n_positions, 2
+    for got, (first, clock, share) in zip(on_spans, steps):
+        lo, hi = live_blocks(first, np.minimum(clock, n_pos - 1), 128)
+        want = (hi - lo + 1).sum() / (n_blocks * len(clock))
+        assert share == pytest.approx(want)
+        assert float(got) == pytest.approx(want, abs=1e-6)
+    shares = {s[2] for s in steps}
+    assert min(shares) == 0.5 and max(shares) > 0.5   # some lanes crossed
+    # the host's clocks are the device's: what the last step left there
+    cache = sched.lanes_at_exit.cache
+    leaves = {p[-1].key: v for p, v in
+              jax.tree_util.tree_flatten_with_path(cache)[0]}
+    first, clock, _ = steps[-1]
+    assert (np.asarray(leaves["cache_index"])[0] == clock + 1).all()
+    valid = np.asarray(leaves["valid"])[0]
+    assert (valid.argmax(axis=1) == first).all()
+
+
+def test_summary_and_cache_plan_say_how_attention_read(live_blocks_run):
+    _, sched, _, _, steps, _, events = live_blocks_run
+    plans = [e for e in events if e["kind"] == "serve.cache_plan"]
+    assert len(plans) == 1
+    assert plans[0]["decode_attention"] == "live_blocks"
+    assert plans[0]["decode_attention_block"] == 128
+    mean = sum(s[2] for s in steps) / len(steps)
+    stats = scheduler_mod.ServingStats(
+        decode_steps=len(steps),
+        kv_blocks_read_share_sum=sum(s[2] for s in steps))
+    assert stats.summary()["kv_blocks_read_share"] == pytest.approx(mean)
+    assert scheduler_mod.ServingStats().summary()[
+        "kv_blocks_read_share"] == 0.0
+
+
+@pytest.mark.parametrize("kw,path", [
+    ({"kv_cache_dtype": "int8"}, "einsum"),
+    ({"alibi": True, "learned_positions": False}, "einsum"),
+    ({}, "live_blocks")], ids=["int8", "alibi", "dense"])
+def test_cache_plan_names_the_path_of_each_layout(kw, path):
+    from deepspeed_tpu.telemetry.bus import telemetry_bus
+
+    sched = ContinuousBatchingScheduler(_engine(**kw), slots=2,
+                                        prompt_bucket=BUCKET)
+    events = []
+    telemetry_bus.subscribe(events.append)
+    try:
+        sched._ensure_compiled()
+    finally:
+        telemetry_bus.unsubscribe(events.append)
+    plan, = [e for e in events if e["kind"] == "serve.cache_plan"]
+    assert plan["decode_attention"] == path
+    assert (plan["decode_attention_block"] == 256) == (path == "live_blocks")
+    clocks = scheduler_mod._LaneClocks(
+        scheduler_mod.ServingStats(), 2, 256,
+        sched._decode_attention_block())
+    assert clocks.step() == 1.0     # one block a lane, or every position
