@@ -365,17 +365,61 @@ def _check_decode_attention(layers, lanes, positions, kv_heads, heads, d,
             "mosaic_calls": mosaic, "tol": tol, "rel_l2": round(err, 6)}
 
 
+def _check_retention_step(layers, lanes, kv_heads, heads, d, strict: bool):
+    """One token of power retention through the kernel that walks the
+    stacked state where it lies, vs the plain ``retention_step`` on that
+    layer's slice; the other layers must come back untouched."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops import power_retention as pr
+
+    rng = np.random.RandomState(17)
+    D = pr.sympow2_width(d)
+    S = jnp.asarray(rng.randn(layers, lanes, kv_heads, d, D), jnp.float32)
+    z = jnp.asarray(np.abs(rng.randn(layers, lanes, kv_heads, D)),
+                    jnp.float32)
+    q = jnp.asarray(1.0 + rng.randn(lanes, heads, d), jnp.float32)
+    k, v = (jnp.asarray(1.0 + rng.randn(lanes, kv_heads, d), jnp.float32)
+            for _ in range(2))
+    log_g = jnp.asarray(-np.abs(rng.randn(lanes, kv_heads)) * 0.05,
+                        jnp.float32)
+    layer = layers - 1
+    want = pr.retention_step(S[layer], z[layer], q, k, v, log_g, 1e-6)
+    untouched = np.asarray(S[0])
+    fn = jax.jit(pr.retention_step_stacked, donate_argnums=(0, 1))
+    mosaic = _mosaic_calls(fn.lower(
+        S, z, jnp.int32(layer), q, k, v, log_g, 1e-6).compile().as_text())
+    y, S, z = fn(S, z, jnp.int32(layer), q, k, v, log_g, 1e-6)
+    errs = {"y": _rel_l2(y, want[0]), "S": _rel_l2(S[layer], want[1]),
+            "z": _rel_l2(z[layer], want[2])}
+    tol = 1e-4
+    if max(errs.values()) > tol or not np.array_equal(
+            np.asarray(S[0]), untouched):
+        raise AssertionError(f"retention_step rel-L2 {errs} > {tol}")
+    if strict and mosaic != 1:
+        raise AssertionError(f"retention_step: {mosaic} Mosaic calls")
+    return {"kernel": "ret_step", "layers": layers, "lanes": lanes,
+            "kv_heads": kv_heads, "heads": heads, "head_dim": d,
+            "mosaic_calls": mosaic, "tol": tol,
+            "rel_l2": {n: round(e, 8) for n, e in errs.items()}}
+
+
 def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
                   adam_shape=(2048, 8192), splash=(4096, 128, 16, 64),
                   decode_shapes=((2, 16, 1024, 16, 16, 128),
                                  (2, 8, 1408, 4, 20, 128)),
+                  retention_shape=(2, 8, 8, 40, 128),
                   dtype=None, strict=True) -> dict:
     """Each Pallas kernel once at a production shape, forward and
     backward, against plain ``jnp``. ``flash_shapes`` rows are
     ``(seq, head_dim, heads, batch)``; the first also runs with
     ``segment_ids``. ``splash`` is ``(seq, block, heads, head_dim)``;
     ``decode_shapes`` rows are ``(layers, lanes, positions, kv_heads,
-    heads, head_dim)`` of a stacked KV leaf (full heads, grouped heads)."""
+    heads, head_dim)`` of a stacked KV leaf (full heads, grouped heads);
+    ``retention_shape`` is ``(layers, lanes, kv_heads, heads, head_dim)``
+    of a stacked retention state."""
     import jax.numpy as jnp
 
     from deepspeed_tpu.ops.pallas.common import interpret
@@ -395,6 +439,7 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
     checks.append(_check_splash(*splash, dtype, strict))
     checks.extend(_check_decode_attention(*shape, dtype, strict)
                   for shape in decode_shapes)
+    checks.append(_check_retention_step(*retention_shape, strict))
     for c in checks:
         emit({"phase": "kernels", "check": c})
     return {"phase": "kernels", "ok": True, "n_checks": len(checks),

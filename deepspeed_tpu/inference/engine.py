@@ -32,7 +32,6 @@ from deepspeed_tpu.runtime.zero.sharding import ZeroShardingRules
 from deepspeed_tpu.telemetry.scopes import (
     SCOPE_KV_CACHE_CARRY,
     SCOPE_SAMPLE,
-    SCOPE_SSM_STATE_CARRY,
     DispatchedProgram,
     scope_table,
 )
@@ -67,37 +66,37 @@ def kv_leaf_shapes(tree):
     return shapes
 
 
-def ssm_leaf_shapes(tree):
-    """The same for the Mamba-2 mixer's leaves (``ssm_state``,
-    ``conv_tail``; models/mamba2.py): the leaf as stored and, of a stacked
-    state, one layer's slice. One layer's convolution tail is what the
-    convolution itself concatenates in front of its input (kilobytes a
-    lane), so only the stacked tail counts as a whole leaf."""
-    from deepspeed_tpu.models.mamba2 import CONV_TAIL, SSM_STATE
-
-    shapes = set()
+def recurrent_leaf_shapes(tree, leaves):
+    """The same for the leaves a model declares as recurrent state
+    (``GPTConfig.recurrent_leaves``): ``{carry tag: shapes}``, the leaf as
+    stored and, where the declaration says a layer's slice is a whole
+    leaf too, the stacked leaf without its layer axis."""
+    declared = {leaf.name: leaf for leaf in leaves}
+    shapes = {leaf.carry_tag: set() for leaf in leaves}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        name = str(getattr(path[-1], "key", ""))
-        if name not in (SSM_STATE, CONV_TAIL):
+        decl = declared.get(str(getattr(path[-1], "key", "")))
+        if decl is None:
             continue
         shape = tuple(leaf.shape)
-        shapes.add(shape)
-        if name == SSM_STATE and len(shape) > 4:
-            shapes.add(shape[1:])
+        shapes[decl.carry_tag].add(shape)
+        if decl.slice_is_whole and len(shape) > decl.rank:
+            shapes[decl.carry_tag].add(shape[1:])
     return shapes
 
 
-def programs_scope_table(programs):
+def programs_scope_table(programs, recurrent_leaves=()):
     """``scope_table`` of ``DispatchedProgram``s, with ``kv_cache_carry``
-    for the KV-cache leaves among their arguments and results and
-    ``ssm_state_carry`` for the recurrent-state leaves."""
+    for the KV-cache leaves among their arguments and results and each
+    declared recurrent leaf's own tag (``ssm_state_carry``,
+    ``ret_state_carry``) for those."""
     lowered = [(avals, low) for prog in programs
                for avals, low in zip(prog.avals.values(), prog.lowered())]
-    carry = {SCOPE_KV_CACHE_CARRY: set(), SCOPE_SSM_STATE_CARRY: set()}
+    carry = {SCOPE_KV_CACHE_CARRY: set()}
     for avals, low in lowered:
         carry[SCOPE_KV_CACHE_CARRY] |= kv_leaf_shapes((avals, low.out_info))
-        carry[SCOPE_SSM_STATE_CARRY] |= ssm_leaf_shapes(
-            (avals, low.out_info))
+        for tag, shapes in recurrent_leaf_shapes(
+                (avals, low.out_info), recurrent_leaves).items():
+            carry.setdefault(tag, set()).update(shapes)
     return scope_table((low.compile().as_text() for _, low in lowered),
                        carry)
 
@@ -642,7 +641,10 @@ class InferenceEngine:
         ``kv_cache_carry`` (none, while the cache crosses the layer loop
         in place). Re-lowers (a cache hit) and parses HLO text:
         call it after the measured window, never inside it."""
-        return programs_scope_table(self.step_programs())
+        return programs_scope_table(
+            self.step_programs(),
+            getattr(getattr(self.module, "config", None),
+                    "recurrent_leaves", ()))
 
     def _chunked_prefill(self, input_ids, attention_mask):
         """Prefill ``input_ids`` exactly: one pass when that is exact,

@@ -33,12 +33,16 @@ its lanes:
 Free lanes keep decoding garbage tokens — attention is row-independent and
 the masked softmax is NaN-safe, so a garbage lane costs FLOPs but never
 contaminates a neighbor; its next admission overwrites every cache row it
-touched. A hybrid model's recurrent state and convolution tail
-(``GPTConfig.ssm``, models/mamba2.py) are leaves of the same cache: a
-finished lane's state goes on absorbing garbage tokens, is read by nobody,
-and is overwritten whole by the splice of the lane's next admission. They
-cannot be truncated to a prefix, so such a model refuses speculative
-decoding and the prefix cache (``RecurrentStateError``).
+touched. The recurrent state a model's mixers keep (a hybrid block's
+Mamba-2 state and convolution tail, models/mamba2.py; a retention block's
+state and normaliser, which are ALL its lanes hold: models/
+power_retention.py) are leaves of the same cache, declared once by the
+model (``GPTConfig.recurrent_leaves``) and read from there by everything
+here that has to know: a finished lane's state goes on absorbing garbage
+tokens, is read by nobody, and is overwritten whole by the splice of the
+lane's next admission. They cannot be truncated to a prefix, so such a
+model refuses speculative decoding and the prefix cache
+(``RecurrentStateError``).
 
 Prompts are LEFT-padded to a ``prompt_bucket`` multiple to bound prefill
 compile count (bucket is a multiple of the layout block for ring models,
@@ -123,13 +127,15 @@ class DeadlineExceededError(AdmissionRejected):
 class RecurrentStateError(ValueError):
     """A serving feature that truncates a cache to a shorter prefix was
     asked of a model whose blocks hold recurrent state
-    (``GPTConfig.ssm``): keys and values of the first ``n`` positions are
-    a prefix's cache, a state after ``m > n`` tokens is not."""
+    (``GPTConfig.recurrent_leaves``): keys and values of the first ``n``
+    positions are a prefix's cache, a state after ``m > n`` tokens is
+    not."""
 
-    def __init__(self, feature: str, why: str):
+    def __init__(self, feature: str, why: str, leaves):
         super().__init__(
             f"{feature} cannot serve a model with recurrent state "
-            f"(GPTConfig.ssm): {why}")
+            f"({', '.join(leaf.name for leaf in leaves)}: "
+            f"GPTConfig.recurrent_leaves): {why}")
         self.feature = feature
 
 
@@ -209,7 +215,11 @@ class _LaneClocks:
     rule (ops/pallas/decode_attention.py ``live_blocks``), and 1.0 where
     attention takes the einsums over every position (``block`` None).
     A lane that holds no request keeps its clock running, as on the
-    device, and is read up to it."""
+    device, and is read up to it. A cache without keys and values
+    (``block`` 0) has no such share: ``step`` gives None, nothing is
+    summed, and the decode step's span carries no
+    ``kv_blocks_read_share`` (``telemetry.span`` drops an attribute that
+    is None)."""
 
     def __init__(self, stats, slots: int, positions: int, block):
         self.stats = stats
@@ -222,7 +232,9 @@ class _LaneClocks:
         self.first[lane] = bucket - prompt_len
         self.clock[lane] = bucket + replayed
 
-    def step(self) -> float:
+    def step(self) -> Optional[float]:
+        if self.block == 0:
+            return None
         share = 1.0
         if self.block is not None:
             lo, hi = live_blocks(
@@ -248,18 +260,35 @@ class LanesAtExit:
     ``stream_callback`` that raises ends it inside a step's delivery, and
     the lanes after its own are then one undelivered token ahead."""
 
+    # what the model declares as recurrent state (``GPTConfig.
+    # recurrent_leaves``); the scheduler sets it on what it keeps
+    recurrent_leaves = ()
+
     def __init__(self, owners, cache):
         self.cache = cache
         self.live = {n: lane.comp for n, lane in enumerate(owners)
                      if lane is not None and not lane.comp.t_done}
 
     def recurrent_state(self, lane: int):
-        """``{"ssm_state": [layers, H, P, N], "conv_tail": [layers, K - 1,
-        C]}`` of one lane, as stored (models/mamba2.py); empty for a model
-        without a mixer."""
-        from deepspeed_tpu.models.mamba2 import lane_state
-
-        return lane_state(self.cache, lane)
+        """``{leaf name: [layers, ...]}`` of one lane, as stored, for each
+        leaf the model declares (``GPTConfig.recurrent_leaves``: a hybrid
+        block's ``ssm_state`` ``[layers, H, P, N]`` and ``conv_tail``
+        ``[layers, K - 1, C]``, a retention block's ``ret_state``
+        ``[layers, Hkv, D, d]`` and ``ret_norm`` ``[layers, Hkv, D]``):
+        the stacked leaves of ``ScannedBlocks`` or, layer by layer in tree
+        order, an unrolled model's. Empty for a model without one."""
+        rank = {leaf.name: leaf.rank for leaf in self.recurrent_leaves}
+        out = {}
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+                self.cache)[0]:
+            name = str(getattr(path[-1], "key", ""))
+            if name in rank:
+                one = jax.lax.dynamic_index_in_dim(
+                    leaf, jnp.int32(lane), leaf.ndim - rank[name],
+                    keepdims=False)
+                out.setdefault(name, []).append(
+                    one if one.ndim == rank[name] else one[None])
+        return {name: jnp.concatenate(parts) for name, parts in out.items()}
 
 
 @dataclass
@@ -378,21 +407,23 @@ class ContinuousBatchingScheduler:
 
         self._ring = ring_engaged(self._mcfg) if self._mcfg is not None \
             else None
-        if getattr(self._mcfg, "ssm", None) is not None:
+        # what the model declares as recurrent state, read here once
+        self._recurrent = tuple(getattr(self._mcfg, "recurrent_leaves", ()))
+        if self._recurrent:
             # refused here, by name, and not by a wrong answer later
             if draft_engine is not None:
                 raise RecurrentStateError(
                     "draft_engine (speculative decoding)",
                     "_rewind steps the cache clocks back past the "
                     "rejected tokens, and a state that has absorbed them "
-                    "cannot be stepped back")
+                    "cannot be stepped back", self._recurrent)
             if prefix_cache is not None:
                 raise RecurrentStateError(
                     "prefix_cache",
                     "an entry is a cache cut at a promotion boundary, and "
                     "the state of a longer prompt cannot be cut there (a "
                     "snapshot of the state at the boundary would do; "
-                    "serving/prefix_cache.py takes none)")
+                    "serving/prefix_cache.py takes none)", self._recurrent)
         if prompt_bucket is None:
             prompt_bucket = self._ring[2] if self._ring is not None else 64
         if self._ring is not None and prompt_bucket % self._ring[2] != 0:
@@ -659,12 +690,13 @@ class ContinuousBatchingScheduler:
             kv = self._kv_geometry()
             block = self._decode_attention_block()
             publish(KIND_SERVE_CACHE_PLAN, slots=self.slots,
-                    decode_attention="einsum" if block is None
-                    else "live_blocks",
+                    decode_attention="none" if block == 0
+                    else "einsum" if block is None else "live_blocks",
                     decode_attention_block=block or 0,
                     **{k: kv[k] for k in (
                         "kv_bytes_per_lane", "state_bytes_per_lane",
-                        "conv_bytes_per_lane", "bytes_per_lane")})
+                        "conv_bytes_per_lane", "norm_bytes_per_lane",
+                        "bytes_per_lane")})
         de = self.draft_engine
         if de is None:
             return
@@ -686,7 +718,9 @@ class ContinuousBatchingScheduler:
         """Positions a block of the plain loop's decode attention, None
         where it reads every position (the model decides: models/
         transformer_lm.py ``decode_attention_block``; a module without a
-        ``GPTConfig`` has no such kernel)."""
+        ``GPTConfig`` has no such kernel), 0 where a lane's cache holds no
+        keys and values at all (a model whose token mixer is not attention
+        says so: ``GPTConfig.has_kv_cache``)."""
         from deepspeed_tpu.models.transformer_lm import (
             GPTConfig,
             decode_attention_block,
@@ -694,6 +728,8 @@ class ContinuousBatchingScheduler:
 
         if not isinstance(self._mcfg, GPTConfig):
             return None
+        if not self._mcfg.has_kv_cache:
+            return 0
         return decode_attention_block(self._mcfg)
 
     def _cache_shapes_for(self, eng):
@@ -882,7 +918,7 @@ class ContinuousBatchingScheduler:
         programs += [p for p in (self._splice_fn, self._set_token_fn,
                                  self._copy_fn, self._rewind_fn)
                      if p is not None]
-        return programs_scope_table(programs)
+        return programs_scope_table(programs, self._recurrent)
 
     def _draft_prefill(self, ids: np.ndarray, mask: np.ndarray,
                        req: Request):
@@ -1031,10 +1067,13 @@ class ContinuousBatchingScheduler:
         factor, and with a known HBM size (telemetry/memory.hbm_bytes)
         ``lanes_at_hbm_budget`` says how many decode lanes of THIS
         per-lane footprint fit the part — the capacity number the
-        disaggregated-serving sizing tables are built from. A hybrid
-        model's recurrent state and convolution tail are counted apart
-        from keys, values and clocks (``state_bytes``, ``conv_bytes``,
-        ``kv_bytes``, each also ``_per_lane``)."""
+        disaggregated-serving sizing tables are built from. The leaves a
+        model declares as recurrent state are counted apart from keys,
+        values and clocks, each where its declaration says (``state_bytes``,
+        ``conv_bytes``, and ``norm_bytes``: the part of ``state_bytes``
+        that is a normaliser; ``kv_bytes`` is the rest; each also
+        ``_per_lane``). A cache without keys and values has the clocks
+        alone in ``kv_bytes``."""
         from deepspeed_tpu.telemetry.memory import hbm_bytes
 
         out = dict(self._kv_geometry())
@@ -1056,19 +1095,22 @@ class ContinuousBatchingScheduler:
                                            jnp.float32))
             resident = 0
             unquant = 0
-            from deepspeed_tpu.models.mamba2 import CONV_TAIL, SSM_STATE
-
-            # the mixer's leaves apart from keys, values and their clocks
-            apart = {SSM_STATE: 0, CONV_TAIL: 0}
+            # the declared recurrent leaves apart from keys, values and
+            # their clocks, each in the sums its declaration names
+            counted = {leaf.name: leaf.counted_as for leaf in self._recurrent}
+            apart = {"state": 0, "conv": 0, "norm": 0}
+            recurrent = 0
 
             def acc(path, sd):
-                nonlocal resident, unquant
+                nonlocal resident, unquant, recurrent
                 name = path[-1].key if hasattr(path[-1], "key") \
                     else path[-1]
                 nbytes = sd.size * jnp.dtype(sd.dtype).itemsize
                 resident += nbytes
-                if name in apart:
-                    apart[name] += nbytes
+                if name in counted:
+                    recurrent += nbytes
+                    for part in counted[name]:
+                        apart[part] += nbytes
                 if name in ("cached_key", "cached_value"):
                     unquant += sd.size * compute_dt.itemsize
                 elif name in ("cached_key_scale", "cached_value_scale"):
@@ -1077,18 +1119,20 @@ class ContinuousBatchingScheduler:
                     unquant += nbytes
 
             jax.tree_util.tree_map_with_path(acc, shapes)
-            kv_bytes = resident - sum(apart.values())
+            kv_bytes = resident - recurrent
             self._kv_stats_static = {
                 "kv_cache_dtype": (getattr(self._mcfg, "kv_cache_dtype",
                                            None) or "compute"),
                 "resident_bytes": int(resident),
                 "unquantized_bytes": int(unquant),
                 "bytes_per_lane": int(resident // self.slots),
-                "state_bytes": int(apart[SSM_STATE]),
-                "conv_bytes": int(apart[CONV_TAIL]),
+                "state_bytes": int(apart["state"]),
+                "conv_bytes": int(apart["conv"]),
+                "norm_bytes": int(apart["norm"]),
                 "kv_bytes": int(kv_bytes),
-                "state_bytes_per_lane": int(apart[SSM_STATE] // self.slots),
-                "conv_bytes_per_lane": int(apart[CONV_TAIL] // self.slots),
+                "state_bytes_per_lane": int(apart["state"] // self.slots),
+                "conv_bytes_per_lane": int(apart["conv"] // self.slots),
+                "norm_bytes_per_lane": int(apart["norm"] // self.slots),
                 "kv_bytes_per_lane": int(kv_bytes // self.slots),
                 "lanes": self.slots,
                 "compression_ratio": (float(unquant) / float(resident)
@@ -1194,6 +1238,7 @@ class ContinuousBatchingScheduler:
                 jax.block_until_ready(step[0])
             if self.retain_lanes and unread:
                 self.lanes_at_exit = LanesAtExit(*unread[-1][1:])
+                self.lanes_at_exit.recurrent_leaves = self._recurrent
 
     def _run(self, poll_fn, unread) -> ServingStats:
         self._ensure_compiled()

@@ -48,24 +48,6 @@ SSM_STATE = "ssm_state"
 CONV_TAIL = "conv_tail"
 
 
-def lane_state(cache, lane):
-    """``{SSM_STATE: [layers, H, P, N], CONV_TAIL: [layers, K - 1, C]}`` of
-    one lane of a ``cache`` collection, as stored: the stacked leaves of
-    ``ScannedBlocks`` or, layer by layer in tree order, an unrolled
-    model's. Empty where no block holds a mixer."""
-    rank = {SSM_STATE: 4, CONV_TAIL: 3}     # of one layer's [B, ...] leaf
-    out = {}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
-        name = str(getattr(path[-1], "key", ""))
-        if name in rank:
-            one = jax.lax.dynamic_index_in_dim(
-                leaf, jnp.int32(lane), leaf.ndim - rank[name],
-                keepdims=False)
-            out.setdefault(name, []).append(
-                one if one.ndim == rank[name] else one[None])
-    return {name: jnp.concatenate(parts) for name, parts in out.items()}
-
-
 def mup_vector(m):
     """The muP factors over the input projection's columns."""
     gn = m.n_groups * m.d_state

@@ -30,7 +30,28 @@ from deepspeed_tpu.telemetry.scopes import (
     SCOPE_KV_CACHE_WRITE,
     SCOPE_LM_HEAD,
     SCOPE_LM_HEAD_CE,
+    SCOPE_RET_STATE_CARRY,
+    SCOPE_SSM_STATE_CARRY,
 )
+
+
+@dataclasses.dataclass(frozen=True)
+class RecurrentLeaf:
+    """One leaf of the decode cache that holds recurrent state: what a
+    model whose mixer keeps one declares, once
+    (``GPTConfig.recurrent_leaves``), and what the scheduler's refusals and
+    byte accounting, ``LanesAtExit`` and the scope table's carry tags
+    read. Unlike keys and values such a leaf cannot be cut at a prefix."""
+    name: str            # in the ``cache`` collection
+    rank: int            # of one layer's ``[B, ...]`` leaf
+    dtype: Any           # as stored
+    # which sums of ``kv_cache_stats`` the leaf's bytes enter
+    counted_as: Tuple[str, ...]
+    # the scope table's tag of an instruction that no scope owns and whose
+    # result is this whole leaf (the stacked one, and one layer's slice of
+    # it where ``slice_is_whole``)
+    carry_tag: str
+    slice_is_whole: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,6 +84,17 @@ class SSMConfig:
         if len(self.multipliers) != 5:
             raise ValueError("ssm multipliers are five: z, x, B, C, dt")
 
+    def recurrent_leaves(self, cfg) -> Tuple[RecurrentLeaf, ...]:
+        """(models/mamba2.py) One layer's convolution tail is what the
+        convolution itself concatenates in front of its input (kilobytes a
+        lane), so only the stacked tail counts as a whole leaf."""
+        from deepspeed_tpu.models.mamba2 import CONV_TAIL, SSM_STATE
+
+        return (RecurrentLeaf(SSM_STATE, 4, self.state_dtype, ("state",),
+                              SCOPE_SSM_STATE_CARRY),
+                RecurrentLeaf(CONV_TAIL, 3, cfg.dtype, ("conv",),
+                              SCOPE_SSM_STATE_CARRY, slice_is_whole=False))
+
     @property
     def d_inner(self) -> int:
         return self.n_heads * self.d_head
@@ -74,6 +106,40 @@ class SSMConfig:
     @property
     def in_proj_dim(self) -> int:
         return self.d_inner + self.conv_dim + self.n_heads
+
+
+@dataclasses.dataclass(frozen=True)
+class RetentionConfig:
+    """Power retention IN PLACE OF attention as a block's token mixer
+    (models/power_retention.py over ops/power_retention.py): gated linear
+    attention with the kernel ``(q . k)^2``. The heads, their size,
+    rotary and the norm's epsilon are the model's own (``n_head``,
+    ``n_kv_head``, ``head_dim``, ``rope_theta``, ``layer_norm_epsilon``);
+    each head of q and k is RMS-normalised before rotary. The state ``S``
+    (per KV head the symmetric square of the keys by ``d``) and its
+    normaliser ``z`` are all a lane's cache holds: no keys, no values."""
+    # tokens in a chunk of a pass over many (prefill); changes no value
+    chunk: int = 128
+    # added to the normaliser phi(q) . z
+    eps: float = 1e-6
+    # storage dtype of S and z in the decode cache; the arithmetic is
+    # float32 either way
+    state_dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        if self.chunk < 1:
+            raise ValueError(f"retention chunk must be >= 1; {self.chunk}")
+
+    def recurrent_leaves(self, cfg) -> Tuple[RecurrentLeaf, ...]:
+        """One layer's slice of the normaliser has the shape of ``phi(k)``
+        itself, so only the stacked one counts as a whole leaf."""
+        from deepspeed_tpu.models.power_retention import RET_NORM, RET_STATE
+
+        return (RecurrentLeaf(RET_STATE, 4, self.state_dtype, ("state",),
+                              SCOPE_RET_STATE_CARRY),
+                RecurrentLeaf(RET_NORM, 3, self.state_dtype,
+                              ("state", "norm"), SCOPE_RET_STATE_CARRY,
+                              slice_is_whole=False))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -236,6 +302,10 @@ class GPTConfig:
     # recurrent state and convolution tail live in the decode cache beside
     # keys and values and cannot be rewound to a shorter prefix
     ssm: Optional[SSMConfig] = None
+    # --- attention-free blocks (Brumby) -------------------------------------
+    # power retention in place of attention as the token mixer; None =
+    # attention. A lane's cache is then the retention's state alone
+    retention: Optional[RetentionConfig] = None
     # attention head size when it is not n_embd // n_head
     attn_head_dim: Optional[int] = None
     # muP multipliers, each applied where the published model applies it;
@@ -309,6 +379,29 @@ class GPTConfig:
                     "global blocks); BigBird's random links cannot be "
                     "served from a bounded ring — use 'auto' to fall back "
                     "to the dense cache")
+
+        if self.retention is not None and (
+                not self.rotary or self.learned_positions or self.alibi
+                or self.sparse_attention is not None or not self.causal
+                or self.ssm is not None):
+            raise ValueError(
+                "a retention block is causal, takes its positions from "
+                "rotary alone and has no second mixer")
+
+    @property
+    def recurrent_leaves(self) -> Tuple[RecurrentLeaf, ...]:
+        """The leaves of the decode cache that hold recurrent state, as
+        the model's mixers declare them; empty for a model whose cache is
+        keys and values alone. The one place that knows which mixers keep
+        a state."""
+        return tuple(leaf for mixer in (self.ssm, self.retention)
+                     if mixer is not None
+                     for leaf in mixer.recurrent_leaves(self))
+
+    @property
+    def has_kv_cache(self) -> bool:
+        """Whether a lane's cache holds keys and values at all."""
+        return self.retention is None
 
     @property
     def head_dim(self) -> int:
@@ -941,21 +1034,28 @@ class Block(nn.Module):
         cfg = self.config
         x_in = x
         u = _norm(cfg, "ln_1")(x)
-        a = CausalSelfAttention(cfg, name="attn")(
-            scaled(u, cfg.attention_in_multiplier),
-            mask=mask, segment_ids=segment_ids, positions=positions,
-            deterministic=deterministic, decode=decode,
-            cache_layer=cache_layer)
-        a = scaled(a, cfg.attention_out_multiplier)
+        if cfg.recurrent_leaves and segment_ids is not None:
+            raise NotImplementedError(
+                "packed-sequence segment_ids with a recurrent mixer: "
+                "the state would run across documents")
+        if cfg.retention is not None:
+            # the token mixer is retention, not attention: no KV cache
+            from deepspeed_tpu.models.power_retention import PowerRetention
+
+            a = PowerRetention(cfg, name="attn")(
+                u, mask=mask, decode=decode, cache_layer=cache_layer)
+        else:
+            a = CausalSelfAttention(cfg, name="attn")(
+                scaled(u, cfg.attention_in_multiplier),
+                mask=mask, segment_ids=segment_ids, positions=positions,
+                deterministic=deterministic, decode=decode,
+                cache_layer=cache_layer)
+            a = scaled(a, cfg.attention_out_multiplier)
         if cfg.ssm is not None:
             # the hybrid block: the mixer reads what attention reads and
             # the two are summed into the one residual
             from deepspeed_tpu.models.mamba2 import Mamba2Mixer
 
-            if segment_ids is not None:
-                raise NotImplementedError(
-                    "packed-sequence segment_ids with a recurrent mixer: "
-                    "the state would run across documents")
             a = a + Mamba2Mixer(cfg, name="mamba")(
                 u, mask=mask, decode=decode, cache_layer=cache_layer)
         if cfg.parallel_residual:
@@ -1504,6 +1604,9 @@ def num_params(config: GPTConfig) -> int:
         (2 if cfg.gated_mlp else 1) * F + C)
     norm_p = C * (2 if (cfg.norm == "layernorm" and cfg.use_bias) else 1)
     per_layer = attn + mlp + 2 * norm_p
+    if cfg.retention is not None:
+        # the gate's kernel and bias, q's and k's per-head norm
+        per_layer += C * Hkv + Hkv + 2 * D
     if cfg.ssm is not None:
         m = cfg.ssm
         # projections, convolution and its bias, A_log / dt_bias / D, norm

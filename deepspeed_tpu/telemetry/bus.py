@@ -56,7 +56,8 @@ KIND_SERVE_STATS = "serve.stats"
 KIND_SERVE_KV_TRANSFER = "serve.kv_transfer"
 KIND_SERVE_SPEC_ACCEPT = "serve.spec_accept"
 # once per scheduler, when its lane cache is laid out: what a lane holds
-# (kv_bytes_per_lane, state_bytes_per_lane, conv_bytes_per_lane, slots)
+# (kv_bytes_per_lane, state_bytes_per_lane, conv_bytes_per_lane,
+# norm_bytes_per_lane, slots) and how a decode step attends over it
 KIND_SERVE_CACHE_PLAN = "serve.cache_plan"
 KIND_SHUTDOWN = "shutdown.graceful"
 KIND_ELASTIC_RESHARD = "elastic.reshard"

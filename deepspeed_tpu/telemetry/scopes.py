@@ -72,6 +72,17 @@ SCOPE_KV_CACHE_CARRY = "kv_cache_carry"
 # ``ssm_scan``'s / ``ssm_conv``'s, in place; anything else that produces a
 # whole leaf is a copy
 SCOPE_SSM_STATE_CARRY = "ssm_state_carry"
+# the power-retention mixer (models/power_retention.py): the q, k, v and
+# gate projections; the per-head norm of q and k and rotary; the gate, the
+# symmetric square, the state's and normaliser's read-modify-write, the
+# query and the division; the output projection
+SCOPE_RET_PROJ = "ret_proj"
+SCOPE_RET_QK_NORM_ROPE = "ret_qk_norm_rope"
+SCOPE_RET_STATE = "ret_state"
+SCOPE_RET_OUT_PROJ = "ret_out_proj"
+# a whole retention-state or normaliser leaf that no scope owns: the
+# mixer's own update of its layer's slice is ``ret_state``'s, in place
+SCOPE_RET_STATE_CARRY = "ret_state_carry"
 # JAX's own name-stack component of a rematerialised (recomputed) operation;
 # ``checkpoint`` alone is also on the backward pass of a checkpointed region
 SCOPE_REMAT = "rematted_computation"
@@ -90,7 +101,8 @@ _CARRY_FREE = frozenset((
     SCOPE_GRAD_NORM_CLIP, SCOPE_LM_HEAD, SCOPE_LM_HEAD_CE, SCOPE_MLM_HEAD,
     SCOPE_ATTN_CORE, SCOPE_KV_CACHE_WRITE, SCOPE_KV_CACHE_READ, SCOPE_SAMPLE,
     SCOPE_SSM_IN_PROJ, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE_NORM,
-    SCOPE_SSM_OUT_PROJ))
+    SCOPE_SSM_OUT_PROJ, SCOPE_RET_PROJ, SCOPE_RET_QK_NORM_ROPE,
+    SCOPE_RET_STATE, SCOPE_RET_OUT_PROJ))
 _STRUCTURE = re.compile(
     r"^(jit\(.*\)|pjit\(.*\)|while|body|cond|branch_\d+_fun|closed_call|"
     r"core_call|custom_jvp_call|custom_vjp_call|custom_vjp_call_jaxpr)$")

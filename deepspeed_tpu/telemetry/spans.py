@@ -31,7 +31,10 @@ TRAIN_PHASE = "train."      # + the engine's phase name
 
 def span(name, **attrs):
     """A context manager that records ``ds:<name>`` with ``attrs`` while a
-    profiler session is active and is a no-op otherwise."""
+    profiler session is active and is a no-op otherwise. An attribute that
+    is None is left out: what a caller has nothing to say about is not
+    written as a number."""
     from jax.profiler import TraceAnnotation
 
-    return TraceAnnotation(SPAN_PREFIX + name, **attrs)
+    return TraceAnnotation(SPAN_PREFIX + name, **{
+        k: v for k, v in attrs.items() if v is not None})

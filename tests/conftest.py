@@ -31,27 +31,32 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import pytest  # noqa: E402
 
 # The benchmark's rehearsal tables (tests/perfbench/rehearsal.py) predate
-# the hybrid serve cell, and both they and tests/perfbench/conftest.py are
-# the benchmark's own files. The cell's tiny stand-in is data in a new file
+# the hybrid and the attention-free serve cells, and both they and tests/perfbench/conftest.py are
+# the benchmark's own files. Each cell's tiny stand-in is data in a new file
 # beside its tests and is registered from here: this conftest is loaded
 # first and for any subset of the tests (PERF.md, section 7).
 _PERFBENCH_TESTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "perfbench")
 if _PERFBENCH_TESTS not in sys.path:
     sys.path.insert(0, _PERFBENCH_TESTS)
+import brumby_tiny  # noqa: E402
 import falcon_h1_tiny  # noqa: E402
 import rehearsal  # noqa: E402
 
 falcon_h1_tiny.register(rehearsal)
+brumby_tiny.register(rehearsal)
+_PREDATE_REDUCED = {
+    falcon_h1_tiny.PREDATES_REDUCED: "test_perfbench_falcon_h1.py",
+    brumby_tiny.PREDATES_REDUCED: "test_perfbench_brumby.py"}
 
 
 def pytest_collection_modifyitems(items):
     for item in items:
-        if item.name == falcon_h1_tiny.PREDATES_REDUCED:
+        if item.name in _PREDATE_REDUCED:
             item.add_marker(pytest.mark.xfail(
                 strict=True, reason="asserts reduced == [] of every "
                 "configuration; this one lists its cut (replaced by "
-                "test_perfbench_falcon_h1.py::"
+                f"{_PREDATE_REDUCED[item.name]}::"
                 "test_reduced_is_exactly_what_differs_from_the_catalog)"))
 
 

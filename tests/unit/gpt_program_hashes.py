@@ -23,6 +23,7 @@ if __name__ == "__main__":
     # run from a checkout's root (``python tests/unit/gpt_program_hashes.py``)
     # to hash THAT checkout
     sys.path.insert(0, os.getcwd())
+    sys.path.insert(0, os.path.join(os.getcwd(), "tests", "perfbench"))
 
 import jax
 import jax.numpy as jnp
@@ -174,8 +175,46 @@ def fallback_hashes():
     return out
 
 
+def hybrid_hashes():
+    """The tiny hybrid configuration (a Mamba-2 mixer beside attention,
+    ``tests/perfbench/falcon_h1_tiny.py``) served in bf16 on three lanes:
+    ``jit_prefill``, ``jit_decode_k`` and the scheduler's ``splice``.
+    Recorded on d382f5d + PR 34 (2b32c9c), the parent of the PR that made
+    a model declare its recurrent leaves."""
+    import deepspeed_tpu
+    from deepspeed_tpu import serving
+    from deepspeed_tpu.models.transformer_lm import GPT
+    from deepspeed_tpu.parallel.mesh import reset_default_topology
+    from falcon_h1_tiny import TINY_FALCON_H1
+    from perfbench.builders import falcon_h1_serve
+
+    reset_default_topology()
+    section = dict(TINY_FALCON_H1["serve"], param_dtype="bfloat16",
+                   compute_dtype="bfloat16")
+    eng = deepspeed_tpu.init_inference(
+        GPT(falcon_h1_serve.model_config(TINY_FALCON_H1, section)),
+        dtype="bf16", seed=5)
+    sched = serving.build_serving(eng, {"slots": 3, "prompt_bucket": 16})
+    sched._ensure_compiled()
+    ids = jnp.zeros((1, 32), jnp.int32)
+    mask = jnp.ones((1, 32), jnp.bool_)
+    out = {"hybrid_jit_prefill[32]": _sha(
+        eng._prefill_fn.fn.lower(eng.params, ids, mask).as_text())}
+    sub = jax.eval_shape(eng._prefill_fn.fn, eng.params, ids, mask)[1]
+    cache = sched._cache_shapes()
+    out["hybrid_jit_decode_k"] = _sha(eng._decode_k_fn.fn.lower(
+        eng.params, jnp.zeros((3,), jnp.int32), cache,
+        jax.random.PRNGKey(0), jnp.float32(0.0), 1).as_text())
+    sched._splice(sched._empty_cache(),
+                  jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), sub), 1)
+    out["hybrid_jit_splice"] = _sha(sched._splice_fn.fn.lower(
+        cache, sub, jnp.int32(1)).as_text())
+    return out
+
+
 def all_hashes():
-    return dict(serve_hashes(), **train_hashes(), **fallback_hashes())
+    return dict(serve_hashes(), **train_hashes(), **fallback_hashes(),
+                **hybrid_hashes())
 
 
 if __name__ == "__main__":

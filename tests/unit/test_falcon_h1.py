@@ -13,7 +13,10 @@ import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu import serving
-from deepspeed_tpu.inference.engine import kv_leaf_shapes, ssm_leaf_shapes
+from deepspeed_tpu.inference.engine import (
+    kv_leaf_shapes,
+    recurrent_leaf_shapes,
+)
 from deepspeed_tpu.models.transformer_lm import GPT, num_params
 from deepspeed_tpu.ops import ssd
 from deepspeed_tpu.telemetry import scopes, telemetry_bus
@@ -331,7 +334,10 @@ def test_layer_loop_carries_state_and_tail_in_place():
     n_layer = eng.module.config.n_layer
     stacked = jax.tree.leaves(cache["h"])
     assert all(leaf.shape[0] == n_layer for leaf in stacked)
-    whole = kv_leaf_shapes(cache) | ssm_leaf_shapes(cache)
+    declared = recurrent_leaf_shapes(
+        cache, eng.module.config.recurrent_leaves)
+    assert set(declared) == {scopes.SCOPE_SSM_STATE_CARRY}
+    whole = kv_leaf_shapes(cache) | declared[scopes.SCOPE_SSM_STATE_CARRY]
     m = eng.module.config.ssm
     assert (n_layer, 3, m.n_heads, m.d_head, m.d_state) in whole
     assert (3, m.n_heads, m.d_head, m.d_state) in whole
@@ -420,8 +426,10 @@ def test_speculation_and_prefix_cache_refuse_a_model_with_state(fp32):
 def test_lowered_gpt_programs_hash_as_on_the_parent():
     """``lower(...).as_text()`` of the serving programs and the train steps
     of the configurations the benchmark had before the hybrid block, at a
-    small size: byte for byte what the parent commit lowers
-    (``tests/unit/data/gpt_program_hashes.json``, recorded there)."""
+    small size, and of the tiny hybrid configuration's own three serving
+    programs: byte for byte what the commits that recorded them lower
+    (``tests/unit/data/gpt_program_hashes.json``; ``gpt_program_hashes.py``
+    says which commit recorded which)."""
     from unit import gpt_program_hashes
 
     with open(os.path.join(HERE, "data", "gpt_program_hashes.json"),
