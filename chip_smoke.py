@@ -406,11 +406,53 @@ def _check_retention_step(layers, lanes, kv_heads, heads, d, strict: bool):
             "rel_l2": {n: round(e, 8) for n, e in errs.items()}}
 
 
+def _check_ssd_step(layers, lanes, heads, groups, d_head, d_state,
+                    strict: bool):
+    """One token of the Mamba-2 recurrence through the kernel that walks
+    the stacked state where it lies, vs the plain ``ssd_step`` on that
+    layer's slice; the other layers must come back untouched."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops import ssd
+
+    rng = np.random.RandomState(19)
+    S = jnp.asarray(rng.randn(layers, lanes, heads, d_head, d_state),
+                    jnp.float32)
+    x = jnp.asarray(rng.randn(lanes, heads, d_head), jnp.float32)
+    dt = jnp.asarray(np.log1p(np.exp(rng.randn(lanes, heads) - 2.0)),
+                     jnp.float32)
+    A = -jnp.exp(jnp.asarray(rng.randn(heads), jnp.float32))
+    Bm, Cm = (jnp.asarray(rng.randn(lanes, groups, d_state), jnp.float32)
+              for _ in range(2))
+    D = jnp.asarray(rng.randn(heads), jnp.float32)
+    layer = layers - 1
+    want = ssd.ssd_step(S[layer], x, dt, A, Bm, Cm, D)
+    untouched = np.asarray(S[0])
+    fn = jax.jit(ssd.ssd_step_stacked, donate_argnums=(0,))
+    mosaic = _mosaic_calls(fn.lower(
+        S, jnp.int32(layer), x, dt, A, Bm, Cm, D).compile().as_text())
+    y, S = fn(S, jnp.int32(layer), x, dt, A, Bm, Cm, D)
+    errs = {"y": _rel_l2(y, want[0]), "S": _rel_l2(S[layer], want[1])}
+    tol = 1e-4
+    if max(errs.values()) > tol or not np.array_equal(
+            np.asarray(S[0]), untouched):
+        raise AssertionError(f"ssd_step rel-L2 {errs} > {tol}")
+    if strict and mosaic != 1:
+        raise AssertionError(f"ssd_step: {mosaic} Mosaic calls")
+    return {"kernel": "ssm_step", "layers": layers, "lanes": lanes,
+            "heads": heads, "groups": groups, "d_head": d_head,
+            "d_state": d_state, "mosaic_calls": mosaic, "tol": tol,
+            "rel_l2": {n: round(e, 8) for n, e in errs.items()}}
+
+
 def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
                   adam_shape=(2048, 8192), splash=(4096, 128, 16, 64),
                   decode_shapes=((2, 16, 1024, 16, 16, 128),
                                  (2, 8, 1408, 4, 20, 128)),
                   retention_shape=(2, 8, 8, 40, 128),
+                  ssd_shape=(2, 8, 32, 2, 128, 256),
                   dtype=None, strict=True) -> dict:
     """Each Pallas kernel once at a production shape, forward and
     backward, against plain ``jnp``. ``flash_shapes`` rows are
@@ -419,7 +461,8 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
     ``decode_shapes`` rows are ``(layers, lanes, positions, kv_heads,
     heads, head_dim)`` of a stacked KV leaf (full heads, grouped heads);
     ``retention_shape`` is ``(layers, lanes, kv_heads, heads, head_dim)``
-    of a stacked retention state."""
+    of a stacked retention state; ``ssd_shape`` is ``(layers, lanes,
+    heads, groups, d_head, d_state)`` of a stacked Mamba-2 state."""
     import jax.numpy as jnp
 
     from deepspeed_tpu.ops.pallas.common import interpret
@@ -440,6 +483,7 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
     checks.extend(_check_decode_attention(*shape, dtype, strict)
                   for shape in decode_shapes)
     checks.append(_check_retention_step(*retention_shape, strict))
+    checks.append(_check_ssd_step(*ssd_shape, strict))
     for c in checks:
         emit({"phase": "kernels", "check": c})
     return {"phase": "kernels", "ok": True, "n_checks": len(checks),

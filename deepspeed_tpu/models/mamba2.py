@@ -12,7 +12,9 @@ Three entry shapes, the same equations:
   state, zero convolution tail, the chunked scan;
 * a pass that continues one (``prefill_more``): the same from the state
   and tail in the ``cache`` collection;
-* one token (a decode step): the recurrence itself.
+* one token (a decode step): the recurrence itself, as one pass of the
+  kernel ``ssm_step`` over the state where it lies (``ssd.ssd_step_stacked``;
+  the plain ``ssd.ssd_step`` on a slice where ``step_kernel`` says no).
 
 On the decode path the state ``[B, H, P, N]`` (float32 unless
 ``SSMConfig.state_dtype`` says otherwise) and the tail ``[B, d_conv - 1,
@@ -20,9 +22,10 @@ conv_dim]`` (compute dtype) are leaves ``ssm_state`` / ``conv_tail`` of the
 ``cache`` collection, beside attention's ``cached_key`` / ``cached_value``.
 Under ``ScannedBlocks`` they are the stacked ``[n_layer, ...]`` buffers the
 layer loop carries and this call is layer ``cache_layer`` of them: it reads
-its slice and writes it back in place, and produces no whole leaf
-otherwise. Unlike keys and values, neither can be truncated to a shorter
-prefix: what rewinds or shares a cache has to refuse such a model.
+its slice and writes it back in place (the kernel takes the leaf whole,
+aliased to its result), and produces no whole leaf otherwise. Unlike keys
+and values, neither can be truncated to a shorter prefix: what rewinds or
+shares a cache has to refuse such a model.
 
 LEFT-padded prompts: a pad's input is zeroed before the input projection
 (no bias: everything it projects is zero) and again after the convolution,
@@ -74,7 +77,7 @@ class Mamba2Mixer(nn.Module):
 
     @nn.compact
     def __call__(self, u, *, mask=None, decode=False, cache_layer=None):
-        from deepspeed_tpu.models.transformer_lm import scaled
+        from deepspeed_tpu.models.transformer_lm import scaled, step_kernel
 
         cfg = self.config
         m = cfg.ssm
@@ -148,15 +151,21 @@ class Mamba2Mixer(nn.Module):
         with jax.named_scope(SCOPE_SSM_SCAN):
             dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
             A = -jnp.exp(A_log.astype(f32))
-            state = leaf(SSM_STATE, (B, H, P, N), f32).astype(f32)
-            if decode and T == 1:
-                y, state = ssd.ssd_step(state, x[:, 0], dt[:, 0], A,
-                                        Bm[:, 0], Cm[:, 0], D)
-                y = y[:, None]
+            if decode and T == 1 and step_kernel():
+                # one token: each lane's state read once and written once
+                # where it lies in the (stacked) leaf; no slice is made
+                y, cache[SSM_STATE].value = ssd.ssd_step_stacked(
+                    cache[SSM_STATE].value, cache_layer, x[:, 0], dt[:, 0],
+                    A, Bm[:, 0], Cm[:, 0], D)
             else:
-                y, state = ssd.ssd_chunked_scan(state, x, dt, A, Bm, Cm, D,
-                                                m.chunk)
-            put(SSM_STATE, state)
+                state = leaf(SSM_STATE, (B, H, P, N), f32).astype(f32)
+                if decode and T == 1:
+                    y, state = ssd.ssd_step(state, x[:, 0], dt[:, 0], A,
+                                            Bm[:, 0], Cm[:, 0], D)
+                else:
+                    y, state = ssd.ssd_chunked_scan(state, x, dt, A, Bm, Cm,
+                                                    D, m.chunk)
+                put(SSM_STATE, state)
             y = y.reshape(B, T, m.d_inner)                   # float32
 
         with jax.named_scope(SCOPE_SSM_GATE_NORM):

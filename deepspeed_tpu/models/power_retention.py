@@ -68,21 +68,12 @@ def _gate_bias_init(key, shape, dtype, shortest=8.0, longest=4096.0):
     return (log_g - jnp.log(-jnp.expm1(log_g))).astype(dtype)
 
 
-def step_kernel(cfg) -> bool:
-    """Whether a decode step of one token runs the kernel that walks the
-    state where it lies (ops/pallas/retention_step.py), told from what the
-    call shows: not where heads are sharded over ``tp``, because GSPMD
-    cannot partition a Mosaic call; the plain ``retention_step`` there."""
-    from deepspeed_tpu.parallel.mesh import get_default_topology
-
-    return get_default_topology().size("tp") == 1
-
-
 class PowerRetention(nn.Module):
     config: "GPTConfig"  # noqa: F821  (models/transformer_lm.py)
 
     @nn.compact
     def __call__(self, x, *, mask=None, decode=False, cache_layer=None):
+        from deepspeed_tpu.models.transformer_lm import step_kernel
         from deepspeed_tpu.ops.rotary import apply_rotary_pos_emb
 
         cfg = self.config
@@ -169,7 +160,7 @@ class PowerRetention(nn.Module):
                 log_g = jnp.where(keep[..., None], log_g, 0.0)
 
         with jax.named_scope(SCOPE_RET_STATE):
-            if decode and T == 1 and step_kernel(cfg):
+            if decode and T == 1 and step_kernel():
                 # one token: each lane's state read once and written once
                 # where it lies in the (stacked) leaf; no slice is made
                 y, cache[RET_STATE].value, cache[RET_NORM].value = \

@@ -615,6 +615,17 @@ def decode_attention_block(cfg, T: int = 1):
                            jnp.dtype(cfg.dtype).itemsize)
 
 
+def step_kernel() -> bool:
+    """Whether a recurrent mixer's decode step of one token runs the kernel
+    that walks the state where it lies (ops/pallas/retention_step.py,
+    ops/pallas/ssd_step.py), told from what the call shows: not where heads
+    are sharded over ``tp``, because GSPMD cannot partition a Mosaic call;
+    the mixer's plain one-token form on a slice there."""
+    from deepspeed_tpu.parallel.mesh import get_default_topology
+
+    return get_default_topology().size("tp") == 1
+
+
 class CausalSelfAttention(nn.Module):
     config: GPTConfig
 

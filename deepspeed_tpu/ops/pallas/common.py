@@ -9,6 +9,15 @@ NEG_INF = -1e30
 LSE_LANES = 8
 
 
+# bytes of one tile of a recurrent state that a one-token kernel walks
+# (retention_step.py, ssd_step.py): in and out, double-buffered, four of
+# them are live (under the 16 MB a kernel may use unasked). On the v5e a
+# retention tile of all 8,320 columns (with the limit raised) ran no faster
+# than one of 1,664, and a Mamba-2 tile of 32 heads (4 MB) 1.3% faster than
+# one of 16: the walk is bound by the bytes, not by its steps
+STATE_TILE_BYTES = 2 << 20
+
+
 def interpret() -> bool:
     """Whether Pallas kernels run in interpreter mode: on every backend but
     TPU, so the CPU test mesh exercises the same kernel bodies. Logged once

@@ -29,27 +29,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.pallas.common import STATE_TILE_BYTES
 from deepspeed_tpu.ops.pallas.common import interpret as _interpret
 
 KERNEL_NAME = "ret_step"
 _LANE = 128
-# bytes of one [d, block] tile of the state: in and out, double-buffered,
-# four of them are live (under the 16 MB a kernel may use unasked). On the
-# v5e a tile of all 8,320 columns (with the limit raised) ran no faster
-# than one of 1,664: the walk is bound by the bytes, not by its steps
-_TILE_BYTES = 2 << 20
 
 
 def block_columns(d: int, D: int) -> int:
     """Columns of ``D`` in a tile: the largest multiple of 128 dividing
-    ``D`` whose ``[d, block]`` float32 tile is at most ``_TILE_BYTES``;
+    ``D`` whose ``[d, block]`` float32 tile is at most ``STATE_TILE_BYTES``;
     all of ``D`` where 128 does not divide it (small shapes)."""
     if D % _LANE:
         return D
     best = _LANE
     for n in range(1, D // _LANE + 1):
         block = n * _LANE
-        if D % block == 0 and d * block * 4 <= _TILE_BYTES:
+        if D % block == 0 and d * block * 4 <= STATE_TILE_BYTES:
             best = block
     return best
 

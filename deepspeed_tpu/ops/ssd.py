@@ -1,6 +1,7 @@
-"""The state-space duality (Mamba-2) operations, in plain ``jax.numpy``.
+"""The state-space duality (Mamba-2) operations: plain ``jax.numpy`` but for
+the served decode step's pass over the state, which is a Pallas kernel.
 
-One set of equations, three entry shapes. With ``S`` a head's ``[P, N]``
+One set of equations, four entry shapes. With ``S`` a head's ``[P, N]``
 state, ``a_t = dt_t * A`` (``A < 0``):
 
     S_t = exp(a_t) * S_{t-1} + dt_t * x_t (outer) B_t
@@ -13,7 +14,15 @@ state, ``a_t = dt_t * A`` (``A < 0``):
   chunks (inside a chunk the recurrence is a masked matmul, between chunks
   a state is handed on), returning every ``y_t`` and the state after the
   last token;
-* :func:`ssd_step`: the recurrence itself for one token.
+* :func:`ssd_step`: the recurrence itself for one token, the plain form;
+* :func:`ssd_step_stacked`: the same on one layer of the stacked state leaf
+  where it lies, each lane's state read once and written once by the kernel
+  ``ssm_step`` (ops/pallas/ssd_step.py). A served decode step of one token
+  takes this one; it falls back to :func:`ssd_step` on a slice where heads
+  are sharded over ``tp`` (GSPMD cannot partition a Mosaic call), and a
+  pass of more than one token over a cache (verification, a chunked
+  prefill's continuation) is the chunked scan
+  (models/transformer_lm.py ``step_kernel``, models/mamba2.py).
 
 All arithmetic is float32; the matmuls of the chunked form run at
 precision ``highest`` (the state is an accumulator over the whole
@@ -58,6 +67,23 @@ def ssd_step(state, x, dt, A, Bm, Cm, D):
     y = jnp.sum(new * Cm.astype(f32)[:, :, None, None, :], axis=-1)
     y = y.reshape(B_, H, P) + D.astype(f32)[None, :, None] * x
     return y, new.reshape(B_, H, P, N)
+
+
+def ssd_step_stacked(state, layer, x, dt, A, Bm, Cm, D):
+    """:func:`ssd_step` on layer ``layer`` of the stacked ``[n_layer, B, H,
+    P, N]`` leaf (or on one layer's with ``layer`` None), through the
+    kernel that reads and writes each lane's state once, where it lies
+    (ops/pallas/ssd_step.py). The state keeps its dtype; the arithmetic is
+    :func:`ssd_step`'s in float32, but for the order of the sum over ``N``.
+    Returns ``(y [B, H, P], the WHOLE leaf with this layer replaced)``."""
+    from deepspeed_tpu.ops.pallas.ssd_step import ssm_step_update
+
+    f32 = jnp.float32
+    x, dt = x.astype(f32), dt.astype(f32)
+    decay = jnp.exp(dt * A.astype(f32))                     # [B, H]
+    state, y = ssm_step_update(state, layer, decay, x * dt[..., None],
+                               Bm, Cm)
+    return y + D.astype(f32)[None, :, None] * x, state
 
 
 def ssd_chunked_scan(state, x, dt, A, Bm, Cm, D, chunk):

@@ -10,7 +10,8 @@ PR 34 moved decode attention of one query token over dense storage into a
 Pallas kernel: ``jit_decode_k`` ALONE was recorded again, on that PR's
 tree, and the other five entries stand as recorded on 534fd1d. The
 ``fallback_hashes`` entries (the decode calls that kernel does not take)
-were recorded on PR 34's parent, d382f5d."""
+were recorded on PR 34's parent, d382f5d. The hybrid and retention entries
+say in their functions' docstrings where each was recorded."""
 import hashlib
 import json
 import os
@@ -180,7 +181,10 @@ def hybrid_hashes():
     ``tests/perfbench/falcon_h1_tiny.py``) served in bf16 on three lanes:
     ``jit_prefill``, ``jit_decode_k`` and the scheduler's ``splice``.
     Recorded on d382f5d + PR 34 (2b32c9c), the parent of the PR that made
-    a model declare its recurrent leaves."""
+    a model declare its recurrent leaves; ``hybrid_jit_decode_k`` ALONE
+    was recorded again on PR 36's tree (on e55e290), which moved the
+    one-token recurrence of the mixer into the Pallas kernel ``ssm_step``
+    (ops/pallas/ssd_step.py)."""
     import deepspeed_tpu
     from deepspeed_tpu import serving
     from deepspeed_tpu.models.transformer_lm import GPT
@@ -212,9 +216,40 @@ def hybrid_hashes():
     return out
 
 
+def retention_hashes():
+    """The tiny attention-free configuration (power retention in place of
+    attention, ``tests/perfbench/brumby_tiny.py``) served in bf16 on three
+    lanes: ``jit_prefill`` and ``jit_decode_k``. Recorded on e55e290, the
+    parent of the PR that gave the hybrid decode step its kernel and moved
+    the two mixers' ``step_kernel`` predicate to one place."""
+    import deepspeed_tpu
+    from brumby_tiny import TINY_BRUMBY
+    from deepspeed_tpu import serving
+    from deepspeed_tpu.models.transformer_lm import GPT
+    from deepspeed_tpu.parallel.mesh import reset_default_topology
+    from perfbench.builders import brumby_serve
+
+    reset_default_topology()
+    section = dict(TINY_BRUMBY["serve"], param_dtype="bfloat16",
+                   compute_dtype="bfloat16")
+    eng = deepspeed_tpu.init_inference(
+        GPT(brumby_serve.model_config(TINY_BRUMBY, section)),
+        dtype="bf16", seed=5)
+    sched = serving.build_serving(eng, {"slots": 3, "prompt_bucket": 16})
+    sched._ensure_compiled()
+    ids = jnp.zeros((1, 32), jnp.int32)
+    mask = jnp.ones((1, 32), jnp.bool_)
+    return {
+        "retention_jit_prefill[32]": _sha(
+            eng._prefill_fn.fn.lower(eng.params, ids, mask).as_text()),
+        "retention_jit_decode_k": _sha(eng._decode_k_fn.fn.lower(
+            eng.params, jnp.zeros((3,), jnp.int32), sched._cache_shapes(),
+            jax.random.PRNGKey(0), jnp.float32(0.0), 1).as_text())}
+
+
 def all_hashes():
     return dict(serve_hashes(), **train_hashes(), **fallback_hashes(),
-                **hybrid_hashes())
+                **hybrid_hashes(), **retention_hashes())
 
 
 if __name__ == "__main__":

@@ -721,16 +721,21 @@ def test_lowered_programs_hash_as_on_the_parent():
     ``jit_decode_k`` and ``splice``: byte for byte what the commits that
     recorded them lower (``tests/unit/data/gpt_program_hashes.json``; the
     hybrid entries were recorded on 2b32c9c, the parent of the PR that
-    made a model declare its recurrent leaves). ``test_falcon_h1.py``
-    holds the whole table; this holds the entries that PR's plumbing
-    runs."""
+    made a model declare its recurrent leaves, but ``hybrid_jit_decode_k``,
+    recorded anew on PR 36's tree (on e55e290) with the kernel ``ssm_step``
+    in it; this model's own two on e55e290, before the two mixers shared
+    one ``step_kernel``). ``test_falcon_h1.py`` holds the whole table; this
+    holds the entries that PR's plumbing runs."""
     from unit import gpt_program_hashes
 
     with open(os.path.join(HERE, "data", "gpt_program_hashes.json"),
               encoding="utf-8") as f:
         want = json.load(f)
     got = dict(gpt_program_hashes.serve_hashes(),
-               **gpt_program_hashes.hybrid_hashes())
+               **gpt_program_hashes.hybrid_hashes(),
+               **gpt_program_hashes.retention_hashes())
     assert {"hybrid_jit_prefill[32]", "hybrid_jit_decode_k",
-            "hybrid_jit_splice", "jit_decode_k", "jit_splice"} <= set(got)
+            "hybrid_jit_splice", "jit_decode_k", "jit_splice",
+            "retention_jit_decode_k", "retention_jit_prefill[32]"} \
+        <= set(got)
     assert got == {k: want[k] for k in got}

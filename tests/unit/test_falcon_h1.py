@@ -19,6 +19,7 @@ from deepspeed_tpu.inference.engine import (
 )
 from deepspeed_tpu.models.transformer_lm import GPT, num_params
 from deepspeed_tpu.ops import ssd
+from deepspeed_tpu.ops.pallas import ssd_step as ssd_kernel
 from deepspeed_tpu.telemetry import scopes, telemetry_bus
 from falcon_h1_tiny import TINY_FALCON_H1
 from perfbench.builders import falcon_h1_serve
@@ -68,6 +69,14 @@ def mixer_leaves(cache):
     m = cache["h"]["block"]["mamba"]
     return np.asarray(m["ssm_state"], np.float32), \
         np.asarray(m["conv_tail"], np.float32)
+
+
+def all_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from all_eqns(sub)
 
 
 # ---------------------------------------------------------------------------
@@ -321,14 +330,142 @@ def test_chunked_scan_is_the_token_recurrence():
     np.testing.assert_array_equal(np.asarray(tail), u[:, -3:])
 
 
+def _one_token(rng, B, H, P, N, G):
+    x = jnp.asarray(rng.normal(size=(B, H, P)), jnp.float32)
+    dt = jnp.asarray(np.log1p(np.exp(rng.normal(size=(B, H)))), jnp.float32)
+    A = -jnp.exp(jnp.asarray(rng.normal(size=(H,)), jnp.float32))
+    Bm = jnp.asarray(rng.normal(size=(B, G, N)), jnp.float32)
+    Cm = jnp.asarray(rng.normal(size=(B, G, N)), jnp.float32)
+    D = jnp.asarray(rng.normal(size=(H,)), jnp.float32)
+    return x, dt, A, Bm, Cm, D
+
+
+@pytest.mark.parametrize("stacked", [True, False])
+@pytest.mark.parametrize("state_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", [None, 2])
+def test_the_step_kernel_is_the_plain_step(stacked, state_dtype, heads):
+    """``ops/pallas/ssd_step.py`` interpreted on the CPU: the kernel over
+    the stacked leaf, layer a traced scalar, gives ``ssd_step``'s ``y`` and
+    new state for that layer, stores it in the leaf's dtype and leaves the
+    other layers bit for bit as they were; at the tile it chooses (a
+    group's four heads: two grid steps a lane) and at one of two heads
+    (four)."""
+    rng = np.random.default_rng(5)
+    L, B, H, P, N, G = 3, 2, 8, 8, 16, 2
+    sdt = jnp.dtype(state_dtype)
+    x, dt, A, Bm, Cm, D = tok = _one_token(rng, B, H, P, N, G)
+    S = jnp.asarray(rng.normal(size=(L, B, H, P, N)), sdt)
+    want_y, want_S = ssd.ssd_step(S[1].astype(jnp.float32), *tok)
+    assert ssd_kernel.block_heads(H, G, P, N) == 4
+
+    if heads is None:
+        def step(S, layer):
+            return ssd.ssd_step_stacked(S, layer, *tok)
+    else:
+        def step(S, layer):   # the kernel's own entry, its tile chosen
+            S, y = ssd_kernel.ssm_step_update(
+                S, layer, jnp.exp(dt * A), x * dt[..., None], Bm, Cm,
+                heads=heads)
+            return y + D[None, :, None] * x, S
+
+    if stacked:
+        y, S2 = jax.jit(step)(S, jnp.int32(1))
+        assert S2.shape == S.shape
+        for other in (0, 2):
+            np.testing.assert_array_equal(np.asarray(S2[other], np.float32),
+                                          np.asarray(S[other], np.float32))
+        got_S = S2[1]
+    else:
+        y, got_S = step(S[1], None)
+    assert got_S.dtype == sdt and y.dtype == jnp.float32
+    tol = dict(rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), **tol)
+    np.testing.assert_allclose(
+        np.asarray(got_S, np.float32),
+        np.asarray(want_S.astype(sdt), np.float32), **tol)
+
+
+def test_the_step_kernels_tile_comes_from_the_shapes():
+    """Sixteen heads of a group a tile at the 34B widths (2 MB), the whole
+    group where ``N`` is small, one head where even one is over the limit;
+    a tile that does not divide a group's heads is refused."""
+    assert ssd_kernel.block_heads(32, 2, 128, 256) == 16
+    assert ssd_kernel.block_heads(32, 1, 128, 256) == 16
+    assert ssd_kernel.block_heads(4, 2, 8, 16) == 2
+    assert ssd_kernel.block_heads(24, 2, 64, 128) == 12
+    assert ssd_kernel.block_heads(4, 1, 1024, 1024) == 1
+    rng = np.random.default_rng(6)
+    B, H, P, N, G = 2, 8, 8, 16, 2
+    x, dt, A, Bm, Cm, _ = _one_token(rng, B, H, P, N, G)
+    S = jnp.zeros((2, B, H, P, N), jnp.float32)
+    with pytest.raises(ValueError, match="divide"):
+        ssd_kernel.ssm_step_update(S, 1, jnp.exp(dt * A), x * dt[..., None],
+                                   Bm, Cm, heads=3)
+
+
+@pytest.mark.parametrize("case", ["tp2", "two_tokens"])
+def test_sharded_heads_and_longer_passes_keep_the_plain_form(case):
+    """The mixer alone, its cache made by ``init``: one token on an
+    unsharded mesh traces exactly one ``ssm_step`` call; with heads sharded
+    over ``tp`` (GSPMD cannot partition a Mosaic call) the same token takes
+    ``ssd_step`` on the slice and gives the same output and state; a pass
+    of two tokens over the cache is the chunked scan."""
+    from deepspeed_tpu.models.mamba2 import Mamba2Mixer
+    from deepspeed_tpu.parallel.mesh import (
+        MeshTopology,
+        set_default_topology,
+    )
+
+    cfg = model_config()
+    mixer = Mamba2Mixer(cfg)
+    T = 2 if case == "two_tokens" else 1
+    u = jnp.asarray(np.random.default_rng(7).normal(
+        size=(3, T, cfg.n_embd)), jnp.float32)
+    variables = mixer.init(jax.random.PRNGKey(0), u, decode=True)
+    state = jnp.asarray(np.random.default_rng(8).normal(
+        size=variables["cache"]["ssm_state"].shape), jnp.float32)
+    variables = {"params": variables["params"],
+                 "cache": dict(variables["cache"], ssm_state=state)}
+
+    def step(v, u):
+        return mixer.apply(v, u, decode=True, mutable=["cache"])
+
+    def kernel_calls():
+        # a function of its own each time: a trace is cached by function,
+        # and the choice is made from the topology while tracing
+        return [e.params["name"] for e in all_eqns(
+            jax.make_jaxpr(lambda v, u: step(v, u))(variables, u).jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+    if case == "two_tokens":
+        assert kernel_calls() == []
+        return
+    assert kernel_calls() == [ssd_kernel.KERNEL_NAME]
+    out, upd = step(variables, u)
+    set_default_topology(MeshTopology(tp=2, dp=-1,
+                                      devices=jax.devices()[:2]))
+    assert kernel_calls() == []
+    plain_out, plain_upd = step(variables, u)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(plain_out),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(upd["cache"]["ssm_state"]),
+        np.asarray(plain_upd["cache"]["ssm_state"]), rtol=2e-5, atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # (e) the decode program moves no whole state leaf
 # ---------------------------------------------------------------------------
 def test_layer_loop_carries_state_and_tail_in_place():
     """``jit_decode_k``: the mixer's leaves cross the layer loop and the
-    loop over ``k`` in the carry only, like keys and values; the compiled
-    program aliases every cache leaf to its output; and its scope table
-    names the mixer's five scopes."""
+    loop over ``k`` in the carry only, like keys and values; the layer
+    loop's body holds exactly one ``ssm_step`` call, which takes the
+    stacked state leaf whole, and no slice or slice-update of that leaf's
+    shape (the traced jaxpr: on the CPU the kernel is interpreted, so the
+    compiled text would not show it); the compiled program aliases every
+    cache leaf to its output; and its scope table names the mixer's five
+    scopes. ``hybrid_jit_decode_k`` of ``gpt_program_hashes.json`` was
+    recorded anew on the tree that brought the kernel (PR 36, on e55e290)."""
     eng, sched = served("bfloat16", slots=3)
     cache = sched._cache_shapes()
     n_layer = eng.module.config.n_layer
@@ -347,13 +484,9 @@ def test_layer_loop_carries_state_and_tail_in_place():
     decode_k = eng._decode_k_fn.fn
 
     def scans(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "scan":
-                yield eqn
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from scans(sub)
+        return [e for e in all_eqns(jaxpr) if e.primitive.name == "scan"]
 
-    loops = list(scans(decode_k.trace(*args).jaxpr.jaxpr))
+    loops = scans(decode_k.trace(*args).jaxpr.jaxpr)
     assert sorted(e.params["length"] for e in loops) == [2, n_layer]
     for eqn in loops:
         first_x = eqn.params["num_consts"] + eqn.params["num_carry"]
@@ -363,6 +496,23 @@ def test_layer_loop_carries_state_and_tail_in_place():
             v.aval.shape for v in eqn.outvars[eqn.params["num_carry"]:]}
         assert not crossing & whole, crossing & whole
         assert {leaf.shape for leaf in stacked} <= carried
+
+    state_leaf = (n_layer, 3, m.n_heads, m.d_head, m.d_state)
+    # the layer loop is the inner one (the loop over k holds it)
+    layer_loop, = (e for e in loops
+                   if not scans(e.params["jaxpr"].jaxpr))
+    assert layer_loop.params["length"] == n_layer
+    body = list(all_eqns(layer_loop.params["jaxpr"].jaxpr))
+    kernels = [e for e in body if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in kernels].count(
+        ssd_kernel.KERNEL_NAME) == 1
+    step_call, = (e for e in kernels
+                  if e.params["name"] == ssd_kernel.KERNEL_NAME)
+    assert state_leaf in {v.aval.shape for v in step_call.invars}
+    assert state_leaf in {v.aval.shape for v in step_call.outvars}
+    for eqn in body:
+        if eqn.primitive.name in ("dynamic_slice", "dynamic_update_slice"):
+            assert eqn.invars[0].aval.shape != state_leaf, eqn
 
     text = decode_k.lower(*args).compile().as_text()
     header = text[:text.index("\n")]
@@ -427,9 +577,13 @@ def test_lowered_gpt_programs_hash_as_on_the_parent():
     """``lower(...).as_text()`` of the serving programs and the train steps
     of the configurations the benchmark had before the hybrid block, at a
     small size, and of the tiny hybrid configuration's own three serving
-    programs: byte for byte what the commits that recorded them lower
+    programs, and the tiny attention-free configuration's two: byte for
+    byte what the commits that recorded them lower
     (``tests/unit/data/gpt_program_hashes.json``; ``gpt_program_hashes.py``
-    says which commit recorded which)."""
+    says which commit recorded which). ``hybrid_jit_decode_k`` alone was
+    recorded anew on PR 36's tree (on e55e290), whose kernel ``ssm_step``
+    it now holds; every other entry stands, ``hybrid_jit_prefill[32]`` and
+    ``hybrid_jit_splice`` among them: nothing else moved."""
     from unit import gpt_program_hashes
 
     with open(os.path.join(HERE, "data", "gpt_program_hashes.json"),
