@@ -104,35 +104,60 @@ def device_report() -> dict:
 
 
 class CompileCounter:
-    """Counts XLA backend compilations and persistent-cache hits through
-    ``jax.monitoring`` (a hit still fires the compile event, with the
-    retrieval time as its duration)."""
+    """XLA backend compilations and persistent-cache hits since it was
+    made, read from the program's own build log (telemetry/builds.py: a hit
+    is a row too, its duration the retrieval and load)."""
 
     def __init__(self):
-        import jax
+        from deepspeed_tpu.telemetry.builds import build_log
 
-        self.compiles = 0
-        self.compile_s = 0.0
-        self.cache_hits = 0
-        self.by_name = {}
-        jax.monitoring.register_event_duration_secs_listener(self._on_secs)
-        jax.monitoring.register_event_listener(self._on_event)
+        build_log.listen()      # the kernels phase passes no entry point
+        self._rows, self._mark = build_log.rows, len(build_log.rows)
 
-    def _on_secs(self, name, secs, **kw):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-            self.compile_s += secs
-            fn = kw.get("fun_name", "?")
-            self.by_name[fn] = self.by_name.get(fn, 0) + 1
+    def _compiled(self):
+        return [r for r in self._rows[self._mark:]
+                if r["stage"] == "compile_or_load"]
 
-    def _on_event(self, name, **kw):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
+    @property
+    def compiles(self) -> int:
+        return len(self._compiled())
+
+    def named(self, program: str) -> int:
+        return sum(1 for r in self._compiled() if r["program"] == program)
 
     def snapshot(self) -> dict:
-        return {"n_compiles": self.compiles,
-                "compile_s": round(self.compile_s, 2),
-                "persistent_cache_hits": self.cache_hits}
+        rows = self._compiled()
+        return {"n_compiles": len(rows),
+                "compile_s": round(sum(r["end"] - r["start"]
+                                       for r in rows), 2),
+                "persistent_cache_hits": sum(
+                    1 for r in rows if r["cache_hit"])}
+
+
+def builds_report(events, floor_s=0.1) -> dict:
+    """Where a phase's set-up went, by the program's own account: the
+    seconds of each build stage (unions), and one row per program built
+    (its ``program.built`` event) that took ``floor_s`` or more; the quick
+    ones, mostly one-operation eager programs, are summed."""
+    from deepspeed_tpu.telemetry.builds import build_log
+
+    fields = ("program", "key", "nth", "trace_s", "lower_s",
+              "compile_or_load_s", "cache_hit", "since_entry_s")
+    costs = [sum(ev.get(k, 0.0) for k in fields[3:6]) for ev in events]
+    slow = [ev for ev, c in zip(events, costs) if c >= floor_s]
+    return {
+        "stage_seconds": {k: round(v, 3) for k, v in
+                          build_log.snapshot()["seconds"].items()},
+        "programs_built": len(events),
+        "first_dispatch_s": round(sum(
+            d["first_dispatch_s"] for d in build_log.dispatches), 3),
+        "columns": list(fields),
+        "programs": [[round(ev[k], 3) if isinstance(ev.get(k), float)
+                      else ev.get(k) for k in fields] for ev in slow],
+        "quicker_programs": {
+            "count": len(events) - len(slow),
+            "seconds": round(sum(c for c in costs if c < floor_s), 3)},
+    }
 
 
 def _memory(device=None) -> dict:
@@ -553,7 +578,7 @@ def _train(name, *, model, seq, micro, steps, zero_stage, devices, tile_rows,
         "steady_ms_per_step": round(
             1e3 * float(np.mean(step_s[2:] or step_s[-1:])), 1),
         "compiles_per_train_batch": compiles_per_step,
-        "train_step_compiles": counter.by_name.get("jit(train_step)", 0),
+        "train_step_compiles": counter.named("jit(train_step)"),
         "mosaic_calls_in_step_hlo": mosaic, "mosaic_call_sample": sample,
         "interpret": interpret(),
         "compiled_step_bytes": {
@@ -811,6 +836,25 @@ def phase_four_chip(model="gpt2-1.3b", seq=1024, micro=6, steps=8, slots=4,
 # ---------------------------------------------------------------------------
 # children and parent
 # ---------------------------------------------------------------------------
+def run_phase(name: str, fn) -> None:
+    """Print the phase's result line, then where its set-up went: on a
+    new host, its first minute."""
+    from deepspeed_tpu.telemetry import telemetry_bus
+
+    built = []
+
+    def on_event(ev):
+        if ev["kind"] == "program.built":
+            built.append(ev)
+
+    telemetry_bus.subscribe(on_event)
+    try:
+        emit(fn())
+    finally:
+        telemetry_bus.unsubscribe(on_event)
+    emit({"phase": name, "builds": builds_report(built)})
+
+
 def _child(name: str) -> int:
     """Run one phase in this (fresh) process. Any exception propagates:
     the traceback goes to stderr and the exit code is nonzero."""
@@ -824,7 +868,7 @@ def _child(name: str) -> int:
     fn = {"kernels": phase_kernels, "train": phase_train,
           "train_warm": lambda: phase_train(name="train_warm"),
           "serve": phase_serve, "four_chip": phase_four_chip}[name]
-    emit(fn())
+    run_phase(name, fn)
     return 0
 
 

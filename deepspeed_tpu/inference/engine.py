@@ -29,6 +29,7 @@ from deepspeed_tpu.module_inject import policy_for
 from deepspeed_tpu.parallel.mesh import MeshTopology, set_default_topology
 from deepspeed_tpu.runtime.checkpoint_engine import MsgpackCheckpointEngine
 from deepspeed_tpu.runtime.zero.sharding import ZeroShardingRules
+from deepspeed_tpu.telemetry.builds import build_log
 from deepspeed_tpu.telemetry.scopes import (
     SCOPE_KV_CACHE_CARRY,
     SCOPE_SAMPLE,
@@ -199,6 +200,7 @@ def init_inference(model, config: Optional[Dict[str, Any]] = None,
     :227 builds the EP process groups, moe_inference.py:206 serves through
     them)."""
     ensure_compile_cache()
+    build_log.listen()
     config = dict(config or {})
     config.setdefault("tensor_parallel", {"tp_size": mp_size})
     if ep_size != 1:
@@ -645,6 +647,17 @@ class InferenceEngine:
             self.step_programs(),
             getattr(getattr(self.module, "config", None),
                     "recurrent_leaves", ()))
+
+    def program_builds(self, before: Optional[float] = None):
+        """What this process built so far, by JAX's own account
+        (telemetry/builds.py ``BuildLog.snapshot``): a row per stage of
+        every program, those of this engine's prefill and decode programs
+        with their prompt bucket or scan length (``key``), each such first
+        call's wall time (``dispatches``), and per stage the seconds of
+        the union of the rows; ``before`` keeps what ended by that
+        ``time.monotonic()``. The log is the process's, not this
+        engine's."""
+        return build_log.snapshot(before)
 
     def _chunked_prefill(self, input_ids, attention_mask):
         """Prefill ``input_ids`` exactly: one pass when that is exact,

@@ -71,6 +71,7 @@ from deepspeed_tpu.inference.engine import (
 )
 from deepspeed_tpu.ops.pallas.decode_attention import live_blocks
 from deepspeed_tpu.parallel.mesh import set_default_topology
+from deepspeed_tpu.telemetry.builds import build_log
 from deepspeed_tpu.telemetry.scopes import SCOPE_SAMPLE, DispatchedProgram
 from deepspeed_tpu.telemetry.spans import (
     SERVE_ADMIT,
@@ -919,6 +920,13 @@ class ContinuousBatchingScheduler:
                                  self._copy_fn, self._rewind_fn)
                      if p is not None]
         return programs_scope_table(programs, self._recurrent)
+
+    def program_builds(self, before: Optional[float] = None):
+        """What this process built so far, by JAX's own account: the
+        engine's ``program_builds()`` (the log is the process's, so the
+        splice, first-token, copy and rewind programs are in it like the
+        engine's)."""
+        return build_log.snapshot(before)
 
     def _draft_prefill(self, ids: np.ndarray, mask: np.ndarray,
                        req: Request):

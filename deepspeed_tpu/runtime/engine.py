@@ -55,6 +55,7 @@ from deepspeed_tpu.runtime.lr_schedules import (
     schedule_fn_from_config,
 )
 from deepspeed_tpu.runtime.optimizer import build_optimizer
+from deepspeed_tpu.telemetry.builds import build_log
 from deepspeed_tpu.telemetry.scopes import (
     avals_like as _avals_like,
     scope_table,
@@ -150,6 +151,7 @@ def initialize(
     if dist_init_required is None or dist_init_required:
         comm.init_distributed()
     ensure_compile_cache()
+    build_log.listen()
 
     # Pipeline-module dispatch (reference __init__.py:123-147)
     from deepspeed_tpu.runtime.pipe import PipelineModule  # lazy, avoids cycle
@@ -1047,6 +1049,16 @@ class DeepSpeedEngine:
         the measured window, never inside it."""
         programs = self.compiled_step_programs() or {}
         return scope_table(c.as_text() for c in programs.values())
+
+    def program_builds(self, before: Optional[float] = None):
+        """What this process built so far, by JAX's own account
+        (telemetry/builds.py ``BuildLog.snapshot``): a row per stage
+        (trace, lower, compile or load) of every program, the step program
+        among them under JAX's name for it, and per stage the seconds of
+        the union of the rows; ``before`` keeps what ended by that
+        ``time.monotonic()``. The log is the process's, not this
+        engine's."""
+        return build_log.snapshot(before)
 
     def compiled_step_memory(self) -> Optional[Dict[str, float]]:
         """XLA ``memory_analysis()`` of one optimizer step's compiled

@@ -62,6 +62,7 @@ from deepspeed_tpu.serving.router import (
     PrefixRouter,
     route_trace,
 )
+from deepspeed_tpu.telemetry.builds import build_log
 
 __all__ = [
     "AdmissionConfig",
@@ -138,6 +139,7 @@ def build_serving(engine, config: Optional[Dict[str, Any]] = None,
     """
     import jax
 
+    build_log.listen()
     shards = engine.topology.data_parallel_size
     if shards > 1 and jax.default_backend() == "tpu":
         # The scheduler shards neither lanes nor caches: on a mesh with

@@ -64,6 +64,10 @@ KIND_ELASTIC_RESHARD = "elastic.reshard"
 # ZeRO-3's gather at the point of use (runtime/zero/gather.py): what a step
 # program gathers and reduce-scatters, published once when it is traced
 KIND_ZERO3_GATHER_PLAN = "zero3.gather_plan"
+# a program's backend compile, or its load from the persistent cache, ended
+# (telemetry/builds.py): program, key, trace_s, lower_s, compile_or_load_s,
+# cache_hit, nth, since_entry_s
+KIND_PROGRAM_BUILT = "program.built"
 # cluster health plane (runtime/health.py): peer liveness over the
 # out-of-band heartbeat mesh, step-time straggler detection, step-skew
 # desync, and SDC parameter-digest mismatches

@@ -26,6 +26,8 @@ import json
 import re
 import sys
 
+from deepspeed_tpu.telemetry.builds import build_log
+
 # named scopes the program sets (jax.named_scope), by the name a reader
 # looks for among a path's components
 SCOPE_OPTIMIZER = "optimizer"
@@ -269,7 +271,13 @@ class DispatchedProgram:
     ``key(args)`` (the shapes that select a specialisation: a prompt
     bucket, a scan length) also keeps the arguments' avals, so that
     :meth:`lowered` can hand out exactly the executables that ran, after
-    the fact and off the hot path."""
+    the fact and off the hot path. That first call is also where JAX
+    traces, lowers and compiles or loads the specialisation: the build log
+    (telemetry/builds.py) is told of it beforehand and closes it itself
+    when the compile or load ends, so that ``__call__`` stays the frame it
+    was under every jitted call (a ``with`` around the first call, three
+    more stack slots, cost the serve ramp 1.2 s of 17.5 on the v5e's host:
+    PERF.md, section 6, PR 37)."""
 
     __slots__ = ("fn", "key", "avals")
 
@@ -281,8 +289,14 @@ class DispatchedProgram:
     def __call__(self, *args):
         k = self.key(args)
         if k not in self.avals:
-            self.avals[k] = avals_like(args)
+            self.avals[k] = self._first(args)
         return self.fn(*args)
+
+    def _first(self, args):
+        """The avals to keep for a specialisation's first call, which
+        follows; the build log is told what it builds."""
+        build_log.first_call(self.fn, self.key(args))
+        return avals_like(args)
 
     def lowered(self):
         """``jax.stages.Lowered`` of every specialisation run (its

@@ -27,6 +27,9 @@ SERVE_STATS = "serve.stats"
 SERVE_DECODE_STEP = "serve.decode_step"
 SERVE_DECODE_READ = "serve.decode_read"
 TRAIN_PHASE = "train."      # + the engine's phase name
+# the first call of one specialisation of a dispatched program, up to the
+# end of its compile or load (attrs ``program``, ``key``; telemetry/builds.py)
+PROGRAM_BUILD = "program.build"
 
 
 def span(name, **attrs):

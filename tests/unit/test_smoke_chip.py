@@ -69,6 +69,31 @@ def test_phase_serve_tiny():
     assert out["ok"] and out["requests"] == 2 and out["matched_generate"]
 
 
+def test_a_phase_prints_where_its_set_up_went(capsys):
+    """After its result line: the build stages' seconds and a row per
+    program built, from the program's own log (telemetry/builds.py)."""
+    import json
+
+    chip_smoke.run_phase("serve", lambda: chip_smoke.phase_serve(
+        model="gpt2-125m", seq=128, slots=2, prompt_range=(5, 40),
+        new_tokens=3, strict=False, **TINY))
+    result, report = [json.loads(ln) for ln in
+                      capsys.readouterr().out.splitlines()
+                      if ln.startswith("{")]
+    assert result["ok"] and report["phase"] == "serve"
+    b = report["builds"]
+    assert set(b["stage_seconds"]) == {"trace", "lower", "compile_or_load"}
+    assert b["programs_built"] == result["n_compiles"] > 0
+    assert b["programs_built"] \
+        == len(b["programs"]) + b["quicker_programs"]["count"]
+    rows = [dict(zip(b["columns"], row)) for row in b["programs"]]
+    prefill = [r for r in rows if r["program"] == "jit(prefill)"]
+    assert prefill and all(r["key"] for r in prefill)
+    assert all(r["compile_or_load_s"] > 0 and r["since_entry_s"] > 0
+               for r in rows)
+    assert b["first_dispatch_s"] > 0
+
+
 def test_phase_four_chip_tiny(eight_devices):
     out = chip_smoke.phase_four_chip(
         model="gpt2-125m", seq=128, micro=2, steps=2, slots=2,
