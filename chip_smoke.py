@@ -472,12 +472,67 @@ def _check_ssd_step(layers, lanes, heads, groups, d_head, d_state,
             "rel_l2": {n: round(e, 8) for n, e in errs.items()}}
 
 
+def _check_grouped_matmul(rows, d_in, d_out, groups, dtype, strict: bool):
+    """The experts' grouped matmul through the kernels, forward and both
+    gradients, vs ``jax.lax.ragged_dot`` and its autodiff on ragged groups
+    that end inside tiles, one of them empty, the last rows uncovered."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    rng = np.random.RandomState(23)
+    lhs = jnp.asarray(rng.randn(rows, d_in), dtype)
+    rhs = jnp.asarray(rng.randn(groups, d_in, d_out) / np.sqrt(d_in), dtype)
+    cot = jnp.asarray(rng.randn(rows, d_out), dtype)
+    sizes = rng.multinomial(rows - rows // 16,
+                            rng.dirichlet(np.ones(groups)))
+    sizes[groups // 2] = 0
+    sizes = jnp.asarray(sizes, jnp.int32)
+
+    def both(matmul):
+        def run(lhs, rhs, sizes, cot):
+            out, vjp = jax.vjp(lambda a, w: matmul(a, w, sizes), lhs, rhs)
+            return (out,) + vjp(cot)
+        return jax.jit(run)
+
+    fn = both(grouped_matmul)
+    mosaic = _mosaic_calls(
+        fn.lower(lhs, rhs, sizes, cot).compile().as_text())
+    got, want = fn(lhs, rhs, sizes, cot), both(jax.lax.ragged_dot)(
+        lhs, rhs, sizes, cot)
+    # over the rows the groups cover: past them the kernels give zeros,
+    # which is what the program counts dropped pairs from, and what the
+    # compiler's call leaves there on the TPU is reported, not compared
+    covered = int(sizes.sum())
+    errs = {"out": _rel_l2(got[0][:covered], want[0][:covered]),
+            "d_lhs": _rel_l2(got[1][:covered], want[1][:covered]),
+            "d_rhs": _rel_l2(got[2], want[2])}
+    past = {n: float(np.abs(np.asarray(t[0][covered:], np.float32)).max())
+            for n, t in (("kernel", got), ("ragged_dot", want))}
+    tol = 4e-3  # both round an f32 sum once to bf16, in another order
+    if max(errs.values()) > tol or past["kernel"] != 0 or np.asarray(
+            got[1][covered:], np.float32).any():
+        raise AssertionError(
+            f"grouped_matmul rel-L2 {errs} > {tol}, or rows past the "
+            f"groups not zero: {past}")
+    if strict and mosaic != 3:
+        raise AssertionError(f"grouped_matmul: {mosaic} Mosaic calls")
+    return {"kernel": "ragged-dot-gmm", "rows": rows, "d_in": d_in,
+            "d_out": d_out, "groups": groups, "dtype": jnp.dtype(dtype).name,
+            "mosaic_calls": mosaic, "tol": tol,
+            "largest_past_the_groups": past,
+            "rel_l2": {n: round(e, 8) for n, e in errs.items()}}
+
+
 def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
                   adam_shape=(2048, 8192), splash=(4096, 128, 16, 64),
                   decode_shapes=((2, 16, 1024, 16, 16, 128),
                                  (2, 8, 1408, 4, 20, 128)),
                   retention_shape=(2, 8, 8, 40, 128),
                   ssd_shape=(2, 8, 32, 2, 128, 256),
+                  gmm_shape=(8192, 2048, 1024, 64),
                   dtype=None, strict=True) -> dict:
     """Each Pallas kernel once at a production shape, forward and
     backward, against plain ``jnp``. ``flash_shapes`` rows are
@@ -487,7 +542,9 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
     heads, head_dim)`` of a stacked KV leaf (full heads, grouped heads);
     ``retention_shape`` is ``(layers, lanes, kv_heads, heads, head_dim)``
     of a stacked retention state; ``ssd_shape`` is ``(layers, lanes,
-    heads, groups, d_head, d_state)`` of a stacked Mamba-2 state."""
+    heads, groups, d_head, d_state)`` of a stacked Mamba-2 state;
+    ``gmm_shape`` is ``(rows, d_in, d_out, groups)`` of a grouped matmul
+    over rows sorted by group."""
     import jax.numpy as jnp
 
     from deepspeed_tpu.ops.pallas.common import interpret
@@ -509,6 +566,8 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
                   for shape in decode_shapes)
     checks.append(_check_retention_step(*retention_shape, strict))
     checks.append(_check_ssd_step(*ssd_shape, strict))
+    checks.extend(_check_grouped_matmul(*gmm_shape, dt, strict)
+                  for dt in dict.fromkeys((dtype, jnp.float32)))
     for c in checks:
         emit({"phase": "kernels", "check": c})
     return {"phase": "kernels", "ok": True, "n_checks": len(checks),

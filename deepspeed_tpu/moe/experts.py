@@ -5,21 +5,57 @@ cloned FFNs, each rank holding ``num_local_experts``). TPU re-design: ONE
 parameter tensor with a leading ``experts`` axis, sharded over the ``ep`` mesh
 axis — "local experts" are the shard XLA assigns this device; the per-expert
 loop becomes a batched einsum on the MXU.
+
+Over rows sorted by expert (the dropless path) each projection is one
+grouped matmul. Where the layout allows it that is the repo's own Pallas
+kernel (``ops/pallas/grouped_matmul.py``: an expert's matrix stays on chip
+while its rows stream past; forward and both gradients), chosen by
+:func:`grouped_matmul_tiles` from what the call shows and by no option;
+otherwise ``jax.lax.ragged_dot``, which the compiler can partition.
 """
 
-from typing import Any, Callable
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+def grouped_matmul_tiles(rows: int, d_model: int, d_hidden: int,
+                         num_experts: int,
+                         dtype) -> Optional[Tuple[int, int, int]]:
+    """``(tm, tk, tn)`` of the kernel's forward call for the up projection
+    (its ``tm`` is the layer's: one walk over the row tiles serves all its
+    calls) where the experts' grouped matmuls over ``rows`` sorted rows run
+    in the Pallas kernel, None where they are ``jax.lax.ragged_dot``. The
+    kernel
+    wants both widths whole lanes (multiples of 128), the rows whole row
+    tiles, bf16 or float32 operands (``grouped_matmul.supported``), and
+    the expert tensors whole on the device: a Pallas call is opaque to the
+    partitioner, so under an ``ep`` or ``tp`` axis of more than one device
+    the compiler's ragged dot, which it can partition, stays."""
+    # imported where it is needed: importing Pallas and Mosaic is a second
+    # of a process's start, and most models have no experts
+    from deepspeed_tpu.ops.pallas import autotune, grouped_matmul
+    from deepspeed_tpu.parallel.mesh import get_default_topology
+
+    topo = get_default_topology()
+    if topo.size("ep") > 1 or topo.size("tp") > 1 \
+            or not grouped_matmul.supported(rows, d_model, d_hidden, dtype):
+        return None
+    return autotune.grouped_matmul_tiles(
+        "gmm", rows, d_model, d_hidden, num_experts, dtype)
 
 class StackedExperts(nn.Module):
     """[E, C, M] -> [E, C, M] two-layer FFN, vectorized over experts; or,
     with ``group_sizes`` ([E], summing to R), [R, M] -> [R, M] over rows
     sorted by expert: each einsum becomes one grouped matmul over the
-    ragged groups (``jax.lax.ragged_dot``), so no row is padding and none
-    is dropped.
+    ragged groups, so no row is padding and none is dropped: the Pallas
+    kernel ``ops/pallas/grouped_matmul.py`` where
+    :func:`grouped_matmul_tiles` says the layout allows it (widths
+    multiples of 128, bf16 or float32, no ``ep`` or ``tp`` axis over the
+    expert tensors), ``jax.lax.ragged_dot`` otherwise; the same arithmetic
+    either way (float32 accumulation over all of K, one rounding, zeros
+    for rows past ``sum(group_sizes)``).
 
     Param shapes carry the expert axis first (``wi: [E, M, H]``,
     ``wo: [E, H, M]``) so expert-parallel sharding rules can address it
@@ -49,7 +85,20 @@ class StackedExperts(nn.Module):
             def per_expert(b):
                 return b[:, None, :]
         else:
+            # (``init`` wants the parameters' shapes alone: tracing and
+            # lowering three kernels for it is set-up time for nothing)
+            tiles = None if self.is_initializing() else \
+                grouped_matmul_tiles(x.shape[0], M, H, E, self.dtype)
+            if tiles:
+                from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+
+                # one walk over the row tiles for all three projections,
+                # forward and backward
+                walk = gm.row_walk(group_sizes, x.shape[0], tiles[0])
+
             def matmul(a, w):
+                if tiles:
+                    return gm.grouped_matmul(a, w, group_sizes, walk)
                 return jax.lax.ragged_dot(a, w, group_sizes)
 
             def per_expert(b):

@@ -22,9 +22,16 @@ above, which drops what overflows. Dropless (``drop_tokens=False`` or
 ``k > 2``): softmax, top-k, a sort of the tokens * k (token, expert) pairs
 by expert, a gather, one grouped matmul per projection over the ragged
 groups, and the weighted sum back (moe/sharded_moe.py); no token is left
-out under any load, and no tensor grows with experts * capacity. On an
-``ep`` mesh the expert tensors keep their ``ep`` sharding; the sorted rows
-are not annotated yet (no dropless configuration runs expert-parallel).
+out under any load, and no tensor grows with experts * capacity. The
+grouped matmul is the repo's Pallas kernel
+(``ops/pallas/grouped_matmul.py``: an expert's matrix stays on chip while
+its rows stream past) where the widths are multiples of 128, the operands
+bf16 or float32 and no ``ep`` or ``tp`` axis spans the expert tensors
+(``moe/experts.py`` ``grouped_matmul_tiles`` decides from what the call
+shows; no option), and ``jax.lax.ragged_dot`` otherwise: on an ``ep`` mesh
+the expert tensors keep their ``ep`` sharding and the compiler partitions
+its own call; the sorted rows are not annotated yet (no dropless
+configuration runs expert-parallel).
 """
 
 from typing import Any, Optional
@@ -34,7 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from deepspeed_tpu.moe.experts import StackedExperts
+from deepspeed_tpu.moe.experts import StackedExperts, grouped_matmul_tiles
 from deepspeed_tpu.moe.sharded_moe import (
     combine_rows,
     combine_tokens,
@@ -116,9 +123,11 @@ class MoE(nn.Module):
         the (token, expert) pairs the router asked for; ``computed``
         [experts], those whose expert output exists, counted from the
         dispatch (one-hot path) or the grouped matmuls' output (dropless)
-        and not from the routing; ``chosen`` [tokens, k] on the dropless
-        path. (``init`` makes every collection mutable and would return
-        them beside the parameters.)"""
+        and not from the routing; on the dropless path ``chosen`` [tokens,
+        k] and ``gmm_tiles`` [3], the grouped-matmul kernel's ``(tm, tk,
+        tn)`` as this trace chose them, zeros where it chose
+        ``ragged_dot``. (``init`` makes every collection mutable and would
+        return them beside the parameters.)"""
         if not self.is_initializing():
             for name, value in counters.items():
                 self.sow(MOE_STATS, name, value)
@@ -159,7 +168,11 @@ class MoE(nn.Module):
                                  dtype=x.dtype)
             self._count(routed=routed, chosen=route.experts,
                         computed=rows_computed(rows, route.experts, order,
-                                               self.num_experts))
+                                               self.num_experts),
+                        gmm_tiles=jnp.asarray(grouped_matmul_tiles(
+                            rows.shape[0], d_model, self.d_hidden,
+                            self.num_experts, self.dtype) or (0, 0, 0),
+                            jnp.int32))
             return (y.reshape(orig_shape), route.l_aux, route.l_z,
                     route.exp_counts)
 

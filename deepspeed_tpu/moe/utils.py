@@ -34,7 +34,7 @@ def split_moe_params(params) -> Tuple[Any, Any]:
 
 # rank of one layer's value of each counter the MoE layer sows; whatever
 # leads it (a scan's layer axis, none for a layer on its own) is flattened
-_COUNTER_RANK = {"routed": 0, "computed": 1, "chosen": 2}
+_COUNTER_RANK = {"routed": 0, "computed": 1, "chosen": 2, "gmm_tiles": 1}
 
 
 def routing_stats(model, params, batch):
@@ -43,8 +43,10 @@ def routing_stats(model, params, batch):
     (token, expert) pairs each router asked for), ``computed`` [layers,
     experts] (the pairs whose expert output exists, counted from the
     dispatch or the experts' output) and, on the dropless path, ``chosen``
-    [layers, tokens, k] (each token's chosen experts). One forward program
-    of its own, off the step."""
+    [layers, tokens, k] (each token's chosen experts) and ``gmm_tiles``
+    [layers, 3] (the grouped-matmul kernel's tiles, zeros where the layer
+    traced ``ragged_dot``). One forward program of its own, off the
+    step."""
     import numpy as np
     from flax.traverse_util import flatten_dict
 
@@ -72,13 +74,34 @@ def publish_expert_load(model, params, batch):
     ``max_over_mean`` (the fullest expert's load over the mean load, the
     largest over the layers; 1 is balanced) and ``tokens_dropped`` (pairs
     the routers asked for less pairs computed, over all layers; the
-    dropless path computes every pair). A training loop calls it when it
-    wants to look, never per step."""
+    dropless path computes every pair). Of the dropless path's grouped
+    matmuls it says which implementation the layers traced,
+    ``grouped_matmul`` (``"pallas"``: the kernel of
+    ``ops/pallas/grouped_matmul.py``; ``"xla"``: ``jax.lax.ragged_dot``;
+    ``"none"``: the one-hot path has neither), the kernel's
+    ``grouped_matmul_tiles`` ``[tm, tk, tn]`` and
+    ``row_tile_visits_over_least``: the row-tile visits the kernel makes
+    for these ``tokens_per_expert`` over ``rows / tm``, the worst layer's
+    (1.0 when no expert's rows end inside a tile; each one that does is a
+    tile computed twice, and an expert of no rows has one visit that
+    computes nothing); host arithmetic on the counts. A training loop
+    calls it when it wants to look, never per step."""
     from deepspeed_tpu.telemetry import publish
 
     stats = routing_stats(model, params, batch)
     counts = stats["computed"]
+    tiles, over_least = stats.get("gmm_tiles"), None
+    path = "none" if tiles is None else "pallas" if tiles.any() else "xla"
+    tiles = tiles[0].tolist() if path == "pallas" else None
+    if tiles:
+        from deepspeed_tpu.ops.pallas.grouped_matmul import row_tile_visits
+
+        over_least = max(
+            row_tile_visits(c, int(rows), tiles[0]) / (int(rows) / tiles[0])
+            for c, rows in zip(counts, stats["routed"]))
     return publish(
         "moe.load", tokens_per_expert=counts.tolist(),
         max_over_mean=float((counts.max(axis=1) / counts.mean(axis=1)).max()),
-        tokens_dropped=int(stats["routed"].sum() - counts.sum()))
+        tokens_dropped=int(stats["routed"].sum() - counts.sum()),
+        grouped_matmul=path, grouped_matmul_tiles=tiles,
+        row_tile_visits_over_least=over_least)

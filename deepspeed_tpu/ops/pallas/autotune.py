@@ -241,3 +241,74 @@ def clear_memory_cache() -> None:
     with _lock:
         _mem_cache.clear()
         _disk_warned = False
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul (ops/pallas/grouped_matmul.py): (tm, tk, tn) per product.
+# No live search: a program's set-up is judged, and the table is small.
+# ---------------------------------------------------------------------------
+# what the tiles of one call may hold of VMEM (double-buffered operands and
+# result, the float32 product or accumulator) when nothing better is known
+_GMM_VMEM_BUDGET = 24 << 20
+_GMM_KINDS = ("gmm", "gmm_t", "tgmm")
+
+# (kind, rows, K, N, groups, dtype, device_kind) -> (tm, tk, tn), found on
+# the chip (PERF.md, section 6, PR 38); rows, K, N as the call has them
+GMM_PRETUNED: Dict[Tuple[str, int, int, int, int, str, str],
+                   Tuple[int, int, int]] = {}
+# OLMoE-1B-7B at 2 x 4096 tokens x 8 experts a token (65,536 rows, 64
+# experts of [2048, 1024]): row tiles of 256 and the whole of the other
+# two axes, for all three products of both projections' shapes. tm 128
+# reads within 1.5% of it (an eighth of its visits are second visits of a
+# shared tile, a quarter at 256, and the matrix unit likes the longer
+# stream as much), tm 512 is 10% slower; a narrower tn reads the rows
+# again for every column tile and is 4-30% slower
+for _kind in ("TPU v5 lite", "TPU v5e"):
+    for _k, _n in ((2048, 1024), (1024, 2048)):
+        for _product in _GMM_KINDS:
+            GMM_PRETUNED[(_product, 65536, _k, _n, 64, "bfloat16",
+                          _kind)] = (256, _k, _n)
+
+
+def _multiples(x: int, unit: int, most: int) -> List[int]:
+    """Divisors of ``x`` that are multiples of ``unit``, at most ``most``,
+    largest first."""
+    return [b for b in range(min(most, x) // unit * unit, 0, -unit)
+            if x % b == 0]
+
+
+def grouped_matmul_vmem_bytes(kind: str, tm: int, tk: int, tn: int,
+                              itemsize: int) -> int:
+    """VMEM the tiles of one call take: operands and result double-buffered,
+    and the float32 product (``tgmm``: accumulator and product)."""
+    if kind == "tgmm":
+        return 2 * itemsize * (tm * (tk + tn) + tk * tn) + 8 * tk * tn
+    return 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def grouped_matmul_tiles(kind: str, rows: int, k: int, n: int, groups: int,
+                         dtype) -> Tuple[int, int, int]:
+    """``(tm, tk, tn)`` of one grouped-matmul call. ``kind``: ``gmm``
+    (rows [rows, k] by matrices [k, n]), ``gmm_t`` (the same by matrices
+    [n, k] contracted on their last axis), ``tgmm`` ([rows, k] and [rows,
+    n] to [k, n] a group). For the first two ``tk`` is all of ``k``: it is
+    what keeps a group's matrix on chip. The table's entry where the chip
+    and the shapes have one; else ``tm`` 128 (the most that divides the
+    rows) and the widest ``tn`` (``tgmm``: the largest ``tk x tn``) whose
+    tiles fit :data:`_GMM_VMEM_BUDGET`."""
+    if kind not in _GMM_KINDS:
+        raise ValueError(f"kind {kind!r} is none of {_GMM_KINDS}")
+    dtype = jnp.dtype(dtype)
+    hit = GMM_PRETUNED.get((kind, rows, k, n, groups, dtype.name,
+                            jax.devices()[0].device_kind))
+    if hit is not None:
+        return hit
+    tm = _multiples(rows, 16, 128)[0]
+    fits = [(tk, tn)
+            for tk in (_multiples(k, 128, k) if kind == "tgmm" else [k])
+            for tn in _multiples(n, 128, n)
+            if grouped_matmul_vmem_bytes(kind, tm, tk, tn, dtype.itemsize)
+            <= _GMM_VMEM_BUDGET]
+    tk, tn = max(fits, key=lambda t: (t[0] * t[1], t[1])) if fits \
+        else ((128 if kind == "tgmm" else k), 128)
+    return tm, tk, tn

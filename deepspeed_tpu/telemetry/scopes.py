@@ -93,9 +93,13 @@ SCOPE_REMAT = "rematted_computation"
 # (``ragged-dot-none.N`` and the small ``ragged-dot-metadata.N``) whose
 # ``op_name`` is that name alone, not the path of the line that asked for
 # the dot. The program has one such line, the experts' grouped matmul
-# (moe/experts.py, under ``moe_experts``), so an instruction with such a
-# bare name is given that scope; whether it ran forward, backward or
-# recomputed cannot be told from it.
+# where it falls back to ``jax.lax.ragged_dot`` (moe/experts.py, under
+# ``moe_experts``), so an instruction with such a bare name is given that
+# scope; whether it ran forward, backward or recomputed cannot be told
+# from it. The repo's own kernel (ops/pallas/grouped_matmul.py:
+# ``ragged-dot-gmm.N``, ``ragged-dot-tgmm.N``) keeps the whole path as
+# every Pallas call does, ``moe_experts`` and ``rematted_computation``
+# included, and needs no rule.
 _RAGGED_DOT = "ragged-dot"
 
 _CARRY_FREE = frozenset((

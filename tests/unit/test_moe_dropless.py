@@ -19,6 +19,7 @@ import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.models.transformer_lm import GPT, GPTConfig
+from deepspeed_tpu.moe import experts as experts_mod
 from deepspeed_tpu.moe import sharded_moe
 from deepspeed_tpu.moe.experts import StackedExperts
 from deepspeed_tpu.moe.layer import MoE
@@ -28,6 +29,10 @@ from perfbench.reference import olmoe
 
 TOL = 2e-4
 SIZES = [(8, 3), (64, 8)]       # (experts, experts per token)
+# at widths of 128 the grouped matmuls are the Pallas kernel
+# (ops/pallas/grouped_matmul.py; moe/experts.py grouped_matmul_tiles); the
+# narrower models above run jax.lax.ragged_dot
+WIDE = dict(n_embd=128, intermediate_size=128)
 GROUPS = {"experts": ("mlp/experts/",), "router": ("mlp/gate/",),
           "attention": ("attn/",), "norms": ("ln_1/", "ln_2/"),
           "embedding": ("wte/",), "head": ("lm_head", "ln_f/")}
@@ -82,11 +87,16 @@ def by_group(tree):
     return out
 
 
-@pytest.fixture(scope="module", params=SIZES, ids=lambda s: f"e{s[0]}k{s[1]}")
+@pytest.fixture(scope="module", params=SIZES + [(8, 3, WIDE)],
+                ids=lambda s: f"e{s[0]}k{s[1]}" + "-kernel" * (len(s) > 2))
 def both(request):
     """System and reference on one seeded model: logits, loss, routing and
     gradients of each."""
-    cfg = config(*request.param)
+    cfg = config(*request.param[:2], **dict(*request.param[2:]))
+    assert (experts_mod.grouped_matmul_tiles(
+        2 * 32 * cfg.moe_top_k, cfg.n_embd, cfg.intermediate_size,
+        cfg.moe_num_experts, cfg.dtype) is not None) == (
+            len(request.param) > 2)
     model, params, ids = seeded(cfg)
     sys_logits = model.apply({"params": params}, ids)
     sys_loss, sys_grads = jax.value_and_grad(
@@ -149,6 +159,18 @@ def test_the_engine_trains_it_and_starts_at_the_reference_loss(both):
                                {"input_ids": ids})
     assert load["kind"] == "moe.load" and load["tokens_dropped"] == 0
     assert load["max_over_mean"] >= 1.0
+    # which grouped matmul the layers traced, and how well its row tiles
+    # fit this load (a tile that two experts share is computed twice)
+    if cfg.n_embd == WIDE["n_embd"]:
+        rows, tm = ids.size * cfg.moe_top_k, 96
+        assert load["grouped_matmul"] == "pallas"
+        assert load["grouped_matmul_tiles"] == [tm, cfg.n_embd, 128]
+        assert 1.0 <= load["row_tile_visits_over_least"] \
+            <= (rows // tm + cfg.moe_num_experts) / (rows // tm)
+    else:
+        assert load["grouped_matmul"] == "xla"
+        assert load["grouped_matmul_tiles"] is None
+        assert load["row_tile_visits_over_least"] is None
 
 
 def layer_on(experts, top_k, gate, **kw):
