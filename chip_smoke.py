@@ -526,6 +526,178 @@ def _check_grouped_matmul(rows, d_in, d_out, groups, dtype, strict: bool):
             "rel_l2": {n: round(e, 8) for n, e in errs.items()}}
 
 
+def _deepseek_sizes(heads, q_rank, kv_rank, nope, rope, v_dim, routed_over,
+                    held, n_group, topk_group, top_k):
+    """``perfbench/reference/deepseek_v2.py``'s sizes for a check's
+    shapes, with the published YaRN numbers."""
+    from perfbench.reference import deepseek_v2 as reference
+
+    return reference.sizes({
+        "attention_bias": False, "topk_method": "group_limited_greedy",
+        "scoring_func": "softmax", "norm_topk_prob": False,
+        "moe_layer_freq": 1, "hidden_act": "silu",
+        "tie_word_embeddings": False, "rms_norm_eps": 1e-6,
+        "num_attention_heads": heads, "qk_nope_head_dim": nope,
+        "qk_rope_head_dim": rope, "v_head_dim": v_dim,
+        "kv_lora_rank": kv_rank, "rope_theta": 10000,
+        "rope_scaling": {"type": "yarn", "factor": 40, "beta_fast": 32,
+                         "beta_slow": 1, "mscale": 0.707,
+                         "mscale_all_dim": 0.707,
+                         "original_max_position_embeddings": 4096},
+        "first_k_dense_replace": 1, "n_group": n_group,
+        "topk_group": topk_group, "num_experts_per_tok": top_k,
+        "routed_scaling_factor": 16,
+        "moe": {"routed_over": routed_over, "experts_held": list(held)}})
+
+
+def _deepseek_config(hidden, heads, q_rank, kv_rank, nope, rope, v_dim,
+                     positions, dtype, **moe):
+    from deepspeed_tpu.models.transformer_lm import GPTConfig, MLAConfig
+
+    return GPTConfig(
+        vocab_size=256, n_positions=positions, n_embd=hidden, n_layer=1,
+        n_head=heads, norm="rmsnorm", layer_norm_epsilon=1e-6,
+        activation="silu", gated_mlp=True, use_bias=False, rotary=True,
+        learned_positions=False, tie_word_embeddings=False, dtype=dtype,
+        param_dtype=dtype, use_flash_attention=False,
+        mla=MLAConfig(q_rank=q_rank, kv_rank=kv_rank, nope_dim=nope,
+                      rope_dim=rope, v_dim=v_dim, yarn_factor=40.0,
+                      yarn_original_positions=4096, yarn_mscale=0.707,
+                      yarn_mscale_all_dim=0.707), **moe)
+
+
+def _check_latent_decode(lanes, positions, hidden, heads, q_rank, kv_rank,
+                         nope, rope, v_dim, dtype, strict: bool):
+    """One decode step of latent attention, the absorbed form over a
+    lane cache of ragged lengths, vs the plain reference's NON-absorbed
+    sum over the same cached latents (every position's keys and values
+    decompressed per head, float32). The cached rows are random: the
+    check is of this step's arithmetic, not of how they came to be."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.models.latent_attention import Lane, LatentAttention
+    from perfbench.reference import deepseek_v2 as reference
+
+    cfg = _deepseek_config(hidden, heads, q_rank, kv_rank, nope, rope,
+                           v_dim, positions, dtype)
+    s = _deepseek_sizes(heads, q_rank, kv_rank, nope, rope, v_dim, 16,
+                        (0, 2), 8, 3, 6)
+    rng = np.random.RandomState(23)
+    held = rng.randint(positions // 4, positions - 1, size=lanes)
+    first = rng.randint(0, 8, size=lanes)           # a bucket's padding
+    rows = np.arange(positions)
+    valid = (rows[None] >= first[:, None]) & (rows[None] <= held[:, None])
+    lane = Lane(
+        latent=jnp.asarray(rng.randn(1, lanes, positions, kv_rank), dtype),
+        rope_key=jnp.asarray(rng.randn(1, lanes, positions, rope), dtype),
+        valid=jnp.asarray(valid), index=jnp.asarray(held, jnp.int32),
+        fresh=False)
+    x = jnp.asarray(rng.randn(lanes, 1, hidden), dtype)
+    mixer = LatentAttention(cfg)
+    params = mixer.init(jax.random.PRNGKey(3), x)["params"]
+
+    def step(params, x, lane):
+        return mixer.apply({"params": params}, x, lane=lane, cache_layer=0)
+
+    fn = jax.jit(step)
+    y, after = fn(params, x, lane)
+    p32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+
+    def plain(b):
+        """The reference's per-head sum for lane ``b``."""
+        n, t = int(held[b]), int(held[b]) + 1
+        xb = x[b].astype(jnp.float32)                        # [1, hidden]
+        c_q = reference.rms_norm(
+            reference.mm(xb, p32["q_a"]["kernel"]),
+            p32["q_a_norm"]["scale"], s["eps"])
+        q = reference.mm(c_q, p32["q_b"]["kernel"]).reshape(
+            1, heads, nope + rope)
+        q_rope = reference.rotary(q[..., nope:], s, n)
+        c_new, r_new = reference.latents(xb, p32, s, n)
+        c = jnp.concatenate([lane.latent[0, b, :n].astype(jnp.float32),
+                             c_new])
+        r = jnp.concatenate([lane.rope_key[0, b, :n].astype(jnp.float32),
+                             r_new])
+        kv = reference.mm(c, p32["kv_b"]["kernel"]).reshape(
+            t, heads, nope + v_dim)
+        scores = (jnp.einsum("qhd,khd->hqk", q[..., :nope], kv[..., :nope],
+                             precision=reference.HIGHEST)
+                  + jnp.einsum("qhd,kd->hqk", q_rope, r,
+                               precision=reference.HIGHEST)) * s["scale"]
+        seen = jnp.asarray(valid[b, :t] | (rows[:t] == n))
+        probs = jax.nn.softmax(jnp.where(seen[None, None], scores, -jnp.inf),
+                               -1)
+        out = jnp.einsum("hqk,khd->qhd", probs, kv[..., nope:],
+                         precision=reference.HIGHEST)
+        return reference.mm(out.reshape(1, heads * v_dim),
+                            p32["c_proj"]["kernel"]), c_new, r_new
+
+    errs = {"y": 0.0, "latent": 0.0, "rope_key": 0.0}
+    for b in range(lanes):
+        want, c_new, r_new = plain(b)
+        n = int(held[b])
+        errs["y"] = max(errs["y"], _rel_l2(y[b], want))
+        errs["latent"] = max(errs["latent"],
+                             _rel_l2(after.latent[0, b, n], c_new[0]))
+        errs["rope_key"] = max(errs["rope_key"],
+                               _rel_l2(after.rope_key[0, b, n], r_new[0]))
+    tol = 1e-4 if jnp.dtype(dtype) == jnp.float32 else 2e-2
+    if max(errs.values()) > tol:
+        raise AssertionError(f"latent decode step rel-L2 {errs} > {tol}")
+    return {"kernel": "mla_absorbed_decode", "lanes": lanes,
+            "positions": positions, "heads": heads, "kv_rank": kv_rank,
+            "rope_dim": rope, "tol": tol,
+            "rel_l2": {n: round(e, 8) for n, e in errs.items()}}
+
+
+def _check_held_experts(tokens, hidden, width, routed_over, held, dtype,
+                        strict: bool):
+    """One expert layer that holds a share of the experts its router
+    scores (group-limited choice of 6 among 8 groups' best 3, weights x
+    16, two shared experts) vs the plain reference, which computes every
+    held expert on every token and weighs it by the token's choice."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.moe.layer import MOE_STATS, MoE
+    from perfbench.reference import deepseek_v2 as reference
+
+    layer = MoE(d_model=hidden, d_hidden=width, num_experts=routed_over, k=6,
+                drop_tokens=False, gated_experts=True, n_shared=2, n_group=8,
+                topk_group=3, routed_scale=16.0, experts_held=held,
+                dtype=dtype, param_dtype=dtype)
+    s = _deepseek_sizes(4, 8, 8, 4, 4, 4, routed_over, held, 8, 3, 6)
+    x = jnp.asarray(np.random.RandomState(29).randn(tokens, hidden), dtype)
+    params = layer.init(jax.random.PRNGKey(5), x)["params"]
+
+    def run(params, x):
+        (y, *_), stats = layer.apply({"params": params}, x,
+                                     mutable=[MOE_STATS])
+        return y, stats[MOE_STATS]
+
+    fn = jax.jit(run)
+    mosaic = _mosaic_calls(fn.lower(params, x).compile().as_text())
+    y, stats = fn(params, x)
+    here = int(stats["routed_here"][0])
+    want = reference.moe(
+        x.astype(jnp.float32),
+        jax.tree.map(lambda a: a.astype(jnp.float32), params), s)
+    err = _rel_l2(y, want)
+    tol = 1e-4 if jnp.dtype(dtype) == jnp.float32 else 2e-2
+    if err > tol or int(stats["computed"][0].sum()) != here:
+        raise AssertionError(
+            f"held experts rel-L2 {err} > {tol}, or "
+            f"{int(stats['computed'][0].sum())} pairs computed of {here}")
+    if strict and mosaic != 3:
+        raise AssertionError(f"held experts: {mosaic} Mosaic calls")
+    return {"kernel": "moe_held_experts", "tokens": tokens, "held": held[1],
+            "routed_over": routed_over, "routed_here": here,
+            "mosaic_calls": mosaic, "tol": tol, "rel_l2": round(err, 8)}
+
+
 def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
                   adam_shape=(2048, 8192), splash=(4096, 128, 16, 64),
                   decode_shapes=((2, 16, 1024, 16, 16, 128),
@@ -533,6 +705,9 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
                   retention_shape=(2, 8, 8, 40, 128),
                   ssd_shape=(2, 8, 32, 2, 128, 256),
                   gmm_shape=(8192, 2048, 1024, 64),
+                  latent_shape=(8, 2944, 5120, 128, 1536, 512, 128, 64,
+                                128),
+                  held_experts_shape=(256, 5120, 1536, 160, (0, 20)),
                   dtype=None, strict=True) -> dict:
     """Each Pallas kernel once at a production shape, forward and
     backward, against plain ``jnp``. ``flash_shapes`` rows are
@@ -544,7 +719,12 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
     of a stacked retention state; ``ssd_shape`` is ``(layers, lanes,
     heads, groups, d_head, d_state)`` of a stacked Mamba-2 state;
     ``gmm_shape`` is ``(rows, d_in, d_out, groups)`` of a grouped matmul
-    over rows sorted by group."""
+    over rows sorted by group; ``latent_shape`` is ``(lanes, positions,
+    hidden, heads, q_rank, kv_rank, nope, rope, v_dim)`` of one decode
+    step of latent attention, and ``held_experts_shape`` ``(tokens,
+    hidden, width, experts scored, (first, count) held)`` of one expert
+    layer that holds a share, both against ``perfbench/reference/
+    deepseek_v2.py``."""
     import jax.numpy as jnp
 
     from deepspeed_tpu.ops.pallas.common import interpret
@@ -568,6 +748,8 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
     checks.append(_check_ssd_step(*ssd_shape, strict))
     checks.extend(_check_grouped_matmul(*gmm_shape, dt, strict)
                   for dt in dict.fromkeys((dtype, jnp.float32)))
+    checks.append(_check_latent_decode(*latent_shape, dtype, strict))
+    checks.append(_check_held_experts(*held_experts_shape, dtype, strict))
     for c in checks:
         emit({"phase": "kernels", "check": c})
     return {"phase": "kernels", "ok": True, "n_checks": len(checks),

@@ -51,10 +51,13 @@ def kv_leaf_shapes(tree):
     """The shapes a whole KV-cache leaf takes inside a program, from any
     pytree of arrays or avals that holds caches (the leaves named
     ``cached_key`` / ``cached_value`` / ``cached_*_scale`` of the model's
-    ``cache`` collection): the leaf as stored, and without its leading
-    layer axis where ``ScannedBlocks`` stacked it (one layer's slice, which
-    a turn of the layer loop reads inside its attention fusions and should
-    never produce)."""
+    ``cache`` collection, and latent attention's ``cached_latent`` /
+    ``cached_rope_key``, which have no head axis): the leaf as stored, and
+    without its leading layer axis where ``ScannedBlocks`` stacked it (one
+    layer's slice, which a turn of the layer loop reads inside its
+    attention fusions and should never produce)."""
+    from deepspeed_tpu.models.latent_attention import LATENT_LEAVES
+
     shapes = set()
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         name = str(getattr(path[-1], "key", ""))
@@ -62,7 +65,8 @@ def kv_leaf_shapes(tree):
             continue
         shape = tuple(leaf.shape)
         shapes.add(shape)
-        if len(shape) > (3 if name.endswith("_scale") else 4):
+        headless = name.endswith("_scale") or name in LATENT_LEAVES
+        if len(shape) > (3 if headless else 4):
             shapes.add(shape[1:])
     return shapes
 

@@ -233,6 +233,15 @@ class _LaneClocks:
         self.first[lane] = bucket - prompt_len
         self.clock[lane] = bucket + replayed
 
+    def live_positions(self, lanes) -> int:
+        """Rows that the requests now in ``lanes`` (None: a free lane)
+        have written: each one's prompt and what it has decoded, all
+        lanes summed; the positions a decode step's attention has to
+        read, whatever it does read."""
+        held = np.fromiter((lane is not None for lane in lanes), bool,
+                           len(lanes))
+        return int((self.clock - self.first)[held].sum())
+
     def step(self) -> Optional[float]:
         if self.block == 0:
             return None
@@ -262,8 +271,10 @@ class LanesAtExit:
     the lanes after its own are then one undelivered token ahead."""
 
     # what the model declares as recurrent state (``GPTConfig.
-    # recurrent_leaves``); the scheduler sets it on what it keeps
+    # recurrent_leaves``) and as what it keeps per position (``GPTConfig.
+    # position_leaves``); the scheduler sets both on what it keeps
     recurrent_leaves = ()
+    position_leaves = {}
 
     def __init__(self, owners, cache):
         self.cache = cache
@@ -278,7 +289,18 @@ class LanesAtExit:
         ``[layers, Hkv, D, d]`` and ``ret_norm`` ``[layers, Hkv, D]``):
         the stacked leaves of ``ScannedBlocks`` or, layer by layer in tree
         order, an unrolled model's. Empty for a model without one."""
-        rank = {leaf.name: leaf.rank for leaf in self.recurrent_leaves}
+        return self._lane_leaves(
+            lane, {leaf.name: leaf.rank for leaf in self.recurrent_leaves})
+
+    def positions(self, lane: int):
+        """The same for what the model keeps PER POSITION (``GPTConfig.
+        position_leaves``: keys and values ``[layers, S, Hkv, D]``, or
+        latent attention's ``cached_latent`` ``[layers, S, kv_rank]`` and
+        ``cached_rope_key`` ``[layers, S, rope_dim]``), with ``valid``
+        ``[layers or 1, S]``: which rows the lane's request wrote."""
+        return self._lane_leaves(lane, dict(self.position_leaves, valid=2))
+
+    def _lane_leaves(self, lane: int, rank):
         out = {}
         for path, leaf in jax.tree_util.tree_flatten_with_path(
                 self.cache)[0]:
@@ -425,6 +447,37 @@ class ContinuousBatchingScheduler:
                     "the state of a longer prompt cannot be cut there (a "
                     "snapshot of the state at the boundary would do; "
                     "serving/prefix_cache.py takes none)", self._recurrent)
+        # which leaves hold something per position, as the model says
+        from deepspeed_tpu.models.transformer_lm import KV_LEAVES
+
+        self._position_leaves = dict(getattr(
+            self._mcfg, "position_leaves", KV_LEAVES))
+        if getattr(self._mcfg, "mla", None) is not None:
+            # refused here, by name, and not by a wrong answer later
+            from deepspeed_tpu.models.transformer_lm import LatentCacheError
+
+            if draft_engine is not None:
+                raise LatentCacheError(
+                    "draft_engine (speculative decoding)",
+                    "_rewind restores each attention module's leaves "
+                    "beside its own clock, and a latent cache has one "
+                    "clock a lane and its leaves with whoever runs the "
+                    "layers; nothing has verified a draft against it")
+            if prefix_cache is not None:
+                raise LatentCacheError(
+                    "prefix_cache",
+                    "a continuation over a cached prefix runs the "
+                    "absorbed form with many query tokens, which no "
+                    "entry has been cut for or checked against; "
+                    "serving/prefix_cache.py sizes its entries by keys "
+                    "and values per head")
+            if engine.topology.size("tp") > 1:
+                raise LatentCacheError(
+                    "tp > 1",
+                    "the latent is shared by all heads, so sharding the "
+                    "heads over tp leaves every device the whole cache "
+                    "and the decompression matrices have no sharding "
+                    "rule (models/transformer_lm.py gpt_tp_rules)")
         if prompt_bucket is None:
             prompt_bucket = self._ring[2] if self._ring is not None else 64
         if self._ring is not None and prompt_bucket % self._ring[2] != 0:
@@ -697,7 +750,7 @@ class ContinuousBatchingScheduler:
                     **{k: kv[k] for k in (
                         "kv_bytes_per_lane", "state_bytes_per_lane",
                         "conv_bytes_per_lane", "norm_bytes_per_lane",
-                        "bytes_per_lane")})
+                        "latent_bytes_per_lane", "bytes_per_lane")})
         de = self.draft_engine
         if de is None:
             return
@@ -861,6 +914,8 @@ class ContinuousBatchingScheduler:
         if self._rewind_fn is None:
             from collections.abc import Mapping
 
+            per_position = self._position_leaves
+
             def rewind(c0, c1, d):
                 def rewind_attn(a0, a1):
                     ci = a1["cache_index"]
@@ -869,7 +924,8 @@ class ContinuousBatchingScheduler:
                     if "slot_pos" in a1:
                         stale = a1["slot_pos"] >= idx_new[..., None]
                     else:
-                        s_len = a1["cached_key"].shape[-3]
+                        name, rank = next(iter(per_position.items()))
+                        s_len = a1[name].shape[1 - rank]
                         pos = jnp.arange(s_len, dtype=ci.dtype)
                         stale = pos >= idx_new[..., None]
                     out = {}
@@ -1081,7 +1137,10 @@ class ContinuousBatchingScheduler:
         ``conv_bytes``, and ``norm_bytes``: the part of ``state_bytes``
         that is a normaliser; ``kv_bytes`` is the rest; each also
         ``_per_lane``). A cache without keys and values has the clocks
-        alone in ``kv_bytes``."""
+        alone in ``kv_bytes``. Which leaves are per position is the
+        model's to say (``GPTConfig.position_leaves``); latent attention's
+        (a latent and a rotary key a position, no heads) are in
+        ``kv_bytes`` and, apart, ``latent_bytes_per_lane``."""
         from deepspeed_tpu.telemetry.memory import hbm_bytes
 
         out = dict(self._kv_geometry())
@@ -1109,8 +1168,14 @@ class ContinuousBatchingScheduler:
             apart = {"state": 0, "conv": 0, "norm": 0}
             recurrent = 0
 
+            # latent attention's leaves: part of ``kv_bytes``, also apart
+            from deepspeed_tpu.models.latent_attention import \
+                LATENT_LEAVES as latent_leaves
+
+            latent = 0
+
             def acc(path, sd):
-                nonlocal resident, unquant, recurrent
+                nonlocal resident, unquant, recurrent, latent
                 name = path[-1].key if hasattr(path[-1], "key") \
                     else path[-1]
                 nbytes = sd.size * jnp.dtype(sd.dtype).itemsize
@@ -1119,9 +1184,11 @@ class ContinuousBatchingScheduler:
                     recurrent += nbytes
                     for part in counted[name]:
                         apart[part] += nbytes
-                if name in ("cached_key", "cached_value"):
+                if name in self._position_leaves:
                     unquant += sd.size * compute_dt.itemsize
-                elif name in ("cached_key_scale", "cached_value_scale"):
+                    if name in latent_leaves:
+                        latent += nbytes
+                elif name.endswith("_scale"):
                     pass  # sideband of the int8 store; the twin has none
                 else:
                     unquant += nbytes
@@ -1142,6 +1209,7 @@ class ContinuousBatchingScheduler:
                 "conv_bytes_per_lane": int(apart["conv"] // self.slots),
                 "norm_bytes_per_lane": int(apart["norm"] // self.slots),
                 "kv_bytes_per_lane": int(kv_bytes // self.slots),
+                "latent_bytes_per_lane": int(latent // self.slots),
                 "lanes": self.slots,
                 "compression_ratio": (float(unquant) / float(resident)
                                       if resident else 1.0),
@@ -1197,6 +1265,8 @@ class ContinuousBatchingScheduler:
             "decode_steps": stats.decode_steps,
             "draining": self._draining,
         }
+        if self._clocks is not None:
+            payload["live_positions"] = self._clocks.live_positions(lanes)
         if self.prefix_cache is not None:
             payload["prefix_hit_rate"] = \
                 self.prefix_cache.stats().get("hit_rate", 0.0)
@@ -1247,6 +1317,7 @@ class ContinuousBatchingScheduler:
             if self.retain_lanes and unread:
                 self.lanes_at_exit = LanesAtExit(*unread[-1][1:])
                 self.lanes_at_exit.recurrent_leaves = self._recurrent
+                self.lanes_at_exit.position_leaves = self._position_leaves
 
     def _run(self, poll_fn, unread) -> ServingStats:
         self._ensure_compiled()

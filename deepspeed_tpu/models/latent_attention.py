@@ -1,0 +1,237 @@
+"""Multi-head latent attention (MLA) in place of attention as a block's
+token mixer, as DeepSeek-V2 has it (arXiv:2405.04434, section 2.1;
+``GPTConfig.mla``, a ``MLAConfig``). With ``x`` the block's normalised
+input:
+
+    c_q = RMSNorm(x W_qa)            q = c_q W_qb -> H x [q_nope | q_rope]
+    [c | r] = x W_kva                c_kv = RMSNorm(c)     k_rope = RoPE(r)
+    [k_nope_h | v_h] = c_kv W_kvb    (per head h)
+    score_h(t, s) = (q_nope_h(t) . k_nope_h(s)
+                     + RoPE(q_rope_h(t)) . k_rope(s)) * scale
+    o_h = sum_s softmax_s(score_h)(t, s) v_h(s)      y = concat_h(o_h) W_o
+
+``k_rope`` is ONE vector a token, shared by all heads; rotary is
+rotate-half over the rotary part's halves with YaRN's frequencies
+(ops/rotary.py); the softmax is float32.
+
+One layer, two forms of the same sum, chosen from what the call shows and
+by no option:
+
+* **per head** (training, and the pass that CREATES a lane's cache, a
+  prefill): ``k_nope`` and ``v`` of the tokens at hand are decompressed
+  and attended per head, causally;
+* **absorbed** (a decode call on a cache that exists, any number of query
+  tokens): ``W_kvb`` is split per head into ``W_UK_h`` and ``W_UV_h``
+  ``[kv_rank, d]`` and re-associated into the query and the output,
+
+      q_lat_h = q_nope_h W_UK_h^T
+      score_h(t, s) = (q_lat_h(t) . c_kv(s) + q_rope_h(t) . k_rope(s)) * scale
+      o_h = (sum_s p_h(t, s) c_kv(s)) W_UV_h
+
+  so that H query heads attend over ONE ``kv_rank + rope_dim``-wide key
+  and one ``kv_rank``-wide value a position: the cache is read as it lies
+  and never decompressed (decompressing costs ``2 kv_rank H (nope_dim +
+  v_dim)`` operations a cached position and layer at every step).
+
+The cache. A lane holds ``c_kv`` (after its norm) and ``k_rope`` (after
+rotary) per position and layer and nothing per head: ``cached_latent``
+``[layers, B, S, kv_rank]`` and ``cached_rope_key`` ``[layers, B, S,
+rope_dim]`` in the compute dtype, with ONE ``valid [B, S]`` and one clock
+``cache_index [B]`` a lane (positions are the lane's, not a layer's). The
+leaves belong to whoever runs the layers (``ScannedBlocks``, or ``GPT``
+with ``scan_layers=False``: :func:`open_lane_cache`) and cross them as a
+value that each layer updates in place at its own index, so the leading
+dense blocks and the scanned stack write layers of the same leaves.
+Left-padded prompts pass ``mask``; a pad's latent and rotary key are
+written and never read (``valid``).
+"""
+from typing import Any, NamedTuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from flax import struct
+
+from deepspeed_tpu.telemetry.scopes import (
+    SCOPE_KV_CACHE_READ,
+    SCOPE_KV_CACHE_WRITE,
+    SCOPE_MLA_ABSORB,
+    SCOPE_MLA_ATTN,
+    SCOPE_MLA_KV_PROJ,
+    SCOPE_MLA_OUT_PROJ,
+    SCOPE_MLA_Q_PROJ,
+)
+
+CACHED_LATENT = "cached_latent"
+CACHED_ROPE_KEY = "cached_rope_key"
+LATENT_LEAVES = (CACHED_LATENT, CACHED_ROPE_KEY)
+
+
+@struct.dataclass
+class Lane:
+    """A latent lane cache as the layers see it on one decode call."""
+    latent: Any      # [layers, B, S, kv_rank], this call's rows written
+    rope_key: Any    # [layers, B, S, rope_dim]  by each layer as it runs
+    valid: Any       # [B, S] bool, this call's tokens included
+    index: Any       # [B] each lane's clock BEFORE this call
+    # static: whether this call made the cache (every row a lane holds is
+    # among the tokens at hand) or found it
+    fresh: bool = struct.field(pytree_node=False)
+
+
+class LaneCache(NamedTuple):
+    lane: Lane
+    close: Any       # ``close(lane)``: the layers' writes back to the leaves
+
+
+def open_lane_cache(module, cfg, B: int, T: int, mask) -> LaneCache:
+    """Declare on ``module`` (inside its compact ``__call__``) the lane
+    cache of a latent-attention model, mark this call's ``T`` tokens per
+    lane valid where ``mask`` (or everywhere) says and advance the
+    clocks."""
+    m, S, L = cfg.mla, cfg.n_positions, cfg.n_layer
+    fresh = not module.has_variable("cache", CACHED_LATENT)
+    latent = module.variable("cache", CACHED_LATENT, jnp.zeros,
+                             (L, B, S, m.kv_rank), cfg.dtype)
+    rope_key = module.variable("cache", CACHED_ROPE_KEY, jnp.zeros,
+                               (L, B, S, m.rope_dim), cfg.dtype)
+    valid = module.variable("cache", "valid", jnp.zeros, (B, S), jnp.bool_)
+    index = module.variable("cache", "cache_index", jnp.zeros, (B,),
+                            jnp.int32)
+    idx = index.value
+    with jax.named_scope(SCOPE_KV_CACHE_WRITE):
+        pos = idx[:, None] + jnp.arange(T)[None, :]
+        now_valid = valid.value.at[jnp.arange(B)[:, None], pos].set(
+            mask.astype(jnp.bool_) if mask is not None
+            else jnp.ones((B, T), jnp.bool_), mode="drop")
+        valid.value = now_valid
+        index.value = idx + T
+
+    def close(lane):
+        latent.value, rope_key.value = lane.latent, lane.rope_key
+
+    return LaneCache(Lane(latent.value, rope_key.value, now_valid, idx,
+                          fresh), close)
+
+
+class _Kernel(nn.Module):
+    """A ``Dense``'s kernel under a ``Dense``'s name, for a projection
+    that is also used re-associated (``W_kvb``)."""
+    shape: tuple
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", nn.initializers.lecun_normal(),
+                          self.shape, self.param_dtype)
+
+
+class LatentAttention(nn.Module):
+    config: Any
+
+    @nn.compact
+    def __call__(self, x, *, mask=None, segment_ids=None, positions=None,
+                 lane=None, cache_layer=None):
+        """``lane`` None: no cache (training; the per-head form over the
+        ``T`` tokens). With a ``Lane`` (a decode call) returns ``(y,
+        lane)``, this layer's rows written at ``cache_layer``."""
+        cfg = self.config
+        m = cfg.mla
+        B, T, C = x.shape
+        H, dn, dr, dv, r = (cfg.n_head, m.nope_dim, m.rope_dim, m.v_dim,
+                            m.kv_rank)
+        if segment_ids is not None and lane is not None:
+            raise NotImplementedError(
+                "packed-sequence segment_ids are a training-path feature; "
+                "decode caches are per-sequence")
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
+                            param_dtype=cfg.param_dtype, name=name)
+
+        def norm(name):
+            return nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
+                              param_dtype=cfg.param_dtype, name=name)
+
+        def rope(t, pos):
+            # [B, T, heads, dr] in float32, rounded once
+            from deepspeed_tpu.ops.rotary import apply_rotary_pos_emb
+
+            out = apply_rotary_pos_emb(
+                t.astype(jnp.float32), pos, base=cfg.rope_theta,
+                inv_freq=m.inv_freq(cfg.rope_theta))
+            if m.rope_mscale != 1.0:
+                out = out * m.rope_mscale
+            return out.astype(cfg.dtype)
+
+        if lane is not None:
+            pos = lane.index[:, None] + jnp.arange(T)[None, :]      # [B, T]
+        else:
+            pos = positions if positions is not None \
+                else jnp.arange(T)[None, :]
+        with jax.named_scope(SCOPE_MLA_Q_PROJ):
+            q = dense(H * (dn + dr), "q_b")(
+                norm("q_a_norm")(dense(m.q_rank, "q_a")(x)))
+            q = q.reshape(B, T, H, dn + dr)
+            q_nope, q_rope = q[..., :dn], rope(q[..., dn:], pos)
+        with jax.named_scope(SCOPE_MLA_KV_PROJ):
+            ckr = dense(r + dr, "kv_a")(x)
+            c_kv = norm("kv_a_norm")(ckr[..., :r])                  # [B,T,r]
+            k_rope = rope(ckr[..., None, r:], pos)[:, :, 0]         # [B,T,dr]
+        w_kvb = _Kernel((r, H * (dn + dv)), cfg.param_dtype, name="kv_b")() \
+            .astype(cfg.dtype)
+        if lane is not None:
+            with jax.named_scope(SCOPE_KV_CACHE_WRITE):
+                at = (cache_layer, jnp.arange(B)[:, None], pos)
+                lane = lane.replace(
+                    latent=lane.latent.at[at].set(
+                        c_kv.astype(cfg.dtype), mode="drop"),
+                    rope_key=lane.rope_key.at[at].set(k_rope, mode="drop"))
+
+        if lane is None or lane.fresh:
+            # per head, over the tokens at hand
+            with jax.named_scope(SCOPE_MLA_KV_PROJ):
+                kv = jnp.dot(c_kv, w_kvb).reshape(B, T, H, dn + dv)
+                k_nope, v = kv[..., :dn], kv[..., dn:]
+            with jax.named_scope(SCOPE_MLA_ATTN):
+                att = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
+                                  preferred_element_type=jnp.float32)
+                       + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
+                                    preferred_element_type=jnp.float32)
+                       ) * m.softmax_scale
+                visible = jnp.tril(jnp.ones((T, T), bool))[None, None]
+                if mask is not None:
+                    visible = visible & mask.astype(bool)[:, None, None, :]
+                if segment_ids is not None:
+                    visible = visible & (segment_ids[:, None, :, None]
+                                         == segment_ids[:, None, None, :])
+                att = jnp.where(visible, att, jnp.finfo(jnp.float32).min)
+                att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
+                y = jnp.einsum("bhqk,bkhd->bqhd", att, v)
+        else:
+            # absorbed, over the latent where it lies
+            w = w_kvb.reshape(r, H, dn + dv)
+            with jax.named_scope(SCOPE_MLA_ABSORB):
+                q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, w[..., :dn])
+            with jax.named_scope(SCOPE_KV_CACHE_READ):
+                lat_all = jax.lax.dynamic_index_in_dim(
+                    lane.latent, cache_layer, 0, keepdims=False)   # [B,S,r]
+                rk_all = jax.lax.dynamic_index_in_dim(
+                    lane.rope_key, cache_layer, 0, keepdims=False)
+                visible = (jnp.arange(cfg.n_positions)[None, None, :]
+                           <= pos[:, :, None]) & lane.valid[:, None, :]
+            with jax.named_scope(SCOPE_MLA_ATTN):
+                att = (jnp.einsum("bqhr,bkr->bhqk", q_lat, lat_all,
+                                  preferred_element_type=jnp.float32)
+                       + jnp.einsum("bqhd,bkd->bhqk", q_rope, rk_all,
+                                    preferred_element_type=jnp.float32)
+                       ) * m.softmax_scale
+                att = jnp.where(visible[:, None], att,
+                                jnp.finfo(jnp.float32).min)
+                att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
+                o_lat = jnp.einsum("bhqk,bkr->bqhr", att, lat_all)
+            with jax.named_scope(SCOPE_MLA_ABSORB):
+                y = jnp.einsum("bqhr,rhd->bqhd", o_lat, w[..., dn:])
+        with jax.named_scope(SCOPE_MLA_OUT_PROJ):
+            y = dense(C, "c_proj")(y.reshape(B, T, H * dv))
+        return y if lane is None else (y, lane)

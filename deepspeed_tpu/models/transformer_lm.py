@@ -142,6 +142,84 @@ class RetentionConfig:
                               slice_is_whole=False))
 
 
+# ``GPTConfig.position_leaves`` of attention with keys and values per head
+KV_LEAVES = (("cached_key", 4), ("cached_value", 4))
+
+
+class LatentCacheError(ValueError):
+    """A feature that assumes keys and values per head was asked of a
+    model whose cache is latent attention's (``GPTConfig.mla``): one
+    compressed latent and one rotary key a position."""
+
+    def __init__(self, feature: str, why: str):
+        super().__init__(
+            f"{feature} cannot serve a model with a latent cache "
+            f"(cached_latent, cached_rope_key: GPTConfig.mla): {why}")
+        self.feature = feature
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention IN PLACE OF ``CausalSelfAttention`` as a
+    block's token mixer (models/latent_attention.py; DeepSeek-V2,
+    arXiv:2405.04434): queries and keys/values come through low-rank
+    projections, a token's keys and values of ALL heads are decompressed
+    from one ``kv_rank``-wide latent, and position enters through a
+    decoupled ``rope_dim``-wide rotary part, whose key is one vector a
+    token, shared by the heads. A lane's cache holds that latent (after its
+    norm) and that rotary key (after rotary) per position and layer,
+    ``kv_rank + rope_dim`` values, and nothing per head. The heads, the
+    norm's epsilon and ``rope_theta`` are the model's own. One form, the
+    published one: a norm on both latents, no bias."""
+    q_rank: int             # q_lora_rank
+    kv_rank: int            # kv_lora_rank
+    nope_dim: int           # qk_nope_head_dim
+    rope_dim: int           # qk_rope_head_dim
+    v_dim: int              # v_head_dim
+    # YaRN on the rotary part (ops/rotary.py); factor 1 = plain rotary.
+    # ``yarn_mscale_all_dim`` enters the softmax scale squared, the ratio
+    # of the two mscales multiplies cos and sin
+    yarn_factor: float = 1.0
+    yarn_original_positions: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
+    def __post_init__(self):
+        if self.rope_dim % 2:
+            raise ValueError(f"rope_dim must be even; got {self.rope_dim}")
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        from deepspeed_tpu.ops.rotary import yarn_mscale
+
+        m = yarn_mscale(self.yarn_factor, self.yarn_mscale_all_dim)
+        return self.qk_dim ** -0.5 * m * m
+
+    @property
+    def rope_mscale(self) -> float:
+        from deepspeed_tpu.ops.rotary import yarn_mscale
+
+        return yarn_mscale(self.yarn_factor, self.yarn_mscale) \
+            / yarn_mscale(self.yarn_factor, self.yarn_mscale_all_dim)
+
+    def inv_freq(self, theta: float):
+        """The rotary part's inverse frequencies, None for the plain
+        ladder."""
+        from deepspeed_tpu.ops.rotary import yarn_inv_freq
+
+        if self.yarn_factor <= 1:
+            return None
+        return yarn_inv_freq(self.rope_dim, theta, self.yarn_factor,
+                             self.yarn_original_positions,
+                             self.yarn_beta_fast, self.yarn_beta_slow)
+
+
 @dataclasses.dataclass(frozen=True)
 class GPTConfig:
     vocab_size: int = 50257
@@ -296,6 +374,24 @@ class GPTConfig:
     moe_norm_topk_prob: bool = False
     # the coefficient of the router z-loss, beside moe_aux_loss_coef
     moe_z_loss_coef: float = 0.0
+    # the dropless path, as DeepSeek-V2's expert layer has it (moe/layer.py
+    # says what each does): the experts' width where it is not the dense
+    # MLP's; shared experts beside the routed ones; the choice limited to
+    # the best ``moe_topk_group`` of ``moe_n_group`` consecutive groups; a
+    # factor on the weights; and which of the ``moe_num_experts`` that the
+    # router scores this program HOLDS, ``(first, count)``: one device's
+    # share of an expert-parallel layer, whose routed part it computes
+    # alone (None = all)
+    moe_intermediate_size: Optional[int] = None
+    moe_n_shared: int = 0
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_routed_scale: float = 1.0
+    moe_experts_held: Optional[Tuple[int, int]] = None
+    # the first ``first_k_dense`` blocks hold a dense MLP (``ffn_dim``)
+    # whatever ``moe_num_experts`` says; under ``scan_layers`` they run
+    # before the scanned stack of the rest
+    first_k_dense: int = 0
     # --- hybrid blocks (Falcon-H1) -----------------------------------------
     # a Mamba-2 mixer beside attention in every block, both on ln_1's
     # output, summed into one residual; None = attention alone. Its
@@ -306,6 +402,11 @@ class GPTConfig:
     # power retention in place of attention as the token mixer; None =
     # attention. A lane's cache is then the retention's state alone
     retention: Optional[RetentionConfig] = None
+    # --- latent attention (DeepSeek-V2) --------------------------------------
+    # multi-head latent attention in place of attention; None = attention.
+    # A lane's cache is then one compressed latent and one rotary key a
+    # position and layer, nothing per head
+    mla: Optional[MLAConfig] = None
     # attention head size when it is not n_embd // n_head
     attn_head_dim: Optional[int] = None
     # muP multipliers, each applied where the published model applies it;
@@ -388,6 +489,54 @@ class GPTConfig:
                 "a retention block is causal, takes its positions from "
                 "rotary alone and has no second mixer")
 
+        if self.mla is not None:
+            if (not self.rotary or self.learned_positions or self.alibi
+                    or self.sparse_attention is not None or not self.causal
+                    or self.retention is not None or self.ssm is not None
+                    or self.sequence_parallel != "none"):
+                raise ValueError(
+                    "a latent-attention block is causal, takes its "
+                    "positions from its rotary part alone and has no "
+                    "second mixer")
+            if self.kv_cache_dtype is not None:
+                raise LatentCacheError(
+                    f"kv_cache_dtype={self.kv_cache_dtype!r}",
+                    "the int8 format keeps one scale per (position, KV "
+                    "head) over a head's values, and a latent has no "
+                    "heads: the latent is the model's own compression of "
+                    "the cache, and a format for it would be another")
+        if not 0 <= self.first_k_dense <= self.n_layer:
+            raise ValueError(
+                f"first_k_dense ({self.first_k_dense}) must lie in 0.."
+                f"n_layer ({self.n_layer})")
+        if self.moe_experts_held is not None:
+            first, count = self.moe_experts_held
+            if not (0 <= first and count >= 1
+                    and first + count <= self.moe_num_experts):
+                raise ValueError(
+                    f"moe_experts_held {self.moe_experts_held} is no run "
+                    f"of the {self.moe_num_experts} experts")
+
+    @property
+    def position_leaves(self) -> Tuple[Tuple[str, int], ...]:
+        """``(name, rank of one layer's [B, S, ...] leaf)`` of the leaves
+        of the decode cache that hold something PER POSITION, the kind a
+        cache can be cut at a prefix of: keys and values per head, or
+        latent attention's latent and rotary key; none for a model whose
+        mixer keeps a state alone. The one place that knows; the
+        scheduler's rewind and byte accounting and the disaggregated
+        hand-off read it."""
+        if self.mla is not None:
+            from deepspeed_tpu.models.latent_attention import (
+                CACHED_LATENT,
+                CACHED_ROPE_KEY,
+            )
+
+            return ((CACHED_LATENT, 3), (CACHED_ROPE_KEY, 3))
+        if self.retention is not None:
+            return ()
+        return KV_LEAVES
+
     @property
     def recurrent_leaves(self) -> Tuple[RecurrentLeaf, ...]:
         """The leaves of the decode cache that hold recurrent state, as
@@ -400,8 +549,9 @@ class GPTConfig:
 
     @property
     def has_kv_cache(self) -> bool:
-        """Whether a lane's cache holds keys and values at all."""
-        return self.retention is None
+        """Whether a lane's cache holds anything per position at all (keys
+        and values, or latents)."""
+        return bool(self.position_leaves)
 
     @property
     def head_dim(self) -> int:
@@ -423,6 +573,10 @@ class GPTConfig:
     @property
     def is_moe(self) -> bool:
         return self.moe_num_experts > 0
+
+    @property
+    def moe_ffn_dim(self) -> int:
+        return self.moe_intermediate_size or self.ffn_dim
 
 
 # GPT-2 sizes (reference benchmarks target 125M / 1.3B; BASELINE.md configs 2-5)
@@ -600,15 +754,17 @@ def decode_attention_block(cfg, T: int = 1):
     continuation, speculative verification), the ring cache of a window
     layout, int8 storage (dequantised whole on read) and ALiBi (a bias on
     every position) stay on the einsums, and so do heads sharded over
-    ``tp``: GSPMD cannot partition a Mosaic call. The scheduler asks the
-    same question for its counter (``kv_blocks_read_share``)."""
+    ``tp``: GSPMD cannot partition a Mosaic call; so does latent attention,
+    whose absorbed einsums read the latent of every position (the kernel
+    walks keys and values per head). The scheduler asks the same question
+    for its counter (``kv_blocks_read_share``)."""
     from deepspeed_tpu.ops.pallas.decode_attention import block_positions
     from deepspeed_tpu.ops.sparse_attention.sparse_attention_utils import \
         ring_engaged
     from deepspeed_tpu.parallel.mesh import get_default_topology
 
     if (T != 1 or cfg.kv_cache_dtype == "int8" or cfg.alibi
-            or ring_engaged(cfg) is not None
+            or cfg.mla is not None or ring_engaged(cfg) is not None
             or get_default_topology().size("tp") > 1):
         return None
     return block_positions(cfg.n_positions, cfg.kv_heads, cfg.head_dim,
@@ -1037,11 +1193,18 @@ class Block(nn.Module):
     their coefficients, 0 for the dense path."""
 
     config: GPTConfig
+    # one of the leading ``first_k_dense`` blocks: a dense MLP whatever
+    # the configuration's experts
+    dense_mlp: bool = False
 
     @nn.compact
     def __call__(self, x, *, mask=None, segment_ids=None, positions=None,
                  deterministic=True, decode=False, pld_keep=None,
-                 cache_layer=None):
+                 cache_layer=None, lane=None):
+        """``lane`` is a latent-attention model's lane cache on a decode
+        call (models/latent_attention.py ``LaneCache``: its owner carries
+        it through the layers as a value), and the block then returns
+        ``(x, l_aux, lane)``."""
         cfg = self.config
         x_in = x
         u = _norm(cfg, "ln_1")(x)
@@ -1049,7 +1212,15 @@ class Block(nn.Module):
             raise NotImplementedError(
                 "packed-sequence segment_ids with a recurrent mixer: "
                 "the state would run across documents")
-        if cfg.retention is not None:
+        if cfg.mla is not None:
+            from deepspeed_tpu.models.latent_attention import LatentAttention
+
+            a = LatentAttention(cfg, name="attn")(
+                u, mask=mask, segment_ids=segment_ids, positions=positions,
+                lane=lane, cache_layer=cache_layer)
+            if lane is not None:
+                a, lane = a
+        elif cfg.retention is not None:
             # the token mixer is retention, not attention: no KV cache
             from deepspeed_tpu.models.power_retention import PowerRetention
 
@@ -1077,12 +1248,12 @@ class Block(nn.Module):
         else:
             x = x + a
             h = _norm(cfg, "ln_2")(x)
-        if cfg.is_moe:
+        if cfg.is_moe and not self.dense_mlp:
             from deepspeed_tpu.moe.layer import MoE
 
             y, l_aux, l_z, _ = MoE(
                 d_model=cfg.n_embd,
-                d_hidden=cfg.ffn_dim,
+                d_hidden=cfg.moe_ffn_dim,
                 num_experts=cfg.moe_num_experts,
                 k=cfg.moe_top_k,
                 capacity_factor=cfg.moe_capacity_factor,
@@ -1093,6 +1264,11 @@ class Block(nn.Module):
                 use_rts=cfg.moe_use_rts,
                 gated_experts=cfg.moe_gated_experts,
                 norm_topk_prob=cfg.moe_norm_topk_prob,
+                n_shared=cfg.moe_n_shared,
+                n_group=cfg.moe_n_group,
+                topk_group=cfg.moe_topk_group,
+                routed_scale=cfg.moe_routed_scale,
+                experts_held=cfg.moe_experts_held,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 name="mlp",
@@ -1112,6 +1288,8 @@ class Block(nn.Module):
             gate = jax.random.bernoulli(self.make_rng("dropout"), pld_keep)
             x = jnp.where(gate, x, x_in)
             l_aux = jnp.where(gate, l_aux, jnp.zeros_like(l_aux))
+        if lane is not None:
+            return x, l_aux, lane
         return x, l_aux
 
 
@@ -1285,6 +1463,34 @@ class ScannedBlocks(nn.Module):
         cfg = self.config
         use_pld = (cfg.stochastic_mode and pld_theta is not None
                    and not deterministic)
+        # A latent-attention model's lane cache is this module's own and
+        # crosses the layers as a value (models/latent_attention.py), so
+        # that the leading dense blocks and the scanned stack write their
+        # layers of the same stacked leaves
+        lane, lane_cache = None, None
+        if cfg.mla is not None and decode:
+            from deepspeed_tpu.models.latent_attention import open_lane_cache
+
+            lane_cache = open_lane_cache(self, cfg, x.shape[0], x.shape[1],
+                                         mask)
+            lane = lane_cache.lane
+        # the leading dense blocks, each a module of its own before the
+        # scanned stack of the rest
+        dense_aux = jnp.float32(0.0)
+        for i in range(cfg.first_k_dense):
+            out = _maybe_gathered_block(
+                _maybe_quantized_block(Block, cfg), cfg,
+                self.path + (f"dense_{i}",))(
+                    cfg, dense_mlp=True, name=f"dense_{i}")(
+                x, mask=mask, segment_ids=segment_ids, positions=positions,
+                deterministic=deterministic, decode=decode,
+                cache_layer=i if lane is not None else None, lane=lane)
+            if lane is not None:
+                x, aux_i, lane = out
+            else:
+                x, aux_i = out
+            dense_aux = dense_aux + aux_i
+        n_scanned = cfg.n_layer - cfg.first_k_dense
         # A decode call on a cache that exists carries it through the loop
         # as ONE stacked [n_layer, ...] buffer per leaf, which each turn
         # updates in place at its own layer index (CausalSelfAttention's
@@ -1299,11 +1505,15 @@ class ScannedBlocks(nn.Module):
         # loop and then carried (same entry of PERF.md).
         carried = decode and self.has_variable("cache", "block")
 
-        def call_block(block, x, mask, segment_ids, positions, layer_idx):
+        def call_block(block, x, mask, segment_ids, positions, layer_idx,
+                       lane=None):
             # deterministic/decode ride the closure so remat never sees
             # them as traced booleans
             pld_keep = (pld_keep_probability(layer_idx, cfg.n_layer,
                                              pld_theta) if use_pld else None)
+            if lane is not None:
+                return block(x, mask=mask, deterministic=deterministic,
+                             decode=decode, cache_layer=layer_idx, lane=lane)
             return block(x, mask=mask, segment_ids=segment_ids,
                          positions=positions, deterministic=deterministic,
                          decode=decode, pld_keep=pld_keep,
@@ -1315,7 +1525,11 @@ class ScannedBlocks(nn.Module):
 
         def body(block, carry, layer_idx):
             # None entries are valid (empty) pytree leaves in the carry
-            x, mask, segment_ids, positions = carry
+            x, mask, segment_ids, positions = carry[:4]
+            if len(carry) == 5:
+                x, l_aux, lane = call_block(block, x, mask, segment_ids,
+                                            positions, layer_idx, carry[4])
+                return (x, mask, segment_ids, positions, lane), l_aux
             x, l_aux = call_block(block, x, mask, segment_ids, positions,
                                   layer_idx)
             return (x, mask, segment_ids, positions), l_aux
@@ -1324,7 +1538,7 @@ class ScannedBlocks(nn.Module):
         # fsdp, dequantised
         block_cls = _maybe_gathered_block(
             _maybe_quantized_block(Block, cfg), cfg, self.path + ("block",),
-            stacked=cfg.n_layer)
+            stacked=n_scanned)
         if cfg.param_offload:
             # ZeRO-Infinity param tier: the scan's per-iteration slice of
             # the (host-resident) layer stack is copied into HBM right
@@ -1344,13 +1558,20 @@ class ScannedBlocks(nn.Module):
             variable_carry="cache" if carried else False,
             split_rngs={"params": True, "dropout": True, "gating": True},
             in_axes=0,
-            length=cfg.n_layer,
+            length=n_scanned,
             metadata_params={nn.PARTITION_NAME: "layers"},
         )
-        (x, _, _, _), l_aux = scanned(
-            block_cls(cfg, name="block"),
-            (x, mask, segment_ids, positions), jnp.arange(cfg.n_layer))
-        return x, jnp.sum(l_aux)
+        carry = (x, mask, segment_ids, positions)
+        if lane is not None:
+            carry += (lane,)
+        carry, l_aux = scanned(
+            block_cls(cfg, name="block"), carry,
+            jnp.arange(cfg.first_k_dense, cfg.n_layer))
+        if lane is not None:
+            lane_cache.close(carry[4])
+        if cfg.first_k_dense:
+            return carry[0], jnp.sum(l_aux) + dense_aux
+        return carry[0], jnp.sum(l_aux)
 
 
 def gpt_tp_rules(path: str, shape) -> "PartitionSpec":
@@ -1466,15 +1687,32 @@ class GPT(nn.Module):
                 call_block = nn.remat(call_block, prevent_cse=False,
                                       policy=_remat_policy(cfg.remat_policy))
             loop_block_cls = _maybe_quantized_block(Block, cfg)
+            # a latent-attention model's lane cache is the model's own
+            # here, the same stacked leaves as under ``ScannedBlocks``
+            lane_cache = None
+            if cfg.mla is not None and decode:
+                from deepspeed_tpu.models.latent_attention import \
+                    open_lane_cache
+
+                lane_cache = open_lane_cache(self, cfg, B, T, attention_mask)
+                lane = lane_cache.lane
             for i in range(cfg.n_layer):
                 keep = (pld_keep_probability(i, cfg.n_layer, pld_theta)
                         if use_pld else None)
                 block_cls = _maybe_gathered_block(
                     loop_block_cls, cfg, self.path + (f"h_{i}",))
-                x, aux_i = call_block(block_cls(cfg, name=f"h_{i}"), x,
-                                      attention_mask, segment_ids, positions,
-                                      keep)
+                block = block_cls(cfg, dense_mlp=i < cfg.first_k_dense,
+                                  name=f"h_{i}")
+                if lane_cache is not None:
+                    x, aux_i, lane = block(
+                        x, mask=attention_mask, deterministic=deterministic,
+                        decode=True, cache_layer=i, lane=lane)
+                else:
+                    x, aux_i = call_block(block, x, attention_mask,
+                                          segment_ids, positions, keep)
                 l_aux = l_aux + aux_i
+            if lane_cache is not None:
+                lane_cache.close(lane)
 
         if decode and labels is None and cfg.num_logits_to_keep:
             # the published ``num_logits_to_keep``: a prefill needs the

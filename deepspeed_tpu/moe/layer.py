@@ -30,11 +30,24 @@ bf16 or float32 and no ``ep`` or ``tp`` axis spans the expert tensors
 (``moe/experts.py`` ``grouped_matmul_tiles`` decides from what the call
 shows; no option), and ``jax.lax.ragged_dot`` otherwise: on an ``ep`` mesh
 the expert tensors keep their ``ep`` sharding and the compiler partitions
-its own call; the sorted rows are not annotated yet (no dropless
-configuration runs expert-parallel).
+its own call; the sorted rows are not annotated for it.
+
+One share of an expert-parallel dropless layer runs on its own
+(``experts_held = (first, count)``): the router scores ALL
+``num_experts``, the layer holds the matrices of ``count`` of them, sorts
+the pairs routed to the others past its last group, so that the grouped
+matmuls' ``group_sizes`` cover the held experts alone, and returns its
+part of the layer's output, the weighted sum over the pairs routed to
+experts it holds. The shares of all devices summed are the layer's routed
+output. The exchange that would bring other devices' rows here and take
+the parts back is not built: a share serves the tokens it is given. The
+choice can be limited to the best groups of experts (``n_group``,
+``topk_group``: DeepSeek-V2's device-limited routing, one group a device),
+the weights scaled (``routed_scale``), and ``n_shared`` shared experts, one
+SwiGLU of their summed width, run on every token beside the routed ones.
 """
 
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -58,6 +71,7 @@ from deepspeed_tpu.telemetry.scopes import (
     SCOPE_MOE_DISPATCH,
     SCOPE_MOE_EXPERTS,
     SCOPE_MOE_ROUTER,
+    SCOPE_MOE_SHARED,
 )
 
 # the collection the layer sows its routing counts into; nothing is written
@@ -76,6 +90,28 @@ def _ep_constraint(x, ndim_spec):
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(topo.mesh, PartitionSpec(*ndim_spec))
     )
+
+
+class SharedExperts(nn.Module):
+    """The experts every token passes through beside its routed ones, as
+    one gated FFN of their summed width (DeepSeek-V2's ``shared_experts``:
+    ``down(act(gate x) * up x)``, no bias)."""
+
+    d_model: int
+    width: int
+    activation: Any
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype,
+                            param_dtype=self.param_dtype, name=name)
+
+        h = self.activation(dense(self.width, "c_gate")(x)) \
+            * dense(self.width, "c_fc")(x)
+        return dense(self.d_model, "c_proj")(h)
 
 
 class MoE(nn.Module):
@@ -98,16 +134,32 @@ class MoE(nn.Module):
     expert_activation: Any = None    # defaults: gelu, or silu when gated
     # dropless path only: divide the k weights by their sum
     norm_topk_prob: bool = False
+    # dropless path only (the module's docstring): shared experts on every
+    # token; the choice limited to the best ``topk_group`` of ``n_group``
+    # consecutive groups; a factor on the weights; the ``(first, count)``
+    # of the ``num_experts`` whose matrices this layer holds (None = all)
+    n_shared: int = 0
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0
+    experts_held: Optional[Tuple[int, int]] = None
 
     @property
     def dropless(self) -> bool:
         return self.k > 2 or not self.drop_tokens
 
-    def _experts(self):
-        act = self.expert_activation or (
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.num_experts)
+
+    def _activation(self):
+        return self.expert_activation or (
             nn.silu if self.gated_experts else nn.gelu)
+
+    def _experts(self):
+        act = self._activation()
         return StackedExperts(
-            num_experts=self.num_experts,
+            num_experts=self.held[1],
             d_model=self.d_model,
             d_hidden=self.d_hidden,
             dtype=self.dtype,
@@ -124,9 +176,11 @@ class MoE(nn.Module):
         [experts], those whose expert output exists, counted from the
         dispatch (one-hot path) or the grouped matmuls' output (dropless)
         and not from the routing; on the dropless path ``chosen`` [tokens,
-        k] and ``gmm_tiles`` [3], the grouped-matmul kernel's ``(tm, tk,
+        k], ``gmm_tiles`` [3], the grouped-matmul kernel's ``(tm, tk,
         tn)`` as this trace chose them, zeros where it chose
-        ``ragged_dot``. (``init`` makes every collection mutable and would
+        ``ragged_dot``, ``held``, how many experts' matrices the layer
+        holds (``computed`` is of those), and ``routed_here``, the pairs
+        routed to them. (``init`` makes every collection mutable and would
         return them beside the parameters.)"""
         if not self.is_initializing():
             for name, value in counters.items():
@@ -155,24 +209,50 @@ class MoE(nn.Module):
                     "the dropless path routes deterministically; "
                     f"noisy_gate_policy={self.noisy_gate_policy!r} exists "
                     "only with a capacity (k <= 2, drop_tokens=True)")
+            first, held = self.held
             with jax.named_scope(SCOPE_MOE_ROUTER):
                 route = topk_routing(gate(tokens.astype(jnp.float32)),
-                                     self.k, self.norm_topk_prob)
+                                     self.k, self.norm_topk_prob,
+                                     self.n_group, self.topk_group,
+                                     self.routed_scale)
+                groups, weights, sizes = (route.experts, route.weights,
+                                          route.exp_counts)
+                routed_here = routed
+                if self.experts_held is not None:
+                    # a pair routed to an expert held elsewhere sorts past
+                    # the last held group and weighs nothing here
+                    here = (groups >= first) & (groups < first + held)
+                    groups = jnp.where(here, groups - first, held)
+                    weights = jnp.where(here, weights, 0.0)
+                    sizes = jax.lax.dynamic_slice_in_dim(sizes, first, held)
+                    routed_here = jnp.sum(here, dtype=jnp.int32)
             with jax.named_scope(SCOPE_MOE_DISPATCH):
-                order, inverse = sort_by_expert(route.experts)
+                order, inverse = sort_by_expert(groups)
                 rows = dispatch_rows(tokens, order, inverse, self.k)
+            tiles = grouped_matmul_tiles(rows.shape[0], d_model,
+                                         self.d_hidden, held, self.dtype)
             with jax.named_scope(SCOPE_MOE_EXPERTS):
-                rows = self._experts()(rows, route.exp_counts)
+                rows = self._experts()(rows, sizes)
+                if self.experts_held is not None and not tiles:
+                    # the kernel writes zeros past ``sum(group_sizes)``;
+                    # ``ragged_dot`` leaves those rows unwritten on the TPU
+                    rows = jnp.where(
+                        jnp.arange(rows.shape[0])[:, None] < jnp.sum(sizes),
+                        rows, jnp.zeros((), rows.dtype))
             with jax.named_scope(SCOPE_MOE_COMBINE):
-                y = combine_rows(rows, route.weights, order, inverse,
+                y = combine_rows(rows, weights, order, inverse,
                                  dtype=x.dtype)
+            if self.n_shared:
+                with jax.named_scope(SCOPE_MOE_SHARED):
+                    y = y + SharedExperts(
+                        d_model, self.n_shared * self.d_hidden,
+                        self._activation(), self.dtype, self.param_dtype,
+                        name="shared")(tokens).astype(y.dtype)
             self._count(routed=routed, chosen=route.experts,
-                        computed=rows_computed(rows, route.experts, order,
-                                               self.num_experts),
-                        gmm_tiles=jnp.asarray(grouped_matmul_tiles(
-                            rows.shape[0], d_model, self.d_hidden,
-                            self.num_experts, self.dtype) or (0, 0, 0),
-                            jnp.int32))
+                        computed=rows_computed(rows, groups, order,
+                                               held + 1)[:held],
+                        gmm_tiles=jnp.asarray(tiles or (0, 0, 0), jnp.int32),
+                        held=jnp.int32(held), routed_here=routed_here)
             return (y.reshape(orig_shape), route.l_aux, route.l_z,
                     route.exp_counts)
 

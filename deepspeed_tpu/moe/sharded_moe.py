@@ -245,12 +245,23 @@ def router_z_loss(logits: jnp.ndarray) -> jnp.ndarray:
         jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)))
 
 
-def topk_routing(logits: jnp.ndarray, k: int,
-                 renormalize: bool = False) -> RoutingOutput:
+def topk_routing(logits: jnp.ndarray, k: int, renormalize: bool = False,
+                 n_group: int = 1, topk_group: int = 1,
+                 scale: float = 1.0) -> RoutingOutput:
     """Softmax over all experts, then the k largest probabilities per token
     (any k). ``renormalize`` divides the k weights by their sum
     (``norm_topk_prob``); without it they are the softmax's own values and
-    sum to less than one.
+    sum to less than one. ``scale`` multiplies them last
+    (``routed_scaling_factor``).
+
+    With ``n_group > 1`` the choice is group-limited (DeepSeek-V2's
+    ``group_limited_greedy``, its device-limited routing): the experts are
+    ``n_group`` consecutive runs, a group's score is its largest
+    probability, and only the experts of the ``topk_group`` best groups
+    can be chosen (the others' probabilities count as 0 in the top-k; the
+    chosen weights are still the softmax's over all experts). Ties go to
+    the lower index, in the groups and in the experts (``lax.top_k``'s
+    rule).
 
     ``l_aux`` is the load-balancing loss ``E * sum_i f_i * P_i`` with
     ``f_i`` the share of the tokens * k pairs routed to expert i and ``P_i``
@@ -261,11 +272,22 @@ def topk_routing(logits: jnp.ndarray, k: int,
     logits = logits.astype(jnp.float32)
     num_tokens, num_experts = logits.shape
     probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
+    eligible = probs
+    if n_group > 1:
+        per_group = num_experts // n_group
+        _, best = jax.lax.top_k(
+            probs.reshape(num_tokens, n_group, per_group).max(-1),
+            topk_group)
+        keep = jnp.sum(jax.nn.one_hot(best, n_group, dtype=jnp.int32),
+                       axis=1) > 0                       # [tokens, groups]
+        eligible = jnp.where(jnp.repeat(keep, per_group, axis=1), probs, 0.0)
+    weights, experts = jax.lax.top_k(eligible, k)
     if renormalize:
         weights = weights / jnp.maximum(
             jnp.sum(weights, axis=-1, keepdims=True),
             jnp.finfo(jnp.float32).eps)
+    if scale != 1.0:
+        weights = weights * scale
     exp_counts = jnp.bincount(experts.reshape(-1), length=num_experts)
     f = exp_counts.astype(jnp.float32) / (num_tokens * k)
     l_aux = num_experts * jnp.sum(f * jnp.mean(probs, axis=0))

@@ -34,7 +34,8 @@ def split_moe_params(params) -> Tuple[Any, Any]:
 
 # rank of one layer's value of each counter the MoE layer sows; whatever
 # leads it (a scan's layer axis, none for a layer on its own) is flattened
-_COUNTER_RANK = {"routed": 0, "computed": 1, "chosen": 2, "gmm_tiles": 1}
+_COUNTER_RANK = {"routed": 0, "computed": 1, "chosen": 2, "gmm_tiles": 1,
+                 "held": 0, "routed_here": 0}
 
 
 def routing_stats(model, params, batch):
@@ -45,8 +46,10 @@ def routing_stats(model, params, batch):
     dispatch or the experts' output) and, on the dropless path, ``chosen``
     [layers, tokens, k] (each token's chosen experts) and ``gmm_tiles``
     [layers, 3] (the grouped-matmul kernel's tiles, zeros where the layer
-    traced ``ragged_dot``). One forward program of its own, off the
-    step."""
+    traced ``ragged_dot``), ``held`` [layers] (the experts whose matrices
+    the layer holds: ``computed`` is of those) and ``routed_here``
+    [layers] (the pairs routed to them). One forward program of its own,
+    off the step."""
     import numpy as np
     from flax.traverse_util import flatten_dict
 
@@ -74,7 +77,11 @@ def publish_expert_load(model, params, batch):
     ``max_over_mean`` (the fullest expert's load over the mean load, the
     largest over the layers; 1 is balanced) and ``tokens_dropped`` (pairs
     the routers asked for less pairs computed, over all layers; the
-    dropless path computes every pair). Of the dropless path's grouped
+    dropless path computes every pair). A layer that holds a share of its
+    experts (``MoE.experts_held``) counts the held ones alone: ``held``
+    (how many a layer), ``routed_here`` (the pairs routed to them, all
+    layers; ``tokens_dropped`` is then of those) and ``routed`` (all the
+    routers asked for). Of the dropless path's grouped
     matmuls it says which implementation the layers traced,
     ``grouped_matmul`` (``"pallas"``: the kernel of
     ``ops/pallas/grouped_matmul.py``; ``"xla"``: ``jax.lax.ragged_dot``;
@@ -99,9 +106,12 @@ def publish_expert_load(model, params, batch):
         over_least = max(
             row_tile_visits(c, int(rows), tiles[0]) / (int(rows) / tiles[0])
             for c, rows in zip(counts, stats["routed"]))
+    here = stats.get("routed_here", stats["routed"])
     return publish(
         "moe.load", tokens_per_expert=counts.tolist(),
         max_over_mean=float((counts.max(axis=1) / counts.mean(axis=1)).max()),
-        tokens_dropped=int(stats["routed"].sum() - counts.sum()),
+        tokens_dropped=int(here.sum() - counts.sum()),
+        held=int(stats["held"][0]) if "held" in stats else counts.shape[1],
+        routed=int(stats["routed"].sum()), routed_here=int(here.sum()),
         grouped_matmul=path, grouped_matmul_tiles=tiles,
         row_tile_visits_over_least=over_least)

@@ -113,6 +113,10 @@ def lane_kv_bytes(model, slots: int = 1) -> Dict[str, int]:
 
     shapes = jax.eval_shape(shape_fn, pshapes)
     compute_dt = jnp.dtype(getattr(mcfg, "dtype", jnp.float32))
+    # which leaves are per position is the model's to say
+    from deepspeed_tpu.models.transformer_lm import KV_LEAVES
+
+    per_position = dict(getattr(mcfg, "position_leaves", KV_LEAVES))
     resident = 0
     unquant = 0
 
@@ -121,9 +125,9 @@ def lane_kv_bytes(model, slots: int = 1) -> Dict[str, int]:
         name = path[-1].key if hasattr(path[-1], "key") else path[-1]
         nbytes = sd.size * jnp.dtype(sd.dtype).itemsize
         resident += nbytes
-        if name in ("cached_key", "cached_value"):
+        if name in per_position:
             unquant += sd.size * compute_dt.itemsize
-        elif name in ("cached_key_scale", "cached_value_scale"):
+        elif name.endswith("_scale"):
             pass  # sideband of the int8 store; the unquantized twin has none
         else:
             unquant += nbytes

@@ -48,6 +48,8 @@ SCOPE_MOE_ROUTER = "moe_router"
 SCOPE_MOE_DISPATCH = "moe_dispatch"
 SCOPE_MOE_EXPERTS = "moe_experts"
 SCOPE_MOE_COMBINE = "moe_combine"
+# the shared experts that every token passes through beside its routed ones
+SCOPE_MOE_SHARED = "moe_shared"
 # ZeRO-3 (runtime/zero/gather.py): a layer's weights cast and gathered where
 # the layer reads them, and their cotangents reduce-scattered back
 SCOPE_ZERO3_GATHER = "zero3_gather"
@@ -85,6 +87,19 @@ SCOPE_RET_OUT_PROJ = "ret_out_proj"
 # a whole retention-state or normaliser leaf that no scope owns: the
 # mixer's own update of its layer's slice is ``ret_state``'s, in place
 SCOPE_RET_STATE_CARRY = "ret_state_carry"
+# latent attention (models/latent_attention.py): the queries' low-rank
+# projections, norm and rotary; the latent's projection, norm, the rotary
+# key and, where a pass decompresses them, the per-head keys and values;
+# the absorbed form's products with the decompression matrices (the query
+# into the latent space, the output out of it); scores, softmax and the
+# weighted sum, per head or over the latent; the output projection. A whole
+# latent leaf that no scope owns is ``kv_cache_carry``'s, as keys and
+# values are
+SCOPE_MLA_Q_PROJ = "mla_q_proj"
+SCOPE_MLA_KV_PROJ = "mla_kv_proj"
+SCOPE_MLA_ABSORB = "mla_absorb"
+SCOPE_MLA_ATTN = "mla_attn"
+SCOPE_MLA_OUT_PROJ = "mla_out_proj"
 # JAX's own name-stack component of a rematerialised (recomputed) operation;
 # ``checkpoint`` alone is also on the backward pass of a checkpointed region
 SCOPE_REMAT = "rematted_computation"
@@ -108,7 +123,9 @@ _CARRY_FREE = frozenset((
     SCOPE_ATTN_CORE, SCOPE_KV_CACHE_WRITE, SCOPE_KV_CACHE_READ, SCOPE_SAMPLE,
     SCOPE_SSM_IN_PROJ, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE_NORM,
     SCOPE_SSM_OUT_PROJ, SCOPE_RET_PROJ, SCOPE_RET_QK_NORM_ROPE,
-    SCOPE_RET_STATE, SCOPE_RET_OUT_PROJ))
+    SCOPE_RET_STATE, SCOPE_RET_OUT_PROJ, SCOPE_MLA_Q_PROJ,
+    SCOPE_MLA_KV_PROJ, SCOPE_MLA_ABSORB, SCOPE_MLA_ATTN,
+    SCOPE_MLA_OUT_PROJ))
 _STRUCTURE = re.compile(
     r"^(jit\(.*\)|pjit\(.*\)|while|body|cond|branch_\d+_fun|closed_call|"
     r"core_call|custom_jvp_call|custom_vjp_call|custom_vjp_call_jaxpr)$")

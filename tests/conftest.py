@@ -31,8 +31,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import pytest  # noqa: E402
 
 # The benchmark's rehearsal tables (tests/perfbench/rehearsal.py) predate
-# the hybrid and the attention-free serve cells, and both they and tests/perfbench/conftest.py are
-# the benchmark's own files. Each cell's tiny stand-in is data in a new file
+# the hybrid, the attention-free and the latent-attention serve cells, and
+# both they and tests/perfbench/conftest.py are the benchmark's own files.
+# Each cell's tiny stand-in is data in a new file
 # beside its tests and is registered from here: this conftest is loaded
 # first and for any subset of the tests (PERF.md, section 7).
 _PERFBENCH_TESTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -40,14 +41,17 @@ _PERFBENCH_TESTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 if _PERFBENCH_TESTS not in sys.path:
     sys.path.insert(0, _PERFBENCH_TESTS)
 import brumby_tiny  # noqa: E402
+import deepseek_v2_tiny  # noqa: E402
 import falcon_h1_tiny  # noqa: E402
 import rehearsal  # noqa: E402
 
 falcon_h1_tiny.register(rehearsal)
 brumby_tiny.register(rehearsal)
+deepseek_v2_tiny.register(rehearsal)
 _PREDATE_REDUCED = {
     falcon_h1_tiny.PREDATES_REDUCED: "test_perfbench_falcon_h1.py",
-    brumby_tiny.PREDATES_REDUCED: "test_perfbench_brumby.py"}
+    brumby_tiny.PREDATES_REDUCED: "test_perfbench_brumby.py",
+    deepseek_v2_tiny.PREDATES_REDUCED: "test_perfbench_deepseek_v2.py"}
 
 
 def pytest_collection_modifyitems(items):

@@ -1,0 +1,601 @@
+"""DeepSeek-V2's block (latent attention with its two forms, YaRN, a
+leading dense layer, group-limited routing over experts of which the layer
+holds a share, shared experts) on the normal serving path, at a small size
+on the CPU with seeded weights, against the plain reference the
+benchmark's cell uses (``perfbench/reference/deepseek_v2.py``).
+
+Tolerances. Program and reference are float32 with every matmul at
+``highest`` (the fixture below), so they differ by the ORDER of float32
+sums alone: the absorbed form re-associates ``q (W_UK c)`` into ``(q W_UK)
+c``, the router and softmax are the same operations. Logits of these tiny
+models are ~0.5 in size and came out 1e-7..3e-7 apart; 5e-6 leaves the sums
+an order of magnitude and is three orders under what bfloat16 operands
+give (~4e-3: ``test_bfloat16_would_not_pass_the_float32_tolerance``)."""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepseek_v2_tiny import TINY_DEEPSEEK
+from deepspeed_tpu import serving
+from deepspeed_tpu.inference.engine import kv_leaf_shapes
+from deepspeed_tpu.models import latent_attention as la
+from deepspeed_tpu.models.transformer_lm import (
+    GPT,
+    KV_LEAVES,
+    GPTConfig,
+    LatentCacheError,
+    MLAConfig,
+)
+from deepspeed_tpu.moe.layer import MOE_STATS, MoE
+from deepspeed_tpu.moe.sharded_moe import topk_routing
+from deepspeed_tpu.ops import rotary
+from deepspeed_tpu.telemetry import scopes, telemetry_bus
+from perfbench.builders import deepseek_v2_serve
+from perfbench.reference import deepseek_v2 as reference
+
+SIZES = reference.sizes(TINY_DEEPSEEK)
+VOCAB = TINY_DEEPSEEK["vocab_size"]
+BUCKET = 16
+ATOL = 5e-6
+
+
+@pytest.fixture(autouse=True)
+def highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def model_config(dtype="float32", **changes):
+    section = dict(TINY_DEEPSEEK["serve"], param_dtype=dtype,
+                   compute_dtype=dtype)
+    return dataclasses.replace(
+        deepseek_v2_serve.model_config(TINY_DEEPSEEK, section), **changes)
+
+
+def served(slots=4, seed=3, **changes):
+    eng = deepspeed_tpu.init_inference(GPT(model_config(**changes)),
+                                       dtype="fp32", seed=seed)
+    sched = serving.build_serving(eng, {"slots": slots,
+                                        "prompt_bucket": BUCKET})
+    sched._ensure_compiled()
+    return eng, sched
+
+
+@pytest.fixture(scope="module")
+def fp32():
+    return served()
+
+
+def tokens(n, seed=0):
+    return np.random.default_rng([seed, n]).integers(0, VOCAB, size=n)
+
+
+def init(cfg, seed=0):
+    model = GPT(cfg)
+    return model, model.init(jax.random.PRNGKey(seed),
+                             jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+def as_scanned(params, cfg):
+    """An unrolled model's parameters in the tree the reference reads."""
+    k = cfg.first_k_dense
+    blocks = [params[f"h_{i}"] for i in range(cfg.n_layer)]
+    h = {f"dense_{i}": blocks[i] for i in range(k)}
+    h["block"] = jax.tree.map(lambda *a: jnp.stack(a), *blocks[k:])
+    return dict({n: v for n, v in params.items() if not n.startswith("h_")},
+                h=h)
+
+
+# ---------------------------------------------------------------------------
+# the two forms of the layer and the reference
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("scan", [True, False], ids=["scanned", "unrolled"])
+@pytest.mark.parametrize("form", ["train", "prefill", "steps", "chunk"])
+def test_each_form_gives_the_references_logits(scan, form):
+    """The per-head form without a cache (``train``) and on the pass that
+    makes the cache (``prefill``), the absorbed form one token at a time
+    (``steps``) and with many query tokens on a cache that exists
+    (``chunk``), under ``ScannedBlocks`` and unrolled."""
+    cfg = model_config(scan_layers=scan, num_logits_to_keep=None)
+    model, params = init(cfg)
+    ids = jnp.asarray(tokens(24)[None])
+    want = reference.logits(params if scan else as_scanned(params, cfg),
+                            np.asarray(ids[0]), SIZES)
+
+    def decode(ids, cache=None):
+        variables = {"params": params}
+        if cache is not None:
+            variables["cache"] = cache
+        out, new = model.apply(variables, ids, decode=True,
+                               mutable=["cache"])
+        return np.asarray(out[0]), new["cache"]
+
+    if form == "train":
+        got = np.asarray(model.apply({"params": params}, ids)[0])
+    elif form == "prefill":
+        got, _ = decode(ids)
+    elif form == "steps":
+        first, cache = decode(ids[:, :16])
+        rest = []
+        for t in range(16, 24):
+            row, cache = decode(ids[:, t:t + 1], cache)
+            rest.append(row)
+        got = np.concatenate([first] + rest)
+    else:
+        first, cache = decode(ids[:, :8])
+        got = np.concatenate([first, decode(ids[:, 8:], cache)[0]])
+    np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
+
+
+def test_bfloat16_would_not_pass_the_float32_tolerance():
+    """What ``ATOL`` is for: the same weights computed in bfloat16 are three
+    orders of magnitude outside it."""
+    cfg = model_config(num_logits_to_keep=None)
+    model, params = init(cfg)
+    ids = jnp.asarray(tokens(24)[None])
+    want = reference.logits(params, np.asarray(ids[0]), SIZES)
+    low = GPT(dataclasses.replace(cfg, dtype=jnp.bfloat16))
+    got = np.asarray(low.apply({"params": params}, ids)[0], np.float32)
+    assert np.abs(got - want).max() > 100 * ATOL
+
+
+def test_the_cache_is_one_latent_and_one_rotary_key_a_position(fp32):
+    """Stacked over ALL layers, the leading dense one among them, with one
+    ``valid`` and one clock a lane, and it holds the reference's ``c_kv``
+    and ``k_rope``."""
+    eng, _ = fp32
+    cfg = eng.module.config
+    ids = jnp.asarray(tokens(16)[None])
+    _, new = eng.module.apply({"params": eng.params}, ids, decode=True,
+                              mutable=["cache"])
+    cache = new["cache"]["h"]
+    assert {k: v.shape for k, v in cache.items()} == {
+        "cached_latent": (3, 1, 64, 16), "cached_rope_key": (3, 1, 64, 4),
+        "valid": (1, 64), "cache_index": (1,)}
+    assert cfg.position_leaves == (("cached_latent", 3),
+                                   ("cached_rope_key", 3))
+    assert GPTConfig().position_leaves == KV_LEAVES
+    assert cfg.has_kv_cache and not cfg.recurrent_leaves
+    _, latent, rope_key = reference.hidden_and_states(
+        eng.params, np.asarray(ids[0]), SIZES)
+    np.testing.assert_allclose(cache["cached_latent"][:, 0, :16], latent,
+                               atol=ATOL, rtol=0)
+    np.testing.assert_allclose(cache["cached_rope_key"][:, 0, :16],
+                               rope_key, atol=ATOL, rtol=0)
+    assert not np.asarray(cache["cached_latent"][:, 0, 16:]).any()
+    assert np.asarray(cache["valid"])[0].tolist() == [True] * 16 + [False] * 48
+    # 576 values a position and layer where 4 heads of keys and values
+    # would be 4 x (12 + 8)
+    assert kv_leaf_shapes(cache) == {(3, 1, 64, 16), (1, 64, 16),
+                                     (3, 1, 64, 4), (1, 64, 4)}
+
+
+def test_the_leading_block_is_dense_and_the_rest_hold_their_share(fp32):
+    eng, _ = fp32
+    h = eng.params["h"]
+    assert set(h) == {"dense_0", "block"}
+    assert set(h["dense_0"]["mlp"]) == {"c_fc", "c_gate", "c_proj"}
+    assert h["dense_0"]["mlp"]["c_fc"]["kernel"].shape == (32, 48)
+    mlp = h["block"]["mlp"]
+    assert set(mlp) == {"gate", "experts", "shared"}
+    assert mlp["gate"]["kernel"].shape == (2, 32, 16)      # all 16 scored
+    assert mlp["gate"]["kernel"].dtype == jnp.float32
+    assert mlp["experts"]["wi"].shape == (2, 2, 32, 16)    # 2 held
+    assert mlp["shared"]["c_fc"]["kernel"].shape == (2, 32, 32)
+    assert set(h["block"]["attn"]) == set(h["dense_0"]["attn"]) == {
+        "q_a", "q_a_norm", "q_b", "kv_a", "kv_a_norm", "kv_b", "c_proj"}
+
+
+# ---------------------------------------------------------------------------
+# YaRN and the softmax scale, against hand-computed values
+# ---------------------------------------------------------------------------
+def test_yarn_frequencies_and_the_scale_at_the_published_numbers():
+    m = MLAConfig(q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+                  v_dim=128, yarn_factor=40.0, yarn_original_positions=4096,
+                  yarn_mscale=0.707, yarn_mscale_all_dim=0.707)
+    # m = 0.1 * 0.707 * ln 40 + 1
+    assert rotary.yarn_mscale(40.0, 0.707) == pytest.approx(1.2608, abs=5e-5)
+    assert m.softmax_scale == pytest.approx(1.2608 ** 2 * 192 ** -0.5,
+                                            rel=1e-4)
+    assert m.rope_mscale == 1.0
+    f = m.inv_freq(10000.0)
+    plain = 10000.0 ** (-np.arange(0, 64, 2) / 64)
+    # the dimensions whose wavelengths make 32 and 1 turns in 4,096
+    # positions: 64 ln(4096 / (32 * 2 pi)) / (2 ln 1e4) = 10.47 -> 10, and
+    # 64 ln(4096 / (2 pi)) / (2 ln 1e4) = 22.51 -> 23
+    np.testing.assert_allclose(f[:11], plain[:11], rtol=1e-12)
+    np.testing.assert_allclose(f[23:], plain[23:] / 40, rtol=1e-12)
+    ramp = (16 - 10) / (23 - 10)
+    assert f[16] == pytest.approx(
+        plain[16] / 40 * ramp + plain[16] * (1 - ramp), rel=1e-12)
+    np.testing.assert_allclose(f, reference.yarn_inv_freq(64, 10000.0, dict(
+        factor=40, original_max_position_embeddings=4096, beta_fast=32,
+        beta_slow=1)), rtol=0)
+    assert MLAConfig(1, 1, 2, 2, 2).inv_freq(10000.0) is None
+    assert MLAConfig(1, 1, 2, 2, 2).softmax_scale == 0.5
+
+
+def test_rotary_with_given_frequencies_rotates_by_position_times_each():
+    x = jnp.ones((1, 3, 1, 4))
+    out = rotary.apply_rotary_pos_emb(x, jnp.asarray([[0, 1, 5]]),
+                                      inv_freq=[0.5, 0.25])
+    for row, pos in zip(np.asarray(out[0, :, 0]), (0, 1, 5)):
+        a, b = 0.5 * pos, 0.25 * pos
+        np.testing.assert_allclose(
+            row, [np.cos(a) - np.sin(a), np.cos(b) - np.sin(b),
+                  np.cos(a) + np.sin(a), np.cos(b) + np.sin(b)], atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# group-limited routing against a brute-force loop
+# ---------------------------------------------------------------------------
+def brute_force_route(probs, k, n_group, topk_group, scale):
+    """``[(expert, weight)]`` per token by the published rule, with Python
+    loops; ties to the lower index."""
+    out = []
+    per = probs.shape[1] // n_group
+    for p in probs:
+        group_score = [max(p[g * per:(g + 1) * per]) for g in range(n_group)]
+        best = sorted(range(n_group), key=lambda g: (-group_score[g], g))
+        keep = set(best[:topk_group])
+        left = [(p[e] if e // per in keep else 0.0, e)
+                for e in range(len(p))]
+        chosen = sorted(left, key=lambda pe: (-pe[0], pe[1]))[:k]
+        out.append([(e, w * scale) for w, e in chosen])
+    return out
+
+
+def routing_logits(case):
+    rng = np.random.default_rng(7)
+    logits = rng.normal(size=(40, 16)).astype(np.float32)
+    if case == "ties":
+        # whole groups tie, and experts inside a group tie
+        logits = np.round(logits)
+        logits[:8] = 0.0
+    elif case == "one_strong_expert":
+        # a group with one strong expert and nothing else is kept for its
+        # maximum, and then contributes that one expert alone
+        logits[:, 6] = 6.0
+        logits[:, 7] = -6.0
+    return logits
+
+
+@pytest.mark.parametrize("case", ["random", "ties", "one_strong_expert"])
+@pytest.mark.parametrize("k,topk_group", [(6, 3), (2, 1), (4, 8)])
+def test_group_limited_routing_is_the_brute_force_loop(case, k, topk_group):
+    logits = routing_logits(case)
+    route = topk_routing(jnp.asarray(logits), k, False, n_group=8,
+                         topk_group=topk_group, scale=16.0)
+    probs = np.asarray(jax.nn.softmax(jnp.asarray(logits), -1))
+    want = brute_force_route(probs, k, 8, topk_group, 16.0)
+    for t, pairs in enumerate(want):
+        assert np.asarray(route.experts[t]).tolist() == \
+            [e for e, _ in pairs], t
+        np.testing.assert_allclose(route.weights[t], [w for _, w in pairs],
+                                   rtol=1e-6)
+    # not renormalised: the weights are 16 x the softmax's own values
+    assert float(route.weights.sum(-1).max()) < 16.0
+    assert np.asarray(route.exp_counts).sum() == 40 * k
+
+
+@pytest.mark.parametrize("case", ["random", "ties", "one_strong_expert"])
+def test_the_reference_routes_as_the_brute_force_loop(case):
+    logits = routing_logits(case)
+    # x W_g with x the identity's rows: the logits themselves
+    got = np.asarray(reference.route(
+        jnp.asarray(logits), jnp.eye(16, dtype=jnp.float32), SIZES))
+    probs = np.asarray(jax.nn.softmax(jnp.asarray(logits), -1))
+    want = np.zeros_like(got)
+    for t, pairs in enumerate(brute_force_route(probs, 6, 8, 3, 16.0)):
+        for e, w in pairs:
+            want[t, e] = w
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+def test_without_groups_or_scale_routing_is_what_it_was():
+    logits = jnp.asarray(routing_logits("random"))
+    old = topk_routing(logits, 6)
+    new = topk_routing(logits, 6, False, n_group=1, topk_group=1, scale=1.0)
+    for a, b in zip(old, new):
+        np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# the share test: what ties the cut to the model
+# ---------------------------------------------------------------------------
+def moe_layer(held):
+    return MoE(d_model=32, d_hidden=16, num_experts=16, k=6,
+               drop_tokens=False, gated_experts=True, n_shared=2, n_group=8,
+               topk_group=3, routed_scale=16.0, experts_held=held,
+               dtype=jnp.float32, param_dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def shares():
+    """The uncut layer (all 16 experts held) and its 8 shares of one group
+    of 2, each with ITS slice of the uncut layer's expert matrices, on 48
+    tokens: ``(x, whole, [share outputs], [share stats], shared part)``."""
+    x = jax.random.normal(jax.random.PRNGKey(2), (48, 32))
+    whole = moe_layer(None)
+    params = whole.init(jax.random.PRNGKey(1), x)["params"]
+    with jax.default_matmul_precision("highest"):
+        y_whole = whole.apply({"params": params}, x)[0]
+        outs, stats = [], []
+        for g in range(8):
+            part = dict(params, experts=jax.tree.map(
+                lambda a: a[2 * g:2 * g + 2], params["experts"]))
+            (y, *_), st = moe_layer((2 * g, 2)).apply(
+                {"params": part}, x, mutable=[MOE_STATS])
+            outs.append(y)
+            stats.append({k: v[0] for k, v in st[MOE_STATS].items()})
+        zero = jax.tree.map(jnp.zeros_like, params["experts"])
+        y_shared = whole.apply(
+            {"params": dict(params, experts=zero)}, x)[0]
+    return x, params, y_whole, outs, stats, y_shared
+
+
+def test_the_eight_shares_sum_to_the_uncut_layer(shares):
+    """Every share adds the shared experts' output (each device computes
+    them for its own tokens), so the routed parts are the shares less
+    that; summed, with the shared experts counted once, they are the uncut
+    layer's output. Float32 sums in another order: 1e-5 of values ~1."""
+    _, _, y_whole, outs, stats, y_shared = shares
+    routed = sum(y - y_shared for y in outs)
+    np.testing.assert_allclose(routed + y_shared, y_whole, atol=1e-5, rtol=0)
+    assert float(jnp.abs(y_whole - y_shared).max()) > 0.1     # not vacuous
+    # every pair is computed on exactly one share
+    assert sum(int(s["routed_here"]) for s in stats) == 48 * 6
+    assert all(int(s["held"]) == 2 for s in stats)
+    assert all(int(s["computed"].sum()) == int(s["routed_here"])
+               for s in stats)
+
+
+def test_a_share_computes_nothing_for_a_token_whose_groups_exclude_it(
+        shares):
+    x, params, _, outs, stats, y_shared = shares
+    chosen = np.asarray(stats[0]["chosen"])                # [tokens, 6]
+    groups = chosen // 2
+    assert all(len(set(row)) <= 3 for row in groups)       # at most 3
+    seen = 0
+    for g in range(8):
+        absent = ~(groups == g).any(1)
+        seen += absent.sum()
+        # the share's output for such a token is the shared experts' alone,
+        # to the bit: its rows lie past the last held group and weigh 0
+        np.testing.assert_array_equal(np.asarray(outs[g])[absent],
+                                      np.asarray(y_shared)[absent])
+        assert not np.array_equal(np.asarray(outs[g])[~absent],
+                                  np.asarray(y_shared)[~absent])
+    assert seen >= 48 * 5          # every token is absent from >= 5 groups
+
+
+def test_the_reference_leaves_out_what_absent_experts_would_add(shares):
+    x, params, _, outs, _, _ = shares
+    for g in (0, 3, 7):
+        p = {"gate": params["gate"], "shared": params["shared"],
+             "experts": jax.tree.map(lambda a: a[2 * g:2 * g + 2],
+                                     params["experts"])}
+        want = reference.moe(x, p, dict(SIZES, held=(2 * g, 2)))
+        np.testing.assert_allclose(outs[g], want, atol=ATOL, rtol=0)
+
+
+def test_expert_load_counts_the_held_experts_alone(fp32):
+    from deepspeed_tpu.moe.utils import publish_expert_load
+
+    eng, _ = fp32
+    seen = []
+    telemetry_bus.subscribe(seen.append)
+    try:
+        load = publish_expert_load(
+            eng.module, eng.params,
+            {"input_ids": np.asarray(tokens(64).reshape(4, 16), np.int32)})
+    finally:
+        telemetry_bus.unsubscribe(seen.append)
+    assert [e["kind"] for e in seen].count("moe.load") == 1
+    counts = np.asarray(load["tokens_per_expert"])
+    assert counts.shape == (2, 2) and load["held"] == 2
+    assert load["routed"] == 2 * 64 * 6
+    assert load["routed_here"] == counts.sum() < load["routed"]
+    assert load["tokens_dropped"] == 0
+    assert load["max_over_mean"] >= 1.0
+
+
+# ---------------------------------------------------------------------------
+# through the scheduler's lane cache
+# ---------------------------------------------------------------------------
+def reference_greedy(params, prompt, n):
+    seq = list(prompt)
+    for _ in range(n):
+        row = reference.logits(params, np.asarray(seq), SIZES,
+                               positions=[len(seq) - 1])[0]
+        seq.append(int(row.argmax()))
+    return seq[len(prompt):]
+
+
+def test_the_scheduler_serves_the_references_greedy_tokens_with_lanes_reused(
+        fp32):
+    """Five ragged prompts (left-padded into buckets of 16, two of them
+    two buckets long) over three lanes: every admission after the third
+    is spliced into a lane beside live lanes, and every token is the
+    plain reference's argmax over prompt + tokens so far."""
+    eng, _ = fp32
+    sched = serving.build_serving(eng, {"slots": 3, "prompt_bucket": BUCKET})
+    prompts = [tokens(n, seed=1).tolist() for n in (5, 16, 21, 3, 30)]
+    got = {}
+    rids = [sched.submit(p, max_new_tokens=5 + i,
+                         stream_callback=lambda r, t, d: got.setdefault(
+                             r, []).append(int(t)))
+            for i, p in enumerate(prompts)]
+    stats = sched.run()
+    assert stats.decode_steps > 0
+    for i, (rid, prompt) in enumerate(zip(rids, prompts)):
+        assert got[rid] == reference_greedy(eng.params, prompt, 5 + i), i
+    plan = sched.kv_cache_stats()
+    assert plan["latent_bytes_per_lane"] == 3 * 64 * (16 + 4) * 4
+    assert plan["kv_bytes_per_lane"] == plan["bytes_per_lane"] \
+        == plan["latent_bytes_per_lane"] + 64 + 4
+    assert plan["state_bytes"] == 0 and plan["compression_ratio"] == 1.0
+
+
+def test_lanes_at_exit_hold_the_latents_of_every_token_taken_in(fp32):
+    """What the benchmark's check reads: a lane's rows are its request's
+    prompt and tokens, exactly those ``valid`` marks, and their latents
+    and rotary keys are the reference's (rotary counting cache rows)."""
+    eng, _ = fp32
+    sched = serving.build_serving(eng, {"slots": 2, "prompt_bucket": BUCKET})
+    sched.retain_lanes = True
+    prompts = {sched.submit(tokens(n, seed=2).tolist(), max_new_tokens=30): n
+               for n in (5, 21)}
+    events = []
+    telemetry_bus.subscribe(events.append)
+
+    class Stop(Exception):
+        pass
+
+    def poll(state={"n": 0}):
+        state["n"] += 1
+        if state["n"] > 9:
+            raise Stop
+
+    try:
+        sched.run(poll_fn=poll)
+    except Stop:
+        pass
+    finally:
+        telemetry_bus.unsubscribe(events.append)
+    kept = sched.lanes_at_exit
+    assert sorted(kept.live) == [0, 1]
+    live = [e["live_positions"] for e in events
+            if e["kind"] == "serve.stats"]
+    assert live and live[-1] <= sum(
+        prompts[c.request_id] + len(c.tokens) for c in kept.live.values())
+    for lane, comp in kept.live.items():
+        n_prompt = prompts[comp.request_id]
+        got = kept.positions(lane)
+        assert got["cached_latent"].shape == (3, 64, 16)
+        assert got["cached_rope_key"].shape == (3, 64, 4)
+        first = -(-n_prompt // BUCKET) * BUCKET - n_prompt
+        n = n_prompt + len(comp.tokens)
+        valid = np.asarray(got["valid"][0])
+        assert valid.sum() == n and valid[first:first + n].all()
+        seq = tokens(n_prompt, seed=2).tolist() + list(comp.tokens)
+        _, latent, rope_key = reference.hidden_and_states(
+            eng.params, np.asarray(seq), SIZES, offset=first)
+        np.testing.assert_allclose(got["cached_latent"][:, first:first + n],
+                                   latent, atol=ATOL, rtol=0)
+        np.testing.assert_allclose(
+            got["cached_rope_key"][:, first:first + n], rope_key, atol=ATOL,
+            rtol=0)
+
+
+@pytest.mark.parametrize("n", [1, 5, 16, 21, 30])
+def test_left_padded_bucket_equals_the_unpadded_prompt(fp32, n):
+    """A pad's latent and rotary key are written and never read: the
+    bucket's last-position logits are the unpadded prompt's."""
+    eng, sched = fp32
+    prompt = tokens(n, seed=4)
+    Lp = -(-n // BUCKET) * BUCKET
+    ids = np.zeros((1, Lp), np.int32)
+    mask = np.zeros((1, Lp), bool)
+    ids[0, Lp - n:], mask[0, Lp - n:] = prompt, True
+    got, cache = eng._prefill_fn(eng.params, jnp.asarray(ids),
+                                 jnp.asarray(mask))
+    want = reference.logits(eng.params, prompt, SIZES, positions=[n - 1])[0]
+    np.testing.assert_allclose(np.asarray(got[0]), want, atol=ATOL, rtol=0)
+    assert np.asarray(cache["h"]["valid"])[0, :Lp].tolist() == \
+        mask[0].tolist()
+
+
+# ---------------------------------------------------------------------------
+# what a latent cache refuses, by name of the reason
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("feature", ["int8_kv", "prefix_cache",
+                                     "speculation", "tp"])
+def test_a_latent_cache_refuses_what_assumes_keys_and_values(fp32, feature):
+    from deepspeed_tpu.parallel.mesh import (
+        MeshTopology,
+        reset_default_topology,
+    )
+    from deepspeed_tpu.serving.prefix_cache import PrefixCache
+
+    eng, _ = fp32
+    try:
+        with pytest.raises(LatentCacheError) as err:
+            if feature == "int8_kv":
+                deepspeed_tpu.init_inference(
+                    GPT(model_config()), dtype="fp32",
+                    config={"kv_cache": "int8"})
+            elif feature == "prefix_cache":
+                serving.ContinuousBatchingScheduler(
+                    eng, slots=2, prompt_bucket=BUCKET,
+                    prefix_cache=PrefixCache())
+            elif feature == "speculation":
+                serving.ContinuousBatchingScheduler(
+                    eng, slots=2, prompt_bucket=BUCKET, draft_engine=eng,
+                    spec_k=2)
+            else:
+                reset_default_topology()
+                tp = deepspeed_tpu.init_inference(
+                    GPT(model_config()), dtype="fp32", mp_size=2)
+                serving.ContinuousBatchingScheduler(
+                    tp, slots=2, prompt_bucket=BUCKET)
+    finally:
+        if feature == "tp":
+            reset_default_topology()
+            from deepspeed_tpu.parallel.mesh import set_default_topology
+
+            set_default_topology(eng.topology)
+    assert "latent cache" in str(err.value)
+    assert err.value.feature.startswith(
+        {"int8_kv": "kv_cache_dtype", "prefix_cache": "prefix_cache",
+         "speculation": "draft_engine", "tp": "tp > 1"}[feature])
+
+
+def test_a_latent_block_has_no_second_mixer_and_takes_rotary_alone():
+    with pytest.raises(ValueError, match="latent-attention block"):
+        model_config(learned_positions=True)
+    with pytest.raises(ValueError, match="first_k_dense"):
+        model_config(first_k_dense=9)
+    with pytest.raises(ValueError, match="moe_experts_held"):
+        model_config(moe_experts_held=(15, 2))
+
+
+# ---------------------------------------------------------------------------
+# scopes: where the readers find the layer in a lowering
+# ---------------------------------------------------------------------------
+def test_the_serving_programs_carry_the_new_scopes(fp32):
+    eng, sched = fp32
+    sched.submit(tokens(5).tolist(), max_new_tokens=3)
+    sched.run()
+    table = sched.program_scopes()
+
+    def scopes_of(program):
+        return {c for path in table[program].values() if path
+                for c in scopes.split_path(path)}
+
+    decode = scopes_of("jit_decode_k")
+    assert {scopes.SCOPE_MLA_Q_PROJ, scopes.SCOPE_MLA_KV_PROJ,
+            scopes.SCOPE_MLA_ABSORB, scopes.SCOPE_MLA_ATTN,
+            scopes.SCOPE_MLA_OUT_PROJ, scopes.SCOPE_MOE_SHARED,
+            scopes.SCOPE_MOE_ROUTER, scopes.SCOPE_MOE_EXPERTS,
+            scopes.SCOPE_KV_CACHE_WRITE, scopes.SCOPE_KV_CACHE_READ} <= decode
+    prefill = scopes_of("jit_prefill")
+    # a prefill decompresses keys and values and absorbs nothing
+    assert scopes.SCOPE_MLA_ABSORB not in prefill
+    assert {scopes.SCOPE_MLA_KV_PROJ, scopes.SCOPE_MLA_ATTN,
+            scopes.SCOPE_MOE_SHARED} <= prefill
+    # a whole latent leaf is only ever handed on (an argument, an element
+    # of the loop's carry), never produced outside the row update
+    carried = [name for name, p in table["jit_decode_k"].items()
+               if p and scopes.SCOPE_KV_CACHE_CARRY in scopes.split_path(p)]
+    assert carried and all(
+        n.startswith(("get-tuple-element", "cache__", "param")) for n in carried)
+
+
+def test_latent_leaves_names_are_what_the_model_declares():
+    assert la.LATENT_LEAVES == tuple(
+        n for n, _ in model_config().position_leaves)

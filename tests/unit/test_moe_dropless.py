@@ -294,8 +294,8 @@ def test_rows_the_grouped_matmul_skips_are_counted_as_dropped(monkeypatch):
     though the routing asked for every pair."""
     from deepspeed_tpu.moe import layer
 
-    def short(logits, k, renormalize=False):
-        route = sharded_moe.topk_routing(logits, k, renormalize)
+    def short(logits, k, *rest):
+        route = sharded_moe.topk_routing(logits, k, *rest)
         counts = route.exp_counts
         return route._replace(exp_counts=counts.at[-1].set(counts[-1] // 2))
 
