@@ -652,6 +652,71 @@ def _check_latent_decode(lanes, positions, hidden, heads, q_rank, kv_rank,
             "rel_l2": {n: round(e, 8) for n, e in errs.items()}}
 
 
+def _check_latent_decode_attention(layers, lanes, positions, heads, kv_rank,
+                                   rope, dtype, strict: bool):
+    """The latent decode kernel over the stacked latent and rotary-key
+    leaves, lanes with left padding and clocks all over the cache (one
+    with nothing visible, one full), at the model's own block, vs the
+    masked softmax over the f32 upcast of the same rows."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas.latent_decode_attention import (
+        block_positions,
+        latent_decode_attention,
+        step_plan,
+    )
+
+    rng = np.random.RandomState(29)
+    q_lat = jnp.asarray(rng.randn(lanes, heads, kv_rank), dtype)
+    q_rope = jnp.asarray(rng.randn(lanes, heads, rope), dtype)
+    latent = jnp.asarray(rng.randn(layers, lanes, positions, kv_rank), dtype)
+    rope_key = jnp.asarray(rng.randn(layers, lanes, positions, rope), dtype)
+    first = rng.randint(0, 64, lanes)               # a bucket's padding
+    clock = np.minimum(first + rng.randint(0, positions, lanes),
+                       positions - 1)
+    first[0], clock[0] = positions - 1, 0      # nothing visible
+    first[-1], clock[-1] = 0, positions - 1    # every row
+    valid = jnp.asarray(np.arange(positions)[None, :] >= first[:, None])
+    layer, scale = layers - 1, 1.0 / np.sqrt(kv_rank // 4 + rope)
+    block = block_positions(positions, kv_rank, jnp.dtype(dtype).itemsize)
+
+    def kernel(q_lat, q_rope, latent, rope_key, valid, clock, layer):
+        return latent_decode_attention(
+            q_lat, q_rope, latent, rope_key, step_plan(valid, clock, block),
+            layer, scale=scale)
+
+    fn = jax.jit(kernel)
+    args = (q_lat, q_rope, latent, rope_key, valid, jnp.asarray(clock),
+            jnp.int32(layer))
+    got = fn(*args)
+    visible = valid & (jnp.arange(positions)[None, :]
+                       <= jnp.asarray(clock)[:, None])
+    lat32 = latent[layer].astype(jnp.float32)
+    att = (jnp.einsum("bhr,bkr->bhk", q_lat.astype(jnp.float32), lat32,
+                      precision="highest")
+           + jnp.einsum("bhd,bkd->bhk", q_rope.astype(jnp.float32),
+                        rope_key[layer].astype(jnp.float32),
+                        precision="highest")) * scale
+    att = jax.nn.softmax(jnp.where(visible[:, None, :], att, -1e30), axis=-1)
+    ref = jnp.einsum("bhk,bkr->bhr", att, lat32, precision="highest")
+    mosaic = _mosaic_calls(fn.lower(*args).compile().as_text())
+    err = _rel_l2(got[1:], ref[1:])
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
+    if not bool(jnp.isfinite(got.astype(jnp.float32)).all()) or err > tol:
+        raise AssertionError(
+            f"latent_decode_attention rel-L2 {err:.3e} > {tol}")
+    if strict and mosaic != 1:
+        raise AssertionError(
+            f"latent_decode_attention: {mosaic} Mosaic calls")
+    return {"kernel": "mla_decode_attn", "layers": layers, "lanes": lanes,
+            "positions": positions, "block": block, "heads": heads,
+            "kv_rank": kv_rank, "rope_dim": rope,
+            "dtype": jnp.dtype(dtype).name, "mosaic_calls": mosaic,
+            "tol": tol, "rel_l2": round(err, 6)}
+
+
 def _check_held_experts(tokens, hidden, width, routed_over, held, dtype,
                         strict: bool):
     """One expert layer that holds a share of the experts its router
@@ -708,6 +773,7 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
                   latent_shape=(8, 2944, 5120, 128, 1536, 512, 128, 64,
                                 128),
                   held_experts_shape=(256, 5120, 1536, 160, (0, 20)),
+                  latent_kernel_shape=(2, 16, 2944, 128, 512, 64),
                   dtype=None, strict=True) -> dict:
     """Each Pallas kernel once at a production shape, forward and
     backward, against plain ``jnp``. ``flash_shapes`` rows are
@@ -724,7 +790,10 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
     step of latent attention, and ``held_experts_shape`` ``(tokens,
     hidden, width, experts scored, (first, count) held)`` of one expert
     layer that holds a share, both against ``perfbench/reference/
-    deepseek_v2.py``."""
+    deepseek_v2.py``; ``latent_kernel_shape`` is ``(layers, lanes,
+    positions, heads, kv_rank, rope_dim)`` of the stacked latent leaves
+    under the latent decode kernel (``latent_shape``'s step builds its
+    ``Lane`` without a plan and keeps the einsums)."""
     import jax.numpy as jnp
 
     from deepspeed_tpu.ops.pallas.common import interpret
@@ -750,6 +819,8 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
                   for dt in dict.fromkeys((dtype, jnp.float32)))
     checks.append(_check_latent_decode(*latent_shape, dtype, strict))
     checks.append(_check_held_experts(*held_experts_shape, dtype, strict))
+    checks.append(_check_latent_decode_attention(*latent_kernel_shape, dtype,
+                                                 strict))
     for c in checks:
         emit({"phase": "kernels", "check": c})
     return {"phase": "kernels", "ok": True, "n_checks": len(checks),

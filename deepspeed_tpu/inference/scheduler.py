@@ -250,8 +250,8 @@ class _LaneClocks:
             lo, hi = live_blocks(
                 self.first, np.minimum(self.clock, self.positions - 1),
                 self.block)
-            share = float((hi - lo + 1).sum()) * self.block \
-                / (self.positions * len(self.clock))
+            share = float((hi - lo + 1).sum()) \
+                / (-(-self.positions // self.block) * len(self.clock))
         self.clock += 1
         self.stats.kv_blocks_read_share_sum += share
         return share

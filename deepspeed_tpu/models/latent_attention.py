@@ -33,6 +33,19 @@ by no option:
   and never decompressed (decompressing costs ``2 kv_rank H (nope_dim +
   v_dim)`` operations a cached position and layer at every step).
 
+  The sum between the two products with ``W_kvb`` has two routes, again
+  told from the call (``models/transformer_lm.py``
+  ``decode_attention_block``). ONE query token a lane: the Pallas kernel
+  ``mla_decode_attn`` (ops/pallas/latent_decode_attention.py), which
+  reads of each lane only the position blocks between its first valid
+  row and its clock, each once (the block fetched for the scores is the
+  value too), out of the stacked leaves where they lie, with scores and
+  softmax statistics in float32 on the chip; what its calls share, the
+  grid and the mask, is made once a step and rides on the ``Lane``
+  (``plan``). More query tokens at once (a continuation, verification)
+  or heads sharded over ``tp``: two einsums over every position of a
+  layer's slice, float32 scores and softmax through memory.
+
 The cache. A lane holds ``c_kv`` (after its norm) and ``k_rope`` (after
 rotary) per position and layer and nothing per head: ``cached_latent``
 ``[layers, B, S, kv_rank]`` and ``cached_rope_key`` ``[layers, B, S,
@@ -77,6 +90,10 @@ class Lane:
     # static: whether this call made the cache (every row a lane holds is
     # among the tokens at hand) or found it
     fresh: bool = struct.field(pytree_node=False)
+    # the decode kernel's grid and mask for this call (ops/pallas/
+    # latent_decode_attention.py ``StepPlan``), the same for every layer;
+    # None where the call keeps the einsums
+    plan: Any = None
 
 
 class LaneCache(NamedTuple):
@@ -110,8 +127,19 @@ def open_lane_cache(module, cfg, B: int, T: int, mask) -> LaneCache:
     def close(lane):
         latent.value, rope_key.value = lane.latent, lane.rope_key
 
+    plan = None
+    if not fresh:
+        from deepspeed_tpu.models.transformer_lm import decode_attention_block
+
+        block = decode_attention_block(cfg, T)
+        if block is not None:
+            from deepspeed_tpu.ops.pallas.latent_decode_attention import \
+                step_plan
+
+            with jax.named_scope(SCOPE_KV_CACHE_READ):
+                plan = step_plan(now_valid, idx, block)
     return LaneCache(Lane(latent.value, rope_key.value, now_valid, idx,
-                          fresh), close)
+                          fresh, plan), close)
 
 
 class _Kernel(nn.Module):
@@ -213,23 +241,35 @@ class LatentAttention(nn.Module):
             w = w_kvb.reshape(r, H, dn + dv)
             with jax.named_scope(SCOPE_MLA_ABSORB):
                 q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, w[..., :dn])
-            with jax.named_scope(SCOPE_KV_CACHE_READ):
-                lat_all = jax.lax.dynamic_index_in_dim(
-                    lane.latent, cache_layer, 0, keepdims=False)   # [B,S,r]
-                rk_all = jax.lax.dynamic_index_in_dim(
-                    lane.rope_key, cache_layer, 0, keepdims=False)
-                visible = (jnp.arange(cfg.n_positions)[None, None, :]
-                           <= pos[:, :, None]) & lane.valid[:, None, :]
-            with jax.named_scope(SCOPE_MLA_ATTN):
-                att = (jnp.einsum("bqhr,bkr->bhqk", q_lat, lat_all,
-                                  preferred_element_type=jnp.float32)
-                       + jnp.einsum("bqhd,bkd->bhqk", q_rope, rk_all,
-                                    preferred_element_type=jnp.float32)
-                       ) * m.softmax_scale
-                att = jnp.where(visible[:, None], att,
-                                jnp.finfo(jnp.float32).min)
-                att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
-                o_lat = jnp.einsum("bhqk,bkr->bqhr", att, lat_all)
+            if lane.plan is not None:
+                # one query token: each lane's live blocks, each once,
+                # out of the stacked leaves where they lie
+                from deepspeed_tpu.ops.pallas.latent_decode_attention import \
+                    latent_decode_attention
+
+                with jax.named_scope(SCOPE_MLA_ATTN):
+                    o_lat = latent_decode_attention(
+                        q_lat[:, 0], q_rope[:, 0], lane.latent,
+                        lane.rope_key, lane.plan, cache_layer,
+                        scale=m.softmax_scale)[:, None]
+            else:
+                with jax.named_scope(SCOPE_KV_CACHE_READ):
+                    lat_all = jax.lax.dynamic_index_in_dim(
+                        lane.latent, cache_layer, 0, keepdims=False)
+                    rk_all = jax.lax.dynamic_index_in_dim(
+                        lane.rope_key, cache_layer, 0, keepdims=False)
+                    visible = (jnp.arange(cfg.n_positions)[None, None, :]
+                               <= pos[:, :, None]) & lane.valid[:, None, :]
+                with jax.named_scope(SCOPE_MLA_ATTN):
+                    att = (jnp.einsum("bqhr,bkr->bhqk", q_lat, lat_all,
+                                      preferred_element_type=jnp.float32)
+                           + jnp.einsum("bqhd,bkd->bhqk", q_rope, rk_all,
+                                        preferred_element_type=jnp.float32)
+                           ) * m.softmax_scale
+                    att = jnp.where(visible[:, None], att,
+                                    jnp.finfo(jnp.float32).min)
+                    att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
+                    o_lat = jnp.einsum("bhqk,bkr->bqhr", att, lat_all)
             with jax.named_scope(SCOPE_MLA_ABSORB):
                 y = jnp.einsum("bqhr,rhd->bqhd", o_lat, w[..., dn:])
         with jax.named_scope(SCOPE_MLA_OUT_PROJ):

@@ -746,29 +746,37 @@ def _mesh_flash_attention(q, k, v, segment_ids, *, causal, autotune):
 def decode_attention_block(cfg, T: int = 1):
     """How a decode call of ``T`` query tokens per lane attends over its
     cache, told from what the call and the cache's layout show: the
-    positions in a block of the block-skipping kernel
-    (ops/pallas/decode_attention.py), which reads of each lane only the
-    blocks between its first valid row and its clock; or None where the
-    call keeps the two einsums over every position. The kernel takes one
-    query token over dense storage. More tokens at once (prefill, chunked
-    continuation, speculative verification), the ring cache of a window
-    layout, int8 storage (dequantised whole on read) and ALiBi (a bias on
-    every position) stay on the einsums, and so do heads sharded over
-    ``tp``: GSPMD cannot partition a Mosaic call; so does latent attention,
-    whose absorbed einsums read the latent of every position (the kernel
-    walks keys and values per head). The scheduler asks the same question
-    for its counter (``kv_blocks_read_share``)."""
+    positions in a block of the block-skipping kernel, which reads of each
+    lane only the blocks between its first valid row and its clock; or
+    None where the call keeps the two einsums over every position. The
+    kernel takes one query token over dense storage: keys and values per
+    head (ops/pallas/decode_attention.py) or, for latent attention, the
+    one latent and rotary key a position that all heads share
+    (ops/pallas/latent_decode_attention.py: another body over the same
+    grid, with a block rule of its own, which need not divide the cache).
+    More tokens at once (prefill, chunked continuation, speculative
+    verification), the ring cache of a window layout, int8 storage
+    (dequantised whole on read) and ALiBi (a bias on every position) stay
+    on the einsums, and so do heads sharded over ``tp``: GSPMD cannot
+    partition a Mosaic call. The scheduler asks the same question for its
+    counter (``kv_blocks_read_share``)."""
     from deepspeed_tpu.ops.pallas.decode_attention import block_positions
     from deepspeed_tpu.ops.sparse_attention.sparse_attention_utils import \
         ring_engaged
     from deepspeed_tpu.parallel.mesh import get_default_topology
 
     if (T != 1 or cfg.kv_cache_dtype == "int8" or cfg.alibi
-            or cfg.mla is not None or ring_engaged(cfg) is not None
+            or ring_engaged(cfg) is not None
             or get_default_topology().size("tp") > 1):
         return None
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    if cfg.mla is not None:
+        from deepspeed_tpu.ops.pallas import latent_decode_attention
+
+        return latent_decode_attention.block_positions(
+            cfg.n_positions, cfg.mla.kv_rank, itemsize)
     return block_positions(cfg.n_positions, cfg.kv_heads, cfg.head_dim,
-                           jnp.dtype(cfg.dtype).itemsize)
+                           itemsize)
 
 
 def step_kernel() -> bool:

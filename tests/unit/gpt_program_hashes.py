@@ -10,8 +10,8 @@ PR 34 moved decode attention of one query token over dense storage into a
 Pallas kernel: ``jit_decode_k`` ALONE was recorded again, on that PR's
 tree, and the other five entries stand as recorded on 534fd1d. The
 ``fallback_hashes`` entries (the decode calls that kernel does not take)
-were recorded on PR 34's parent, d382f5d. The hybrid and retention entries
-say in their functions' docstrings where each was recorded."""
+were recorded on PR 34's parent, d382f5d. The hybrid, retention and latent
+entries say in their functions' docstrings where each was recorded."""
 import hashlib
 import json
 import os
@@ -247,9 +247,55 @@ def retention_hashes():
             jax.random.PRNGKey(0), jnp.float32(0.0), 1).as_text())}
 
 
+def latent_hashes():
+    """The tiny latent-attention configuration (DeepSeek-V2's block,
+    ``tests/perfbench/deepseek_v2_tiny.py``) served in bf16 on three
+    lanes: ``jit_prefill`` (the per-head form), the scheduler's
+    ``splice``, ``jit_prefill_more`` (a continuation of sixteen tokens
+    over a cache that exists: the absorbed einsums) and ``jit_decode_k``.
+    The first three were recorded on 0ed240d, the parent of the PR that
+    gave the absorbed decode step its kernel ``mla_decode_attn``
+    (ops/pallas/latent_decode_attention.py): the pass that makes a lane's
+    cache plans no kernel work, the einsum route is the parent's byte for
+    byte, and both lower as they did. ``latent_jit_decode_k`` was recorded
+    on that PR's tree, with the kernel in it."""
+    import deepspeed_tpu
+    from deepseek_v2_tiny import TINY_DEEPSEEK
+    from deepspeed_tpu import serving
+    from deepspeed_tpu.models.transformer_lm import GPT
+    from deepspeed_tpu.parallel.mesh import reset_default_topology
+    from perfbench.builders import deepseek_v2_serve
+
+    reset_default_topology()
+    section = dict(TINY_DEEPSEEK["serve"], param_dtype="bfloat16",
+                   compute_dtype="bfloat16")
+    eng = deepspeed_tpu.init_inference(
+        GPT(deepseek_v2_serve.model_config(TINY_DEEPSEEK, section)),
+        dtype="bf16", seed=5)
+    sched = serving.build_serving(eng, {"slots": 3, "prompt_bucket": 16})
+    sched._ensure_compiled()
+    ids = jnp.zeros((1, 32), jnp.int32)
+    mask = jnp.ones((1, 32), jnp.bool_)
+    out = {"latent_jit_prefill[32]": _sha(
+        eng._prefill_fn.fn.lower(eng.params, ids, mask).as_text())}
+    sub = jax.eval_shape(eng._prefill_fn.fn, eng.params, ids, mask)[1]
+    cache = sched._cache_shapes()
+    out["latent_jit_decode_k"] = _sha(eng._decode_k_fn.fn.lower(
+        eng.params, jnp.zeros((3,), jnp.int32), cache,
+        jax.random.PRNGKey(0), jnp.float32(0.0), 1).as_text())
+    sched._splice(sched._empty_cache(),
+                  jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), sub), 1)
+    out["latent_jit_splice"] = _sha(sched._splice_fn.fn.lower(
+        cache, sub, jnp.int32(1)).as_text())
+    out["latent_jit_prefill_more[16]"] = _sha(eng._prefill_more_fn.fn.lower(
+        eng.params, jnp.zeros((1, 16), jnp.int32),
+        jnp.ones((1, 16), jnp.bool_), sub).as_text())
+    return out
+
+
 def all_hashes():
     return dict(serve_hashes(), **train_hashes(), **fallback_hashes(),
-                **hybrid_hashes(), **retention_hashes())
+                **hybrid_hashes(), **retention_hashes(), **latent_hashes())
 
 
 if __name__ == "__main__":

@@ -19,10 +19,12 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
+import flax.linen as nn
 from deepseek_v2_tiny import TINY_DEEPSEEK
 from deepspeed_tpu import serving
 from deepspeed_tpu.inference.engine import kv_leaf_shapes
 from deepspeed_tpu.models import latent_attention as la
+from deepspeed_tpu.models import transformer_lm
 from deepspeed_tpu.models.transformer_lm import (
     GPT,
     KV_LEAVES,
@@ -33,6 +35,7 @@ from deepspeed_tpu.models.transformer_lm import (
 from deepspeed_tpu.moe.layer import MOE_STATS, MoE
 from deepspeed_tpu.moe.sharded_moe import topk_routing
 from deepspeed_tpu.ops import rotary
+from deepspeed_tpu.ops.pallas import latent_decode_attention as lda
 from deepspeed_tpu.telemetry import scopes, telemetry_bus
 from perfbench.builders import deepseek_v2_serve
 from perfbench.reference import deepseek_v2 as reference
@@ -589,13 +592,304 @@ def test_the_serving_programs_carry_the_new_scopes(fp32):
     assert {scopes.SCOPE_MLA_KV_PROJ, scopes.SCOPE_MLA_ATTN,
             scopes.SCOPE_MOE_SHARED} <= prefill
     # a whole latent leaf is only ever handed on (an argument, an element
-    # of the loop's carry), never produced outside the row update
+    # of the loop's carry), never produced outside the row update. Off the
+    # chip the interpreter's emulation of the decode kernel copies the
+    # leaves it is handed; Mosaic takes them where they lie
+    # (``test_the_decode_program_hands_the_kernel_the_stacked_leaves``)
     carried = [name for name, p in table["jit_decode_k"].items()
                if p and scopes.SCOPE_KV_CACHE_CARRY in scopes.split_path(p)]
+    assert lda._interpret()
     assert carried and all(
-        n.startswith(("get-tuple-element", "cache__", "param")) for n in carried)
+        n.startswith(("get-tuple-element", "cache__", "param", "copy"))
+        for n in carried)
 
 
 def test_latent_leaves_names_are_what_the_model_declares():
     assert la.LATENT_LEAVES == tuple(
         n for n, _ in model_config().position_leaves)
+
+
+# ---------------------------------------------------------------------------
+# the absorbed form's two routes: one query token through the kernel that
+# reads each lane's live blocks once (ops/pallas/latent_decode_attention.py),
+# everything else on the einsums
+# ---------------------------------------------------------------------------
+def padded_prompts(lengths, bucket=BUCKET):
+    ids = np.zeros((len(lengths), bucket), np.int32)
+    mask = np.zeros((len(lengths), bucket), bool)
+    for row, n in enumerate(lengths):
+        ids[row, bucket - n:] = tokens(n, seed=row + 5)
+        mask[row, bucket - n:] = True
+    return jnp.asarray(ids), jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scanned", "unrolled"])
+def test_decode_k_over_the_carried_leaves_matches_the_einsum_model(
+        monkeypatch, scan):
+    """Four decode steps in one program over the stacked leaves the layer
+    loop carries, ragged left-padded prompts: the kernel's tokens and
+    cache are the einsum route's (the same model with the choice turned
+    off). The leading dense block hands the kernel a Python layer index,
+    the scanned blocks a traced one."""
+    eng = deepspeed_tpu.init_inference(
+        GPT(model_config(scan_layers=scan)), dtype="fp32", seed=4)
+    ids, mask = padded_prompts((16, 5, 1))
+    eng._materialize(ids)
+    eng._build_decode_fns()
+
+    def decode():
+        logits, cache = eng._prefill_fn(eng.params, ids, mask)
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        return eng._decode_k_fn.fn(eng.params, tok, cache,
+                                   jax.random.PRNGKey(0), jnp.float32(0.0),
+                                   4)[:3]
+
+    layers = []
+    real = lda.latent_decode_attention
+    monkeypatch.setattr(
+        lda, "latent_decode_attention",
+        lambda *a, **k: layers.append(a[5]) or real(*a, **k))
+    toks, tok, cache = decode()
+    # traced once a layer, or for the dense block and then for the
+    # scanned one (which flax traces more than once)
+    if scan:
+        assert layers[0] == 0 and len(layers) > 1
+        assert all(isinstance(n, jax.core.Tracer) for n in layers[1:])
+    else:
+        assert layers == [0, 1, 2]
+    del layers[:]
+    monkeypatch.setattr(transformer_lm, "decode_attention_block",
+                        lambda cfg, T=1: None)
+    jax.clear_caches()
+    want_toks, want_tok, want_cache = decode()
+    jax.clear_caches()
+    assert not layers
+    assert np.asarray(toks).tolist() == np.asarray(want_toks).tolist()
+    assert np.asarray(tok).tolist() == np.asarray(want_tok).tolist()
+    for got, want in zip(jax.tree.leaves(cache),
+                         jax.tree.leaves(want_cache)):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=ATOL, rtol=ATOL)
+
+
+@pytest.mark.parametrize("case", ["one_token", "several_tokens", "tp"])
+def test_the_route_is_told_from_the_call_and_by_no_option(case):
+    """One query token over a cache that exists takes the kernel, at the
+    latent rule's block; a continuation of several tokens and heads
+    sharded over ``tp`` keep the einsums; a pass that makes the cache
+    plans nothing; no field of either configuration names the choice."""
+    from deepspeed_tpu.parallel.mesh import (
+        MeshTopology,
+        reset_default_topology,
+        set_default_topology,
+    )
+
+    reset_default_topology()
+    cfg = model_config()
+    m = cfg.mla
+    try:
+        if case == "tp":
+            set_default_topology(MeshTopology(tp=2, dp=-1,
+                                              devices=jax.devices()[:2]))
+        T = 4 if case == "several_tokens" else 1
+        block = transformer_lm.decode_attention_block(cfg, T)
+        if case == "one_token":
+            assert block == lda.block_positions(64, m.kv_rank, 4) == 64
+            # the cell's shape: 512 positions of 512 in bf16, whatever
+            # divides the cache; a float32 latent half as many
+            assert lda.block_positions(2944, 512, 2) == 512
+            assert lda.block_positions(2944, 512, 4) == 256
+            assert lda.block_positions(200, 512, 2) == 200
+        else:
+            assert block is None
+
+        def lane_of(variables, T):
+            holder = {}
+
+            class Probe(nn.Module):
+                @nn.compact
+                def __call__(self):
+                    holder["lane"] = la.open_lane_cache(
+                        self, cfg, 2, T, None).lane
+                    return jnp.zeros(())
+
+            _, out = Probe().apply(variables, mutable=["cache"])
+            return holder["lane"], out
+
+        made, variables = lane_of({}, 1)
+        assert made.fresh and made.plan is None
+        found, _ = lane_of(variables, T)
+        assert not found.fresh
+        assert (found.plan is not None) == (case == "one_token")
+    finally:
+        reset_default_topology()
+    for config in (GPTConfig, MLAConfig):
+        names = {f.name for f in dataclasses.fields(config)}
+        assert not {n for n in names if "decode_attention" in n
+                    or "kernel" in n or "live_block" in n}
+
+
+def test_the_decode_program_hands_the_kernel_the_stacked_leaves(fp32):
+    """In the traced decode program each of the two layer bodies (the
+    leading dense block, the scanned block) holds one ``mla_decode_attn``
+    call whose operands are the stacked latent leaf as it lies and the
+    stacked rotary leaf as ``[layers, B, rope_dim, S]`` (a bitcast on the
+    TPU, whose layout of that leaf has the positions along the lanes),
+    and no slice of either leaf's layer is made; what the calls share
+    (work items, mask) is made outside the layer loop."""
+    eng, sched = fp32
+    cfg = eng.module.config
+    L, S, r, dr = cfg.n_layer, cfg.n_positions, cfg.mla.kv_rank, \
+        cfg.mla.rope_dim
+    jaxpr = eng._decode_k_fn.fn.trace(
+        eng.params, jnp.zeros((4,), jnp.int32), sched._cache_shapes(),
+        jax.random.PRNGKey(0), jnp.float32(0.0), 1).jaxpr.jaxpr
+
+    def walk(jaxpr, depth=0):
+        for e in jaxpr.eqns:
+            yield e, depth
+            inner = depth + (e.primitive.name == "scan")
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from walk(sub, inner)
+
+    eqns = list(walk(jaxpr))
+    calls = [(e, d) for e, d in eqns if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e, _ in calls] == [lda.KERNEL_NAME] * 2
+    # the token loop is one scan; the scanned blocks a second inside it
+    assert sorted(d for _, d in calls) == [1, 2]
+    for e, _ in calls:
+        shapes = [v.aval.shape for v in e.invars]
+        assert (L, 4, S, r) in shapes and (L, 4, dr, S) in shapes
+        assert (4, S, r) not in shapes
+    sliced = [e for e, _ in eqns
+              if e.primitive.name in ("dynamic_slice", "gather", "slice")
+              and e.outvars[0].aval.shape[-2:] in ((S, r), (S, dr))]
+    assert not sliced
+    # the running sum of work_items is the plan's: once a step
+    assert all(d == 1 for e, d in eqns if e.primitive.name == "argmax"
+               and e.invars[0].aval.shape == (4, S))
+    assert sum(e.primitive.name == "argmax"
+               and e.invars[0].aval.shape == (4, S) for e, _ in eqns) == 1
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's check keeps its teeth with the kernel engaged (the body
+# of ``test_perfbench_deepseek_v2.py::test_check_fails_a_swapped_token_
+# and_a_perturbed_latent``, which pins ``decode_attention == "einsum"`` for
+# the stand-in and is a benchmark file: PERF.md, section 7)
+# ---------------------------------------------------------------------------
+def test_the_check_refuses_swapped_tokens_and_perturbed_latents():
+    """The tiny system, its decode attention on the kernel, serves two
+    requests to their end and is stopped with two more in their lanes.
+    ``check`` over that record is correct; a swapped token, a swapped
+    first token, a live lane that took in other tokens than it streamed,
+    first-layer latents or rotary keys off by a hundredth are each
+    refused; without live lanes there is no verdict."""
+    import copy
+    import types
+
+    from brumby_tiny import TINY_CLOSED_DECODED
+    from perfbench.traffic_kinds import serve_closed, serve_closed_decoded
+
+    env = types.SimpleNamespace(
+        config=TINY_DEEPSEEK, traffic=TINY_CLOSED_DECODED, seed=11,
+        t_open=0.0, t_close=float("inf"))
+    system = deepseek_v2_serve.build(env, None)
+    sched, by_rid, done, polls = system.scheduler, {}, [], []
+
+    class WindowEnds(Exception):
+        pass
+
+    def on_token(rid, token, ended):
+        req = by_rid[rid]
+        req.times.append(2.0 + len(req.times))
+        req.tokens.append(int(token))
+        if ended:
+            done.append(req)
+
+    def poll():
+        polls.append(1)
+        if len(polls) == 12:
+            raise WindowEnds
+
+    rng = np.random.default_rng(0)
+    try:
+        for i, (n, want) in enumerate(zip((9, 20, 5, 30), (6, 30, 6, 30))):
+            prompt = rng.integers(0, 128, size=n).tolist()
+            rid = sched.submit(prompt, max_new_tokens=want,
+                               stream_callback=on_token)
+            by_rid[rid] = serve_closed.Req(client=i, prompt=prompt,
+                                           want=want, ramp=False,
+                                           t_submit=1.0)
+        with pytest.raises(WindowEnds):
+            sched.run(poll_fn=poll)
+    finally:
+        system.unsubscribe(system.on_bus)
+    sched._pending.clear()
+    record = {"done": done, "by_rid": by_rid,
+              "in_flight": [r for r in by_rid.values() if r not in done]}
+    assert system.cache_plan["decode_attention"] == "live_blocks"
+    assert system.cache_plan["decode_attention_block"] == 64
+    assert len(record["done"]) == 2 and len(record["in_flight"]) == 2
+    kept = sched.lanes_at_exit
+    assert len(kept.live) == 2
+    real_lanes = system.live_lanes
+    plan = types.SimpleNamespace(vocab=128)
+
+    def checked(edit=None, lanes=None):
+        rec = copy.deepcopy(record)
+        rec["by_rid"] = {rid: next(
+            x for x in rec["done"] + rec["in_flight"] if x.client == r.client)
+            for rid, r in record["by_rid"].items()}
+        if edit:
+            edit(rec)
+        sched.lanes_at_exit = kept              # ``check`` lets it go
+        system.live_lanes = (lambda n, rng: lanes(real_lanes(n, rng))) \
+            if lanes else real_lanes
+        return serve_closed_decoded.check(env, system, plan, rec)
+
+    def swap(where, k):
+        def edit(rec):
+            for i, r in enumerate(rec[where]):
+                r.tokens[k] = (r.tokens[k] + 1 + i) % 128
+        return edit
+
+    def perturb(leaf):
+        def lanes(found):
+            for lane in found:                  # the first layer's rows
+                lane[leaf] = lane[leaf].at[0].multiply(1.01)
+            return found
+        return lanes
+
+    good = checked()
+    assert good["correct"] is True and good["decode"]["lanes"] == 2
+    # float32 against float32: the latents the kernel's steps left are
+    # the reference's, to the order of the sums
+    assert good["decode"]["mean_state_error"] < 2e-6
+    assert good["decode"]["first_layer_head_state_error"] < 2e-6
+    assert good["decode"]["mean_tail_error"] < 2e-6
+    bad = checked(swap("done", 3))
+    assert bad["correct"] is False and bad["failed"] == 0
+    assert bad["decode"]["ok"] is False
+    first = checked(swap("done", 0))
+    assert first["correct"] is False
+    assert any(f["margin"] > f["tolerance"] for f in first["reference"])
+    other = checked(swap("in_flight", 2))
+    assert other["correct"] is False
+    assert other["live_lanes_streamed_their_tokens"] is False
+    latent = checked(lanes=perturb("cached_latent"))
+    assert latent["correct"] is False and latent["failed"] == 0
+    assert latent["decode"]["first_layer_head_state_error"] \
+        == pytest.approx(0.01, rel=1e-2)
+    assert latent["decode"]["mean_margin"] == 0.0      # tokens cannot tell
+    keys = checked(lanes=perturb("cached_rope_key"))
+    assert keys["correct"] is False
+    assert keys["decode"]["mean_tail_error"] > 1e-3
+    system.live_lanes = real_lanes
+    sched.lanes_at_exit = None
+    none = serve_closed_decoded.check(env, system, plan, record)
+    assert none["correct"] is False and none["decode"]["lanes"] == 0

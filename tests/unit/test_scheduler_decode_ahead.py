@@ -543,6 +543,10 @@ def live_blocks_run(tmp_path_factory):
     prompt}, every decode step's host clocks, the serve spans, the bus
     events)``. Eight lanes, twenty requests: lanes are re-used, one prompt
     fills its bucket exactly, some lanes cross the block boundary."""
+    return _served_in_a_trace(_engine, tmp_path_factory)
+
+
+def _served_in_a_trace(make_engine, tmp_path_factory):
     import glob
     import os
 
@@ -552,7 +556,7 @@ def live_blocks_run(tmp_path_factory):
 
     patch = pytest.MonkeyPatch()
     patch.setattr(da, "_BLOCK_BYTES", 1)
-    eng = _engine()
+    eng = make_engine()
     sched = ContinuousBatchingScheduler(eng, slots=8, prompt_bucket=BUCKET)
     sched.retain_lanes = True
     steps = []
@@ -603,12 +607,7 @@ def live_blocks_run(tmp_path_factory):
     return eng, sched, rec, ids, steps, found, events
 
 
-def test_served_tokens_equal_a_cache_free_forward(live_blocks_run):
-    """Every token of every request is the argmax, at the last position,
-    of the model applied with ``decode=False`` to prompt + tokens so far:
-    no cache, no kernel, no scheduler. Requests that ended before the run
-    was stopped, and the tokens so far of the ones still in their lanes."""
-    eng, sched, rec, ids, _, _, _ = live_blocks_run
+def _check_against_a_cache_free_forward(eng, sched, rec, ids):
     model, n_pos = eng.module, eng.module.config.n_positions
 
     @jax.jit
@@ -635,12 +634,20 @@ def test_served_tokens_equal_a_cache_free_forward(live_blocks_run):
     assert checked > 20
 
 
-def test_the_spans_counter_is_live_blocks_on_the_hosts_clocks(
-        live_blocks_run):
+def test_served_tokens_equal_a_cache_free_forward(live_blocks_run):
+    """Every token of every request is the argmax, at the last position,
+    of the model applied with ``decode=False`` to prompt + tokens so far:
+    no cache, no kernel, no scheduler. Requests that ended before the run
+    was stopped, and the tokens so far of the ones still in their lanes."""
+    _check_against_a_cache_free_forward(*live_blocks_run[:4])
+
+
+def _check_the_spans_counter(eng, sched, steps, found, per_layer):
+    """``per_layer``: whether ``cache_index`` and ``valid`` are stacked a
+    layer (keys and values) or one a lane (a latent cache)."""
     from deepspeed_tpu.ops.pallas.decode_attention import live_blocks
     from deepspeed_tpu.telemetry import spans
 
-    eng, sched, _, _, steps, found, _ = live_blocks_run
     on_spans = [attrs["kv_blocks_read_share"] for _, name, attrs in found
                 if name == spans.SERVE_DECODE_STEP]
     assert len(on_spans) == len(steps) > 10
@@ -654,12 +661,17 @@ def test_the_spans_counter_is_live_blocks_on_the_hosts_clocks(
     assert min(shares) == 0.5 and max(shares) > 0.5   # some lanes crossed
     # the host's clocks are the device's: what the last step left there
     cache = sched.lanes_at_exit.cache
-    leaves = {p[-1].key: v for p, v in
-              jax.tree_util.tree_flatten_with_path(cache)[0]}
+    leaves = {p[-1].key: np.asarray(v)[0] if per_layer else np.asarray(v)
+              for p, v in jax.tree_util.tree_flatten_with_path(cache)[0]}
     first, clock, _ = steps[-1]
-    assert (np.asarray(leaves["cache_index"])[0] == clock + 1).all()
-    valid = np.asarray(leaves["valid"])[0]
-    assert (valid.argmax(axis=1) == first).all()
+    assert (leaves["cache_index"] == clock + 1).all()
+    assert (leaves["valid"].argmax(axis=1) == first).all()
+
+
+def test_the_spans_counter_is_live_blocks_on_the_hosts_clocks(
+        live_blocks_run):
+    eng, sched, _, _, steps, found, _ = live_blocks_run
+    _check_the_spans_counter(eng, sched, steps, found, per_layer=True)
 
 
 def test_summary_and_cache_plan_say_how_attention_read(live_blocks_run):
@@ -699,3 +711,81 @@ def test_cache_plan_names_the_path_of_each_layout(kw, path):
         scheduler_mod.ServingStats(), 2, 256,
         sched._decode_attention_block())
     assert clocks.step() == 1.0     # one block a lane, or every position
+
+
+# ---------------------------------------------------------------------------
+# (l) latent attention's decode reads each lane's live blocks once (PR 40):
+# the same run with the tiny DeepSeek-V2 block, whose one query token goes
+# through ``mla_decode_attn`` (ops/pallas/latent_decode_attention.py)
+# ---------------------------------------------------------------------------
+def _latent_engine():
+    import deepspeed_tpu
+    from deepseek_v2_tiny import TINY_DEEPSEEK
+    from perfbench.builders import deepseek_v2_serve
+
+    section = dict(TINY_DEEPSEEK["serve"], cache_positions=256)
+    return deepspeed_tpu.init_inference(
+        GPT(deepseek_v2_serve.model_config(TINY_DEEPSEEK, section)),
+        dtype="fp32", seed=3)
+
+
+@pytest.fixture(scope="module")
+def latent_blocks_run(tmp_path_factory):
+    """``live_blocks_run`` for a latent lane cache: two blocks of 128 of
+    its 256 positions (the latent rule alone would give one)."""
+    from deepspeed_tpu.ops.pallas import latent_decode_attention as lda
+
+    calls = []
+    patch = pytest.MonkeyPatch()
+    real = lda.latent_decode_attention
+    patch.setattr(lda, "latent_decode_attention",
+                  lambda *a, **k: calls.append(a[4].block) or real(*a, **k))
+    try:
+        return _served_in_a_trace(_latent_engine, tmp_path_factory) \
+            + (calls,)
+    finally:
+        patch.undo()
+
+
+def test_latent_served_tokens_equal_a_cache_free_forward(latent_blocks_run):
+    """Every token of every request is the argmax, at the last position,
+    of the model applied with ``decode=False`` (the per-head form over
+    all the tokens, no cache, no kernel) to prompt + tokens so far, with
+    lanes re-used and one prompt that fills its bucket exactly."""
+    calls = latent_blocks_run[-1]
+    assert calls and set(calls) == {128}     # the kernel, at two blocks
+    _check_against_a_cache_free_forward(*latent_blocks_run[:4])
+
+
+def test_latent_spans_counter_is_live_blocks_on_the_hosts_clocks(
+        latent_blocks_run):
+    """The span's ``kv_blocks_read_share`` is ``live_blocks`` on the
+    host's clocks, which are the device's ``cache_index`` and ``valid``
+    (one of each a lane for a latent cache, no layer axis)."""
+    eng, sched, _, _, steps, found, _, _ = latent_blocks_run
+    _check_the_spans_counter(eng, sched, steps, found, per_layer=False)
+
+
+def test_latent_cache_plan_and_summary_say_live_blocks(latent_blocks_run):
+    _, sched, _, _, steps, _, events, _ = latent_blocks_run
+    plans = [e for e in events if e["kind"] == "serve.cache_plan"]
+    assert len(plans) == 1
+    assert plans[0]["decode_attention"] == "live_blocks"
+    assert plans[0]["decode_attention_block"] == 128
+    assert plans[0]["latent_bytes_per_lane"] > 0
+    mean = sum(s[2] for s in steps) / len(steps)
+    assert 0.5 < mean < 1.0
+
+
+def test_a_ragged_last_block_counts_as_a_block_held():
+    """A block that does not divide the cache (the latent rule: 2,944
+    positions in blocks of 512) leaves a ragged sixth block: the share is
+    blocks read over blocks held, 1.0 with every block of every lane
+    read."""
+    stats = scheduler_mod.ServingStats()
+    clocks = scheduler_mod._LaneClocks(stats, 2, 2944, 512)
+    clocks.admit(0, 64, 60, 0)
+    clocks.admit(1, 512, 500, 2431)
+    assert clocks.step() == pytest.approx((1 + 6) / 12)
+    clocks.admit(0, 64, 64, 2900)
+    assert clocks.step() == 1.0
