@@ -763,6 +763,59 @@ def _check_held_experts(tokens, hidden, width, routed_over, held, dtype,
             "mosaic_calls": mosaic, "tol": tol, "rel_l2": round(err, 8)}
 
 
+def _check_grouped_matmul_stack(layers, rows, d_in, d_out, groups, dtype,
+                                strict: bool):
+    """The forward product inside a scan over the layers, the matrices
+    read where they lie in the stack (``gmm(..., layer=)``), against the
+    same kernel on each layer's slice: bitwise, and no copy of a layer's
+    matrices in the compiled loop."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas.grouped_matmul import gmm
+
+    rng = np.random.RandomState(29)
+    lhs = jnp.asarray(rng.randn(rows, d_in), dtype)
+    stack = jnp.asarray(
+        rng.randn(layers, groups, d_in, d_out) / np.sqrt(d_in), dtype)
+    # an eighth of the rows on the groups, as one chip's share of a layer
+    sizes = jnp.asarray(rng.multinomial(rows // 8, np.ones(groups) / groups),
+                        jnp.int32)
+
+    # (the products are summed in the carry: stacked as the scan's output
+    # the compiler fuses the call into the stack's update, and that fusion
+    # does not fit VMEM at these widths)
+    def add(acc, product):
+        return acc + product.astype(jnp.float32)
+
+    @jax.jit
+    def scanned(lhs, stack, sizes):
+        return jax.lax.scan(
+            lambda acc, n: (add(acc, gmm(lhs, stack, sizes, layer=n)), None),
+            jnp.zeros((rows, d_out), jnp.float32), jnp.arange(layers))[0]
+
+    text = scanned.lower(lhs, stack, sizes).compile().as_text()
+    mosaic = _mosaic_calls(text)
+    hlo = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    copies = sum(f"= {hlo}[{groups},{d_in},{d_out}]" in line
+                 and " parameter(" not in line for line in text.splitlines())
+    got = np.asarray(scanned(lhs, stack, sizes))
+    want = jnp.zeros((rows, d_out), jnp.float32)
+    for n in range(layers):
+        want = add(want, gmm(lhs, stack[n], sizes))
+    want = np.asarray(want)
+    if not (got == want).all() or not want.any():
+        raise AssertionError("gmm over the stack is not the slices' product")
+    if strict and (mosaic != 1 or copies):
+        raise AssertionError(f"gmm over the stack: {mosaic} Mosaic calls, "
+                             f"{copies} whole-layer results")
+    return {"kernel": "ragged-dot-gmm[stack]", "layers": layers,
+            "rows": rows, "d_in": d_in, "d_out": d_out, "groups": groups,
+            "dtype": jnp.dtype(dtype).name, "mosaic_calls": mosaic,
+            "whole_layer_results": copies, "bitwise": True}
+
+
 def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
                   adam_shape=(2048, 8192), splash=(4096, 128, 16, 64),
                   decode_shapes=((2, 16, 1024, 16, 16, 128),
@@ -770,6 +823,7 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
                   retention_shape=(2, 8, 8, 40, 128),
                   ssd_shape=(2, 8, 32, 2, 128, 256),
                   gmm_shape=(8192, 2048, 1024, 64),
+                  gmm_stack_shape=(2, 1536, 5120, 1536, 20),
                   latent_shape=(8, 2944, 5120, 128, 1536, 512, 128, 64,
                                 128),
                   held_experts_shape=(256, 5120, 1536, 160, (0, 20)),
@@ -785,7 +839,9 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
     of a stacked retention state; ``ssd_shape`` is ``(layers, lanes,
     heads, groups, d_head, d_state)`` of a stacked Mamba-2 state;
     ``gmm_shape`` is ``(rows, d_in, d_out, groups)`` of a grouped matmul
-    over rows sorted by group; ``latent_shape`` is ``(lanes, positions,
+    over rows sorted by group, ``gmm_stack_shape`` ``(layers, rows, d_in,
+    d_out, groups)`` of the forward product over a stack of layers'
+    matrices read in place; ``latent_shape`` is ``(lanes, positions,
     hidden, heads, q_rank, kv_rank, nope, rope, v_dim)`` of one decode
     step of latent attention, and ``held_experts_shape`` ``(tokens,
     hidden, width, experts scored, (first, count) held)`` of one expert
@@ -817,6 +873,8 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 2), (512, 64, 16, 2)),
     checks.append(_check_ssd_step(*ssd_shape, strict))
     checks.extend(_check_grouped_matmul(*gmm_shape, dt, strict)
                   for dt in dict.fromkeys((dtype, jnp.float32)))
+    checks.append(_check_grouped_matmul_stack(*gmm_stack_shape, dtype,
+                                              strict))
     checks.append(_check_latent_decode(*latent_shape, dtype, strict))
     checks.append(_check_held_experts(*held_experts_shape, dtype, strict))
     checks.append(_check_latent_decode_attention(*latent_kernel_shape, dtype,

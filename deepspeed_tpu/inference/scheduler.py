@@ -747,6 +747,7 @@ class ContinuousBatchingScheduler:
                     decode_attention="none" if block == 0
                     else "einsum" if block is None else "live_blocks",
                     decode_attention_block=block or 0,
+                    expert_matrices=self._expert_matrices(),
                     **{k: kv[k] for k in (
                         "kv_bytes_per_lane", "state_bytes_per_lane",
                         "conv_bytes_per_lane", "norm_bytes_per_lane",
@@ -785,6 +786,20 @@ class ContinuousBatchingScheduler:
         if not self._mcfg.has_kv_cache:
             return 0
         return decode_attention_block(self._mcfg)
+
+    def _expert_matrices(self) -> str:
+        """How the decode step's expert layers read their matrices:
+        ``"in_place"`` in the stacked parameters, ``"slice"`` a layer's own
+        tensor, ``"none"`` for a model without experts (the model decides,
+        by the rule it traces under: moe/experts.py ``expert_matrices``; a
+        step sorts ``slots * moe_top_k`` rows a layer)."""
+        from deepspeed_tpu.models.transformer_lm import GPTConfig
+        from deepspeed_tpu.moe.experts import expert_matrices
+
+        if not isinstance(self._mcfg, GPTConfig):
+            return "none"
+        return expert_matrices(
+            self._mcfg, self.slots * self._mcfg.moe_top_k, decode=True)
 
     def _cache_shapes_for(self, eng):
         """Leaf geometry (jax.eval_shape, nothing materialized) of one

@@ -15,6 +15,7 @@ Design is idiomatic JAX, not a translation:
 """
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -1416,6 +1417,37 @@ def _maybe_gathered_block(block_cls, cfg, path, stacked=None):
                            keep_dtype=_GATHERED_AS_STORED)
 
 
+def _maybe_in_place_experts(body, owner, cfg, rows, decode):
+    """``body`` (one turn of ``owner``'s layer scan) with the expert
+    matrices read where they lie in the stacked parameters, where
+    moe/experts.py ``expert_matrices`` says a call over ``rows`` sorted
+    rows does: the stack is ``owner``'s own variable before the scan slices
+    it, closed over (loop-invariant, no carry), and the turn's index into
+    it what the scan counts less the leading dense blocks. The turn's
+    sliced ``wi`` / ``wg`` / ``wo`` are then read by nothing and their
+    slices leave the program. Anywhere else, at ``init`` (no leaf exists
+    yet) and for a tree handed over in another dtype than the
+    configuration declares: ``body`` itself."""
+    from deepspeed_tpu.moe import experts
+
+    if owner.is_initializing() \
+            or experts.expert_matrices(cfg, rows, decode=decode) != "in_place":
+        return body
+    stacked = owner.get_variable("params", "block")["mlp"]["experts"]
+    if any(leaf.dtype != cfg.dtype for leaf in stacked.values()):
+        return body
+
+    # (under ``body``'s own name, which the scan gives its scope: the
+    # operations' ``op_name`` paths stay what every reader knows)
+    @functools.wraps(body)
+    def in_place(block, carry, layer_idx):
+        with experts.matrices_in_place(stacked,
+                                       layer_idx - cfg.first_k_dense):
+            return body(block, carry, layer_idx)
+
+    return in_place
+
+
 def pld_keep_probability(layer_idx, n_layer: int, theta):
     """Depth schedule for PLD stochastic depth: layer i survives with
     ``p_i = 1 - (i/L)(1 - theta)`` — deeper layers drop more. Shared by
@@ -1560,7 +1592,9 @@ class ScannedBlocks(nn.Module):
                 init=True)  # composes: stream int8-at-rest, dequant inner
 
         scanned = nn.scan(
-            body,
+            _maybe_in_place_experts(
+                body, self, cfg, x.shape[0] * x.shape[1] * cfg.moe_top_k,
+                decode),
             variable_axes={"params": 0, MOE_STATS: 0} if carried
             else {"params": 0, "cache": 0, MOE_STATS: 0},
             variable_carry="cache" if carried else False,

@@ -12,8 +12,18 @@ kernel (``ops/pallas/grouped_matmul.py``: an expert's matrix stays on chip
 while its rows stream past; forward and both gradients), chosen by
 :func:`grouped_matmul_tiles` from what the call shows and by no option;
 otherwise ``jax.lax.ragged_dot``, which the compiler can partition.
+
+Inside a layer scan a layer's matrices are the scan's slice of the stacked
+parameters, and a slice handed to a custom call is written out whole
+first (3 x 314 MB a layer at DeepSeek-V2's widths, two fifths of its
+decode step: PERF.md, section 6, PR 44). On a serving call the kernel
+therefore reads the STACK where it lies, the layer one more index
+(:func:`expert_matrices` is the rule, :func:`matrices_in_place` how the
+scan's owner hands stack and index over).
 """
 
+import contextlib
+import contextvars
 from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
@@ -45,6 +55,73 @@ def grouped_matmul_tiles(rows: int, d_model: int, d_hidden: int,
     return autotune.grouped_matmul_tiles(
         "gmm", rows, d_model, d_hidden, num_experts, dtype)
 
+
+def expert_matrices(cfg, rows: int, *, decode: bool) -> str:
+    """How the scanned expert layers of a model (``cfg``: its
+    ``GPTConfig``) read their matrices in a call that sorts ``rows`` (token,
+    expert) pairs a layer: ``"in_place"``, the grouped-matmul kernel over
+    the stacked parameter leaf ``[layers, E, K, N]`` as it lies in memory
+    with the layer as an index; ``"slice"``, each layer multiplies by the
+    ``[E, K, N]`` tensor it is handed (the scan's slice, or its own leaf
+    without a scan); ``"none"``, no layer has experts. Told from what the
+    call shows, by no option; model and scheduler ask alike. In place
+    where all hold:
+
+    * :func:`grouped_matmul_tiles` chose the kernel (the sorted-rows path
+      at widths of whole lanes, rows of whole tiles, bf16 or float32, no
+      ``ep`` or ``tp`` axis): the compiler fuses a slice into a ragged dot
+      of its own and has nothing to copy;
+    * the layers run in ``ScannedBlocks``' scan: a layer looped over reads
+      a leaf of its own;
+    * the call serves (``decode``: prefill, continuation, decode step,
+      verification). Nothing there is differentiated; a training forward
+      over the stack would want a cotangent the size of the stack a layer;
+    * the layer multiplies by what is stored: not wider parameters cast
+      on use, nor a stack that is dequantised, gathered over ``fsdp`` or
+      streamed from the host where the layer reads it."""
+    from deepspeed_tpu.runtime.zero.gather import current_plan
+
+    if not cfg.is_moe or cfg.n_layer <= cfg.first_k_dense:
+        return "none"
+    dropless = cfg.moe_top_k > 2 or not cfg.moe_drop_tokens
+    held = (cfg.moe_experts_held or (0, cfg.moe_num_experts))[1]
+    if (dropless and decode and cfg.scan_layers
+            and jnp.dtype(cfg.param_dtype) == jnp.dtype(cfg.dtype)
+            and not (cfg.quantized_weights or cfg.param_offload)
+            and current_plan() is None
+            and grouped_matmul_tiles(rows, cfg.n_embd, cfg.moe_ffn_dim, held,
+                                     cfg.dtype)):
+        return "in_place"
+    return "slice"
+
+
+# What a layer scan's owner offers the experts traced inside one turn: the
+# stacked ``{"wi", "wg", "wo"}`` leaves and the turn's index into them.
+_STACKED: contextvars.ContextVar = contextvars.ContextVar(
+    "stacked_expert_matrices", default=None)
+
+
+@contextlib.contextmanager
+def matrices_in_place(stacked, layer):
+    """Entered around one turn of a layer scan whose expert matrices are
+    read where they lie (:func:`expert_matrices`): ``stacked`` the
+    ``experts`` subtree of the scanned blocks' parameters with its leading
+    layer axis, ``layer`` this turn's index into it (traced). Read at trace
+    time by :class:`StackedExperts`; as ZeRO-3's ``gather_context`` tells
+    the layer loop of its rules, without an argument through every
+    ``__call__`` between."""
+    token = _STACKED.set((stacked, layer))
+    try:
+        yield
+    finally:
+        _STACKED.reset(token)
+
+
+def reading_in_place() -> bool:
+    """Whether the experts traced now read the stack (a counter's source)."""
+    return _STACKED.get() is not None
+
+
 class StackedExperts(nn.Module):
     """[E, C, M] -> [E, C, M] two-layer FFN, vectorized over experts; or,
     with ``group_sizes`` ([E], summing to R), [R, M] -> [R, M] over rows
@@ -64,6 +141,11 @@ class StackedExperts(nn.Module):
     ``gated=True`` makes each expert a SwiGLU FFN (Mixtral-style:
     ``wo @ (act(wg x) * (wi x))``, biasless), with a ``wg`` gate tensor
     alongside ``wi`` — same expert-parallel layout.
+
+    Under :func:`matrices_in_place` the three products take the stacked
+    leaves and the layer's index (``gmm(..., layer=)``): the kernel's own
+    forward call on the same blocks, bitwise the slice's result, and
+    nothing that is differentiated.
     """
 
     num_experts: int
@@ -79,7 +161,7 @@ class StackedExperts(nn.Module):
     def __call__(self, x, group_sizes=None):
         E, M, H = self.num_experts, self.d_model, self.d_hidden
         if group_sizes is None:
-            def matmul(a, w):
+            def matmul(a, w, name):
                 return jnp.einsum("ecm,emh->ech", a, w)
 
             def per_expert(b):
@@ -89,6 +171,7 @@ class StackedExperts(nn.Module):
             # lowering three kernels for it is set-up time for nothing)
             tiles = None if self.is_initializing() else \
                 grouped_matmul_tiles(x.shape[0], M, H, E, self.dtype)
+            stacked, layer = _STACKED.get() or (None, None)
             if tiles:
                 from deepspeed_tpu.ops.pallas import grouped_matmul as gm
 
@@ -96,7 +179,10 @@ class StackedExperts(nn.Module):
                 # forward and backward
                 walk = gm.row_walk(group_sizes, x.shape[0], tiles[0])
 
-            def matmul(a, w):
+            def matmul(a, w, name):
+                if tiles and stacked is not None:
+                    return gm.gmm(a, stacked[name], None, walk=walk,
+                                  layer=layer)
                 if tiles:
                     return gm.grouped_matmul(a, w, group_sizes, walk)
                 return jax.lax.ragged_dot(a, w, group_sizes)
@@ -110,7 +196,7 @@ class StackedExperts(nn.Module):
         wo = self.param("wo", nn.initializers.lecun_normal(),
                         (E, H, M), self.param_dtype)
         x = x.astype(self.dtype)
-        h = matmul(x, wi.astype(self.dtype))
+        h = matmul(x, wi.astype(self.dtype), "wi")
         if self.use_bias:
             bi = self.param("bi", nn.initializers.zeros, (E, H),
                             self.param_dtype)
@@ -118,11 +204,11 @@ class StackedExperts(nn.Module):
         if self.gated:
             wg = self.param("wg", nn.initializers.lecun_normal(),
                             (E, M, H), self.param_dtype)
-            g = matmul(x, wg.astype(self.dtype))
+            g = matmul(x, wg.astype(self.dtype), "wg")
             h = self.activation(g) * h
         else:
             h = self.activation(h)
-        y = matmul(h, wo.astype(self.dtype))
+        y = matmul(h, wo.astype(self.dtype), "wo")
         if self.use_bias:
             bo = self.param("bo", nn.initializers.zeros, (E, M),
                             self.param_dtype)

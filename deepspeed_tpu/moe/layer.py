@@ -54,7 +54,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from deepspeed_tpu.moe.experts import StackedExperts, grouped_matmul_tiles
+from deepspeed_tpu.moe.experts import (
+    StackedExperts,
+    grouped_matmul_tiles,
+    reading_in_place,
+)
 from deepspeed_tpu.moe.sharded_moe import (
     combine_rows,
     combine_tokens,
@@ -178,7 +182,10 @@ class MoE(nn.Module):
         and not from the routing; on the dropless path ``chosen`` [tokens,
         k], ``gmm_tiles`` [3], the grouped-matmul kernel's ``(tm, tk,
         tn)`` as this trace chose them, zeros where it chose
-        ``ragged_dot``, ``held``, how many experts' matrices the layer
+        ``ragged_dot``, ``in_place``, 1 where this trace's grouped matmuls
+        read the matrices in the stacked parameters and 0 where they took
+        a layer's own tensor (moe/experts.py ``expert_matrices``),
+        ``held``, how many experts' matrices the layer
         holds (``computed`` is of those), and ``routed_here``, the pairs
         routed to them. (``init`` makes every collection mutable and would
         return them beside the parameters.)"""
@@ -252,6 +259,7 @@ class MoE(nn.Module):
                         computed=rows_computed(rows, groups, order,
                                                held + 1)[:held],
                         gmm_tiles=jnp.asarray(tiles or (0, 0, 0), jnp.int32),
+                        in_place=jnp.int32(bool(tiles) and reading_in_place()),
                         held=jnp.int32(held), routed_here=routed_here)
             return (y.reshape(orig_shape), route.l_aux, route.l_z,
                     route.exp_counts)

@@ -35,7 +35,7 @@ def split_moe_params(params) -> Tuple[Any, Any]:
 # rank of one layer's value of each counter the MoE layer sows; whatever
 # leads it (a scan's layer axis, none for a layer on its own) is flattened
 _COUNTER_RANK = {"routed": 0, "computed": 1, "chosen": 2, "gmm_tiles": 1,
-                 "held": 0, "routed_here": 0}
+                 "in_place": 0, "held": 0, "routed_here": 0}
 
 
 def routing_stats(model, params, batch):
@@ -46,7 +46,9 @@ def routing_stats(model, params, batch):
     dispatch or the experts' output) and, on the dropless path, ``chosen``
     [layers, tokens, k] (each token's chosen experts) and ``gmm_tiles``
     [layers, 3] (the grouped-matmul kernel's tiles, zeros where the layer
-    traced ``ragged_dot``), ``held`` [layers] (the experts whose matrices
+    traced ``ragged_dot``), ``in_place`` [layers] (1 where the kernel read
+    the stacked parameters: a serving call's, never this forward's),
+    ``held`` [layers] (the experts whose matrices
     the layer holds: ``computed`` is of those) and ``routed_here``
     [layers] (the pairs routed to them). One forward program of its own,
     off the step."""

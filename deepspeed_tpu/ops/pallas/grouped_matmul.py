@@ -9,7 +9,11 @@ its two gradients':
 * :func:`gmm` ``(lhs [R, K], rhs [E, K, N]) -> [R, N]``: row r times its
   group's matrix; with ``transpose_rhs`` the matrices are ``[E, N, K]`` and
   are contracted on their LAST axis inside the kernel (the rows' gradient:
-  no transposed copy of the experts' tensor is ever written);
+  no transposed copy of the experts' tensor is ever written); with
+  ``layer`` the matrices are a stack ``[layers, E, K, N]`` read where it
+  lies, the layer one more prefetched scalar in their block index (a
+  serving call inside the layer scan: no slice of the stack is written
+  out for the custom call);
 * :func:`tgmm` ``(lhs [R, K], dout [R, N]) -> [E, K, N]``: each group's
   rows contracted (the matrices' gradient), a group of no rows zeros.
 
@@ -145,8 +149,10 @@ def _own_rows(offsets, g, tile, shape):
     return (row >= offsets[g]) & (row < offsets[g + 1])
 
 
-def _gmm_kernel(offsets, group, tile, count, lhs_ref, rhs_ref, out_ref, *,
-                groups, transpose_rhs):
+def _gmm_kernel(offsets, group, tile, count, *refs, groups, transpose_rhs):
+    # (over a stack of layers the layer's index is a fifth prefetched
+    # scalar, which the matrices' index map alone reads)
+    lhs_ref, rhs_ref, out_ref = refs[-3:]
     # the grid is (column tiles, visits); (no program_id under a `when`)
     v = pl.program_id(1)
     g, t = group[v], tile[v]
@@ -220,19 +226,31 @@ def _tiles_and_walk(kind, tiles, walk, group_sizes, rows, k, n, groups,
 
 
 def gmm(lhs, rhs, group_sizes, *, transpose_rhs=False, tiles=None,
-        walk=None):
+        walk=None, layer=None):
     """``out[r] = lhs[r] @ rhs[g(r)]`` (``lhs[r] @ rhs[g(r)].T`` with
     ``transpose_rhs``) for rows sorted by group, zeros for the rows past
     ``sum(group_sizes)``; float32 accumulation over all of K, rounded once
     to ``lhs``'s dtype. ``tiles`` ``(tm, tn)`` overrides the table's;
     ``walk`` is :func:`row_walk`'s of these sizes, made once for several
-    calls (its ``tm`` then holds)."""
-    (rows, k), groups = lhs.shape, rhs.shape[0]
-    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    calls (its ``tm`` then holds).
+
+    With ``layer`` (an int32 scalar, traced or not) ``rhs`` is a STACK
+    ``[layers, E, K, N]`` of such tensors as it lies in memory and the
+    call multiplies by ``rhs[layer]``: the layer is one more prefetched
+    scalar and leads the matrices' block index, so no ``[E, K, N]`` tensor
+    is made. (Inside a layer scan the slice ``rhs[layer]`` is an operand
+    that XLA writes out whole before a custom call may read it: three
+    times 314 MB a layer at DeepSeek-V2's widths, PERF.md section 6, PR
+    44.) Same blocks, same walk, same arithmetic: bitwise the slice's."""
+    (rows, k), groups = lhs.shape, rhs.shape[-3]
+    n = rhs.shape[-2] if transpose_rhs else rhs.shape[-1]
     (tm, _, tn), walk = _tiles_and_walk(
         "gmm_t" if transpose_rhs else "gmm",
         tiles and (tiles[0], k, tiles[1]), walk, group_sizes, rows, k, n,
         groups, lhs.dtype)
+    if layer is not None:
+        walk += (lax.reshape(lax.convert_element_type(layer, jnp.int32),
+                             (1,)),)
     return _gmm(lhs, rhs, walk, transpose_rhs=transpose_rhs, tiles=(tm, tn),
                 interpret=_interpret())
 
@@ -244,29 +262,33 @@ def gmm(lhs, rhs, group_sizes, *, transpose_rhs=False, tiles=None,
 @functools.partial(jax.jit,
                    static_argnames=("transpose_rhs", "tiles", "interpret"))
 def _gmm(lhs, rhs, walk, *, transpose_rhs, tiles, interpret):
+    # ``walk`` is the four vectors, or five with the layer of a stacked
+    # ``rhs`` [layers, E, ., .] last
     rows, k = lhs.shape
-    groups = rhs.shape[0]
-    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    groups = rhs.shape[-3]
+    n = rhs.shape[-2] if transpose_rhs else rhs.shape[-1]
     tm, tn = tiles
     item = lhs.dtype.itemsize
 
-    def rhs_map(j, v, offsets, group, tile, count):
+    def rhs_map(j, v, offsets, group, tile, count, *layer):
         g = lax.min(group[v], groups - 1)
-        return (g, j, 0) if transpose_rhs else (g, 0, j)
+        return tuple(at[0] for at in layer) + (
+            (g, j, 0) if transpose_rhs else (g, 0, j))
 
     return pl.pallas_call(
         functools.partial(_gmm_kernel, groups=groups,
                           transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(walk),
             grid=(n // tn, walk[1].shape[0]),
             in_specs=[
-                pl.BlockSpec((tm, k), lambda j, v, o, g, t, c: (t[v], 0)),
-                pl.BlockSpec((None, tn, k) if transpose_rhs
-                             else (None, k, tn), rhs_map),
+                pl.BlockSpec((tm, k), lambda j, v, o, g, t, *_: (t[v], 0)),
+                pl.BlockSpec((None,) * (rhs.ndim - 2)
+                             + ((tn, k) if transpose_rhs else (k, tn)),
+                             rhs_map),
             ],
             out_specs=pl.BlockSpec(
-                (tm, tn), lambda j, v, o, g, t, c: (t[v], j))),
+                (tm, tn), lambda j, v, o, g, t, *_: (t[v], j))),
         out_shape=jax.ShapeDtypeStruct((rows, n), lhs.dtype),
         compiler_params=_params(
             autotune.grouped_matmul_vmem_bytes("gmm", tm, k, tn, item)),

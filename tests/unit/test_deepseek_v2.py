@@ -777,6 +777,144 @@ def test_the_decode_program_hands_the_kernel_the_stacked_leaves(fp32):
 
 
 # ---------------------------------------------------------------------------
+# the experts' grouped matmuls read the stacked parameters in place
+# ---------------------------------------------------------------------------
+def aligned_config(**changes):
+    """The tiny model with its experts 128 wide over a hidden size of 128:
+    one leading dense layer, two scanned expert layers, one group of two
+    experts held of sixteen. Eight prompts of a 16-token bucket sort 768
+    rows a layer, eight lanes' decode step 48."""
+    return model_config(n_embd=128, moe_intermediate_size=128, **changes)
+
+
+def serving_programs(cfg, seed=4):
+    eng = deepspeed_tpu.init_inference(GPT(cfg), dtype="fp32", seed=seed)
+    ids, mask = padded_prompts((16, 5, 1, 9, 16, 2, 12, 7))
+    eng._materialize(ids)
+    eng._build_decode_fns()
+    host = lambda tree: jax.tree.map(np.asarray, tree)    # noqa: E731
+    logits, cache = eng._prefill_fn(eng.params, ids, mask)
+    out = {"prefill": host((logits, cache))}
+    more_ids, more_mask = padded_prompts((16,) * 8)
+    logits, cache = eng._prefill_more_fn(eng.params, more_ids, more_mask,
+                                         cache)
+    out["prefill_more"] = host((logits, cache))
+    tok = jnp.argmax(logits, -1).astype(jnp.int32)
+    out["decode_k"] = host(eng._decode_k_fn.fn(
+        eng.params, tok, cache, jax.random.PRNGKey(0), jnp.float32(0.0),
+        4)[:3])
+    return out
+
+
+def gmm_routes(monkeypatch):
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+
+    routes, real = [], gm.gmm
+
+    def spy(*args, **kwargs):
+        routes.append(kwargs.get("layer") is not None)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(gm, "gmm", spy)
+    return routes
+
+
+def test_the_serving_programs_over_the_stack_are_bitwise_the_slices(
+        monkeypatch):
+    """``jit_prefill``, ``jit_prefill_more`` and four steps of
+    ``jit_decode_k`` of the aligned model, the experts' products over the
+    stacked leaves with the scan's index: logits, tokens and every cache
+    leaf are bitwise those of the same model on the scan's slices (the
+    rule answered for it), because the kernel makes the same walk over
+    the same blocks."""
+    from deepspeed_tpu.moe import experts
+
+    cfg = aligned_config()
+    routes = gmm_routes(monkeypatch)
+    got = serving_programs(cfg)
+    assert routes and all(routes)
+    del routes[:]
+    monkeypatch.setattr(experts, "expert_matrices",
+                        lambda cfg, rows, decode: "slice")
+    jax.clear_caches()
+    want = serving_programs(cfg)
+    jax.clear_caches()
+    assert routes and not any(routes)
+    for program in want:
+        for a, b in zip(jax.tree.leaves(got[program]),
+                        jax.tree.leaves(want[program])):
+            assert a.dtype == b.dtype and (a == b).all(), program
+
+
+def test_a_training_forwards_gradient_is_what_it_was(monkeypatch):
+    """``jax.grad`` of the aligned model's training forward never takes
+    the stack: with the rule answering "slice" for everything the
+    gradients are bitwise the same, and every product went through the
+    custom VJP on one layer's matrices."""
+    from deepspeed_tpu.moe import experts
+
+    cfg = aligned_config(num_logits_to_keep=None)
+    model, params = init(cfg)
+    ids = jnp.asarray(np.stack([tokens(16, seed=s) for s in range(8)]))
+
+    def grads():
+        jax.clear_caches()
+        return jax.grad(lambda p: model.apply(
+            {"params": p}, ids, labels=ids))(params)
+
+    routes = gmm_routes(monkeypatch)
+    got = grads()
+    assert routes and not any(routes)
+    monkeypatch.setattr(experts, "expert_matrices",
+                        lambda cfg, rows, decode: "slice")
+    want = grads()
+    jax.clear_caches()
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert (np.asarray(a) == np.asarray(b)).all()
+    assert np.asarray(got["h"]["block"]["mlp"]["experts"]["wi"]).any()
+
+
+@pytest.mark.parametrize("model", ["aligned_latent", "tiny_latent", "gpt"])
+def test_the_cache_plan_and_the_counter_say_where_the_matrices_are_read(
+        model, fp32):
+    """``serve.cache_plan``'s ``expert_matrices`` and the layers'
+    ``in_place`` counter read what the rule said: in place at widths of
+    whole lanes, the scan's slice for the tiny model (16-wide experts:
+    ``ragged_dot``), none for a model without experts."""
+    from flax.traverse_util import flatten_dict
+    from simple_model import tiny_gpt_config
+
+    events = []
+    telemetry_bus.subscribe(events.append)
+    try:
+        if model == "aligned_latent":
+            eng, sched = served(slots=8, n_embd=128,
+                                moe_intermediate_size=128)
+        elif model == "tiny_latent":
+            eng, sched = served(slots=8)
+        else:
+            eng = deepspeed_tpu.init_inference(GPT(tiny_gpt_config()),
+                                               dtype="fp32", seed=0)
+            sched = serving.build_serving(eng, {"slots": 8,
+                                                "prompt_bucket": BUCKET})
+            sched._ensure_compiled()
+    finally:
+        telemetry_bus.unsubscribe(events.append)
+    want = {"aligned_latent": "in_place", "tiny_latent": "slice",
+            "gpt": "none"}[model]
+    (plan,) = [e for e in events if e.get("kind") == "serve.cache_plan"]
+    assert plan["expert_matrices"] == want
+    _, out = eng.module.apply(
+        {"params": eng.params}, jnp.zeros((8, 1), jnp.int32), decode=True,
+        mutable=["cache", MOE_STATS])
+    counted = [np.asarray(value).reshape(-1).tolist()
+               for path, (value,) in flatten_dict(
+                   out.get(MOE_STATS, {})).items() if path[-1] == "in_place"]
+    assert sum(counted, []) == {"in_place": [1, 1], "slice": [0, 0],
+                                "none": []}[want]
+
+
+# ---------------------------------------------------------------------------
 # the benchmark's check keeps its teeth with the kernel engaged (the body
 # of ``test_perfbench_deepseek_v2.py::test_check_fails_a_swapped_token_
 # and_a_perturbed_latent``, which pins ``decode_attention == "einsum"`` for

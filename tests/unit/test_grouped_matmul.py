@@ -1,7 +1,10 @@
 """The grouped-matmul kernels (``ops/pallas/grouped_matmul.py``) against
 ``jax.lax.ragged_dot`` and its autodiff on the same inputs, in interpret
 mode on the CPU; the rule that chooses them (``moe/experts.py``); and, for
-a described v5e, Mosaic's own compile at the OLMoE cell's widths.
+a described v5e, Mosaic's own compile at the OLMoE cell's widths; and the
+forward product over a STACK of layers' matrices with the layer as an
+index (``gmm(..., layer=)``), which a serving call inside the layer scan
+takes so that no slice of the stack is written out for the custom call.
 
 Tolerance: both sides accumulate in float32 and round once, in another
 order of the sums, so a bf16 result differs by one bf16 ulp at most (2^-7
@@ -10,6 +13,8 @@ of its value) and a sum that cancels by float32's own error on its terms
 row given to the wrong group, a tile skipped or computed from another
 group's rows is wrong by the size of the values themselves.
 """
+import dataclasses
+import hashlib
 import re
 
 import jax
@@ -188,6 +193,137 @@ def test_under_checkpoint_inside_the_layer_scan(layout):
         assert np.linalg.norm(g - w) < 2e-2 * np.linalg.norm(w)
 
 
+# --- the matrices read where they lie in a stack of layers ------------------
+LAYERS = 3
+
+
+def stacked_operands(dtype, rows, seed=2):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.standard_normal((rows, 256)), dtype),
+            jnp.asarray(rng.standard_normal((LAYERS, GROUPS, 256, 128)) / 16,
+                        dtype))
+
+
+def bitwise(got, want):
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert (np.asarray(got, np.float32) == np.asarray(want, np.float32)).all()
+
+
+@pytest.mark.parametrize("rows", [ROWS, 2 * ROWS])
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_over_the_stack_with_a_layer_is_the_slices_product_bitwise(
+        layout, dtype, rows):
+    """Every layer, groups of no rows and rows past ``sum(group_sizes)``
+    (all of the second half at 512 rows) included; the layer a Python int
+    or a traced scalar."""
+    lhs, stack = stacked_operands(DTYPES[dtype], rows)
+    sizes = jnp.asarray(LAYOUTS[layout], jnp.int32)
+    traced = jax.jit(lambda a, w, n: gm.gmm(a, w, sizes, layer=n))
+    for n in range(LAYERS):
+        want = gm.gmm(lhs, stack[n], sizes)
+        bitwise(gm.gmm(lhs, stack, sizes, layer=n), want)
+        bitwise(traced(lhs, stack, jnp.int32(n)), want)
+    assert not np.asarray(want[int(sizes.sum()):], np.float32).any()
+
+
+def test_the_rows_gradients_product_takes_a_stack_too():
+    lhs, stack = stacked_operands(jnp.bfloat16, ROWS)
+    sizes = jnp.asarray(LAYOUTS["ending_inside_a_tile"], jnp.int32)
+    cot = lhs[:, :128]
+    for n in range(LAYERS):
+        bitwise(gm.gmm(cot, stack, sizes, transpose_rhs=True, layer=n),
+                gm.gmm(cot, stack[n], sizes, transpose_rhs=True))
+
+
+def scanned_layers(in_place, walk_rows=None):
+    """One product a layer inside a ``lax.scan`` over the layers: over the
+    scan's slice of the stack, or over the stack (closed over, not a
+    carry) with the turn's index."""
+    def run(x, stack, sizes):
+        walk = gm.row_walk(sizes, x.shape[0], walk_rows or 32)
+
+        def turn(x, xs):
+            if in_place:
+                y = gm.gmm(x, stack, None, walk=walk, layer=xs)
+            else:
+                y = gm.gmm(x, xs, None, walk=walk)
+            return x + jnp.tanh(jnp.concatenate([y, y], axis=1)), None
+
+        return jax.lax.scan(
+            turn, x, jnp.arange(stack.shape[0]) if in_place else stack)[0]
+
+    return jax.jit(run)
+
+
+@pytest.mark.parametrize("layout", ["ending_inside_a_tile", "some_empty",
+                                    "rows_no_group_covers"])
+def test_inside_a_scan_over_the_layers_likewise(layout):
+    lhs, stack = stacked_operands(jnp.bfloat16, ROWS)
+    sizes = jnp.asarray(LAYOUTS[layout], jnp.int32)
+    bitwise(scanned_layers(True)(lhs, stack, sizes),
+            scanned_layers(False)(lhs, stack, sizes))
+
+
+def pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from pallas_calls(sub)
+
+
+# sha256 of the traced call (the jaxpr with the kernel's body, block
+# shapes, cost estimate and compiler parameters, then the three index
+# maps), recorded on f3cc1e2, PR 44's parent. (The text LOWERED for the TPU
+# carries the kernel's source path and line numbers inside the serialised
+# Mosaic module, so it moves with any edit of the file.)
+AS_ON_THE_PARENT = {
+    False: "cff83dfac2b27dbd086cb024d35e74d388ec70cb9ecb6341eff25736df3664ee",
+    True: "9436909b13be0de84b581dc076619d2d7c027e51c8434fa6072c071e643e3bcf",
+}
+
+
+@pytest.mark.parametrize("transpose_rhs", [False, True],
+                         ids=["forward", "rows_gradient"])
+def test_without_a_layer_the_call_is_the_parents(monkeypatch, transpose_rhs):
+    """OLMoE's training step calls ``gmm`` nine times a layer and must
+    trace as it did: four prefetched scalars, the same blocks and index
+    maps, the same cost estimate."""
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
+    closed = jax.make_jaxpr(
+        lambda a, w, s: gm.gmm(a, w, s, transpose_rhs=transpose_rhs))(
+        jax.ShapeDtypeStruct((256, 256), jnp.bfloat16),
+        jax.ShapeDtypeStruct((4, 128, 256) if transpose_rhs
+                             else (4, 256, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((4,), jnp.int32))
+    (call,) = pallas_calls(closed.jaxpr)
+    assert call.params["grid_mapping"].num_index_operands == 4
+    text = "\n".join([str(closed)] + [
+        str(m.index_map_jaxpr)
+        for m in call.params["grid_mapping"].block_mappings])
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == AS_ON_THE_PARENT[transpose_rhs]
+
+
+def test_the_stacked_calls_cost_counts_one_layers_matrices(monkeypatch):
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
+    lhs = jax.ShapeDtypeStruct((256, 256), jnp.bfloat16)
+    sizes = jax.ShapeDtypeStruct((4,), jnp.int32)
+
+    def cost(rhs_shape, **layer):
+        closed = jax.make_jaxpr(lambda a, w, s: gm.gmm(a, w, s, **layer))(
+            lhs, jax.ShapeDtypeStruct(rhs_shape, jnp.bfloat16), sizes)
+        (call,) = pallas_calls(closed.jaxpr)
+        return (call.params["cost_estimate"],
+                call.params["grid_mapping"].num_index_operands)
+
+    one, scalars = cost((4, 256, 128))
+    stacked, stacked_scalars = cost((LAYERS, 4, 256, 128), layer=1)
+    assert stacked == one
+    assert (scalars, stacked_scalars) == (4, 5)
+
+
 # --- the rule that chooses the kernel --------------------------------------
 def experts_program(monkeypatch, d_model, d_hidden, dtype, rows):
     """The sorted-rows path of the experts, lowered for the TPU (no chip is
@@ -251,9 +387,138 @@ def test_the_kernel_is_chosen_from_what_the_call_shows(
             r'= "?chlo\.ragged_dot"?[ (]', text)) == 3
 
 
+# --- the rule that says where the kernel reads the matrices ------------------
+def moe_gpt(**changes):
+    """Two scanned expert layers at widths of whole lanes; a call over
+    ``[2, 8]`` tokens sorts 64 rows a layer."""
+    from deepspeed_tpu.models.transformer_lm import GPT, GPTConfig
+
+    base = dict(
+        vocab_size=64, n_positions=16, n_embd=128, n_layer=2, n_head=4,
+        norm="rmsnorm", activation="silu", use_bias=False, rotary=True,
+        learned_positions=False, dtype=jnp.float32, param_dtype=jnp.float32,
+        scan_layers=True, use_flash_attention=False, moe_num_experts=4,
+        moe_top_k=4, moe_drop_tokens=False, moe_gated_experts=True,
+        moe_intermediate_size=128)
+    base.update(changes)
+    return GPT(GPTConfig(**base))
+
+
+IDS = np.arange(16, dtype=np.int32).reshape(2, 8) % 64
+# name: (mesh axes, changes to the configuration, a serving call?, the
+# rule's answer)
+MATRICES = {
+    "serving_under_the_scan": (dict(), dict(), True, "in_place"),
+    "serving_bf16": (dict(), dict(dtype=jnp.bfloat16,
+                                  param_dtype=jnp.bfloat16), True,
+                     "in_place"),
+    "training_forward": (dict(), dict(), False, "slice"),
+    "under_ep": (dict(ep=2), dict(), True, "slice"),
+    "under_tp": (dict(tp=2), dict(), True, "slice"),
+    "widths_off_128": (dict(), dict(n_embd=64), True, "slice"),
+    "stored_wider_than_computed": (dict(), dict(dtype=jnp.bfloat16), True,
+                                   "slice"),
+    "layers_looped_over": (dict(), dict(scan_layers=False), True, "slice"),
+    "with_a_capacity": (dict(), dict(moe_top_k=1, moe_drop_tokens=True),
+                        True, "slice"),
+    "no_experts": (dict(), dict(moe_num_experts=0), True, "none"),
+}
+
+
+def gmm_routes(monkeypatch):
+    """What the traces to come hand ``gmm``: True for a stack and a layer,
+    False for one layer's matrices."""
+    routes, real = [], gm.gmm
+
+    def spy(lhs, rhs, *args, **kwargs):
+        routes.append(kwargs.get("layer") is not None)
+        assert rhs.ndim == 3 + routes[-1]
+        return real(lhs, rhs, *args, **kwargs)
+
+    monkeypatch.setattr(gm, "gmm", spy)
+    return routes
+
+
+@pytest.mark.parametrize("name", MATRICES)
+def test_where_the_matrices_are_read_is_told_from_what_the_call_shows(
+        topology, monkeypatch, name):
+    """The rule's answer, the route the model then traces, and the
+    layers' ``in_place`` counter agree."""
+    from flax.traverse_util import flatten_dict
+
+    from deepspeed_tpu.moe.layer import MOE_STATS
+
+    axes, changes, decode, want = MATRICES[name]
+    topology(**axes)
+    model = moe_gpt(**changes)
+    cfg = model.config
+    rows = IDS.size * cfg.moe_top_k
+    assert experts_mod.expert_matrices(cfg, rows, decode=decode) == want
+    params = model.init(jax.random.PRNGKey(0), IDS)["params"]
+    routes = gmm_routes(monkeypatch)
+    _, out = model.apply({"params": params}, IDS, decode=decode,
+                         mutable=["cache", MOE_STATS])
+    tiles = experts_mod.grouped_matmul_tiles(
+        rows, cfg.n_embd, cfg.moe_ffn_dim, max(cfg.moe_num_experts, 1),
+        cfg.dtype)
+    dropless = cfg.is_moe and not cfg.moe_drop_tokens
+    assert bool(routes) == bool(dropless and tiles)
+    assert set(routes) <= {want == "in_place"}
+    counted = [np.asarray(value).reshape(-1).tolist()
+               for path, (value,) in flatten_dict(
+                   out.get(MOE_STATS, {})).items() if path[-1] == "in_place"]
+    assert sum(counted, []) == (
+        [int(want == "in_place")] * 2 if dropless else [])
+
+
+def test_a_differentiated_forward_and_init_keep_the_slice(monkeypatch):
+    """``jax.grad`` of a training forward goes through the custom VJP on
+    the scan's slices (a stack read in place would want a cotangent the
+    size of the stack a layer); ``init`` traces no kernel at all, serving
+    call or not."""
+    model = moe_gpt()
+    routes = gmm_routes(monkeypatch)
+    params = model.init(jax.random.PRNGKey(0), IDS, decode=True)["params"]
+    assert not routes
+    grads = jax.grad(lambda p: model.apply(
+        {"params": p}, IDS, labels=IDS))(params)
+    assert routes and not any(routes)
+    stacked = grads["h"]["block"]["mlp"]["experts"]
+    assert all(np.isfinite(np.asarray(g)).all() and np.asarray(g).any()
+               for g in stacked.values())
+
+
+@pytest.mark.parametrize("stored", ["int8_at_rest", "offloaded", "gathered"])
+def test_a_stack_that_is_not_what_the_layer_multiplies_by_keeps_the_slice(
+        stored, monkeypatch):
+    from deepspeed_tpu.runtime.zero import gather
+
+    changes = {"int8_at_rest": dict(quantized_weights=True),
+               "offloaded": dict(param_offload=True), "gathered": dict()}
+    cfg = moe_gpt(**changes[stored]).config
+    assert experts_mod.expert_matrices(moe_gpt().config, 64, decode=True) \
+        == "in_place"
+    if stored == "gathered":
+        monkeypatch.setattr(gather, "current_plan", lambda: object())
+    assert experts_mod.expert_matrices(cfg, 64, decode=True) == "slice"
+
+
+def test_a_tree_in_another_dtype_than_declared_keeps_the_slice(monkeypatch):
+    """The configuration says bf16 parameters, the caller hands float32
+    ones: the layer casts its slice, as it did."""
+    model = moe_gpt(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    params = model.init(jax.random.PRNGKey(0), IDS)["params"]
+    routes = gmm_routes(monkeypatch)
+    model.apply({"params": jax.tree.map(
+        lambda x: x.astype(jnp.float32), params)}, IDS, decode=True,
+        mutable=["cache"])
+    assert routes and not any(routes)
+
+
 def test_no_field_or_variable_chooses_it():
-    import dataclasses
     import inspect
+
+    from deepspeed_tpu.models.transformer_lm import GPTConfig
 
     fields = {f.name for f in dataclasses.fields(StackedExperts)}
     assert fields == {"num_experts", "d_model", "d_hidden", "dtype",
@@ -261,6 +526,14 @@ def test_no_field_or_variable_chooses_it():
                       "parent", "name"}
     for module in (experts_mod, gm):
         assert "environ" not in inspect.getsource(module)
+    # where the matrices are read is asked with the model's configuration,
+    # the rows and whether the call serves, and nothing of the
+    # configuration names the answer
+    assert list(inspect.signature(experts_mod.expert_matrices).parameters) \
+        == ["cfg", "rows", "decode"]
+    assert not {f.name for f in dataclasses.fields(GPTConfig)
+                if "in_place" in f.name or "stacked" in f.name
+                or "expert_matrices" in f.name}
 
 
 # --- the tiles --------------------------------------------------------------
@@ -312,11 +585,24 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def compiled_for_the_chip(fn, *args):
+    """The compiled program's text, with JAX's persistent cache off (an
+    executable for a described chip can be written to it and not read)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return fn.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize("call", CELL_CALLS)
 def test_the_cells_calls_compile_for_the_chip_and_keep_their_name(
         one_chip, call, monkeypatch):
-    from jax.experimental.compilation_cache import compilation_cache
-
     kind, k, n = CELL_CALLS[call]
     rows, groups = CELL["rows"], CELL["groups"]
     tiles = autotune.GMM_PRETUNED[kind, rows, k, n, groups, "bfloat16",
@@ -337,14 +623,7 @@ def test_the_cells_calls_compile_for_the_chip_and_keep_their_name(
         args = (spec(rows, k),
                 spec(groups, n, k) if kind == "gmm_t" else spec(groups, k, n),
                 spec(groups, dtype=jnp.int32))
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(run).lower(*args).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
+    text = compiled_for_the_chip(jax.jit(run), *args)
     name = gm.TGMM_NAME if kind == "tgmm" else gm.GMM_NAME
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "custom-call(" in line]
@@ -352,3 +631,36 @@ def test_the_cells_calls_compile_for_the_chip_and_keep_their_name(
     # no copy of an operand: neither a transposed expert tensor nor rows
     assert not [line for line in text.splitlines() if "bf16[" in line
                 and (" transpose(" in line or " copy(" in line)]
+
+
+@pytest.mark.parametrize("route", ["in_place", "slice"])
+def test_the_scan_over_the_stack_compiles_to_no_copy_of_a_layers_matrices(
+        one_chip, route, monkeypatch):
+    """Scanned over slices, the loop's body writes the turn's ``[E, K, N]``
+    out before the custom call may read it (the control: the test sees the
+    copy, under the name the v5e's trace gave it); handed the stack and the
+    turn's index it holds the one call and no such result."""
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
+    layers, groups, k, n, rows = 4, 20, 512, 256, 256
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = compiled_for_the_chip(
+        scanned_layers(route == "in_place", walk_rows=128),
+        spec(rows, k), spec(layers, groups, k, n),
+        spec(groups, dtype=jnp.int32))
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line]
+    assert len(calls) == 1 and f"%{gm.GMM_NAME}" in calls[0]
+    whole_layer = [line.split("=")[0].strip() for line in text.splitlines()
+                   if f"= bf16[{groups},{k},{n}]" in line
+                   and " parameter(" not in line
+                   and " get-tuple-element(" not in line]
+    if route == "in_place":
+        assert not whole_layer
+        # the stack rides the loop as it came in
+        assert f"bf16[{layers},{groups},{k},{n}]" in calls[0]
+    else:
+        assert any(name.startswith("%dynamic-slice") and "fusion" in name
+                   for name in whole_layer)

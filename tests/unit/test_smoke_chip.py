@@ -42,12 +42,12 @@ def test_phase_kernels_tiny_interpret():
         splash=(256, 32, 2, 16),
         decode_shapes=((2, 3, 256, 4, 4, 16), (1, 2, 256, 2, 10, 16)),
         retention_shape=(2, 3, 2, 4, 8), ssd_shape=(2, 3, 4, 2, 8, 16),
-        gmm_shape=(256, 128, 256, 4),
+        gmm_shape=(256, 128, 256, 4), gmm_stack_shape=(2, 64, 128, 128, 4),
         latent_shape=(3, 64, 32, 4, 24, 16, 8, 4, 8),
         held_experts_shape=(32, 32, 16, 16, (2, 2)),
         latent_kernel_shape=(2, 4, 328, 4, 16, 4), dtype=jnp.float32,
         strict=False)
-    assert out["ok"] and out["interpret"] and out["n_checks"] == 12
+    assert out["ok"] and out["interpret"] and out["n_checks"] == 13
 
 
 def test_phase_kernels_strict_refuses_interpret_mode():
