@@ -1,12 +1,11 @@
-"""Shape-tuned (block_q, block_k) selection for the flash-attention kernel.
+"""Shape-tuned schedule selection for the flash-attention kernels.
 
 ``largest_divisor_block``'s fixed ``want`` heuristic picks the largest
-divisor of the sequence length — shape-blind: for CAUSAL attention the
-kernel skips fully-masked K blocks (``nk_eff`` pruning in
-``flash_attention.py``), so a smaller ``block_k`` does strictly less work
-per q-row, while a larger ``block_q`` amortizes grid overhead. The best
-trade depends on (seq, head_dim, dtype, device) — exactly what a fixed
-default cannot know.
+divisor of the sequence length — shape-blind. What a kernel wants depends
+on (seq, head_dim, dtype, device): on the v5e a strip of the whole
+sequence a program (a static schedule, no loop) beats every smaller block
+at 1,024 and at 4,096 positions, and each of the three kernels has its own
+best blocks and granule (``flash_attention.KernelBlocks``).
 
 Resolution order for :func:`get_flash_blocks` (first hit wins):
 
@@ -17,18 +16,24 @@ Resolution order for :func:`get_flash_blocks` (first hit wins):
    keyed by ``device_kind|seq|head_dim|dtype|causal``; written by a
    previous autotune run. A corrupt/unreadable file falls through (warn
    once) and is overwritten by the next tuned write.
-3. shipped pretuned table (:data:`PRETUNED`) — seeds for the shapes the
-   1.3B benchmark config hits, derived from the kernel's VMEM/pruning
-   model (docs/performance.md); refreshed in place by live autotunes.
+3. shipped pretuned table (:data:`PRETUNED`: each kernel's blocks and
+   granule) — the v5e's bf16 entries at 1,024 and 4,096 positions (the
+   1.3B and OLMoE benchmark configs' shapes) are measured on the v5e,
+   PR 45 (``benchmarks/flash_sweep.py --kernels``; PERF.md section 6);
+   every other entry is a seed never run on its chip.
 4. live benchmark at the actual shape, IF enabled (``autotune=True`` or
    ``DS_TPU_FLASH_AUTOTUNE=1``): times the jitted fwd+bwd over a
    divisor-filtered candidate grid and persists the winner to (2).
 5. the ``largest_divisor_block`` heuristic — today's default, unchanged.
 
-Every cached/pretuned entry is re-validated against the current shape
-(divisibility) before use, so a stale or hand-edited cache can never
-produce an invalid launch. Default-safe: with no cache, no pretuned hit,
-and autotuning off, behavior is identical to the old fixed default.
+:func:`get_flash_schedule` is what ``flash_attention`` asks: each
+kernel's ``(block_q, block_k, granule)`` by that order (a pair from the
+disk cache, the live benchmark or the heuristic goes to all three, their
+granules left to the fitting); :func:`get_flash_blocks` is its forward
+pair. Every cached/pretuned entry is re-validated against the current
+shape (divisibility) before use, and ``flash_attention.fit_blocks`` makes
+the shapes of whatever comes out a valid launch, so a stale or hand-edited
+cache can never produce an invalid one.
 """
 
 import json
@@ -47,28 +52,39 @@ _CACHE_ENV = "DS_TPU_PALLAS_CACHE"
 _AUTOTUNE_ENV = "DS_TPU_FLASH_AUTOTUNE"
 _DEFAULT_WANT = 512  # flash_attention's historical fixed block default
 
-# (device_kind, seq, head_dim, dtype, causal) -> (block_q, block_k).
-# Seeds for the 1.3B/seq-1024 shape (n_embd=2048 / 16 heads -> d=128):
-# causal entries keep block_k at seq/4 so the kernel's nk_eff pruning
-# skips ~ the upper-triangle (block_k=seq would always compute the full
-# square), and block_q at seq/2 to halve grid launches. A live autotune
-# (DS_TPU_FLASH_AUTOTUNE=1) overwrites these via the disk cache.
-PRETUNED: Dict[Tuple[str, int, int, str, bool], Tuple[int, int]] = {}
+# one kernel's (block_q, block_k, granule); granule None: the fitting's
+Blocks = Tuple[int, int, Optional[int]]
+
+# (device_kind, seq, head_dim, dtype, causal) -> the three kernels' blocks,
+# in ``flash_attention.KERNELS``' order: forward, dQ, dK/dV.
+# Seeds for the 1.3B/seq-1024 shape (n_embd=2048 / 16 heads -> d=128),
+# never run on their chips: block_k at seq/4, block_q at seq/2 up to 2048,
+# (512, 512) past it. The v5e's bf16 entries below are measured.
+PRETUNED: Dict[Tuple[str, int, int, str, bool], Tuple[Blocks, ...]] = {}
 for _kind in ("TPU v4", "TPU v5 lite", "TPU v5e", "TPU v5p", "TPU v6 lite",
               "TPU v6e"):
     for _dt in ("bfloat16", "float32"):
-        PRETUNED[(_kind, 1024, 128, _dt, True)] = (512, 256)
-        PRETUNED[(_kind, 2048, 128, _dt, True)] = (512, 256)
-        # Long-context seeds: past 2048 the inner k loop dominates the
-        # grid, so block_k doubles to 512 to halve k iterations (a
-        # 512x128 k/v tile is 128 KiB in bf16 — q, k, v, o plus the
-        # f32 acc/lse scratch stay well under the ~16 MiB VMEM budget)
-        # while block_q holds at 512: q tiles scale launches, not reuse.
-        PRETUNED[(_kind, 4096, 128, _dt, True)] = (512, 512)
-        PRETUNED[(_kind, 8192, 128, _dt, True)] = (512, 512)
+        for _t, _pair in ((1024, (512, 256)), (2048, (512, 256)),
+                          (4096, (512, 512)), (8192, (512, 512))):
+            PRETUNED[(_kind, _t, 128, _dt, True)] = ((*_pair, None),) * 3
+
+# Measured on the v5e, PR 45 (``benchmarks/flash_sweep.py --kernels``, each
+# kernel alone at 96 heads of 1,024 and 32 of 4,096 positions; PERF.md
+# section 6 has the tables). A strip of the WHOLE sequence wins at both
+# lengths: its schedule is static (no loop whose trip count a program id
+# decides) and every slice's tile is as wide as its rows see; dK/dV at
+# 4,096 is best at strips of 2,048 keys. Granule 256 at 1,024 (1.25 of the
+# causal half computed; 128 computes 1.125 and is 10-40% slower in the
+# forward and dK/dV), 512 at 4,096 (within 1-5% of 256's time at half the
+# slices to trace and lower: ``setup_s``). 2,048 stays a seed: whole strips
+# read faster there too, kernel alone, and no cell runs that length.
+for _kind in ("TPU v5 lite", "TPU v5e"):
+    for _t, _g, _keys in ((1024, 256, 1024), (4096, 512, 2048)):
+        PRETUNED[(_kind, _t, 128, "bfloat16", True)] = (
+            (_t, 512, _g), (_t, 512, _g), (512, _keys, _g))
 
 _lock = threading.Lock()
-_mem_cache: Dict[str, Tuple[int, int]] = {}
+_mem_cache: Dict[str, Tuple[Tuple[Blocks, ...], str]] = {}
 _disk_warned = False
 
 
@@ -128,6 +144,10 @@ def _valid(blocks, t: int) -> Optional[Tuple[int, int]]:
     return bq, bk
 
 
+def _all_three(pair: Tuple[int, int]) -> Tuple[Blocks, ...]:
+    return ((pair[0], pair[1], None),) * 3
+
+
 def default_candidates(t: int) -> List[Tuple[int, int]]:
     """Divisor-filtered (block_q, block_k) grid around the MXU-friendly
     power-of-two sizes, bounded so the f32 score tile stays well under a
@@ -185,19 +205,13 @@ def benchmark_candidates(t: int, d: int, dtype, causal: bool,
     return best
 
 
-def get_flash_blocks(t: int, d: int, dtype, causal: bool, *,
-                     want_q: int = _DEFAULT_WANT,
-                     want_k: int = _DEFAULT_WANT,
-                     autotune: Optional[bool] = None,
-                     candidates: Optional[List[Tuple[int, int]]] = None
-                     ) -> Tuple[int, int]:
-    """Resolve (block_q, block_k) for a flash-attention launch.
-
-    ``autotune=None`` defers to the ``DS_TPU_FLASH_AUTOTUNE`` env flag;
-    ``candidates`` overrides the benchmark grid (tests use tiny ones).
-    """
-    heuristic = (largest_divisor_block(t, want_q),
-                 largest_divisor_block(t, want_k))
+def _resolve(t: int, d: int, dtype, causal: bool, *,
+             want_q: int = _DEFAULT_WANT, want_k: int = _DEFAULT_WANT,
+             autotune: Optional[bool] = None,
+             candidates: Optional[List[Tuple[int, int]]] = None
+             ) -> Tuple[Tuple[Blocks, ...], str]:
+    """The three kernels' blocks and the step of the resolution order that
+    gave them: ``disk``, ``pretuned``, ``autotuned`` or ``heuristic``."""
     device_kind = jax.devices()[0].device_kind
     key = cache_key(device_kind, t, d, dtype, causal)
 
@@ -207,24 +221,24 @@ def get_flash_blocks(t: int, d: int, dtype, causal: bool, *,
             return hit
         entry = _valid(_load_disk_cache().get(key), t)
         if entry is not None:
-            _mem_cache[key] = entry
-            return entry
-        pre = _valid(PRETUNED.get(
-            (device_kind, int(t), int(d), jnp.dtype(dtype).name,
-             bool(causal))), t)
-        if pre is not None:
-            _mem_cache[key] = pre
-            return pre
+            _mem_cache[key] = (_all_three(entry), "disk")
+            return _mem_cache[key]
+        pre = PRETUNED.get((device_kind, int(t), int(d),
+                            jnp.dtype(dtype).name, bool(causal)), ())
+        if pre and all(_valid(blocks, t) for blocks in pre):
+            _mem_cache[key] = (pre, "pretuned")
+            return _mem_cache[key]
 
     if autotune is None:
         autotune = os.environ.get(_AUTOTUNE_ENV, "0") not in ("", "0")
     if not autotune:
-        return heuristic
+        return _all_three((largest_divisor_block(t, want_q),
+                           largest_divisor_block(t, want_k))), "heuristic"
 
     tuned = benchmark_candidates(
         t, d, dtype, causal, candidates or default_candidates(t))
     with _lock:
-        _mem_cache[key] = tuned
+        _mem_cache[key] = (_all_three(tuned), "autotuned")
         try:
             _store_disk_cache(key, tuned)
         except OSError as e:
@@ -232,7 +246,34 @@ def get_flash_blocks(t: int, d: int, dtype, causal: bool, *,
                 f"flash autotune: could not persist winner to "
                 f"{cache_path()!r} ({e}); it stays in-memory for this "
                 "process", RuntimeWarning)
-    return tuned
+    return _mem_cache[key]
+
+
+def get_flash_blocks(t: int, d: int, dtype, causal: bool, *,
+                     want_q: int = _DEFAULT_WANT,
+                     want_k: int = _DEFAULT_WANT,
+                     autotune: Optional[bool] = None,
+                     candidates: Optional[List[Tuple[int, int]]] = None
+                     ) -> Tuple[int, int]:
+    """Resolve the forward's (block_q, block_k) for a flash-attention
+    launch.
+
+    ``autotune=None`` defers to the ``DS_TPU_FLASH_AUTOTUNE`` env flag;
+    ``candidates`` overrides the benchmark grid (tests use tiny ones).
+    """
+    return _resolve(t, d, dtype, causal, want_q=want_q, want_k=want_k,
+                    autotune=autotune, candidates=candidates)[0][0][:2]
+
+
+def get_flash_schedule(t: int, d: int, dtype, causal: bool, *,
+                       autotune: Optional[bool] = None):
+    """What each of the three kernels wants at this shape, and the source:
+    ``{kernel: (block_q, block_k, granule)}`` (granule ``None`` where the
+    kernel's own fitting decides)."""
+    from deepspeed_tpu.ops.pallas.flash_attention import KERNELS
+
+    wanted, source = _resolve(t, d, dtype, causal, autotune=autotune)
+    return dict(zip(KERNELS, wanted)), source
 
 
 def clear_memory_cache() -> None:

@@ -11,6 +11,25 @@ blocks from VMEM with running max/sum rescaling. The backward pass is the
 standard two-kernel recompute formulation (dq; then dk/dv) using the saved
 logsumexp — O(T) memory like the forward.
 
+Schedule (:class:`KernelBlocks`, one per kernel). A program's strip of
+scores (``block_q`` rows by all keys; for dK/dV ``block_k`` keys by all
+rows) falls into tiles wholly under the causal diagonal, which a loop walks
+with no mask arithmetic at all, and the square the diagonal crosses, which
+is cut into ``granule``-wide slices that stop at the diagonal: only the
+last ``granule x granule`` corner of each slice is masked, and nothing
+above the diagonal but those corners' halves is computed. ``scale`` is
+folded into q (dK/dV: into k) once a program, and into dq / dk once after
+the loops. The fold costs one rounding: the scaled operand goes back to the
+input dtype for the matrix unit, so where ``scale`` is no power of two
+(head 128: 128 ** -0.5) every score carries one bf16 rounding more than
+``scale * dot`` in float32 gave it. On the v5e the results stand 0.0030-
+0.0037 of their norm from the kernels before PR 45 (lse: 0.005 at most) and
+0.0023-0.0032 from float32 attention at 1,024 positions, where those stood
+0.0019-0.0026 (PERF.md section 6, PR 45); ``chip_smoke.py`` holds them to
+twice that. A strip of the whole sequence has no loop at all: on the v5e
+that static schedule is what the kernels gain most from (PERF.md section
+6, PR 45).
+
 On non-TPU backends the kernels run in Pallas interpreter mode, so the CPU
 test mesh exercises the exact same code path.
 
@@ -25,10 +44,9 @@ to match TPU tiling with no in-kernel transpose:
   give the query-side segment id column;
 * ``seg_c [bh, LSE_LANES, t]`` — column layout: the key-side segment id
   row. The dkv kernel takes its own k block of it through a BlockSpec; the
-  fwd and dq kernels, which walk the k blocks in a loop, get it regrouped
-  as ``[bh, t/block_k, LSE_LANES, block_k]`` and index the block on the
-  leading axis (Mosaic lowers no ``dynamic_slice`` of a value, and a
-  leading-axis ref index needs no lane alignment proof).
+  fwd and dq kernels, which walk the keys tile by tile, slice the whole
+  row's ref along lanes (Mosaic lowers that for offsets it can prove
+  128-aligned: ``fit_blocks(lane_aligned=True)`` keeps them so).
 
 Masking uses the same finite ``NEG_INF`` as the causal path: a masked
 score contributes ``exp(-1e30) == 0.0`` exactly to both softmax and its
@@ -37,11 +55,14 @@ still see their own diagonal so no row is ever fully masked.
 """
 
 import functools
+import math
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas.common import (
     LSE_LANES,
@@ -49,6 +70,7 @@ from deepspeed_tpu.ops.pallas.common import (
     interpret as _interpret,
     largest_divisor_block as _block,
 )
+from deepspeed_tpu.telemetry.bus import KIND_FLASH_PLAN, publish
 
 # the kernels' names in a profiler trace and in the lowered HLO
 # (``kernel_name`` of the tpu_custom_call): a reader tells forward from
@@ -56,308 +78,453 @@ from deepspeed_tpu.ops.pallas.common import (
 KERNEL_FWD = "flash_fwd"
 KERNEL_BWD_DQ = "flash_bwd_dq"
 KERNEL_BWD_DKV = "flash_bwd_dkv"
+KERNELS = (KERNEL_FWD, KERNEL_BWD_DQ, KERNEL_BWD_DKV)
+
+# past this much of blocks and tiles a call asks for more VMEM than the
+# 16 MB a kernel may use unasked (a whole strip of 4,096 positions does)
+_VMEM_UNASKED = 12 << 20
+_VMEM_V5E = 128 << 20
+
+
+def _vmem_asked() -> int:
+    """Three quarters of the chip's VMEM: 96 MiB on the v5e, what the
+    table's whole strips were measured under. A host with no TPU (a
+    compile for a described chip) asks as the v5e does."""
+    try:
+        capacity = pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:  # no TPU here, or one Pallas has no entry for
+        capacity = _VMEM_V5E
+    return capacity * 3 // 4
+
+
+class KernelBlocks(NamedTuple):
+    """One kernel's schedule: the tile, and the width of the slices the
+    diagonal square is cut into."""
+    block_q: int
+    block_k: int
+    granule: int
+
+    def strip(self, kernel: str) -> int:
+        """The block a program of ``kernel`` owns (the other is its loop's
+        tile): ``block_q`` rows, for dK/dV ``block_k`` keys."""
+        return self.block_k if kernel == KERNEL_BWD_DKV else self.block_q
+
+
+def _compiler_params(kernel, t, d, itemsize, blocks):
+    """None, or the VMEM limit raised (to :func:`_vmem_asked`; blocks that
+    need more than the chip has are the compiler's to refuse) where the
+    blocks and six float32 temporaries of the largest tile need more than
+    a kernel gets unasked.
+    The blocks, double-buffered: the whole-sequence operands (K, V; for
+    dK/dV q, do and the lse and delta rows, whose 8 lanes lie padded to
+    128) and the strip's own (q, o and lse; q, do, dq and two rows; k, v,
+    dk, dv)."""
+    block_q, block_k, granule = blocks
+    strip = blocks.strip(kernel)
+    row, lanes = d * itemsize, 128 * 4
+    need = 2 * {
+        KERNEL_FWD: 2 * t * row + strip * (2 * row + lanes),
+        KERNEL_BWD_DQ: 2 * t * row + strip * (3 * row + 2 * lanes),
+        KERNEL_BWD_DKV: 2 * t * (row + lanes) + strip * 4 * row}[kernel]
+    need += 24 * strip * max(
+        granule, 0 if strip == t else block_q + block_k - strip)
+    if need <= _VMEM_UNASKED:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=_vmem_asked())
+
+
+def fit_blocks(kernel: str, t: int, causal: bool, block_q: int, block_k: int,
+               granule: Optional[int] = None,
+               lane_aligned: bool = False) -> KernelBlocks:
+    """``wanted`` made a launch of ``kernel`` whose shapes are valid (its
+    VMEM is :func:`_compiler_params`'s to ask for): blocks that divide
+    ``t``; under the causal mask the loop's tile dividing the program's
+    strip (so the diagonal square is the strip's own); a granule dividing
+    the strip (256 or 128 where one divides it, else the strip whole: on
+    the v5e slices of 128 leave least above the diagonal and feed the
+    matrix unit worst, PERF.md section 6, PR 45). ``lane_aligned`` (segment
+    ids: the key side's row of them is sliced along lanes) keeps blocks of
+    a 128-aligned ``t`` multiples of 128."""
+    block_q, block_k = _block(t, block_q), _block(t, block_k)
+    if lane_aligned and t % 128 == 0:
+        block_q, block_k = (
+            b if b % 128 == 0 else 128 * _block(t // 128, max(1, b // 128))
+            for b in (block_q, block_k))
+    dkv = kernel == KERNEL_BWD_DKV
+    if causal:
+        if dkv and block_k % block_q:
+            block_q = math.gcd(block_q, block_k)
+        elif not dkv and block_q % block_k:
+            block_k = math.gcd(block_q, block_k)
+    strip = block_k if dkv else block_q
+    if not granule or strip % granule:
+        granule = next((g for g in (256, 128) if strip % g == 0), strip)
+    return KernelBlocks(block_q, block_k, granule)
+
+
+def tile_counts(kernel: str, t: int, causal: bool,
+                blocks: KernelBlocks) -> Dict[str, float]:
+    """Scores one head computes, needs (the causal half) and runs mask
+    arithmetic on, in tiles of ``block_q x block_k``."""
+    bq, bk, g = blocks
+    tile = float(bq * bk)
+    if not causal:
+        n = t * t / tile
+        return {"tiles_computed": n, "tiles_needed": n, "tiles_masked": 0.0}
+    strip = blocks.strip(kernel)
+    step = bq + bk - strip
+    n = t // strip
+    interior = (strip // step) * n * (n - 1) / 2
+    r = strip // g
+    return {"tiles_computed": interior + n * g * g * r * (r + 1) / 2 / tile,
+            "tiles_needed": t * t / 2 / tile,
+            "tiles_masked": n * r * g * g / tile}
+
+
+def _scaled(x, scale):
+    """``x * scale`` in float32, back in x's dtype: once a program."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _lower_triangle(g):
+    """``[g, g]`` bool: row i of a diagonal corner sees its column j."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (g, g), 0)
+            >= jax.lax.broadcasted_iota(jnp.int32, (g, g), 1))
+
+
+def _mask_corner(s, keep, axis):
+    """Mask the corner of a slice that the diagonal crosses: its last
+    ``keep.shape[1]`` columns (``axis`` 1) or first ``keep.shape[0]`` rows
+    (``axis`` 0). ``keep`` None: an interior tile, left as it is."""
+    if keep is None:
+        return s
+    if keep.shape == s.shape:
+        return jnp.where(keep, s, NEG_INF)
+    if axis == 1:
+        w = s.shape[1] - keep.shape[1]
+        return jnp.concatenate(
+            [s[:, :w], jnp.where(keep, s[:, w:], NEG_INF)], axis=1)
+    g = keep.shape[0]
+    return jnp.concatenate(
+        [jnp.where(keep, s[:g], NEG_INF), s[g:]], axis=0)
+
+
+def _walk(n, body, init):
+    """The loop over a strip's interior tiles; ``n`` None: a strip of the
+    whole sequence under the causal mask has none, and no loop is emitted
+    (its body's tile would still claim VMEM)."""
+    return init if n is None else jax.lax.fori_loop(0, n, body, init)
+
+
+def interior_tiles(kernel: str, t: int, causal: bool, blocks: KernelBlocks,
+                   start):
+    """``(first, n)``: the tiles a strip's loop walks with no mask, ``n``
+    of the loop's tile from position ``first`` of the loop's axis on, for
+    the strip that starts at ``start`` (a program's offset, or a Python
+    int). Under the causal mask they are the keys before the strip's own
+    square (forward, dQ) or the rows after it (dK/dV); ``n`` None: a strip
+    of the whole sequence has none, and its kernel no loop."""
+    strip = blocks.strip(kernel)
+    tile = blocks.block_q + blocks.block_k - strip
+    if not causal:
+        return 0, t // tile
+    if strip == t:
+        return 0, None
+    if kernel == KERNEL_BWD_DKV:
+        first = start + strip
+        return first, (t - first) // tile
+    return 0, start // tile
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
-                has_seg=False):
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, blocks, has_seg):
     if has_seg:
         sq_ref, sk_ref, o_ref, lse_ref = rest
     else:
         o_ref, lse_ref = rest
-    bq, d = q_ref.shape
-    t = k_ref.shape[0]
-    nk = t // block_k
-    qi = pl.program_id(1)
+    bq, block_k, granule = blocks
+    t, d = k_ref.shape
+    q0 = pl.program_id(1) * bq
+    # tiles wholly under the diagonal (all of them without a mask)
+    _, n_interior = interior_tiles(KERNEL_FWD, t, causal, blocks, q0)
 
     # keep MXU operands in the input dtype (bf16): f32xf32 dots fall off the
-    # systolic array's fast path; accumulate in f32 via preferred_element_type
-    q = q_ref[...]  # [bq, d]
-    m = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((bq, 1), jnp.float32)
-    acc = jnp.zeros((bq, d), jnp.float32)
-
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
+    # systolic array's fast path; accumulate in f32
+    q = _scaled(q_ref[...], scale)  # [bq, d]
     if has_seg:
         q_seg = sq_ref[...][:, :1]  # [bq, 1]
 
-    def body(j, carry):
+    def tile(rows, carry, k0, width, keep):
         m, l, acc = carry
-        k_blk = k_ref[pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[pl.ds(j * block_k, block_k), :]
-        s = scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bq, block_k]
-        if causal:
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        k_blk = k_ref[pl.ds(k0, width), :]
+        v_blk = v_ref[pl.ds(k0, width), :]
+        s = _mask_corner(_dot(q[rows], k_blk, (1, 1)), keep, 1)
         if has_seg:
-            k_seg = sk_ref[j][:1, :]  # [1, block_k]
-            s = jnp.where(q_seg == k_seg, s, NEG_INF)
+            k_seg = sk_ref[:1, pl.ds(k0, width)]  # [1, width]
+            s = jnp.where(q_seg[rows] == k_seg, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_new = acc * alpha + _dot(p.astype(v_blk.dtype), v_blk, (1, 0))
         return m_new, l_new, acc_new
 
-    if causal:
-        # only blocks with k_start <= q_end contribute
-        nk_eff = jnp.minimum((qi * bq + bq + block_k - 1) // block_k, nk)
-    else:
-        nk_eff = nk
-    m, l, acc = jax.lax.fori_loop(0, nk_eff, body, (m, l, acc))
+    def interior(j, carry):
+        k0 = pl.multiple_of(j * block_k, block_k)
+        return tile(slice(None), carry, k0, block_k, None)
 
-    o_ref[...] = (acc / l).astype(o_ref.dtype)
-    # lse carries 8 broadcast sublane copies to satisfy TPU tiling
-    lse_ref[...] = jnp.broadcast_to(m + jnp.log(l), (bq, LSE_LANES))
+    def finish(rows, carry):
+        m, l, acc = carry
+        o_ref[rows, :] = (acc / l).astype(o_ref.dtype)
+        # lse carries 8 broadcast sublane copies to satisfy TPU tiling
+        lse_ref[rows, :] = jnp.broadcast_to(m + jnp.log(l),
+                                            (m.shape[0], LSE_LANES))
+
+    carry = _walk(n_interior, interior,
+                  (jnp.full((bq, 1), NEG_INF, jnp.float32),
+                   jnp.zeros((bq, 1), jnp.float32),
+                   jnp.zeros((bq, d), jnp.float32)))
+    if not causal:
+        finish(slice(None), carry)
+        return
+    # the diagonal square: slice r's rows see the square's columns up to
+    # their own corner, and are done after it
+    corner = _lower_triangle(granule)
+    for r in range(bq // granule):
+        rows = slice(r * granule, (r + 1) * granule)
+        finish(rows, tile(rows, tuple(x[rows] for x in carry), q0,
+                          (r + 1) * granule, corner))
 
 
-def _seg_by_k_block(seg_c, block_k):
-    """``[bh, LSE_LANES, t]`` -> ``[bh, t/block_k, LSE_LANES, block_k]``
-    with its whole-array BlockSpec (see the module docstring)."""
-    bh, lanes, t = seg_c.shape
-    nk = t // block_k
-    grouped = seg_c.reshape(bh, lanes, nk, block_k).transpose(0, 2, 1, 3)
-    return grouped, pl.BlockSpec((None, nk, lanes, block_k),
-                                 lambda i, j: (i, 0, 0, 0))
+def _specs(block, d, t):
+    """BlockSpecs of one kernel: a strip's block of a ``[bh, t, d]`` tensor,
+    a whole one, the same two of a ``[bh, t, LSE_LANES]`` row tensor, and
+    a whole ``[bh, LSE_LANES, t]`` column tensor."""
+    return (pl.BlockSpec((None, block, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, block, LSE_LANES), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, t, LSE_LANES), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, LSE_LANES, t), lambda i, j: (i, 0, 0)))
 
 
-def _fwd(q, k, v, seg, scale, causal, block_q, block_k):
-    b, t, h, d = q.shape
-    bh = b * h
-    qf = q.transpose(0, 2, 1, 3).reshape(bh, t, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(bh, t, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(bh, t, d)
-    nq = t // block_q
+# jitted: a step program traces each call a dozen times (forward, recomputed
+# forward, backward, several programs), and a whole strip's unrolled slices
+# are hundreds of operations to trace; jit's cache hands the later traces
+# the first one's jaxpr (``setup_s``)
+# (``interpret`` is among the keys: what ran interpreted on the CPU is not
+# what a compile for a described chip may be handed)
+_jit_call = functools.partial(
+    jax.jit, static_argnames=("scale", "causal", "blocks", "interpret"))
 
-    in_specs = [
-        pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0)),
-    ]
-    operands = [qf, kf, vf]
-    if seg is not None:
-        seg_r, seg_c = seg
-        seg_ck, seg_ck_spec = _seg_by_k_block(seg_c, block_k)
-        in_specs += [
-            pl.BlockSpec((None, block_q, LSE_LANES), lambda i, j: (i, j, 0)),
-            seg_ck_spec,
-        ]
-        operands += [seg_r, seg_ck]
 
-    o, lse = pl.pallas_call(
+@_jit_call
+def _call_fwd(qf, kf, vf, seg, scale, causal, blocks, interpret=None):
+    """``flash_fwd`` on ``[bh, t, d]`` operands: ``(o, lse)``."""
+    bh, t, d = qf.shape
+    interpret = _interpret() if interpret is None else interpret
+    strip, whole, strip_rows, _, whole_cols = _specs(blocks.block_q, d, t)
+    return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          block_k=block_k, has_seg=seg is not None),
-        grid=(bh, nq),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_q, LSE_LANES), lambda i, j: (i, j, 0)),
-        ],
+                          blocks=blocks, has_seg=seg is not None),
+        grid=(bh, t // blocks.block_q),
+        in_specs=[strip, whole, whole] + (
+            [] if seg is None else [strip_rows, whole_cols]),
+        out_specs=[strip, strip_rows],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, t, d), qf.dtype),
             jax.ShapeDtypeStruct((bh, t, LSE_LANES), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
+        compiler_params=None if interpret else _compiler_params(
+            KERNEL_FWD, t, d, qf.dtype.itemsize, blocks),
         name=KERNEL_FWD,
-    )(*operands)
-    return o, lse
+    )(qf, kf, vf, *(seg or ()))
+
+
+def _flat(x):
+    b, t, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+
+def _fwd(q, k, v, seg, scale, causal, blocks):
+    return _call_fwd(_flat(q), _flat(k), _flat(v), seg, scale, causal,
+                     blocks, interpret=_interpret())
 
 
 # ---------------------------------------------------------------------------
 # backward (recompute with saved lse)
 # ---------------------------------------------------------------------------
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                   scale, causal, block_k, has_seg=False):
+                   scale, causal, blocks, has_seg):
     if has_seg:
         sq_ref, sk_ref, dq_ref = rest
     else:
         (dq_ref,) = rest
-    bq, d = q_ref.shape
-    t = k_ref.shape[0]
-    nk = t // block_k
-    qi = pl.program_id(1)
+    bq, block_k, granule = blocks
+    t, d = k_ref.shape
+    q0 = pl.program_id(1) * bq
+    _, n_interior = interior_tiles(KERNEL_BWD_DQ, t, causal, blocks, q0)
 
-    q = q_ref[...]
+    q = _scaled(q_ref[...], scale)
     do = do_ref[...]
     lse = lse_ref[...][:, :1]
     delta = delta_ref[...][:, :1]
-    dq = jnp.zeros((bq, d), jnp.float32)
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
     if has_seg:
         q_seg = sq_ref[...][:, :1]  # [bq, 1]
 
-    def body(j, dq):
-        k_blk = k_ref[pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[pl.ds(j * block_k, block_k), :]
-        s = scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    def tile(rows, dq, k0, width, keep):
+        k_blk = k_ref[pl.ds(k0, width), :]
+        v_blk = v_ref[pl.ds(k0, width), :]
+        s = _mask_corner(_dot(q[rows], k_blk, (1, 1)), keep, 1)
         if has_seg:
-            k_seg = sk_ref[j][:1, :]  # [1, block_k]
-            s = jnp.where(q_seg == k_seg, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(k_blk.dtype)
-        return dq + scale * jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            k_seg = sk_ref[:1, pl.ds(k0, width)]  # [1, width]
+            s = jnp.where(q_seg[rows] == k_seg, s, NEG_INF)
+        p = jnp.exp(s - lse[rows])
+        dp = _dot(do[rows], v_blk, (1, 1))
+        ds = (p * (dp - delta[rows])).astype(k_blk.dtype)
+        return dq + _dot(ds, k_blk, (1, 0))
 
-    nk_eff = (jnp.minimum((qi * bq + bq + block_k - 1) // block_k, nk)
-              if causal else nk)
-    dq = jax.lax.fori_loop(0, nk_eff, body, dq)
-    dq_ref[...] = dq.astype(dq_ref.dtype)
+    def interior(j, dq):
+        k0 = pl.multiple_of(j * block_k, block_k)
+        return tile(slice(None), dq, k0, block_k, None)
+
+    dq = _walk(n_interior, interior, jnp.zeros((bq, d), jnp.float32))
+    if not causal:
+        dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
+        return
+    corner = _lower_triangle(granule)
+    for r in range(bq // granule):
+        rows = slice(r * granule, (r + 1) * granule)
+        dq_r = tile(rows, dq[rows], q0, (r + 1) * granule, corner)
+        dq_ref[rows, :] = (dq_r * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                    scale, causal, block_q, has_seg=False):
+                    scale, causal, blocks, has_seg):
     if has_seg:
         sr_ref, sc_ref, dk_ref, dv_ref = rest
     else:
         dk_ref, dv_ref = rest
-    bk, d = k_ref.shape
-    t = q_ref.shape[0]
-    nq = t // block_q
-    ki = pl.program_id(1)
+    block_q, bk, granule = blocks
+    t, d = q_ref.shape
+    k0 = pl.program_id(1) * bk
+    # q tiles wholly under the diagonal start where this strip's square ends
+    first, n_interior = interior_tiles(KERNEL_BWD_DKV, t, causal, blocks, k0)
 
-    k = k_ref[...]
+    k = _scaled(k_ref[...], scale)
     v = v_ref[...]
-    dk = jnp.zeros((bk, d), jnp.float32)
-    dv = jnp.zeros((bk, d), jnp.float32)
-    k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
     if has_seg:
         k_seg = sc_ref[...][:1, :]  # [1, bk]
 
-    def body(i, carry):
+    def tile(cols, carry, q0, height, keep):
         dk, dv = carry
-        j = i + (ki * bk) // block_q if causal else i
-        q_blk = q_ref[pl.ds(j * block_q, block_q), :]
-        do_blk = do_ref[pl.ds(j * block_q, block_q), :]
-        lse_blk = lse_ref[pl.ds(j * block_q, block_q), :1]
-        delta_blk = delta_ref[pl.ds(j * block_q, block_q), :1]
-        s = scale * jax.lax.dot_general(
-            q_blk, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [block_q, bk]
-        if causal:
-            q_pos = j * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 0)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        q_blk = q_ref[pl.ds(q0, height), :]
+        do_blk = do_ref[pl.ds(q0, height), :]
+        lse_blk = lse_ref[pl.ds(q0, height), :1]
+        delta_blk = delta_ref[pl.ds(q0, height), :1]
+        s = _mask_corner(_dot(q_blk, k[cols], (1, 1)), keep, 0)
         if has_seg:
-            q_seg_blk = sr_ref[pl.ds(j * block_q, block_q), :1]  # [block_q, 1]
-            s = jnp.where(q_seg_blk == k_seg, s, NEG_INF)
+            q_seg = sr_ref[pl.ds(q0, height), :1]  # [height, 1]
+            s = jnp.where(q_seg == k_seg[:, cols], s, NEG_INF)
         p = jnp.exp(s - lse_blk)
-        pb = p.astype(do_blk.dtype)
-        dv = dv + jax.lax.dot_general(
-            pb, do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do_blk, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dv = dv + _dot(p.astype(do_blk.dtype), do_blk, (0, 0))
+        dp = _dot(do_blk, v[cols], (1, 1))
         ds = (p * (dp - delta_blk)).astype(q_blk.dtype)
-        dk = dk + scale * jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk, dv
+        return dk + _dot(ds, q_blk, (0, 0)), dv
 
-    if causal:
-        # q blocks entirely before this k block's diagonal contribute nothing
-        n_eff = nq - (ki * bk) // block_q
-    else:
-        n_eff = nq
-    dk, dv = jax.lax.fori_loop(0, n_eff, body, (dk, dv))
-    dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
+    def interior(i, carry):
+        q0 = pl.multiple_of(first + i * block_q, block_q)
+        return tile(slice(None), carry, q0, block_q, None)
+
+    def finish(cols, carry):
+        dk, dv = carry
+        dk_ref[cols, :] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[cols, :] = dv.astype(dv_ref.dtype)
+
+    carry = _walk(n_interior, interior,
+                  (jnp.zeros((bk, d), jnp.float32),
+                   jnp.zeros((bk, d), jnp.float32)))
+    if not causal:
+        finish(slice(None), carry)
+        return
+    # the diagonal square: slice c's columns are seen by the square's rows
+    # from their own corner down
+    corner = _lower_triangle(granule)
+    for c in range(bk // granule):
+        cols = slice(c * granule, (c + 1) * granule)
+        finish(cols, tile(cols, tuple(x[cols] for x in carry),
+                          k0 + c * granule, bk - c * granule, corner))
 
 
-def _bwd_impl(scale, causal, block_q, block_k, q, k, v, o, lse, do,
-              seg=None):
-    b, t, h, d = q.shape
-    bh = b * h
-
-    def flat(x):
-        return x.transpose(0, 2, 1, 3).reshape(bh, t, d)
-
-    qf, kf, vf = map(flat, (q, k, v))
-    of, dof = o, do  # already [bh, t, d] (the op's internal layout)
-    delta = jnp.sum(of.astype(jnp.float32) * dof.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[..., None], delta.shape + (LSE_LANES,))
-
-    nq, nk = t // block_q, t // block_k
-    dq_in_specs = [
-        pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, block_q, LSE_LANES), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, block_q, LSE_LANES), lambda i, j: (i, j, 0)),
-    ]
-    dq_operands = [qf, kf, vf, dof, lse, delta]
-    dkv_in_specs = [
-        pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, t, LSE_LANES), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, t, LSE_LANES), lambda i, j: (i, 0, 0)),
-    ]
-    dkv_operands = [qf, kf, vf, dof, lse, delta]
-    if seg is not None:
-        seg_r, seg_c = seg
-        seg_ck, seg_ck_spec = _seg_by_k_block(seg_c, block_k)
-        dq_in_specs += [
-            pl.BlockSpec((None, block_q, LSE_LANES), lambda i, j: (i, j, 0)),
-            seg_ck_spec,
-        ]
-        dq_operands += [seg_r, seg_ck]
-        # dkv slices the row layout by q block in-kernel and takes its own
-        # k block from the column layout
-        dkv_in_specs += [
-            pl.BlockSpec((None, t, LSE_LANES), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, LSE_LANES, block_k), lambda i, j: (i, 0, j)),
-        ]
-        dkv_operands += [seg_r, seg_c]
-
-    dq = pl.pallas_call(
+@_jit_call
+def _call_dq(operands, seg, scale, causal, blocks, interpret=None):
+    """``flash_bwd_dq`` on ``(q, k, v, do, lse, delta)``, flat: dq."""
+    bh, t, d = operands[0].shape
+    interpret = _interpret() if interpret is None else interpret
+    strip, whole, strip_rows, _, whole_cols = _specs(blocks.block_q, d, t)
+    return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_k=block_k, has_seg=seg is not None),
-        grid=(bh, nq),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        interpret=_interpret(),
+                          blocks=blocks, has_seg=seg is not None),
+        grid=(bh, t // blocks.block_q),
+        in_specs=[strip, whole, whole, strip, strip_rows, strip_rows] + (
+            [] if seg is None else [strip_rows, whole_cols]),
+        out_specs=strip,
+        out_shape=jax.ShapeDtypeStruct((bh, t, d), operands[0].dtype),
+        interpret=interpret,
+        compiler_params=None if interpret else _compiler_params(
+            KERNEL_BWD_DQ, t, d, operands[0].dtype.itemsize, blocks),
         name=KERNEL_BWD_DQ,
-    )(*dq_operands)
+    )(*operands, *(seg or ()))
 
-    dk, dv = pl.pallas_call(
+
+@_jit_call
+def _call_dkv(operands, seg, scale, causal, blocks, interpret=None):
+    """``flash_bwd_dkv`` on ``(q, k, v, do, lse, delta)``, flat: (dk, dv)."""
+    bh, t, d = operands[0].shape
+    interpret = _interpret() if interpret is None else interpret
+    strip, whole, _, whole_rows, _ = _specs(blocks.block_k, d, t)
+    # dkv slices the segments' row layout by q tile in-kernel and takes its
+    # own k block from the column layout
+    seg_specs = [] if seg is None else [
+        whole_rows, pl.BlockSpec((None, LSE_LANES, blocks.block_k),
+                                 lambda i, j: (i, 0, j))]
+    out = jax.ShapeDtypeStruct((bh, t, d), operands[0].dtype)
+    return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, has_seg=seg is not None),
-        grid=(bh, nk),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        ],
-        interpret=_interpret(),
+                          blocks=blocks, has_seg=seg is not None),
+        grid=(bh, t // blocks.block_k),
+        in_specs=[whole, strip, strip, whole, whole_rows, whole_rows]
+        + seg_specs,
+        out_specs=[strip, strip],
+        out_shape=[out, out],
+        interpret=interpret,
+        compiler_params=None if interpret else _compiler_params(
+            KERNEL_BWD_DKV, t, d, operands[0].dtype.itemsize, blocks),
         name=KERNEL_BWD_DKV,
-    )(*dkv_operands)
+    )(*operands, *(seg or ()))
+
+
+def row_delta(of, dof):
+    """``sum(o * do)`` a row, in the rows' lane-broadcast layout."""
+    delta = jnp.sum(of.astype(jnp.float32) * dof.astype(jnp.float32), axis=-1)
+    return jnp.broadcast_to(delta[..., None], delta.shape + (LSE_LANES,))
+
+
+def _bwd_impl(scale, causal, schedule, q, k, v, o, lse, do, seg=None):
+    b, t, h, d = q.shape
+    # o and do are already [bh, t, d] (the op's internal layout)
+    operands = (_flat(q), _flat(k), _flat(v), do, lse, row_delta(o, do))
+    dq = _call_dq(operands, seg, scale, causal, schedule[1],
+                  interpret=_interpret())
+    dk, dv = _call_dkv(operands, seg, scale, causal, schedule[2],
+                       interpret=_interpret())
 
     def unflat(x):
         return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
@@ -365,24 +532,24 @@ def _bwd_impl(scale, causal, block_q, block_k, q, k, v, o, lse, do,
     return unflat(dq), unflat(dk), unflat(dv)
 
 
-def _bwd(scale, causal, block_q, block_k, res, g):
+def _bwd(scale, causal, schedule, res, g):
     q, k, v, o, lse = res
-    return _bwd_impl(scale, causal, block_q, block_k, q, k, v, o, lse, g)
+    return _bwd_impl(scale, causal, schedule, q, k, v, o, lse, g)
 
 
 # ---------------------------------------------------------------------------
 # public op
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, scale, causal, block_q, block_k):
-    o, _ = _fwd(q, k, v, None, scale, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, scale, causal, schedule):
+    o, _ = _fwd(q, k, v, None, scale, causal, schedule[0])
     return o
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
+def _flash_fwd(q, k, v, scale, causal, schedule):
     from jax.ad_checkpoint import checkpoint_name
 
-    o, lse = _fwd(q, k, v, None, scale, causal, block_q, block_k)
+    o, lse = _fwd(q, k, v, None, scale, causal, schedule[0])
     # under remat, tagging the kernel outputs lets a names-aware policy keep
     # them (o: 2 bytes/elem, lse: 1/head_dim of that) instead of re-running
     # the whole forward kernel to regenerate residuals in the backward pass
@@ -394,25 +561,25 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
 _flash.defvjp(_flash_fwd, _bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash_seg(q, k, v, seg_r, seg_c, scale, causal, block_q, block_k):
-    o, _ = _fwd(q, k, v, (seg_r, seg_c), scale, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_seg(q, k, v, seg_r, seg_c, scale, causal, schedule):
+    o, _ = _fwd(q, k, v, (seg_r, seg_c), scale, causal, schedule[0])
     return o
 
 
-def _flash_seg_fwd(q, k, v, seg_r, seg_c, scale, causal, block_q, block_k):
+def _flash_seg_fwd(q, k, v, seg_r, seg_c, scale, causal, schedule):
     from jax.ad_checkpoint import checkpoint_name
 
-    o, lse = _fwd(q, k, v, (seg_r, seg_c), scale, causal, block_q, block_k)
+    o, lse = _fwd(q, k, v, (seg_r, seg_c), scale, causal, schedule[0])
     o = checkpoint_name(o, "attn_out")
     lse = checkpoint_name(lse, "attn_lse")
     return o, (q, k, v, seg_r, seg_c, o, lse)
 
 
-def _flash_seg_bwd(scale, causal, block_q, block_k, res, g):
+def _flash_seg_bwd(scale, causal, schedule, res, g):
     q, k, v, seg_r, seg_c, o, lse = res
-    dq, dk, dv = _bwd_impl(scale, causal, block_q, block_k, q, k, v, o, lse,
-                           g, seg=(seg_r, seg_c))
+    dq, dk, dv = _bwd_impl(scale, causal, schedule, q, k, v, o, lse, g,
+                           seg=(seg_r, seg_c))
     # integer operands take symbolic-zero (float0) cotangents
     dseg_r = np.zeros(seg_r.shape, jax.dtypes.float0)
     dseg_c = np.zeros(seg_c.shape, jax.dtypes.float0)
@@ -420,6 +587,40 @@ def _flash_seg_bwd(scale, causal, block_q, block_k, res, g):
 
 
 _flash_seg.defvjp(_flash_seg_fwd, _flash_seg_bwd)
+
+
+def schedule_plan(t: int, causal: bool, schedule) -> Dict[str, dict]:
+    """What the ``flash.plan`` event says of each kernel: its blocks and
+    its tile counts. ``heads``: a grid step holds one head; several were
+    swept on the v5e and bought nothing (PERF.md section 6, PR 45)."""
+    return {kernel: {**blocks._asdict(), "heads": 1,
+                     **tile_counts(kernel, t, causal, blocks)}
+            for kernel, blocks in zip(KERNELS, schedule)}
+
+
+def resolve_schedule(t: int, d: int, dtype, causal: bool, *,
+                     block_q: int = None, block_k: int = None,
+                     autotune: bool = None, lane_aligned: bool = False
+                     ) -> Tuple[Tuple[KernelBlocks, ...], str]:
+    """The three kernels' schedules for one launch, and where the blocks
+    came from (``explicit``, or ``get_flash_schedule``'s source). Publishes
+    the ``flash.plan`` event: once per trace of a call, never per step."""
+    wanted, source = {}, "explicit"
+    if block_q is None or block_k is None:
+        from deepspeed_tpu.ops.pallas.autotune import get_flash_schedule
+
+        wanted, source = get_flash_schedule(t, d, dtype, causal,
+                                            autotune=autotune)
+    schedule = []
+    for kernel in KERNELS:
+        bq, bk, granule = wanted.get(kernel, (None,) * 3)
+        schedule.append(fit_blocks(
+            kernel, t, causal, bq if block_q is None else block_q,
+            bk if block_k is None else block_k, granule,
+            lane_aligned=lane_aligned))
+    publish(KIND_FLASH_PLAN, t=t, d=d, causal=bool(causal), source=source,
+            kernels=schedule_plan(t, causal, schedule))
+    return tuple(schedule), source
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
@@ -437,24 +638,20 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
     per-document unpacked attention (docs/data.md).
 
     ``block_q``/``block_k`` default to the shape-tuned resolution in
-    ``ops/pallas/autotune.py`` (disk cache -> pretuned table -> optional
-    live benchmark gated by ``autotune``/``DS_TPU_FLASH_AUTOTUNE`` -> the
-    historical want-512 divisor heuristic); pass them explicitly to pin.
+    ``ops/pallas/autotune.py`` (disk cache -> pretuned table, which may
+    name each kernel's own -> optional live benchmark gated by
+    ``autotune``/``DS_TPU_FLASH_AUTOTUNE`` -> the historical want-512
+    divisor heuristic); pass them explicitly to pin all three kernels.
     """
     b, t, h, d = q.shape
     if scale is None:
         scale = 1.0 / np.sqrt(d)
-    if block_q is None or block_k is None:
-        from deepspeed_tpu.ops.pallas.autotune import get_flash_blocks
-
-        tuned_q, tuned_k = get_flash_blocks(
-            t, d, q.dtype, causal, autotune=autotune)
-        block_q = tuned_q if block_q is None else block_q
-        block_k = tuned_k if block_k is None else block_k
-    block_q = _block(t, block_q)
-    block_k = _block(t, block_k)
+    schedule, _ = resolve_schedule(t, d, q.dtype, causal,
+                                   block_q=block_q, block_k=block_k,
+                                   autotune=autotune,
+                                   lane_aligned=segment_ids is not None)
     if segment_ids is None:
-        of = _flash(q, k, v, float(scale), bool(causal), block_q, block_k)
+        of = _flash(q, k, v, float(scale), bool(causal), schedule)
     else:
         if segment_ids.shape != (b, t):
             raise ValueError(
@@ -466,5 +663,5 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
         seg_r = jnp.broadcast_to(segf[:, :, None], (b * h, t, LSE_LANES))
         seg_c = jnp.broadcast_to(segf[:, None, :], (b * h, LSE_LANES, t))
         of = _flash_seg(q, k, v, seg_r, seg_c, float(scale), bool(causal),
-                        block_q, block_k)
+                        schedule)
     return of.reshape(b, h, t, d).transpose(0, 2, 1, 3)

@@ -64,6 +64,12 @@ KIND_ELASTIC_RESHARD = "elastic.reshard"
 # ZeRO-3's gather at the point of use (runtime/zero/gather.py): what a step
 # program gathers and reduce-scatters, published once when it is traced
 KIND_ZERO3_GATHER_PLAN = "zero3.gather_plan"
+# the flash-attention kernels' schedule (ops/pallas/flash_attention.py),
+# published once when a call is traced: t, d, causal, source (where the
+# blocks came from: explicit, disk, pretuned, autotuned, heuristic) and per
+# kernel block_q, block_k, heads (a grid step), granule, tiles_computed,
+# tiles_needed, tiles_masked (a head's, in tiles of block_q x block_k)
+KIND_FLASH_PLAN = "flash.plan"
 # a program's backend compile, or its load from the persistent cache, ended
 # (telemetry/builds.py): program, key, trace_s, lower_s, compile_or_load_s,
 # cache_hit, nth, since_entry_s

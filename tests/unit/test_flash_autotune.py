@@ -104,15 +104,54 @@ class TestCacheResolution:
 
 class TestPretuned:
     def test_shipped_entries_cover_the_13b_shapes(self):
-        for kind in ("TPU v4", "TPU v5e", "TPU v5p", "TPU v6e"):
+        # what the v5e measured (PERF.md section 6, PR 45): a strip of the
+        # whole sequence a kernel, in KERNELS' order; every other entry is
+        # a seed never run on its chip, one pair for all three
+        measured = {(kind, seq, "bfloat16"): (
+                        (seq, 512, g), (seq, 512, g), (512, keys, g))
+                    for kind in ("TPU v5e", "TPU v5 lite")
+                    for seq, g, keys in ((1024, 256, 1024),
+                                         (4096, 512, 2048))}
+        for kind in ("TPU v4", "TPU v5 lite", "TPU v5e", "TPU v5p",
+                     "TPU v6e"):
             for dt in ("bfloat16", "float32"):
                 for seq in (1024, 2048):
                     # 1.3B: n_embd=2048 / 16 heads -> head_dim 128
-                    assert PRETUNED[(kind, seq, 128, dt, True)] == (512, 256)
+                    assert PRETUNED[(kind, seq, 128, dt, True)] == \
+                        measured.get((kind, seq, dt),
+                                     ((512, 256, None),) * 3)
+        assert PRETUNED[("TPU v5 lite", 4096, 128, "bfloat16", True)] == \
+            measured[("TPU v5 lite", 4096, "bfloat16")]
+
+    def test_measured_entries_name_each_kernels_own_blocks(self,
+                                                           monkeypatch):
+        """On the v5e the 1.3B shape resolves to the measured schedule,
+        with ``pretuned`` as its source; another chip keeps the pair."""
+        from deepspeed_tpu.ops.pallas.flash_attention import KERNELS
+
+        class Device:
+            device_kind = "TPU v5 lite"
+
+        monkeypatch.setattr(autotune.jax, "devices", lambda: [Device()])
+        wanted, source = autotune.get_flash_schedule(1024, 128,
+                                                     jnp.bfloat16, True)
+        assert source == "pretuned"
+        assert wanted == dict(zip(KERNELS, ((1024, 512, 256),
+                                            (1024, 512, 256),
+                                            (512, 1024, 256))))
+        assert get_flash_blocks(1024, 128, jnp.bfloat16, True) == (1024, 512)
+        Device.device_kind = "TPU v4"
+        wanted, source = autotune.get_flash_schedule(1024, 128,
+                                                     jnp.bfloat16, True)
+        assert source == "pretuned"
+        assert set(wanted.values()) == {(512, 256, None)}
 
     def test_entries_are_valid_launches(self):
-        for (kind, seq, d, dt, causal), blocks in PRETUNED.items():
-            assert autotune._valid(blocks, seq) == blocks, (kind, seq)
+        for (kind, seq, d, dt, causal), kernels in PRETUNED.items():
+            assert len(kernels) == 3
+            for blocks in kernels:
+                assert autotune._valid(blocks, seq) == blocks[:2], (kind,
+                                                                    seq)
 
     def test_candidate_grid_is_divisor_filtered(self):
         for bq, bk in default_candidates(1024):
@@ -164,14 +203,14 @@ class TestNumericalParity:
         a cached winner changes the launch (observed via the resolver
         memo), while explicit blocks bypass it."""
         seen = []
-        real = autotune.get_flash_blocks
+        real = autotune.get_flash_schedule
 
         def spy(*a, **kw):
             seen.append(a)
             return real(*a, **kw)
 
         monkeypatch.setattr(
-            "deepspeed_tpu.ops.pallas.autotune.get_flash_blocks", spy)
+            "deepspeed_tpu.ops.pallas.autotune.get_flash_schedule", spy)
         rng = np.random.RandomState(1)
         q, k, v = (jnp.asarray(rng.randn(1, 64, 2, 4), jnp.float32)
                    for _ in range(3))

@@ -664,3 +664,50 @@ def test_the_scan_over_the_stack_compiles_to_no_copy_of_a_layers_matrices(
     else:
         assert any(name.startswith("%dynamic-slice") and "fusion" in name
                    for name in whole_layer)
+
+
+# the flash kernels live in this file's compile tests too: one file holds
+# every test that describes the chip (a second could go to another worker)
+FLASH_CELLS = {"gpt-1.3b-train": (96, 1024),
+               "olmoe-1b-7b-train-4k": (32, 4096)}
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"])
+@pytest.mark.parametrize("cell", sorted(FLASH_CELLS))
+def test_the_flash_kernels_compile_for_the_chip_under_the_tables_schedule(
+        one_chip, cell, kernel, monkeypatch):
+    """The v5e's measured entries at the two training cells' shapes: whole
+    strips (at 4,096 with the VMEM limit asked) compile, and each call
+    keeps the name and the result signature the benchmark's reader tells
+    the three apart by."""
+    import importlib
+
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    bh, t = FLASH_CELLS[cell]
+    d = 128
+    blocks = fa.fit_blocks(kernel, t, True, *autotune.PRETUNED[
+        "TPU v5 lite", t, d, "bfloat16", True][fa.KERNELS.index(kernel)])
+    assert blocks[kernel == fa.KERNEL_BWD_DKV] in (t, t // 2)
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x, rows = spec(bh, t, d), spec(bh, t, fa.LSE_LANES, dtype=jnp.float32)
+    scale = d ** -0.5
+    run = {fa.KERNEL_FWD: lambda q, k, v, do, lse, delta: fa._call_fwd(
+               q, k, v, None, scale, True, blocks),
+           fa.KERNEL_BWD_DQ: lambda *ops: fa._call_dq(
+               ops, None, scale, True, blocks),
+           fa.KERNEL_BWD_DKV: lambda *ops: fa._call_dkv(
+               ops, None, scale, True, blocks)}[kernel]
+    text = compiled_for_the_chip(jax.jit(run), x, x, x, x, rows, rows)
+    (call,) = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "custom-call(" in line]
+    assert f"%{kernel}" in call
+    results = call.split(" custom-call(")[0].split(" = ", 1)[1]
+    big, lse = f"bf16[{bh},{t},{d}]", f"f32[{bh},{t},{fa.LSE_LANES}]"
+    assert (results.count(big), results.count(lse)) == {
+        fa.KERNEL_FWD: (1, 1), fa.KERNEL_BWD_DQ: (1, 0),
+        fa.KERNEL_BWD_DKV: (2, 0)}[kernel]
