@@ -47,35 +47,25 @@ PROGRAM_PREFILL_MORE = "jit_prefill_more"
 PROGRAM_DECODE_K = "jit_decode_k"
 
 
-def kv_leaf_shapes(tree):
-    """The shapes a whole KV-cache leaf takes inside a program, from any
-    pytree of arrays or avals that holds caches (the leaves named
-    ``cached_key`` / ``cached_value`` / ``cached_*_scale`` of the model's
-    ``cache`` collection, and latent attention's ``cached_latent`` /
-    ``cached_rope_key``, which have no head axis): the leaf as stored, and
-    without its leading layer axis where ``ScannedBlocks`` stacked it (one
-    layer's slice, which a turn of the layer loop reads inside its
-    attention fusions and should never produce)."""
-    from deepspeed_tpu.models.latent_attention import LATENT_LEAVES
-
-    shapes = set()
-    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        name = str(getattr(path[-1], "key", ""))
-        if not name.startswith("cached_"):
-            continue
-        shape = tuple(leaf.shape)
-        shapes.add(shape)
-        headless = name.endswith("_scale") or name in LATENT_LEAVES
-        if len(shape) > (3 if headless else 4):
-            shapes.add(shape[1:])
-    return shapes
+def probe_length(config, bucket: int) -> int:
+    """Tokens of the probe that materializes parameters: ``init`` traces
+    the TRAINING forward, whose sparse layout needs a multiple of
+    ``bucket`` (a multiple of the layout's block) with at least the full
+    window of blocks present (sparsity_config ``make_layout``)."""
+    sc = getattr(config, "sparse_attention", None)
+    window = int(getattr(sc, "num_sliding_window_blocks", None) or 0) \
+        * int(getattr(sc, "block", None) or 0)
+    return -(-max(bucket, window) // bucket) * bucket
 
 
-def recurrent_leaf_shapes(tree, leaves):
-    """The same for the leaves a model declares as recurrent state
-    (``GPTConfig.recurrent_leaves``): ``{carry tag: shapes}``, the leaf as
-    stored and, where the declaration says a layer's slice is a whole
-    leaf too, the stacked leaf without its layer axis."""
+def carried_leaf_shapes(tree, leaves):
+    """``{carry tag: shapes}``: the shapes each declared leaf
+    (``GPTConfig.cache_leaves``) takes as a whole inside a program, from
+    any pytree of arrays or avals that holds caches: the leaf as stored
+    and, where the declaration says a layer's slice is a whole leaf too,
+    without its leading layer axis where ``ScannedBlocks`` stacked it
+    (which a turn of the layer loop reads inside its fusions and should
+    never produce)."""
     declared = {leaf.name: leaf for leaf in leaves}
     shapes = {leaf.carry_tag: set() for leaf in leaves}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
@@ -89,18 +79,17 @@ def recurrent_leaf_shapes(tree, leaves):
     return shapes
 
 
-def programs_scope_table(programs, recurrent_leaves=()):
-    """``scope_table`` of ``DispatchedProgram``s, with ``kv_cache_carry``
-    for the KV-cache leaves among their arguments and results and each
-    declared recurrent leaf's own tag (``ssm_state_carry``,
-    ``ret_state_carry``) for those."""
+def programs_scope_table(programs, cache_leaves):
+    """``scope_table`` of ``DispatchedProgram``s, with each declared
+    leaf's carry tag (``kv_cache_carry``, ``ssm_state_carry``,
+    ``ret_state_carry``) for the whole cache leaves among their arguments
+    and results."""
     lowered = [(avals, low) for prog in programs
                for avals, low in zip(prog.avals.values(), prog.lowered())]
     carry = {SCOPE_KV_CACHE_CARRY: set()}
     for avals, low in lowered:
-        carry[SCOPE_KV_CACHE_CARRY] |= kv_leaf_shapes((avals, low.out_info))
-        for tag, shapes in recurrent_leaf_shapes(
-                (avals, low.out_info), recurrent_leaves).items():
+        for tag, shapes in carried_leaf_shapes(
+                (avals, low.out_info), cache_leaves).items():
             carry.setdefault(tag, set()).update(shapes)
     return scope_table((low.compile().as_text() for _, low in lowered),
                        carry)
@@ -610,7 +599,7 @@ class InferenceEngine:
             ``argmax`` per column IS the greedy token after accepting ``j``
             drafts — acceptance is a host-side prefix match, and the
             scheduler rewinds the cache clocks past the first mismatch
-            (ContinuousBatchingScheduler._rewind)."""
+            (inference/lane_cache.py ``LaneLayout.rewind``)."""
             logits, vars_out = model.apply(
                 {"params": self._dequant(params), "cache": cache}, toks,
                 deterministic=True, decode=True, mutable=["cache"])
@@ -647,10 +636,11 @@ class InferenceEngine:
         ``kv_cache_carry`` (none, while the cache crosses the layer loop
         in place). Re-lowers (a cache hit) and parses HLO text:
         call it after the measured window, never inside it."""
+        from deepspeed_tpu.models.transformer_lm import declared_cache_leaves
+
         return programs_scope_table(
             self.step_programs(),
-            getattr(getattr(self.module, "config", None),
-                    "recurrent_leaves", ()))
+            declared_cache_leaves(getattr(self.module, "config", None)))
 
     def program_builds(self, before: Optional[float] = None):
         """What this process built so far, by JAX's own account

@@ -36,12 +36,13 @@ contaminates a neighbor; its next admission overwrites every cache row it
 touched. The recurrent state a model's mixers keep (a hybrid block's
 Mamba-2 state and convolution tail, models/mamba2.py; a retention block's
 state and normaliser, which are ALL its lanes hold: models/
-power_retention.py) are leaves of the same cache, declared once by the
-model (``GPTConfig.recurrent_leaves``) and read from there by everything
-here that has to know: a finished lane's state goes on absorbing garbage
-tokens, is read by nobody, and is overwritten whole by the splice of the
-lane's next admission. They cannot be truncated to a prefix, so such a
-model refuses speculative decoding and the prefix cache
+power_retention.py) are leaves of the same cache. What a lane keeps is
+declared once by the model (``GPTConfig.cache_leaves``) and read from
+there by the one module that knows a lane cache's layout (inference/
+lane_cache.py); nothing here names a leaf. A finished lane's state goes on
+absorbing garbage tokens, is read by nobody, and is overwritten whole by
+the splice of the lane's next admission. A state cannot be truncated to a
+prefix, so such a model refuses speculative decoding and the prefix cache
 (``RecurrentStateError``).
 
 Prompts are LEFT-padded to a ``prompt_bucket`` multiple to bound prefill
@@ -67,9 +68,22 @@ import numpy as np
 from deepspeed_tpu.inference.engine import (
     continuation_chunk_spans,
     prefill_chunk_spans,
+    probe_length,
     programs_scope_table,
 )
-from deepspeed_tpu.ops.pallas.decode_attention import live_blocks
+from deepspeed_tpu.inference.lane_cache import (
+    LaneClocks,
+    LaneLayout,
+    LanesAtExit,
+)
+from deepspeed_tpu.models.transformer_lm import (
+    GPTConfig,
+    decode_attention_block,
+)
+from deepspeed_tpu.moe.experts import expert_matrices
+from deepspeed_tpu.ops.sparse_attention.sparse_attention_utils import (
+    ring_engaged,
+)
 from deepspeed_tpu.parallel.mesh import set_default_topology
 from deepspeed_tpu.telemetry.builds import build_log
 from deepspeed_tpu.telemetry.scopes import SCOPE_SAMPLE, DispatchedProgram
@@ -89,10 +103,6 @@ from deepspeed_tpu.telemetry.spans import (
 # the names of the scheduler's own programs as a profiler trace has them
 # (its ``XLA Modules`` events) and as ``program_scopes()`` keys them
 PROGRAM_SPLICE = "jit_splice"
-
-
-def _first_leaf_shape(tree):
-    return jax.tree.leaves(tree)[0].shape
 
 
 class AdmissionRejected(RuntimeError):
@@ -123,21 +133,6 @@ class DeadlineExceededError(AdmissionRejected):
 
     def __init__(self, message: str):
         super().__init__(message, reason="deadline")
-
-
-class RecurrentStateError(ValueError):
-    """A serving feature that truncates a cache to a shorter prefix was
-    asked of a model whose blocks hold recurrent state
-    (``GPTConfig.recurrent_leaves``): keys and values of the first ``n``
-    positions are a prefix's cache, a state after ``m > n`` tokens is
-    not."""
-
-    def __init__(self, feature: str, why: str, leaves):
-        super().__init__(
-            f"{feature} cannot serve a model with recurrent state "
-            f"({', '.join(leaf.name for leaf in leaves)}: "
-            f"GPTConfig.recurrent_leaves): {why}")
-        self.feature = feature
 
 
 class DrainingError(AdmissionRejected):
@@ -207,113 +202,6 @@ class _Lane:
     emitted: int = 0
 
 
-class _LaneClocks:
-    """Where each lane's rows begin and where its next query sits, kept on
-    the host from what admissions and steps do to the device's
-    ``cache_index`` and ``valid`` leaves, so that a decode step can say
-    what its attention reads without asking the device: ``step`` gives
-    the blocks read over the blocks held, all lanes, by the kernel's own
-    rule (ops/pallas/decode_attention.py ``live_blocks``), and 1.0 where
-    attention takes the einsums over every position (``block`` None).
-    A lane that holds no request keeps its clock running, as on the
-    device, and is read up to it. A cache without keys and values
-    (``block`` 0) has no such share: ``step`` gives None, nothing is
-    summed, and the decode step's span carries no
-    ``kv_blocks_read_share`` (``telemetry.span`` drops an attribute that
-    is None)."""
-
-    def __init__(self, stats, slots: int, positions: int, block):
-        self.stats = stats
-        self.block = block
-        self.positions = positions
-        self.first = np.zeros((slots,), np.int64)
-        self.clock = np.zeros((slots,), np.int64)
-
-    def admit(self, lane: int, bucket: int, prompt_len: int, replayed: int):
-        self.first[lane] = bucket - prompt_len
-        self.clock[lane] = bucket + replayed
-
-    def live_positions(self, lanes) -> int:
-        """Rows that the requests now in ``lanes`` (None: a free lane)
-        have written: each one's prompt and what it has decoded, all
-        lanes summed; the positions a decode step's attention has to
-        read, whatever it does read."""
-        held = np.fromiter((lane is not None for lane in lanes), bool,
-                           len(lanes))
-        return int((self.clock - self.first)[held].sum())
-
-    def step(self) -> Optional[float]:
-        if self.block == 0:
-            return None
-        share = 1.0
-        if self.block is not None:
-            lo, hi = live_blocks(
-                self.first, np.minimum(self.clock, self.positions - 1),
-                self.block)
-            share = float((hi - lo + 1).sum()) \
-                / (-(-self.positions // self.block) * len(self.clock))
-        self.clock += 1
-        self.stats.kv_blocks_read_share_sum += share
-        return share
-
-
-class LanesAtExit:
-    """What ``run`` left on the device when it ended with a decode step in
-    flight (``ContinuousBatchingScheduler.retain_lanes``): the lane cache
-    as that step left it, and ``live``, lane number -> the ``Completion``
-    so far of the request that still held the lane.
-
-    A live lane's rows, its recurrent state included, have taken in the
-    request's prompt and every token of ``Completion.tokens``: the step in
-    flight consumed the last of them, and what it computed is nobody's.
-    That holds for a run ended from ``poll_fn``, between two steps; a
-    ``stream_callback`` that raises ends it inside a step's delivery, and
-    the lanes after its own are then one undelivered token ahead."""
-
-    # what the model declares as recurrent state (``GPTConfig.
-    # recurrent_leaves``) and as what it keeps per position (``GPTConfig.
-    # position_leaves``); the scheduler sets both on what it keeps
-    recurrent_leaves = ()
-    position_leaves = {}
-
-    def __init__(self, owners, cache):
-        self.cache = cache
-        self.live = {n: lane.comp for n, lane in enumerate(owners)
-                     if lane is not None and not lane.comp.t_done}
-
-    def recurrent_state(self, lane: int):
-        """``{leaf name: [layers, ...]}`` of one lane, as stored, for each
-        leaf the model declares (``GPTConfig.recurrent_leaves``: a hybrid
-        block's ``ssm_state`` ``[layers, H, P, N]`` and ``conv_tail``
-        ``[layers, K - 1, C]``, a retention block's ``ret_state``
-        ``[layers, Hkv, D, d]`` and ``ret_norm`` ``[layers, Hkv, D]``):
-        the stacked leaves of ``ScannedBlocks`` or, layer by layer in tree
-        order, an unrolled model's. Empty for a model without one."""
-        return self._lane_leaves(
-            lane, {leaf.name: leaf.rank for leaf in self.recurrent_leaves})
-
-    def positions(self, lane: int):
-        """The same for what the model keeps PER POSITION (``GPTConfig.
-        position_leaves``: keys and values ``[layers, S, Hkv, D]``, or
-        latent attention's ``cached_latent`` ``[layers, S, kv_rank]`` and
-        ``cached_rope_key`` ``[layers, S, rope_dim]``), with ``valid``
-        ``[layers or 1, S]``: which rows the lane's request wrote."""
-        return self._lane_leaves(lane, dict(self.position_leaves, valid=2))
-
-    def _lane_leaves(self, lane: int, rank):
-        out = {}
-        for path, leaf in jax.tree_util.tree_flatten_with_path(
-                self.cache)[0]:
-            name = str(getattr(path[-1], "key", ""))
-            if name in rank:
-                one = jax.lax.dynamic_index_in_dim(
-                    leaf, jnp.int32(lane), leaf.ndim - rank[name],
-                    keepdims=False)
-                out.setdefault(name, []).append(
-                    one if one.ndim == rank[name] else one[None])
-        return {name: jnp.concatenate(parts) for name, parts in out.items()}
-
-
 @dataclass
 class ServingStats:
     completions: List[Completion] = field(default_factory=list)
@@ -325,7 +213,7 @@ class ServingStats:
     # time the host read them (a lane's end is seen one step late)
     decode_tokens_discarded: int = 0
     # over the plain loop's decode steps, the sum of each step's
-    # ``kv_blocks_read_share`` (``_LaneClocks.step``)
+    # ``kv_blocks_read_share`` (``LaneClocks.step``)
     kv_blocks_read_share_sum: float = 0.0
 
     def summary(self) -> Dict[str, Any]:
@@ -425,59 +313,22 @@ class ContinuousBatchingScheduler:
         self._lanes_active = 0
         self._mcfg = getattr(engine.module, "config", None)
 
-        from deepspeed_tpu.ops.sparse_attention.sparse_attention_utils \
-            import ring_engaged
-
         self._ring = ring_engaged(self._mcfg) if self._mcfg is not None \
             else None
-        # what the model declares as recurrent state, read here once
-        self._recurrent = tuple(getattr(self._mcfg, "recurrent_leaves", ()))
-        if self._recurrent:
-            # refused here, by name, and not by a wrong answer later
-            if draft_engine is not None:
-                raise RecurrentStateError(
-                    "draft_engine (speculative decoding)",
-                    "_rewind steps the cache clocks back past the "
-                    "rejected tokens, and a state that has absorbed them "
-                    "cannot be stepped back", self._recurrent)
-            if prefix_cache is not None:
-                raise RecurrentStateError(
-                    "prefix_cache",
-                    "an entry is a cache cut at a promotion boundary, and "
-                    "the state of a longer prompt cannot be cut there (a "
-                    "snapshot of the state at the boundary would do; "
-                    "serving/prefix_cache.py takes none)", self._recurrent)
-        # which leaves hold something per position, as the model says
-        from deepspeed_tpu.models.transformer_lm import KV_LEAVES
-
-        self._position_leaves = dict(getattr(
-            self._mcfg, "position_leaves", KV_LEAVES))
-        if getattr(self._mcfg, "mla", None) is not None:
-            # refused here, by name, and not by a wrong answer later
-            from deepspeed_tpu.models.transformer_lm import LatentCacheError
-
-            if draft_engine is not None:
-                raise LatentCacheError(
-                    "draft_engine (speculative decoding)",
-                    "_rewind restores each attention module's leaves "
-                    "beside its own clock, and a latent cache has one "
-                    "clock a lane and its leaves with whoever runs the "
-                    "layers; nothing has verified a draft against it")
-            if prefix_cache is not None:
-                raise LatentCacheError(
-                    "prefix_cache",
-                    "a continuation over a cached prefix runs the "
-                    "absorbed form with many query tokens, which no "
-                    "entry has been cut for or checked against; "
-                    "serving/prefix_cache.py sizes its entries by keys "
-                    "and values per head")
-            if engine.topology.size("tp") > 1:
-                raise LatentCacheError(
-                    "tp > 1",
-                    "the latent is shared by all heads, so sharding the "
-                    "heads over tp leaves every device the whole cache "
-                    "and the decompression matrices have no sharding "
-                    "rule (models/transformer_lm.py gpt_tp_rules)")
+        # what a lane keeps on the device, as the model declares it, and
+        # what may be done to it: one owner per engine. What this kind of
+        # cache cannot serve is refused here, by name, not by a wrong
+        # answer later.
+        self.lane_cache = LaneLayout(engine, self.slots)
+        self.lane_cache.refuse(draft_engine=draft_engine is not None,
+                               prefix_cache=prefix_cache is not None)
+        self.draft_lane_cache = None if draft_engine is None \
+            else LaneLayout(draft_engine, self.slots)
+        # the target's programs serve the draft's cache too: they take
+        # any cache tree, and one ``jit_splice`` serves both
+        self._splice = self.lane_cache.splice
+        self._copy_tree = self.lane_cache.copy
+        self._rewind = self.lane_cache.rewind
         if prompt_bucket is None:
             prompt_bucket = self._ring[2] if self._ring is not None else 64
         if self._ring is not None and prompt_bucket % self._ring[2] != 0:
@@ -543,14 +394,9 @@ class ContinuousBatchingScheduler:
 
         self._pending: deque = deque()
         self._next_id = 0
-        self._splice_fn = None
         self._set_token_fn = None
-        self._copy_fn = None
-        self._rewind_fn = None
-        self._empty_cache_shapes = None
-        self._kv_stats_static = None
         self._cache_plan_published = False
-        self._clocks: Optional[_LaneClocks] = None     # made by each run
+        self._clocks: Optional[LaneClocks] = None      # made by each run
         # set to keep what a run that ends with a step in flight leaves on
         # the device (``LanesAtExit``) in ``lanes_at_exit`` until the next
         # run or until the holder drops it: a whole lane cache stays
@@ -719,19 +565,11 @@ class ContinuousBatchingScheduler:
     def _ensure_compiled(self):
         eng = self.engine
         set_default_topology(eng.topology)
-        # the engine's param-shape init traces the TRAINING forward, whose
-        # sparse layout requires block-divisible T with at least the full
-        # window of blocks present (sparsity_config make_layout); param
-        # shapes don't depend on B or T, so one [1, T_probe] probe does
+        # param shapes don't depend on B or T, so one [1, T_probe] probe does
         if eng._params is None or not hasattr(eng, "_param_shardings"):
-            t_probe = self.prompt_bucket
-            sc = getattr(self._mcfg, "sparse_attention", None)
-            nswb = getattr(sc, "num_sliding_window_blocks", None)
-            blk = getattr(sc, "block", None)
-            if nswb and blk:
-                t_probe = max(t_probe, int(nswb) * int(blk))
-            eng._materialize(
-                jnp.zeros((1, self._bucketed_len(t_probe)), jnp.int32))
+            eng._materialize(jnp.zeros(
+                (1, probe_length(self._mcfg, self.prompt_bucket)),
+                jnp.int32))
         if eng._prefill_fn is None:
             eng._build_decode_fns()
         if not self._cache_plan_published:
@@ -741,7 +579,7 @@ class ContinuousBatchingScheduler:
             )
 
             self._cache_plan_published = True
-            kv = self._kv_geometry()
+            kv = self.lane_cache.geometry()
             block = self._decode_attention_block()
             publish(KIND_SERVE_CACHE_PLAN, slots=self.slots,
                     decode_attention="none" if block == 0
@@ -755,17 +593,11 @@ class ContinuousBatchingScheduler:
         de = self.draft_engine
         if de is None:
             return
-        # the draft engine compiles the same way, probed at ITS layout's
-        # minimum trace length (its sparse config may differ)
+        # the draft engine likewise, probed at ITS layout's length
         if de._params is None or not hasattr(de, "_param_shardings"):
-            t_probe = self.prompt_bucket
-            sc = getattr(self._draft_mcfg, "sparse_attention", None)
-            nswb = getattr(sc, "num_sliding_window_blocks", None)
-            blk = getattr(sc, "block", None)
-            if nswb and blk:
-                t_probe = max(t_probe, int(nswb) * int(blk))
-            de._materialize(
-                jnp.zeros((1, self._bucketed_len(t_probe)), jnp.int32))
+            de._materialize(jnp.zeros(
+                (1, probe_length(self._draft_mcfg, self.prompt_bucket)),
+                jnp.int32))
         if de._prefill_fn is None:
             de._build_decode_fns()
 
@@ -775,15 +607,10 @@ class ContinuousBatchingScheduler:
         transformer_lm.py ``decode_attention_block``; a module without a
         ``GPTConfig`` has no such kernel), 0 where a lane's cache holds no
         keys and values at all (a model whose token mixer is not attention
-        says so: ``GPTConfig.has_kv_cache``)."""
-        from deepspeed_tpu.models.transformer_lm import (
-            GPTConfig,
-            decode_attention_block,
-        )
-
+        declares no leaf of kind "position")."""
         if not isinstance(self._mcfg, GPTConfig):
             return None
-        if not self._mcfg.has_kv_cache:
+        if not self._mcfg.position_leaves:
             return 0
         return decode_attention_block(self._mcfg)
 
@@ -793,87 +620,16 @@ class ContinuousBatchingScheduler:
         tensor, ``"none"`` for a model without experts (the model decides,
         by the rule it traces under: moe/experts.py ``expert_matrices``; a
         step sorts ``slots * moe_top_k`` rows a layer)."""
-        from deepspeed_tpu.models.transformer_lm import GPTConfig
-        from deepspeed_tpu.moe.experts import expert_matrices
-
         if not isinstance(self._mcfg, GPTConfig):
             return "none"
         return expert_matrices(
             self._mcfg, self.slots * self._mcfg.moe_top_k, decode=True)
 
-    def _cache_shapes_for(self, eng):
-        """Leaf geometry (jax.eval_shape, nothing materialized) of one
-        ``[slots]``-lane decode cache for ``eng``'s model."""
-        model = eng.module
-        probe = jnp.zeros((self.slots, 1), jnp.int32)
-
-        def shape_fn(params):
-            _, vars_out = model.apply(
-                {"params": eng._dequant(params)}, probe,
-                deterministic=True, decode=True, mutable=["cache"])
-            return vars_out["cache"]
-
-        return jax.eval_shape(shape_fn, eng._params)
-
-    def _cache_shapes(self):
-        """The TARGET engine's cache geometry, memoized — `_empty_cache`
-        initializes lanes from it and `kv_cache_stats` accounts resident
-        bytes from it without allocating anything."""
-        if self._empty_cache_shapes is None:
-            self._empty_cache_shapes = self._cache_shapes_for(self.engine)
-        return self._empty_cache_shapes
-
     def _empty_cache(self, eng=None):
-        """A ``[slots]``-lane cache with every per-row clock at its virgin
-        value, WITHOUT running the model (a real apply would advance
-        ``cache_index``/``position`` and bake garbage into ``slot_pos``):
-        eval_shape the decode apply for the leaf geometry, then initialize
-        by name — ``slot_pos`` is -1 (no position cached), everything else
-        zeros (``valid`` bools are False, clocks are 0). ``eng`` defaults
-        to the target engine; pass the draft engine for its lane cache.
-        The leaves are made on the sharding that the splice and decode
-        programs hand back (committed, as every jitted result is when an
-        argument is): an uncommitted first cache would be a second
-        specialisation of each program that takes it."""
-        if eng is None or eng is self.engine:
-            eng = self.engine
-            shapes = self._cache_shapes()
-        else:
-            shapes = self._cache_shapes_for(eng)
-        where = eng.topology.replicated()
-
-        def init_leaf(path, sd):
-            name = path[-1].key if hasattr(path[-1], "key") else path[-1]
-            if name == "slot_pos":
-                return jnp.full(sd.shape, -1, sd.dtype, device=where)
-            return jnp.zeros(sd.shape, sd.dtype, device=where)
-
-        return jax.tree_util.tree_map_with_path(init_leaf, shapes)
-
-    def _splice(self, cache, sub_cache, lane):
-        """Write a freshly prefilled ``[1, ...]`` cache into batch lane
-        ``lane`` of the full cache. The batch axis differs per leaf (flax
-        nn.scan caches carry a leading layer axis: ``[L, B, ...]`` vs the
-        top-level ``position``/``cache_index`` at ``[B]``), so each leaf
-        locates its own first differing axis. Jitted once, lane traced."""
-        if self._splice_fn is None:
-
-            def splice(full, sub, lane_idx):
-                def one(f, s):
-                    if f.shape == s.shape:  # slots == 1
-                        return s
-                    ax = next(i for i, (a, b)
-                              in enumerate(zip(f.shape, s.shape)) if a != b)
-                    starts = tuple(lane_idx if i == ax else 0
-                                   for i in range(f.ndim))
-                    return jax.lax.dynamic_update_slice(f, s, starts)
-
-                return jax.tree.map(one, full, sub)
-
-            self._splice_fn = DispatchedProgram(
-                jax.jit(splice, donate_argnums=(0,)),
-                key=lambda a: _first_leaf_shape(a[1]))
-        return self._splice_fn(cache, sub_cache, jnp.int32(lane))
+        """The target engine's empty lane cache (``LaneLayout.empty``), or
+        the draft engine's when handed it."""
+        return (self.lane_cache if eng is None or eng is self.engine
+                else self.draft_lane_cache).empty()
 
     def _set_token(self, tok_dev, lane, token):
         """Write an admitted lane's first token into the ``[slots]`` token
@@ -895,102 +651,20 @@ class ContinuousBatchingScheduler:
             token = np.asarray([token], np.int32)
         return self._set_token_fn(tok_dev, np.int32(lane), token)
 
-    def _copy_tree(self, tree):
-        """Jitted deep copy of a cache pytree. Continuation prefill DONATES
-        its cache argument, so both the cached entry handed to a lane and
-        the snapshot taken at a promotion boundary must be fresh buffers —
-        extending a cached tree in place would invalidate the cache."""
-        if self._copy_fn is None:
-
-            def copy_tree(t):
-                return jax.tree.map(jnp.copy, t)
-
-            self._copy_fn = DispatchedProgram(
-                jax.jit(copy_tree), key=lambda a: _first_leaf_shape(a[0]))
-        return self._copy_fn(tree)
-
-    def _rewind(self, snapshot, cache, delta):
-        """Step every per-row cache clock back by ``delta[B]`` REJECTED
-        tokens, restoring from ``snapshot`` (the copy taken before the
-        speculative pass) every entry those rejected writes clobbered.
-
-        Selective per-slot restore, not a wholesale snapshot swap: the
-        accepted prefix's writes must SURVIVE — they are exactly the
-        writes sequential decode would have made — so a slot is stale
-        (take snapshot) iff its entry was written at a position at or
-        past the new clock: ring caches compare ``slot_pos`` against the
-        new ``cache_index``, dense caches compare the storage position
-        itself (storage index == semantic position). ``cache_index`` and
-        the top-level ``position`` counters step back by delta. Ragged
-        per-lane acceptance is just a ragged ``delta``. Jitted once;
-        only the live cache is donated (each output leaf can reuse at
-        most one input buffer, so donating the snapshot too would just
-        warn)."""
-        if self._rewind_fn is None:
-            from collections.abc import Mapping
-
-            per_position = self._position_leaves
-
-            def rewind(c0, c1, d):
-                def rewind_attn(a0, a1):
-                    ci = a1["cache_index"]
-                    # ci is [B] ([L, B] under nn.scan); d broadcasts up
-                    idx_new = ci - d.astype(ci.dtype)
-                    if "slot_pos" in a1:
-                        stale = a1["slot_pos"] >= idx_new[..., None]
-                    else:
-                        name, rank = next(iter(per_position.items()))
-                        s_len = a1[name].shape[1 - rank]
-                        pos = jnp.arange(s_len, dtype=ci.dtype)
-                        stale = pos >= idx_new[..., None]
-                    out = {}
-                    for k in a1:
-                        if k == "cache_index":
-                            out[k] = idx_new
-                            continue
-                        v0, v1 = a0[k], a1[k]
-                        m = stale.reshape(
-                            stale.shape + (1,) * (v1.ndim - stale.ndim))
-                        out[k] = jnp.where(m, v0, v1)
-                    return out
-
-                def walk(t0, t1, top):
-                    out = {}
-                    for k in t1:
-                        v1 = t1[k]
-                        if isinstance(v1, Mapping):
-                            if "cache_index" in v1:
-                                out[k] = rewind_attn(t0[k], v1)
-                            else:
-                                out[k] = walk(t0[k], v1, False)
-                        elif top and k == "position":
-                            out[k] = v1 - d.astype(v1.dtype)
-                        else:
-                            out[k] = v1
-                    return out
-
-                return walk(c0, c1, True)
-
-            self._rewind_fn = DispatchedProgram(
-                jax.jit(rewind, donate_argnums=(1,)),
-                key=lambda a: _first_leaf_shape(a[1]))
-        return self._rewind_fn(snapshot, cache, delta)
-
     def program_scopes(self) -> Dict[str, Dict[str, Optional[str]]]:
         """``{program_name: {hlo_instruction_name: op_name_path}}`` of every
         program this scheduler has dispatched: the engine's prefill and
         decode programs (and the draft engine's), and its own splice,
         first-token, copy and rewind programs (telemetry/scopes.py; see
-        ``InferenceEngine.program_scopes``). ``_empty_cache`` fills its
-        leaves eagerly and has no program of its own. After the window,
-        never inside it."""
+        ``InferenceEngine.program_scopes``). After the window, never
+        inside it."""
         programs = list(self.engine.step_programs())
         if self.draft_engine is not None:
             programs += self.draft_engine.step_programs()
-        programs += [p for p in (self._splice_fn, self._set_token_fn,
-                                 self._copy_fn, self._rewind_fn)
-                     if p is not None]
-        return programs_scope_table(programs, self._recurrent)
+        programs += self.lane_cache.programs()
+        if self._set_token_fn is not None:
+            programs.append(self._set_token_fn)
+        return programs_scope_table(programs, self.lane_cache.leaves)
 
     def program_builds(self, before: Optional[float] = None):
         """What this process built so far, by JAX's own account: the
@@ -1158,7 +832,7 @@ class ContinuousBatchingScheduler:
         ``kv_bytes`` and, apart, ``latent_bytes_per_lane``."""
         from deepspeed_tpu.telemetry.memory import hbm_bytes
 
-        out = dict(self._kv_geometry())
+        out = dict(self.lane_cache.geometry())
         hbm, source = hbm_bytes(override_gib=hbm_override_gib)
         if hbm:
             out["hbm_bytes"] = int(hbm)
@@ -1167,69 +841,6 @@ class ContinuousBatchingScheduler:
             out["lanes_at_hbm_budget"] = (int(hbm // per_lane)
                                           if per_lane else 0)
         return out
-
-    def _kv_geometry(self) -> Dict[str, Any]:
-        """What ``kv_cache_stats`` says without asking for the HBM size:
-        the bytes of the memoized leaf geometry, computed once."""
-        if self._kv_stats_static is None:
-            shapes = self._cache_shapes()
-            compute_dt = jnp.dtype(getattr(self._mcfg, "dtype",
-                                           jnp.float32))
-            resident = 0
-            unquant = 0
-            # the declared recurrent leaves apart from keys, values and
-            # their clocks, each in the sums its declaration names
-            counted = {leaf.name: leaf.counted_as for leaf in self._recurrent}
-            apart = {"state": 0, "conv": 0, "norm": 0}
-            recurrent = 0
-
-            # latent attention's leaves: part of ``kv_bytes``, also apart
-            from deepspeed_tpu.models.latent_attention import \
-                LATENT_LEAVES as latent_leaves
-
-            latent = 0
-
-            def acc(path, sd):
-                nonlocal resident, unquant, recurrent, latent
-                name = path[-1].key if hasattr(path[-1], "key") \
-                    else path[-1]
-                nbytes = sd.size * jnp.dtype(sd.dtype).itemsize
-                resident += nbytes
-                if name in counted:
-                    recurrent += nbytes
-                    for part in counted[name]:
-                        apart[part] += nbytes
-                if name in self._position_leaves:
-                    unquant += sd.size * compute_dt.itemsize
-                    if name in latent_leaves:
-                        latent += nbytes
-                elif name.endswith("_scale"):
-                    pass  # sideband of the int8 store; the twin has none
-                else:
-                    unquant += nbytes
-
-            jax.tree_util.tree_map_with_path(acc, shapes)
-            kv_bytes = resident - recurrent
-            self._kv_stats_static = {
-                "kv_cache_dtype": (getattr(self._mcfg, "kv_cache_dtype",
-                                           None) or "compute"),
-                "resident_bytes": int(resident),
-                "unquantized_bytes": int(unquant),
-                "bytes_per_lane": int(resident // self.slots),
-                "state_bytes": int(apart["state"]),
-                "conv_bytes": int(apart["conv"]),
-                "norm_bytes": int(apart["norm"]),
-                "kv_bytes": int(kv_bytes),
-                "state_bytes_per_lane": int(apart["state"] // self.slots),
-                "conv_bytes_per_lane": int(apart["conv"] // self.slots),
-                "norm_bytes_per_lane": int(apart["norm"] // self.slots),
-                "kv_bytes_per_lane": int(kv_bytes // self.slots),
-                "latent_bytes_per_lane": int(latent // self.slots),
-                "lanes": self.slots,
-                "compression_ratio": (float(unquant) / float(resident)
-                                      if resident else 1.0),
-            }
-        return self._kv_stats_static
 
     def frontdoor_stats(self) -> Dict[str, Any]:
         """Shed + prefix-cache + health counters for benches/servers."""
@@ -1257,8 +868,7 @@ class ContinuousBatchingScheduler:
                                 if self.spec_proposed else 0.0)}
         # gated on the geometry already being traced (run() does it):
         # frontdoor_stats must stay safe on fake/unmaterialized engines
-        if self._empty_cache_shapes is not None or \
-                self._kv_stats_static is not None:
+        if self._cache_plan_published:
             out["kv_cache"] = self.kv_cache_stats()
         return out
 
@@ -1330,9 +940,8 @@ class ContinuousBatchingScheduler:
             for step in unread:
                 jax.block_until_ready(step[0])
             if self.retain_lanes and unread:
-                self.lanes_at_exit = LanesAtExit(*unread[-1][1:])
-                self.lanes_at_exit.recurrent_leaves = self._recurrent
-                self.lanes_at_exit.position_leaves = self._position_leaves
+                self.lanes_at_exit = self.lane_cache.at_exit(
+                    *unread[-1][1:])
 
     def _run(self, poll_fn, unread) -> ServingStats:
         self._ensure_compiled()
@@ -1351,8 +960,8 @@ class ContinuousBatchingScheduler:
         tok = np.zeros((self.slots,), np.int32)
         tok_dev = None if use_spec else jax.device_put(tok, where)
         cache = self._empty_cache()
-        self._clocks = _LaneClocks(stats, self.slots, self._max_pos,
-                                   self._decode_attention_block())
+        self._clocks = LaneClocks(stats, self.slots, self._max_pos,
+                                  self._decode_attention_block())
         eng._rng, rng = jax.random.split(eng._rng)
         rng = jax.device_put(rng, where)
         temp = jnp.float32(self.temperature)

@@ -77,7 +77,6 @@ from deepspeed_tpu.telemetry.scopes import (
 
 CACHED_LATENT = "cached_latent"
 CACHED_ROPE_KEY = "cached_rope_key"
-LATENT_LEAVES = (CACHED_LATENT, CACHED_ROPE_KEY)
 
 
 @struct.dataclass

@@ -27,6 +27,7 @@ from deepspeed_tpu.moe.layer import MOE_STATS
 from deepspeed_tpu.runtime.zero.gather import gather_tree, gathered_on_use
 from deepspeed_tpu.telemetry.scopes import (
     SCOPE_ATTN_CORE,
+    SCOPE_KV_CACHE_CARRY,
     SCOPE_KV_CACHE_READ,
     SCOPE_KV_CACHE_WRITE,
     SCOPE_LM_HEAD,
@@ -37,22 +38,26 @@ from deepspeed_tpu.telemetry.scopes import (
 
 
 @dataclasses.dataclass(frozen=True)
-class RecurrentLeaf:
-    """One leaf of the decode cache that holds recurrent state: what a
-    model whose mixer keeps one declares, once
-    (``GPTConfig.recurrent_leaves``), and what the scheduler's refusals and
-    byte accounting, ``LanesAtExit`` and the scope table's carry tags
-    read. Unlike keys and values such a leaf cannot be cut at a prefix."""
+class CacheLeaf:
+    """One leaf of the decode cache that holds what a lane keeps: declared
+    once, by the mixer that writes it (``GPTConfig.cache_leaves``), and
+    read from there by whatever has to know a lane cache's layout
+    (inference/lane_cache.py; the scope table's carry tags)."""
     name: str            # in the ``cache`` collection
     rank: int            # of one layer's ``[B, ...]`` leaf
-    dtype: Any           # as stored
-    # which sums of ``kv_cache_stats`` the leaf's bytes enter
+    # "position": a row a position, so the cache can be cut at a prefix
+    # and stepped back; "recurrent": a state, which can be neither
+    kind: str
+    # which sums of ``kv_cache_stats`` the leaf's bytes enter beside
+    # ``resident_bytes``: "state", "conv", "norm", "latent"; "sideband"
+    # (an int8 store's scales) enters none, and no unquantised twin
     counted_as: Tuple[str, ...]
     # the scope table's tag of an instruction that no scope owns and whose
     # result is this whole leaf (the stacked one, and one layer's slice of
     # it where ``slice_is_whole``)
     carry_tag: str
     slice_is_whole: bool = True
+    dtype: Any = None    # as stored, where the leaf has a dtype of its own
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,16 +90,17 @@ class SSMConfig:
         if len(self.multipliers) != 5:
             raise ValueError("ssm multipliers are five: z, x, B, C, dt")
 
-    def recurrent_leaves(self, cfg) -> Tuple[RecurrentLeaf, ...]:
+    def cache_leaves(self, cfg) -> Tuple[CacheLeaf, ...]:
         """(models/mamba2.py) One layer's convolution tail is what the
         convolution itself concatenates in front of its input (kilobytes a
         lane), so only the stacked tail counts as a whole leaf."""
         from deepspeed_tpu.models.mamba2 import CONV_TAIL, SSM_STATE
 
-        return (RecurrentLeaf(SSM_STATE, 4, self.state_dtype, ("state",),
-                              SCOPE_SSM_STATE_CARRY),
-                RecurrentLeaf(CONV_TAIL, 3, cfg.dtype, ("conv",),
-                              SCOPE_SSM_STATE_CARRY, slice_is_whole=False))
+        return (CacheLeaf(SSM_STATE, 4, "recurrent", ("state",),
+                          SCOPE_SSM_STATE_CARRY, dtype=self.state_dtype),
+                CacheLeaf(CONV_TAIL, 3, "recurrent", ("conv",),
+                          SCOPE_SSM_STATE_CARRY, slice_is_whole=False,
+                          dtype=cfg.dtype))
 
     @property
     def d_inner(self) -> int:
@@ -131,20 +137,38 @@ class RetentionConfig:
         if self.chunk < 1:
             raise ValueError(f"retention chunk must be >= 1; {self.chunk}")
 
-    def recurrent_leaves(self, cfg) -> Tuple[RecurrentLeaf, ...]:
+    def cache_leaves(self, cfg) -> Tuple[CacheLeaf, ...]:
         """One layer's slice of the normaliser has the shape of ``phi(k)``
         itself, so only the stacked one counts as a whole leaf."""
         from deepspeed_tpu.models.power_retention import RET_NORM, RET_STATE
 
-        return (RecurrentLeaf(RET_STATE, 4, self.state_dtype, ("state",),
-                              SCOPE_RET_STATE_CARRY),
-                RecurrentLeaf(RET_NORM, 3, self.state_dtype,
-                              ("state", "norm"), SCOPE_RET_STATE_CARRY,
-                              slice_is_whole=False))
+        return (CacheLeaf(RET_STATE, 4, "recurrent", ("state",),
+                          SCOPE_RET_STATE_CARRY, dtype=self.state_dtype),
+                CacheLeaf(RET_NORM, 3, "recurrent", ("state", "norm"),
+                          SCOPE_RET_STATE_CARRY, slice_is_whole=False,
+                          dtype=self.state_dtype))
 
 
-# ``GPTConfig.position_leaves`` of attention with keys and values per head
-KV_LEAVES = (("cached_key", 4), ("cached_value", 4))
+def attention_cache_leaves(cfg=None) -> Tuple[CacheLeaf, ...]:
+    """What ``CausalSelfAttention`` keeps: keys and values per head and,
+    in an int8 store (``GPTConfig.kv_cache_dtype``), a scale per
+    (position, KV head) beside each. ``cfg`` None is a module that
+    declares nothing, taken to keep keys and values."""
+    leaves = tuple(CacheLeaf(name, 4, "position", (), SCOPE_KV_CACHE_CARRY)
+                   for name in ("cached_key", "cached_value"))
+    if getattr(cfg, "kv_cache_dtype", None) == "int8":
+        leaves += tuple(
+            CacheLeaf(leaf.name + "_scale", 3, "position", ("sideband",),
+                      SCOPE_KV_CACHE_CARRY, dtype=jnp.float32)
+            for leaf in leaves)
+    return leaves
+
+
+def declared_cache_leaves(config) -> Tuple[CacheLeaf, ...]:
+    """``config.cache_leaves``, or attention's for a module whose
+    configuration declares nothing."""
+    leaves = getattr(config, "cache_leaves", None)
+    return attention_cache_leaves() if leaves is None else leaves
 
 
 class LatentCacheError(ValueError):
@@ -190,6 +214,19 @@ class MLAConfig:
     def __post_init__(self):
         if self.rope_dim % 2:
             raise ValueError(f"rope_dim must be even; got {self.rope_dim}")
+
+    def cache_leaves(self, cfg) -> Tuple[CacheLeaf, ...]:
+        """(models/latent_attention.py) A latent and a rotary key a
+        position, no heads: part of ``kv_bytes`` and, apart,
+        ``latent_bytes_per_lane``."""
+        from deepspeed_tpu.models.latent_attention import (
+            CACHED_LATENT,
+            CACHED_ROPE_KEY,
+        )
+
+        return tuple(CacheLeaf(name, 3, "position", ("latent",),
+                               SCOPE_KV_CACHE_CARRY)
+                     for name in (CACHED_LATENT, CACHED_ROPE_KEY))
 
     @property
     def qk_dim(self) -> int:
@@ -519,40 +556,31 @@ class GPTConfig:
                     f"of the {self.moe_num_experts} experts")
 
     @property
+    def cache_leaves(self) -> Tuple[CacheLeaf, ...]:
+        """What a lane keeps in the decode cache, as the model's mixers
+        declare it: attention's keys and values, or latent attention's
+        latent and rotary key, or none where retention is the mixer, and
+        the state of the mixers that keep one. The one place that knows
+        which mixers a block runs."""
+        attention = (self.mla.cache_leaves(self) if self.mla is not None
+                     else () if self.retention is not None
+                     else attention_cache_leaves(self))
+        return attention + tuple(
+            leaf for mixer in (self.ssm, self.retention)
+            if mixer is not None for leaf in mixer.cache_leaves(self))
+
+    @property
     def position_leaves(self) -> Tuple[Tuple[str, int], ...]:
-        """``(name, rank of one layer's [B, S, ...] leaf)`` of the leaves
-        of the decode cache that hold something PER POSITION, the kind a
-        cache can be cut at a prefix of: keys and values per head, or
-        latent attention's latent and rotary key; none for a model whose
-        mixer keeps a state alone. The one place that knows; the
-        scheduler's rewind and byte accounting and the disaggregated
-        hand-off read it."""
-        if self.mla is not None:
-            from deepspeed_tpu.models.latent_attention import (
-                CACHED_LATENT,
-                CACHED_ROPE_KEY,
-            )
-
-            return ((CACHED_LATENT, 3), (CACHED_ROPE_KEY, 3))
-        if self.retention is not None:
-            return ()
-        return KV_LEAVES
+        """``(name, rank)`` of the leaves that hold a model's own values
+        per position (keys and values, or latents; no sideband)."""
+        return tuple((leaf.name, leaf.rank) for leaf in self.cache_leaves
+                     if leaf.kind == "position"
+                     and "sideband" not in leaf.counted_as)
 
     @property
-    def recurrent_leaves(self) -> Tuple[RecurrentLeaf, ...]:
-        """The leaves of the decode cache that hold recurrent state, as
-        the model's mixers declare them; empty for a model whose cache is
-        keys and values alone. The one place that knows which mixers keep
-        a state."""
-        return tuple(leaf for mixer in (self.ssm, self.retention)
-                     if mixer is not None
-                     for leaf in mixer.recurrent_leaves(self))
-
-    @property
-    def has_kv_cache(self) -> bool:
-        """Whether a lane's cache holds anything per position at all (keys
-        and values, or latents)."""
-        return bool(self.position_leaves)
+    def recurrent_leaves(self) -> Tuple[CacheLeaf, ...]:
+        return tuple(leaf for leaf in self.cache_leaves
+                     if leaf.kind == "recurrent")
 
     @property
     def head_dim(self) -> int:

@@ -19,12 +19,12 @@ analogue of ``deepspeed_tpu.initialize(config=...)``.
 
 from typing import Any, Dict, Optional
 
+from deepspeed_tpu.inference.lane_cache import RecurrentStateError
 from deepspeed_tpu.inference.scheduler import (
     AdmissionRejected,
     ContinuousBatchingScheduler,
     DeadlineExceededError,
     DrainingError,
-    RecurrentStateError,
     QueueFullError,
     RequestShedError,
 )

@@ -52,6 +52,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.inference.engine import probe_length
+from deepspeed_tpu.inference.lane_cache import LaneLayout
 from deepspeed_tpu.ops.sparse_attention.sparse_attention_utils import (
     ring_engaged,
 )
@@ -70,22 +72,10 @@ def tree_nbytes(tree) -> int:
         for leaf in jax.tree.leaves(tree) if hasattr(leaf, "dtype")))
 
 
-def _probe_len(mcfg, bucket: int, bucketed) -> int:
-    """Trace length for engine materialization: the training forward
-    needs block-divisible T with the full window of blocks present
-    (same probe the scheduler's _ensure_compiled uses)."""
-    t_probe = bucket
-    sc = getattr(mcfg, "sparse_attention", None)
-    nswb = getattr(sc, "num_sliding_window_blocks", None)
-    blk = getattr(sc, "block", None)
-    if nswb and blk:
-        t_probe = max(t_probe, int(nswb) * int(blk))
-    return bucketed(t_probe)
-
-
 def lane_kv_bytes(model, slots: int = 1) -> Dict[str, int]:
-    """Per-lane decode KV-cache footprint for ``model`` — pure
-    ``eval_shape``, no parameters materialized, so sizing a 70B-scale
+    """Per-lane decode KV-cache footprint for ``model``: the scheduler's
+    own accounting (inference/lane_cache.py ``LaneLayout.geometry``) over
+    pure ``eval_shape``, no parameters materialized, so sizing a 70B-scale
     capacity table costs microseconds.
 
     Returns ``resident_bytes`` (what this cache stores: int8 payloads +
@@ -94,47 +84,9 @@ def lane_kv_bytes(model, slots: int = 1) -> Dict[str, int]:
     lanes-per-HBM capacity tables in docs/performance.md divide the HBM
     budget by these.
     """
-    mcfg = model.config
-    ring = ring_engaged(mcfg)
-    blk = ring[2] if ring is not None else 64
-    t_probe = _probe_len(mcfg, blk,
-                         lambda t: ((t + blk - 1) // blk) * blk)
-    init_probe = jnp.zeros((1, t_probe), jnp.int32)
-    pshapes = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), init_probe,
-                           deterministic=True))["params"]
-    probe = jnp.zeros((slots, 1), jnp.int32)
-
-    def shape_fn(params):
-        _, vars_out = model.apply({"params": params}, probe,
-                                  deterministic=True, decode=True,
-                                  mutable=["cache"])
-        return vars_out["cache"]
-
-    shapes = jax.eval_shape(shape_fn, pshapes)
-    compute_dt = jnp.dtype(getattr(mcfg, "dtype", jnp.float32))
-    # which leaves are per position is the model's to say
-    from deepspeed_tpu.models.transformer_lm import KV_LEAVES
-
-    per_position = dict(getattr(mcfg, "position_leaves", KV_LEAVES))
-    resident = 0
-    unquant = 0
-
-    def acc(path, sd):
-        nonlocal resident, unquant
-        name = path[-1].key if hasattr(path[-1], "key") else path[-1]
-        nbytes = sd.size * jnp.dtype(sd.dtype).itemsize
-        resident += nbytes
-        if name in per_position:
-            unquant += sd.size * compute_dt.itemsize
-        elif name.endswith("_scale"):
-            pass  # sideband of the int8 store; the unquantized twin has none
-        else:
-            unquant += nbytes
-
-    jax.tree_util.tree_map_with_path(acc, shapes)
-    return {"resident_bytes": int(resident // slots),
-            "unquantized_bytes": int(unquant // slots)}
+    kv = LaneLayout(model, slots).geometry()
+    return {"resident_bytes": kv["resident_bytes"] // slots,
+            "unquantized_bytes": kv["unquantized_bytes"] // slots}
 
 
 @dataclass
@@ -185,8 +137,8 @@ class PrefillWorker:
         eng = self.engine
         if eng._params is None or not hasattr(eng, "_param_shardings"):
             eng._materialize(jnp.zeros(
-                (1, _probe_len(self._mcfg, self.prompt_bucket,
-                               self._bucketed)), jnp.int32))
+                (1, probe_length(self._mcfg, self.prompt_bucket)),
+                jnp.int32))
         if eng._prefill_fn is None:
             eng._build_decode_fns()
 

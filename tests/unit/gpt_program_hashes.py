@@ -10,8 +10,11 @@ PR 34 moved decode attention of one query token over dense storage into a
 Pallas kernel: ``jit_decode_k`` ALONE was recorded again, on that PR's
 tree, and the other five entries stand as recorded on 534fd1d. The
 ``fallback_hashes`` entries (the decode calls that kernel does not take)
-were recorded on PR 34's parent, d382f5d. The hybrid, retention and latent
-entries say in their functions' docstrings where each was recorded."""
+were recorded on PR 34's parent, d382f5d. The hybrid, retention, latent and
+speculative entries say in their functions' docstrings where each was
+recorded. The splice, copy and rewind programs are reached through the
+scheduler's ``lane_cache`` (inference/lane_cache.py) since PR 46, which
+moved them there; what they lower to is what it was."""
 import hashlib
 import json
 import os
@@ -59,15 +62,15 @@ def serve_hashes():
             eng._prefill_fn.fn.lower(eng.params, ids, mask).as_text())
         subs[bucket] = jax.eval_shape(eng._prefill_fn.fn, eng.params, ids,
                                       mask)[1]
-    cache = sched._cache_shapes()
+    cache = sched.lane_cache.shapes
     out["jit_decode_k"] = _sha(eng._decode_k_fn.fn.lower(
         eng.params, jnp.zeros((4,), jnp.int32), cache,
         jax.random.PRNGKey(0), jnp.float32(0.0), 1).as_text())
     full = sched._empty_cache()
     sub = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), subs[64])
     sched._splice(full, sub, 1)
-    out["jit_splice"] = _sha(sched._splice_fn.fn.lower(
-        sched._cache_shapes(), subs[64], jnp.int32(1)).as_text())
+    out["jit_splice"] = _sha(sched.lane_cache._splice_fn.fn.lower(
+        sched.lane_cache.shapes, subs[64], jnp.int32(1)).as_text())
     return out
 
 
@@ -205,13 +208,13 @@ def hybrid_hashes():
     out = {"hybrid_jit_prefill[32]": _sha(
         eng._prefill_fn.fn.lower(eng.params, ids, mask).as_text())}
     sub = jax.eval_shape(eng._prefill_fn.fn, eng.params, ids, mask)[1]
-    cache = sched._cache_shapes()
+    cache = sched.lane_cache.shapes
     out["hybrid_jit_decode_k"] = _sha(eng._decode_k_fn.fn.lower(
         eng.params, jnp.zeros((3,), jnp.int32), cache,
         jax.random.PRNGKey(0), jnp.float32(0.0), 1).as_text())
     sched._splice(sched._empty_cache(),
                   jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), sub), 1)
-    out["hybrid_jit_splice"] = _sha(sched._splice_fn.fn.lower(
+    out["hybrid_jit_splice"] = _sha(sched.lane_cache._splice_fn.fn.lower(
         cache, sub, jnp.int32(1)).as_text())
     return out
 
@@ -243,7 +246,7 @@ def retention_hashes():
         "retention_jit_prefill[32]": _sha(
             eng._prefill_fn.fn.lower(eng.params, ids, mask).as_text()),
         "retention_jit_decode_k": _sha(eng._decode_k_fn.fn.lower(
-            eng.params, jnp.zeros((3,), jnp.int32), sched._cache_shapes(),
+            eng.params, jnp.zeros((3,), jnp.int32), sched.lane_cache.shapes,
             jax.random.PRNGKey(0), jnp.float32(0.0), 1).as_text())}
 
 
@@ -279,13 +282,13 @@ def latent_hashes():
     out = {"latent_jit_prefill[32]": _sha(
         eng._prefill_fn.fn.lower(eng.params, ids, mask).as_text())}
     sub = jax.eval_shape(eng._prefill_fn.fn, eng.params, ids, mask)[1]
-    cache = sched._cache_shapes()
+    cache = sched.lane_cache.shapes
     out["latent_jit_decode_k"] = _sha(eng._decode_k_fn.fn.lower(
         eng.params, jnp.zeros((3,), jnp.int32), cache,
         jax.random.PRNGKey(0), jnp.float32(0.0), 1).as_text())
     sched._splice(sched._empty_cache(),
                   jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), sub), 1)
-    out["latent_jit_splice"] = _sha(sched._splice_fn.fn.lower(
+    out["latent_jit_splice"] = _sha(sched.lane_cache._splice_fn.fn.lower(
         cache, sub, jnp.int32(1)).as_text())
     out["latent_jit_prefill_more[16]"] = _sha(eng._prefill_more_fn.fn.lower(
         eng.params, jnp.zeros((1, 16), jnp.int32),
@@ -293,9 +296,56 @@ def latent_hashes():
     return out
 
 
+def speculative_hashes():
+    """The programs that only a speculative scheduler dispatches,
+    ``jit_copy_tree`` and ``jit_rewind``, over a dense and over a ring lane
+    cache (``fallback_hashes``' models, the ring one with the slack block
+    speculation needs; each is its own draft), and the plain loop's
+    ``jit_set_token``. Recorded on 7a4dead, the parent of the PR that moved
+    the first two behind inference/lane_cache.py."""
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.inference.scheduler import ContinuousBatchingScheduler
+    from deepspeed_tpu.models.transformer_lm import GPT, GPTConfig
+    from deepspeed_tpu.ops.sparse_attention.sparse_attention_utils import \
+        apply_sparse_attention
+    from deepspeed_tpu.parallel.mesh import reset_default_topology
+
+    base = dict(vocab_size=128, n_positions=256, n_embd=32, n_layer=2,
+                n_head=4, dtype=jnp.float32, scan_layers=True)
+    models = {
+        "dense": GPT(GPTConfig(**base)),
+        "ring": apply_sparse_attention(
+            GPT(GPTConfig(rotary=True, learned_positions=False,
+                          kv_cache_slack_blocks=1, **base)),
+            {"mode": "local_sliding_window", "block": 16,
+             "num_sliding_window_blocks": 3}),
+    }
+    out = {}
+    delta = jnp.zeros((3,), jnp.int32)
+    for name, model in models.items():
+        reset_default_topology()
+        eng = InferenceEngine(model, {"dtype": "fp32"}, seed=0)
+        sched = ContinuousBatchingScheduler(
+            eng, slots=3, prompt_bucket=16, draft_engine=eng, spec_k=2)
+        sched._ensure_compiled()
+        lanes = sched.lane_cache
+        cache = sched._empty_cache()
+        sched._rewind(sched._copy_tree(cache), cache, delta)
+        out["jit_copy_tree[%s]" % name] = _sha(
+            lanes._copy_fn.fn.lower(lanes.shapes).as_text())
+        out["jit_rewind[%s]" % name] = _sha(lanes._rewind_fn.fn.lower(
+            lanes.shapes, lanes.shapes, delta).as_text())
+    sched = ContinuousBatchingScheduler(eng, slots=3, prompt_bucket=16)
+    sched._set_token(delta, 1, jnp.zeros((1,), jnp.int32))
+    out["jit_set_token"] = _sha(sched._set_token_fn.fn.lower(
+        delta, np.int32(1), jnp.zeros((1,), jnp.int32)).as_text())
+    return out
+
+
 def all_hashes():
     return dict(serve_hashes(), **train_hashes(), **fallback_hashes(),
-                **hybrid_hashes(), **retention_hashes(), **latent_hashes())
+                **hybrid_hashes(), **retention_hashes(), **latent_hashes(),
+                **speculative_hashes())
 
 
 if __name__ == "__main__":

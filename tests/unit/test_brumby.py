@@ -15,10 +15,7 @@ import pytest
 import deepspeed_tpu
 from brumby_tiny import TINY_BRUMBY
 from deepspeed_tpu import serving
-from deepspeed_tpu.inference.engine import (
-    kv_leaf_shapes,
-    recurrent_leaf_shapes,
-)
+from deepspeed_tpu.inference.engine import carried_leaf_shapes
 from deepspeed_tpu.models.transformer_lm import GPT, num_params
 from deepspeed_tpu.ops import power_retention as pr
 from deepspeed_tpu.ops.pallas import retention_step as kernel
@@ -429,9 +426,11 @@ def test_the_scheduler_serves_the_references_greedy_tokens_with_lanes_reused(
     assert stats.kv_blocks_read_share_sum == 0.0 and stats.decode_steps > 0
     assert stats.summary()["kv_blocks_read_share"] == 0.0
     assert sched._clocks.step() is None and sched._clocks.block == 0
-    assert not kv_leaf_shapes(sched._cache_shapes())
+    assert scopes.SCOPE_KV_CACHE_CARRY not in carried_leaf_shapes(
+        sched.lane_cache.shapes, eng.module.config.cache_leaves)
     names = {str(p[-1].key) for p, _ in
-             jax.tree_util.tree_flatten_with_path(sched._cache_shapes())[0]}
+             jax.tree_util.tree_flatten_with_path(
+                 sched.lane_cache.shapes)[0]}
     assert names == {"ret_state", "ret_norm", "clock"}
 
 
@@ -595,12 +594,12 @@ def test_layer_loop_carries_state_and_normaliser_in_place():
     cache leaf to its output; and its scope table names the mixer's four
     scopes and tags nothing as a carried whole leaf."""
     eng, sched = served("bfloat16", slots=3)
-    cache = sched._cache_shapes()
+    cache = sched.lane_cache.shapes
     cfg = eng.module.config
     n_layer, D = cfg.n_layer, pr.sympow2_width(HEAD)
     stacked = jax.tree.leaves(cache["h"])
     assert all(leaf.shape[0] == n_layer for leaf in stacked)
-    declared = recurrent_leaf_shapes(cache, cfg.recurrent_leaves)
+    declared = carried_leaf_shapes(cache, cfg.cache_leaves)
     assert set(declared) == {scopes.SCOPE_RET_STATE_CARRY}
     whole = declared[scopes.SCOPE_RET_STATE_CARRY]
     assert whole == {(n_layer, 3, cfg.kv_heads, HEAD, D),
@@ -686,8 +685,8 @@ def test_a_model_declares_its_recurrent_leaves_once():
         ("ssm_state", 4, ("state",), "ssm_state_carry"),
         ("conv_tail", 3, ("conv",), "ssm_state_carry")]
     plain = tiny_gpt_config(n_embd=32, n_layer=1, vocab_size=64)
-    assert plain.recurrent_leaves == () and plain.has_kv_cache
-    assert not model_config().has_kv_cache
+    assert plain.recurrent_leaves == () and plain.position_leaves
+    assert not model_config().position_leaves
     with pytest.raises(ValueError, match="chunk"):
         dataclasses.replace(model_config().retention, chunk=0)
     with pytest.raises(ValueError, match="rotary"):

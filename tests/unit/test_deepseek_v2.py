@@ -22,12 +22,11 @@ import deepspeed_tpu
 import flax.linen as nn
 from deepseek_v2_tiny import TINY_DEEPSEEK
 from deepspeed_tpu import serving
-from deepspeed_tpu.inference.engine import kv_leaf_shapes
+from deepspeed_tpu.inference.engine import carried_leaf_shapes
 from deepspeed_tpu.models import latent_attention as la
 from deepspeed_tpu.models import transformer_lm
 from deepspeed_tpu.models.transformer_lm import (
     GPT,
-    KV_LEAVES,
     GPTConfig,
     LatentCacheError,
     MLAConfig,
@@ -161,8 +160,10 @@ def test_the_cache_is_one_latent_and_one_rotary_key_a_position(fp32):
         "valid": (1, 64), "cache_index": (1,)}
     assert cfg.position_leaves == (("cached_latent", 3),
                                    ("cached_rope_key", 3))
-    assert GPTConfig().position_leaves == KV_LEAVES
-    assert cfg.has_kv_cache and not cfg.recurrent_leaves
+    assert GPTConfig().position_leaves == (
+        ("cached_key", 4), ("cached_value", 4)) \
+        == GPTConfig(kv_cache_dtype="int8").position_leaves
+    assert cfg.position_leaves and not cfg.recurrent_leaves
     _, latent, rope_key = reference.hidden_and_states(
         eng.params, np.asarray(ids[0]), SIZES)
     np.testing.assert_allclose(cache["cached_latent"][:, 0, :16], latent,
@@ -173,8 +174,9 @@ def test_the_cache_is_one_latent_and_one_rotary_key_a_position(fp32):
     assert np.asarray(cache["valid"])[0].tolist() == [True] * 16 + [False] * 48
     # 576 values a position and layer where 4 heads of keys and values
     # would be 4 x (12 + 8)
-    assert kv_leaf_shapes(cache) == {(3, 1, 64, 16), (1, 64, 16),
-                                     (3, 1, 64, 4), (1, 64, 4)}
+    assert carried_leaf_shapes(cache, cfg.cache_leaves) == {
+        "kv_cache_carry": {(3, 1, 64, 16), (1, 64, 16),
+                           (3, 1, 64, 4), (1, 64, 4)}}
 
 
 def test_the_leading_block_is_dense_and_the_rest_hold_their_share(fp32):
@@ -605,8 +607,12 @@ def test_the_serving_programs_carry_the_new_scopes(fp32):
 
 
 def test_latent_leaves_names_are_what_the_model_declares():
-    assert la.LATENT_LEAVES == tuple(
+    assert (la.CACHED_LATENT, la.CACHED_ROPE_KEY) == tuple(
         n for n, _ in model_config().position_leaves)
+    assert [(x.name, x.rank, x.kind, x.counted_as, x.carry_tag)
+            for x in model_config().cache_leaves] == [
+        ("cached_latent", 3, "position", ("latent",), "kv_cache_carry"),
+        ("cached_rope_key", 3, "position", ("latent",), "kv_cache_carry")]
 
 
 # ---------------------------------------------------------------------------
@@ -743,7 +749,7 @@ def test_the_decode_program_hands_the_kernel_the_stacked_leaves(fp32):
     L, S, r, dr = cfg.n_layer, cfg.n_positions, cfg.mla.kv_rank, \
         cfg.mla.rope_dim
     jaxpr = eng._decode_k_fn.fn.trace(
-        eng.params, jnp.zeros((4,), jnp.int32), sched._cache_shapes(),
+        eng.params, jnp.zeros((4,), jnp.int32), sched.lane_cache.shapes,
         jax.random.PRNGKey(0), jnp.float32(0.0), 1).jaxpr.jaxpr
 
     def walk(jaxpr, depth=0):

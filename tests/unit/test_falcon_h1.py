@@ -13,10 +13,7 @@ import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu import serving
-from deepspeed_tpu.inference.engine import (
-    kv_leaf_shapes,
-    recurrent_leaf_shapes,
-)
+from deepspeed_tpu.inference.engine import carried_leaf_shapes
 from deepspeed_tpu.models.transformer_lm import GPT, num_params
 from deepspeed_tpu.ops import ssd
 from deepspeed_tpu.ops.pallas import ssd_step as ssd_kernel
@@ -467,14 +464,15 @@ def test_layer_loop_carries_state_and_tail_in_place():
     scopes. ``hybrid_jit_decode_k`` of ``gpt_program_hashes.json`` was
     recorded anew on the tree that brought the kernel (PR 36, on e55e290)."""
     eng, sched = served("bfloat16", slots=3)
-    cache = sched._cache_shapes()
+    cache = sched.lane_cache.shapes
     n_layer = eng.module.config.n_layer
     stacked = jax.tree.leaves(cache["h"])
     assert all(leaf.shape[0] == n_layer for leaf in stacked)
-    declared = recurrent_leaf_shapes(
-        cache, eng.module.config.recurrent_leaves)
-    assert set(declared) == {scopes.SCOPE_SSM_STATE_CARRY}
-    whole = kv_leaf_shapes(cache) | declared[scopes.SCOPE_SSM_STATE_CARRY]
+    declared = carried_leaf_shapes(cache, eng.module.config.cache_leaves)
+    assert set(declared) == {scopes.SCOPE_KV_CACHE_CARRY,
+                             scopes.SCOPE_SSM_STATE_CARRY}
+    whole = declared[scopes.SCOPE_KV_CACHE_CARRY] \
+        | declared[scopes.SCOPE_SSM_STATE_CARRY]
     m = eng.module.config.ssm
     assert (n_layer, 3, m.n_heads, m.d_head, m.d_state) in whole
     assert (3, m.n_heads, m.d_head, m.d_state) in whole

@@ -120,7 +120,7 @@ class TestKVCacheDecode:
         aliases every cache leaf to its output."""
         import re
 
-        from deepspeed_tpu.inference.engine import kv_leaf_shapes
+        from deepspeed_tpu.inference.engine import carried_leaf_shapes
         from deepspeed_tpu.inference.scheduler import \
             ContinuousBatchingScheduler
 
@@ -131,11 +131,12 @@ class TestKVCacheDecode:
         sched = ContinuousBatchingScheduler(eng, slots=3,
                                             prompt_bucket=bucket)
         sched._ensure_compiled()
-        cache = sched._cache_shapes()
+        cache = sched.lane_cache.shapes
         n_layer = model.config.n_layer
         stacked = jax.tree.leaves(cache["h"])
         assert all(leaf.shape[0] == n_layer for leaf in stacked)
-        whole = kv_leaf_shapes(cache)
+        whole = carried_leaf_shapes(
+            cache, model.config.cache_leaves)["kv_cache_carry"]
         args = (eng.params, jnp.zeros((3,), jnp.int32), cache,
                 jax.random.PRNGKey(0), jnp.float32(0.0), 2)
         decode_k = eng._decode_k_fn.fn

@@ -154,7 +154,8 @@ def test_one_layers_own_leaf_needs_no_layer_index():
 # ---------------------------------------------------------------------------
 # the programs that exist do not move
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("family", ["serve", "hybrid", "retention", "latent"])
+@pytest.mark.parametrize("family", ["serve", "hybrid", "retention", "latent",
+                                    "speculative"])
 def test_lowered_programs_hash_as_recorded(family):
     """``lower(...).as_text()`` of each family's serving programs, byte
     for byte what the commits that recorded them lower
@@ -164,7 +165,10 @@ def test_lowered_programs_hash_as_recorded(family):
     the latent model's prefill, splice and sixteen-token continuation
     were recorded on the parent of the PR that brought its decode kernel
     (the pass that makes the cache and the einsum route lower as they
-    did), its ``jit_decode_k`` on that PR's tree."""
+    did), its ``jit_decode_k`` on that PR's tree; the copy and rewind of
+    a speculative scheduler and the plain loop's ``set_token`` on the
+    parent of the PR that moved the lane cache's programs behind
+    inference/lane_cache.py."""
     from unit import gpt_program_hashes
 
     with open(os.path.join(os.path.dirname(__file__), "data",

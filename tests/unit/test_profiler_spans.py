@@ -348,10 +348,12 @@ def test_serving_scopes_cover_prefill_decode_and_splice(served):
 
 
 def test_kv_leaf_shapes_know_the_stacked_and_the_per_layer_leaf(served):
-    from deepspeed_tpu.inference.engine import kv_leaf_shapes
+    from deepspeed_tpu.inference.engine import carried_leaf_shapes
 
     sched = served[0]
-    shapes = kv_leaf_shapes(sched._cache_shapes())
+    shapes = carried_leaf_shapes(
+        sched.lane_cache.shapes,
+        sched.lane_cache.leaves)[scopes.SCOPE_KV_CACHE_CARRY]
     # [layers, slots, positions, kv heads, head dim] and one layer of it
     assert shapes == {(2, 2, 64, 4, 8), (2, 64, 4, 8)}
 

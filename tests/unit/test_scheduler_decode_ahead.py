@@ -516,7 +516,7 @@ def test_one_specialisation_of_every_program_and_no_compile_after_warm_up():
     assert in_warm.count("jit(prefill)") == len(buckets), in_warm
     assert list(eng._decode_k_fn.avals) == [((SLOTS,), 1)]     # k stays 1
     assert eng._decode_k_fn.fn._cache_size() == 1
-    assert sched._splice_fn.fn._cache_size() == 1
+    assert sched.lane_cache._splice_fn.fn._cache_size() == 1
     assert sched._set_token_fn.fn._cache_size() == 1
     assert sorted(eng._prefill_fn.avals) == [(1, b) for b in buckets]
     assert eng._prefill_fn.fn._cache_size() == len(buckets)
@@ -560,7 +560,7 @@ def _served_in_a_trace(make_engine, tmp_path_factory):
     sched = ContinuousBatchingScheduler(eng, slots=8, prompt_bucket=BUCKET)
     sched.retain_lanes = True
     steps = []
-    real_step = scheduler_mod._LaneClocks.step
+    real_step = scheduler_mod.LaneClocks.step
 
     def step(self):
         first, clock = self.first.copy(), self.clock.copy()
@@ -568,7 +568,7 @@ def _served_in_a_trace(make_engine, tmp_path_factory):
         steps.append((first, clock, share))
         return share
 
-    patch.setattr(scheduler_mod._LaneClocks, "step", step)
+    patch.setattr(scheduler_mod.LaneClocks, "step", step)
     prompts = _prompts(19, seed=11, lo=3, hi=150)
     prompts.insert(0, list(range(1, 2 * BUCKET + 1)))    # fills its bucket
     outs = [3 + (i * 7) % 13 for i in range(len(prompts))]
@@ -707,7 +707,7 @@ def test_cache_plan_names_the_path_of_each_layout(kw, path):
     plan, = [e for e in events if e["kind"] == "serve.cache_plan"]
     assert plan["decode_attention"] == path
     assert (plan["decode_attention_block"] == 256) == (path == "live_blocks")
-    clocks = scheduler_mod._LaneClocks(
+    clocks = scheduler_mod.LaneClocks(
         scheduler_mod.ServingStats(), 2, 256,
         sched._decode_attention_block())
     assert clocks.step() == 1.0     # one block a lane, or every position
@@ -783,7 +783,7 @@ def test_a_ragged_last_block_counts_as_a_block_held():
     blocks read over blocks held, 1.0 with every block of every lane
     read."""
     stats = scheduler_mod.ServingStats()
-    clocks = scheduler_mod._LaneClocks(stats, 2, 2944, 512)
+    clocks = scheduler_mod.LaneClocks(stats, 2, 2944, 512)
     clocks.admit(0, 64, 60, 0)
     clocks.admit(1, 512, 500, 2431)
     assert clocks.step() == pytest.approx((1 + 6) / 12)
