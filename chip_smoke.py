@@ -427,6 +427,90 @@ def _check_decode_attention(layers, lanes, positions, kv_heads, heads, d,
             "mosaic_calls": mosaic, "tol": tol, "rel_l2": round(err, 6)}
 
 
+def _check_selected_attention(layers, lanes, positions, kv_heads, heads, d,
+                              ix_heads, ix_dim, topk, dtype, strict: bool):
+    """One decode step of attention over the rows an indexer chooses
+    (ops/indexed_attention.py ``decode_step``: scores over a layer's index
+    keys, ``top_k``, the chosen rows gathered out of the stacked leaves),
+    lanes with left padding and a third to the whole of the cache live, vs
+    its plain form on the f32 upcast: dense scores, the ``topk`` best of
+    each lane's visible positions as a mask, a masked softmax over every
+    position. No kernel of the repo's own is in it (plain XLA: a sort and
+    a gather), so ``mosaic_calls`` is 0; the sets may differ by a position
+    at the boundary where two float32 scores differ in their last bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops import indexed_attention as ia
+
+    rng = np.random.RandomState(17)
+    q = jnp.asarray(rng.randn(lanes, heads, d), dtype)
+    q_idx = jnp.asarray(rng.randn(lanes, ix_heads, ix_dim), dtype)
+    w = jnp.asarray(rng.randn(lanes, ix_heads) * 0.03, jnp.float32)
+    kc, vc = (jnp.asarray(rng.randn(layers, lanes, positions, kv_heads, d),
+                          dtype) for _ in range(2))
+    ki = jnp.asarray(rng.randn(layers, lanes, positions, ix_dim), dtype)
+    live = rng.randint(positions // 3, positions + 1, lanes)
+    live[0], live[-1] = max(topk // 2, 1), positions   # under topk; all
+    first = np.array([rng.randint(0, positions - n + 1) for n in live])
+    clock = first + live - 1
+    at = np.arange(positions)[None, :]
+    visible = jnp.asarray((at >= first[:, None]) & (at <= clock[:, None]))
+    layer = layers - 1
+    scale = 1.0 / np.sqrt(d)
+    fn = jax.jit(lambda *a: ia.decode_step(*a, topk, scale, dtype))
+    args = (q, q_idx, w, kc, vc, ki, jnp.int32(layer), visible)
+    got, rows, ok = fn(*args)
+    with jax.default_matmul_precision("highest"):
+        dots = jnp.einsum("bjd,bsd->bjs", q_idx.astype(jnp.float32),
+                          ki[layer].astype(jnp.float32))
+        index = jnp.sum(jax.nn.relu(dots) * w[..., None], 1)
+        # the plain form's own choice, by no function of the module under
+        # test: a stable sort of the float32 scores on the host (ties to
+        # the lower row), the rows a lane cannot see last
+        order = np.argsort(-np.where(np.asarray(visible), np.asarray(index),
+                                     -np.inf), axis=1, kind="stable")
+        chosen = np.zeros((lanes, positions), bool)
+        for b, n in enumerate(np.minimum(live, topk)):
+            chosen[b, order[b, :n]] = True
+        chosen = jnp.asarray(chosen)
+        qg = q.astype(jnp.float32).reshape(lanes, kv_heads,
+                                           heads // kv_heads, d)
+        att = jnp.einsum("bhgd,bkhd->bhgk", qg,
+                         kc[layer].astype(jnp.float32)) * scale
+        att = jax.nn.softmax(
+            jnp.where(chosen[:, None, None, :], att, -1e30), axis=-1)
+        ref = jnp.einsum("bhgk,bkhd->bhgd", att,
+                         vc[layer].astype(jnp.float32)).reshape(
+                             lanes, heads, d)
+    mine = np.zeros((lanes, positions), bool)
+    np.logical_or.at(mine, (np.arange(lanes)[:, None], np.asarray(rows)),
+                     np.asarray(ok))
+    want = np.minimum(live, topk)
+    if not (mine.sum(1) == want).all() or (mine & ~np.asarray(visible)).any():
+        raise AssertionError("decode_step chose rows a lane cannot see, or "
+                             f"not min(topk, live) of them: {mine.sum(1)}")
+    agree = float((mine & np.asarray(chosen)).sum() / want.sum())
+    mosaic = _mosaic_calls(fn.lower(*args).compile().as_text())
+    err = _rel_l2(got, ref)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
+    if not bool(jnp.isfinite(got.astype(jnp.float32)).all()) or err > tol \
+            or agree < 0.999:
+        raise AssertionError(f"selected attention rel-L2 {err:.3e} > {tol} "
+                             f"or sets agree {agree:.5f} < 0.999")
+    if strict and mosaic != 0:
+        raise AssertionError(f"selected attention: {mosaic} Mosaic calls")
+    return {"kernel": "selected_attention (XLA)", "layers": layers,
+            "lanes": lanes, "positions": positions, "kv_heads": kv_heads,
+            "heads": heads, "head_dim": d, "index_heads": ix_heads,
+            "index_dim": ix_dim, "topk": topk,
+            "live": [int(live.min()), int(live.max())],
+            "dtype": jnp.dtype(dtype).name, "mosaic_calls": mosaic,
+            "sets_agree": round(agree, 6), "tol": tol,
+            "rel_l2": round(err, 6)}
+
+
 def _check_retention_step(layers, lanes, kv_heads, heads, d, strict: bool):
     """One token of power retention through the kernel that walks the
     stacked state where it lies, vs the plain ``retention_step`` on that
@@ -853,6 +937,12 @@ def _check_grouped_matmul_stack(layers, rows, d_in, d_out, groups, dtype,
             "whole_layer_results": copies, "bitwise": True}
 
 
+# the Keye-VL serve cell's decode step: 32 lanes of 24,576 positions (two
+# layers of the stacked leaves), 4 KV heads of 128 under 32 query heads, 16
+# index heads of 64, 2,048 chosen of 8k-24k live
+SELECTED_SHAPE = (2, 32, 24576, 4, 32, 128, 16, 64, 2048)
+
+
 def phase_kernels(flash_shapes=((1024, 128, 16, 6), (512, 64, 16, 2),
                                 (4096, 128, 16, 1)),
                   adam_shape=(2048, 8192), splash=(4096, 128, 16, 64),
@@ -866,7 +956,7 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 6), (512, 64, 16, 2),
                                 128),
                   held_experts_shape=(256, 5120, 1536, 160, (0, 20)),
                   latent_kernel_shape=(2, 16, 2944, 128, 512, 64),
-                  dtype=None, strict=True) -> dict:
+                  selected_shape=None, dtype=None, strict=True) -> dict:
     """Each Pallas kernel once at a production shape, forward and
     backward, against plain ``jnp``. ``flash_shapes`` rows are
     ``(seq, head_dim, heads, batch)`` (the 1.3B cell's 96 heads of 1,024
@@ -889,7 +979,11 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 6), (512, 64, 16, 2),
     deepseek_v2.py``; ``latent_kernel_shape`` is ``(layers, lanes,
     positions, heads, kv_rank, rope_dim)`` of the stacked latent leaves
     under the latent decode kernel (``latent_shape``'s step builds its
-    ``Lane`` without a plan and keeps the einsums)."""
+    ``Lane`` without a plan and keeps the einsums); ``selected_shape`` is
+    ``(layers, lanes, positions, kv_heads, heads, head_dim, index heads,
+    index dim, topk)`` of one decode step of attention over the rows an
+    indexer chooses (None: not run; ``python chip_smoke.py`` runs it at
+    ``SELECTED_SHAPE``, the Keye-VL cell's)."""
     import jax.numpy as jnp
 
     from deepspeed_tpu.ops.pallas.common import interpret
@@ -919,6 +1013,9 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 6), (512, 64, 16, 2),
     checks.append(_check_held_experts(*held_experts_shape, dtype, strict))
     checks.append(_check_latent_decode_attention(*latent_kernel_shape, dtype,
                                                  strict))
+    if selected_shape is not None:
+        checks.append(_check_selected_attention(*selected_shape, dtype,
+                                                strict))
     for c in checks:
         emit({"phase": "kernels", "check": c})
     return {"phase": "kernels", "ok": True, "n_checks": len(checks),
@@ -1275,7 +1372,8 @@ def _child(name: str) -> int:
               "reason": f"JAX reports {info['device']['count']} device(s); "
                         "the four-chip phase needs 4"})
         return 0
-    fn = {"kernels": phase_kernels, "train": phase_train,
+    fn = {"kernels": lambda: phase_kernels(selected_shape=SELECTED_SHAPE),
+          "train": phase_train,
           "train_warm": lambda: phase_train(name="train_warm"),
           "serve": phase_serve, "four_chip": phase_four_chip}[name]
     run_phase(name, fn)

@@ -22,6 +22,7 @@ import numpy as np
 
 from deepspeed_tpu.inference.engine import probe_length
 from deepspeed_tpu.models.transformer_lm import (
+    IndexKeyError,
     LatentCacheError,
     declared_cache_leaves,
 )
@@ -49,7 +50,10 @@ class RecurrentStateError(ValueError):
 
 # (the feature asked, the kind of declared leaf that refuses it, why);
 # "latent" is a position leaf counted as latent: one clock a lane, no
-# heads, its leaves with whoever runs the layers
+# heads, its leaves with whoever runs the layers; "index" a position leaf
+# counted as index: an indexer's key beside keys and values, under their
+# clock (so ``_rewind`` steps it back with them, and a draft engine is
+# served)
 _REFUSALS = (
     ("draft_engine (speculative decoding)", "recurrent",
      "_rewind steps the cache clocks back past the rejected tokens, and a "
@@ -71,7 +75,22 @@ _REFUSALS = (
      "the latent is shared by all heads, so sharding the heads over tp "
      "leaves every device the whole cache and the decompression matrices "
      "have no sharding rule (models/transformer_lm.py gpt_tp_rules)"),
+    ("kv_cache_dtype='int8'", "index",
+     "the int8 format keeps one scale per (position, KV head) beside the "
+     "keys and the values; the index key has no heads and no scale, and "
+     "an indexer that scores rounded keys chooses other positions"),
+    ("prefix_cache", "index",
+     "serving/prefix_cache.py sizes and cuts its entries by keys and "
+     "values per head; an entry without the prefix's index keys would "
+     "leave a continuation nothing to choose among"),
+    ("tp > 1", "index",
+     "there is one index key head for all query heads, so sharding the "
+     "heads over tp leaves every device the whole leaf and the indexer's "
+     "projections have no sharding rule (models/transformer_lm.py "
+     "gpt_tp_rules)"),
 )
+
+_REFUSAL_ERRORS = {"latent": LatentCacheError, "index": IndexKeyError}
 
 
 def _leaf_name(path) -> str:
@@ -171,6 +190,13 @@ class LanesAtExit:
         return self._lane_leaves(
             lane, dict(_ranks(self.leaves, "position"), valid=2))
 
+    def last_step(self, lane: int):
+        """The same for what the lane's last decode step left of itself
+        (an indexer's ``chosen_rows`` ``[layers, topk]``: the rows that
+        step's query attended over, and the query). Empty for a model that
+        leaves nothing."""
+        return self._lane_leaves(lane, _ranks(self.leaves, "step"))
+
     def _lane_leaves(self, lane: int, rank):
         out = {}
         for path, leaf in jax.tree_util.tree_flatten_with_path(
@@ -214,7 +240,7 @@ def _copy_program():
                              key=lambda a: _first_leaf_shape(a[0]))
 
 
-def _rewind_program(per_position):
+def _rewind_program(per_position, as_left=()):
     """Selective per slot, not a snapshot swap: the accepted prefix's
     writes are exactly those sequential decode would have made and must
     SURVIVE, so a slot is stale (take the snapshot's) iff it was written
@@ -222,7 +248,8 @@ def _rewind_program(per_position):
     ``slot_pos``, dense ones the storage index, which is the position
     (``per_position``: name -> rank of the leaves that have one).
     ``cache_index`` and the top-level ``position`` counters step back by
-    delta."""
+    delta. What a step leaves of itself (``as_left``: names) has no slots
+    and stays as the last pass left it."""
     def rewind(c0, c1, d):
         def rewind_attn(a0, a1):
             ci = a1["cache_index"]
@@ -239,6 +266,9 @@ def _rewind_program(per_position):
             for k in a1:
                 if k == "cache_index":
                     out[k] = idx_new
+                    continue
+                if k in as_left:
+                    out[k] = a1[k]
                     continue
                 v0, v1 = a0[k], a1[k]
                 m = stale.reshape(
@@ -282,21 +312,24 @@ class LaneLayout:
         # each layout's own jitted functions: a build is seen per scheduler
         self._splice_fn = _splice_program()
         self._copy_fn = _copy_program()
-        self._rewind_fn = _rewind_program(_ranks(self.leaves, "position"))
+        self._rewind_fn = _rewind_program(_ranks(self.leaves, "position"),
+                                          tuple(_ranks(self.leaves, "step")))
 
     def refuse(self, draft_engine: bool, prefix_cache: bool) -> None:
         """Raise for the first serving feature asked that a declared leaf
         cannot serve (``_REFUSALS``)."""
         asked = {"draft_engine (speculative decoding)": draft_engine,
                  "prefix_cache": prefix_cache,
-                 "tp > 1": self.engine.topology.size("tp") > 1}
+                 "tp > 1": self.engine.topology.size("tp") > 1,
+                 "kv_cache_dtype='int8'": getattr(
+                     self.config, "kv_cache_dtype", None) == "int8"}
         for feature, kind, why in _REFUSALS:
             held = tuple(leaf for leaf in self.leaves
                          if kind in (leaf.kind,) + leaf.counted_as)
             if held and asked[feature]:
                 raise (RecurrentStateError(feature, why, held)
                        if kind == "recurrent"
-                       else LatentCacheError(feature, why))
+                       else _REFUSAL_ERRORS[kind](feature, why))
 
     @property
     def shapes(self):
@@ -331,17 +364,22 @@ class LaneLayout:
         """A cache with every per-row clock at its virgin value, WITHOUT
         running the model (a real apply would advance the clocks and bake
         garbage into ``slot_pos``): ``slot_pos`` is -1 (no position
-        cached), everything else zeros (``valid`` False, clocks 0). The
-        leaves are made on the sharding that the splice and decode
-        programs hand back (committed, as every jitted result is when an
-        argument is): an uncommitted first cache would be a second
-        specialisation of each program that takes it."""
+        cached), a declared leaf what its declaration says an empty cache
+        holds (``CacheLeaf.unset``), everything else zeros (``valid``
+        False, clocks 0). The leaves are made on the sharding that the
+        splice and decode programs hand back (committed, as every jitted
+        result is when an argument is): an uncommitted first cache would
+        be a second specialisation of each program that takes it."""
         where = None if self.engine is None \
             else self.engine.topology.replicated()
 
+        unset = {"slot_pos": -1,
+                 **{leaf.name: leaf.unset for leaf in self.leaves}}
+
         def init_leaf(path, sd):
-            if _leaf_name(path) == "slot_pos":
-                return jnp.full(sd.shape, -1, sd.dtype, device=where)
+            value = unset.get(_leaf_name(path), 0)
+            if value:
+                return jnp.full(sd.shape, value, sd.dtype, device=where)
             return jnp.zeros(sd.shape, sd.dtype, device=where)
 
         return jax.tree_util.tree_map_with_path(init_leaf, self.shapes)
@@ -385,7 +423,7 @@ class LaneLayout:
             declared = {leaf.name: leaf for leaf in self.leaves}
             total = dict.fromkeys(
                 ("resident", "unquantized", "recurrent", "state", "conv",
-                 "norm", "latent", "sideband"), 0)
+                 "norm", "latent", "index", "sideband"), 0)
             for path, sd in jax.tree_util.tree_flatten_with_path(
                     self.shapes)[0]:
                 leaf = declared.get(_leaf_name(path))
@@ -396,9 +434,10 @@ class LaneLayout:
                 # the unquantised twin: the per-position leaves at the
                 # compute dtype, no sideband, and everything else (clocks,
                 # masks, states) as it is
-                if leaf is None or leaf.kind == "recurrent":
+                if leaf is not None and leaf.kind == "recurrent":
+                    total["recurrent"] += nbytes
+                if leaf is None or leaf.kind != "position":
                     total["unquantized"] += nbytes
-                    total["recurrent"] += nbytes if leaf is not None else 0
                 elif "sideband" not in leaf.counted_as:
                     total["unquantized"] += sd.size * compute_dt.itemsize
             total["kv"] = total["resident"] - total["recurrent"]
@@ -411,6 +450,9 @@ class LaneLayout:
                 geo[part + "_bytes"] = total[part]
             for part in ("state", "conv", "norm", "kv", "latent"):
                 geo[part + "_bytes_per_lane"] = total[part] // self.slots
+            if total["index"]:      # said only of a cache that has one
+                geo["index_key_bytes_per_lane"] = \
+                    total["index"] // self.slots
             geo["lanes"] = self.slots
             geo["compression_ratio"] = (
                 float(total["unquantized"]) / float(total["resident"])

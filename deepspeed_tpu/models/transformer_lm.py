@@ -46,7 +46,10 @@ class CacheLeaf:
     name: str            # in the ``cache`` collection
     rank: int            # of one layer's ``[B, ...]`` leaf
     # "position": a row a position, so the cache can be cut at a prefix
-    # and stepped back; "recurrent": a state, which can be neither
+    # and stepped back; "recurrent": a state, which can be neither;
+    # "step": what a lane's last decode step left for whoever reads the
+    # cache afterwards (every step overwrites it, nothing reads it back,
+    # so it is neither cut nor stepped back)
     kind: str
     # which sums of ``kv_cache_stats`` the leaf's bytes enter beside
     # ``resident_bytes``: "state", "conv", "norm", "latent"; "sideband"
@@ -58,6 +61,7 @@ class CacheLeaf:
     carry_tag: str
     slice_is_whole: bool = True
     dtype: Any = None    # as stored, where the leaf has a dtype of its own
+    unset: Any = 0       # what an empty cache holds
 
 
 @dataclasses.dataclass(frozen=True)
@@ -258,6 +262,89 @@ class MLAConfig:
                              self.yarn_beta_fast, self.yarn_beta_slow)
 
 
+class IndexKeyError(ValueError):
+    """A feature that assumes a lane keeps keys and values alone was asked
+    of a model that keeps an index key beside them
+    (``GPTConfig.indexer``)."""
+
+    def __init__(self, feature: str, why: str):
+        super().__init__(
+            f"{feature} cannot serve a model that keeps an index key a "
+            f"position (cached_index_key: GPTConfig.indexer): {why}")
+        self.feature = feature
+
+
+@dataclasses.dataclass(frozen=True)
+class IndexerConfig:
+    """A lightning indexer beside ``CausalSelfAttention`` (models/
+    indexer.py over ops/indexed_attention.py; DeepSeek-V3.2-Exp's sparse
+    attention, Keye-VL-2.0's ``sa_config``): ``n_heads`` small query heads
+    and ONE key of ``head_dim`` a position score every cached position for
+    a query, the ``topk`` best are chosen (all of them while a query sees
+    no more) and attention runs over those rows alone. The key, after its
+    norm and rotary, is the third thing a lane keeps a position. A cache
+    of at most ``topk`` positions chooses all of them whatever the scores,
+    and the model then runs plain attention."""
+    n_heads: int            # indexer_num_heads
+    head_dim: int           # indexer_head_dim
+    topk: int
+    # the tiling of a pass of many queries; change no value
+    q_chunk: int = 512
+    kv_chunk: int = 512
+
+    def __post_init__(self):
+        if self.head_dim % 2 or min(self.n_heads, self.topk, self.q_chunk,
+                                    self.kv_chunk) < 1:
+            raise ValueError(f"no indexer has these sizes: {self}")
+
+    def cache_leaves(self, cfg) -> Tuple[CacheLeaf, ...]:
+        """One key a position and layer, no heads, in the compute dtype:
+        part of ``kv_bytes`` and, apart, ``index_key_bytes_per_lane``;
+        and, where a choice is made, what the decode program says of its
+        own selection, a layer: the rows a lane's last decode query
+        attended over (``chosen_rows`` ``[B, topk]`` int32, -1 where it
+        saw fewer) and the indexer's query and weights it scored them
+        with (``choice_query`` ``[B, n_heads, head_dim]``,
+        ``choice_weights`` ``[B, n_heads]`` float32)."""
+        from deepspeed_tpu.models import indexer
+
+        leaves = (CacheLeaf(indexer.CACHED_INDEX_KEY, 3, "position",
+                            ("index",), SCOPE_KV_CACHE_CARRY),)
+        if self.engaged(cfg):
+            leaves += (
+                CacheLeaf(indexer.CHOSEN_ROWS, 2, "step", (),
+                          SCOPE_KV_CACHE_CARRY, dtype=jnp.int32, unset=-1),
+                CacheLeaf(indexer.CHOICE_QUERY, 3, "step", (),
+                          SCOPE_KV_CACHE_CARRY),
+                CacheLeaf(indexer.CHOICE_WEIGHTS, 2, "step", (),
+                          SCOPE_KV_CACHE_CARRY, dtype=jnp.float32))
+        return leaves
+
+    def sections(self, cfg) -> Optional[Tuple[int, ...]]:
+        """The model's rotary sections (``GPTConfig.mrope_section``) in the
+        indexer's own ladder of ``head_dim / 2`` frequencies: the same
+        proportions."""
+        if cfg.mrope_section is None:
+            return None
+        out = tuple(s * self.head_dim // cfg.head_dim
+                    for s in cfg.mrope_section)
+        if sum(out) != self.head_dim // 2:
+            raise ValueError(
+                f"mrope_section {cfg.mrope_section} of a head of "
+                f"{cfg.head_dim} has no whole counterpart in an indexer "
+                f"head of {self.head_dim}")
+        return out
+
+    def engaged(self, cfg) -> bool:
+        """Whether a cache of ``cfg.n_positions`` can hold more than
+        ``topk`` positions, so that the choice is one."""
+        return cfg.n_positions > self.topk
+
+    @property
+    def weight_scale(self) -> float:
+        return self.n_heads ** -0.5 * self.head_dim ** -0.5
+
+
 @dataclasses.dataclass(frozen=True)
 class GPTConfig:
     vocab_size: int = 50257
@@ -293,8 +380,9 @@ class GPTConfig:
     lm_head_bias: bool = False         # GPT-J's untied head carries a bias
     parallel_residual: bool = False    # x + attn(ln_1 x) + mlp(ln_2 x)
     n_kv_head: Optional[int] = None    # grouped-query attention; None = MHA
-    qk_norm: bool = False              # RMSNorm over the whole q and k
-                                       # projections, before rotary (OLMoE)
+    qk_norm: Any = False               # RMSNorm over the whole q and k
+                                       # projections, before rotary (OLMoE);
+                                       # "head": over each head (Qwen3)
     remat: bool = False
     # "full" recomputes everything (min memory); "selective" saves matmul
     # outputs and recomputes only elementwise ops — the TPU sweet spot:
@@ -445,6 +533,16 @@ class GPTConfig:
     # A lane's cache is then one compressed latent and one rotary key a
     # position and layer, nothing per head
     mla: Optional[MLAConfig] = None
+    # --- attention over a chosen few positions (Keye-VL-2.0) ----------------
+    # a lightning indexer beside attention; None = every position. A lane
+    # then keeps an index key a position beside keys and values
+    indexer: Optional[IndexerConfig] = None
+    # sectioned rotary (``mrope_section``): of the rotary frequencies the
+    # first ``s[0]`` take their angle from the temporal position, the next
+    # ``s[1]`` from the height, the rest from the width, where a call
+    # hands ``positions [3, B, T]``; a decode call's positions are the
+    # lane's clock, every stream's alike (text)
+    mrope_section: Optional[Tuple[int, ...]] = None
     # attention head size when it is not n_embd // n_head
     attn_head_dim: Optional[int] = None
     # muP multipliers, each applied where the published model applies it;
@@ -543,6 +641,20 @@ class GPTConfig:
                     "head) over a head's values, and a latent has no "
                     "heads: the latent is the model's own compression of "
                     "the cache, and a format for it would be another")
+        if self.indexer is not None:
+            if (not self.rotary or self.learned_positions or self.alibi
+                    or self.sparse_attention is not None or not self.causal
+                    or self.retention is not None or self.mla is not None
+                    or self.sequence_parallel != "none"
+                    or self.rotary_interleaved):
+                raise ValueError(
+                    "an indexer sits beside causal attention with keys "
+                    "and values per head and plain rotary positions")
+            self.indexer.sections(self)     # raises for sections it lacks
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(
+                f"qk_norm must be False, True or 'head'; got "
+                f"{self.qk_norm!r}")
         if not 0 <= self.first_k_dense <= self.n_layer:
             raise ValueError(
                 f"first_k_dense ({self.first_k_dense}) must lie in 0.."
@@ -566,7 +678,7 @@ class GPTConfig:
                      else () if self.retention is not None
                      else attention_cache_leaves(self))
         return attention + tuple(
-            leaf for mixer in (self.ssm, self.retention)
+            leaf for mixer in (self.indexer, self.ssm, self.retention)
             if mixer is not None for leaf in mixer.cache_leaves(self))
 
     @property
@@ -787,7 +899,10 @@ def decode_attention_block(cfg, T: int = 1):
     verification), the ring cache of a window layout, int8 storage
     (dequantised whole on read) and ALiBi (a bias on every position) stay
     on the einsums, and so do heads sharded over ``tp``: GSPMD cannot
-    partition a Mosaic call. The scheduler asks the same question for its
+    partition a Mosaic call. A model whose indexer chooses among the
+    cached positions (``IndexerConfig.engaged``) reads chosen rows, not
+    blocks (ops/indexed_attention.py). The scheduler asks the same
+    question for its
     counter (``kv_blocks_read_share``)."""
     from deepspeed_tpu.ops.pallas.decode_attention import block_positions
     from deepspeed_tpu.ops.sparse_attention.sparse_attention_utils import \
@@ -796,7 +911,8 @@ def decode_attention_block(cfg, T: int = 1):
 
     if (T != 1 or cfg.kv_cache_dtype == "int8" or cfg.alibi
             or ring_engaged(cfg) is not None
-            or get_default_topology().size("tp") > 1):
+            or get_default_topology().size("tp") > 1
+            or (cfg.indexer is not None and cfg.indexer.engaged(cfg))):
         return None
     itemsize = jnp.dtype(cfg.dtype).itemsize
     if cfg.mla is not None:
@@ -859,13 +975,15 @@ class CausalSelfAttention(nn.Module):
         v = qkv[..., (H + Hkv) * D:].reshape(B, T, Hkv, D)
         k = scaled(k, cfg.key_multiplier)
         if cfg.qk_norm:
-            def whole(t, name):
+            def normed(t, name):
+                # over the whole projection, or ("head") over each head
+                rows = t if cfg.qk_norm == "head" else t.reshape(B, T, -1)
                 return nn.RMSNorm(
                     epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
                     param_dtype=cfg.param_dtype, name=name)(
-                        t.reshape(B, T, -1)).reshape(t.shape)
+                        rows).reshape(t.shape)
 
-            q, k = whole(q, "q_norm"), whole(k, "k_norm")
+            q, k = normed(q, "q_norm"), normed(k, "k_norm")
 
         def rope(t, positions):
             from deepspeed_tpu.ops.rotary import apply_rotary_pos_emb
@@ -873,7 +991,8 @@ class CausalSelfAttention(nn.Module):
             return apply_rotary_pos_emb(
                 t, positions, base=cfg.rope_theta,
                 rotary_dim=cfg.rotary_dim,
-                interleaved=cfg.rotary_interleaved)
+                interleaved=cfg.rotary_interleaved,
+                sections=cfg.mrope_section)
 
         def repeat_kv(t):
             return (t if Hkv == H
@@ -924,10 +1043,26 @@ class CausalSelfAttention(nn.Module):
                 spec.update(dict.fromkeys(
                     ("cached_key_scale", "cached_value_scale"),
                     ((B, S, Hkv), 0, jnp.float32)))
+            ix = cfg.indexer
+            if ix is not None:
+                from deepspeed_tpu.models import indexer
+
+                spec[indexer.CACHED_INDEX_KEY] = (
+                    (B, S, ix.head_dim), 0, cfg.dtype)
+                if ix.engaged(cfg):
+                    # what a decode step leaves of its choice; none yet
+                    spec[indexer.CHOSEN_ROWS] = ((B, ix.topk), -1, jnp.int32)
+                    spec[indexer.CHOICE_QUERY] = (
+                        (B, ix.n_heads, ix.head_dim), 0, cfg.dtype)
+                    spec[indexer.CHOICE_WEIGHTS] = (
+                        (B, ix.n_heads), 0, jnp.float32)
             spec["valid"] = ((B, S), False, jnp.bool_)
             if ring is not None:
                 spec["slot_pos"] = ((B, S), -1, jnp.int32)  # nothing cached
             spec["cache_index"] = ((B,), 0, jnp.int32)
+            # whether this call makes the cache (a prefill: every row a
+            # lane holds is among the tokens at hand) or finds it
+            fresh = not self.has_variable("cache", "cache_index")
             cache = {name: self.variable("cache", name, jnp.full, *leaf_spec)
                      for name, leaf_spec in spec.items()}
 
@@ -961,6 +1096,9 @@ class CausalSelfAttention(nn.Module):
                 # position-baked, exactly like the reference's KV cache
                 # after its apply_rotary_pos_emb kernel
                 q, k = rope(q, pos), rope(k, pos)
+            if ix is not None:
+                # (qI, kI, w): the index key is cached beside k and v
+                index = indexer.Indexer(cfg, name="indexer")(x, pos)
             if ring is None:
                 slot_sets = (pos,)
             else:
@@ -997,12 +1135,25 @@ class CausalSelfAttention(nn.Module):
                         new[name + "_scale"] = scale[..., 0]
                     else:
                         new[name] = t.astype(cfg.dtype)
+                if ix is not None:
+                    new[indexer.CACHED_INDEX_KEY] = index[1]
                 if ring is not None:
                     new["slot_pos"] = pos
                 for slots in slot_sets:
                     for name, val in new.items():
                         put(name, (rows, slots), val)
                 put("cache_index", (Ellipsis,), idx + T)
+            if ix is not None and ix.engaged(cfg):
+                # attention over the rows the indexer chooses: of the
+                # tokens at hand where this call makes the cache, of the
+                # cached rows where it finds one (one query token gathers
+                # its rows out of the stacked leaves where they lie)
+                stored = None if fresh else (cache, leaf, put, cache_layer)
+                y = indexer.attend_chosen(
+                    self, (q, k, v), index, pos, write_valid, stored)
+                return nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
+                                param_dtype=cfg.param_dtype,
+                                name="c_proj")(y.reshape(B, T, H * D))
             kernel_block = decode_attention_block(cfg, T)
             if kernel_block is not None:
                 # one query token over dense storage: each lane reads the
@@ -1077,6 +1228,22 @@ class CausalSelfAttention(nn.Module):
                    else jnp.arange(T)[None, :])
             q = rope(q, pos)
             k = rope(k, pos)
+        if cfg.indexer is not None:
+            # no cache: the indexer chooses among this call's own tokens
+            from deepspeed_tpu.models import indexer
+
+            if segment_ids is not None:
+                raise NotImplementedError(
+                    "packed-sequence segment_ids with an indexer: the "
+                    "choice would run across documents")
+            y = indexer.attend_chosen(
+                self, (q, k, v),
+                indexer.Indexer(cfg, name="indexer")(x, pos), None,
+                jnp.ones((B, T), jnp.bool_) if mask is None
+                else mask.astype(jnp.bool_), None)
+            return nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
+                            param_dtype=cfg.param_dtype,
+                            name="c_proj")(y.reshape(B, T, H * D))
         k = repeat_kv(k)
         v = repeat_kv(v)
 

@@ -46,19 +46,42 @@ def yarn_inv_freq(dim: int, base: float, factor: float,
     return plain / factor * ramp + plain * (1.0 - ramp)
 
 
+def section_streams(sections: Sequence[int], dim: int) -> np.ndarray:
+    """Which position stream each of the ``dim / 2`` frequencies takes its
+    angle from under a sectioned rotary (``mrope_section``): the first
+    ``sections[0]`` frequencies from stream 0 (the temporal position), the
+    next ``sections[1]`` from stream 1 (the height), and so on. The
+    sections must add up to ``dim / 2``."""
+    if sum(sections) != dim // 2:
+        raise ValueError(
+            f"rotary sections {tuple(sections)} do not add up to the "
+            f"{dim // 2} frequencies of a rotary dimension of {dim}")
+    return np.repeat(np.arange(len(sections)), sections)
+
+
 def rotary_angles(positions: jnp.ndarray, dim: int, base: float = 10000.0,
                   dtype=jnp.float32,
-                  inv_freq: Optional[Sequence[float]] = None
+                  inv_freq: Optional[Sequence[float]] = None,
+                  sections: Optional[Sequence[int]] = None
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(cos, sin) tables of shape [..., dim/2] for integer positions;
     ``inv_freq`` ([dim / 2]) in place of the plain ``base`` ladder where a
-    model scales its frequencies (:func:`yarn_inv_freq`)."""
+    model scales its frequencies (:func:`yarn_inv_freq`). With
+    ``sections`` and positions that carry a leading axis of
+    ``len(sections)`` streams (``[3, ...]``: temporal, height, width),
+    each frequency takes its angle from its section's stream
+    (:func:`section_streams`); positions without that axis are every
+    stream's, as for text, and the sections then change nothing."""
     if inv_freq is None:
         inv_freq = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32)
                                    / dim))
     else:
         inv_freq = jnp.asarray(inv_freq, jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * inv_freq
+    if sections is not None and positions.ndim == 3:
+        stream = section_streams(sections, dim)             # [dim / 2]
+        angles = jnp.take_along_axis(
+            angles, jnp.asarray(stream)[None, None, None, :], axis=0)[0]
     return jnp.cos(angles).astype(dtype), jnp.sin(angles).astype(dtype)
 
 
@@ -69,8 +92,12 @@ def apply_rotary_pos_emb(
     rotary_dim: Optional[int] = None,
     interleaved: bool = False,
     inv_freq: Optional[Sequence[float]] = None,
+    sections: Optional[Sequence[int]] = None,
 ) -> jnp.ndarray:
     """Rotate ``x: [batch, seq, heads, head_dim]``.
+
+    ``sections`` with ``positions [streams, batch, seq]``: the sectioned
+    rotary of :func:`rotary_angles`.
 
     ``interleaved=False``: pairwise half-dim split — the GPT-NeoX/LLaMA
     convention the reference's kernel implements with rotate_half.
@@ -82,7 +109,7 @@ def apply_rotary_pos_emb(
     if positions is None:
         positions = jnp.arange(t)[None, :]
     cos, sin = rotary_angles(positions, rd, base, dtype=x.dtype,
-                             inv_freq=inv_freq)
+                             inv_freq=inv_freq, sections=sections)
     cos = cos[:, :, None, :]  # [b, t, 1, rd/2]
     sin = sin[:, :, None, :]
 

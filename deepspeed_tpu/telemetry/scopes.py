@@ -100,6 +100,17 @@ SCOPE_MLA_KV_PROJ = "mla_kv_proj"
 SCOPE_MLA_ABSORB = "mla_absorb"
 SCOPE_MLA_ATTN = "mla_attn"
 SCOPE_MLA_OUT_PROJ = "mla_out_proj"
+# attention over a chosen few of the cached positions (ops/
+# indexed_attention.py; ``GPTConfig.indexer``): the indexer's three
+# projections with their norm and rotary; its scores over a lane's index
+# keys; the choice of the best positions; scores, softmax and weighted sum
+# over the chosen rows (``attn_core`` stays the dense path's). The index
+# key's row writes are ``kv_cache_write``'s and a whole index-key leaf that
+# no scope owns is ``kv_cache_carry``'s, as keys and values are
+SCOPE_DSA_INDEX_PROJ = "dsa_index_proj"
+SCOPE_DSA_INDEX_SCORES = "dsa_index_scores"
+SCOPE_DSA_SELECT = "dsa_select"
+SCOPE_DSA_ATTN = "dsa_attn"
 # JAX's own name-stack component of a rematerialised (recomputed) operation;
 # ``checkpoint`` alone is also on the backward pass of a checkpointed region
 SCOPE_REMAT = "rematted_computation"
@@ -125,7 +136,8 @@ _CARRY_FREE = frozenset((
     SCOPE_SSM_OUT_PROJ, SCOPE_RET_PROJ, SCOPE_RET_QK_NORM_ROPE,
     SCOPE_RET_STATE, SCOPE_RET_OUT_PROJ, SCOPE_MLA_Q_PROJ,
     SCOPE_MLA_KV_PROJ, SCOPE_MLA_ABSORB, SCOPE_MLA_ATTN,
-    SCOPE_MLA_OUT_PROJ))
+    SCOPE_MLA_OUT_PROJ, SCOPE_DSA_INDEX_PROJ, SCOPE_DSA_INDEX_SCORES,
+    SCOPE_DSA_SELECT, SCOPE_DSA_ATTN))
 _STRUCTURE = re.compile(
     r"^(jit\(.*\)|pjit\(.*\)|while|body|cond|branch_\d+_fun|closed_call|"
     r"core_call|custom_jvp_call|custom_vjp_call|custom_vjp_call_jaxpr)$")
