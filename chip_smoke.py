@@ -431,18 +431,32 @@ def _check_selected_attention(layers, lanes, positions, kv_heads, heads, d,
                               ix_heads, ix_dim, topk, dtype, strict: bool):
     """One decode step of attention over the rows an indexer chooses
     (ops/indexed_attention.py ``decode_step``: scores over a layer's index
-    keys, ``top_k``, the chosen rows gathered out of the stacked leaves),
-    lanes with left padding and a third to the whole of the cache live, vs
-    its plain form on the f32 upcast: dense scores, the ``topk`` best of
-    each lane's visible positions as a mask, a masked softmax over every
-    position. No kernel of the repo's own is in it (plain XLA: a sort and
-    a gather), so ``mosaic_calls`` is 0; the sets may differ by a position
-    at the boundary where two float32 scores differ in their last bit."""
+    keys, ``top_k``, then the chosen rows out of the stacked leaves, either
+    gathered or as the lanes' live blocks under the chosen mask by the
+    kernel ``decode_attn``, as ``reads_blocks`` says of the lanes' clocks),
+    lanes with left padding and a sixth to five sixths of the cache live
+    (one under ``topk``, one full), vs its plain form on the f32 upcast:
+    dense scores, the ``topk`` best of each lane's visible positions as a
+    mask computed ON THE HOST, a masked softmax over every position. Twice:
+    at ``topk``, where the rule reads blocks, and at a quarter of it, where
+    it gathers; the program holds both forms (one Mosaic call), and each
+    form is also run alone on the first case's rows. The sets may differ by
+    a position at the boundary where two float32 scores differ in their
+    last bit; the mask the blocks are read under is held to the scatter of
+    ``top_k``'s rows exactly, on scores full of ties and zeros of both
+    signs. On the chip (``strict``) it also times each form alone, which is
+    what the rule's two constants rest on: every lane holding a quarter, a
+    half and the whole of the cache, and a mix of short and long lanes; the
+    result is written to ``chiprun_out/selected.json`` too."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from deepspeed_tpu.ops import indexed_attention as ia
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        block_positions,
+        live_blocks,
+    )
 
     rng = np.random.RandomState(17)
     q = jnp.asarray(rng.randn(lanes, heads, d), dtype)
@@ -451,64 +465,157 @@ def _check_selected_attention(layers, lanes, positions, kv_heads, heads, d,
     kc, vc = (jnp.asarray(rng.randn(layers, lanes, positions, kv_heads, d),
                           dtype) for _ in range(2))
     ki = jnp.asarray(rng.randn(layers, lanes, positions, ix_dim), dtype)
-    live = rng.randint(positions // 3, positions + 1, lanes)
+    rng = np.random.RandomState(18)
+    live = rng.randint(positions // 6, positions * 5 // 6 + 1, lanes)
     live[0], live[-1] = max(topk // 2, 1), positions   # under topk; all
     first = np.array([rng.randint(0, positions - n + 1) for n in live])
     clock = first + live - 1
     at = np.arange(positions)[None, :]
     visible = jnp.asarray((at >= first[:, None]) & (at <= clock[:, None]))
-    layer = layers - 1
+    layer = jnp.int32(layers - 1)
     scale = 1.0 / np.sqrt(d)
-    fn = jax.jit(lambda *a: ia.decode_step(*a, topk, scale, dtype))
-    args = (q, q_idx, w, kc, vc, ki, jnp.int32(layer), visible)
-    got, rows, ok = fn(*args)
+    block = block_positions(positions, kv_heads, d, jnp.dtype(dtype).itemsize)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
+
     with jax.default_matmul_precision("highest"):
         dots = jnp.einsum("bjd,bsd->bjs", q_idx.astype(jnp.float32),
-                          ki[layer].astype(jnp.float32))
-        index = jnp.sum(jax.nn.relu(dots) * w[..., None], 1)
-        # the plain form's own choice, by no function of the module under
-        # test: a stable sort of the float32 scores on the host (ties to
-        # the lower row), the rows a lane cannot see last
-        order = np.argsort(-np.where(np.asarray(visible), np.asarray(index),
-                                     -np.inf), axis=1, kind="stable")
-        chosen = np.zeros((lanes, positions), bool)
-        for b, n in enumerate(np.minimum(live, topk)):
-            chosen[b, order[b, :n]] = True
-        chosen = jnp.asarray(chosen)
+                          ki[layers - 1].astype(jnp.float32))
+        index = np.asarray(jnp.sum(jax.nn.relu(dots) * w[..., None], 1))
         qg = q.astype(jnp.float32).reshape(lanes, kv_heads,
                                            heads // kv_heads, d)
         att = jnp.einsum("bhgd,bkhd->bhgk", qg,
-                         kc[layer].astype(jnp.float32)) * scale
-        att = jax.nn.softmax(
-            jnp.where(chosen[:, None, None, :], att, -1e30), axis=-1)
-        ref = jnp.einsum("bhgk,bkhd->bhgd", att,
-                         vc[layer].astype(jnp.float32)).reshape(
-                             lanes, heads, d)
-    mine = np.zeros((lanes, positions), bool)
-    np.logical_or.at(mine, (np.arange(lanes)[:, None], np.asarray(rows)),
-                     np.asarray(ok))
-    want = np.minimum(live, topk)
-    if not (mine.sum(1) == want).all() or (mine & ~np.asarray(visible)).any():
-        raise AssertionError("decode_step chose rows a lane cannot see, or "
-                             f"not min(topk, live) of them: {mine.sum(1)}")
-    agree = float((mine & np.asarray(chosen)).sum() / want.sum())
-    mosaic = _mosaic_calls(fn.lower(*args).compile().as_text())
-    err = _rel_l2(got, ref)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
-    if not bool(jnp.isfinite(got.astype(jnp.float32)).all()) or err > tol \
-            or agree < 0.999:
-        raise AssertionError(f"selected attention rel-L2 {err:.3e} > {tol} "
-                             f"or sets agree {agree:.5f} < 0.999")
-    if strict and mosaic != 0:
+                         kc[layers - 1].astype(jnp.float32)) * scale
+
+    def plain(k):
+        """The plain form's own choice of ``k``, by no function of the
+        module under test: a stable sort of the float32 scores on the
+        host (ties to the lower row), the rows a lane cannot see last."""
+        order = np.argsort(-np.where(np.asarray(visible), index, -np.inf),
+                           axis=1, kind="stable")
+        chosen = np.zeros((lanes, positions), bool)
+        for b, n in enumerate(np.minimum(live, k)):
+            chosen[b, order[b, :n]] = True
+        with jax.default_matmul_precision("highest"):
+            p = jax.nn.softmax(jnp.where(
+                jnp.asarray(chosen)[:, None, None, :], att, -1e30), axis=-1)
+            ref = jnp.einsum("bhgk,bkhd->bhgd", p,
+                             vc[layers - 1].astype(jnp.float32))
+        return chosen, ref.reshape(lanes, heads, d)
+
+    def scattered(rows, ok):
+        mine = np.zeros((lanes, positions), bool)
+        np.logical_or.at(mine, (np.arange(lanes)[:, None], np.asarray(rows)),
+                         np.asarray(ok))
+        return mine
+
+    # each form alone: the two branches of ``decode_step``'s conditional,
+    # the mask with the blocks (the leaves are arguments: a closed-over
+    # array is a constant of the program, 1.6 GB each at the cell's shape)
+    forms = {
+        "blocks": jax.jit(lambda kc, vc, masked, r, o, c:
+                          ia.attend_chosen_blocks(
+                              q, kc, vc, layer, ia.rows_mask(masked, r, o),
+                              c, block, dtype)),
+        "rows": jax.jit(lambda kc, vc, masked, r, o, c:
+                        ia.attend_chosen_rows(q, kc, vc, layer, r, o, scale,
+                                              dtype))}
+    cases, mosaic = [], None
+    for k in (topk, max(topk // 4, 1)):
+        fn = jax.jit(lambda *a, k=k: ia.decode_step(*a, k, dtype))
+        args = (q, q_idx, w, kc, vc, ki, layer, visible,
+                jnp.asarray(clock, jnp.int32))
+        got, rows, ok = fn(*args)
+        chosen, ref = plain(k)
+        mine, want = scattered(rows, ok), np.minimum(live, k)
+        if not (mine.sum(1) == want).all() \
+                or (mine & ~np.asarray(visible)).any():
+            raise AssertionError("decode_step chose rows a lane cannot see, "
+                                 f"or not min(topk, live): {mine.sum(1)}")
+        agree = float((mine & chosen).sum() / want.sum())
+        err = _rel_l2(got, ref)
+        if not bool(jnp.isfinite(got.astype(jnp.float32)).all()) \
+                or err > tol or agree < 0.999:
+            raise AssertionError(
+                f"selected attention (topk {k}) rel-L2 {err:.3e} > {tol} or "
+                f"sets agree {agree:.5f} < 0.999")
+        cases.append({"topk": k, "reads_blocks": bool(ia.reads_blocks(
+            jnp.asarray(first, jnp.int32), jnp.asarray(clock, jnp.int32),
+            block, min(k, positions))),
+            "sets_agree": round(agree, 6), "rel_l2": round(err, 6)})
+        if mosaic is None:
+            mosaic = _mosaic_calls(fn.lower(*args).compile().as_text())
+            masked = jnp.where(visible, jnp.asarray(index), -jnp.inf)
+            alone = {name: _rel_l2(form(kc, vc, masked, rows, ok, args[-1]),
+                                   ref) for name, form in forms.items()}
+            if max(alone.values()) > tol:
+                raise AssertionError(f"a form alone: rel-L2 {alone} > {tol}")
+    if [c["reads_blocks"] for c in cases] != [True, False]:
+        raise AssertionError(f"the rule did not take both sides: {cases}")
+    if strict and mosaic != 1:
         raise AssertionError(f"selected attention: {mosaic} Mosaic calls")
-    return {"kernel": "selected_attention (XLA)", "layers": layers,
-            "lanes": lanes, "positions": positions, "kv_heads": kv_heads,
-            "heads": heads, "head_dim": d, "index_heads": ix_heads,
-            "index_dim": ix_dim, "topk": topk,
-            "live": [int(live.min()), int(live.max())],
-            "dtype": jnp.dtype(dtype).name, "mosaic_calls": mosaic,
-            "sets_agree": round(agree, 6), "tol": tol,
-            "rel_l2": round(err, 6)}
+
+    # the mask against the scatter, on ties: small integers, zeros of both
+    # signs (a relu's weighted sum gives both), lanes that see too few
+    tied = rng.randint(-2, 3, (lanes, positions)).astype(np.float32)
+    tied[rng.rand(lanes, positions) < 0.3] = -0.0
+    t_rows, t_ok = ia.choose(jnp.asarray(tied), visible, topk)
+    t_mask = ia.rows_mask(jnp.where(visible, jnp.asarray(tied), -jnp.inf),
+                          t_rows, t_ok)
+    if not np.array_equal(np.asarray(t_mask), scattered(t_rows, t_ok)):
+        raise AssertionError("rows_mask is not top_k's set on ties")
+
+    cost = "not measured (no chip)"
+    if strict:
+        cost = []
+        gathered = lanes * min(topk, positions)
+        whole = np.full(lanes, positions)
+        mix = np.where(np.arange(lanes) % 2, positions, min(topk, positions))
+        for name, held in (("quarter", whole // 4), ("half", whole // 2),
+                           ("whole", whole), ("short_and_long", mix)):
+            vis = jnp.asarray(at < held[:, None])
+            clk = jnp.asarray(held - 1, jnp.int32)
+            masked = jnp.where(vis, jnp.asarray(index), -jnp.inf)
+            rows, ok = ia.choose(jnp.asarray(index), vis, topk)
+            lo, hi = live_blocks(np.zeros(lanes, int), held - 1, block)
+            read = int(((hi - lo + 1) * block).sum())
+            times = {}
+            for form_name, form in forms.items():
+                form(kc, vc, masked, rows, ok, clk).block_until_ready()
+                t1 = time.perf_counter()
+                for _ in range(20):
+                    out = form(kc, vc, masked, rows, ok, clk)
+                out.block_until_ready()
+                times[form_name] = (time.perf_counter() - t1) / 20
+            cost.append({
+                "lanes_hold": name, "live": int(held.sum()),
+                "positions_in_blocks": read,
+                "blocks_ms": round(times["blocks"] * 1e3, 4),
+                "rows_ms": round(times["rows"] * 1e3, 4),
+                "ns_a_position_in_blocks": round(
+                    times["blocks"] * 1e9 / read, 3),
+                "ns_a_chosen_position_by_rows": round(
+                    times["rows"] * 1e9 / gathered, 3),
+                "rule_reads_blocks": bool(ia.reads_blocks(
+                    jnp.zeros(lanes, jnp.int32), clk, block,
+                    min(topk, positions)))})
+    out = {"kernel": "selected_attention (decode_attn | XLA gathers)",
+           "layers": layers, "lanes": lanes, "positions": positions,
+           "kv_heads": kv_heads, "heads": heads, "head_dim": d,
+           "index_heads": ix_heads, "index_dim": ix_dim, "topk": topk,
+           "block": block, "live": [int(live.min()), int(live.max())],
+           "dtype": jnp.dtype(dtype).name, "mosaic_calls": mosaic,
+           "tol": tol, "cases": cases,
+           "alone_rel_l2": {n: round(e, 6) for n, e in alone.items()},
+           "mask_is_top_ks_set_on_ties": True,
+           "rule_ns": {"a_position_in_blocks": ia._NS_A_BLOCK_POSITION,
+                       "a_chosen_row": ia._NS_A_CHOSEN_ROW},
+           "cost": cost}
+    if strict:
+        os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
+        with open(os.path.join(REPO, "chiprun_out", "selected.json"),
+                  "w") as fh:
+            json.dump(out, fh, indent=1)
+    return out
 
 
 def _check_retention_step(layers, lanes, kv_heads, heads, d, strict: bool):
@@ -939,7 +1046,7 @@ def _check_grouped_matmul_stack(layers, rows, d_in, d_out, groups, dtype,
 
 # the Keye-VL serve cell's decode step: 32 lanes of 24,576 positions (two
 # layers of the stacked leaves), 4 KV heads of 128 under 32 query heads, 16
-# index heads of 64, 2,048 chosen of 8k-24k live
+# index heads of 64, 2,048 chosen of 4k-20k live
 SELECTED_SHAPE = (2, 32, 24576, 4, 32, 128, 16, 64, 2048)
 
 

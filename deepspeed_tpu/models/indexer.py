@@ -88,8 +88,11 @@ def attend_chosen(module, heads, index, pos, valid_now, stored):
       cache_layer)`` (the module's cache variables, its reader of a layer's
       slice and its writer into one, this call's layer of the stacked
       leaves or None) at rows ``pos``: scores over the layer's index keys,
-      ``top_k``, the chosen rows gathered out of the stacked key and value
-      leaves; rows, query and weights are left in the cache;
+      ``top_k``, and the chosen rows out of the stacked key and value
+      leaves, gathered or read as the lanes' live blocks under the chosen
+      mask by the dense path's decode kernel, whichever is cheaper for
+      what the lanes hold this step (``indexed_attention.reads_blocks``);
+      rows, query and weights are left in the cache;
     * more query tokens on a cache that exists (a continuation,
       verification): the tiled form over the layer's slices.
 
@@ -118,7 +121,7 @@ def attend_chosen(module, heads, index, pos, valid_now, stored):
         y, rows, ok = ia.decode_step(
             q[:, 0], q_idx[:, 0], w[:, 0], cache["cached_key"].value,
             cache["cached_value"].value, cache[CACHED_INDEX_KEY].value,
-            cache_layer, visible, ix.topk, scale, cfg.dtype)
+            cache_layer, visible, pos[:, 0], ix.topk, cfg.dtype)
         y = y[:, None]
         with jax.named_scope(SCOPE_KV_CACHE_WRITE):
             put(CHOSEN_ROWS, (Ellipsis,), jnp.where(ok, rows, -1))

@@ -900,10 +900,10 @@ def decode_attention_block(cfg, T: int = 1):
     (dequantised whole on read) and ALiBi (a bias on every position) stay
     on the einsums, and so do heads sharded over ``tp``: GSPMD cannot
     partition a Mosaic call. A model whose indexer chooses among the
-    cached positions (``IndexerConfig.engaged``) reads chosen rows, not
-    blocks (ops/indexed_attention.py). The scheduler asks the same
-    question for its
-    counter (``kv_blocks_read_share``)."""
+    cached positions (``IndexerConfig.engaged``) is answered None too: it
+    reads its chosen rows, gathered or as live blocks under the chosen
+    mask (ops/indexed_attention.py). The scheduler asks the same question
+    for its counter (``kv_blocks_read_share``)."""
     from deepspeed_tpu.ops.pallas.decode_attention import block_positions
     from deepspeed_tpu.ops.sparse_attention.sparse_attention_utils import \
         ring_engaged
@@ -1146,7 +1146,7 @@ class CausalSelfAttention(nn.Module):
             if ix is not None and ix.engaged(cfg):
                 # attention over the rows the indexer chooses: of the
                 # tokens at hand where this call makes the cache, of the
-                # cached rows where it finds one (one query token gathers
+                # cached rows where it finds one (one query token reads
                 # its rows out of the stacked leaves where they lie)
                 stored = None if fresh else (cache, leaf, put, cache_layer)
                 y = indexer.attend_chosen(

@@ -11,13 +11,25 @@ leaf a lane keeps beside keys and values) and ``w`` a weight a head; the
 there are no more than ``topk``; ties to the lower position, ``lax.top_k``'s
 rule) and the query attends, per head, over those rows alone.
 
-Plain XLA, two forms of the same sums, chosen by the caller from what its
-call shows:
+The same sums in the forms below, chosen by the caller from what its call
+shows:
 
-* :func:`attend_chosen_rows`: ONE query token a lane over a cache. The
-  scores run over a layer's index keys, ``top_k`` gives the rows, and the
-  rows' keys and values are gathered out of the stacked leaves where they
-  lie (no layer's slice of keys or values is made);
+* :func:`decode_step`: ONE query token a lane over a cache. The scores run
+  over a layer's index keys, ``top_k`` gives the rows, and the rows' keys
+  and values reach the softmax out of the stacked leaves where they lie (no
+  layer's slice of keys or values is made) by one of two fetches, both
+  exact, so that cost alone decides, each step, from the lanes' clocks
+  (:func:`reads_blocks`):
+
+  - *rows* (:func:`attend_chosen_rows`): two XLA gathers of the chosen rows
+    and two einsums. The cost is the chosen rows', whatever a lane holds;
+  - *blocks* (:func:`attend_chosen_blocks`): the decode kernel of the dense
+    path (ops/pallas/decode_attention.py, as it stands) with ``visible &
+    chosen`` in the place of ``valid``: each lane's blocks from its first
+    chosen row to its clock, streamed near the memory's bandwidth and
+    masked down to the chosen set. The cost is the LIVE positions', an
+    eighth of a gathered row's each, so it wins while a step's live
+    contexts are within that ratio of what it chooses;
 * :func:`attend_tiled`: many query tokens (a prefill, a continuation, a
   pass without a cache), a tile of ``q_chunk`` queries at a time: the
   tile's scores over all keys, the mask of its chosen positions, then an
@@ -27,7 +39,13 @@ call shows:
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from deepspeed_tpu.ops.pallas.decode_attention import (
+    block_positions,
+    decode_attention,
+    live_blocks,
+)
 from deepspeed_tpu.telemetry.scopes import (
     SCOPE_DSA_ATTN,
     SCOPE_DSA_INDEX_SCORES,
@@ -75,13 +93,66 @@ def chosen_mask(scores, visible, topk: int):
     return above | (ties & first)
 
 
+def rows_mask(masked, rows, ok):
+    """``[B, S]`` bool: True at the rows ``rows [B, K]`` where ``ok``, for
+    the ``rows, ok`` that :func:`choose` gave of ``masked [B, S]`` (the
+    scores, ``-inf`` where a lane does not see), with no scatter of the
+    rows and no second ``top_k``. ``top_k`` hands its rows over best first
+    and ties to the lower row, so the last row that counts holds the least
+    chosen score and is the highest chosen row among those that tie with
+    it: the set is every position that scores above it, and of those that
+    score the same the ones at or before it. Where a lane sees fewer than
+    ``K`` rows that is every row it sees. Scores compare as ``top_k``
+    orders them (-0.0 below 0.0; a relu's weighted sum gives both): as the
+    int32 whose order is the floats' total order."""
+    S = masked.shape[-1]
+    bits = jax.lax.bitcast_convert_type(masked, jnp.int32)
+    key = jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+    count = jnp.sum(ok, axis=-1, keepdims=True)
+    last = jnp.max(jnp.where(jnp.arange(rows.shape[-1]) == count - 1, rows,
+                             -1), axis=-1, keepdims=True)        # [B, 1]
+    at = jnp.arange(S)[None, :]
+    # a masked maximum, not ``key[lane, last]``: no gather
+    kth = jnp.max(jnp.where(at == last, key, jnp.iinfo(jnp.int32).min),
+                  axis=-1, keepdims=True)
+    return (count > 0) & ((key > kth) | ((key == kth) & (at <= last)))
+
+
+# What the two forms cost on the v5e, in nanoseconds: a position in the
+# blocks the kernel reads (the mask's making included), and a chosen
+# position by the gathers (its row of keys, its row of values, the einsums
+# over them). Each form alone at the selected-attention cell's shape (32
+# lanes, 4 KV heads of 128 in bf16, 2,048 chosen of 24,576, blocks of 512),
+# chip_smoke.py's sweep (``chiprun_out/p49a/selected.json``; PERF.md, PR
+# 49): blocks 0.598 / 1.135 / 2.197 ms with every lane holding 6,144 /
+# 12,288 / 24,576 live positions = 3.04 / 2.89 / 2.79 ns a position, and
+# 1.219 ms at 16 lanes of 2,048 beside 16 of 24,576 = 2.86; rows 1.610-1.615
+# ms whatever is live = 24.6 ns a chosen position. The two cost the same at
+# 8.5 live positions a chosen one (~17.4k a lane here)
+_NS_A_BLOCK_POSITION = 2.9
+_NS_A_CHOSEN_ROW = 24.6
+
+
+def reads_blocks(first, clock, block: int, chosen: int):
+    """Whether a decode step reads the lanes' live blocks under the chosen
+    mask (True) or gathers the chosen rows (False): both give the same
+    sums, so the cheaper. The kernel reads each lane's blocks from its
+    first visible row ``first`` to its query's row ``clock`` (``[B]`` each;
+    ``live_blocks``, a lane with nothing still one); the gathers fetch
+    ``chosen`` rows a lane whatever it holds (their shape is static). Both
+    costs are linear in the lanes' totals, so one predicate a step."""
+    lo, hi = live_blocks(first, clock, block)
+    return jnp.sum(hi - lo + 1) * (block * _NS_A_BLOCK_POSITION) \
+        < first.shape[0] * chosen * _NS_A_CHOSEN_ROW
+
+
 def attend_chosen_rows(q, keys, values, layer, rows, ok, scale, dtype):
-    """One query token a lane over its chosen rows: ``q [B, H, D]``;
-    ``keys`` / ``values`` the stacked ``[L, B, S, Hkv, D]`` leaves with
-    ``layer`` this call's index (or one layer's ``[B, S, Hkv, D]`` with
-    ``layer`` None); ``rows`` / ``ok`` ``[B, K]``. Returns ``[B, H, D]``.
-    Query head ``r`` reads KV head ``r // (H / Hkv)``; scores and softmax
-    in float32."""
+    """One query token a lane over its chosen rows, gathered: ``q [B, H,
+    D]``; ``keys`` / ``values`` the stacked ``[L, B, S, Hkv, D]`` leaves
+    with ``layer`` this call's index (or one layer's ``[B, S, Hkv, D]``
+    with ``layer`` None); ``rows`` / ``ok`` ``[B, K]``. Returns ``[B, H,
+    D]``. Query head ``r`` reads KV head ``r // (H / Hkv)``; scores and
+    softmax in float32."""
     B, H, D = q.shape
     lane = jnp.arange(B)[:, None]
     at = (lane, rows) if layer is None else (layer, lane, rows)
@@ -96,24 +167,66 @@ def attend_chosen_rows(q, keys, values, layer, rows, ok, scale, dtype):
     return y.reshape(B, H, D)
 
 
+def attend_chosen_blocks(q, keys, values, layer, chosen, clock, block,
+                         dtype):
+    """One query token a lane over its chosen rows, read as blocks: the
+    dense path's decode kernel with ``chosen [B, S]`` (:func:`rows_mask`:
+    visible and chosen) in the place of ``valid``. It fetches, of each
+    lane, the blocks of ``block`` positions between the first chosen row
+    and ``clock [B]`` (the query's row; no chosen row lies past it) out of
+    the stacked leaves where they lie, and counts the chosen positions
+    alone: the same sums as :func:`attend_chosen_rows` over the same set,
+    float32 scores under an online softmax, probabilities meeting the
+    values in the cache's dtype, the kernel's scale ``1 / sqrt(D)``. A lane
+    with nothing chosen gets finite numbers that mean nothing."""
+    return decode_attention(q, keys, values, chosen, clock, layer,
+                            block=block).astype(dtype)
+
+
 def decode_step(q, q_idx, w, keys, values, index_keys, layer, visible,
-                topk: int, scale, dtype):
+                clock, topk: int, dtype):
     """The whole selection of one decode token a lane: scores over the
-    layer's index keys, the choice, attention over the chosen rows.
+    layer's index keys, the choice, attention over the chosen rows by
+    whichever fetch :func:`reads_blocks` finds cheaper for these lanes.
     ``q [B, H, D]``, ``q_idx [B, Hi, Di]``, ``w [B, Hi]``, the three
-    stacked leaves, ``visible [B, S]``. Returns ``(y [B, H, D], rows, ok)``."""
+    stacked leaves, ``visible [B, S]``, ``clock [B]`` the row of each
+    lane's query. Returns ``(y [B, H, D], rows, ok)``."""
+    B, H, D = q.shape
+    S, Hkv = visible.shape[1], keys.shape[-2]
     with jax.named_scope(SCOPE_DSA_INDEX_SCORES):
         k_idx = index_keys if layer is None else \
             jax.lax.dynamic_index_in_dim(index_keys, layer, 0,
                                          keepdims=False)
-        scores = index_scores(q_idx[:, None], k_idx, w[:, None])
+        scores = index_scores(q_idx[:, None], k_idx, w[:, None])[:, 0]
     with jax.named_scope(SCOPE_DSA_SELECT):
-        rows, ok = choose(scores, visible[:, None], topk)
-        rows, ok = rows[:, 0], ok[:, 0]
+        # one query a lane: scores, the sort and every operand of the
+        # conditional below are [B, S], lanes along the sublanes (a
+        # [B, 1, S] operand is laid out a row a tile, the sort with it,
+        # which then takes eight times as long: PERF.md, PR 48)
+        rows, ok = choose(scores, visible, topk)
     with jax.named_scope(SCOPE_DSA_ATTN):
-        y = attend_chosen_rows(q, keys, values, layer, rows, ok, scale,
-                               dtype)
-    return y, rows, ok
+        block = block_positions(S, Hkv, D, keys.dtype.itemsize)
+        clock = jnp.minimum(clock, S - 1).astype(jnp.int32)
+
+        # each form hands over [B, H * D], the layout the output projection
+        # takes, so that the relayout is the form's own reshape under this
+        # scope (one the compiler puts at a conditional's root has no
+        # ``op_name``)
+        def by_blocks():
+            chosen = rows_mask(jnp.where(visible, scores, _NEG), rows, ok)
+            return attend_chosen_blocks(q, keys, values, layer, chosen,
+                                        clock, block, dtype).reshape(B, -1)
+
+        def by_rows():
+            return attend_chosen_rows(q, keys, values, layer, rows, ok,
+                                      1.0 / np.sqrt(D),
+                                      dtype).reshape(B, -1)
+
+        y = jax.lax.cond(
+            reads_blocks(jnp.argmax(visible, axis=1).astype(jnp.int32),
+                         clock, block, min(topk, S)),
+            by_blocks, by_rows)
+    return y.reshape(B, H, D), rows, ok
 
 
 def attend_tiled(q, k, v, q_idx, k_idx, w, q_pos, k_valid, topk: int,

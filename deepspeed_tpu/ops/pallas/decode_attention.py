@@ -41,9 +41,9 @@ maximum, sum and accumulator are float32 (online softmax across blocks);
 probabilities meet ``V`` in the cache's dtype, as on the einsum path.
 
 Not taken into the kernel, and left on the einsum path by the caller
-(``models/transformer_lm.py``): more than one query token (prefill, chunked
-continuation, speculative verification), the ring cache of a sliding-window
-layout, int8 KV storage and ALiBi.
+(``models/transformer_lm.py``): T > 1 (prefill, chunked continuation,
+verification), a window's ring cache, int8 KV storage and ALiBi. A second
+caller, ops/indexed_attention.py, hands ``valid & chosen`` as ``valid``.
 
 On every backend but the TPU the kernel runs in Pallas interpreter mode
 (``ops/pallas/common.py``).

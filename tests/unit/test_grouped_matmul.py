@@ -711,3 +711,57 @@ def test_the_flash_kernels_compile_for_the_chip_under_the_tables_schedule(
     assert (results.count(big), results.count(lse)) == {
         fa.KERNEL_FWD: (1, 1), fa.KERNEL_BWD_DQ: (1, 0),
         fa.KERNEL_BWD_DKV: (2, 0)}[kernel]
+
+
+def test_the_selected_decode_step_compiles_for_the_chip_at_the_cells_shape(
+        one_chip, monkeypatch):
+    """``ops/indexed_attention.py`` ``decode_step`` inside a scan over the
+    stacked leaves, at the selected-attention cell's shape (32 lanes of
+    24,576 positions, 4 KV heads of 128 under 32, ``topk`` 2,048): one
+    conditional, the ``decode_attn`` call in one branch and the two gathers
+    in the other; the one sort runs over ``[lanes, positions]``, eight lanes
+    a tile (with a ``[lanes, 1, positions]`` operand of the conditional the
+    sort was laid out a row a tile and ran eight times as long on the chip:
+    PERF.md, PR 48); and no stacked key or value leaf is copied on its way
+    into either form."""
+    from deepspeed_tpu.ops import indexed_attention as ia
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+
+    monkeypatch.setattr(da, "_interpret", lambda: False)
+    layers, lanes, positions, kv, heads, d = 2, 32, 24576, 4, 32, 128
+    ix_heads, ix_dim, topk = 16, 64, 2048
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def run(q, q_idx, w, keys, values, index_keys, visible, clock):
+        def layer(carry, i):
+            keys, values, index_keys, y = carry
+            out, rows, ok = ia.decode_step(
+                q + y, q_idx, w, keys, values, index_keys, i, visible, clock,
+                topk, jnp.bfloat16)
+            return (keys, values, index_keys, out), rows
+        return jax.lax.scan(layer, (keys, values, index_keys, q),
+                            jnp.arange(layers))
+
+    text = compiled_for_the_chip(
+        jax.jit(run, donate_argnums=(3, 4, 5)),
+        spec(lanes, heads, d), spec(lanes, ix_heads, ix_dim),
+        spec(lanes, ix_heads, dtype=jnp.float32),
+        spec(layers, lanes, positions, kv, d),
+        spec(layers, lanes, positions, kv, d),
+        spec(layers, lanes, positions, ix_dim),
+        spec(lanes, positions, dtype=jnp.bool_),
+        spec(lanes, dtype=jnp.int32))
+    lines = text.splitlines()
+    calls = [line for line in lines
+             if "tpu_custom_call" in line and "custom-call(" in line]
+    assert len(calls) == 1 and f"%{da.KERNEL_NAME}" in calls[0]
+    assert len([line for line in lines if " conditional(" in line]) == 1
+    assert sum(" gather(" in line for line in lines) == 2
+    (sort,) = [line for line in lines if re.search(r" sort\(", line)]
+    assert f"f32[{lanes},{positions}]{{1,0:T(8,128)" in sort, sort[:200]
+    leaf = f"bf16[{layers},{lanes},{positions},{kv},{d}]"
+    assert not [line[:160] for line in lines
+                if re.search(r"= " + re.escape(leaf) + r"\S* (copy|fusion)\(",
+                             line)]

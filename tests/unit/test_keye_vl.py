@@ -563,11 +563,18 @@ def test_the_serving_programs_carry_the_new_scopes(fp32):
 
 def test_chip_smokes_check_of_the_selection_at_a_tiny_size():
     """``chip_smoke.py``'s check of one decode step over chosen rows
-    against its plain form, as the chip runs it at the cell's shape."""
+    against its plain form, as the chip runs it at the cell's shape: at
+    ``topk`` the step reads blocks, at a quarter of it it gathers, and
+    each form alone gives the plain form's sums."""
     import chip_smoke
 
     out = chip_smoke._check_selected_attention(
         2, 3, 96, 2, 4, 16, 3, 8, 16, jnp.float32, strict=False)
-    assert out["rel_l2"] < 1e-5 and out["sets_agree"] == 1.0
+    assert [(c["topk"], c["reads_blocks"]) for c in out["cases"]] \
+        == [(16, True), (4, False)]
+    assert all(c["rel_l2"] < 1e-5 and c["sets_agree"] == 1.0
+               for c in out["cases"])
+    assert max(out["alone_rel_l2"].values()) < 1e-5
     assert out["live"][1] == 96 and out["mosaic_calls"] == 0
+    assert out["cost"] == "not measured (no chip)"
     assert chip_smoke.SELECTED_SHAPE[1:4] == (32, 24576, 4)
