@@ -427,11 +427,23 @@ def _check_decode_attention(layers, lanes, positions, kv_heads, heads, d,
             "mosaic_calls": mosaic, "tol": tol, "rel_l2": round(err, 6)}
 
 
+def _scattered(rows, ok, positions):
+    """``[lanes, positions]`` bool on the host: True at ``rows`` where
+    ``ok``."""
+    import numpy as np
+
+    mine = np.zeros((rows.shape[0], positions), bool)
+    np.logical_or.at(mine, (np.arange(rows.shape[0])[:, None],
+                            np.asarray(rows)), np.asarray(ok))
+    return mine
+
+
 def _check_selected_attention(layers, lanes, positions, kv_heads, heads, d,
                               ix_heads, ix_dim, topk, dtype, strict: bool):
     """One decode step of attention over the rows an indexer chooses
     (ops/indexed_attention.py ``decode_step``: scores over a layer's index
-    keys, ``top_k``, then the chosen rows out of the stacked leaves, either
+    keys, ``top_k``'s set of them by a threshold (``_check_selection`` has
+    that alone), then the chosen rows out of the stacked leaves, either
     gathered or as the lanes' live blocks under the chosen mask by the
     kernel ``decode_attn``, as ``reads_blocks`` says of the lanes' clocks),
     lanes with left padding and a sixth to five sixths of the cache live
@@ -442,9 +454,7 @@ def _check_selected_attention(layers, lanes, positions, kv_heads, heads, d,
     it gathers; the program holds both forms (one Mosaic call), and each
     form is also run alone on the first case's rows. The sets may differ by
     a position at the boundary where two float32 scores differ in their
-    last bit; the mask the blocks are read under is held to the scatter of
-    ``top_k``'s rows exactly, on scores full of ties and zeros of both
-    signs. On the chip (``strict``) it also times each form alone, which is
+    last bit. On the chip (``strict``) it also times each form alone, which is
     what the rule's two constants rest on: every lane holding a quarter, a
     half and the whole of the cache, and a mix of short and long lanes; the
     result is written to ``chiprun_out/selected.json`` too."""
@@ -502,21 +512,14 @@ def _check_selected_attention(layers, lanes, positions, kv_heads, heads, d,
                              vc[layers - 1].astype(jnp.float32))
         return chosen, ref.reshape(lanes, heads, d)
 
-    def scattered(rows, ok):
-        mine = np.zeros((lanes, positions), bool)
-        np.logical_or.at(mine, (np.arange(lanes)[:, None], np.asarray(rows)),
-                         np.asarray(ok))
-        return mine
-
-    # each form alone: the two branches of ``decode_step``'s conditional,
-    # the mask with the blocks (the leaves are arguments: a closed-over
-    # array is a constant of the program, 1.6 GB each at the cell's shape)
+    # each form alone: the two branches of ``decode_step``'s conditional
+    # (the leaves are arguments: a closed-over array is a constant of the
+    # program, 1.6 GB each at the cell's shape)
     forms = {
-        "blocks": jax.jit(lambda kc, vc, masked, r, o, c:
+        "blocks": jax.jit(lambda kc, vc, chosen, r, o, c:
                           ia.attend_chosen_blocks(
-                              q, kc, vc, layer, ia.rows_mask(masked, r, o),
-                              c, block, dtype)),
-        "rows": jax.jit(lambda kc, vc, masked, r, o, c:
+                              q, kc, vc, layer, chosen, c, block, dtype)),
+        "rows": jax.jit(lambda kc, vc, chosen, r, o, c:
                         ia.attend_chosen_rows(q, kc, vc, layer, r, o, scale,
                                               dtype))}
     cases, mosaic = [], None
@@ -526,7 +529,7 @@ def _check_selected_attention(layers, lanes, positions, kv_heads, heads, d,
                 jnp.asarray(clock, jnp.int32))
         got, rows, ok = fn(*args)
         chosen, ref = plain(k)
-        mine, want = scattered(rows, ok), np.minimum(live, k)
+        mine, want = _scattered(rows, ok, positions), np.minimum(live, k)
         if not (mine.sum(1) == want).all() \
                 or (mine & ~np.asarray(visible)).any():
             raise AssertionError("decode_step chose rows a lane cannot see, "
@@ -544,8 +547,8 @@ def _check_selected_attention(layers, lanes, positions, kv_heads, heads, d,
             "sets_agree": round(agree, 6), "rel_l2": round(err, 6)})
         if mosaic is None:
             mosaic = _mosaic_calls(fn.lower(*args).compile().as_text())
-            masked = jnp.where(visible, jnp.asarray(index), -jnp.inf)
-            alone = {name: _rel_l2(form(kc, vc, masked, rows, ok, args[-1]),
+            mask = ia.chosen_set(jnp.asarray(index), visible, k)
+            alone = {name: _rel_l2(form(kc, vc, mask, rows, ok, args[-1]),
                                    ref) for name, form in forms.items()}
             if max(alone.values()) > tol:
                 raise AssertionError(f"a form alone: rel-L2 {alone} > {tol}")
@@ -553,16 +556,6 @@ def _check_selected_attention(layers, lanes, positions, kv_heads, heads, d,
         raise AssertionError(f"the rule did not take both sides: {cases}")
     if strict and mosaic != 1:
         raise AssertionError(f"selected attention: {mosaic} Mosaic calls")
-
-    # the mask against the scatter, on ties: small integers, zeros of both
-    # signs (a relu's weighted sum gives both), lanes that see too few
-    tied = rng.randint(-2, 3, (lanes, positions)).astype(np.float32)
-    tied[rng.rand(lanes, positions) < 0.3] = -0.0
-    t_rows, t_ok = ia.choose(jnp.asarray(tied), visible, topk)
-    t_mask = ia.rows_mask(jnp.where(visible, jnp.asarray(tied), -jnp.inf),
-                          t_rows, t_ok)
-    if not np.array_equal(np.asarray(t_mask), scattered(t_rows, t_ok)):
-        raise AssertionError("rows_mask is not top_k's set on ties")
 
     cost = "not measured (no chip)"
     if strict:
@@ -574,16 +567,16 @@ def _check_selected_attention(layers, lanes, positions, kv_heads, heads, d,
                            ("whole", whole), ("short_and_long", mix)):
             vis = jnp.asarray(at < held[:, None])
             clk = jnp.asarray(held - 1, jnp.int32)
-            masked = jnp.where(vis, jnp.asarray(index), -jnp.inf)
+            mask = ia.chosen_set(jnp.asarray(index), vis, topk)
             rows, ok = ia.choose(jnp.asarray(index), vis, topk)
             lo, hi = live_blocks(np.zeros(lanes, int), held - 1, block)
             read = int(((hi - lo + 1) * block).sum())
             times = {}
             for form_name, form in forms.items():
-                form(kc, vc, masked, rows, ok, clk).block_until_ready()
+                form(kc, vc, mask, rows, ok, clk).block_until_ready()
                 t1 = time.perf_counter()
                 for _ in range(20):
-                    out = form(kc, vc, masked, rows, ok, clk)
+                    out = form(kc, vc, mask, rows, ok, clk)
                 out.block_until_ready()
                 times[form_name] = (time.perf_counter() - t1) / 20
             cost.append({
@@ -606,7 +599,6 @@ def _check_selected_attention(layers, lanes, positions, kv_heads, heads, d,
            "dtype": jnp.dtype(dtype).name, "mosaic_calls": mosaic,
            "tol": tol, "cases": cases,
            "alone_rel_l2": {n: round(e, 6) for n, e in alone.items()},
-           "mask_is_top_ks_set_on_ties": True,
            "rule_ns": {"a_position_in_blocks": ia._NS_A_BLOCK_POSITION,
                        "a_chosen_row": ia._NS_A_CHOSEN_ROW},
            "cost": cost}
@@ -1044,6 +1036,104 @@ def _check_grouped_matmul_stack(layers, rows, d_in, d_out, groups, dtype,
             "whole_layer_results": copies, "bitwise": True}
 
 
+def _check_selection(lanes, positions, topk, strict: bool):
+    """The selection of a decode step alone (ops/indexed_attention.py
+    ``chosen_set`` and ``choose``: the set by a search for the ``topk``-th
+    largest score and among its ties, its positions in ascending order by
+    a running count, nothing sorted) against ``lax.top_k`` of the masked
+    scores and a scatter of its rows on the host, which it has to equal to
+    the row: on random scores and on scores full of ties and zeros of both
+    signs (a relu's weighted sum gives both), lanes with left padding that
+    see half of ``topk``, a sixth to five sixths of the cache, and all of
+    it. On the chip (``strict``) at ``positions`` and at 131,072
+    positions, where it also times ``top_k`` against the selection by
+    stage (each inside one program of 50 turns, so that no dispatch is in
+    the time; the stages alone add up to more than the whole, whose passes
+    the compiler merges); the result is written to ``chiprun_out/
+    selection.json`` too."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops import indexed_attention as ia
+
+    def top_k(scores, visible):
+        vals, rows = jax.lax.top_k(jnp.where(visible, scores, -jnp.inf),
+                                   min(topk, scores.shape[-1]))
+        return rows, vals > -jnp.inf
+
+    stages = {
+        "top_k": top_k,
+        "set": lambda s, v: ia.chosen_set(s, v, topk),
+        # of a set that is at hand, handed over in ``visible``'s place
+        "rows": lambda s, chosen: ia.rows_of(chosen & (s > -jnp.inf), topk),
+        "set_and_rows": lambda s, v: (ia.chosen_set(s, v, topk),
+                                      ia.choose(s, v, topk))}
+    turns = 50
+
+    def ms_a_turn(stage, scores, visible):
+        def many(scores, visible):
+            def turn(_, carry):
+                out = stage(scores + carry, visible)
+                return 1e-38 * sum(jnp.sum(o.astype(jnp.float32))
+                                   for o in jax.tree_util.tree_leaves(out))
+            return jax.lax.fori_loop(0, turns, turn, jnp.float32(0))
+        fn = jax.jit(many)
+        fn(scores, visible).block_until_ready()
+        best = float("inf")
+        for _ in range(3):
+            t1 = time.perf_counter()
+            fn(scores, visible).block_until_ready()
+            best = min(best, (time.perf_counter() - t1) / turns)
+        return round(best * 1e3, 4)
+
+    cases, cost = [], []
+    for n in (positions, 131072) if strict else (positions,):
+        rng = np.random.RandomState(19)
+        held = rng.randint(n // 6, n * 5 // 6 + 1, lanes)
+        held[0], held[-1] = max(topk // 2, 1), n
+        first = np.array([rng.randint(0, n - h + 1) for h in held])
+        at = np.arange(n)[None, :]
+        visible = jnp.asarray((at >= first[:, None])
+                              & (at < (first + held)[:, None]))
+        tied = rng.randint(-2, 3, (lanes, n)).astype(np.float32)
+        tied[rng.rand(lanes, n) < 0.3] = -0.0
+        drawn = {"random": rng.randn(lanes, n).astype(np.float32),
+                 "ties_and_both_zeros": tied}
+        for name, scores in drawn.items():
+            scores = jnp.asarray(scores)
+            want = _scattered(*top_k(scores, visible), n)
+            chosen, (rows, ok) = jax.jit(stages["set_and_rows"])(scores,
+                                                                 visible)
+            rows, ok = np.asarray(rows), np.asarray(ok)
+            if not np.array_equal(np.asarray(chosen), want):
+                raise AssertionError(
+                    f"the chosen set is not top_k's ({name}, {n} positions)")
+            if not all(r[o].tolist() == np.flatnonzero(w).tolist()
+                       for r, o, w in zip(rows, ok, want)) \
+                    or (ok.sum(1) != np.minimum(held, topk)).any():
+                raise AssertionError(
+                    f"the rows are not the set's ({name}, {n} positions)")
+            cases.append({"positions": n, "scores": name,
+                          "set_is_top_ks": True, "rows_are_the_sets": True})
+        if strict:
+            scores = jnp.asarray(drawn["random"])
+            chosen = ia.chosen_set(scores, visible, topk)
+            cost.append({"positions": n, "topk": topk, **{
+                f"{name}_ms": ms_a_turn(
+                    stage, scores, chosen if name == "rows" else visible)
+                for name, stage in stages.items()}})
+    out = {"kernel": "selection (chosen_set, choose | lax.top_k)",
+           "lanes": lanes, "positions": positions, "topk": topk,
+           "cases": cases, "cost": cost or "not measured (no chip)"}
+    if strict:
+        os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
+        with open(os.path.join(REPO, "chiprun_out", "selection.json"),
+                  "w") as fh:
+            json.dump(out, fh, indent=1)
+    return out
+
+
 # the Keye-VL serve cell's decode step: 32 lanes of 24,576 positions (two
 # layers of the stacked leaves), 4 KV heads of 128 under 32 query heads, 16
 # index heads of 64, 2,048 chosen of 4k-20k live
@@ -1089,8 +1179,9 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 6), (512, 64, 16, 2),
     ``Lane`` without a plan and keeps the einsums); ``selected_shape`` is
     ``(layers, lanes, positions, kv_heads, heads, head_dim, index heads,
     index dim, topk)`` of one decode step of attention over the rows an
-    indexer chooses (None: not run; ``python chip_smoke.py`` runs it at
-    ``SELECTED_SHAPE``, the Keye-VL cell's)."""
+    indexer chooses, and of the selection alone at its lanes, positions
+    and ``topk`` (None: neither is run; ``python chip_smoke.py`` runs them
+    at ``SELECTED_SHAPE``, the Keye-VL cell's)."""
     import jax.numpy as jnp
 
     from deepspeed_tpu.ops.pallas.common import interpret
@@ -1123,6 +1214,8 @@ def phase_kernels(flash_shapes=((1024, 128, 16, 6), (512, 64, 16, 2),
     if selected_shape is not None:
         checks.append(_check_selected_attention(*selected_shape, dtype,
                                                 strict))
+        checks.append(_check_selection(selected_shape[1], selected_shape[2],
+                                       selected_shape[8], strict))
     for c in checks:
         emit({"phase": "kernels", "check": c})
     return {"phase": "kernels", "ok": True, "n_checks": len(checks),

@@ -88,11 +88,13 @@ def attend_chosen(module, heads, index, pos, valid_now, stored):
       cache_layer)`` (the module's cache variables, its reader of a layer's
       slice and its writer into one, this call's layer of the stacked
       leaves or None) at rows ``pos``: scores over the layer's index keys,
-      ``top_k``, and the chosen rows out of the stacked key and value
-      leaves, gathered or read as the lanes' live blocks under the chosen
-      mask by the dense path's decode kernel, whichever is cheaper for
-      what the lanes hold this step (``indexed_attention.reads_blocks``);
-      rows, query and weights are left in the cache;
+      ``top_k``'s set of them by a threshold and no sort (``indexed_
+      attention.chosen_set``), and the chosen rows out of the stacked key
+      and value leaves, gathered or read as the lanes' live blocks under
+      the chosen mask by the dense path's decode kernel, whichever is
+      cheaper for what the lanes hold this step (``indexed_attention.
+      reads_blocks``); rows (ascending), query and weights are left in the
+      cache;
     * more query tokens on a cache that exists (a continuation,
       verification): the tiled form over the layer's slices.
 

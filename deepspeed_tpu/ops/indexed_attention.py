@@ -15,7 +15,13 @@ The same sums in the forms below, chosen by the caller from what its call
 shows:
 
 * :func:`decode_step`: ONE query token a lane over a cache. The scores run
-  over a layer's index keys, ``top_k`` gives the rows, and the rows' keys
+  over a layer's index keys; the set is ``lax.top_k``'s to the row and
+  nothing is sorted (:func:`chosen_set`: the ``topk``-th largest score by a
+  search over the scores' bits, one compare-and-count a bit, then the ties
+  by the same search over the positions' bits; :func:`rows_of`: the set's
+  positions in ascending order, by two small matrix products over chunks
+  of the mask; the v5e took ``top_k`` as a full sort of every position
+  with its index, four times the time: PERF.md, PR 51); and the rows' keys
   and values reach the softmax out of the stacked leaves where they lie (no
   layer's slice of keys or values is made) by one of two fetches, both
   exact, so that cost alone decides, each step, from the lanes' clocks
@@ -66,13 +72,105 @@ def index_scores(q_idx, k_idx, w):
                    axis=2)
 
 
-def choose(scores, visible, topk: int):
-    """The rows a query attends over: ``(rows [B, T, K] int32, ok [B, T,
-    K] bool)`` with ``K = min(topk, S)``; ``ok`` is False where fewer than
-    ``K`` positions are visible (such a row is nobody's)."""
+def _order_keys(masked):
+    """``uint32`` whose order is the float32s' total order, as ``lax.top_k``
+    ranks them: -0.0 below 0.0 (a relu's weighted sum gives both), ``-inf``
+    below every finite score."""
+    bits = jax.lax.bitcast_convert_type(masked, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def _kth_largest(x, member, need, bits: int):
+    """``[B, 1]`` uint32: the largest ``t < 2**bits`` that at least ``need
+    [B, 1]`` of the ``member`` s (None: all) of ``x [B, S]`` uint32 reach
+    (``x >= t``), which is the ``need``-th largest of them where there are
+    that many and ``need > 0``. A search over ``t``'s bits from the top,
+    one compare-and-count over ``x`` a bit: nothing is ordered."""
+    def narrow(i, t):
+        probe = t | (jnp.uint32(1) << (bits - 1 - i).astype(jnp.uint32))
+        reach = x >= probe
+        if member is not None:
+            reach &= member
+        count = jnp.sum(reach, axis=-1, keepdims=True, dtype=jnp.int32)
+        return jnp.where(count >= need, probe, t)
+
+    return jax.lax.fori_loop(
+        0, bits, narrow, jnp.zeros(x.shape[:-1] + (1,), jnp.uint32))
+
+
+def chosen_set(scores, visible, topk: int):
+    """``[B, S]`` bool: the ``topk`` best visible positions of a lane (all
+    it sees where those are fewer), the set ``lax.top_k`` of the masked
+    scores would give to the row, with no sort: the ``topk``-th largest
+    score by :func:`_kth_largest` over the scores' 32 bits, everything above
+    it, and of the positions that tie with it the lowest, as many as are
+    left (``top_k``'s rule), by the same search over the positions' bits."""
+    S = scores.shape[-1]
     masked = jnp.where(visible, scores, _NEG)
-    vals, rows = jax.lax.top_k(masked, min(topk, scores.shape[-1]))
-    return rows, vals > _NEG
+    seen = masked > _NEG
+    if S <= topk:
+        return seen
+    key = _order_keys(masked)
+    kth = _kth_largest(key, None, topk, 32)
+    above, ties = key > kth, key == kth
+    left = topk - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    # the lower the position the larger ``early``; it never has every bit
+    # set, so that a lane with nothing left takes no tie
+    early = jnp.broadcast_to(
+        (S - 1 - jnp.arange(S, dtype=jnp.int32)).astype(jnp.uint32),
+        ties.shape)
+    last = _kth_largest(early, ties, left, S.bit_length())
+    return (above | (ties & (early >= last))) & seen
+
+
+# positions a chunk of :func:`rows_of`: the v5e's lane width, and small
+# integers up to it are exact in bfloat16
+_CHUNK = 128
+
+
+def rows_of(chosen, count: int):
+    """The positions of ``chosen [B, S]`` bool in ascending order: ``(rows
+    [B, count] int32, ok [B, count] bool)``, ``ok`` False (and the row 0)
+    past a lane's last; ``chosen`` holds no more than ``count`` a lane. No
+    sort and no scatter: the mask in chunks of ``_CHUNK`` positions, the
+    chunk of output ``j`` from the chunks' running count, and its place in
+    the chunk from the chunk's own running count, fetched by a matrix
+    product with the steps ``running count <= j`` (the sum of the steps
+    times the chunks' differences telescopes to the one chunk; every term
+    is a small integer, exact in bfloat16 and in the float32 sums)."""
+    B, S = chosen.shape
+    n_chunks = -(-S // _CHUNK)
+    m = jnp.pad(chosen, ((0, 0), (0, n_chunks * _CHUNK - S))).reshape(
+        B, n_chunks, _CHUNK).astype(jnp.bfloat16)
+    at = jnp.arange(_CHUNK)
+    within = jnp.einsum("bnc,cd->bnd", m, (at[:, None] <= at[None, :]).astype(
+        jnp.bfloat16), preferred_element_type=jnp.float32)   # running count
+    held = within[..., -1].astype(jnp.int32)                  # [B, n_chunks]
+    upto = jnp.cumsum(held, axis=-1)            # held by a chunk and before
+    j = jnp.arange(count, dtype=jnp.int32)[None, :, None]
+    step = upto[:, None, :] <= j                # [B, count, n]: chunks past
+    chunk = jnp.sum(step, axis=-1, dtype=jnp.int32)           # [B, count]
+    rank = j[..., 0] - jnp.sum(jnp.where(step, held[:, None, :], 0), axis=-1)
+    # the running count of output j's chunk: the first chunk's plus the
+    # differences of every chunk before its own
+    diff = jnp.concatenate([within[:, 1:] - within[:, :-1],
+                            -within[:, -1:]], axis=1).astype(jnp.bfloat16)
+    mine = within[:, :1] + jnp.einsum(
+        "bjn,bnc->bjc", step.astype(jnp.bfloat16), diff,
+        preferred_element_type=jnp.float32)                   # [B, count, C]
+    place = jnp.sum(mine <= rank[..., None].astype(jnp.float32), axis=-1,
+                    dtype=jnp.int32)
+    ok = j[..., 0] < upto[:, -1:]
+    return jnp.where(ok, chunk * _CHUNK + place, 0), ok
+
+
+def choose(scores, visible, topk: int):
+    """The rows a query attends over: ``(rows [B, K] int32, ok [B, K]
+    bool)`` with ``K = min(topk, S)``: :func:`chosen_set`'s positions in
+    ascending order; ``ok`` is False where fewer than ``K`` positions are
+    visible (such a row is nobody's)."""
+    return rows_of(chosen_set(scores, visible, topk),
+                   min(topk, scores.shape[-1]))
 
 
 def chosen_mask(scores, visible, topk: int):
@@ -91,31 +189,6 @@ def chosen_mask(scores, visible, topk: int):
     # the ties in position order: the first ``left`` of them
     first = jnp.cumsum(ties.astype(jnp.int32), axis=-1) <= left
     return above | (ties & first)
-
-
-def rows_mask(masked, rows, ok):
-    """``[B, S]`` bool: True at the rows ``rows [B, K]`` where ``ok``, for
-    the ``rows, ok`` that :func:`choose` gave of ``masked [B, S]`` (the
-    scores, ``-inf`` where a lane does not see), with no scatter of the
-    rows and no second ``top_k``. ``top_k`` hands its rows over best first
-    and ties to the lower row, so the last row that counts holds the least
-    chosen score and is the highest chosen row among those that tie with
-    it: the set is every position that scores above it, and of those that
-    score the same the ones at or before it. Where a lane sees fewer than
-    ``K`` rows that is every row it sees. Scores compare as ``top_k``
-    orders them (-0.0 below 0.0; a relu's weighted sum gives both): as the
-    int32 whose order is the floats' total order."""
-    S = masked.shape[-1]
-    bits = jax.lax.bitcast_convert_type(masked, jnp.int32)
-    key = jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
-    count = jnp.sum(ok, axis=-1, keepdims=True)
-    last = jnp.max(jnp.where(jnp.arange(rows.shape[-1]) == count - 1, rows,
-                             -1), axis=-1, keepdims=True)        # [B, 1]
-    at = jnp.arange(S)[None, :]
-    # a masked maximum, not ``key[lane, last]``: no gather
-    kth = jnp.max(jnp.where(at == last, key, jnp.iinfo(jnp.int32).min),
-                  axis=-1, keepdims=True)
-    return (count > 0) & ((key > kth) | ((key == kth) & (at <= last)))
 
 
 # What the two forms cost on the v5e, in nanoseconds: a position in the
@@ -170,7 +243,7 @@ def attend_chosen_rows(q, keys, values, layer, rows, ok, scale, dtype):
 def attend_chosen_blocks(q, keys, values, layer, chosen, clock, block,
                          dtype):
     """One query token a lane over its chosen rows, read as blocks: the
-    dense path's decode kernel with ``chosen [B, S]`` (:func:`rows_mask`:
+    dense path's decode kernel with ``chosen [B, S]`` (:func:`chosen_set`:
     visible and chosen) in the place of ``valid``. It fetches, of each
     lane, the blocks of ``block`` positions between the first chosen row
     and ``clock [B]`` (the query's row; no chosen row lies past it) out of
@@ -186,7 +259,8 @@ def attend_chosen_blocks(q, keys, values, layer, chosen, clock, block,
 def decode_step(q, q_idx, w, keys, values, index_keys, layer, visible,
                 clock, topk: int, dtype):
     """The whole selection of one decode token a lane: scores over the
-    layer's index keys, the choice, attention over the chosen rows by
+    layer's index keys, the choice (as a mask for the blocks and as rows
+    for the gathers and the caller), attention over the chosen rows by
     whichever fetch :func:`reads_blocks` finds cheaper for these lanes.
     ``q [B, H, D]``, ``q_idx [B, Hi, Di]``, ``w [B, Hi]``, the three
     stacked leaves, ``visible [B, S]``, ``clock [B]`` the row of each
@@ -199,10 +273,13 @@ def decode_step(q, q_idx, w, keys, values, index_keys, layer, visible,
                                          keepdims=False)
         scores = index_scores(q_idx[:, None], k_idx, w[:, None])[:, 0]
     with jax.named_scope(SCOPE_DSA_SELECT):
-        # one query a lane: scores, the sort and every operand of the
-        # conditional below are [B, S], lanes along the sublanes (a
-        # [B, 1, S] operand is laid out a row a tile, the sort with it,
-        # which then takes eight times as long: PERF.md, PR 48)
+        # one query a lane: scores, the search's passes and every operand
+        # of the conditional below are [B, S], lanes along the sublanes (a
+        # [B, 1, S] operand is laid out a row a tile, eight times the
+        # tiles: PERF.md, PR 48). ``choose`` makes its rows of the same set:
+        # the compiler merges the two searches into one (tests/unit/
+        # test_grouped_matmul.py counts the compiled program's loops)
+        chosen = chosen_set(scores, visible, topk)
         rows, ok = choose(scores, visible, topk)
     with jax.named_scope(SCOPE_DSA_ATTN):
         block = block_positions(S, Hkv, D, keys.dtype.itemsize)
@@ -213,7 +290,6 @@ def decode_step(q, q_idx, w, keys, values, index_keys, layer, visible,
         # scope (one the compiler puts at a conditional's root has no
         # ``op_name``)
         def by_blocks():
-            chosen = rows_mask(jnp.where(visible, scores, _NEG), rows, ok)
             return attend_chosen_blocks(q, keys, values, layer, chosen,
                                         clock, block, dtype).reshape(B, -1)
 

@@ -719,9 +719,13 @@ def test_the_selected_decode_step_compiles_for_the_chip_at_the_cells_shape(
     stacked leaves, at the selected-attention cell's shape (32 lanes of
     24,576 positions, 4 KV heads of 128 under 32, ``topk`` 2,048): one
     conditional, the ``decode_attn`` call in one branch and the two gathers
-    in the other; the one sort runs over ``[lanes, positions]``, eight lanes
-    a tile (with a ``[lanes, 1, positions]`` operand of the conditional the
-    sort was laid out a row a tile and ran eight times as long on the chip:
+    in the other; nothing is sorted, and the selection's searches are two
+    loops beside the scan's (over the scores' bits and over the ties'
+    positions: ``decode_step`` asks for the set as a mask and, through
+    ``choose``, as rows, and the compiler merges the two into one), each
+    pass over ``[lanes, positions]``, eight lanes a tile (with a ``[lanes,
+    1, positions]`` operand of the conditional the sort that stood here
+    was laid out a row a tile and ran eight times as long on the chip:
     PERF.md, PR 48); and no stacked key or value leaf is copied on its way
     into either form."""
     from deepspeed_tpu.ops import indexed_attention as ia
@@ -759,8 +763,13 @@ def test_the_selected_decode_step_compiles_for_the_chip_at_the_cells_shape(
     assert len(calls) == 1 and f"%{da.KERNEL_NAME}" in calls[0]
     assert len([line for line in lines if " conditional(" in line]) == 1
     assert sum(" gather(" in line for line in lines) == 2
-    (sort,) = [line for line in lines if re.search(r" sort\(", line)]
-    assert f"f32[{lanes},{positions}]{{1,0:T(8,128)" in sort, sort[:200]
+    assert not [line[:160] for line in lines if re.search(r" sort\(", line)]
+    loops = [line for line in lines if " while(" in line]
+    assert len(loops) == 3
+    searches = [line for line in loops if f" u32[{lanes},1]" in line]
+    assert len(searches) == 2
+    assert f"u32[{lanes},{positions}]{{1,0:T(8,128)" in searches[0]
+    assert f"pred[{lanes},{positions}]{{1,0:T(8,128)" in searches[1]
     leaf = f"bf16[{layers},{lanes},{positions},{kv},{d}]"
     assert not [line[:160] for line in lines
                 if re.search(r"= " + re.escape(leaf) + r"\S* (copy|fusion)\(",

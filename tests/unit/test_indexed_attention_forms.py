@@ -1,9 +1,10 @@
 """The two forms of a decode step's attention over the rows an indexer chose
 (``ops/indexed_attention.py``): the chosen rows gathered, and the lanes' live
 blocks under the chosen mask through the dense path's kernel ``decode_attn``
-(Pallas interpreter mode here); the mask that stands in for a scatter of
-``top_k``'s rows; the rule that tells a step which form to take; and that a
-model without an indexer never enters the module."""
+(Pallas interpreter mode here); the selection that stands in for ``top_k``
+and a scatter of its rows (the set by a threshold, its positions by a
+running count, nothing sorted); the rule that tells a step which form to
+take; and that a model without an indexer never enters the module."""
 import json
 import os
 
@@ -23,6 +24,14 @@ def scatter(rows, ok, positions):
     np.logical_or.at(want, (np.arange(rows.shape[0])[:, None],
                             np.asarray(rows)), np.asarray(ok))
     return want
+
+
+def top_k_rows(scores, visible, topk):
+    """What the selection has to equal: ``lax.top_k`` of the masked scores,
+    ``ok`` where a row is one the lane sees."""
+    masked = jnp.where(visible, scores, -jnp.inf)
+    vals, rows = jax.lax.top_k(masked, min(topk, scores.shape[-1]))
+    return rows, vals > -jnp.inf
 
 
 MASK_CASES = {
@@ -47,9 +56,9 @@ def test_the_mask_is_the_scatter_of_top_ks_rows(case):
     scores[rng.random((B, S)) < 0.3] = -0.0
     scores[rng.random((B, S)) < 0.2] = 0.0
     visible = jnp.asarray(MASK_CASES[case](np.arange(S)[None, :]))
-    rows, ok = ia.choose(jnp.asarray(scores), visible, TOPK)
-    masked = jnp.where(visible, jnp.asarray(scores), -jnp.inf)
-    got = np.asarray(jax.jit(ia.rows_mask)(masked, rows, ok))
+    rows, ok = top_k_rows(jnp.asarray(scores), visible, TOPK)
+    got = np.asarray(jax.jit(ia.chosen_set, static_argnums=2)(
+        jnp.asarray(scores), visible, TOPK))
     np.testing.assert_array_equal(got, scatter(rows, ok, S))
     seen = np.asarray(visible).sum(1)
     assert (got.sum(1) == np.minimum(seen, TOPK)).all()
@@ -64,23 +73,123 @@ def test_a_tie_of_both_zeros_goes_to_the_positive_one_whatever_its_row():
     which all four tie)."""
     scores = jnp.asarray([[-0.0, 0.0, -0.0, 0.0]], jnp.float32)
     visible = jnp.ones((1, 4), bool)
-    rows, ok = ia.choose(scores, visible, 2)
+    rows, ok = top_k_rows(scores, visible, 2)
     assert sorted(np.asarray(rows)[0].tolist()) == [1, 3]
     np.testing.assert_array_equal(
-        np.asarray(ia.rows_mask(scores, rows, ok)),
+        np.asarray(ia.chosen_set(scores, visible, 2)),
         [[False, True, False, True]])
+    mine, counts = ia.choose(scores, visible, 2)
+    assert np.asarray(mine).tolist() == [[1, 3]] and np.asarray(counts).all()
 
 
 def test_the_mask_follows_the_rows_that_count():
-    """Of ``top_k``'s rows a prefix may count (``ok``): the mask is that
-    prefix's scatter, not the whole row's."""
+    """Of ``top_k``'s rows a prefix may count (``ok``): the set of that
+    many is the prefix's scatter (``top_k`` hands its rows over best first,
+    ties to the lower row), not the whole row's."""
     rng = np.random.default_rng(4)
     scores = jnp.asarray(rng.integers(0, 3, size=(B, S)), jnp.float32)
     visible = jnp.ones((B, S), bool)
-    rows, ok = ia.choose(scores, visible, TOPK)
+    rows, ok = top_k_rows(scores, visible, TOPK)
     ok = ok & (jnp.arange(TOPK) < TOPK // 2)
     np.testing.assert_array_equal(
-        np.asarray(ia.rows_mask(scores, rows, ok)), scatter(rows, ok, S))
+        np.asarray(ia.chosen_set(scores, visible, TOPK // 2)),
+        scatter(rows, ok, S))
+
+
+def drawn_scores(kind, lanes, positions, seed=5):
+    """Scores that try the selection: the floats' total order where a float
+    comparison ties, many ties at the threshold, none at all, and
+    magnitudes at both ends of the format."""
+    rng = np.random.default_rng(seed)
+    shape = (lanes, positions)
+    if kind == "random":
+        return rng.standard_normal(shape).astype(np.float32)
+    if kind == "quantised":
+        scores = rng.integers(-2, 3, shape).astype(np.float32)
+        scores[rng.random(shape) < 0.3] = -0.0
+        return scores
+    if kind == "all_equal":
+        return np.full(shape, 1.5, np.float32)
+    if kind == "zeros_of_both_signs":
+        return np.where(rng.random(shape) < 0.5, 0.0, -0.0).astype(np.float32)
+    assert kind == "denormals_and_large_negatives"
+    scores = (rng.integers(-3, 4, shape) * np.float32(1e-42)).astype(
+        np.float32)
+    scores[rng.random(shape) < 0.2] = -3e38
+    scores[rng.random(shape) < 0.1] = 3e38
+    return scores
+
+
+SCORE_KINDS = ["random", "quantised", "all_equal", "zeros_of_both_signs",
+               "denormals_and_large_negatives"]
+# positions and ``topk``: more positions than chosen, with a ragged last
+# chunk of ``rows_of`` and with none; no more positions than ``topk``, where
+# nothing is searched
+SELECT_SHAPES = {"300_of_37": (300, 37), "256_of_128": (256, 128),
+                 "64_of_100": (64, 100)}
+
+
+def lanes_that_see(positions, topk):
+    """``visible``: lanes that see nothing, one row, one under ``topk``,
+    exactly ``topk``, one over it, every row, and ``topk + 5`` behind left
+    padding."""
+    seen = np.minimum([0, 1, topk - 1, topk, topk + 1, positions, topk + 5],
+                      positions)
+    at = np.arange(positions)[None, :]
+    first = np.zeros(len(seen), int)
+    first[-1] = positions - seen[-1]
+    return (at >= first[:, None]) & (at < (first + seen)[:, None])
+
+
+@pytest.mark.parametrize("shape", SELECT_SHAPES)
+@pytest.mark.parametrize("kind", SCORE_KINDS)
+def test_the_set_is_top_ks_to_the_row(kind, shape):
+    positions, topk = SELECT_SHAPES[shape]
+    visible = lanes_that_see(positions, topk)
+    scores = jnp.asarray(drawn_scores(kind, len(visible), positions))
+    rows, ok = top_k_rows(scores, jnp.asarray(visible), topk)
+    got = np.asarray(jax.jit(ia.chosen_set, static_argnums=2)(
+        scores, jnp.asarray(visible), topk))
+    np.testing.assert_array_equal(got, scatter(rows, ok, positions))
+    assert (got.sum(1) == np.minimum(visible.sum(1), topk)).all()
+    assert not (got & ~visible).any()
+
+
+@pytest.mark.parametrize("shape", SELECT_SHAPES)
+@pytest.mark.parametrize("kind", SCORE_KINDS)
+def test_the_rows_are_the_sets_positions_each_once(kind, shape):
+    """``choose``: ascending, ``ok`` on the first ``min(K, visible)`` and on
+    no other; what the caller leaves in ``chosen_rows`` pads with -1."""
+    positions, topk = SELECT_SHAPES[shape]
+    visible = lanes_that_see(positions, topk)
+    scores = jnp.asarray(drawn_scores(kind, len(visible), positions, seed=6))
+    chosen = np.asarray(ia.chosen_set(scores, jnp.asarray(visible), topk))
+    rows, ok = jax.jit(ia.choose, static_argnums=2)(
+        scores, jnp.asarray(visible), topk)
+    assert rows.shape == ok.shape == (len(visible), min(topk, positions))
+    assert rows.dtype == jnp.int32
+    leaf = np.asarray(jnp.where(ok, rows, -1))
+    for lane, want in zip(leaf, chosen):
+        n = want.sum()
+        assert lane[:n].tolist() == np.flatnonzero(want).tolist()
+        assert (lane[n:] == -1).all()
+    assert (np.asarray(ok).sum(1) == np.minimum(visible.sum(1), topk)).all()
+
+
+@pytest.mark.parametrize("count", [1, 7, 128, 200])
+def test_rows_of_counts_through_every_chunk(count):
+    """The positions of a mask, whichever chunks hold them: all in the
+    first, all in the last (a ragged one), one a chunk, a full chunk."""
+    positions = 3 * ia._CHUNK + 17
+    masks = np.zeros((4, positions), bool)
+    masks[0, :count] = True
+    masks[1, positions - count:] = True
+    masks[2, np.arange(min(count, 4)) * ia._CHUNK + 5] = True
+    masks[3, ia._CHUNK:ia._CHUNK + min(count, ia._CHUNK)] = True
+    rows, ok = ia.rows_of(jnp.asarray(masks), count)
+    for lane, counts, want in zip(np.asarray(rows), np.asarray(ok), masks):
+        assert lane[counts].tolist() == np.flatnonzero(want).tolist()
+        assert counts.sum() == want.sum() and not lane[~counts].any()
 
 
 # lanes: every row of a full cache; left padding and a clock just past a
@@ -147,7 +256,7 @@ def test_blocks_under_the_mask_give_what_the_gathered_rows_give(
     scores = ia.index_scores(x["q_idx"][:, None], x["index_keys"][layer],
                              x["w"][:, None])[:, 0]
     rows, ok = ia.choose(scores, x["visible"], topk)
-    chosen = ia.rows_mask(jnp.where(x["visible"], scores, -jnp.inf), rows, ok)
+    chosen = ia.chosen_set(scores, x["visible"], topk)
     assert np.asarray(chosen).sum(1).tolist() == [24, 24, 2, 24, 0]
     np.testing.assert_array_equal(np.asarray(chosen),
                                   scatter(rows, ok, POSITIONS))
@@ -355,7 +464,8 @@ def test_a_model_without_an_indexer_never_enters_the_module(monkeypatch,
         raise AssertionError("ops/indexed_attention.py entered")
 
     for name in ("decode_step", "attend_tiled", "attend_chosen_rows",
-                 "attend_chosen_blocks", "reads_blocks", "choose"):
+                 "attend_chosen_blocks", "reads_blocks", "choose",
+                 "chosen_set", "rows_of"):
         monkeypatch.setattr(ia, name, refuse)
     fn, prefix = FAMILIES[family]
     got = getattr(recorded_programs, fn)()
@@ -367,3 +477,23 @@ def test_a_model_without_an_indexer_never_enters_the_module(monkeypatch,
         and any("prefill" in k for k in programs)
     assert all(k.startswith(prefix) for k in programs)
     assert {k: got[k] for k in programs} == {k: want[k] for k in programs}
+
+
+def test_the_decode_program_of_a_model_with_an_indexer_sorts_nothing_there():
+    """The tiny selected-attention model's decode program as it is lowered
+    (its cache of 64 positions is longer than its ``topk`` of 8): the
+    selection's scope holds the search's loops and neither a ``top_k`` nor a
+    sort (the router's ``top_k`` of 3 experts is elsewhere)."""
+    import re
+
+    sched = tiny_served()
+    sched._ensure_compiled()
+    eng = sched.engine
+    text = eng._decode_k_fn.fn.lower(
+        eng.params, jnp.zeros((3,), jnp.int32), sched.lane_cache.shapes,
+        jax.random.PRNGKey(0), jnp.float32(0.0), 1).as_text(debug_info=True)
+    jax.clear_caches()
+    assert "chlo.top_k" in text and "moe_router/top_k" in text
+    under = set(re.findall(r'loc\("([^"]*/dsa_select/[^"]*)"', text))
+    assert any(name.endswith("/while") for name in under)
+    assert not [name for name in under if "top_k" in name or "sort" in name]

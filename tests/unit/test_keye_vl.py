@@ -185,20 +185,24 @@ def test_the_chosen_sets_are_the_references(form):
 
 def test_the_mask_without_a_scatter_is_top_ks_set_ties_included():
     """``chosen_mask`` (a tile of a pass of many queries) against
-    ``choose`` (``lax.top_k``'s own rows), on scores full of exact ties,
-    zeros among them as a relu's sum gives, and rows that see fewer than
-    ``topk`` positions."""
+    ``lax.top_k``'s own rows, on scores full of exact ties, zeros among
+    them as a relu's sum gives, and rows that see fewer than ``topk``
+    positions; a decode step's ``chosen_set`` is the same set."""
     rng = np.random.default_rng(0)
     scores = rng.integers(-2, 3, size=(2, 24, 40)).astype(np.float32)
     visible = (np.arange(40)[None, None] <= np.arange(3, 27)[None, :, None]) \
         & (rng.random((2, 1, 40)) > 0.2)
-    rows, ok = ia.choose(jnp.asarray(scores), jnp.asarray(visible), 8)
+    vals, rows = jax.lax.top_k(
+        jnp.where(jnp.asarray(visible), jnp.asarray(scores), -jnp.inf), 8)
+    ok = vals > -jnp.inf
     want = np.zeros(scores.shape, bool)
     b, t = np.indices(rows.shape[:2])
     np.logical_or.at(want, (b[..., None], t[..., None], np.asarray(rows)),
                      np.asarray(ok))
     got = ia.chosen_mask(jnp.asarray(scores), jnp.asarray(visible), 8)
     np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(ia.chosen_set(
+        jnp.asarray(scores), jnp.asarray(visible), 8)), want)
     assert (want.sum(-1) == np.minimum(visible.sum(-1), 8)).all()
 
 
@@ -578,3 +582,17 @@ def test_chip_smokes_check_of_the_selection_at_a_tiny_size():
     assert out["live"][1] == 96 and out["mosaic_calls"] == 0
     assert out["cost"] == "not measured (no chip)"
     assert chip_smoke.SELECTED_SHAPE[1:4] == (32, 24576, 4)
+
+
+def test_chip_smokes_check_of_the_selection_alone_at_a_tiny_size():
+    """``chip_smoke.py``'s check of the set and its rows against
+    ``lax.top_k`` and a scatter on the host, as the chip runs it at the
+    cell's shape and at 131,072 positions (there with the stages' times)."""
+    import chip_smoke
+
+    out = chip_smoke._check_selection(5, 300, 16, strict=False)
+    assert [(c["positions"], c["scores"]) for c in out["cases"]] \
+        == [(300, "random"), (300, "ties_and_both_zeros")]
+    assert all(c["set_is_top_ks"] and c["rows_are_the_sets"]
+               for c in out["cases"])
+    assert out["cost"] == "not measured (no chip)"
