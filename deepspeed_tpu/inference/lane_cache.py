@@ -415,8 +415,10 @@ class LaneLayout:
     def geometry(self) -> Dict[str, Any]:
         """What ``kv_cache_stats`` says without asking for the HBM size
         (its docstring says what each sum is): the bytes of the memoized
-        shapes, each leaf in the sums its declaration names, computed
-        once."""
+        shapes, each leaf in the sums its declaration names, and
+        ``leaf_layers``, how many layers keep each declared leaf (a model
+        that mixes kinds of layer stacks each leaf over its kind's layers
+        alone), computed once."""
         if self._geometry is None:
             compute_dt = jnp.dtype(getattr(self.config, "dtype",
                                            jnp.float32))
@@ -424,11 +426,16 @@ class LaneLayout:
             total = dict.fromkeys(
                 ("resident", "unquantized", "recurrent", "state", "conv",
                  "norm", "latent", "index", "sideband"), 0)
+            layers = dict.fromkeys(declared, 0)
             for path, sd in jax.tree_util.tree_flatten_with_path(
                     self.shapes)[0]:
                 leaf = declared.get(_leaf_name(path))
                 nbytes = sd.size * jnp.dtype(sd.dtype).itemsize
                 total["resident"] += nbytes
+                if leaf is not None:
+                    # stacked over the layers that keep it, or one layer's
+                    layers[leaf.name] += sd.shape[0] \
+                        if len(sd.shape) > leaf.rank else 1
                 for part in leaf.counted_as if leaf is not None else ():
                     total[part] += nbytes
                 # the unquantised twin: the per-position leaves at the
@@ -454,6 +461,7 @@ class LaneLayout:
                 geo["index_key_bytes_per_lane"] = \
                     total["index"] // self.slots
             geo["lanes"] = self.slots
+            geo["leaf_layers"] = layers
             geo["compression_ratio"] = (
                 float(total["unquantized"]) / float(total["resident"])
                 if total["resident"] else 1.0)

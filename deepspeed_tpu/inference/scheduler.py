@@ -586,6 +586,7 @@ class ContinuousBatchingScheduler:
                     else "einsum" if block is None else "live_blocks",
                     decode_attention_block=block or 0,
                     expert_matrices=self._expert_matrices(),
+                    leaf_layers=kv["leaf_layers"],
                     **{k: kv[k] for k in (
                         "kv_bytes_per_lane", "state_bytes_per_lane",
                         "conv_bytes_per_lane", "norm_bytes_per_lane",

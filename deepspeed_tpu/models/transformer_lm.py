@@ -27,6 +27,7 @@ from deepspeed_tpu.moe.layer import MOE_STATS
 from deepspeed_tpu.runtime.zero.gather import gather_tree, gathered_on_use
 from deepspeed_tpu.telemetry.scopes import (
     SCOPE_ATTN_CORE,
+    SCOPE_CONV_STATE_CARRY,
     SCOPE_KV_CACHE_CARRY,
     SCOPE_KV_CACHE_READ,
     SCOPE_KV_CACHE_WRITE,
@@ -62,6 +63,9 @@ class CacheLeaf:
     slice_is_whole: bool = True
     dtype: Any = None    # as stored, where the leaf has a dtype of its own
     unset: Any = 0       # what an empty cache holds
+    # the kind of layer that keeps the leaf (an entry of
+    # ``GPTConfig.layer_types``); None = every layer
+    held_by: Optional[str] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -151,6 +155,40 @@ class RetentionConfig:
                 CacheLeaf(RET_NORM, 3, "recurrent", ("state", "norm"),
                           SCOPE_RET_STATE_CARRY, slice_is_whole=False,
                           dtype=self.state_dtype))
+
+
+@dataclasses.dataclass(frozen=True)
+class ShortConvConfig:
+    """The gated short convolution that a layer of kind ``"conv"`` runs as
+    its token mixer (models/short_conv.py; LFM2's ``conv`` layers): one
+    input projection to three parts ``[B | C | z]`` of the model's width,
+    a depthwise causal convolution of ``width`` taps over ``B * z`` with
+    neither bias nor activation, the gate ``C`` on its output, one output
+    projection. A lane keeps the convolution's last ``width - 1`` inputs
+    a layer, the leaf the Mamba-2 mixer's convolution keeps
+    (``conv_tail``), and nothing per position."""
+    width: int = 3          # conv_L_cache: taps, the current token's last
+
+    def __post_init__(self):
+        if self.width < 2:
+            raise ValueError(f"a short convolution has >= 2 taps; "
+                             f"{self.width}")
+
+    def cache_leaves(self, cfg) -> Tuple[CacheLeaf, ...]:
+        """(models/short_conv.py) One layer's tail is what the convolution
+        concatenates in front of its input, so only the stacked tail
+        counts as a whole leaf."""
+        from deepspeed_tpu.models.mamba2 import CONV_TAIL
+
+        return (CacheLeaf(CONV_TAIL, 3, "recurrent", ("conv",),
+                          SCOPE_CONV_STATE_CARRY, slice_is_whole=False,
+                          dtype=cfg.dtype, held_by=KIND_CONV),)
+
+
+# the kinds of layer a stack can mix (``GPTConfig.layer_types``), each
+# named for its token mixer
+KIND_ATTENTION = "attention"
+KIND_CONV = "conv"
 
 
 def attention_cache_leaves(cfg=None) -> Tuple[CacheLeaf, ...]:
@@ -518,6 +556,29 @@ class GPTConfig:
     # whatever ``moe_num_experts`` says; under ``scan_layers`` they run
     # before the scanned stack of the rest
     first_k_dense: int = 0
+    # the dropless path's scoring: "softmax" over all experts, or
+    # "sigmoid" of each expert's own logit (moe/sharded_moe.py
+    # ``topk_routing``); and a per-expert bias, a parameter of the layer,
+    # that is added to the scores for the CHOICE alone (the weights are the
+    # uncorrected scores'), drawn at ``init`` from a normal of this
+    # standard deviation (0.0: zeros, as a model is published before its
+    # balancing has moved it)
+    moe_scoring: str = "softmax"
+    moe_expert_bias: bool = False
+    moe_expert_bias_init: float = 0.0
+    # --- which token mixer a layer runs --------------------------------------
+    # Declared once, here, and read by ``Block`` (its ``mixer`` field), by
+    # whoever runs the layers and by ``cache_leaves``. ``layer_types`` None
+    # is a stack of ONE kind, chosen by the whole-model fields below
+    # (``mla``, ``retention``, else attention, with ``ssm`` beside it and
+    # ``indexer`` inside it) and run by ``ScannedBlocks``. A tuple of
+    # ``n_layer`` kinds ("attention" | "conv") is a stack that MIXES kinds
+    # (models/kind_stacks.py ``KindStackedBlocks``): parameters and cache
+    # leaves are stacked per kind, so an attention layer keeps keys and
+    # values and no tail, a convolution layer a tail and no keys
+    layer_types: Optional[Tuple[str, ...]] = None
+    # the "conv" kind's mixer: a gated short convolution (LFM2)
+    short_conv: Optional[ShortConvConfig] = None
     # --- hybrid blocks (Falcon-H1) -----------------------------------------
     # a Mamba-2 mixer beside attention in every block, both on ln_1's
     # output, summed into one residual; None = attention alone. Its
@@ -651,6 +712,32 @@ class GPTConfig:
                     "an indexer sits beside causal attention with keys "
                     "and values per head and plain rotary positions")
             self.indexer.sections(self)     # raises for sections it lacks
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_scoring must be 'softmax' or 'sigmoid'; got "
+                f"{self.moe_scoring!r}")
+        if self.layer_types is not None:
+            kinds = set(self.layer_types)
+            if len(self.layer_types) != self.n_layer \
+                    or not kinds <= {KIND_ATTENTION, KIND_CONV}:
+                raise ValueError(
+                    f"layer_types names a kind ({KIND_ATTENTION!r} | "
+                    f"{KIND_CONV!r}) for each of the {self.n_layer} layers; "
+                    f"got {self.layer_types!r}")
+            if KIND_CONV in kinds and self.short_conv is None:
+                raise ValueError("a 'conv' layer needs short_conv")
+            unmixable = [name for name in (
+                "mla", "retention", "ssm", "indexer", "sparse_attention")
+                if getattr(self, name) is not None] + [
+                name for name in ("quantized_weights", "param_offload",
+                                  "stochastic_mode", "parallel_residual")
+                if getattr(self, name)]
+            if unmixable or not self.scan_layers:
+                raise ValueError(
+                    "a stack that mixes kinds of layer "
+                    "(models/kind_stacks.py) runs attention and "
+                    "convolution layers with their weights as stored, "
+                    f"scanned; not with {unmixable or 'scan_layers=False'}")
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(
                 f"qk_norm must be False, True or 'head'; got "
@@ -674,12 +761,27 @@ class GPTConfig:
         latent and rotary key, or none where retention is the mixer, and
         the state of the mixers that keep one. The one place that knows
         which mixers a block runs."""
+        if self.layer_types is not None:
+            # by kind of layer: each leaf says which kind holds it
+            kinds = set(self.layer_types)
+            return tuple(
+                dataclasses.replace(leaf, held_by=KIND_ATTENTION)
+                for leaf in attention_cache_leaves(self)
+                if KIND_ATTENTION in kinds) + (
+                self.short_conv.cache_leaves(self)
+                if KIND_CONV in kinds else ())
         attention = (self.mla.cache_leaves(self) if self.mla is not None
                      else () if self.retention is not None
                      else attention_cache_leaves(self))
         return attention + tuple(
             leaf for mixer in (self.indexer, self.ssm, self.retention)
             if mixer is not None for leaf in mixer.cache_leaves(self))
+
+    def layers_holding(self, leaf: CacheLeaf) -> int:
+        """How many layers keep ``leaf`` (one of ``cache_leaves``)."""
+        if leaf.held_by is None or self.layer_types is None:
+            return self.n_layer
+        return self.layer_types.count(leaf.held_by)
 
     @property
     def position_leaves(self) -> Tuple[Tuple[str, int], ...]:
@@ -924,6 +1026,24 @@ def decode_attention_block(cfg, T: int = 1):
                            itemsize)
 
 
+def kv_lane_pack(cfg) -> int:
+    """How many KV heads share one row of the cached keys and values. The
+    TPU lays a leaf's last axis along 128 lanes, so a head of 64 stored as
+    ``[.., Hkv, 64]`` is padded to 128: twice the cache, in memory and in
+    every read (seen compiling the 2,944-position cache of a 64-wide head
+    for a described v5e: ``2.0x expansion``). Heads narrower than a lane
+    row are therefore stored side by side, ``[B, S, Hkv / pack, pack * D]``
+    (the same bytes in the same order), where whole rows come out: ``pack
+    * D == 128`` and ``pack`` divides the KV heads; 1 (a head a row)
+    everywhere else, for an int8 store (a scale a head) and beside an
+    indexer (whose gathers take a head's rows)."""
+    D, pack = cfg.head_dim, 128 // max(cfg.head_dim, 1)
+    if (pack < 2 or pack * D != 128 or cfg.kv_heads % pack
+            or cfg.kv_cache_dtype == "int8" or cfg.indexer is not None):
+        return 1
+    return pack
+
+
 def step_kernel() -> bool:
     """Whether a recurrent mixer's decode step of one token runs the kernel
     that walks the state where it lies (ops/pallas/retention_step.py,
@@ -1036,9 +1156,12 @@ class CausalSelfAttention(nn.Module):
                 ring_len = ring_storage_len(cfg, ring)
                 S = g_tok + ring_len
             # leaf -> (shape, virgin value, dtype)
+            # (narrow heads side by side in a row of 128: kv_lane_pack)
+            pack = kv_lane_pack(cfg)
             spec = dict.fromkeys(
                 ("cached_key", "cached_value"),
-                ((B, S, Hkv, D), 0, jnp.int8 if kv_int8 else cfg.dtype))
+                ((B, S, Hkv // pack, D * pack), 0,
+                 jnp.int8 if kv_int8 else cfg.dtype))
             if kv_int8:
                 spec.update(dict.fromkeys(
                     ("cached_key_scale", "cached_value_scale"),
@@ -1133,6 +1256,9 @@ class CausalSelfAttention(nn.Module):
                         # scales [B, T, Hkv, 1] -> [B, T, Hkv]
                         new[name], scale = quantize_blockwise(t, D)
                         new[name + "_scale"] = scale[..., 0]
+                    elif pack > 1:
+                        new[name] = t.astype(cfg.dtype).reshape(
+                            B, T, Hkv // pack, D * pack)
                     else:
                         new[name] = t.astype(cfg.dtype)
                 if ix is not None:
@@ -1166,10 +1292,22 @@ class CausalSelfAttention(nn.Module):
                 with jax.named_scope(SCOPE_KV_CACHE_READ):
                     valid = leaf("valid")
                 with jax.named_scope(SCOPE_ATTN_CORE):
+                    q1 = q[:, 0]
+                    if pack > 1:
+                        # a query head in its KV head's lanes of the row,
+                        # zeros in the others': the same scores and, in
+                        # those lanes of the output, the same sums
+                        lanes = jax.nn.one_hot(
+                            jnp.arange(H) // (H // Hkv) % pack, pack,
+                            dtype=cfg.dtype)[None, :, :, None]
+                        q1 = (q1[:, :, None] * lanes).reshape(B, H, pack * D)
                     y = decode_attention(
-                        q[:, 0], cache["cached_key"].value,
+                        q1, cache["cached_key"].value,
                         cache["cached_value"].value, valid, idx,
-                        cache_layer, block=kernel_block)
+                        cache_layer, block=kernel_block,
+                        scale=1.0 / np.sqrt(D))
+                    if pack > 1:
+                        y = jnp.sum(y.reshape(B, H, pack, D) * lanes, axis=2)
                 return nn.Dense(C, use_bias=bias, dtype=cfg.dtype,
                                 param_dtype=cfg.param_dtype,
                                 name="c_proj")(y.reshape(B, T, H * D))
@@ -1177,6 +1315,9 @@ class CausalSelfAttention(nn.Module):
             k_pos = jnp.arange(S)[None, :]                  # [1, S]
             with jax.named_scope(SCOPE_KV_CACHE_READ):
                 k_all, v_all = leaf("cached_key"), leaf("cached_value")
+                if pack > 1:
+                    k_all = k_all.reshape(B, S, Hkv, D)
+                    v_all = v_all.reshape(B, S, Hkv, D)
                 if kv_int8:
                     from deepspeed_tpu.ops.quantizer import \
                         dequantize_blockwise
@@ -1394,12 +1535,19 @@ class Block(nn.Module):
     """Pre-LN transformer block; MLP becomes an expert-parallel MoE layer when
     the config asks for experts (reference moe/layer.py MoE drop-in).
     Returns ``(x, l_aux)`` — l_aux is the layer's auxiliary losses with
-    their coefficients, 0 for the dense path."""
+    their coefficients, 0 for the dense path.
+
+    The token mixer is the block's ``mixer`` field, the layer's entry of
+    ``GPTConfig.layer_types`` as whoever runs the layers hands it over;
+    None (a stack of one kind) is the mixer the whole-model fields choose:
+    latent attention, retention, or attention with the Mamba-2 mixer
+    beside it and the indexer inside it."""
 
     config: GPTConfig
     # one of the leading ``first_k_dense`` blocks: a dense MLP whatever
     # the configuration's experts
     dense_mlp: bool = False
+    mixer: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, *, mask=None, segment_ids=None, positions=None,
@@ -1416,7 +1564,12 @@ class Block(nn.Module):
             raise NotImplementedError(
                 "packed-sequence segment_ids with a recurrent mixer: "
                 "the state would run across documents")
-        if cfg.mla is not None:
+        if self.mixer == KIND_CONV:
+            from deepspeed_tpu.models.short_conv import ShortConv
+
+            a = ShortConv(cfg, name="conv")(
+                u, mask=mask, decode=decode, cache_layer=cache_layer)
+        elif cfg.mla is not None:
             from deepspeed_tpu.models.latent_attention import LatentAttention
 
             a = LatentAttention(cfg, name="attn")(
@@ -1473,6 +1626,9 @@ class Block(nn.Module):
                 topk_group=cfg.moe_topk_group,
                 routed_scale=cfg.moe_routed_scale,
                 experts_held=cfg.moe_experts_held,
+                scoring=cfg.moe_scoring,
+                expert_bias=cfg.moe_expert_bias,
+                expert_bias_init=cfg.moe_expert_bias_init,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 name="mlp",
@@ -1595,9 +1751,10 @@ def _maybe_quantized_block(block_cls, cfg):
                                       dtype=cfg.dtype))
 
 
-# The one leaf of a block that its consumer reads as stored: the MoE router
-# multiplies in float32 whatever the compute dtype (moe/layer.py).
-_GATHERED_AS_STORED = ("mlp/gate/kernel",)
+# The leaves of a block that their consumer reads as stored: the MoE router
+# multiplies and corrects its scores in float32 whatever the compute dtype
+# (moe/layer.py).
+_GATHERED_AS_STORED = ("mlp/gate/kernel", "mlp/expert_bias")
 
 
 def _maybe_gathered_block(block_cls, cfg, path, stacked=None):
@@ -1830,9 +1987,13 @@ def gpt_tp_rules(path: str, shape) -> "PartitionSpec":
     if path.endswith(("attn/c_attn/kernel", "mlp/c_fc/kernel",
                       "mlp/c_gate/kernel",
                       "attn/c_attn/bias", "mlp/c_fc/bias",
-                      "mlp/c_gate/bias")):
+                      "mlp/c_gate/bias",
+                      # the short convolution is depthwise: its channels
+                      # split as its input projection's columns do
+                      "conv/in_proj/kernel", "conv/conv_kernel")):
         return dim(-1)  # column parallel
-    if path.endswith(("attn/c_proj/kernel", "mlp/c_proj/kernel")):
+    if path.endswith(("attn/c_proj/kernel", "mlp/c_proj/kernel",
+                      "conv/out_proj/kernel")):
         return dim(-2)  # row parallel
     if path.endswith("wte/embedding"):
         return dim(0)   # vocab parallel (logits shard over vocab)
@@ -1903,7 +2064,16 @@ class GPT(nn.Module):
             x = x + wpe(pos)
         x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
 
-        if cfg.scan_layers:
+        if cfg.layer_types is not None:
+            # layers of several kinds: scanned run by run over parameters
+            # and cache leaves stacked per kind
+            from deepspeed_tpu.models.kind_stacks import KindStackedBlocks
+
+            x, l_aux = KindStackedBlocks(cfg, name="h")(
+                x, mask=attention_mask, segment_ids=segment_ids,
+                positions=positions, deterministic=deterministic,
+                decode=decode)
+        elif cfg.scan_layers:
             x, l_aux = ScannedBlocks(cfg, name="h")(
                 x, mask=attention_mask, segment_ids=segment_ids,
                 positions=positions, deterministic=deterministic,
@@ -2090,6 +2260,14 @@ def num_params(config: GPTConfig) -> int:
         (2 if cfg.gated_mlp else 1) * F + C)
     norm_p = C * (2 if (cfg.norm == "layernorm" and cfg.use_bias) else 1)
     per_layer = attn + mlp + 2 * norm_p
+    if cfg.layer_types is not None:
+        # by the declaration: a convolution layer holds its two
+        # projections and its taps where an attention layer holds q/k/v/o
+        n_conv = cfg.layer_types.count(KIND_CONV)
+        conv = 4 * C * C + cfg.short_conv.width * C if n_conv else 0
+        return (V * C + L * (mlp + 2 * norm_p) + n_conv * conv
+                + (L - n_conv) * attn + norm_p
+                + (0 if cfg.tie_word_embeddings else C * V))
     if cfg.retention is not None:
         # the gate's kernel and bias, q's and k's per-head norm
         per_layer += C * Hkv + Hkv + 2 * D

@@ -19,7 +19,10 @@ The layer returns ``(y, l_aux, l_z, exp_counts)``; the model adds
 Two ways from tokens to experts. With a capacity (``k <= 2`` and
 ``drop_tokens``): the one-hot ``[tokens, experts, capacity]`` dispatch
 above, which drops what overflows. Dropless (``drop_tokens=False`` or
-``k > 2``): softmax, top-k, a sort of the tokens * k (token, expert) pairs
+``k > 2``): scores (``scoring``: a softmax over the experts, or the
+sigmoid of each expert's own logit), top-k (by the scores alone, or by the
+scores plus the layer's ``expert_bias``, which enters the choice and
+nothing else), a sort of the tokens * k (token, expert) pairs
 by expert, a gather, one grouped matmul per projection over the ragged
 groups, and the weighted sum back (moe/sharded_moe.py); no token is left
 out under any load, and no tensor grows with experts * capacity. The
@@ -147,6 +150,13 @@ class MoE(nn.Module):
     topk_group: int = 1
     routed_scale: float = 1.0
     experts_held: Optional[Tuple[int, int]] = None
+    # dropless path only (``topk_routing``): "softmax" | "sigmoid" scores;
+    # a float32 parameter ``expert_bias [num_experts]`` added to the scores
+    # for the choice alone, drawn from a normal of ``expert_bias_init``
+    # (0.0: zeros)
+    scoring: str = "softmax"
+    expert_bias: bool = False
+    expert_bias_init: float = 0.0
 
     @property
     def dropless(self) -> bool:
@@ -186,9 +196,11 @@ class MoE(nn.Module):
         read the matrices in the stacked parameters and 0 where they took
         a layer's own tensor (moe/experts.py ``expert_matrices``),
         ``held``, how many experts' matrices the layer
-        holds (``computed`` is of those), and ``routed_here``, the pairs
-        routed to them. (``init`` makes every collection mutable and would
-        return them beside the parameters.)"""
+        holds (``computed`` is of those), ``routed_here``, the pairs
+        routed to them, and, of a layer with an ``expert_bias``,
+        ``bias_changed``, the tokens whose chosen experts are not their k
+        largest uncorrected scores. (``init`` makes every collection
+        mutable and would return them beside the parameters.)"""
         if not self.is_initializing():
             for name, value in counters.items():
                 self.sow(MOE_STATS, name, value)
@@ -217,11 +229,20 @@ class MoE(nn.Module):
                     f"noisy_gate_policy={self.noisy_gate_policy!r} exists "
                     "only with a capacity (k <= 2, drop_tokens=True)")
             first, held = self.held
+            corrected = {}
+            if self.scoring != "softmax" or self.expert_bias:
+                corrected["scoring"] = self.scoring
+            if self.expert_bias:
+                corrected["bias"] = self.param(
+                    "expert_bias",
+                    nn.initializers.normal(self.expert_bias_init)
+                    if self.expert_bias_init else nn.initializers.zeros,
+                    (self.num_experts,), jnp.float32)
             with jax.named_scope(SCOPE_MOE_ROUTER):
                 route = topk_routing(gate(tokens.astype(jnp.float32)),
                                      self.k, self.norm_topk_prob,
                                      self.n_group, self.topk_group,
-                                     self.routed_scale)
+                                     self.routed_scale, **corrected)
                 groups, weights, sizes = (route.experts, route.weights,
                                           route.exp_counts)
                 routed_here = routed
@@ -260,7 +281,9 @@ class MoE(nn.Module):
                                                held + 1)[:held],
                         gmm_tiles=jnp.asarray(tiles or (0, 0, 0), jnp.int32),
                         in_place=jnp.int32(bool(tiles) and reading_in_place()),
-                        held=jnp.int32(held), routed_here=routed_here)
+                        held=jnp.int32(held), routed_here=routed_here,
+                        **({} if route.bias_changed is None
+                           else {"bias_changed": route.bias_changed}))
             return (y.reshape(orig_shape), route.l_aux, route.l_z,
                     route.exp_counts)
 

@@ -236,6 +236,9 @@ class RoutingOutput(NamedTuple):
     weights: jnp.ndarray     # [tokens, k] float32 combine weights
     experts: jnp.ndarray     # [tokens, k] int32 chosen experts
     exp_counts: jnp.ndarray  # [experts] int32 pairs routed per expert
+    # with a correction bias: scalar int32, the tokens whose chosen set is
+    # not the k largest uncorrected scores
+    bias_changed: Optional[jnp.ndarray] = None
 
 
 def router_z_loss(logits: jnp.ndarray) -> jnp.ndarray:
@@ -247,12 +250,22 @@ def router_z_loss(logits: jnp.ndarray) -> jnp.ndarray:
 
 def topk_routing(logits: jnp.ndarray, k: int, renormalize: bool = False,
                  n_group: int = 1, topk_group: int = 1,
-                 scale: float = 1.0) -> RoutingOutput:
+                 scale: float = 1.0, scoring: str = "softmax",
+                 bias: Optional[jnp.ndarray] = None) -> RoutingOutput:
     """Softmax over all experts, then the k largest probabilities per token
     (any k). ``renormalize`` divides the k weights by their sum
     (``norm_topk_prob``); without it they are the softmax's own values and
     sum to less than one. ``scale`` multiplies them last
     (``routed_scaling_factor``).
+
+    ``scoring="sigmoid"`` scores each expert by the sigmoid of its own
+    logit (LFM2, DeepSeek-V3); the k weights, renormalised, are then
+    ``s_i / (sum of the chosen s + 1e-6)`` as those models publish it.
+    ``bias`` ``[experts]`` (a parameter of the layer that a balancing rule
+    moves apart from the weights' training) is added to the scores for
+    the CHOICE and for nothing else: the k weights are the uncorrected
+    scores of the experts chosen. ``bias_changed`` then counts the tokens
+    whose chosen set is not the k largest uncorrected scores.
 
     With ``n_group > 1`` the choice is group-limited (DeepSeek-V2's
     ``group_limited_greedy``, its device-limited routing): the experts are
@@ -271,7 +284,11 @@ def topk_routing(logits: jnp.ndarray, k: int, renormalize: bool = False,
     differentiable."""
     logits = logits.astype(jnp.float32)
     num_tokens, num_experts = logits.shape
-    probs = jax.nn.softmax(logits, axis=-1)
+    sigmoid = scoring == "sigmoid"
+    if n_group > 1 and (sigmoid or bias is not None):
+        raise ValueError("group-limited routing is written for "
+                         "uncorrected softmax scores")
+    probs = _scores(logits, sigmoid)
     eligible = probs
     if n_group > 1:
         per_group = num_experts // n_group
@@ -281,19 +298,39 @@ def topk_routing(logits: jnp.ndarray, k: int, renormalize: bool = False,
         keep = jnp.sum(jax.nn.one_hot(best, n_group, dtype=jnp.int32),
                        axis=1) > 0                       # [tokens, groups]
         eligible = jnp.where(jnp.repeat(keep, per_group, axis=1), probs, 0.0)
-    weights, experts = jax.lax.top_k(eligible, k)
+    changed = None
+    if bias is None:
+        weights, experts = jax.lax.top_k(eligible, k)
+    else:
+        _, experts = jax.lax.top_k(eligible + bias.astype(jnp.float32), k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
+        # the chosen set is the uncorrected one exactly when it holds the
+        # k largest scores, that is when its least is their k-th
+        changed = jnp.sum(jnp.min(weights, axis=-1)
+                          < jax.lax.top_k(probs, k)[0][:, -1],
+                          dtype=jnp.int32)
     if renormalize:
-        weights = weights / jnp.maximum(
-            jnp.sum(weights, axis=-1, keepdims=True),
-            jnp.finfo(jnp.float32).eps)
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (total + 1e-6 if sigmoid else jnp.maximum(
+            total, jnp.finfo(jnp.float32).eps))
     if scale != 1.0:
         weights = weights * scale
     exp_counts = jnp.bincount(experts.reshape(-1), length=num_experts)
     f = exp_counts.astype(jnp.float32) / (num_tokens * k)
-    l_aux = num_experts * jnp.sum(f * jnp.mean(probs, axis=0))
+    # (of sigmoid scores, a token's shares of their sum)
+    shares = probs / jnp.sum(probs, axis=-1, keepdims=True) if sigmoid \
+        else probs
+    l_aux = num_experts * jnp.sum(f * jnp.mean(shares, axis=0))
     return RoutingOutput(l_aux, router_z_loss(logits), weights,
                          experts.astype(jnp.int32),
-                         exp_counts.astype(jnp.int32))
+                         exp_counts.astype(jnp.int32), changed)
+
+
+def _scores(logits, sigmoid: bool):
+    """Each expert's score for each token, float32: the softmax over the
+    experts, or the sigmoid of the expert's own logit."""
+    return jax.nn.sigmoid(logits) if sigmoid \
+        else jax.nn.softmax(logits, axis=-1)
 
 
 def sort_by_expert(experts: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:

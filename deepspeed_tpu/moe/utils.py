@@ -35,7 +35,8 @@ def split_moe_params(params) -> Tuple[Any, Any]:
 # rank of one layer's value of each counter the MoE layer sows; whatever
 # leads it (a scan's layer axis, none for a layer on its own) is flattened
 _COUNTER_RANK = {"routed": 0, "computed": 1, "chosen": 2, "gmm_tiles": 1,
-                 "in_place": 0, "held": 0, "routed_here": 0}
+                 "in_place": 0, "held": 0, "routed_here": 0,
+                 "bias_changed": 0}
 
 
 def routing_stats(model, params, batch):
@@ -50,7 +51,9 @@ def routing_stats(model, params, batch):
     the stacked parameters: a serving call's, never this forward's),
     ``held`` [layers] (the experts whose matrices
     the layer holds: ``computed`` is of those) and ``routed_here``
-    [layers] (the pairs routed to them). One forward program of its own,
+    [layers] (the pairs routed to them), and of layers with a correction
+    bias ``bias_changed`` [layers] (the tokens whose chosen experts are not
+    their k largest uncorrected scores). One forward program of its own,
     off the step."""
     import numpy as np
     from flax.traverse_util import flatten_dict
@@ -93,8 +96,11 @@ def publish_expert_load(model, params, batch):
     for these ``tokens_per_expert`` over ``rows / tm``, the worst layer's
     (1.0 when no expert's rows end inside a tile; each one that does is a
     tile computed twice, and an expert of no rows has one visit that
-    computes nothing); host arithmetic on the counts. A training loop
-    calls it when it wants to look, never per step."""
+    computes nothing); host arithmetic on the counts. Where the routers'
+    choice is corrected by a bias (``MoE.expert_bias``),
+    ``bias_changed_share`` is the share of the (token, layer) choices
+    whose chosen set is not the k largest uncorrected scores. A training
+    loop calls it when it wants to look, never per step."""
     from deepspeed_tpu.telemetry import publish
 
     stats = routing_stats(model, params, batch)
@@ -109,6 +115,13 @@ def publish_expert_load(model, params, batch):
             row_tile_visits(c, int(rows), tiles[0]) / (int(rows) / tiles[0])
             for c, rows in zip(counts, stats["routed"]))
     here = stats.get("routed_here", stats["routed"])
+    corrected = {}
+    if "bias_changed" in stats:
+        # a layer's tokens are its pairs over k, and k is the chosen
+        # experts' second axis
+        corrected["bias_changed_share"] = float(
+            stats["bias_changed"].sum() * stats["chosen"].shape[2]
+            / stats["routed"].sum())
     return publish(
         "moe.load", tokens_per_expert=counts.tolist(),
         max_over_mean=float((counts.max(axis=1) / counts.mean(axis=1)).max()),
@@ -116,4 +129,4 @@ def publish_expert_load(model, params, batch):
         held=int(stats["held"][0]) if "held" in stats else counts.shape[1],
         routed=int(stats["routed"].sum()), routed_here=int(here.sum()),
         grouped_matmul=path, grouped_matmul_tiles=tiles,
-        row_tile_visits_over_least=over_least)
+        row_tile_visits_over_least=over_least, **corrected)

@@ -189,7 +189,7 @@ def _kernel(layer_ref, lane_ref, blk_ref, clock_ref, q_ref, k_ref, v_ref,
 
 
 def decode_attention(q, k_cache, v_cache, valid, clock, layer=None, *,
-                     block=None):
+                     block=None, scale=None):
     """Attention of one query token per lane over a dense KV cache.
 
     ``q``: ``[B, H, D]``. ``k_cache`` / ``v_cache``: the stacked
@@ -203,7 +203,10 @@ def decode_attention(q, k_cache, v_cache, valid, clock, layer=None, *,
     times ``v``. A lane with nothing visible gets finite numbers that
     mean nothing. ``block``: positions in a block, dividing ``S`` (the
     model passes its ``decode_attention_block``); left out it is
-    ``block_positions`` of the shapes."""
+    ``block_positions`` of the shapes. ``scale``: the factor on the
+    scores where it is not ``1 / sqrt(D)`` (heads narrower than ``D``
+    stored side by side in one row: models/transformer_lm.py
+    ``kv_lane_pack``)."""
     if layer is None:
         k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
     n_layer, B, S, Hkv, D = k_cache.shape
@@ -242,7 +245,8 @@ def decode_attention(q, k_cache, v_cache, valid, clock, layer=None, *,
         return 0, 0
 
     out = pl.pallas_call(
-        functools.partial(_kernel, block=block, scale=1.0 / np.sqrt(D)),
+        functools.partial(_kernel, block=block,
+                          scale=1.0 / np.sqrt(D) if scale is None else scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(count,),

@@ -40,7 +40,8 @@ def causal_conv1d(x, weight, bias, tail):
     """Depthwise causal convolution over time.
 
     ``x`` ``[B, T, C]``, ``weight`` ``[K, C]`` (``weight[K - 1]`` multiplies
-    the current token), ``bias`` ``[C]``, ``tail`` ``[B, K - 1, C]``
+    the current token), ``bias`` ``[C]`` (None: no bias, the gated short
+    convolution's form, models/short_conv.py), ``tail`` ``[B, K - 1, C]``
     the inputs before the pass. Returns ``(y [B, T, C] float32, the next
     tail [B, K - 1, C] in tail's dtype)``."""
     K = weight.shape[0]
@@ -48,7 +49,9 @@ def causal_conv1d(x, weight, bias, tail):
     ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)  # [B, T+K-1, C]
     w = weight.astype(jnp.float32)
     y = sum(ext[:, k:k + T].astype(jnp.float32) * w[k] for k in range(K))
-    return y + bias.astype(jnp.float32), ext[:, T:].astype(tail.dtype)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return y, ext[:, T:].astype(tail.dtype)
 
 
 def ssd_step(state, x, dt, A, Bm, Cm, D):

@@ -76,6 +76,15 @@ SCOPE_KV_CACHE_CARRY = "kv_cache_carry"
 # ``ssm_scan``'s / ``ssm_conv``'s, in place; anything else that produces a
 # whole leaf is a copy
 SCOPE_SSM_STATE_CARRY = "ssm_state_carry"
+# the gated short convolution (models/short_conv.py): the input projection
+# to ``[B | C | z]``; the gate ``B * z``, the depthwise causal convolution
+# and the tail it keeps, the gate ``C`` on its output; the output projection
+SCOPE_CONV_IN_PROJ = "conv_in_proj"
+SCOPE_CONV_GATE_CONV = "conv_gate_conv"
+SCOPE_CONV_OUT_PROJ = "conv_out_proj"
+# a whole convolution-tail leaf of that mixer that no scope owns: the
+# mixer's own update of its layer's slice is ``conv_gate_conv``'s, in place
+SCOPE_CONV_STATE_CARRY = "conv_state_carry"
 # the power-retention mixer (models/power_retention.py): the q, k, v and
 # gate projections; the per-head norm of q and k and rotary; the gate, the
 # symmetric square, the state's and normaliser's read-modify-write, the
@@ -133,7 +142,8 @@ _CARRY_FREE = frozenset((
     SCOPE_GRAD_NORM_CLIP, SCOPE_LM_HEAD, SCOPE_LM_HEAD_CE, SCOPE_MLM_HEAD,
     SCOPE_ATTN_CORE, SCOPE_KV_CACHE_WRITE, SCOPE_KV_CACHE_READ, SCOPE_SAMPLE,
     SCOPE_SSM_IN_PROJ, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE_NORM,
-    SCOPE_SSM_OUT_PROJ, SCOPE_RET_PROJ, SCOPE_RET_QK_NORM_ROPE,
+    SCOPE_SSM_OUT_PROJ, SCOPE_CONV_IN_PROJ, SCOPE_CONV_GATE_CONV,
+    SCOPE_CONV_OUT_PROJ, SCOPE_RET_PROJ, SCOPE_RET_QK_NORM_ROPE,
     SCOPE_RET_STATE, SCOPE_RET_OUT_PROJ, SCOPE_MLA_Q_PROJ,
     SCOPE_MLA_KV_PROJ, SCOPE_MLA_ABSORB, SCOPE_MLA_ATTN,
     SCOPE_MLA_OUT_PROJ, SCOPE_DSA_INDEX_PROJ, SCOPE_DSA_INDEX_SCORES,

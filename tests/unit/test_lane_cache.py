@@ -81,8 +81,13 @@ def test_the_layout_says_of_each_cache_what_the_parent_said(name):
                                                 prompt_bucket=16)
     sched._ensure_compiled()
     lanes = sched.lane_cache
-    assert lanes.geometry() == want["geometry"]
-    assert list(lanes.geometry()) == [
+    # (since PR 52 the geometry also says how many layers keep each
+    # declared leaf: all of them, in a model of one kind of layer)
+    geometry = dict(lanes.geometry())
+    assert set(geometry.pop("leaf_layers").items()) == {
+        (leaf.name, eng.module.config.n_layer) for leaf in lanes.leaves}
+    assert geometry == want["geometry"]
+    assert list(geometry) == [
         "kv_cache_dtype", "resident_bytes", "unquantized_bytes",
         "bytes_per_lane", "state_bytes", "conv_bytes", "norm_bytes",
         "kv_bytes", "state_bytes_per_lane", "conv_bytes_per_lane",
