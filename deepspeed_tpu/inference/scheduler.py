@@ -26,8 +26,17 @@ its lanes:
   computes. A lane's end is therefore seen one step late: the token that
   step n+1 computed for a sequence that ended at step n is dropped (no
   callback, no journal record, no count) and its cache rows are garbage
-  that the lane's next admission overwrites. Speculative decoding keeps
-  the synchronous order, because its next input is the host's acceptance
+  that the lane's next admission overwrites. An admission is handed to
+  the device whole and waited for as little: prefill, the first token
+  into the device's token vector, splice, for every free lane (no more
+  than ``ADMISSIONS_IN_FLIGHT`` unread at once), then the decode step
+  with the new lanes in it, and only then the host reads the step in
+  flight and after it the first tokens, in admission order, so
+  the device goes from one admission's splice to the next one's prefill
+  and on to the step without waiting for the host. A request that ends
+  at its first token is seen as late as any other: one dropped row, the
+  lane refilled an iteration later. Speculative decoding keeps the
+  synchronous order, because its next input is the host's acceptance
   test.
 
 Free lanes keep decoding garbage tokens — attention is row-independent and
@@ -56,6 +65,7 @@ tokens out of the global region — serve those layouts through
 ``generate``, or with bucket == prompt length.
 """
 
+import contextlib
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -103,6 +113,15 @@ from deepspeed_tpu.telemetry.spans import (
 # the names of the scheduler's own programs as a profiler trace has them
 # (its ``XLA Modules`` events) and as ``program_scopes()`` keys them
 PROGRAM_SPLICE = "jit_splice"
+
+# The plain loop's admissions whose first token the host has not read yet,
+# at most: a prefill's ``[1, ...]`` lane cache is allocated when the prefill
+# is dispatched and freed when its splice has RUN, so every admission in
+# flight holds one (0.2 GB at 1.3B and 1,024 positions), and a run's first
+# iteration has every lane free. Four covers what steady traffic admits in
+# one iteration; beyond it the oldest first token is read first, which waits
+# for that prefill while the others keep the device busy.
+ADMISSIONS_IN_FLIGHT = 4
 
 
 class AdmissionRejected(RuntimeError):
@@ -212,6 +231,9 @@ class ServingStats:
     # tokens a step computed for a lane whose request had ended by the
     # time the host read them (a lane's end is seen one step late)
     decode_tokens_discarded: int = 0
+    # first tokens read after the decode step of their admission's
+    # iteration was dispatched (the plain loop's every admission)
+    first_tokens_behind_step: int = 0
     # over the plain loop's decode steps, the sum of each step's
     # ``kv_blocks_read_share`` (``LaneClocks.step``)
     kv_blocks_read_share_sum: float = 0.0
@@ -241,6 +263,7 @@ class ServingStats:
             "decode_steps": self.decode_steps,
             "decode_steps_ahead": self.decode_steps_ahead,
             "decode_tokens_discarded": self.decode_tokens_discarded,
+            "first_tokens_behind_step": self.first_tokens_behind_step,
             "kv_blocks_read_share": (
                 self.kv_blocks_read_share_sum / self.decode_steps
                 if self.decode_steps else 0.0),
@@ -874,12 +897,12 @@ class ContinuousBatchingScheduler:
         return out
 
     def _publish_stats(self, stats: "ServingStats", lanes,
-                       static: Dict[str, Any]) -> None:
+                       kv: Dict[str, Any]) -> None:
         """One ``serve.stats`` snapshot per scheduler iteration — queue
         depth, lane occupancy, shed counters, prefix hit-rate and fleet
         health, so dashboards see front-door pressure without polling.
-        ``static`` holds the keys that cannot change during a ``run()``
-        (the KV geometry), computed once by it."""
+        ``kv`` is ``kv_cache_stats()``, which cannot change during a
+        ``run()`` and is computed once by it."""
         from deepspeed_tpu.telemetry.bus import KIND_SERVE_STATS, publish
 
         self._lanes_active = sum(1 for l in lanes if l is not None)
@@ -899,7 +922,9 @@ class ContinuousBatchingScheduler:
         if self.health_provider is not None and \
                 hasattr(self.health_provider, "states"):
             payload["health"] = dict(self.health_provider.states())
-        publish(KIND_SERVE_STATS, **payload, **static)
+        publish(KIND_SERVE_STATS, **payload,
+                kv_resident_bytes=kv["resident_bytes"],
+                kv_unquantized_bytes=kv["unquantized_bytes"])
 
     # ------------------------------------------------------------------
     def run(self, poll_fn: Optional[Callable[[], None]] = None
@@ -975,8 +1000,11 @@ class ContinuousBatchingScheduler:
         # the KV geometry cannot change during a run: read it once for
         # every serve.stats event (kv_cache_stats also asks for the HBM size)
         kv = self.kv_cache_stats()
-        stats_static = {"kv_resident_bytes": kv["resident_bytes"],
-                        "kv_unquantized_bytes": kv["unquantized_bytes"]}
+        # the plain loop's admissions of one iteration: their open spans,
+        # and ``(lane_no, lane, first token on the device)`` of those whose
+        # first token the host has not read yet, in admission order
+        admits = contextlib.ExitStack()
+        admitted: list = []
 
         from deepspeed_tpu.telemetry.bus import (
             KIND_SERVE_ADMIT,
@@ -1024,6 +1052,15 @@ class ContinuousBatchingScheduler:
                 if done:
                     finish(lane_no, lane)
 
+        def first_token(behind_step: int) -> None:
+            """Read the oldest unread first token, which waits for what is
+            left of its prefill, and emit it."""
+            lane_no, lane, token = admitted.pop(0)
+            with span(SERVE_FIRST_TOKEN_READ, behind_step=behind_step):
+                token = int(np.asarray(token).reshape(-1)[0])
+            stats.first_tokens_behind_step += behind_step
+            emit(lane_no, lane, token)
+
         def deliver(step) -> None:
             """Read a dispatched decode step's tokens, ``(its [slots]
             token vector, the lanes as they stood at its dispatch)``, and
@@ -1048,13 +1085,28 @@ class ContinuousBatchingScheduler:
                     break  # queue left intact for journal hand-off
             elif not (self._pending or active):
                 break
-            with span(SERVE_ITERATION, decode_steps=stats.decode_steps):
-                # admissions: fill every free lane from the queue. A
-                # request that completes AT admission (max_new 1, or first
-                # token is EOS) frees its lane for the next pending request
-                # immediately. An expired deadline sheds here — before the
-                # prefill, so a doomed request never occupies a lane.
-                # Draining admits none.
+            with span(SERVE_ITERATION, decode_steps=stats.decode_steps), \
+                    admits:
+                # admissions: fill every free lane from the queue. An
+                # expired deadline sheds here — before the prefill, so a
+                # doomed request never occupies a lane. Draining admits
+                # none. The plain loop hands the device an admission's
+                # whole work (prefill, the first token into the token
+                # vector, splice) and waits for none of it: the token is
+                # read and emitted further down, once the decode step is
+                # dispatched behind it, and the admission's span stays
+                # open until then, around the time its prefill runs on the
+                # device (``admits`` closes the spans in reverse, so those
+                # of one iteration nest, also when the loop raises). So a
+                # request that ends AT its first token (max_new 1, or the
+                # token is EOS) is seen after that step left with its
+                # lane: the step computes one row for nobody
+                # (``decode_tokens_discarded``) and the lane is refilled
+                # in the next iteration: one admission a lane and
+                # iteration. No more than ``ADMISSIONS_IN_FLIGHT`` wait
+                # for their first token at once. The speculative loop's
+                # next input is the host's, so it reads the token here
+                # and refills such a lane at once.
                 for lane_no in range(
                         self.slots if not self._draining else 0):
                     while lanes[lane_no] is None and self._pending:
@@ -1063,6 +1115,13 @@ class ContinuousBatchingScheduler:
                                 time.monotonic() > req.t_deadline:
                             self._shed_expired(req, t_submit)
                             continue
+                        if len(admitted) == ADMISSIONS_IN_FLIGHT:
+                            # (a run's first iteration, mostly.) The step
+                            # in flight ended before the prefills: its
+                            # tokens go first, as the device made them
+                            if unread:
+                                deliver(unread.pop())
+                            first_token(0)
                         replayed = len(req.replay_tokens or ())
                         comp = Completion(request_id=req.request_id,
                                           tokens=list(req.replay_tokens or ()),
@@ -1073,52 +1132,56 @@ class ContinuousBatchingScheduler:
                         queue_wait_s = comp.t_admit - t_submit
                         # inline, not a helper closure: the same admission
                         # through a nested function cost the ramp 1.2 s of
-                        # 12 on the chip (PERF.md, PR 24)
-                        with span(SERVE_ADMIT, request_id=req.request_id,
-                                  lane=lane_no, prompt_len=len(req.prompt),
-                                  bucket=bucket,
-                                  queue_wait_us=int(queue_wait_s * 1e6),
-                                  queue_depth=len(self._pending)):
-                            publish(KIND_SERVE_ADMIT,
-                                    request_id=req.request_id, lane=lane_no,
-                                    prompt_len=len(req.prompt),
-                                    bucket=bucket, replayed=replayed,
-                                    queue_wait_s=queue_wait_s,
-                                    queue_depth=len(self._pending))
-                            with span(SERVE_PREFILL,
-                                      chunks=self._prefill_passes(
-                                          bucket, req)):
-                                first_tok, sub_cache, draft_sub = \
-                                    self._admit_prefill(req, bucket)
-                                if not use_spec:
-                                    tok_dev = self._set_token(
-                                        tok_dev, lane_no, first_tok)
-                            if unread:
-                                # the device ends the step in flight before
-                                # the prefill queued behind it: the other
-                                # lanes get its tokens now, in the device's
-                                # order, not after the blocking read below
-                                deliver(unread.pop())
-                            with span(SERVE_FIRST_TOKEN_READ):
+                        # 12 on the chip (PERF.md, PR 24); the attributes
+                        # that take a call come first, which keeps this
+                        # frame's stack at 15 slots: the ramp's host time
+                        # moves with the frame's size too (ROADMAP D15)
+                        admits.enter_context(span(
+                            SERVE_ADMIT,
+                            queue_wait_us=int(queue_wait_s * 1e6),
+                            queue_depth=len(self._pending),
+                            prompt_len=len(req.prompt),
+                            request_id=req.request_id, lane=lane_no,
+                            bucket=bucket))
+                        publish(KIND_SERVE_ADMIT,
+                                request_id=req.request_id, lane=lane_no,
+                                prompt_len=len(req.prompt),
+                                bucket=bucket, replayed=replayed,
+                                queue_wait_s=queue_wait_s,
+                                queue_depth=len(self._pending))
+                        with span(SERVE_PREFILL,
+                                  chunks=self._prefill_passes(bucket, req)):
+                            first_tok, sub_cache, draft_sub = \
+                                self._admit_prefill(req, bucket)
+                            if not use_spec:
+                                tok_dev = self._set_token(
+                                    tok_dev, lane_no, first_tok)
+                        if use_spec:
+                            with span(SERVE_FIRST_TOKEN_READ, behind_step=0):
                                 first_tok = int(
                                     np.asarray(first_tok).reshape(-1)[0])
-                            with span(SERVE_SPLICE):
-                                cache = self._splice(
-                                    cache, sub_cache, lane_no)
-                                if use_spec:
-                                    draft_cache = self._splice(
-                                        draft_cache, draft_sub, lane_no)
-                                    tok[lane_no] = first_tok
-                            self._clocks.admit(lane_no, bucket,
-                                               len(req.prompt), replayed)
-                            lane = _Lane(req=req, comp=comp, emitted=replayed)
-                            lanes[lane_no] = lane
+                        with span(SERVE_SPLICE):
+                            cache = self._splice(cache, sub_cache, lane_no)
+                            if use_spec:
+                                draft_cache = self._splice(
+                                    draft_cache, draft_sub, lane_no)
+                                tok[lane_no] = first_tok
+                        self._clocks.admit(lane_no, bucket,
+                                           len(req.prompt), replayed)
+                        lane = _Lane(req=req, comp=comp, emitted=replayed)
+                        lanes[lane_no] = lane
+                        if use_spec:
                             emit(lane_no, lane, first_tok)
+                            admits.close()
+                        else:
+                            admitted.append((lane_no, lane, first_tok))
 
                 with span(SERVE_STATS):
-                    self._publish_stats(stats, lanes, stats_static)
+                    self._publish_stats(stats, lanes, kv)
                 if not any(l is not None for l in lanes):
-                    continue  # everything admitted finished at token 1
+                    # nothing was admitted (shed, or draining), or the
+                    # speculative loop's admissions all ended at token 1
+                    continue
 
                 if use_spec:
                     # speculative step: the draft proposes k greedy tokens
@@ -1188,12 +1251,11 @@ class ContinuousBatchingScheduler:
                     # lane writes its own rows only and a slot past its
                     # last position nowhere, and the lane's next splice
                     # overwrites every row it holds.
-                    # The first step of a run is read at once: where
-                    # the decode program is compiled or loaded, it is in
-                    # that dispatch, and the tokens of a host that has
-                    # been away that long are due before the next poll.
+                    # An admission's lane is in this step: its first token
+                    # is in the token vector and its splice ahead of the
+                    # step in the device's queue, so the device goes from
+                    # prefill to splice to step while the host gets here.
                     ahead = len(unread)
-                    read = ahead or stats.decode_steps == 0
                     with span(SERVE_DECODE_STEP,
                               lanes_active=self._lanes_active, ahead=ahead,
                               kv_blocks_read_share=self._clocks.step()):
@@ -1202,12 +1264,31 @@ class ContinuousBatchingScheduler:
                         stats.decode_steps += 1
                         stats.decode_steps_ahead += ahead
                         unread.append((tok_dev, list(lanes), cache))
-                        if read:
+                        if ahead:
                             with span(SERVE_DECODE_READ):
                                 step = unread.pop(0)
                                 step = (np.asarray(step[0]), step[1])
-                    if read:
+                    if ahead:
                         deliver(step)
+                    # the unread first tokens of this iteration's
+                    # admissions, in order: each read returns when its
+                    # prefill ends, with the splices and the step queued
+                    # behind it. They follow the step that was in flight, which
+                    # the device ended before the prefills and which holds
+                    # no token of theirs, and go before the step just
+                    # dispatched is ever delivered, so every stream stays
+                    # in order. A hand-off's token is a host int and waits
+                    # for nothing.
+                    while admitted:
+                        first_token(1)
+                    admits.close()
+                    if stats.decode_steps == 1:
+                        # the first step of a run is read at once: where
+                        # the decode program is compiled or loaded, it is
+                        # in that dispatch, and the tokens of a host that
+                        # has been away that long are due before the next
+                        # poll
+                        deliver(unread.pop())
 
         if unread:
             # dispatched before the host read that the last lanes had

@@ -120,13 +120,19 @@ def test_one_admit_span_per_request_with_its_attributes(served):
         assert attrs["bucket"] == sched._bucketed_len(attrs["prompt_len"])
         assert attrs["queue_wait_us"] >= 0 and attrs["lane"] in (0, 1)
         assert "queue_depth" in attrs
-        # each admission has exactly its three children, in order
-        kids = [s[0] for s in found if s is not a and _inside(s, a)
-                and s[0] in (spans.SERVE_PREFILL,
-                             spans.SERVE_FIRST_TOKEN_READ,
-                             spans.SERVE_SPLICE)]
-        assert kids == [spans.SERVE_PREFILL, spans.SERVE_FIRST_TOKEN_READ,
-                        spans.SERVE_SPLICE]
+    # each admission encloses exactly one prefill, one splice and one
+    # first-token read of its own, in that order (PR 53: the read comes
+    # after the iteration's decode step is dispatched): the k-th of each
+    # is the k-th admission's, and follows its sibling
+    for name in (spans.SERVE_PREFILL, spans.SERVE_SPLICE,
+                 spans.SERVE_FIRST_TOKEN_READ):
+        kids = [s for s in found if s[0] == name]
+        assert len(kids) == len(admits)
+        assert all(_inside(k, a) for k, a in zip(kids, admits))
+    for p, s, r in zip(*([x for x in found if x[0] == name] for name in (
+            spans.SERVE_PREFILL, spans.SERVE_SPLICE,
+            spans.SERVE_FIRST_TOKEN_READ))):
+        assert p[2] <= s[1] and s[2] <= r[1]
     prefill = [s for s in found if s[0] == spans.SERVE_PREFILL]
     assert all(p[3]["chunks"] == 1 for p in prefill)    # dense cache
     steps = [s for s in found if s[0] == spans.SERVE_DECODE_STEP]
@@ -139,20 +145,55 @@ def test_one_admit_span_per_request_with_its_attributes(served):
 def test_decode_step_spans_say_whether_they_ran_ahead(served):
     """``ahead`` is 1 on a step dispatched with the step before it unread,
     whose read is then that span's child; 0 on the first step of the run,
-    which reads itself, and on the step after an admission, which read the
-    step in flight itself and has no ``serve.decode_read`` of its own."""
+    which is read on its own after the first tokens it follows, and on the
+    second, which finds nothing unread. The step of an iteration with an
+    admission is ahead like any other (PR 53) and lies inside the
+    admission's span, which closes after the first token's emit."""
     _, _, _, found = served
     steps = [s for s in found if s[0] == spans.SERVE_DECODE_STEP]
     reads = [s for s in found if s[0] == spans.SERVE_DECODE_READ]
-    assert {s[3]["ahead"] for s in steps} == {0, 1}
-    assert steps[0][3]["ahead"] == 0
-    for i, s in enumerate(steps):
-        assert len([r for r in reads if _inside(r, s)]) \
-            == (s[3]["ahead"] or i == 0)
-    assert sum(s[3]["ahead"] for s in steps) + 1 == len(reads)
-    for a in (s for s in found if s[0] == spans.SERVE_ADMIT):
-        later = [s for s in steps if s[1] >= a[2]]
-        assert not later or later[0][3]["ahead"] == 0
+    assert [s[3]["ahead"] for s in steps] == [0, 0] + [1] * (len(steps) - 2)
+    for s in steps:
+        assert len([r for r in reads if _inside(r, s)]) == s[3]["ahead"]
+    assert sum(s[3]["ahead"] for s in steps) == len(reads)
+    admits = [s for s in found if s[0] == spans.SERVE_ADMIT]
+    assert len(admits) == 3
+    for a in admits:
+        inside = [s for s in steps if _inside(s, a)]
+        assert len(inside) == 1
+        assert inside[0][3]["ahead"] == (0 if inside[0] is steps[0] else 1)
+
+
+def test_two_admissions_of_one_iteration_nest(served):
+    """The run's first iteration admits into both lanes: the second
+    admission's span lies inside the first one's, each holds its own
+    prefill and splice, and the first tokens are read in admission order,
+    after the step, inside both (``behind_step`` 1)."""
+    _, ids, _, found = served
+    admits = [s for s in found if s[0] == spans.SERVE_ADMIT]
+    outer, inner = admits[0], admits[1]
+    assert [a[3]["request_id"] for a in admits[:2]] == ids[:2]
+    assert _inside(inner, outer) and not _inside(admits[2], outer)
+    prefills = [s for s in found if s[0] == spans.SERVE_PREFILL]
+    assert not _inside(prefills[0], inner) and _inside(prefills[1], inner)
+    reads = [s for s in found if s[0] == spans.SERVE_FIRST_TOKEN_READ]
+    assert all(r[3]["behind_step"] == 1 for r in reads)
+    step = next(s for s in found if s[0] == spans.SERVE_DECODE_STEP)
+    assert _inside(step, inner)
+    assert step[2] <= reads[0][1] and reads[0][2] <= reads[1][1]
+    assert _inside(reads[0], inner) and _inside(reads[1], inner)
+    emits = [s for s in found if s[0] == spans.SERVE_EMIT][:2]
+    assert [e[3]["request_id"] for e in emits] == ids[:2]
+    assert all(_inside(e, inner) for e in emits)
+
+
+def test_summary_counts_the_first_tokens_read_behind_their_step():
+    sched = _scheduler()
+    ids = [sched.submit(p, max_new_tokens=3) for p in PROMPTS]
+    stats = sched.run()
+    assert stats.first_tokens_behind_step == len(ids)
+    assert stats.summary()["first_tokens_behind_step"] == len(ids)
+    assert stats.summary()["decode_steps_ahead"] == stats.decode_steps - 2
 
 
 def test_greedy_tokens_identical_with_and_without_a_session(served):

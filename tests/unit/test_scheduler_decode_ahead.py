@@ -224,14 +224,19 @@ def test_one_token_asked_for_ends_at_admission(eng, sched):
     _check_streams(eng, stats, rec, wants)
 
 
-def test_every_request_ending_at_admission_runs_no_decode_step(eng, sched):
+def test_every_request_ending_at_admission_costs_one_row_of_one_step(
+        eng, sched):
+    """A first token is read after the iteration's decode step left with
+    its lane (PR 53): requests that all end there cost that one step, a
+    row each, and no step more."""
     rec, wants = Recorder(), {}
-    for p in _prompts(5, seed=5):
+    for p in _prompts(4, seed=5):
         wants[sched.submit(p, max_new_tokens=1,
                            stream_callback=rec)] = (p, 1, None)
     stats = sched.run()
     _check_streams(eng, stats, rec, wants)
-    assert stats.decode_steps == 0 and stats.decode_tokens_discarded == 0
+    assert stats.decode_steps == 1 and stats.decode_tokens_discarded == 4
+    assert stats.first_tokens_behind_step == 4
 
 
 # ---------------------------------------------------------------------------
