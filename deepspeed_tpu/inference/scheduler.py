@@ -101,6 +101,7 @@ from deepspeed_tpu.telemetry.spans import (
     SERVE_ADMIT,
     SERVE_DECODE_READ,
     SERVE_DECODE_STEP,
+    SERVE_DELIVER,
     SERVE_EMIT,
     SERVE_FIRST_TOKEN_READ,
     SERVE_ITERATION,
@@ -113,6 +114,9 @@ from deepspeed_tpu.telemetry.spans import (
 # the names of the scheduler's own programs as a profiler trace has them
 # (its ``XLA Modules`` events) and as ``program_scopes()`` keys them
 PROGRAM_SPLICE = "jit_splice"
+
+# what a token after a request's first is emitted under: no span of its own
+_NO_SPAN = contextlib.nullcontext()
 
 # The plain loop's admissions whose first token the host has not read yet,
 # at most: a prefill's ``[1, ...]`` lane cache is allocated when the prefill
@@ -1024,8 +1028,12 @@ class ContinuousBatchingScheduler:
 
         def emit(lane_no: int, lane: _Lane, token: int) -> None:
             """Record one token and hand it to the journal and the stream
-            callback; a sequence that is done frees its lane."""
-            with span(SERVE_EMIT, request_id=lane.req.request_id):
+            callback; a sequence that is done frees its lane. A request's
+            first token is a span (it ends the request's time to first
+            token on the trace's clock); the others are their step's
+            ``ds:serve.deliver``."""
+            with span(SERVE_EMIT, request_id=lane.req.request_id) \
+                    if lane.comp.t_first_token == 0.0 else _NO_SPAN:
                 now = time.monotonic()
                 lane.comp.tokens.append(token)
                 lane.emitted += 1
@@ -1056,7 +1064,8 @@ class ContinuousBatchingScheduler:
             """Read the oldest unread first token, which waits for what is
             left of its prefill, and emit it."""
             lane_no, lane, token = admitted.pop(0)
-            with span(SERVE_FIRST_TOKEN_READ, behind_step=behind_step):
+            with span(SERVE_FIRST_TOKEN_READ, behind_step=behind_step,
+                      request_id=lane.req.request_id):
                 token = int(np.asarray(token).reshape(-1)[0])
             stats.first_tokens_behind_step += behind_step
             emit(lane_no, lane, token)
@@ -1068,13 +1077,14 @@ class ContinuousBatchingScheduler:
             request has ended since (it may already hold another) drops
             its token: no callback, no journal record, no count."""
             step_tok, owners = np.asarray(step[0]), step[1]
-            for lane_no, lane in enumerate(owners):
-                if lane is None:
-                    continue
-                if lanes[lane_no] is lane:
-                    emit(lane_no, lane, int(step_tok[lane_no]))
-                else:
-                    stats.decode_tokens_discarded += 1
+            with span(SERVE_DELIVER):
+                for lane_no, lane in enumerate(owners):
+                    if lane is None:
+                        continue
+                    if lanes[lane_no] is lane:
+                        emit(lane_no, lane, int(step_tok[lane_no]))
+                    else:
+                        stats.decode_tokens_discarded += 1
 
         while True:
             if poll_fn is not None:
@@ -1157,7 +1167,8 @@ class ContinuousBatchingScheduler:
                                 tok_dev = self._set_token(
                                     tok_dev, lane_no, first_tok)
                         if use_spec:
-                            with span(SERVE_FIRST_TOKEN_READ, behind_step=0):
+                            with span(SERVE_FIRST_TOKEN_READ, behind_step=0,
+                                      request_id=req.request_id):
                                 first_tok = int(
                                     np.asarray(first_tok).reshape(-1)[0])
                         with span(SERVE_SPLICE):
@@ -1229,12 +1240,13 @@ class ContinuousBatchingScheduler:
                             proposed=k * len(live), accepted=accepted_now,
                             proposed_total=self.spec_proposed,
                             accepted_total=self.spec_accepted)
-                    for lane_no in live:
-                        lane = lanes[lane_no]
-                        for j in range(int(m_eff[lane_no]) + 1):
-                            emit(lane_no, lane, int(g_np[lane_no, j]))
-                            if lanes[lane_no] is None:
-                                break
+                    with span(SERVE_DELIVER):
+                        for lane_no in live:
+                            lane = lanes[lane_no]
+                            for j in range(int(m_eff[lane_no]) + 1):
+                                emit(lane_no, lane, int(g_np[lane_no, j]))
+                                if lanes[lane_no] is None:
+                                    break
                     tok = g_np[np.arange(self.slots), m_eff] \
                         .astype(np.int32).copy()
                 else:

@@ -151,7 +151,7 @@ _CARRY_FREE = frozenset((
 _STRUCTURE = re.compile(
     r"^(jit\(.*\)|pjit\(.*\)|while|body|cond|branch_\d+_fun|closed_call|"
     r"core_call|custom_jvp_call|custom_vjp_call|custom_vjp_call_jaxpr)$")
-_CONTAINERS = ("while", "conditional", "call")
+CONTAINERS = ("while", "conditional", "call")
 
 _MODULE = re.compile(r"^HloModule ([\w.\-]+)")
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$")
@@ -282,7 +282,7 @@ def instruction_scopes(text, carry_shapes=()):
                 path = inner.most_common(1)[0][0]
         if path and path.startswith(_RAGGED_DOT) and "/" not in path:
             path = "jit(%s)/%s/%s" % (module, SCOPE_MOE_EXPERTS, path)
-        if dims in carry and opcode not in _CONTAINERS \
+        if dims in carry and opcode not in CONTAINERS \
                 and not has_scope(path, *_CARRY_FREE):
             path = _tag_carry(path, module, opcode, carry[dims])
         table[name] = path
@@ -378,6 +378,17 @@ def scope_table(texts, carry_shapes=()):
 # ---------------------------------------------------------------------------
 # joining a trace
 # ---------------------------------------------------------------------------
+def event_instruction(text):
+    """``(instruction, opcode)`` of an ``XLA Ops`` event, which is named
+    by its HLO instruction's whole text; a text that is no instruction is
+    its own name with the opcode ``?``. An opcode in ``CONTAINERS`` spans
+    the events of its body and is no work of its own."""
+    nm = _EVENT_NAME.match(text)
+    op = _OPCODE.search(text, nm.end() - 1) if nm else None
+    return (nm.group(1) if nm else text.lstrip("%"),
+            op.group(1) if op else "?")
+
+
 def load_trace(path):
     """A ``ProfileData`` from an ``.xplane.pb`` or ``.xplane.pb.gz`` file."""
     from jax.profiler import ProfileData
@@ -415,12 +426,9 @@ def time_by_scope(profile, table, window=None):
         for e in ops or ():
             text = e.name
             if text not in opcodes:
-                nm = _EVENT_NAME.match(text)
-                op = _OPCODE.search(text, nm.end() - 1) if nm else None
-                opcodes[text] = (nm.group(1) if nm else text.lstrip("%"),
-                                 op.group(1) if op else "?")
+                opcodes[text] = event_instruction(text)
             name, opcode = opcodes[text]
-            if opcode in _CONTAINERS:
+            if opcode in CONTAINERS:
                 continue
             a, b = e.start_ns, e.start_ns + e.duration_ns
             if window:

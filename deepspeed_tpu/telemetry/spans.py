@@ -22,7 +22,12 @@ SERVE_ADMIT = "serve.admit"
 SERVE_PREFILL = "serve.prefill"
 SERVE_FIRST_TOKEN_READ = "serve.first_token_read"
 SERVE_SPLICE = "serve.splice"
+# a request's first token handed on (one an admission, ``request_id``): the
+# end of its time-to-first-token on the trace's clock
 SERVE_EMIT = "serve.emit"
+# one decode step's tokens handed to their lanes (no attribute: the account
+# of a trace names the span the host was in around a gap of the device's)
+SERVE_DELIVER = "serve.deliver"
 SERVE_STATS = "serve.stats"
 SERVE_DECODE_STEP = "serve.decode_step"
 SERVE_DECODE_READ = "serve.decode_read"
@@ -32,12 +37,21 @@ TRAIN_PHASE = "train."      # + the engine's phase name
 PROGRAM_BUILD = "program.build"
 
 
+# ``jax.profiler.TraceAnnotation``, resolved by the first span (the
+# package imports no jax at import; an import statement a call cost more
+# than the annotation itself while no session records)
+_annotation = None
+
+
 def span(name, **attrs):
     """A context manager that records ``ds:<name>`` with ``attrs`` while a
     profiler session is active and is a no-op otherwise. An attribute that
     is None is left out: what a caller has nothing to say about is not
     written as a number."""
-    from jax.profiler import TraceAnnotation
-
-    return TraceAnnotation(SPAN_PREFIX + name, **{
-        k: v for k, v in attrs.items() if v is not None})
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    if None in attrs.values():
+        attrs = {k: v for k, v in attrs.items() if v is not None}
+    return _annotation(SPAN_PREFIX + name, **attrs)

@@ -25,8 +25,8 @@ from unit.simple_model import random_token_batches, tiny_gpt_config
 
 SERVE_SPANS = [spans.SERVE_ITERATION, spans.SERVE_ADMIT, spans.SERVE_PREFILL,
                spans.SERVE_FIRST_TOKEN_READ, spans.SERVE_SPLICE,
-               spans.SERVE_EMIT, spans.SERVE_STATS, spans.SERVE_DECODE_STEP,
-               spans.SERVE_DECODE_READ]
+               spans.SERVE_EMIT, spans.SERVE_DELIVER, spans.SERVE_STATS,
+               spans.SERVE_DECODE_STEP, spans.SERVE_DECODE_READ]
 PROMPTS = [[5, 9, 3], list(range(1, 20)), [7] * 9]
 
 
@@ -101,7 +101,7 @@ def test_scheduler_run_records_every_serve_span(served, name):
     (spans.SERVE_ADMIT, spans.SERVE_ITERATION),
     (spans.SERVE_STATS, spans.SERVE_ITERATION),
     (spans.SERVE_DECODE_STEP, spans.SERVE_ITERATION),
-    (spans.SERVE_EMIT, spans.SERVE_ITERATION),
+    (spans.SERVE_EMIT, spans.SERVE_ADMIT),
     (spans.SERVE_DECODE_READ, spans.SERVE_DECODE_STEP),
 ])
 def test_serve_spans_nest_as_documented(served, child, parent):
@@ -185,6 +185,34 @@ def test_two_admissions_of_one_iteration_nest(served):
     emits = [s for s in found if s[0] == spans.SERVE_EMIT][:2]
     assert [e[3]["request_id"] for e in emits] == ids[:2]
     assert all(_inside(e, inner) for e in emits)
+
+
+def test_one_emit_span_a_request_and_one_deliver_span_a_delivered_step(
+        served):
+    """A request's first token is the one token with a span of its own,
+    right behind the read that names the same request; the others are
+    their step's ``ds:serve.deliver``: one a delivered step, after that
+    step's dispatch, with no attribute of its own."""
+    _, ids, tokens, found = served
+    emits = [s for s in found if s[0] == spans.SERVE_EMIT]
+    reads = [s for s in found if s[0] == spans.SERVE_FIRST_TOKEN_READ]
+    admits = [s for s in found if s[0] == spans.SERVE_ADMIT]
+    assert [e[3]["request_id"] for e in emits] == ids
+    assert [r[3]["request_id"] for r in reads] == ids
+    for a, r, e in zip(admits, reads, emits):
+        assert a[3]["request_id"] == r[3]["request_id"]
+        assert _inside(r, a) and _inside(e, a) and r[2] <= e[1]
+    steps = [s for s in found if s[0] == spans.SERVE_DECODE_STEP]
+    delivers = [s for s in found if s[0] == spans.SERVE_DELIVER]
+    assert len(delivers) == len(steps) < sum(len(t) for t in tokens)
+    assert all(d[3] == {} for d in delivers)
+    # the run's last step is delivered after the loop
+    iters = [s for s in found if s[0] == spans.SERVE_ITERATION]
+    assert all(any(_inside(d, i) for i in iters) for d in delivers[:-1])
+    assert delivers[-1][1] >= iters[-1][2]
+    assert all(step[1] < d[1] for step, d in zip(steps, delivers))
+    # a token after the first has no span of its own
+    assert not any(_inside(e, d) for e in emits for d in delivers)
 
 
 def test_summary_counts_the_first_tokens_read_behind_their_step():
@@ -316,6 +344,29 @@ def test_span_without_a_session_adds_no_event_fence_or_wait(monkeypatch):
     finally:
         telemetry_bus.unsubscribe(events.append)
     assert waits == [] and events == []
+
+
+@pytest.mark.parametrize("attrs,want", [
+    ({"request_id": 3, "lane": None}, {"request_id": 3}),
+    ({"ahead": None}, {}),
+    ({"ahead": 0, "lane": 0}, {"ahead": 0, "lane": 0}),
+    ({}, {}),
+])
+def test_span_leaves_out_what_the_caller_has_nothing_to_say_about(
+        monkeypatch, attrs, want):
+    made = []
+    monkeypatch.setattr(spans, "_annotation",
+                        lambda name, **kw: made.append((name, kw)))
+    spans.span(spans.SERVE_ADMIT, **attrs)
+    assert made == [(spans.SPAN_PREFIX + spans.SERVE_ADMIT, want)]
+
+
+def test_span_resolves_the_annotation_once(monkeypatch):
+    monkeypatch.setattr(spans, "_annotation", None)
+    first = spans.span(spans.SERVE_STATS)
+    assert spans._annotation is jax.profiler.TraceAnnotation
+    assert type(first) is type(spans.span(spans.SERVE_STATS, x=1)) \
+        is jax.profiler.TraceAnnotation
 
 
 def test_a_traced_step_waits_no_more_than_an_untraced_one(monkeypatch,

@@ -604,7 +604,11 @@ def test_every_admission_span_is_open_from_its_dispatch_to_its_emit(
             == len(admits)
     reads = [s for s in watch.closed
              if s.name == spans.SERVE_FIRST_TOKEN_READ]
-    assert all(s.attrs == {"behind_step": 1} for s in reads)
+    assert all(s.attrs["behind_step"] == 1 for s in reads)
+    # each read names its admission's request, in admission order
+    assert [s.attrs["request_id"] for s in reads] == [
+        s.attrs["request_id"]
+        for s in sorted(admits, key=lambda a: watch.log.index(("open", a)))]
     # the first iteration's four: closed in reverse of their opening
     first = [s.attrs["request_id"] for s in admits[:4]]
     assert first == sorted(first, reverse=True)
@@ -637,7 +641,8 @@ def test_speculative_loop_reads_each_first_token_at_its_admission(
     reads = [s for s in watch.closed
              if s.name == spans.SERVE_FIRST_TOKEN_READ]
     assert len(reads) == 6 and all(
-        s.attrs == {"behind_step": 0} for s in reads)
+        s.attrs["behind_step"] == 0 for s in reads)
+    assert [s.attrs["request_id"] for s in reads] == sorted(wants)
     # the lanes of the one-token requests were refilled in the same
     # iteration: five admissions before the first verify pass
     emits = [e for e in watch.log if e[0] == "emit"]
