@@ -57,6 +57,20 @@ value that each layer updates in place at its own index, so the leading
 dense blocks and the scanned stack write layers of the same leaves.
 Left-padded prompts pass ``mask``; a pad's latent and rotary key are
 written and never read (``valid``).
+
+The parameters under ``ScannedBlocks``' scan. Four of the layer's five
+matrices are read where they lie in the stacked leaves, by a fusion that
+holds the scan's slice: ``q_a``, ``kv_a``, ``c_proj`` as plain 2-D
+products, and ``q_b`` because its 2-D product is held as a value before
+the per-head view is taken (the comment at the product says what the
+compiler does otherwise). ``kv_b`` is read in place by the per-head form
+(one 2-D product) and NOT by the absorbed form: its two einsums have the
+head as a batch dimension between the matrix's two dimensions in memory,
+and the TPU compiler writes the layer's slice out and a transposed copy
+of it (2 x 33.5 MB a layer, 0.35 ms of a 15.5 ms decode step at
+DeepSeek-V2's widths). A Pallas kernel over the leaf as stored removed
+those copies and lost more than it won to the swap of lanes and heads
+around the attention kernel (PERF.md, section 6, PR 55; ROADMAP S16).
 """
 from typing import Any, NamedTuple
 
@@ -199,7 +213,20 @@ class LatentAttention(nn.Module):
         with jax.named_scope(SCOPE_MLA_Q_PROJ):
             q = dense(H * (dn + dr), "q_b")(
                 norm("q_a_norm")(dense(m.q_rank, "q_a")(x)))
-            q = q.reshape(B, T, H, dn + dr)
+            # The 2-D product is held as a value before the per-head view
+            # is taken. Left free, XLA folds the dot and the reshape into
+            # one convolution that writes [B, H, dn + dr] directly and
+            # wants the WEIGHTS as [H, dn + dr, q_rank]: a head's dn + dr
+            # (192) is not a whole number of 128-lane tiles, so it moves
+            # the kernel and not the activations, through a transposed
+            # copy and, under a layer scan, a slice of the stack before it
+            # (2 x 75.5 MB written and read again a layer and call at
+            # DeepSeek-V2's widths, 1.4 ms of a 16.9 ms decode step on the
+            # v5e: PERF.md, section 6, PR 55). Held, the product is one
+            # fusion that reads the kernel where it lies in the stack, as
+            # every other dense layer of the step does, and what is
+            # re-laid is the activations.
+            q = jax.lax.optimization_barrier(q).reshape(B, T, H, dn + dr)
             q_nope, q_rope = q[..., :dn], rope(q[..., dn:], pos)
         with jax.named_scope(SCOPE_MLA_KV_PROJ):
             ckr = dense(r + dr, "kv_a")(x)

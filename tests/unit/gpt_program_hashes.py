@@ -261,7 +261,13 @@ def latent_hashes():
     (ops/pallas/latent_decode_attention.py): the pass that makes a lane's
     cache plans no kernel work, the einsum route is the parent's byte for
     byte, and both lower as they did. ``latent_jit_decode_k`` was recorded
-    on that PR's tree, with the kernel in it."""
+    on that PR's tree, with the kernel in it. PR 55 (parent 63e2c31) holds
+    the second query projection's 2-D product as a value before the
+    per-head view is taken (an ``optimization_barrier`` in every form of
+    the layer): ``latent_jit_prefill[32]``, ``latent_jit_prefill_more[16]``
+    and ``latent_jit_decode_k`` were recorded again on that PR's tree;
+    ``latent_jit_splice`` and every other family's entries stand as they
+    were, which is the proof that no other program moved."""
     import deepspeed_tpu
     from deepseek_v2_tiny import TINY_DEEPSEEK
     from deepspeed_tpu import serving

@@ -921,6 +921,46 @@ def test_the_cache_plan_and_the_counter_say_where_the_matrices_are_read(
 
 
 # ---------------------------------------------------------------------------
+# the second query projection's product is held before its per-head view
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def held_and_free():
+    """The tiny model's three serving programs' results as the layer is,
+    and with ``optimization_barrier`` an identity while they are traced;
+    and the shapes the barrier was asked to hold."""
+    cfg, held = model_config(), []
+    real = jax.lax.optimization_barrier
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax.lax, "optimization_barrier",
+                      lambda x: held.append(x.shape) or real(x))
+        got = serving_programs(cfg)
+        patch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+        jax.clear_caches()
+        want = serving_programs(cfg)
+    jax.clear_caches()
+    return cfg, held, got, want
+
+
+@pytest.mark.parametrize("program", ["prefill", "prefill_more", "decode_k"])
+def test_holding_the_query_projections_product_changes_no_value(
+        held_and_free, program):
+    """``LatentAttention`` holds ``c_q W_qb`` as a 2-D value
+    (``optimization_barrier``) so that the TPU compiler keeps the stacked
+    ``q_b`` kernel where it lies (tests/unit/test_grouped_matmul.py compiles
+    that for the chip). The barrier is an identity: the tiny model's
+    prefill, sixteen-token continuation and four decode steps give, bit for
+    bit, the logits, tokens and cache leaves they give with the barrier
+    taken out, which is what they gave before it was there."""
+    cfg, held, got, want = held_and_free
+    # the product of the tokens at hand alone, whatever the form
+    assert held and {shape[-1] for shape in held} \
+        == {cfg.n_head * cfg.mla.qk_dim}
+    for a, b in zip(jax.tree.leaves(got[program]),
+                    jax.tree.leaves(want[program])):
+        assert a.dtype == b.dtype and (a == b).all()
+
+
+# ---------------------------------------------------------------------------
 # the benchmark's check keeps its teeth with the kernel engaged (the body
 # of ``test_perfbench_deepseek_v2.py::test_check_fails_a_swapped_token_
 # and_a_perturbed_latent``, which pins ``decode_attention == "einsum"`` for

@@ -774,3 +774,95 @@ def test_the_selected_decode_step_compiles_for_the_chip_at_the_cells_shape(
     assert not [line[:160] for line in lines
                 if re.search(r"= " + re.escape(leaf) + r"\S* (copy|fusion)\(",
                              line)]
+
+
+def materialised(text):
+    """``(name, shape)`` of the compiled program's instructions that write
+    a result of their own: those of the entry and the loops' bodies, not
+    the ones fused into another (a fused computation is named by a
+    ``calls=``), and neither parameters nor views, nor the compiler's own
+    asynchronous prefetch of an operand into the chip's fast memory
+    (``slice-done``, ``copy-done``)."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    computation = None
+    for line in text.splitlines():
+        opened = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\) -> .* \{$", line)
+        if opened:
+            computation = opened.group(1)
+        elif line.startswith("}"):
+            computation = None
+        elif computation is not None and computation not in fused:
+            made = re.match(
+                r"\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
+                line)
+            if made and made.group(3) not in (
+                    "parameter", "get-tuple-element", "bitcast", "tuple",
+                    "slice-done", "copy-done"):
+                yield made.group(1), tuple(
+                    int(d) for d in made.group(2).split(",") if d)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_a_latent_models_serving_programs_write_no_copy_of_q_b(
+        one_chip, program, monkeypatch):
+    """The decode step and a prefill of a small latent-attention model
+    under ``ScannedBlocks``, compiled for the chip: no instruction's result
+    has the elements of a whole layer's ``q_b`` kernel, whatever its
+    layout: no slice of the stack written out, no transposed copy (on the
+    parent of PR 55 the decode step had both, under the names the v5e's
+    trace gave them: ``constant_dynamic-slice_fusion.6``, ``copy.28``).
+    This is the test that fails when an edit to ``LatentAttention`` brings
+    back the convolution that XLA makes of the second query projection and
+    the 192-wide per-head view after it. ``kv_b`` is not held to the same:
+    the absorbed form's two einsums over it viewed per head still copy it
+    (a kernel over the leaf as stored was measured and lost: PERF.md,
+    section 6, PR 55)."""
+    from deepspeed_tpu.models.transformer_lm import GPT, GPTConfig, MLAConfig
+    from deepspeed_tpu.ops.pallas import latent_decode_attention as lda
+
+    monkeypatch.setattr(lda, "_interpret", lambda: False)
+    heads, q_rank, kv_rank, nope, rope, lanes = 8, 384, 256, 128, 64, 32
+    cfg = GPTConfig(
+        vocab_size=512, n_positions=256, n_embd=128, n_layer=3, n_head=heads,
+        norm="rmsnorm", use_bias=False, rotary=True, learned_positions=False,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, scan_layers=True,
+        use_flash_attention=False, num_logits_to_keep=1,
+        mla=MLAConfig(q_rank=q_rank, kv_rank=kv_rank, nope_dim=nope,
+                      rope_dim=rope, v_dim=nope))
+    model = GPT(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    def prefill(params, ids):
+        logits, out = model.apply({"params": params}, ids,
+                                  deterministic=True, decode=True,
+                                  mutable=["cache"])
+        return logits[:, -1], out["cache"]
+
+    def decode(params, token, cache):
+        logits, out = model.apply({"params": params, "cache": cache},
+                                  token[:, None], deterministic=True,
+                                  decode=True, mutable=["cache"])
+        return logits[:, -1], out["cache"]
+
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    assert params["h"]["block"]["attn"]["q_b"]["kernel"].shape \
+        == (3, q_rank, heads * (nope + rope))
+    if program == "decode":
+        cache = jax.eval_shape(
+            prefill, params, jnp.zeros((lanes, 1), jnp.int32))[1]
+        text = compiled_for_the_chip(
+            jax.jit(decode, donate_argnums=(2,)), on_chip(params),
+            on_chip(jax.ShapeDtypeStruct((lanes,), jnp.int32)),
+            on_chip(cache))
+        assert f"%{lda.KERNEL_NAME}" in text
+    else:
+        text = compiled_for_the_chip(
+            jax.jit(prefill), on_chip(params),
+            on_chip(jax.ShapeDtypeStruct((1, 128), jnp.int32)))
+    whole = q_rank * heads * (nope + rope)
+    assert not [(name, shape) for name, shape in materialised(text)
+                if int(np.prod(shape)) == whole]

@@ -165,7 +165,9 @@ def test_lowered_programs_hash_as_recorded(family):
     the latent model's prefill, splice and sixteen-token continuation
     were recorded on the parent of the PR that brought its decode kernel
     (the pass that makes the cache and the einsum route lower as they
-    did), its ``jit_decode_k`` on that PR's tree; the copy and rewind of
+    did), its ``jit_decode_k`` on that PR's tree, and the three that run
+    the layer again on PR 55's, which holds the query projection's 2-D
+    product before its per-head view; the copy and rewind of
     a speculative scheduler and the plain loop's ``set_token`` on the
     parent of the PR that moved the lane cache's programs behind
     inference/lane_cache.py."""

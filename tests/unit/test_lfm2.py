@@ -709,7 +709,9 @@ def test_the_experts_matrices_are_read_in_place_in_each_kinds_stack(
 # sha256 of ``jax.jit(f).lower(...).as_text()`` (no source locations, dead
 # code dropped: equal exactly when the traced program is) of three tiny
 # one-kind models' prefill, decode and train programs, recorded from a run
-# of ``one_kind_programs`` on the parent commit (5d84d17)
+# of ``one_kind_programs`` on the parent commit (5d84d17); the latent model's
+# three again on PR 55's tree, which holds the second query projection's 2-D
+# product as a value before its per-head view (models/latent_attention.py)
 PARENT_PROGRAMS = {
     "gpt": {
         "prefill": "541dbeb4424d1cdb6e1e906951eda15b9cd8236018240663ec697c"
@@ -726,12 +728,12 @@ PARENT_PROGRAMS = {
         "train": "4f351d52274160cacd8ebdc3b8da729622aa95fc5878414a21ad64cd"
                  "65c483f2"},
     "deepseek_v2": {
-        "prefill": "53d3d2a148fadc898e55496d70129e4eccbd93dd0b499702d6b049"
-                   "25747d9379",
-        "decode": "14c34e3ffe6b49553359fc6ece370773ac8ad2374e0156c766ad52f"
-                  "beee7de93",
-        "train": "8af51e10257c9d460c7a9560e2ba615ba2d3fdf0c83b45733449f679"
-                 "e458a802"}}
+        "prefill": "bdabb1bae68f8364be67c622b98a57013ee61aeaf9d90a8157841b"
+                   "374705acb1",
+        "decode": "f8b98d8994dd0601a29cd61d24485b492ffea04bf31d1b2bdd2d4c9"
+                  "59179c0fc",
+        "train": "fbff2ee1f8aac5b604a3467b7cc329cf05a17e67c189fa6a675d784f"
+                 "49b5586d"}}
 
 
 def one_kind_config(name):
