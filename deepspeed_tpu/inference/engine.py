@@ -144,7 +144,13 @@ def prefill_chunk_spans(model_cfg, T: int):
 
     ring = ring_engaged(model_cfg) if model_cfg is not None else None
     if ring is None:
-        return None
+        # window layers beside full ones (GPTConfig.pass_tokens): a pass
+        # of at most that many tokens, from the first on, so that no pass
+        # holds more than [pass, n_positions] scores a head either
+        step = getattr(model_cfg, "pass_tokens", None)
+        if step is None or T <= step:
+            return None
+        return [(s, min(s + step, T)) for s in range(0, T, step)]
     w_blk, g_tok, blk = ring
     ring_len = ring_storage_len(model_cfg, ring)
     if T <= ring_len:
@@ -181,7 +187,8 @@ def continuation_chunk_spans(model_cfg, start: int, end: int):
             return [(s, min(end, (s // blk + 1) * blk))
                     for s in range(start, end)
                     if s == start or s % blk == 0]
-    return [(start, end)]
+    step = getattr(model_cfg, "pass_tokens", None) or end - start
+    return [(s, min(s + step, end)) for s in range(start, end, step)]
 
 
 def init_inference(model, config: Optional[Dict[str, Any]] = None,

@@ -22,8 +22,10 @@ import numpy as np
 
 from deepspeed_tpu.inference.engine import probe_length
 from deepspeed_tpu.models.transformer_lm import (
+    KIND_WINDOW,
     IndexKeyError,
     LatentCacheError,
+    MixedCacheError,
     declared_cache_leaves,
 )
 from deepspeed_tpu.ops.pallas.decode_attention import live_blocks
@@ -53,7 +55,9 @@ class RecurrentStateError(ValueError):
 # heads, its leaves with whoever runs the layers; "index" a position leaf
 # counted as index: an indexer's key beside keys and values, under their
 # clock (so ``_rewind`` steps it back with them, and a draft engine is
-# served)
+# served); "window" a position leaf counted as window: a ring of rows
+# beside ``slot_pos`` (``GPTConfig.sliding_window``), which in such a model
+# stands beside other layers' dense leaves
 _REFUSALS = (
     ("draft_engine (speculative decoding)", "recurrent",
      "_rewind steps the cache clocks back past the rejected tokens, and a "
@@ -88,13 +92,31 @@ _REFUSALS = (
      "heads over tp leaves every device the whole leaf and the indexer's "
      "projections have no sharding rule (models/transformer_lm.py "
      "gpt_tp_rules)"),
+    ("draft_engine (speculative decoding)", "window",
+     "the verify pass writes spec_k + 1 rows into every layer before it "
+     "reads, and nothing has verified a draft against a cache whose window "
+     "layers overwrite their oldest rows while the others keep all "
+     "(_rewind steps both kinds back: tests/unit/test_afmoe.py; the "
+     "scheduler's rules for a ring are the block layout's, "
+     "ops/sparse_attention ring_storage_len)"),
+    ("prefix_cache", "window",
+     "an entry is a cache cut at a promotion boundary and continued by "
+     "another prompt; a window layer's ring at the boundary has already "
+     "dropped the rows a shorter cut would need, and "
+     "serving/prefix_cache.py sizes its entries by keys and values of "
+     "every position in every layer"),
 )
 
-_REFUSAL_ERRORS = {"latent": LatentCacheError, "index": IndexKeyError}
+_REFUSAL_ERRORS = {"latent": LatentCacheError, "index": IndexKeyError,
+                   "window": MixedCacheError}
+
+
+def _path_keys(path):
+    return [str(getattr(part, "key", part)) for part in path]
 
 
 def _leaf_name(path) -> str:
-    return str(getattr(path[-1], "key", path[-1]))
+    return _path_keys(path)[-1]
 
 
 def _ranks(leaves, kind: str):
@@ -102,6 +124,16 @@ def _ranks(leaves, kind: str):
     model's own values (not an int8 store's sideband)."""
     return {leaf.name: leaf.rank for leaf in leaves
             if leaf.kind == kind and "sideband" not in leaf.counted_as}
+
+
+def _declared(leaves, path):
+    """The declared leaf that the cache leaf at ``path`` is, or None: by
+    name and, where the declaration says which kind of layer holds it
+    (a model that mixes kinds stacks each kind's leaves under its name),
+    by that kind among the path's keys."""
+    keys = _path_keys(path)
+    return next((leaf for leaf in leaves if leaf.name == keys[-1]
+                 and (leaf.held_by is None or leaf.held_by in keys)), None)
 
 
 def _first_leaf_shape(tree):
@@ -131,14 +163,17 @@ class LaneClocks:
         self.first[lane] = bucket - prompt_len
         self.clock[lane] = bucket + replayed
 
-    def live_positions(self, lanes) -> int:
+    def live_positions(self, lanes, window: Optional[int] = None) -> int:
         """Rows that the requests now in ``lanes`` (None: a free lane)
         have written: each one's prompt and what it has decoded, all
         lanes summed; the positions a decode step's attention has to
-        read, whatever it does read."""
+        read, whatever it does read. With ``window``, each lane's newest
+        ``window`` rows at most: what a layer that sees a window reads."""
         held = np.fromiter((lane is not None for lane in lanes), bool,
                            len(lanes))
-        return int((self.clock - self.first)[held].sum())
+        rows = (self.clock - self.first)[held]
+        return int((rows if window is None
+                    else np.minimum(rows, window)).sum())
 
     def step(self) -> Optional[float]:
         if self.block == 0:
@@ -182,13 +217,17 @@ class LanesAtExit:
         model's. Empty for a model without one."""
         return self._lane_leaves(lane, _ranks(self.leaves, "recurrent"))
 
-    def positions(self, lane: int):
+    def positions(self, lane: int, held_by: Optional[str] = None):
         """The same for what the model keeps PER POSITION (keys and values
         ``[layers, S, Hkv, D]``, or a latent and a rotary key ``[layers,
         S, width]``), with ``valid`` ``[layers or 1, S]``: which rows the
-        lane's request wrote."""
+        lane's request wrote. Where kinds of layer keep leaves of one name
+        and different lengths (a window's ring beside a dense cache),
+        ``held_by`` names the kind whose stack is read, and a ring comes
+        with ``slot_pos`` ``[layers, rows]``: the position each row holds."""
         return self._lane_leaves(
-            lane, dict(_ranks(self.leaves, "position"), valid=2))
+            lane, dict(_ranks(self.leaves, "position"), valid=2, slot_pos=2),
+            held_by)
 
     def last_step(self, lane: int):
         """The same for what the lane's last decode step left of itself
@@ -197,12 +236,13 @@ class LanesAtExit:
         leaves nothing."""
         return self._lane_leaves(lane, _ranks(self.leaves, "step"))
 
-    def _lane_leaves(self, lane: int, rank):
+    def _lane_leaves(self, lane: int, rank, held_by=None):
         out = {}
         for path, leaf in jax.tree_util.tree_flatten_with_path(
                 self.cache)[0]:
             name = _leaf_name(path)
-            if name in rank:
+            if name in rank and (held_by is None
+                                 or held_by in _path_keys(path)):
                 one = jax.lax.dynamic_index_in_dim(
                     leaf, jnp.int32(lane), leaf.ndim - rank[name],
                     keepdims=False)
@@ -307,6 +347,11 @@ class LaneLayout:
         self.config = getattr(self.module, "config", None)
         self.slots = int(slots)
         self.leaves = declared_cache_leaves(self.config)
+        # the positions a window layer sees, where the model has such
+        # layers (the declaration's: ``GPTConfig.attention_kind``)
+        kind = getattr(self.config, "attention_kind", lambda mixer: None)(
+            KIND_WINDOW)
+        self.window = None if kind is None else kind.window
         self._shapes = None
         self._geometry = None
         # each layout's own jitted functions: a build is seen per scheduler
@@ -404,6 +449,15 @@ class LaneLayout:
         reuse one input buffer at most)."""
         return self._rewind_fn(snapshot, cache, delta)
 
+    @property
+    def streams(self) -> bool:
+        """Whether a lane may run past ``n_positions``: every leaf it keeps
+        per position is a window's ring, which overwrites its oldest rows.
+        One layer that keeps every position bounds the lane."""
+        held = [leaf for leaf in self.leaves if leaf.kind == "position"]
+        return bool(held) and all("window" in leaf.counted_as
+                                  for leaf in held)
+
     def programs(self):
         """The programs dispatched so far (``empty`` fills eagerly)."""
         return [p for p in (self._splice_fn, self._copy_fn, self._rewind_fn)
@@ -422,14 +476,13 @@ class LaneLayout:
         if self._geometry is None:
             compute_dt = jnp.dtype(getattr(self.config, "dtype",
                                            jnp.float32))
-            declared = {leaf.name: leaf for leaf in self.leaves}
             total = dict.fromkeys(
                 ("resident", "unquantized", "recurrent", "state", "conv",
-                 "norm", "latent", "index", "sideband"), 0)
-            layers = dict.fromkeys(declared, 0)
+                 "norm", "latent", "index", "window", "sideband"), 0)
+            layers = dict.fromkeys((leaf.name for leaf in self.leaves), 0)
             for path, sd in jax.tree_util.tree_flatten_with_path(
                     self.shapes)[0]:
-                leaf = declared.get(_leaf_name(path))
+                leaf = _declared(self.leaves, path)
                 nbytes = sd.size * jnp.dtype(sd.dtype).itemsize
                 total["resident"] += nbytes
                 if leaf is not None:
@@ -460,6 +513,8 @@ class LaneLayout:
             if total["index"]:      # said only of a cache that has one
                 geo["index_key_bytes_per_lane"] = \
                     total["index"] // self.slots
+            if total["window"]:     # the rings' keys and values, likewise
+                geo["window_bytes_per_lane"] = total["window"] // self.slots
             geo["lanes"] = self.slots
             geo["leaf_layers"] = layers
             geo["compression_ratio"] = (

@@ -369,7 +369,10 @@ class ContinuousBatchingScheduler:
         # hard capacity for models whose decode cannot stream (dense cache
         # or learned positions): prompt + generation must fit n_positions
         self._max_pos = getattr(self._mcfg, "n_positions", None)
-        self._streaming = (self._ring is not None and
+        # (a lane whose every per-position leaf is a ring streams; one
+        # layer that keeps every position bounds it: ``LaneLayout.streams``)
+        self._streaming = ((self._ring is not None
+                            or self.lane_cache.streams) and
                            not getattr(self._mcfg, "learned_positions", True))
 
         # speculative decoding preconditions — checked HERE, not in the
@@ -617,7 +620,8 @@ class ContinuousBatchingScheduler:
                     **{k: kv[k] for k in (
                         "kv_bytes_per_lane", "state_bytes_per_lane",
                         "conv_bytes_per_lane", "norm_bytes_per_lane",
-                        "latent_bytes_per_lane", "bytes_per_lane")})
+                        "latent_bytes_per_lane", "bytes_per_lane",
+                        "window_bytes_per_lane") if k in kv})
         de = self.draft_engine
         if de is None:
             return
@@ -920,6 +924,10 @@ class ContinuousBatchingScheduler:
         }
         if self._clocks is not None:
             payload["live_positions"] = self._clocks.live_positions(lanes)
+            if self.lane_cache.window is not None:
+                # what the window layers of a mixed stack read of them
+                payload["live_window_positions"] = \
+                    self._clocks.live_positions(lanes, self.lane_cache.window)
         if self.prefix_cache is not None:
             payload["prefix_hit_rate"] = \
                 self.prefix_cache.stats().get("hit_rate", 0.0)

@@ -112,8 +112,11 @@ class KindStackedBlocks(nn.Module):
             def init():
                 keys = jax.random.split(self.make_rng("params"),
                                         sizes[stack])
-                return jax.vmap(lambda key: blocks[stack].init(
-                    {"params": key}, x, deterministic=True)["params"])(keys)
+                # a layer at a time (the same values as all at once: each
+                # layer's own key): the temporaries of ONE layer's random
+                # draws, not of the stack's
+                return jax.lax.map(lambda key: blocks[stack].init(
+                    {"params": key}, x, deterministic=True)["params"], keys)
 
             return init
 

@@ -28,6 +28,7 @@ from deepspeed_tpu.runtime.zero.gather import gather_tree, gathered_on_use
 from deepspeed_tpu.telemetry.scopes import (
     SCOPE_ATTN_CORE,
     SCOPE_CONV_STATE_CARRY,
+    SCOPE_FULL_ATTN,
     SCOPE_KV_CACHE_CARRY,
     SCOPE_KV_CACHE_READ,
     SCOPE_KV_CACHE_WRITE,
@@ -35,6 +36,7 @@ from deepspeed_tpu.telemetry.scopes import (
     SCOPE_LM_HEAD_CE,
     SCOPE_RET_STATE_CARRY,
     SCOPE_SSM_STATE_CARRY,
+    SCOPE_WINDOW_ATTN,
 )
 
 
@@ -189,6 +191,38 @@ class ShortConvConfig:
 # named for its token mixer
 KIND_ATTENTION = "attention"
 KIND_CONV = "conv"
+KIND_WINDOW = "window"
+
+
+class MixedCacheError(ValueError):
+    """A feature that assumes every layer keeps every position was asked
+    of a model whose window layers keep a ring beside the other layers'
+    dense cache (``GPTConfig.layer_types`` with ``"window"`` layers)."""
+
+    def __init__(self, feature: str, why: str):
+        super().__init__(
+            f"{feature} cannot serve a model whose window layers keep a "
+            f"ring of rows beside a dense cache (slot_pos: "
+            f"GPTConfig.sliding_window): {why}")
+        self.feature = feature
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """What an attention layer of one kind is, in a stack whose attention
+    layers differ by kind (``GPTConfig.attention_kind``; run by
+    models/kind_attention.py): the positions a query sees, what a lane
+    keeps of the layer, whether q and k are rotated, and the scope its
+    attention is timed under."""
+    # the newest positions a query attends over, itself included; None =
+    # every earlier position
+    window: Optional[int]
+    # rows of keys and values a lane keeps of the layer: a ring of
+    # ``window + window_slack`` rows written at ``position % rows`` beside
+    # ``slot_pos``, or (None) ``n_positions`` rows written at the position
+    ring: Optional[int]
+    rotary: bool
+    scope: str
 
 
 def attention_cache_leaves(cfg=None) -> Tuple[CacheLeaf, ...]:
@@ -204,6 +238,16 @@ def attention_cache_leaves(cfg=None) -> Tuple[CacheLeaf, ...]:
                       SCOPE_KV_CACHE_CARRY, dtype=jnp.float32)
             for leaf in leaves)
     return leaves
+
+
+def window_cache_leaves() -> Tuple[CacheLeaf, ...]:
+    """What an attention layer of kind ``"window"`` keeps: keys and values
+    of the rows its ring holds (``AttentionKind.ring``), counted apart
+    (``window_bytes_per_lane``)."""
+    return tuple(
+        dataclasses.replace(leaf, counted_as=("window",),
+                            held_by=KIND_WINDOW)
+        for leaf in attention_cache_leaves())
 
 
 def declared_cache_leaves(config) -> Tuple[CacheLeaf, ...]:
@@ -566,6 +610,9 @@ class GPTConfig:
     moe_scoring: str = "softmax"
     moe_expert_bias: bool = False
     moe_expert_bias_init: float = 0.0
+    # what sigmoid scoring adds to the chosen scores' sum before it
+    # divides by it; None = the 1e-6 of ``topk_routing``
+    moe_renorm_eps: Optional[float] = None
     # --- which token mixer a layer runs --------------------------------------
     # Declared once, here, and read by ``Block`` (its ``mixer`` field), by
     # whoever runs the layers and by ``cache_leaves``. ``layer_types`` None
@@ -579,6 +626,25 @@ class GPTConfig:
     layer_types: Optional[Tuple[str, ...]] = None
     # the "conv" kind's mixer: a gated short convolution (LFM2)
     short_conv: Optional[ShortConvConfig] = None
+    # the "window" kind: attention over the newest ``sliding_window``
+    # positions, the query's own included, exact by position (not the block
+    # layout of ``sparse_attention``). A stack that has the kind runs its
+    # "window" AND its "attention" layers through models/kind_attention.py
+    # (``attention_kind``): a lane keeps ``sliding_window + window_slack``
+    # rows of a window layer whatever ``n_positions`` is, and every
+    # position of an "attention" layer. The slack is what a pass of several
+    # tokens on a cache needs: ``window_slack + 1`` tokens at most evict no
+    # row that one of them still attends over. ``rotary_kinds`` names the
+    # kinds whose q and k are rotated (None: all, where ``rotary``)
+    sliding_window: Optional[int] = None
+    window_slack: int = 0
+    rotary_kinds: Optional[Tuple[str, ...]] = None
+    # such a stack's attention output times the sigmoid of a projection
+    # ``c_gate`` of the layer's normalised input, before ``c_proj``
+    attn_output_gate: bool = False
+    # a norm on the mixer's output and one on the MLP's, each before its
+    # residual sum (``ln_1_post``, ``ln_2_post``): four norms a layer
+    post_norms: bool = False
     # --- hybrid blocks (Falcon-H1) -----------------------------------------
     # a Mamba-2 mixer beside attention in every block, both on ln_1's
     # output, summed into one residual; None = attention alone. Its
@@ -719,13 +785,15 @@ class GPTConfig:
         if self.layer_types is not None:
             kinds = set(self.layer_types)
             if len(self.layer_types) != self.n_layer \
-                    or not kinds <= {KIND_ATTENTION, KIND_CONV}:
+                    or not kinds <= {KIND_ATTENTION, KIND_CONV, KIND_WINDOW}:
                 raise ValueError(
                     f"layer_types names a kind ({KIND_ATTENTION!r} | "
-                    f"{KIND_CONV!r}) for each of the {self.n_layer} layers; "
-                    f"got {self.layer_types!r}")
+                    f"{KIND_CONV!r} | {KIND_WINDOW!r}) for each of the "
+                    f"{self.n_layer} layers; got {self.layer_types!r}")
             if KIND_CONV in kinds and self.short_conv is None:
                 raise ValueError("a 'conv' layer needs short_conv")
+            if KIND_WINDOW in kinds:
+                self._check_window_kind(kinds)
             unmixable = [name for name in (
                 "mla", "retention", "ssm", "indexer", "sparse_attention")
                 if getattr(self, name) is not None] + [
@@ -738,6 +806,14 @@ class GPTConfig:
                     "(models/kind_stacks.py) runs attention and "
                     "convolution layers with their weights as stored, "
                     f"scanned; not with {unmixable or 'scan_layers=False'}")
+        if (KIND_WINDOW not in (self.layer_types or ())
+                and (self.sliding_window is not None or self.window_slack
+                     or self.rotary_kinds is not None
+                     or self.attn_output_gate)):
+            raise ValueError(
+                "sliding_window, window_slack, rotary_kinds and "
+                "attn_output_gate belong to a stack with layers of kind "
+                f"{KIND_WINDOW!r} (layer_types)")
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(
                 f"qk_norm must be False, True or 'head'; got "
@@ -754,6 +830,68 @@ class GPTConfig:
                     f"moe_experts_held {self.moe_experts_held} is no run "
                     f"of the {self.moe_num_experts} experts")
 
+    def _check_window_kind(self, kinds):
+        """What a stack with ``"window"`` layers has to say, and what
+        models/kind_attention.py does not run."""
+        if not isinstance(self.sliding_window, int) \
+                or self.sliding_window < 1 or self.window_slack < 0:
+            raise ValueError(
+                f"a {KIND_WINDOW!r} layer needs sliding_window >= 1 and "
+                f"window_slack >= 0; got {self.sliding_window!r}, "
+                f"{self.window_slack!r}")
+        if self.rotary_kinds is not None and not (
+                self.rotary and set(self.rotary_kinds) <= kinds):
+            raise ValueError(
+                f"rotary_kinds {self.rotary_kinds!r} names kinds of "
+                f"{sorted(kinds)} whose q and k the model's rotary turns")
+        unbuilt = [name for name, on in (
+            ("alibi", self.alibi), ("causal=False", not self.causal),
+            ("learned_positions", self.learned_positions),
+            ("mrope_section", self.mrope_section is not None),
+            ("qk_norm=True", self.qk_norm is True),
+            ("sequence_parallel", self.sequence_parallel != "none"),
+            ("dropout", self.dropout > 0)) if on]
+        if unbuilt:
+            raise ValueError(
+                "window layers beside full ones "
+                "(models/kind_attention.py) are causal, with positions "
+                f"from rotary or from none; not built over them: {unbuilt}")
+        if self.kv_cache_dtype is not None:
+            raise MixedCacheError(
+                f"kv_cache_dtype={self.kv_cache_dtype!r}",
+                "the int8 format keeps one scale a (position, KV head) "
+                "beside rows that stay where they were written; a ring's "
+                "rows are overwritten in turn and models/kind_attention.py "
+                "stores and reads the compute dtype alone")
+
+    def attention_kind(self, mixer: Optional[str]) -> Optional[AttentionKind]:
+        """What the attention of a layer of kind ``mixer`` is, in a stack
+        whose attention layers differ by kind (one that has ``"window"``
+        layers); None everywhere else: the whole-model fields say it and
+        ``CausalSelfAttention`` runs it. The one place that knows which
+        kind has a window, a ring or rotary."""
+        if self.layer_types is None or KIND_WINDOW not in self.layer_types \
+                or mixer not in (KIND_WINDOW, KIND_ATTENTION):
+            return None
+        rotary = self.rotary and (self.rotary_kinds is None
+                                  or mixer in self.rotary_kinds)
+        if mixer == KIND_WINDOW:
+            return AttentionKind(
+                self.sliding_window, self.sliding_window + self.window_slack,
+                rotary, SCOPE_WINDOW_ATTN)
+        return AttentionKind(None, None, rotary, SCOPE_FULL_ATTN)
+
+    @property
+    def pass_tokens(self) -> Optional[int]:
+        """The most tokens one pass over a cache may take in (None: any
+        number): a window layer's ring holds ``window_slack`` rows beyond
+        the window, so a longer pass would overwrite rows that its own
+        first query still attends over; and a pass of that length holds
+        ``[pass, n_positions]`` scores a head, never ``[T, T]``
+        (inference/engine.py ``prefill_chunk_spans``)."""
+        kind = self.attention_kind(KIND_WINDOW)
+        return None if kind is None else max(1, kind.ring - kind.window)
+
     @property
     def cache_leaves(self) -> Tuple[CacheLeaf, ...]:
         """What a lane keeps in the decode cache, as the model's mixers
@@ -768,6 +906,7 @@ class GPTConfig:
                 dataclasses.replace(leaf, held_by=KIND_ATTENTION)
                 for leaf in attention_cache_leaves(self)
                 if KIND_ATTENTION in kinds) + (
+                window_cache_leaves() if KIND_WINDOW in kinds else ()) + (
                 self.short_conv.cache_leaves(self)
                 if KIND_CONV in kinds else ())
         attention = (self.mla.cache_leaves(self) if self.mla is not None
@@ -1583,6 +1722,14 @@ class Block(nn.Module):
 
             a = PowerRetention(cfg, name="attn")(
                 u, mask=mask, decode=decode, cache_layer=cache_layer)
+        elif (kind := cfg.attention_kind(self.mixer)) is not None:
+            # attention layers that differ by kind: a window and rotary,
+            # or neither, as the layer's kind declares
+            from deepspeed_tpu.models.kind_attention import KindAttention
+
+            a = KindAttention(cfg, kind, name="attn")(
+                u, mask=mask, segment_ids=segment_ids, decode=decode,
+                cache_layer=cache_layer)
         else:
             a = CausalSelfAttention(cfg, name="attn")(
                 scaled(u, cfg.attention_in_multiplier),
@@ -1597,6 +1744,8 @@ class Block(nn.Module):
 
             a = a + Mamba2Mixer(cfg, name="mamba")(
                 u, mask=mask, decode=decode, cache_layer=cache_layer)
+        if cfg.post_norms:
+            a = _norm(cfg, "ln_1_post")(a)
         if cfg.parallel_residual:
             # GPT-J/NeoX form: attention and MLP both read the pre-residual
             # stream; GPT-J's single shared LN is expressed by loading
@@ -1629,6 +1778,7 @@ class Block(nn.Module):
                 scoring=cfg.moe_scoring,
                 expert_bias=cfg.moe_expert_bias,
                 expert_bias_init=cfg.moe_expert_bias_init,
+                renorm_eps=cfg.moe_renorm_eps,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 name="mlp",
@@ -1639,6 +1789,8 @@ class Block(nn.Module):
         else:
             y = MLP(cfg, name="mlp")(h, deterministic=deterministic)
             l_aux = jnp.float32(0.0)
+        if cfg.post_norms:
+            y = _norm(cfg, "ln_2_post")(y)
         x = x + y + a if cfg.parallel_residual else x + y
         if cfg.stochastic_mode and pld_keep is not None and not deterministic:
             # whole-block stochastic depth (PLD form: identity skip, no
@@ -1985,7 +2137,7 @@ def gpt_tp_rules(path: str, shape) -> "PartitionSpec":
         return PartitionSpec(*spec)
 
     if path.endswith(("attn/c_attn/kernel", "mlp/c_fc/kernel",
-                      "mlp/c_gate/kernel",
+                      "mlp/c_gate/kernel", "attn/c_gate/kernel",
                       "attn/c_attn/bias", "mlp/c_fc/bias",
                       "mlp/c_gate/bias",
                       # the short convolution is depthwise: its channels
@@ -2265,7 +2417,12 @@ def num_params(config: GPTConfig) -> int:
         # projections and its taps where an attention layer holds q/k/v/o
         n_conv = cfg.layer_types.count(KIND_CONV)
         conv = 4 * C * C + cfg.short_conv.width * C if n_conv else 0
-        return (V * C + L * (mlp + 2 * norm_p) + n_conv * conv
+        if cfg.attn_output_gate:
+            # the gate's projection, and (exact for these stacks) the two
+            # per-head norm weights
+            attn += C * H * D + (2 * D if cfg.qk_norm == "head" else 0)
+        norms = (4 if cfg.post_norms else 2) * norm_p
+        return (V * C + L * (mlp + norms) + n_conv * conv
                 + (L - n_conv) * attn + norm_p
                 + (0 if cfg.tie_word_embeddings else C * V))
     if cfg.retention is not None:

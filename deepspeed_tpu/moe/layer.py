@@ -157,6 +157,8 @@ class MoE(nn.Module):
     scoring: str = "softmax"
     expert_bias: bool = False
     expert_bias_init: float = 0.0
+    # sigmoid scoring's renormaliser (``topk_routing``); None = its own
+    renorm_eps: Optional[float] = None
 
     @property
     def dropless(self) -> bool:
@@ -232,6 +234,8 @@ class MoE(nn.Module):
             corrected = {}
             if self.scoring != "softmax" or self.expert_bias:
                 corrected["scoring"] = self.scoring
+            if self.renorm_eps is not None:
+                corrected["renorm_eps"] = self.renorm_eps
             if self.expert_bias:
                 corrected["bias"] = self.param(
                     "expert_bias",

@@ -251,7 +251,8 @@ def router_z_loss(logits: jnp.ndarray) -> jnp.ndarray:
 def topk_routing(logits: jnp.ndarray, k: int, renormalize: bool = False,
                  n_group: int = 1, topk_group: int = 1,
                  scale: float = 1.0, scoring: str = "softmax",
-                 bias: Optional[jnp.ndarray] = None) -> RoutingOutput:
+                 bias: Optional[jnp.ndarray] = None,
+                 renorm_eps: Optional[float] = None) -> RoutingOutput:
     """Softmax over all experts, then the k largest probabilities per token
     (any k). ``renormalize`` divides the k weights by their sum
     (``norm_topk_prob``); without it they are the softmax's own values and
@@ -260,7 +261,8 @@ def topk_routing(logits: jnp.ndarray, k: int, renormalize: bool = False,
 
     ``scoring="sigmoid"`` scores each expert by the sigmoid of its own
     logit (LFM2, DeepSeek-V3); the k weights, renormalised, are then
-    ``s_i / (sum of the chosen s + 1e-6)`` as those models publish it.
+    ``s_i / (sum of the chosen s + 1e-6)`` as those models publish it
+    (``renorm_eps`` where a model publishes another: AFMoE's 1e-20).
     ``bias`` ``[experts]`` (a parameter of the layer that a balancing rule
     moves apart from the weights' training) is added to the scores for
     the CHOICE and for nothing else: the k weights are the uncorrected
@@ -311,8 +313,9 @@ def topk_routing(logits: jnp.ndarray, k: int, renormalize: bool = False,
                           dtype=jnp.int32)
     if renormalize:
         total = jnp.sum(weights, axis=-1, keepdims=True)
-        weights = weights / (total + 1e-6 if sigmoid else jnp.maximum(
-            total, jnp.finfo(jnp.float32).eps))
+        weights = weights / (
+            total + (1e-6 if renorm_eps is None else renorm_eps) if sigmoid
+            else jnp.maximum(total, jnp.finfo(jnp.float32).eps))
     if scale != 1.0:
         weights = weights * scale
     exp_counts = jnp.bincount(experts.reshape(-1), length=num_experts)

@@ -86,7 +86,10 @@ def publish_expert_load(model, params, batch):
     experts (``MoE.experts_held``) counts the held ones alone: ``held``
     (how many a layer), ``routed_here`` (the pairs routed to them, all
     layers; ``tokens_dropped`` is then of those) and ``routed`` (all the
-    routers asked for). Of the dropless path's grouped
+    routers asked for); ``experts_with_rows_share`` is the share of the
+    counted experts that computed at least one pair, the layers' mean (at
+    a decode step's few rows the matrices of the others need not be
+    read). Of the dropless path's grouped
     matmuls it says which implementation the layers traced,
     ``grouped_matmul`` (``"pallas"``: the kernel of
     ``ops/pallas/grouped_matmul.py``; ``"xla"``: ``jax.lax.ragged_dot``;
@@ -127,6 +130,7 @@ def publish_expert_load(model, params, batch):
         max_over_mean=float((counts.max(axis=1) / counts.mean(axis=1)).max()),
         tokens_dropped=int(here.sum() - counts.sum()),
         held=int(stats["held"][0]) if "held" in stats else counts.shape[1],
+        experts_with_rows_share=float((counts > 0).mean()),
         routed=int(stats["routed"].sum()), routed_here=int(here.sum()),
         grouped_matmul=path, grouped_matmul_tiles=tiles,
         row_tile_visits_over_least=over_least, **corrected)

@@ -120,6 +120,13 @@ SCOPE_DSA_INDEX_PROJ = "dsa_index_proj"
 SCOPE_DSA_INDEX_SCORES = "dsa_index_scores"
 SCOPE_DSA_SELECT = "dsa_select"
 SCOPE_DSA_ATTN = "dsa_attn"
+# attention layers that differ by kind (models/kind_attention.py;
+# ``GPTConfig.attention_kind``): scores, softmax, weighted sum and the
+# output's gate of the layers that see a window of positions, and of those
+# that see every position. Their row writes are ``kv_cache_write``'s and a
+# whole leaf that no scope owns is ``kv_cache_carry``'s
+SCOPE_WINDOW_ATTN = "window_attn"
+SCOPE_FULL_ATTN = "full_attn"
 # JAX's own name-stack component of a rematerialised (recomputed) operation;
 # ``checkpoint`` alone is also on the backward pass of a checkpointed region
 SCOPE_REMAT = "rematted_computation"
@@ -147,7 +154,7 @@ _CARRY_FREE = frozenset((
     SCOPE_RET_STATE, SCOPE_RET_OUT_PROJ, SCOPE_MLA_Q_PROJ,
     SCOPE_MLA_KV_PROJ, SCOPE_MLA_ABSORB, SCOPE_MLA_ATTN,
     SCOPE_MLA_OUT_PROJ, SCOPE_DSA_INDEX_PROJ, SCOPE_DSA_INDEX_SCORES,
-    SCOPE_DSA_SELECT, SCOPE_DSA_ATTN))
+    SCOPE_DSA_SELECT, SCOPE_DSA_ATTN, SCOPE_WINDOW_ATTN, SCOPE_FULL_ATTN))
 _STRUCTURE = re.compile(
     r"^(jit\(.*\)|pjit\(.*\)|while|body|cond|branch_\d+_fun|closed_call|"
     r"core_call|custom_jvp_call|custom_vjp_call|custom_vjp_call_jaxpr)$")

@@ -32,7 +32,7 @@ import pytest  # noqa: E402
 
 # The benchmark's rehearsal tables (tests/perfbench/rehearsal.py) predate
 # the hybrid, the attention-free, the latent-attention, the
-# selected-attention and the mixed-kinds serve cells, and
+# selected-attention, the mixed-kinds and the window-and-full serve cells, and
 # both they and tests/perfbench/conftest.py are the benchmark's own files.
 # Each cell's tiny stand-in is data in a new file
 # beside its tests and is registered from here: this conftest is loaded
@@ -47,18 +47,21 @@ import falcon_h1_tiny  # noqa: E402
 import keye_vl_tiny  # noqa: E402
 import lfm2_tiny  # noqa: E402
 import rehearsal  # noqa: E402
+import trinity_tiny  # noqa: E402
 
 falcon_h1_tiny.register(rehearsal)
 brumby_tiny.register(rehearsal)
 deepseek_v2_tiny.register(rehearsal)
 keye_vl_tiny.register(rehearsal)
 lfm2_tiny.register(rehearsal)
+trinity_tiny.register(rehearsal)
 _PREDATE_REDUCED = {
     falcon_h1_tiny.PREDATES_REDUCED: "test_perfbench_falcon_h1.py",
     brumby_tiny.PREDATES_REDUCED: "test_perfbench_brumby.py",
     deepseek_v2_tiny.PREDATES_REDUCED: "test_perfbench_deepseek_v2.py",
     keye_vl_tiny.PREDATES_REDUCED: "test_perfbench_keye_vl.py",
-    lfm2_tiny.PREDATES_REDUCED: "test_perfbench_lfm2.py"}
+    lfm2_tiny.PREDATES_REDUCED: "test_perfbench_lfm2.py",
+    trinity_tiny.PREDATES_REDUCED: "test_perfbench_trinity.py"}
 
 
 def pytest_collection_modifyitems(items):
