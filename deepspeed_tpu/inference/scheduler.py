@@ -654,8 +654,8 @@ class ContinuousBatchingScheduler:
         step sorts ``slots * moe_top_k`` rows a layer)."""
         if not isinstance(self._mcfg, GPTConfig):
             return "none"
-        return expert_matrices(
-            self._mcfg, self.slots * self._mcfg.moe_top_k, decode=True)
+        return expert_matrices(self._mcfg,
+                               self.slots * self._mcfg.moe_top_k)
 
     def _empty_cache(self, eng=None):
         """The target engine's empty lane cache (``LaneLayout.empty``), or

@@ -150,15 +150,14 @@ class KindStackedBlocks(nn.Module):
         mutable = (["cache"] if decode else []) \
             + ([MOE_STATS] if counting else [])
         in_place = experts.expert_matrices(
-            cfg, x.shape[0] * x.shape[1] * cfg.moe_top_k,
-            decode=decode) == "in_place"
+            cfg, x.shape[0] * x.shape[1] * cfg.moe_top_k) == "in_place"
 
         def turn(run):
             block, stack = blocks[run.stack], stacks[run.stack]
-            read_in_place = (
-                in_place and not run.dense and not self.is_initializing()
-                and all(leaf.dtype == cfg.dtype for leaf
-                        in stack["mlp"]["experts"].values()))
+            matrices = experts.stack_in_place(
+                stack["mlp"]["experts"], cfg.dtype, serving=decode) \
+                if in_place and not run.dense \
+                and not self.is_initializing() else None
 
             def call(x, cache, at):
                 at_param, at_cache, layer = at
@@ -183,8 +182,8 @@ class KindStackedBlocks(nn.Module):
 
             def body(carry, at):
                 with jax.named_scope(run.stack), experts.matrices_in_place(
-                        stack["mlp"]["experts"], at[0]) if read_in_place \
-                        else contextlib.nullcontext():
+                        matrices, at[0], serving=decode) \
+                        if matrices is not None else contextlib.nullcontext():
                     x, cache, out = call(*carry, at)
                 return (x, cache), out
 

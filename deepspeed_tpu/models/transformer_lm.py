@@ -1929,24 +1929,29 @@ def _maybe_in_place_experts(body, owner, cfg, rows, decode):
     it, closed over (loop-invariant, no carry), and the turn's index into
     it what the scan counts less the leading dense blocks. The turn's
     sliced ``wi`` / ``wg`` / ``wo`` are then read by nothing and their
-    slices leave the program. Anywhere else, at ``init`` (no leaf exists
-    yet) and for a tree handed over in another dtype than the
+    slices leave the program: a training step (not ``decode``) still
+    differentiates with respect to them, so the stack is closed over
+    under ``stop_gradient`` and the matrices' gradient is stacked by the
+    scan like every other leaf's. Anywhere else, at ``init`` (no leaf
+    exists yet) and for a tree handed over in another dtype than the
     configuration declares: ``body`` itself."""
     from deepspeed_tpu.moe import experts
 
     if owner.is_initializing() \
-            or experts.expert_matrices(cfg, rows, decode=decode) != "in_place":
+            or experts.expert_matrices(cfg, rows) != "in_place":
         return body
-    stacked = owner.get_variable("params", "block")["mlp"]["experts"]
-    if any(leaf.dtype != cfg.dtype for leaf in stacked.values()):
+    stacked = experts.stack_in_place(
+        owner.get_variable("params", "block")["mlp"]["experts"], cfg.dtype,
+        serving=decode)
+    if stacked is None:
         return body
 
     # (under ``body``'s own name, which the scan gives its scope: the
     # operations' ``op_name`` paths stay what every reader knows)
     @functools.wraps(body)
     def in_place(block, carry, layer_idx):
-        with experts.matrices_in_place(stacked,
-                                       layer_idx - cfg.first_k_dense):
+        with experts.matrices_in_place(
+                stacked, layer_idx - cfg.first_k_dense, serving=decode):
             return body(block, carry, layer_idx)
 
     return in_place

@@ -16,10 +16,19 @@ otherwise ``jax.lax.ragged_dot``, which the compiler can partition.
 Inside a layer scan a layer's matrices are the scan's slice of the stacked
 parameters, and a slice handed to a custom call is written out whole
 first (3 x 314 MB a layer at DeepSeek-V2's widths, two fifths of its
-decode step: PERF.md, section 6, PR 44). On a serving call the kernel
-therefore reads the STACK where it lies, the layer one more index
-(:func:`expert_matrices` is the rule, :func:`matrices_in_place` how the
-scan's owner hands stack and index over).
+decode step: PERF.md, section 6, PR 44; 3 x 268 MB a layer at OLMoE's,
+forward and again backward: PR 58). The kernel therefore reads the STACK
+where it lies, the layer one more index (:func:`expert_matrices` is the
+rule, :func:`matrices_in_place` how the scan's owner hands stack and index
+over), serving and training alike. A serving call differentiates nothing
+and calls the forward product. A training call is differentiated WITH
+RESPECT TO THE SLICE and reads the stack
+(``grouped_matmul.grouped_matmul(..., stack=, layer=)``): forward turn,
+recomputed turn and the rows' gradient multiply by the stack in place;
+the matrices' gradient (``tgmm``) is the cotangent of the slice, which
+the scan stacks into the parameter's gradient as it does every leaf's (one
+``[E, K, N]`` write a matrix a layer: those stay); the stack itself
+carries no gradient, so nothing the size of the stack is summed a turn.
 """
 
 import contextlib
@@ -56,7 +65,7 @@ def grouped_matmul_tiles(rows: int, d_model: int, d_hidden: int,
         "gmm", rows, d_model, d_hidden, num_experts, dtype)
 
 
-def expert_matrices(cfg, rows: int, *, decode: bool) -> str:
+def expert_matrices(cfg, rows: int) -> str:
     """How the scanned expert layers of a model (``cfg``: its
     ``GPTConfig``) read their matrices in a call that sorts ``rows`` (token,
     expert) pairs a layer: ``"in_place"``, the grouped-matmul kernel over
@@ -64,8 +73,10 @@ def expert_matrices(cfg, rows: int, *, decode: bool) -> str:
     with the layer as an index; ``"slice"``, each layer multiplies by the
     ``[E, K, N]`` tensor it is handed (the scan's slice, or its own leaf
     without a scan); ``"none"``, no layer has experts. Told from what the
-    call shows, by no option; model and scheduler ask alike. In place
-    where all hold:
+    call shows, by no option; model and scheduler ask alike, of a serving
+    call and of a training step (differentiated or not: the gradient of a
+    call in place goes to the scan's slice, the module's docstring). In
+    place where all hold:
 
     * :func:`grouped_matmul_tiles` chose the kernel (the sorted-rows path
       at widths of whole lanes, rows of whole tiles, bf16 or float32, no
@@ -73,19 +84,17 @@ def expert_matrices(cfg, rows: int, *, decode: bool) -> str:
       of its own and has nothing to copy;
     * the layers run in ``ScannedBlocks``' scan: a layer looped over reads
       a leaf of its own;
-    * the call serves (``decode``: prefill, continuation, decode step,
-      verification). Nothing there is differentiated; a training forward
-      over the stack would want a cotangent the size of the stack a layer;
     * the layer multiplies by what is stored: not wider parameters cast
-      on use, nor a stack that is dequantised, gathered over ``fsdp`` or
-      streamed from the host where the layer reads it."""
+      on use, nor a stack that is dequantised, gathered over ``fsdp``
+      (every program under a ZeRO-3 plan) or streamed from the host where
+      the layer reads it."""
     from deepspeed_tpu.runtime.zero.gather import current_plan
 
     if not cfg.is_moe or cfg.n_layer <= cfg.first_k_dense:
         return "none"
     dropless = cfg.moe_top_k > 2 or not cfg.moe_drop_tokens
     held = (cfg.moe_experts_held or (0, cfg.moe_num_experts))[1]
-    if (dropless and decode and cfg.scan_layers
+    if (dropless and cfg.scan_layers
             and jnp.dtype(cfg.param_dtype) == jnp.dtype(cfg.dtype)
             and not (cfg.quantized_weights or cfg.param_offload)
             and current_plan() is None
@@ -96,21 +105,37 @@ def expert_matrices(cfg, rows: int, *, decode: bool) -> str:
 
 
 # What a layer scan's owner offers the experts traced inside one turn: the
-# stacked ``{"wi", "wg", "wo"}`` leaves and the turn's index into them.
+# stacked ``{"wi", "wg", "wo"}`` leaves, the turn's index into them, and
+# whether the call serves.
 _STACKED: contextvars.ContextVar = contextvars.ContextVar(
     "stacked_expert_matrices", default=None)
 
 
+def stack_in_place(stacked, dtype, serving):
+    """``stacked`` (the ``experts`` subtree of a layer scan's parameters,
+    leading layer axis) as :func:`matrices_in_place` takes it, made by the
+    scan's owner OUTSIDE the scan: None for a tree handed over in another
+    dtype than the configuration declares (the layer casts its slice, as
+    it did); under ``lax.stop_gradient`` unless the call serves (a
+    training step's gradient belongs to the turn's slice: a cotangent the
+    size of the stack would otherwise be summed every turn)."""
+    if any(leaf.dtype != dtype for leaf in stacked.values()):
+        return None
+    return stacked if serving else jax.lax.stop_gradient(stacked)
+
+
 @contextlib.contextmanager
-def matrices_in_place(stacked, layer):
+def matrices_in_place(stacked, layer, serving):
     """Entered around one turn of a layer scan whose expert matrices are
-    read where they lie (:func:`expert_matrices`): ``stacked`` the
-    ``experts`` subtree of the scanned blocks' parameters with its leading
-    layer axis, ``layer`` this turn's index into it (traced). Read at trace
-    time by :class:`StackedExperts`; as ZeRO-3's ``gather_context`` tells
-    the layer loop of its rules, without an argument through every
-    ``__call__`` between."""
-    token = _STACKED.set((stacked, layer))
+    read where they lie (:func:`expert_matrices`): ``stacked`` what
+    :func:`stack_in_place` made of the scanned blocks' ``experts``
+    subtree, ``layer`` this turn's index into it (traced), ``serving``
+    whether the call is one that nothing differentiates (its programs
+    then hold the kernel's forward call alone, as they always did). Read
+    at trace time by :class:`StackedExperts`; as ZeRO-3's
+    ``gather_context`` tells the layer loop of its rules, without an
+    argument through every ``__call__`` between."""
+    token = _STACKED.set((stacked, layer, serving))
     try:
         yield
     finally:
@@ -143,9 +168,11 @@ class StackedExperts(nn.Module):
     alongside ``wi`` — same expert-parallel layout.
 
     Under :func:`matrices_in_place` the three products take the stacked
-    leaves and the layer's index (``gmm(..., layer=)``): the kernel's own
-    forward call on the same blocks, bitwise the slice's result, and
-    nothing that is differentiated.
+    leaves and the layer's index: the kernel's own calls on the same
+    blocks, bitwise the slice's results. A serving call is the forward
+    product (``gmm(..., layer=)``); any other is differentiable with
+    respect to the layer's own ``wi`` / ``wg`` / ``wo``, whose values it
+    does not read (``grouped_matmul(..., stack=, layer=)``).
     """
 
     num_experts: int
@@ -171,7 +198,7 @@ class StackedExperts(nn.Module):
             # lowering three kernels for it is set-up time for nothing)
             tiles = None if self.is_initializing() else \
                 grouped_matmul_tiles(x.shape[0], M, H, E, self.dtype)
-            stacked, layer = _STACKED.get() or (None, None)
+            stacked, layer, serving = _STACKED.get() or (None, None, False)
             if tiles:
                 from deepspeed_tpu.ops.pallas import grouped_matmul as gm
 
@@ -180,11 +207,13 @@ class StackedExperts(nn.Module):
                 walk = gm.row_walk(group_sizes, x.shape[0], tiles[0])
 
             def matmul(a, w, name):
-                if tiles and stacked is not None:
+                if tiles and serving:
                     return gm.gmm(a, stacked[name], None, walk=walk,
                                   layer=layer)
                 if tiles:
-                    return gm.grouped_matmul(a, w, group_sizes, walk)
+                    return gm.grouped_matmul(
+                        a, w, group_sizes, walk, layer=layer,
+                        stack=None if stacked is None else stacked[name])
                 return jax.lax.ragged_dot(a, w, group_sizes)
 
             def per_expert(b):

@@ -48,7 +48,7 @@ def routing_stats(model, params, batch):
     [layers, tokens, k] (each token's chosen experts) and ``gmm_tiles``
     [layers, 3] (the grouped-matmul kernel's tiles, zeros where the layer
     traced ``ragged_dot``), ``in_place`` [layers] (1 where the kernel read
-    the stacked parameters: a serving call's, never this forward's),
+    the stacked parameters with the layer as an index),
     ``held`` [layers] (the experts whose matrices
     the layer holds: ``computed`` is of those) and ``routed_here``
     [layers] (the pairs routed to them), and of layers with a correction
@@ -99,8 +99,12 @@ def publish_expert_load(model, params, batch):
     for these ``tokens_per_expert`` over ``rows / tm``, the worst layer's
     (1.0 when no expert's rows end inside a tile; each one that does is a
     tile computed twice, and an expert of no rows has one visit that
-    computes nothing); host arithmetic on the counts. Where the routers'
-    choice is corrected by a bias (``MoE.expert_bias``),
+    computes nothing); host arithmetic on the counts; and
+    ``expert_matrices``, ``"in_place"`` where the kernel read the stacked
+    parameters with the layer as an index and ``"slice"`` where each layer
+    took a tensor of its own (moe/experts.py ``expert_matrices``: the rule
+    a training step of the same model and rows traces under). Where the
+    routers' choice is corrected by a bias (``MoE.expert_bias``),
     ``bias_changed_share`` is the share of the (token, layer) choices
     whose chosen set is not the k largest uncorrected scores. A training
     loop calls it when it wants to look, never per step."""
@@ -133,4 +137,6 @@ def publish_expert_load(model, params, batch):
         experts_with_rows_share=float((counts > 0).mean()),
         routed=int(stats["routed"].sum()), routed_here=int(here.sum()),
         grouped_matmul=path, grouped_matmul_tiles=tiles,
-        row_tile_visits_over_least=over_least, **corrected)
+        row_tile_visits_over_least=over_least,
+        expert_matrices=("in_place" if stats["in_place"].all() else "slice")
+        if tiles else None, **corrected)

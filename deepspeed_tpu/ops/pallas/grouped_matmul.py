@@ -352,35 +352,52 @@ def _tgmm(lhs, dout, walk, *, out_dtype, tiles, interpret):
     )(*walk, lhs, dout)
 
 
-def grouped_matmul(lhs, rhs, group_sizes, walk=None):
+def grouped_matmul(lhs, rhs, group_sizes, walk=None, stack=None, layer=None):
     """``jax.lax.ragged_dot(lhs, rhs, group_sizes)`` through the kernels:
     forward :func:`gmm`; backward the rows' gradient by :func:`gmm` on the
     matrices as they lie (contracted on their last axis) and the matrices'
     gradient by :func:`tgmm`. The residuals are ``ragged_dot``'s own, the
     two operands, and the walk's four small vectors in place of the sizes.
     ``walk``: :func:`row_walk` of these sizes, where a layer makes it once
-    for all its calls."""
+    for all its calls.
+
+    With ``stack`` ``[layers, E, K, N]`` and ``layer`` (inside a layer
+    scan: what the scan's owner closes over and the turn's index), ``rhs``
+    is the scan's slice ``stack[layer]`` and is what the call is
+    DIFFERENTIATED with respect to, but its values are never read: the
+    forward call and the rows' gradient multiply by the stack where it
+    lies (``gmm(..., layer=)``), the residuals are the rows, the walk and
+    the index (the stack is the loop's invariant, not a copy), and
+    :func:`tgmm`'s result is the cotangent of the slice, which the scan
+    stacks into the parameter's gradient as it does any layer's. The
+    stack gets no cotangent (hand it over under ``lax.stop_gradient``: one
+    the size of the stack would otherwise be summed every turn). The same
+    kernels on the same blocks: the values are the slice route's to the
+    bit, and the slice, read by nothing, leaves the program."""
     if walk is None:
         walk = row_walk(group_sizes, lhs.shape[0], autotune.grouped_matmul_tiles(
             "gmm", *lhs.shape, rhs.shape[2], rhs.shape[0], lhs.dtype)[0])
-    return _grouped_matmul(lhs, rhs, tuple(walk))
+    return _grouped_matmul(lhs, rhs, tuple(walk), stack, layer)
 
 
 @jax.custom_vjp
-def _grouped_matmul(lhs, rhs, walk):
-    return gmm(lhs, rhs, None, walk=walk)
+def _grouped_matmul(lhs, rhs, walk, stack, layer):
+    return _grouped_matmul_fwd(lhs, rhs, walk, stack, layer)[0]
 
 
-def _grouped_matmul_fwd(lhs, rhs, walk):
-    return gmm(lhs, rhs, None, walk=walk), (lhs, rhs, walk)
+def _grouped_matmul_fwd(lhs, rhs, walk, stack, layer):
+    # what the products read: the slice, or the stack in its place
+    read = rhs if stack is None else stack
+    return (gmm(lhs, read, None, walk=walk, layer=layer),
+            (lhs, read, walk, layer))
 
 
 def _grouped_matmul_bwd(residuals, dout):
-    lhs, rhs, walk = residuals
+    lhs, read, walk, layer = residuals
     dout = dout.astype(lhs.dtype)
-    return (gmm(dout, rhs, None, transpose_rhs=True, walk=walk),
-            tgmm(lhs, dout, None, out_dtype=rhs.dtype, walk=walk),
-            None)
+    return (gmm(dout, read, None, transpose_rhs=True, walk=walk, layer=layer),
+            tgmm(lhs, dout, None, out_dtype=read.dtype, walk=walk),
+            None, None, None)
 
 
 _grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)

@@ -841,7 +841,7 @@ def test_the_serving_programs_over_the_stack_are_bitwise_the_slices(
     assert routes and all(routes)
     del routes[:]
     monkeypatch.setattr(experts, "expert_matrices",
-                        lambda cfg, rows, decode: "slice")
+                        lambda cfg, rows: "slice")
     jax.clear_caches()
     want = serving_programs(cfg)
     jax.clear_caches()
@@ -853,10 +853,12 @@ def test_the_serving_programs_over_the_stack_are_bitwise_the_slices(
 
 
 def test_a_training_forwards_gradient_is_what_it_was(monkeypatch):
-    """``jax.grad`` of the aligned model's training forward never takes
-    the stack: with the rule answering "slice" for everything the
-    gradients are bitwise the same, and every product went through the
-    custom VJP on one layer's matrices."""
+    """``jax.grad`` of the aligned model's training forward reads the
+    stack too (after the leading dense block: the turn's index is the
+    scan's less one) and is differentiated with respect to the scan's
+    slices: with the rule answering "slice" for everything the gradients
+    are bitwise the same, every product then through the custom VJP on one
+    layer's matrices."""
     from deepspeed_tpu.moe import experts
 
     cfg = aligned_config(num_logits_to_keep=None)
@@ -870,11 +872,13 @@ def test_a_training_forwards_gradient_is_what_it_was(monkeypatch):
 
     routes = gmm_routes(monkeypatch)
     got = grads()
-    assert routes and not any(routes)
+    assert routes and all(routes)
+    del routes[:]
     monkeypatch.setattr(experts, "expert_matrices",
-                        lambda cfg, rows, decode: "slice")
+                        lambda cfg, rows: "slice")
     want = grads()
     jax.clear_caches()
+    assert routes and not any(routes)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert (np.asarray(a) == np.asarray(b)).all()
     assert np.asarray(got["h"]["block"]["mlp"]["experts"]["wi"]).any()

@@ -3,8 +3,11 @@
 mode on the CPU; the rule that chooses them (``moe/experts.py``); and, for
 a described v5e, Mosaic's own compile at the OLMoE cell's widths; and the
 forward product over a STACK of layers' matrices with the layer as an
-index (``gmm(..., layer=)``), which a serving call inside the layer scan
-takes so that no slice of the stack is written out for the custom call.
+index (``gmm(..., layer=)``), which a call inside the layer scan takes so
+that no slice of the stack is written out for the custom call: a serving
+call the forward product itself, a training step the custom VJP that reads
+the stack and is differentiated with respect to the scan's slice
+(``grouped_matmul(..., stack=, layer=)``).
 
 Tolerance: both sides accumulate in float32 and round once, in another
 order of the sums, so a bf16 result differs by one bf16 ulp at most (2^-7
@@ -265,6 +268,61 @@ def test_inside_a_scan_over_the_layers_likewise(layout):
             scanned_layers(False)(lhs, stack, sizes))
 
 
+def differentiated_layers(in_place, remat):
+    """Loss and gradients (rows, stack) of one differentiable product a
+    layer inside a ``lax.scan`` over the layers, the turn under
+    ``jax.checkpoint`` or not: over the scan's slice alone, or reading the
+    stack (closed over under ``stop_gradient``) and differentiated with
+    respect to the slice."""
+    def loss(x, stack, sizes):
+        walk = gm.row_walk(sizes, x.shape[0], 32)
+        held = jax.lax.stop_gradient(stack)
+
+        def turn(x, xs):
+            w, n = xs
+            y = gm.grouped_matmul(x, w, None, walk, stack=held, layer=n) \
+                if in_place else gm.grouped_matmul(x, w, None, walk)
+            return x + jnp.tanh(jnp.concatenate([y, y], axis=1)), None
+
+        if remat:
+            turn = jax.checkpoint(turn, prevent_cse=False)
+        out = jax.lax.scan(turn, x, (stack, jnp.arange(stack.shape[0])))[0]
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    return jax.value_and_grad(loss, argnums=(0, 1))
+
+
+def shapes_made(jaxpr, shape):
+    """Names of the primitives, at any depth, with a result of ``shape``."""
+    for eqn in jaxpr.eqns:
+        if any(getattr(v.aval, "shape", None) == shape for v in eqn.outvars):
+            yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from shapes_made(sub, shape)
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "saved"])
+@pytest.mark.parametrize("layout", ["ending_inside_a_tile", "some_empty",
+                                    "rows_no_group_covers"])
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_differentiated_over_the_stack_gives_the_slices_gradients(
+        dtype, layout, remat):
+    """Read from the stack, differentiated with respect to the slice: the
+    loss, the rows' gradient and every layer's matrices' gradient are the
+    slice route's to the bit, and the backward pass makes nothing of the
+    stack's shape but the scan's own stacking of the layers' gradients."""
+    lhs, stack = stacked_operands(DTYPES[dtype], ROWS)
+    sizes = jnp.asarray(LAYOUTS[layout], jnp.int32)
+    got = jax.jit(differentiated_layers(True, remat))(lhs, stack, sizes)
+    want = jax.jit(differentiated_layers(False, remat))(lhs, stack, sizes)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        bitwise(a, b)
+    assert all(np.asarray(layer, np.float32).any() for layer in got[1][1])
+    made = list(shapes_made(jax.make_jaxpr(differentiated_layers(
+        True, remat))(lhs, stack, sizes).jaxpr, stack.shape))
+    assert sorted(made) == ["scan", "stop_gradient"]
+
+
 def pallas_calls(jaxpr):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
@@ -405,14 +463,17 @@ def moe_gpt(**changes):
 
 
 IDS = np.arange(16, dtype=np.int32).reshape(2, 8) % 64
-# name: (mesh axes, changes to the configuration, a serving call?, the
-# rule's answer)
+# name: (mesh axes, changes to the configuration, a serving call? (else a
+# training step, differentiated), the rule's answer)
 MATRICES = {
     "serving_under_the_scan": (dict(), dict(), True, "in_place"),
     "serving_bf16": (dict(), dict(dtype=jnp.bfloat16,
                                   param_dtype=jnp.bfloat16), True,
                      "in_place"),
-    "training_forward": (dict(), dict(), False, "slice"),
+    "training_forward": (dict(), dict(), False, "in_place"),
+    "training_bf16": (dict(), dict(dtype=jnp.bfloat16,
+                                   param_dtype=jnp.bfloat16, remat=True),
+                      False, "in_place"),
     "under_ep": (dict(ep=2), dict(), True, "slice"),
     "under_tp": (dict(tp=2), dict(), True, "slice"),
     "widths_off_128": (dict(), dict(n_embd=64), True, "slice"),
@@ -422,7 +483,21 @@ MATRICES = {
     "with_a_capacity": (dict(), dict(moe_top_k=1, moe_drop_tokens=True),
                         True, "slice"),
     "no_experts": (dict(), dict(moe_num_experts=0), True, "none"),
+    "training_under_ep": (dict(ep=2), dict(), False, "slice"),
+    "training_stored_wider_than_computed": (
+        dict(), dict(dtype=jnp.bfloat16), False, "slice"),
+    "training_layers_looped_over": (dict(), dict(scan_layers=False), False,
+                                    "slice"),
 }
+
+
+def counted_in_place(stats):
+    """The layers' ``in_place`` counters of a ``moe_stats`` collection."""
+    from flax.traverse_util import flatten_dict
+
+    return sum((np.asarray(value).reshape(-1).tolist()
+                for path, (value,) in flatten_dict(stats).items()
+                if path[-1] == "in_place"), [])
 
 
 def gmm_routes(monkeypatch):
@@ -442,10 +517,9 @@ def gmm_routes(monkeypatch):
 @pytest.mark.parametrize("name", MATRICES)
 def test_where_the_matrices_are_read_is_told_from_what_the_call_shows(
         topology, monkeypatch, name):
-    """The rule's answer, the route the model then traces, and the
-    layers' ``in_place`` counter agree."""
-    from flax.traverse_util import flatten_dict
-
+    """The rule's answer, the route the model then traces (a training
+    step under ``jax.grad``), and the layers' ``in_place`` counter
+    agree."""
     from deepspeed_tpu.moe.layer import MOE_STATS
 
     axes, changes, decode, want = MATRICES[name]
@@ -453,39 +527,113 @@ def test_where_the_matrices_are_read_is_told_from_what_the_call_shows(
     model = moe_gpt(**changes)
     cfg = model.config
     rows = IDS.size * cfg.moe_top_k
-    assert experts_mod.expert_matrices(cfg, rows, decode=decode) == want
+    assert experts_mod.expert_matrices(cfg, rows) == want
     params = model.init(jax.random.PRNGKey(0), IDS)["params"]
     routes = gmm_routes(monkeypatch)
-    _, out = model.apply({"params": params}, IDS, decode=decode,
-                         mutable=["cache", MOE_STATS])
+    if decode:
+        _, out = model.apply({"params": params}, IDS, decode=True,
+                             mutable=["cache", MOE_STATS])
+    else:
+        grads, out = jax.grad(lambda p: model.apply(
+            {"params": p}, IDS, labels=IDS, mutable=[MOE_STATS]),
+            has_aux=True)(params)
+        assert all(np.isfinite(np.asarray(g, np.float32)).all()
+                   and np.asarray(g, np.float32).any()
+                   for g in jax.tree.leaves(grads))
     tiles = experts_mod.grouped_matmul_tiles(
         rows, cfg.n_embd, cfg.moe_ffn_dim, max(cfg.moe_num_experts, 1),
         cfg.dtype)
     dropless = cfg.is_moe and not cfg.moe_drop_tokens
     assert bool(routes) == bool(dropless and tiles)
     assert set(routes) <= {want == "in_place"}
-    counted = [np.asarray(value).reshape(-1).tolist()
-               for path, (value,) in flatten_dict(
-                   out.get(MOE_STATS, {})).items() if path[-1] == "in_place"]
-    assert sum(counted, []) == (
+    assert counted_in_place(out.get(MOE_STATS, {})) == (
         [int(want == "in_place")] * 2 if dropless else [])
 
 
-def test_a_differentiated_forward_and_init_keep_the_slice(monkeypatch):
+def test_a_differentiated_forward_reads_the_stack_and_init_nothing(
+        monkeypatch):
     """``jax.grad`` of a training forward goes through the custom VJP on
-    the scan's slices (a stack read in place would want a cotangent the
-    size of the stack a layer); ``init`` traces no kernel at all, serving
-    call or not."""
+    the stack with the layer as an index, forward and rows' gradient; the
+    stacked leaves get their gradient all the same (it went to the scan's
+    slices); ``init`` traces no kernel at all, serving call or not."""
     model = moe_gpt()
     routes = gmm_routes(monkeypatch)
     params = model.init(jax.random.PRNGKey(0), IDS, decode=True)["params"]
     assert not routes
     grads = jax.grad(lambda p: model.apply(
         {"params": p}, IDS, labels=IDS))(params)
-    assert routes and not any(routes)
+    assert routes and all(routes)
     stacked = grads["h"]["block"]["mlp"]["experts"]
-    assert all(np.isfinite(np.asarray(g)).all() and np.asarray(g).any()
+    assert all(np.isfinite(np.asarray(g)).all()
+               and all(np.asarray(layer).any() for layer in g)
                for g in stacked.values())
+
+
+TRAINED = {
+    "float32_remat": dict(remat=True),
+    "float32_saved": dict(),
+    "bf16_remat": dict(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                       remat=True),
+}
+
+
+def loss_and_grads(model, params):
+    """Loss, gradients and the layers' ``in_place`` counters of one
+    training forward, traced anew."""
+    from deepspeed_tpu.moe.layer import MOE_STATS
+
+    jax.clear_caches()
+    (loss, out), grads = jax.jit(jax.value_and_grad(
+        lambda p: model.apply({"params": p}, IDS, labels=IDS,
+                              mutable=[MOE_STATS]), has_aux=True))(params)
+    return (loss, grads), counted_in_place(out[MOE_STATS])
+
+
+@pytest.mark.parametrize("name", TRAINED)
+def test_a_training_steps_gradients_are_the_slice_routes_to_the_bit(
+        monkeypatch, name):
+    """A tiny scanned MoE model, under ``remat`` and without: read in
+    place, the loss and EVERY parameter's gradient (``wi`` / ``wg`` /
+    ``wo`` among them) are bitwise what the slices give, and the layers'
+    counter says which route each program took. (After a leading dense
+    block, where the turn's index is the scan's less one:
+    ``test_deepseek_v2.py``; through the runs of kinds:
+    ``test_lfm2.py``.)"""
+    model = moe_gpt(**TRAINED[name])
+    params = model.init(jax.random.PRNGKey(0), IDS)["params"]
+    layers = model.config.n_layer - model.config.first_k_dense
+    routes = gmm_routes(monkeypatch)
+    got, counted = loss_and_grads(model, params)
+    assert routes and all(routes) and counted == [1] * layers
+    del routes[:]
+    monkeypatch.setattr(experts_mod, "expert_matrices",
+                        lambda cfg, rows: "slice")
+    want, counted = loss_and_grads(model, params)
+    jax.clear_caches()
+    assert routes and not any(routes) and counted == [0] * layers
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        bitwise(a, b)
+    stacked = got[1]["h"]["block"]["mlp"]["experts"]
+    assert sorted(stacked) == ["wg", "wi", "wo"]
+    assert all(np.asarray(layer, np.float32).any()
+               for g in stacked.values() for layer in g)
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "saved"])
+def test_the_stack_gets_no_gradient_of_its_own(remat):
+    """The backward pass of the model read in place: the only results of
+    a stacked leaf's shape ``[layers, E, K, N]`` are the three
+    ``stop_gradient`` the stack is closed over under and the backward
+    scan's own stacking of the layers' ``tgmm`` results (no sum into a
+    cotangent the size of the stack a turn, no zeros of its shape)."""
+    model = moe_gpt(remat=remat)
+    params = model.init(jax.random.PRNGKey(0), IDS)["params"]
+    shape = params["h"]["block"]["mlp"]["experts"]["wi"].shape
+    assert shape == (2, 4, 128, 128)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: model.apply(
+        {"params": p}, IDS, labels=IDS)))(params).jaxpr
+    assert sorted(shapes_made(jaxpr, shape)) \
+        == ["scan"] + ["stop_gradient"] * 3
 
 
 @pytest.mark.parametrize("stored", ["int8_at_rest", "offloaded", "gathered"])
@@ -496,22 +644,27 @@ def test_a_stack_that_is_not_what_the_layer_multiplies_by_keeps_the_slice(
     changes = {"int8_at_rest": dict(quantized_weights=True),
                "offloaded": dict(param_offload=True), "gathered": dict()}
     cfg = moe_gpt(**changes[stored]).config
-    assert experts_mod.expert_matrices(moe_gpt().config, 64, decode=True) \
-        == "in_place"
+    assert experts_mod.expert_matrices(moe_gpt().config, 64) == "in_place"
     if stored == "gathered":
+        # every program under a ZeRO-3 plan, the step's among them
         monkeypatch.setattr(gather, "current_plan", lambda: object())
-    assert experts_mod.expert_matrices(cfg, 64, decode=True) == "slice"
+    assert experts_mod.expert_matrices(cfg, 64) == "slice"
 
 
-def test_a_tree_in_another_dtype_than_declared_keeps_the_slice(monkeypatch):
+@pytest.mark.parametrize("call", ["serving", "training"])
+def test_a_tree_in_another_dtype_than_declared_keeps_the_slice(
+        monkeypatch, call):
     """The configuration says bf16 parameters, the caller hands float32
     ones: the layer casts its slice, as it did."""
     model = moe_gpt(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
-    params = model.init(jax.random.PRNGKey(0), IDS)["params"]
+    params = jax.tree.map(lambda x: x.astype(jnp.float32), model.init(
+        jax.random.PRNGKey(0), IDS)["params"])
     routes = gmm_routes(monkeypatch)
-    model.apply({"params": jax.tree.map(
-        lambda x: x.astype(jnp.float32), params)}, IDS, decode=True,
-        mutable=["cache"])
+    if call == "serving":
+        model.apply({"params": params}, IDS, decode=True, mutable=["cache"])
+    else:
+        jax.grad(lambda p: model.apply({"params": p}, IDS, labels=IDS))(
+            params)
     assert routes and not any(routes)
 
 
@@ -526,11 +679,11 @@ def test_no_field_or_variable_chooses_it():
                       "parent", "name"}
     for module in (experts_mod, gm):
         assert "environ" not in inspect.getsource(module)
-    # where the matrices are read is asked with the model's configuration,
-    # the rows and whether the call serves, and nothing of the
-    # configuration names the answer
+    # where the matrices are read is asked with the model's configuration
+    # and the rows, of a serving call and a training step alike, and
+    # nothing of the configuration names the answer
     assert list(inspect.signature(experts_mod.expert_matrices).parameters) \
-        == ["cfg", "rows", "decode"]
+        == ["cfg", "rows"]
     assert not {f.name for f in dataclasses.fields(GPTConfig)
                 if "in_place" in f.name or "stacked" in f.name
                 or "expert_matrices" in f.name}
@@ -866,3 +1019,77 @@ def test_a_latent_models_serving_programs_write_no_copy_of_q_b(
     whole = q_rank * heads * (nope + rope)
     assert not [(name, shape) for name, shape in materialised(text)
                 if int(np.prod(shape)) == whole]
+
+
+@pytest.mark.parametrize("route", ["in_place", "slice"])
+def test_the_olmoe_train_step_slices_no_expert_tensor_out_of_the_stack(
+        one_chip, route, monkeypatch):
+    """The differentiated step of ``olmoe-1b-7b-train-4k`` (the published
+    widths, 3 layers, 2 x 4,096 tokens: 65,536 sorted rows a layer, full
+    remat, the flash and grouped-matmul kernels), compiled for the chip.
+    The instructions that write a whole expert tensor of one layer
+    (``[64, 2048, 1024]`` or ``[64, 1024, 2048]``, 268 MB), each run once a
+    layer: on the slice route (the control, and every tree before PR 58)
+    three ``dynamic-slice`` in the forward loop's body, three more in the
+    backward's (one serves the recomputed forward call and the rows'
+    gradient), and three ``dynamic-update-slice`` that put the ``tgmm``
+    results into the stacked gradient: nine a layer, 27 a step. Read in
+    place the six slices are gone and the three writes stay (ROADMAP S17):
+    9 a step."""
+    import importlib
+
+    from deepspeed_tpu.models.transformer_lm import GPT, GPTConfig
+
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
+    if route == "slice":
+        monkeypatch.setattr(experts_mod, "expert_matrices",
+                            lambda cfg, rows: "slice")
+    layers, experts, d_model, d_hidden = 3, 64, 2048, 1024
+    model = GPT(GPTConfig(
+        vocab_size=50304, n_positions=4096, n_embd=d_model, n_layer=layers,
+        n_head=16, intermediate_size=d_hidden, norm="rmsnorm",
+        layer_norm_epsilon=1e-5, activation="silu", use_bias=False,
+        rotary=True, learned_positions=False, tie_word_embeddings=False,
+        qk_norm=True, moe_num_experts=experts, moe_top_k=8,
+        moe_drop_tokens=False, moe_gated_experts=True,
+        moe_norm_topk_prob=False, moe_aux_loss_coef=0.01,
+        moe_z_loss_coef=0.001, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+        scan_layers=True, remat=True, remat_policy="full",
+        use_flash_attention=True))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    def step(params, ids):
+        return jax.grad(lambda p: model.apply(
+            {"params": p}, ids, labels=ids))(params)
+
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    text = compiled_for_the_chip(
+        jax.jit(step), on_chip(params),
+        on_chip(jax.ShapeDtypeStruct((2, 4096), jnp.int32)))
+    up, down = (experts, d_model, d_hidden), (experts, d_hidden, d_model)
+    made = list(materialised(text))
+    # a layer's tensor made outside the kernel that computes it
+    sliced = [name for name, shape in made if shape in (up, down)
+              and not name.startswith(f"%{gm.TGMM_NAME}")]
+    written = [name for name, shape in made
+               if shape in ((layers,) + up, (layers,) + down)
+               and "dynamic-update-slice" in name]
+    assert len(written) == 3
+    if route == "in_place":
+        assert not sliced
+    else:
+        assert len(sliced) == 6 and all(
+            name.startswith("%dynamic-slice") for name in sliced)
+    assert (len(sliced) + len(written)) * layers \
+        == {"in_place": 9, "slice": 27}[route]
+    # the kernels are the cell's: nine grouped matmuls a layer
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line]
+    assert sum(f"%{gm.GMM_NAME}" in line for line in calls) == 6 + 3
+    assert sum(f"%{gm.TGMM_NAME}" in line for line in calls) == 3

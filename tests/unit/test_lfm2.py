@@ -645,15 +645,9 @@ def aligned(**changes):
     return dict(n_embd=128, moe_intermediate_size=128, **changes)
 
 
-def test_the_experts_matrices_are_read_in_place_in_each_kinds_stack(
-        monkeypatch):
-    """At widths of whole lanes a serving call's grouped matmuls take the
-    kind's stacked leaves with the layer's index (never a slice), the plan
-    and the counter say so, and every leaf the programs leave is bitwise
-    what the slices give."""
-    from flax.traverse_util import flatten_dict
-
-    from deepspeed_tpu.moe import experts
+def gmm_routes(monkeypatch):
+    """What the traces to come hand ``gmm``: True for a stack and a layer,
+    False for one layer's matrices."""
     from deepspeed_tpu.ops.pallas import grouped_matmul as gm
 
     routes, real = [], gm.gmm
@@ -663,6 +657,20 @@ def test_the_experts_matrices_are_read_in_place_in_each_kinds_stack(
         return real(*args, **kwargs)
 
     monkeypatch.setattr(gm, "gmm", spy)
+    return routes
+
+
+def test_the_experts_matrices_are_read_in_place_in_each_kinds_stack(
+        monkeypatch):
+    """At widths of whole lanes a serving call's grouped matmuls take the
+    kind's stacked leaves with the layer's index (never a slice), the plan
+    and the counter say so, and every leaf the programs leave is bitwise
+    what the slices give."""
+    from flax.traverse_util import flatten_dict
+
+    from deepspeed_tpu.moe import experts
+
+    routes = gmm_routes(monkeypatch)
     events = []
     telemetry_bus.subscribe(events.append)
     try:
@@ -694,13 +702,48 @@ def test_the_experts_matrices_are_read_in_place_in_each_kinds_stack(
     assert counted["bias_changed"].shape == (6,)
     assert counted["computed"].shape == (6, 8)
     monkeypatch.setattr(experts, "expert_matrices",
-                        lambda cfg, rows, decode: "slice")
+                        lambda cfg, rows: "slice")
     want, counted = step()
     jax.clear_caches()
     assert routes and not any(routes)
     assert counted["in_place"].tolist() == [0] * 6
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert a.dtype == b.dtype and (a == b).all()
+
+
+def test_training_through_the_runs_reads_the_stacks_in_place_too(
+        monkeypatch):
+    """A training step through the runs at widths of whole lanes, each
+    turn under ``jax.checkpoint``: every
+    grouped matmul reads its kind's stack with the layer's index and is
+    differentiated with respect to the turn's own leaves; the loss and
+    every parameter's gradient are bitwise what the rule answering
+    "slice" gives."""
+    from deepspeed_tpu.moe import experts
+
+    routes = gmm_routes(monkeypatch)
+    model, params = init(model_config(num_logits_to_keep=None, remat=True,
+                                      **aligned()))
+    ids = jnp.asarray(np.stack([tokens(16, seed=s) for s in range(4)]))
+
+    def step():
+        jax.clear_caches()
+        del routes[:]
+        return jax.value_and_grad(lambda p: model.apply(
+            {"params": p}, ids, labels=ids))(params)
+
+    got = step()
+    assert routes and all(routes)
+    monkeypatch.setattr(experts, "expert_matrices",
+                        lambda cfg, rows: "slice")
+    want = step()
+    jax.clear_caches()
+    assert routes and not any(routes)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype and (np.asarray(a) == np.asarray(b)).all()
+    assert all(np.asarray(leaf).any() for stack in got[1]["h"].values()
+               if "experts" in stack.get("mlp", {})
+               for leaf in stack["mlp"]["experts"].values())
 
 
 # ---------------------------------------------------------------------------

@@ -167,10 +167,14 @@ def test_the_engine_trains_it_and_starts_at_the_reference_loss(both):
         assert load["grouped_matmul_tiles"] == [tm, cfg.n_embd, 128]
         assert 1.0 <= load["row_tile_visits_over_least"] \
             <= (rows // tm + cfg.moe_num_experts) / (rows // tm)
+        # ... read in the stacked parameters, as the steps above read them
+        assert load["expert_matrices"] == "in_place"
+        assert both["stats"]["in_place"].tolist() == [1] * cfg.n_layer
     else:
         assert load["grouped_matmul"] == "xla"
         assert load["grouped_matmul_tiles"] is None
         assert load["row_tile_visits_over_least"] is None
+        assert load["expert_matrices"] is None
 
 
 def layer_on(experts, top_k, gate, **kw):
