@@ -11,6 +11,23 @@ makes one ``LaneLayout`` per engine; ``serving/disagg.py`` sizes capacity
 from the same geometry. Not declared, and named here alone: the clocks and
 masks every cache has (``cache_index``, ``position``, ``valid``,
 ``slot_pos``).
+
+A declaration may hold THREE families of leaf side by side, each held by a
+kind of layer (``CacheLeaf.held_by``): a dense leaf of every position, a
+window's ring of rows beside ``slot_pos``, and an index key beside the rows
+it chooses among with what a step leaves of its choice; and two kinds may
+keep leaves of one name at different LENGTHS (PR 56: a ring beside a dense
+leaf) and WIDTHS (latent attention by kind: a 1,024-wide latent in a ring
+beside a 512-wide one in a dense leaf). Everything here finds a leaf by
+name AND kind (``_declared``), counts each family apart (``geometry``:
+``latent_``, ``window_`` and ``index_key_bytes_per_lane``; a ring of
+latents counts as both ``latent`` and ``window``), reads one kind's stack
+(``LanesAtExit.positions(held_by=)``) and refuses by family
+(``_REFUSALS``: ``LatentCacheError``, ``MixedCacheError``,
+``IndexKeyError``, the first leaf declared answering first).
+``LaneLayout.window`` and ``LaneLayout.chosen`` are the rows a window
+layer and a choosing layer read of a lane (``serve.stats``
+``live_window_positions`` / ``live_chosen_positions``).
 """
 
 from collections.abc import Mapping
@@ -22,6 +39,7 @@ import numpy as np
 
 from deepspeed_tpu.inference.engine import probe_length
 from deepspeed_tpu.models.transformer_lm import (
+    KIND_ATTENTION,
     KIND_WINDOW,
     IndexKeyError,
     LatentCacheError,
@@ -33,6 +51,15 @@ from deepspeed_tpu.ops.sparse_attention.sparse_attention_utils import (
     ring_engaged,
 )
 from deepspeed_tpu.telemetry.scopes import DispatchedProgram
+
+
+# what of ``LaneLayout.geometry`` the scheduler's ``serve.cache_plan``
+# event says, each where the cache has it: a lane's bytes in all and by
+# family of leaf
+PLAN_FIELDS = ("kv_bytes_per_lane", "state_bytes_per_lane",
+               "conv_bytes_per_lane", "norm_bytes_per_lane",
+               "latent_bytes_per_lane", "bytes_per_lane",
+               "window_bytes_per_lane", "index_key_bytes_per_lane")
 
 
 class RecurrentStateError(ValueError):
@@ -349,9 +376,16 @@ class LaneLayout:
         self.leaves = declared_cache_leaves(self.config)
         # the positions a window layer sees, where the model has such
         # layers (the declaration's: ``GPTConfig.attention_kind``)
-        kind = getattr(self.config, "attention_kind", lambda mixer: None)(
-            KIND_WINDOW)
+        kind_of = getattr(self.config, "attention_kind", lambda mixer: None)
+        kind = kind_of(KIND_WINDOW)
         self.window = None if kind is None else kind.window
+        # and the rows a decode query attends over where the layers that
+        # keep every position choose among them (a latent kind's indexer
+        # with a choice to make: ``LatentKind.indexer``)
+        full = kind_of(KIND_ATTENTION)
+        ix = getattr(getattr(full, "latent", None), "indexer", None)
+        self.chosen = ix.topk if ix is not None \
+            and ix.engaged(self.config) else None
         self._shapes = None
         self._geometry = None
         # each layout's own jitted functions: a build is seen per scheduler

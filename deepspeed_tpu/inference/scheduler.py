@@ -82,6 +82,7 @@ from deepspeed_tpu.inference.engine import (
     programs_scope_table,
 )
 from deepspeed_tpu.inference.lane_cache import (
+    PLAN_FIELDS,
     LaneClocks,
     LaneLayout,
     LanesAtExit,
@@ -617,11 +618,7 @@ class ContinuousBatchingScheduler:
                     decode_attention_block=block or 0,
                     expert_matrices=self._expert_matrices(),
                     leaf_layers=kv["leaf_layers"],
-                    **{k: kv[k] for k in (
-                        "kv_bytes_per_lane", "state_bytes_per_lane",
-                        "conv_bytes_per_lane", "norm_bytes_per_lane",
-                        "latent_bytes_per_lane", "bytes_per_lane",
-                        "window_bytes_per_lane") if k in kv})
+                    **{k: kv[k] for k in PLAN_FIELDS if k in kv})
         de = self.draft_engine
         if de is None:
             return
@@ -928,6 +925,10 @@ class ContinuousBatchingScheduler:
                 # what the window layers of a mixed stack read of them
                 payload["live_window_positions"] = \
                     self._clocks.live_positions(lanes, self.lane_cache.window)
+            if self.lane_cache.chosen is not None:
+                # and what the layers that choose attend over of them
+                payload["live_chosen_positions"] = \
+                    self._clocks.live_positions(lanes, self.lane_cache.chosen)
         if self.prefix_cache is not None:
             payload["prefix_hit_rate"] = \
                 self.prefix_cache.stats().get("hit_rate", 0.0)

@@ -21,7 +21,19 @@ overwrites and nothing reads back, for whoever reads the cache afterwards
 it attended over (-1 where it saw fewer than ``topk``), and the ``qI`` and
 ``w`` it scored them with (``choice_query`` ``[layers, B, n_heads,
 head_dim]``, ``choice_weights`` ``[layers, B, n_heads]``).
+
+The same module is the indexer of ONE KIND of layer in a stack whose
+attention layers are latent attention by kind (``LatentKind.indexer``;
+models/latent_attention.py ``KindLatentAttention``): the kind hands itself
+(``latent``: its sizes, its rotary base, how a reader of its query latent
+is born; the scope is ``latent_index``), the queries come from the
+layer's QUERY LATENT (``q_in``) and rotary turns the first ``rope_dim``
+dimensions of each head alone (DeepSeek-V3.2-Exp's form); the rows chosen are latents, attended over in the absorbed form
+(ops/indexed_attention.py ``latent_decode_step``, ``attend_tiled``), and
+the leaves and what a step leaves of its choice are the same three.
 """
+from typing import Any
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -31,6 +43,7 @@ from deepspeed_tpu.telemetry.scopes import (
     SCOPE_DSA_INDEX_PROJ,
     SCOPE_KV_CACHE_READ,
     SCOPE_KV_CACHE_WRITE,
+    SCOPE_LATENT_INDEX,
 )
 
 CACHED_INDEX_KEY = "cached_index_key"
@@ -41,28 +54,41 @@ CHOICE_WEIGHTS = "choice_weights"
 
 class Indexer(nn.Module):
     config: "GPTConfig"  # noqa: F821  (models/transformer_lm.py)
+    # the kind whose indexer this is (``LatentKind``: its ``indexer``,
+    # ``rope_theta``, and how a reader of its query latent is born), timed
+    # under ``latent_index``; None = the whole model's ``GPTConfig.indexer``
+    # and ``rope_theta``, under ``dsa_index_proj``
+    latent: Any = None
 
     @nn.compact
-    def __call__(self, x, pos):
+    def __call__(self, x, pos, q_in=None):
         """``(qI [B, T, n_heads, head_dim], kI [B, T, head_dim], w [B, T,
         n_heads] float32)`` of ``x [B, T, C]`` at positions ``pos`` (``[B,
-        T]``, or ``[3, B, T]`` under a sectioned rotary)."""
+        T]``, or ``[3, B, T]`` under a sectioned rotary); the queries from
+        ``q_in [B, T, .]`` where the layer hands one (DeepSeek-V3.2-Exp:
+        the query latent), else from ``x`` like the key and the weights."""
         from deepspeed_tpu.ops.rotary import apply_rotary_pos_emb
 
         cfg = self.config
-        ix = cfg.indexer
+        lk = self.latent
+        ix = cfg.indexer if lk is None else lk.indexer
         B, T, _ = x.shape
 
-        def dense(width, name):
+        def dense(width, name, **kw):
             return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
-                            param_dtype=cfg.param_dtype, name=name)
+                            param_dtype=cfg.param_dtype, name=name, **kw)
 
         def rope(t):
             return apply_rotary_pos_emb(
-                t, pos, base=cfg.rope_theta, sections=ix.sections(cfg))
+                t, pos, base=cfg.rope_theta if lk is None else lk.rope_theta,
+                rotary_dim=ix.rope_dim, sections=ix.sections(cfg))
 
-        with jax.named_scope(SCOPE_DSA_INDEX_PROJ):
-            q = dense(ix.n_heads * ix.head_dim, "wq")(x).reshape(
+        with jax.named_scope(SCOPE_DSA_INDEX_PROJ if lk is None
+                             else SCOPE_LATENT_INDEX):
+            born = {} if lk is None \
+                else {"kernel_init": lk.up_init(x.shape[-1])}
+            q = dense(ix.n_heads * ix.head_dim, "wq", **born)(
+                x if q_in is None else q_in).reshape(
                 B, T, ix.n_heads, ix.head_dim)
             k = nn.LayerNorm(
                 epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,

@@ -31,6 +31,15 @@ the kernel reads the ring's blocks. More tokens on a cache (the passes of a
 prefill) and a call without a cache take two einsums a KV head, one head
 after another, so that a pass of ``T`` tokens holds ``[heads / kv_heads, T,
 rows]`` scores at a time.
+
+What a kind declares HERE is its window, its ring and whether q and k are
+rotated; heads, head size and ``rope_theta`` are the model's one set. A
+kind that declares its own mixer (``AttentionKind.latent``: heads, ranks,
+widths, ``rope_theta``, an indexer, a gate a head) is latent attention and
+is run by models/latent_attention.py ``KindLatentAttention``; ``Block``
+chooses between the two from the kind. How a kind's rows are kept, written
+and read back is ONE thing for both (:class:`KindCache`), whatever a row
+is: keys and values per head here, a latent and a rotary key there.
 """
 import flax.linen as nn
 import jax
@@ -62,6 +71,93 @@ def attend_by_kv_head(qg, k, v, visible):
     out = jax.lax.map(one, (jnp.moveaxis(qg, 2, 0), jnp.moveaxis(k, 2, 0),
                             jnp.moveaxis(v, 2, 0)))
     return jnp.moveaxis(out, 0, 2)
+
+
+class KindCache:
+    """What ONE KIND of layer keeps of each position and how a call on a
+    cache writes and reads it, whatever a row is. The leaves are ``[B,
+    rows, ...]`` beside ``valid``, ``cache_index`` (the clock a layer) and,
+    of a ring, ``slot_pos``; ``rows`` is ``AttentionKind.ring`` or
+    ``n_positions``; position ``p`` is written at row ``p`` (``p % ring``);
+    a query at ``p`` sees the rows that hold a position in ``(p - window,
+    p]``, read from ``slot_pos`` and never from a row's index. Under
+    ``KindStackedBlocks`` the leaves are the kind's stacked ``[layers of
+    the kind, B, rows, ...]`` buffers and this call is layer
+    ``cache_layer`` of them (as ``CausalSelfAttention`` under
+    ``ScannedBlocks``): rows are written in place, a slice is only read.
+    Whoever runs the layers makes the kind's stack before the first pass,
+    so a pass cannot tell a new ring from a full one."""
+
+    def __init__(self, module, kind, B, T, rows, cache_layer, step=None):
+        """``rows``: ``{leaf: (the shape after [B, rows], dtype)}`` of what
+        a position keeps; ``step``: ``{leaf: (the shape after [B], fill,
+        dtype)}`` of what a call leaves of itself beside them."""
+        cfg = module.config
+        self.kind, self.layer, self.T = kind, cache_layer, T
+        self.rows = S = kind.ring or cfg.n_positions
+        spec = {name: ((B, S) + tuple(tail), 0, dtype)
+                for name, (tail, dtype) in rows.items()}
+        spec["valid"] = ((B, S), False, jnp.bool_)
+        if kind.ring is not None:
+            spec["slot_pos"] = ((B, S), -1, jnp.int32)      # nothing cached
+        spec["cache_index"] = ((B,), 0, jnp.int32)
+        for name, (tail, fill, dtype) in (step or {}).items():
+            spec[name] = ((B,) + tuple(tail), fill, dtype)
+        self.leaves = {
+            name: module.variable("cache", name, jnp.full, *leaf_spec)
+            for name, leaf_spec in spec.items()}
+        if kind.ring is not None and T > S - kind.window + 1:
+            raise ValueError(
+                f"a pass of {T} tokens over a window layer's ring of {S} "
+                f"rows (window {kind.window}) would overwrite rows that "
+                "its own queries still attend over: prefill in passes of "
+                "GPTConfig.pass_tokens (inference/engine.py "
+                "prefill_chunk_spans)")
+        self.clock = self.leaf("cache_index")                   # [B]
+        self.pos = self.clock[:, None] + jnp.arange(T)[None, :]  # [B, T]
+
+    def stacked(self, name):
+        """The leaf as it lies (a kernel reads its blocks out of it)."""
+        return self.leaves[name].value
+
+    def leaf(self, name):
+        value = self.leaves[name].value
+        return value if self.layer is None else \
+            jax.lax.dynamic_index_in_dim(value, self.layer, 0,
+                                         keepdims=False)
+
+    def put(self, name, index, val):
+        if self.layer is not None:
+            index = (self.layer,) + index
+        self.leaves[name].value = self.leaves[name].value.at[index].set(
+            val, mode="drop")
+
+    def write(self, new, written):
+        """The pass's tokens into their rows (``new``: ``{leaf: [B, T,
+        ...]}``; ``written [B, T]``: which of them hold a token), and the
+        clock on by the pass."""
+        lanes = jnp.arange(written.shape[0])[:, None]
+        slots = self.pos if self.kind.ring is None else self.pos % self.rows
+        with jax.named_scope(SCOPE_KV_CACHE_WRITE):
+            new = dict(new, valid=written)
+            if self.kind.ring is not None:
+                new["slot_pos"] = self.pos
+            for name, val in new.items():
+                self.put(name, (lanes, slots), val)
+            self.put("cache_index", (Ellipsis,), self.clock + self.T)
+
+    def visible(self):
+        """``[B, T, rows]``: which rows each of the pass's queries sees."""
+        pos, kind = self.pos, self.kind
+        with jax.named_scope(SCOPE_KV_CACHE_READ):
+            if kind.ring is None:
+                held = jnp.arange(self.rows)[None, None, :] \
+                    <= pos[:, :, None]
+            else:
+                at = self.leaf("slot_pos")[:, None, :]
+                held = (at >= 0) & (at <= pos[:, :, None]) \
+                    & (at > pos[:, :, None] - kind.window)
+            return held & self.leaf("valid")[:, None, :]
 
 
 class KindAttention(nn.Module):
@@ -128,64 +224,15 @@ class KindAttention(nn.Module):
                     visible[None] & written[:, None, :]))
             return dense(C, "c_proj")(y)
 
-        S = kind.ring or cfg.n_positions
-        spec = dict.fromkeys(("cached_key", "cached_value"),
-                             ((B, S, Hkv, D), 0, cfg.dtype))
-        spec["valid"] = ((B, S), False, jnp.bool_)
-        if kind.ring is not None:
-            spec["slot_pos"] = ((B, S), -1, jnp.int32)      # nothing cached
-        spec["cache_index"] = ((B,), 0, jnp.int32)
-        cache = {name: self.variable("cache", name, jnp.full, *leaf_spec)
-                 for name, leaf_spec in spec.items()}
-        # (whoever runs the layers makes the kind's stack before the first
-        # pass, so a pass cannot tell a new ring from a full one)
-        if kind.ring is not None and T > S - kind.window + 1:
-            raise ValueError(
-                f"a pass of {T} tokens over a window layer's ring of {S} "
-                f"rows (window {kind.window}) would overwrite rows that "
-                "its own queries still attend over: prefill in passes of "
-                "GPTConfig.pass_tokens (inference/engine.py "
-                "prefill_chunk_spans)")
-
-        # under KindStackedBlocks the leaves are the kind's stacked
-        # [layers of the kind, B, S, ...] buffers and this call is layer
-        # ``cache_layer`` of them (as CausalSelfAttention under
-        # ScannedBlocks): rows are written in place, a slice is only read
-        def leaf(name):
-            value = cache[name].value
-            return value if cache_layer is None else \
-                jax.lax.dynamic_index_in_dim(value, cache_layer, 0,
-                                             keepdims=False)
-
-        def put(name, index, val):
-            if cache_layer is not None:
-                index = (cache_layer,) + index
-            cache[name].value = cache[name].value.at[index].set(
-                val, mode="drop")
-
-        idx = leaf("cache_index")                           # [B]
-        pos = idx[:, None] + jnp.arange(T)[None, :]         # [B, T]
-        q, k = rope(q, pos), rope(k, pos)
-        rows = jnp.arange(B)[:, None]
-        slots = pos if kind.ring is None else pos % S
-        with jax.named_scope(SCOPE_KV_CACHE_WRITE):
-            new = {"cached_key": k.astype(cfg.dtype),
-                   "cached_value": v.astype(cfg.dtype), "valid": written}
-            if kind.ring is not None:
-                new["slot_pos"] = pos
-            for name, val in new.items():
-                put(name, (rows, slots), val)
-            put("cache_index", (Ellipsis,), idx + T)
-
-        with jax.named_scope(SCOPE_KV_CACHE_READ):
-            # which rows each query sees: [B, T, S]
-            if kind.ring is None:
-                held = jnp.arange(S)[None, None, :] <= pos[:, :, None]
-            else:
-                at = leaf("slot_pos")[:, None, :]
-                held = (at >= 0) & (at <= pos[:, :, None]) \
-                    & (at > pos[:, :, None] - kind.window)
-            visible = held & leaf("valid")[:, None, :]
+        cache = KindCache(
+            self, kind, B, T, dict.fromkeys(
+                ("cached_key", "cached_value"), ((Hkv, D), cfg.dtype)),
+            cache_layer)
+        S, idx = cache.rows, cache.clock
+        q, k = rope(q, cache.pos), rope(k, cache.pos)
+        cache.write({"cached_key": k.astype(cfg.dtype),
+                     "cached_value": v.astype(cfg.dtype)}, written)
+        visible = cache.visible()
         if T == 1 and step_kernel():
             from deepspeed_tpu.ops.pallas.decode_attention import (
                 block_positions,
@@ -197,13 +244,14 @@ class KindAttention(nn.Module):
             # holds the clock to the last row)
             with jax.named_scope(kind.scope):
                 y = close(decode_attention(
-                    q[:, 0], cache["cached_key"].value,
-                    cache["cached_value"].value, visible[:, 0], idx,
+                    q[:, 0], cache.stacked("cached_key"),
+                    cache.stacked("cached_value"), visible[:, 0], idx,
                     cache_layer, block=block_positions(
                         S, Hkv, D, jnp.dtype(cfg.dtype).itemsize)))
             return dense(C, "c_proj")(y)
         with jax.named_scope(SCOPE_KV_CACHE_READ):
-            k_all, v_all = leaf("cached_key"), leaf("cached_value")
+            k_all, v_all = (cache.leaf("cached_key"),
+                            cache.leaf("cached_value"))
         with jax.named_scope(kind.scope):
             y = close(attend_by_kv_head(
                 q.reshape(B, T, Hkv, H // Hkv, D), k_all, v_all, visible))

@@ -31,8 +31,11 @@ moe/experts.py ``expert_matrices`` says so, as under ``ScannedBlocks``.
 Not built for such a stack, and refused (``GPTConfig.__post_init__``, and
 here for ZeRO-3's gather): weights that are not read as stored
 (``quantized_weights``, ``param_offload``, a gather over ``fsdp``),
-progressive layer drop, and mixers other than attention and the short
-convolution.
+progressive layer drop, and mixers other than attention (grouped-query, or
+latent attention where the kinds declare it: ``GPTConfig.latent_kinds``;
+then two kinds' leaves of one name differ in WIDTH as well as length) and
+the short convolution: the state-space mixer, retention and the whole-model
+``mla`` / ``indexer`` fields stay with stacks of one kind.
 """
 import contextlib
 import dataclasses

@@ -71,6 +71,22 @@ of it (2 x 33.5 MB a layer, 0.35 ms of a 15.5 ms decode step at
 DeepSeek-V2's widths). A Pallas kernel over the leaf as stored removed
 those copies and lost more than it won to the swap of lanes and heads
 around the attention kernel (PERF.md, section 6, PR 55; ROADMAP S16).
+
+**Latent attention as a KIND of layer** (``KindLatentAttention``, at the
+end of this module). ``LatentAttention`` is the mixer of a stack of ONE
+kind: heads, ranks, widths and ``rope_theta`` are the model's, the lane
+cache belongs to whoever runs the layers. A stack whose attention layers
+differ by kind (``GPTConfig.latent_kinds``, read back through
+``GPTConfig.attention_kind``) runs the second module instead: what a kind
+declares there is its OWN head count, its own ``MLAConfig`` (ranks and
+widths), its own ``rope_theta``, a window with a ring of latents
+(``sliding_window`` / ``window_slack``), an indexer whose queries come from
+the query latent and whose chosen rows are latents, a gate a head, and the
+rescale of both normed latents; its cache is the kind's own stack under
+``KindStackedBlocks`` (``cached_latent`` / ``cached_rope_key`` of the
+kind's width and length, ``slot_pos`` for a ring, ``cached_index_key`` and
+the three "step" leaves for an indexer). ``ScannedBlocks`` and this module's
+first class are untouched by it.
 """
 from typing import Any, NamedTuple
 
@@ -160,11 +176,12 @@ class _Kernel(nn.Module):
     that is also used re-associated (``W_kvb``)."""
     shape: tuple
     param_dtype: Any
+    kernel_init: Any = nn.initializers.lecun_normal()
 
     @nn.compact
     def __call__(self):
-        return self.param("kernel", nn.initializers.lecun_normal(),
-                          self.shape, self.param_dtype)
+        return self.param("kernel", self.kernel_init, self.shape,
+                          self.param_dtype)
 
 
 class LatentAttention(nn.Module):
@@ -301,3 +318,239 @@ class LatentAttention(nn.Module):
         with jax.named_scope(SCOPE_MLA_OUT_PROJ):
             y = dense(C, "c_proj")(y.reshape(B, T, H * dv))
         return y if lane is None else (y, lane)
+
+
+class KindLatentAttention(nn.Module):
+    """Latent attention as the mixer of ONE KIND of layer, in a stack whose
+    attention layers differ by kind (``GPTConfig.latent_kinds``; the kind
+    is ``GPTConfig.attention_kind``'s ``AttentionKind`` with its
+    ``LatentKind``): the layer above with the KIND's heads, ranks, widths
+    and rotary base, and beside it what a kind may declare:
+
+    * a window, exact by position (``0 <= i - j < window``), over a RING of
+      latents: ``AttentionKind.ring`` rows whatever ``n_positions`` is,
+      position ``p`` at row ``p % ring`` beside ``slot_pos``, as
+      models/kind_attention.py keeps keys and values;
+    * an indexer whose queries come from the query latent ``c_q`` and whose
+      chosen rows are LATENTS (models/indexer.py; ``cached_index_key`` and
+      the three leaves a step leaves of its choice);
+    * one sigmoid gate a head (``c_gate`` ``[C, heads]`` on the layer's
+      normalised input) on the heads' outputs before ``c_proj``;
+    * both normed latents times ``(n_embd / rank) ** 0.5``.
+
+    The parameters keep ``LatentAttention``'s names. The cache is the
+    kind's own, kept, written and read back by models/kind_attention.py
+    ``KindCache`` as that module's keys and values are, and stacked over
+    the kind's layers by ``KindStackedBlocks`` (``cache_layer``: this
+    layer's place among them): ``cached_latent``
+    ``[B, rows, kv_rank]`` (after its norm and scale), ``cached_rope_key``
+    ``[B, rows, rope_dim]`` (after rotary), ``valid``, ``cache_index`` and,
+    of a ring, ``slot_pos``. Whoever runs the layers makes the stack before
+    the first pass, so EVERY call on a cache takes the absorbed form:
+
+    * one query token: ``mla_decode_attn`` over the blocks between a
+      lane's first and last visible row (``mask_plan``: a ring's ``valid &``
+      what the window holds), or, under an indexer that has a choice to
+      make, ops/indexed_attention.py ``latent_decode_step``;
+    * more tokens (the passes of a prefill, at most
+      ``AttentionKind.pass_tokens`` over a ring): two einsums over the
+      kind's rows, or under an indexer ``attend_tiled`` over the key tiles
+      up to the pass's last row (select first, then attend: no ``[heads,
+      pass, positions]`` scores exist).
+
+    A call without a cache decompresses keys and values per head."""
+    config: Any
+    kind: Any
+
+    @nn.compact
+    def __call__(self, x, *, mask=None, segment_ids=None, decode=False,
+                 cache_layer=None):
+        from deepspeed_tpu.models import indexer as ixm
+        from deepspeed_tpu.models.kind_attention import KindCache
+        from deepspeed_tpu.models.transformer_lm import step_kernel
+        from deepspeed_tpu.ops import indexed_attention as ia
+        from deepspeed_tpu.ops.pallas import latent_decode_attention as lda
+        from deepspeed_tpu.ops.rotary import apply_rotary_pos_emb
+        from deepspeed_tpu.telemetry.scopes import (
+            SCOPE_LATENT_INDEX,
+            SCOPE_LATENT_SELECT,
+        )
+
+        cfg, kind = self.config, self.kind
+        lk = kind.latent
+        m, ix = lk.mla, lk.indexer
+        B, T, C = x.shape
+        H, dn, dr, dv, r = (lk.n_head, m.nope_dim, m.rope_dim, m.v_dim,
+                            m.kv_rank)
+        if segment_ids is not None:
+            raise NotImplementedError(
+                "packed-sequence segment_ids with attention layers that "
+                "differ by kind: a window would run across documents")
+
+        up = lk.up_init(C)      # of the matrices that read a normed latent
+
+        def dense(width, name, kernel_init=nn.initializers.lecun_normal()):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
+                            param_dtype=cfg.param_dtype,
+                            kernel_init=kernel_init, name=name)
+
+        def normed(name, t, rank):
+            t = nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
+                           param_dtype=cfg.param_dtype, name=name)(t)
+            return t * (C / rank) ** 0.5 if lk.rank_rescale else t
+
+        def rope(t, pos):
+            out = apply_rotary_pos_emb(
+                t.astype(jnp.float32), pos, base=lk.rope_theta,
+                inv_freq=m.inv_freq(lk.rope_theta))
+            if m.rope_mscale != 1.0:
+                out = out * m.rope_mscale
+            return out.astype(cfg.dtype)
+
+        written = (jnp.ones((B, T), jnp.bool_) if mask is None
+                   else mask.astype(jnp.bool_))
+        S = kind.ring or cfg.n_positions
+        selects = ix is not None and ix.engaged(cfg)
+        cache = None
+        if decode:
+            rows = {CACHED_LATENT: ((r,), cfg.dtype),
+                    CACHED_ROPE_KEY: ((dr,), cfg.dtype)}
+            step = {}
+            if ix is not None:
+                rows[ixm.CACHED_INDEX_KEY] = ((ix.head_dim,), cfg.dtype)
+            if selects:
+                step = {
+                    ixm.CHOSEN_ROWS: ((min(ix.topk, S),), -1, jnp.int32),
+                    ixm.CHOICE_QUERY: ((ix.n_heads, ix.head_dim), 0,
+                                       cfg.dtype),
+                    ixm.CHOICE_WEIGHTS: ((ix.n_heads,), 0, jnp.float32)}
+            cache = KindCache(self, kind, B, T, rows, cache_layer, step)
+        pos = cache.pos if decode \
+            else jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+
+        with jax.named_scope(SCOPE_MLA_Q_PROJ):
+            c_q = normed("q_a_norm", dense(m.q_rank, "q_a")(x), m.q_rank)
+            # held as a value before the per-head view, as in the layer
+            # above (the comment there says why)
+            q = jax.lax.optimization_barrier(
+                dense(H * (dn + dr), "q_b", up)(c_q)).reshape(
+                    B, T, H, dn + dr)
+            q_nope, q_rope = q[..., :dn], rope(q[..., dn:], pos)
+        with jax.named_scope(SCOPE_MLA_KV_PROJ):
+            ckr = dense(r + dr, "kv_a")(x)
+            c_kv = normed("kv_a_norm", ckr[..., :r], r).astype(cfg.dtype)
+            k_rope = rope(ckr[..., None, r:], pos)[:, :, 0]
+        w_kvb = _Kernel((r, H * (dn + dv)), cfg.param_dtype, up,
+                        name="kv_b")().astype(cfg.dtype)
+        gate = dense(H, "c_gate")(x) if lk.head_gate else None
+        if ix is not None:
+            q_idx, k_idx, w_idx = ixm.Indexer(
+                cfg, latent=lk, name="indexer")(x, pos, q_in=c_q)
+        keep = ix is not None \
+            and self.is_mutable_collection("intermediates")
+
+        def close(y):
+            """The heads' outputs ``[B, T, H, dv]`` under the heads' gate,
+            through the output projection."""
+            if gate is not None:
+                y = y * jax.nn.sigmoid(gate)[..., None]
+            with jax.named_scope(SCOPE_MLA_OUT_PROJ):
+                return dense(C, "c_proj")(y.reshape(B, T, H * dv))
+
+        if not decode:
+            # no cache: per head over the tokens at hand, row t position t
+            ahead = pos[0][:, None] - pos[0][None, :]            # i - j
+            visible = (ahead >= 0) if kind.window is None \
+                else (ahead >= 0) & (ahead < kind.window)
+            visible = visible[None] & written[:, None, :]       # [B, T, T]
+            if ix is not None:
+                with jax.named_scope(SCOPE_LATENT_INDEX):
+                    scores = ia.index_scores(q_idx, k_idx, w_idx)
+                with jax.named_scope(SCOPE_LATENT_SELECT):
+                    visible = ia.chosen_mask(scores, visible, ix.topk)
+                if keep:
+                    self.sow("intermediates", "chosen", visible)
+            with jax.named_scope(SCOPE_MLA_KV_PROJ):
+                kv = jnp.dot(c_kv, w_kvb).reshape(B, T, H, dn + dv)
+            with jax.named_scope(kind.scope):
+                att = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, kv[..., :dn],
+                                  preferred_element_type=jnp.float32)
+                       + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
+                                    preferred_element_type=jnp.float32)
+                       ) * m.softmax_scale
+                att = jnp.where(visible[:, None], att,
+                                jnp.finfo(jnp.float32).min)
+                att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
+                y = jnp.einsum("bhqk,bkhd->bqhd", att, kv[..., dn:])
+            return close(y)
+
+        new = {CACHED_LATENT: c_kv, CACHED_ROPE_KEY: k_rope}
+        if ix is not None:
+            new[ixm.CACHED_INDEX_KEY] = k_idx
+        cache.write(new, written)
+        visible = cache.visible()                           # [B, T, S]
+
+        w_kvb = w_kvb.reshape(r, H, dn + dv)
+        with jax.named_scope(SCOPE_MLA_ABSORB):
+            q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_kvb[..., :dn])
+        chosen = None
+        if T == 1 and step_kernel() and selects:
+            o_lat, picked, ok = ia.latent_decode_step(
+                q_lat[:, 0], q_rope[:, 0], q_idx[:, 0], w_idx[:, 0],
+                cache.stacked(CACHED_LATENT), cache.stacked(CACHED_ROPE_KEY),
+                cache.stacked(ixm.CACHED_INDEX_KEY), cache_layer,
+                visible[:, 0], ix.topk, m.softmax_scale, cfg.dtype)
+            o_lat = o_lat[:, None]
+            with jax.named_scope(SCOPE_KV_CACHE_WRITE):
+                for name, val in ((ixm.CHOSEN_ROWS, jnp.where(ok, picked, -1)),
+                                  (ixm.CHOICE_QUERY, q_idx[:, 0]),
+                                  (ixm.CHOICE_WEIGHTS, w_idx[:, 0])):
+                    cache.put(name, (Ellipsis,), val)
+            if keep:
+                chosen = jnp.zeros((B, S), jnp.bool_).at[
+                    jnp.arange(B)[:, None], picked].max(ok)[:, None]
+        elif T == 1 and step_kernel():
+            # one query token: the blocks between a lane's first and last
+            # visible row, out of the stacked leaves where they lie
+            with jax.named_scope(kind.scope):
+                o_lat = lda.latent_decode_attention(
+                    q_lat[:, 0], q_rope[:, 0], cache.stacked(CACHED_LATENT),
+                    cache.stacked(CACHED_ROPE_KEY), lda.mask_plan(
+                        visible[:, 0], lda.block_positions(
+                            S, r, jnp.dtype(cfg.dtype).itemsize)),
+                    cache_layer, scale=m.softmax_scale)[:, None]
+        else:
+            with jax.named_scope(SCOPE_KV_CACHE_READ):
+                lat_all, rk_all = (cache.leaf(CACHED_LATENT),
+                                   cache.leaf(CACHED_ROPE_KEY))
+            if selects:
+                # select first, then attend over the chosen rows of the
+                # key tiles up to the pass's last row: one "KV head" whose
+                # key is [latent | rotary key] and whose value the latent
+                o_lat, chosen = ia.attend_tiled(
+                    jnp.concatenate([q_lat, q_rope], -1),
+                    jnp.concatenate([lat_all, rk_all], -1)[:, :, None],
+                    lat_all[:, :, None], q_idx,
+                    cache.leaf(ixm.CACHED_INDEX_KEY), w_idx, pos,
+                    cache.leaf("valid"), ix.topk, ix.q_chunk, ix.kv_chunk,
+                    m.softmax_scale, cfg.dtype, keep_mask=keep,
+                    live_tiles=jnp.max(pos) // min(ix.kv_chunk, S) + 1,
+                    scopes=(SCOPE_LATENT_INDEX, SCOPE_LATENT_SELECT,
+                            kind.scope))
+            else:
+                with jax.named_scope(kind.scope):
+                    att = (jnp.einsum("bqhr,bkr->bhqk", q_lat, lat_all,
+                                      preferred_element_type=jnp.float32)
+                           + jnp.einsum("bqhd,bkd->bhqk", q_rope, rk_all,
+                                        preferred_element_type=jnp.float32)
+                           ) * m.softmax_scale
+                    att = jnp.where(visible[:, None], att,
+                                    jnp.finfo(jnp.float32).min)
+                    att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
+                    o_lat = jnp.einsum("bhqk,bkr->bqhr", att, lat_all)
+        if keep:
+            self.sow("intermediates", "chosen",
+                     visible if chosen is None else chosen)
+        with jax.named_scope(SCOPE_MLA_ABSORB):
+            y = jnp.einsum("bqhr,rhd->bqhd", o_lat, w_kvb[..., dn:])
+        return close(y)

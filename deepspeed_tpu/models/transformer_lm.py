@@ -35,8 +35,10 @@ from deepspeed_tpu.telemetry.scopes import (
     SCOPE_LM_HEAD,
     SCOPE_LM_HEAD_CE,
     SCOPE_RET_STATE_CARRY,
+    SCOPE_SPARSE_LATENT_ATTN,
     SCOPE_SSM_STATE_CARRY,
     SCOPE_WINDOW_ATTN,
+    SCOPE_WINDOW_LATENT_ATTN,
 )
 
 
@@ -223,6 +225,27 @@ class AttentionKind:
     ring: Optional[int]
     rotary: bool
     scope: str
+    # the kind's own mixer where it is latent attention (``LatentKind``:
+    # heads, ranks and widths, ``rope_theta``, indexer, gate), run by
+    # models/latent_attention.py ``KindLatentAttention``; None = grouped-
+    # query attention at the model's one head count and width
+    latent: Optional["LatentKind"] = None
+
+    @property
+    def pass_tokens(self) -> Optional[int]:
+        """The tokens one pass over a cache of this kind writes before its
+        queries read (None: any number). A pass of ``T`` tokens from
+        position ``p`` overwrites the row of position ``p + T - 1 - ring``
+        at the latest, and its first query still sees ``p - window + 1``:
+        ``T <= ring - window + 1`` (models/kind_attention.py ``KindCache``
+        refuses more). Of that bound the largest EVEN number, so that
+        passes tile a prompt's bucket: a window of 4,096 in a ring of 4,352
+        gives passes of 256, not 257; a window of 513 in a ring of 1,024
+        gives 512."""
+        if self.ring is None:
+            return None
+        most = self.ring - self.window + 1
+        return max(1, most - most % 2)
 
 
 def attention_cache_leaves(cfg=None) -> Tuple[CacheLeaf, ...]:
@@ -373,10 +396,15 @@ class IndexerConfig:
     # the tiling of a pass of many queries; change no value
     q_chunk: int = 512
     kv_chunk: int = 512
+    # how many of each index head's dimensions rotary turns, the first
+    # ones (DeepSeek-V3.2-Exp: its ``qk_rope_head_dim`` of 128); None = all
+    rope_dim: Optional[int] = None
 
     def __post_init__(self):
         if self.head_dim % 2 or min(self.n_heads, self.topk, self.q_chunk,
-                                    self.kv_chunk) < 1:
+                                    self.kv_chunk) < 1 or (
+                self.rope_dim is not None
+                and not 0 < self.rope_dim <= self.head_dim):
             raise ValueError(f"no indexer has these sizes: {self}")
 
     def cache_leaves(self, cfg) -> Tuple[CacheLeaf, ...]:
@@ -425,6 +453,57 @@ class IndexerConfig:
     @property
     def weight_scale(self) -> float:
         return self.n_heads ** -0.5 * self.head_dim ** -0.5
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentKind:
+    """Latent attention as ONE KIND of layer's mixer, in a stack whose
+    attention layers differ by kind (``GPTConfig.latent_kinds``, read back
+    through ``GPTConfig.attention_kind``; run by models/latent_attention.py
+    ``KindLatentAttention``): everything the whole-model fields ``n_head``,
+    ``mla``, ``rope_theta`` and ``indexer`` say of a stack of one kind, said
+    of the kind, so that 128 heads over a 512-wide latent read through an
+    indexer can stand beside 64 heads over a 1,024-wide latent that sees a
+    window. The window itself (``sliding_window``, ``window_slack``) stays
+    where the ``"window"`` kind has it."""
+    n_head: int
+    mla: MLAConfig
+    rope_theta: float
+    # a lightning indexer whose queries come from the QUERY LATENT ``c_q``
+    # (DeepSeek-V3.2-Exp) and whose chosen rows are latents; None = every
+    # position the kind sees
+    indexer: Optional[IndexerConfig] = None
+    # one sigmoid gate a head on the heads' outputs before ``c_proj``, from
+    # the layer's normalised input through ``c_gate`` ``[C, n_head]``
+    head_gate: bool = False
+    # the two normed latents times ``(n_embd / rank) ** 0.5``, each with
+    # its own rank (LongCat-Flash's ``mla_scale_q_lora`` / ``_kv_lora``)
+    rank_rescale: bool = False
+
+    def up_init(self, n_embd: int):
+        """How the matrices that read one of the kind's normed latents are
+        born (``q_b``, ``kv_b``, the indexer's ``wq``). A RESCALED latent
+        (``rank_rescale``) is ``(n_embd / rank) ** 0.5`` times a unit one,
+        the size a projection of the hidden state has, so its readers are
+        born at the hidden size's scale, as readers of the hidden state
+        are; at their own fan-in's scale the scores' spread is ``n_embd /
+        rank`` too wide and the softmax a near-argmax that bf16 rounding
+        flips (PERF.md, PR 59: the readings at both)."""
+        return nn.initializers.normal(n_embd ** -0.5) if self.rank_rescale \
+            else nn.initializers.lecun_normal()
+
+    def cache_leaves(self, cfg, held_by, window: bool
+                     ) -> Tuple[CacheLeaf, ...]:
+        """The kind's latent and rotary key (a ring's rows counted as
+        ``window`` too) and, with an indexer, its key and what a step
+        leaves of its selection, each held by ``held_by``."""
+        counted = ("latent", "window") if window else ("latent",)
+        leaves = tuple(dataclasses.replace(leaf, counted_as=counted)
+                       for leaf in self.mla.cache_leaves(cfg))
+        if self.indexer is not None:
+            leaves += self.indexer.cache_leaves(cfg)
+        return tuple(dataclasses.replace(leaf, held_by=held_by)
+                     for leaf in leaves)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -619,10 +698,10 @@ class GPTConfig:
     # is a stack of ONE kind, chosen by the whole-model fields below
     # (``mla``, ``retention``, else attention, with ``ssm`` beside it and
     # ``indexer`` inside it) and run by ``ScannedBlocks``. A tuple of
-    # ``n_layer`` kinds ("attention" | "conv") is a stack that MIXES kinds
-    # (models/kind_stacks.py ``KindStackedBlocks``): parameters and cache
-    # leaves are stacked per kind, so an attention layer keeps keys and
-    # values and no tail, a convolution layer a tail and no keys
+    # ``n_layer`` kinds ("attention" | "conv" | "window") is a stack that
+    # MIXES kinds (models/kind_stacks.py ``KindStackedBlocks``): parameters
+    # and cache leaves are stacked per kind, so an attention layer keeps
+    # keys and values and no tail, a convolution layer a tail and no keys
     layer_types: Optional[Tuple[str, ...]] = None
     # the "conv" kind's mixer: a gated short convolution (LFM2)
     short_conv: Optional[ShortConvConfig] = None
@@ -642,6 +721,12 @@ class GPTConfig:
     # such a stack's attention output times the sigmoid of a projection
     # ``c_gate`` of the layer's normalised input, before ``c_proj``
     attn_output_gate: bool = False
+    # such a stack's attention layers as LATENT attention, each kind with
+    # its own heads, ranks, widths, ``rope_theta`` and indexer: ``((kind,
+    # LatentKind), ...)`` for "attention" and "window" both (``n_head``,
+    # ``mla``, ``indexer`` and ``rope_theta`` then say nothing of a layer).
+    # Read back through ``attention_kind`` alone
+    latent_kinds: Optional[Tuple[Tuple[str, LatentKind], ...]] = None
     # a norm on the mixer's output and one on the MLP's, each before its
     # residual sum (``ln_1_post``, ``ln_2_post``): four norms a layer
     post_norms: bool = False
@@ -809,11 +894,12 @@ class GPTConfig:
         if (KIND_WINDOW not in (self.layer_types or ())
                 and (self.sliding_window is not None or self.window_slack
                      or self.rotary_kinds is not None
-                     or self.attn_output_gate)):
+                     or self.attn_output_gate
+                     or self.latent_kinds is not None)):
             raise ValueError(
-                "sliding_window, window_slack, rotary_kinds and "
-                "attn_output_gate belong to a stack with layers of kind "
-                f"{KIND_WINDOW!r} (layer_types)")
+                "sliding_window, window_slack, rotary_kinds, "
+                "attn_output_gate and latent_kinds belong to a stack with "
+                f"layers of kind {KIND_WINDOW!r} (layer_types)")
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(
                 f"qk_norm must be False, True or 'head'; got "
@@ -863,23 +949,56 @@ class GPTConfig:
                 "beside rows that stay where they were written; a ring's "
                 "rows are overwritten in turn and models/kind_attention.py "
                 "stores and reads the compute dtype alone")
+        if self.latent_kinds is not None:
+            declared = dict(self.latent_kinds)
+            attending = kinds & {KIND_ATTENTION, KIND_WINDOW}
+            if set(declared) != attending or len(declared) != len(
+                    self.latent_kinds) or not all(
+                    isinstance(k, LatentKind) for k in declared.values()):
+                raise ValueError(
+                    "latent_kinds declares a LatentKind for each kind of "
+                    f"attention layer the stack has ({sorted(attending)}), "
+                    f"once; got {self.latent_kinds!r}")
+            taken = [name for name, on in (
+                ("rotary=False", not self.rotary),
+                ("rotary_kinds", self.rotary_kinds is not None),
+                ("attn_output_gate", self.attn_output_gate),
+                ("qk_norm", self.qk_norm), ("n_kv_head", self.n_kv_head),
+                ("rotary_interleaved", self.rotary_interleaved)) if on]
+            if taken:
+                raise ValueError(
+                    "latent attention by kind (models/latent_attention.py "
+                    "KindLatentAttention) takes its positions from each "
+                    "kind's rotary part, gates a head where the kind says "
+                    f"so and has no keys or values per head; not: {taken}")
+            if KIND_WINDOW in declared and \
+                    declared[KIND_WINDOW].indexer is not None:
+                raise ValueError(
+                    "an indexer chooses among every cached position; a "
+                    f"{KIND_WINDOW!r} layer's ring holds its window alone")
 
     def attention_kind(self, mixer: Optional[str]) -> Optional[AttentionKind]:
         """What the attention of a layer of kind ``mixer`` is, in a stack
         whose attention layers differ by kind (one that has ``"window"``
         layers); None everywhere else: the whole-model fields say it and
         ``CausalSelfAttention`` runs it. The one place that knows which
-        kind has a window, a ring or rotary."""
+        kind has a window, a ring or rotary and, where the kinds are latent
+        attention (``latent_kinds``), the kind's heads, ranks, widths,
+        ``rope_theta`` and indexer."""
         if self.layer_types is None or KIND_WINDOW not in self.layer_types \
                 or mixer not in (KIND_WINDOW, KIND_ATTENTION):
             return None
         rotary = self.rotary and (self.rotary_kinds is None
                                   or mixer in self.rotary_kinds)
+        latent = dict(self.latent_kinds or ()).get(mixer)
         if mixer == KIND_WINDOW:
             return AttentionKind(
                 self.sliding_window, self.sliding_window + self.window_slack,
-                rotary, SCOPE_WINDOW_ATTN)
-        return AttentionKind(None, None, rotary, SCOPE_FULL_ATTN)
+                rotary, SCOPE_WINDOW_ATTN if latent is None
+                else SCOPE_WINDOW_LATENT_ATTN, latent)
+        return AttentionKind(None, None, rotary, SCOPE_FULL_ATTN
+                             if latent is None else SCOPE_SPARSE_LATENT_ATTN,
+                             latent)
 
     @property
     def pass_tokens(self) -> Optional[int]:
@@ -890,7 +1009,7 @@ class GPTConfig:
         ``[pass, n_positions]`` scores a head, never ``[T, T]``
         (inference/engine.py ``prefill_chunk_spans``)."""
         kind = self.attention_kind(KIND_WINDOW)
-        return None if kind is None else max(1, kind.ring - kind.window)
+        return None if kind is None else kind.pass_tokens
 
     @property
     def cache_leaves(self) -> Tuple[CacheLeaf, ...]:
@@ -902,13 +1021,19 @@ class GPTConfig:
         if self.layer_types is not None:
             # by kind of layer: each leaf says which kind holds it
             kinds = set(self.layer_types)
-            return tuple(
-                dataclasses.replace(leaf, held_by=KIND_ATTENTION)
-                for leaf in attention_cache_leaves(self)
-                if KIND_ATTENTION in kinds) + (
-                window_cache_leaves() if KIND_WINDOW in kinds else ()) + (
-                self.short_conv.cache_leaves(self)
-                if KIND_CONV in kinds else ())
+            if self.latent_kinds is not None:
+                attention = tuple(
+                    leaf for mixer, kind in self.latent_kinds
+                    for leaf in kind.cache_leaves(
+                        self, mixer, window=mixer == KIND_WINDOW))
+            else:
+                attention = tuple(
+                    dataclasses.replace(leaf, held_by=KIND_ATTENTION)
+                    for leaf in attention_cache_leaves(self)
+                    if KIND_ATTENTION in kinds) + (
+                    window_cache_leaves() if KIND_WINDOW in kinds else ())
+            return attention + (self.short_conv.cache_leaves(self)
+                                if KIND_CONV in kinds else ())
         attention = (self.mla.cache_leaves(self) if self.mla is not None
                      else () if self.retention is not None
                      else attention_cache_leaves(self))
@@ -1156,11 +1281,19 @@ def decode_attention_block(cfg, T: int = 1):
             or (cfg.indexer is not None and cfg.indexer.engaged(cfg))):
         return None
     itemsize = jnp.dtype(cfg.dtype).itemsize
-    if cfg.mla is not None:
+    full = cfg.attention_kind(KIND_ATTENTION)
+    latent = getattr(full, "latent", None)
+    if latent is not None:
+        # latent attention by kind: the layers that keep every position
+        # answer (a window kind's ring is read whole once it has turned)
+        if latent.indexer is not None and latent.indexer.engaged(cfg):
+            return None
+    mla = cfg.mla if latent is None else latent.mla
+    if mla is not None:
         from deepspeed_tpu.ops.pallas import latent_decode_attention
 
         return latent_decode_attention.block_positions(
-            cfg.n_positions, cfg.mla.kv_rank, itemsize)
+            cfg.n_positions, mla.kv_rank, itemsize)
     return block_positions(cfg.n_positions, cfg.kv_heads, cfg.head_dim,
                            itemsize)
 
@@ -1725,7 +1858,11 @@ class Block(nn.Module):
         elif (kind := cfg.attention_kind(self.mixer)) is not None:
             # attention layers that differ by kind: a window and rotary,
             # or neither, as the layer's kind declares
-            from deepspeed_tpu.models.kind_attention import KindAttention
+            if kind.latent is not None:
+                from deepspeed_tpu.models.latent_attention import \
+                    KindLatentAttention as KindAttention
+            else:
+                from deepspeed_tpu.models.kind_attention import KindAttention
 
             a = KindAttention(cfg, kind, name="attn")(
                 u, mask=mask, segment_ids=segment_ids, decode=decode,
@@ -2404,6 +2541,21 @@ def cross_entropy_loss(logits, labels, mask=None, segment_ids=None):
                                  w.reshape(b * t))
 
 
+def _latent_kind_params(C: int, kind: LatentKind) -> int:
+    """One layer's attention parameters of a latent kind, exactly."""
+    m, H, ix = kind.mla, kind.n_head, kind.indexer
+    n = (C * m.q_rank + m.q_rank + m.q_rank * H * m.qk_dim
+         + C * (m.kv_rank + m.rope_dim) + m.kv_rank
+         + m.kv_rank * H * (m.nope_dim + m.v_dim) + H * m.v_dim * C)
+    if kind.head_gate:
+        n += C * H
+    if ix is not None:
+        # wq from the query latent, wk and its LayerNorm, weights_proj
+        n += (m.q_rank * ix.n_heads * ix.head_dim + C * ix.head_dim
+              + 2 * ix.head_dim + C * ix.n_heads)
+    return n
+
+
 def num_params(config: GPTConfig) -> int:
     """Approximate parameter count (for flops accounting); tracks the
     architecture-family knobs (GQA, gated MLP, untied head, biases)."""
@@ -2427,8 +2579,13 @@ def num_params(config: GPTConfig) -> int:
             # per-head norm weights
             attn += C * H * D + (2 * D if cfg.qk_norm == "head" else 0)
         norms = (4 if cfg.post_norms else 2) * norm_p
+        attending = (L - n_conv) * attn
+        if cfg.latent_kinds is not None:
+            attending = sum(cfg.layer_types.count(mixer)
+                            * _latent_kind_params(C, kind)
+                            for mixer, kind in cfg.latent_kinds)
         return (V * C + L * (mlp + norms) + n_conv * conv
-                + (L - n_conv) * attn + norm_p
+                + attending + norm_p
                 + (0 if cfg.tie_word_embeddings else C * V))
     if cfg.retention is not None:
         # the gate's kernel and bias, q's and k's per-head norm

@@ -56,6 +56,9 @@ from deepspeed_tpu.telemetry.scopes import (
     SCOPE_DSA_ATTN,
     SCOPE_DSA_INDEX_SCORES,
     SCOPE_DSA_SELECT,
+    SCOPE_LATENT_INDEX,
+    SCOPE_LATENT_SELECT,
+    SCOPE_SPARSE_LATENT_ATTN,
 )
 
 _NEG = float("-inf")
@@ -305,9 +308,44 @@ def decode_step(q, q_idx, w, keys, values, index_keys, layer, visible,
     return y.reshape(B, H, D), rows, ok
 
 
+def latent_decode_step(q_lat, q_rope, q_idx, w, latent, rope_key,
+                       index_keys, layer, visible, topk: int, scale, dtype):
+    """:func:`decode_step` over a latent cache: scores over the layer's
+    index keys, the choice, and the ABSORBED form of latent attention
+    (models/latent_attention.py) over the chosen latents: the latent decode
+    kernel over the lanes' live blocks under the chosen mask
+    (``mask_plan``). One fetch, no rule: on the v5e at 48 lanes, 128 heads
+    over 512 + 64 and 2,048 chosen, the kernel cost 0.55-3.12 ms with
+    3,072-24,576 live positions a lane where two gathers of the chosen
+    rows and the einsums cost 4.8 ms whatever was live (PERF.md, PR 59), so
+    no cache the benchmark runs would gather.
+    ``q_lat [B, H, r]``, ``q_rope [B, H, dr]``, ``q_idx [B, Hi, Di]``, ``w
+    [B, Hi]``, the three stacked leaves, ``visible [B, S]``. Returns
+    ``(o_lat [B, H, r], rows, ok)``."""
+    from deepspeed_tpu.ops.pallas import latent_decode_attention as lda
+
+    S, r = visible.shape[1], latent.shape[-1]
+    with jax.named_scope(SCOPE_LATENT_INDEX):
+        k_idx = index_keys if layer is None else \
+            jax.lax.dynamic_index_in_dim(index_keys, layer, 0,
+                                         keepdims=False)
+        scores = index_scores(q_idx[:, None], k_idx, w[:, None])[:, 0]
+    with jax.named_scope(SCOPE_LATENT_SELECT):
+        chosen = chosen_set(scores, visible, topk)
+        rows, ok = choose(scores, visible, topk)
+    with jax.named_scope(SCOPE_SPARSE_LATENT_ATTN):
+        block = lda.block_positions(S, r, latent.dtype.itemsize)
+        o_lat = lda.latent_decode_attention(
+            q_lat, q_rope, latent, rope_key, lda.mask_plan(chosen, block),
+            layer, scale=scale).astype(dtype)
+    return o_lat, rows, ok
+
+
 def attend_tiled(q, k, v, q_idx, k_idx, w, q_pos, k_valid, topk: int,
                  q_chunk: int, kv_chunk: int, scale, dtype,
-                 keep_mask: bool = False):
+                 keep_mask: bool = False, live_tiles=None,
+                 scopes=(SCOPE_DSA_INDEX_SCORES, SCOPE_DSA_SELECT,
+                         SCOPE_DSA_ATTN)):
     """Many query tokens over ``S`` keys: ``q [B, T, H, D]``, ``k`` / ``v``
     ``[B, S, Hkv, D]``, ``q_idx [B, T, Hi, Di]``, ``k_idx [B, S, Di]``,
     ``w [B, T, Hi]``; ``q_pos [B, T]`` the key row each query sits at (it
@@ -316,9 +354,17 @@ def attend_tiled(q, k, v, q_idx, k_idx, w, q_pos, k_valid, topk: int,
     with ``keep_mask`` (tests), else None. A scan over query tiles of
     ``q_chunk``; inside one, the tile's scores over all keys, its chosen
     positions, and an online softmax over key tiles of ``kv_chunk``, whose
-    running maximum, sum and weighted values are float32."""
+    running maximum, sum and weighted values are float32. The values may be
+    of another width than the keys (the absorbed form of latent attention:
+    one "KV head" whose key is ``[latent | rotary key]`` and whose value is
+    the latent). ``live_tiles`` (a scalar, traced or not): walk the first
+    ``live_tiles`` key tiles alone, where the caller knows that no query sees a
+    row past them (a prefill pass over a long cache); ``scopes``: the
+    three scopes the index scores, the choice and the attention are timed
+    under."""
     B, T, H, D = q.shape
-    S, Hkv = k.shape[1], k.shape[2]
+    S, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
+    scope_scores, scope_select, scope_attn = scopes
     G = H // Hkv
     c, kc = min(q_chunk, T), min(kv_chunk, S)
     n_q, n_k = -(-T // c), -(-S // kc)
@@ -344,13 +390,13 @@ def attend_tiled(q, k, v, q_idx, k_idx, w, q_pos, k_valid, topk: int,
 
     def q_tile(_, xs):
         qt, qit, wt, pt = xs
-        with jax.named_scope(SCOPE_DSA_INDEX_SCORES):
+        with jax.named_scope(scope_scores):
             scores = index_scores(qit, k_idx, wt)               # [B, c, Sp]
-        with jax.named_scope(SCOPE_DSA_SELECT):
+        with jax.named_scope(scope_select):
             visible = ((jnp.arange(Sp)[None, None, :] <= pt[:, :, None])
                        & k_valid[:, None, :])
             chosen = chosen_mask(scores, visible, topk)
-        with jax.named_scope(SCOPE_DSA_ATTN):
+        with jax.named_scope(scope_attn):
             qg = qt.reshape(B, c, Hkv, G, D)
 
             def kv_tile(state, j):
@@ -374,15 +420,20 @@ def attend_tiled(q, k, v, q_idx, k_idx, w, q_pos, k_valid, topk: int,
 
             init = (jnp.full((B, Hkv, G, c), _NEG, jnp.float32),
                     jnp.zeros((B, Hkv, G, c), jnp.float32),
-                    jnp.zeros((B, Hkv, G, c, D), jnp.float32))
-            (_, l, acc), _ = jax.lax.scan(kv_tile, init, jnp.arange(n_k))
+                    jnp.zeros((B, Hkv, G, c, Dv), jnp.float32))
+            if live_tiles is None:
+                (_, l, acc), _ = jax.lax.scan(kv_tile, init, jnp.arange(n_k))
+            else:
+                _, l, acc = jax.lax.fori_loop(
+                    0, jnp.minimum(live_tiles, n_k),
+                    lambda j, state: kv_tile(state, j)[0], init)
             y = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
-            y = jnp.moveaxis(y, 3, 1).reshape(B, c, H, D).astype(dtype)
+            y = jnp.moveaxis(y, 3, 1).reshape(B, c, H, Dv).astype(dtype)
         return None, (y, chosen if keep_mask else None)
 
     _, (y, chosen) = jax.lax.scan(
         q_tile, None, tuple(tiles(t) for t in (q, q_idx, w, q_pos)))
-    y = jnp.moveaxis(y, 0, 1).reshape(B, n_q * c, H, D)[:, :T]
+    y = jnp.moveaxis(y, 0, 1).reshape(B, n_q * c, H, Dv)[:, :T]
     if keep_mask:
         chosen = jnp.moveaxis(chosen, 0, 1).reshape(B, n_q * c, Sp)[:, :T, :S]
     return y, chosen

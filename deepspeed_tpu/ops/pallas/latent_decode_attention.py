@@ -41,7 +41,11 @@ compiled for a described v5e: 0.96 GB of temporaries).
 **What is the same for every layer of a step is made once a step**
 (:func:`step_plan`): the first valid row and the clamped clock of each
 lane, the work items, and the mask ``valid & (position <= clock)`` as
-float32 flags. The layers' calls share it.
+float32 flags. The layers' calls share it. A layer whose rows are its own
+(:func:`mask_plan`: a window kind's ring, 64 heads over 1,024 + 64 at
+dots3's widths, or the rows an indexer chose of a dense latent leaf) makes
+its plan from its mask; the body takes any head count, rank and rotary
+width that its blocks fit beside.
 
 A block need not divide the cache's length: the last block of a cache is
 then ragged, its rows past the end masked and their (unfetched) values
@@ -109,6 +113,24 @@ def step_plan(valid, index, block: int) -> StepPlan:
         first, clock, block, -(-S // block))
     visible = valid & (jnp.arange(S, dtype=jnp.int32)[None, :]
                        <= clock[:, None])
+    return StepPlan(count, item_lane, item_block,
+                    visible.astype(jnp.float32)[:, None, :], block)
+
+
+def mask_plan(visible, block: int) -> StepPlan:
+    """The grid and the mask of a step whose lanes each see the rows
+    ``visible`` ``[B, S]`` marks, wherever they lie: a window layer's ring
+    (rows in no order of position: ``valid &`` what the window holds, by
+    ``slot_pos``) or the set an indexer chose. A lane's blocks run from its
+    first visible row to its last; a lane that sees nothing still names one
+    block, masked whole."""
+    S = visible.shape[1]
+    first = jnp.argmax(visible, axis=1).astype(jnp.int32)
+    last = jnp.where(jnp.any(visible, axis=1),
+                     S - 1 - jnp.argmax(visible[:, ::-1], axis=1),
+                     first).astype(jnp.int32)
+    count, item_lane, item_block = _per_head.work_items(
+        first, last, block, -(-S // block))
     return StepPlan(count, item_lane, item_block,
                     visible.astype(jnp.float32)[:, None, :], block)
 

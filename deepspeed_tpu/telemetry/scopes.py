@@ -127,6 +127,19 @@ SCOPE_DSA_ATTN = "dsa_attn"
 # whole leaf that no scope owns is ``kv_cache_carry``'s
 SCOPE_WINDOW_ATTN = "window_attn"
 SCOPE_FULL_ATTN = "full_attn"
+# the same stack with LATENT attention as each kind's mixer (models/
+# latent_attention.py ``KindLatentAttention``; ``GPTConfig.latent_kinds``):
+# the absorbed form's scores, softmax, weighted sum and the heads' gate
+# over a window layer's ring of latents, and over the rows a full layer's
+# indexer chose; that indexer's projections (its query from the query
+# latent) and its scores over a lane's index keys; the choice of the best
+# positions. The projections around them keep latent attention's scopes
+# (``mla_q_proj`` ... ``mla_out_proj``); the ``dsa_*`` scopes stay the
+# selection over keys and values per head alone
+SCOPE_WINDOW_LATENT_ATTN = "window_latent_attn"
+SCOPE_SPARSE_LATENT_ATTN = "sparse_latent_attn"
+SCOPE_LATENT_INDEX = "latent_index"
+SCOPE_LATENT_SELECT = "latent_select"
 # JAX's own name-stack component of a rematerialised (recomputed) operation;
 # ``checkpoint`` alone is also on the backward pass of a checkpointed region
 SCOPE_REMAT = "rematted_computation"
@@ -154,7 +167,9 @@ _CARRY_FREE = frozenset((
     SCOPE_RET_STATE, SCOPE_RET_OUT_PROJ, SCOPE_MLA_Q_PROJ,
     SCOPE_MLA_KV_PROJ, SCOPE_MLA_ABSORB, SCOPE_MLA_ATTN,
     SCOPE_MLA_OUT_PROJ, SCOPE_DSA_INDEX_PROJ, SCOPE_DSA_INDEX_SCORES,
-    SCOPE_DSA_SELECT, SCOPE_DSA_ATTN, SCOPE_WINDOW_ATTN, SCOPE_FULL_ATTN))
+    SCOPE_DSA_SELECT, SCOPE_DSA_ATTN, SCOPE_WINDOW_ATTN, SCOPE_FULL_ATTN,
+    SCOPE_WINDOW_LATENT_ATTN, SCOPE_SPARSE_LATENT_ATTN, SCOPE_LATENT_INDEX,
+    SCOPE_LATENT_SELECT))
 _STRUCTURE = re.compile(
     r"^(jit\(.*\)|pjit\(.*\)|while|body|cond|branch_\d+_fun|closed_call|"
     r"core_call|custom_jvp_call|custom_vjp_call|custom_vjp_call_jaxpr)$")

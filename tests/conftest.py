@@ -32,7 +32,8 @@ import pytest  # noqa: E402
 
 # The benchmark's rehearsal tables (tests/perfbench/rehearsal.py) predate
 # the hybrid, the attention-free, the latent-attention, the
-# selected-attention, the mixed-kinds and the window-and-full serve cells, and
+# selected-attention, the mixed-kinds, the window-and-full and the
+# two-latent-kinds serve cells, and
 # both they and tests/perfbench/conftest.py are the benchmark's own files.
 # Each cell's tiny stand-in is data in a new file
 # beside its tests and is registered from here: this conftest is loaded
@@ -43,6 +44,7 @@ if _PERFBENCH_TESTS not in sys.path:
     sys.path.insert(0, _PERFBENCH_TESTS)
 import brumby_tiny  # noqa: E402
 import deepseek_v2_tiny  # noqa: E402
+import dots3_tiny  # noqa: E402
 import falcon_h1_tiny  # noqa: E402
 import keye_vl_tiny  # noqa: E402
 import lfm2_tiny  # noqa: E402
@@ -55,13 +57,15 @@ deepseek_v2_tiny.register(rehearsal)
 keye_vl_tiny.register(rehearsal)
 lfm2_tiny.register(rehearsal)
 trinity_tiny.register(rehearsal)
+dots3_tiny.register(rehearsal)
 _PREDATE_REDUCED = {
     falcon_h1_tiny.PREDATES_REDUCED: "test_perfbench_falcon_h1.py",
     brumby_tiny.PREDATES_REDUCED: "test_perfbench_brumby.py",
     deepseek_v2_tiny.PREDATES_REDUCED: "test_perfbench_deepseek_v2.py",
     keye_vl_tiny.PREDATES_REDUCED: "test_perfbench_keye_vl.py",
     lfm2_tiny.PREDATES_REDUCED: "test_perfbench_lfm2.py",
-    trinity_tiny.PREDATES_REDUCED: "test_perfbench_trinity.py"}
+    trinity_tiny.PREDATES_REDUCED: "test_perfbench_trinity.py",
+    dots3_tiny.PREDATES_REDUCED: "test_perfbench_dots3.py"}
 
 
 def pytest_collection_modifyitems(items):

@@ -313,7 +313,7 @@ def test_the_rule_compares_the_two_costs_and_is_set_by_nothing_else():
         "q", "q_idx", "w", "keys", "values", "index_keys", "layer",
         "visible", "clock", "topk", "dtype"}
     assert set(IndexerConfig.__dataclass_fields__) == {
-        "n_heads", "head_dim", "topk", "q_chunk", "kv_chunk"}
+        "n_heads", "head_dim", "topk", "q_chunk", "kv_chunk", "rope_dim"}
     assert "environ" not in inspect.getsource(ia)
 
 
