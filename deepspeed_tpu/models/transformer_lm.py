@@ -24,7 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.moe.layer import MOE_STATS
-from deepspeed_tpu.runtime.zero.gather import gather_tree, gathered_on_use
+from deepspeed_tpu.runtime.zero.gather import (
+    current_plan, gather_tree, gathered_on_use, layers_ahead, layers_per_turn)
 from deepspeed_tpu.telemetry.scopes import (
     SCOPE_ATTN_CORE,
     SCOPE_CONV_STATE_CARRY,
@@ -2058,6 +2059,47 @@ def _maybe_gathered_block(block_cls, cfg, path, stacked=None):
                            keep_dtype=_GATHERED_AS_STORED)
 
 
+def _maybe_layers_ahead(owner, block_cls, cfg, n_scanned, x, mask,
+                        segment_ids, positions, deterministic, plain):
+    """Under a ZeRO-3 step program over ``fsdp > 1`` whose two prefetch
+    keys allow it, ``owner``'s scanned stack run with each layer's first
+    weight gathered a turn early (runtime/zero/gather.py ``layers_ahead``):
+    ``(x, l_aux)``. ``plain`` says that the call is one that loop can run:
+    a pass over whole sequences with no cache, no lane and no layer drop.
+    The loop recomputes a whole layer in its backward pass, so the
+    configuration must ask for that (``remat`` with the ``full`` policy),
+    and it slices the stack itself, so the parameters must be the plain
+    floating leaves on the device. Anywhere else, and at ``init``: ``None``,
+    and the caller builds the scan it always built."""
+    if current_plan() is None or owner.is_initializing():
+        return None
+    stack = owner.get_variable("params", "block")
+    ahead = layers_per_turn(
+        stack, owner.path + ("block",),
+        n_scanned, cfg.dtype, uses=2 if cfg.remat else 1,
+        keep_dtype=_GATHERED_AS_STORED,
+        may_hand_on=(plain and cfg.remat and cfg.remat_policy == "full"
+                     and not cfg.param_offload
+                     and not cfg.quantized_weights))
+    if ahead is None:
+        return None
+    rngs = {name: owner.make_rng(name) for name in ("dropout", "gating")
+            if owner.has_rng(name)}
+
+    def layer(params, x, i, consts):
+        mask, segment_ids, positions, rngs = consts
+        return block_cls(cfg, parent=None).apply(
+            {"params": params}, x, mask=mask, segment_ids=segment_ids,
+            positions=positions, deterministic=deterministic,
+            rngs={name: jax.random.fold_in(key, i)
+                  for name, key in rngs.items()})
+
+    x, l_aux = layers_ahead(
+        layer, stack, x, (mask, segment_ids, positions, rngs), n_scanned,
+        ahead)
+    return x, jnp.sum(l_aux)
+
+
 def _maybe_in_place_experts(body, owner, cfg, rows, decode):
     """``body`` (one turn of ``owner``'s layer scan) with the expert
     matrices read where they lie in the stacked parameters, where
@@ -2237,6 +2279,11 @@ class ScannedBlocks(nn.Module):
                 block_cls, "params", trans_in_fn=stream_tree_to_device,
                 init=True)  # composes: stream int8-at-rest, dequant inner
 
+        ahead = _maybe_layers_ahead(
+            self, block_cls, cfg, n_scanned, x, mask, segment_ids, positions,
+            deterministic, plain=not (decode or use_pld or lane is not None))
+        if ahead is not None:
+            return ahead[0], ahead[1] + dense_aux
         scanned = nn.scan(
             _maybe_in_place_experts(
                 body, self, cfg, x.shape[0] * x.shape[1] * cfg.moe_top_k,

@@ -99,9 +99,16 @@ class ZeroConfig(ConfigModel):
     sub_group_size: int = 1_000_000_000
     cpu_offload: bool = False  # deprecated alias handled in __post_init__validate__
     cpu_offload_param: bool = False  # deprecated alias (reference zero/config.py)
+    # elements that may be gathered ahead of use. Read by
+    # runtime/zero/gather.py ``turn_length`` (through ZeroShardingRules):
+    # where a layer's first matrix and the stack's vectors fit, a layer
+    # loop's turn gathers the next layer's first weight; 0 gathers nothing
+    # ahead and gives the one-layer turn of before PR 60.
     prefetch_bucket_size: int = 50_000_000
     param_persistence_threshold: int = 100_000
     model_persistence_threshold: int = 2 ** 62
+    # the cap on gathered elements whole at once. Read with the key above:
+    # below two layers' gathered elements nothing is gathered ahead.
     max_live_parameters: int = 1_000_000_000
     max_reuse_distance: int = 1_000_000_000
     gather_16bit_weights_on_model_save: bool = False

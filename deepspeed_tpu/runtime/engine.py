@@ -352,6 +352,8 @@ class DeepSpeedEngine:
             param_persistence_threshold=(
                 config.zero_config.param_persistence_threshold),
             tp_rules=getattr(model, "tp_rules", None),
+            prefetch_bucket_size=config.zero_config.prefetch_bucket_size,
+            max_live_parameters=config.zero_config.max_live_parameters,
         )
 
         self.fp16_enabled = config.fp16.enabled

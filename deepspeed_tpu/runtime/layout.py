@@ -70,7 +70,9 @@ def apply_zero_fsdp_move(topology: MeshTopology, zero_stage: int,
 
 def build_sharding_rules(topology: MeshTopology, zero_stage: int,
                          param_persistence_threshold: int = 0,
-                         tp_rules: Optional[Callable] = None
+                         tp_rules: Optional[Callable] = None,
+                         prefetch_bucket_size: int = 0,
+                         max_live_parameters: int = 0
                          ) -> ZeroShardingRules:
     """The per-leaf layout policy for this (topology, stage) pair."""
     return ZeroShardingRules(
@@ -79,6 +81,8 @@ def build_sharding_rules(topology: MeshTopology, zero_stage: int,
         param_persistence_threshold=(
             param_persistence_threshold if zero_stage >= 3 else 0),
         tp_rules=tp_rules,
+        prefetch_bucket_size=prefetch_bucket_size,
+        max_live_parameters=max_live_parameters,
     )
 
 

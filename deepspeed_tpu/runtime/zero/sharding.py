@@ -33,7 +33,11 @@ activations instead (five ``all-to-all`` a layer, 178 ms of a 782 ms step
 exposed on four v5e chips: PERF.md, PR 24). ``gather.py`` says at the use
 site what the stored sharding means; XLA then schedules the gathers and
 reduce-scatters under the layer's matmuls (27.7 ms exposed of 497 ms,
-PERF.md, PR 29). Prefetching layer i+1 under layer i is still not done.
+PERF.md, PR 29). What stayed exposed was the gather at the head of a loop
+turn, which nothing can be scheduled across; since PR 60, where
+``stage3_prefetch_bucket_size`` and ``stage3_max_live_parameters`` allow
+(gather.py ``turn_length``), turn i of the layer loop issues the gather of
+layer i+1's first weight and hands it on (gather.py ``layers_ahead``).
 
 ``param_persistence_threshold`` (stage3, zero/config.py) maps to ``min_size``:
 small params stay replicated.
@@ -79,11 +83,20 @@ class ZeroShardingRules:
 
     def __init__(self, topo: MeshTopology, stage: int,
                  param_persistence_threshold: int = 0,
-                 tp_rules: Optional[Callable] = None):
+                 tp_rules: Optional[Callable] = None,
+                 prefetch_bucket_size: int = 0,
+                 max_live_parameters: int = 0):
         self.topo = topo
         self.stage = stage
         self.persistence_threshold = param_persistence_threshold
         self.tp_rules = tp_rules
+        # stage 3's ``stage3_prefetch_bucket_size`` and
+        # ``stage3_max_live_parameters``, in elements: whether a turn of a
+        # layer loop gathers for the next layer too (gather.py
+        # ``turn_length``). Rules built without a ZeRO configuration
+        # gather nothing ahead.
+        self.prefetch_bucket_size = prefetch_bucket_size
+        self.max_live_parameters = max_live_parameters
 
     # -- per-leaf specs ----------------------------------------------------
     def _tp_spec(self, path, shape) -> Optional[PartitionSpec]:

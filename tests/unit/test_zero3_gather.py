@@ -5,6 +5,8 @@ and that the mechanism is absent wherever there is nothing to gather."""
 
 import contextlib
 import hashlib
+import json
+import os
 import re
 
 import jax
@@ -69,7 +71,19 @@ MODELS = {"gpt-scan": gpt, "gpt-loop": lambda p: gpt(p, scan_layers=False),
           "bert": bert, "moe": moe}
 
 
-def engine_for(model, stage, topo, precision="fp32", threshold=0, **extra):
+def ahead(n_layer):
+    """What the tiny model's loop gathers ahead of use: the first matrix
+    a layer reads (``c_attn``'s 64 x 192 kernel) for the layer after a
+    turn's own, and every layer's vectors (832 elements) before the loop.
+    A bucket of k - 1 times that lets a turn gather for k layers
+    (``zero3.turn_length``); ``zero3.layers_ahead`` hands on by one turn,
+    so 2 is the most, which DeepSpeed's default of 5e7 gives too."""
+    return 64 * 192 + n_layer * (192 + 256 + 6 * 64)
+
+
+
+def engine_for(model, stage, topo, precision="fp32", threshold=0,
+               layers_per_turn=None, **extra):
     reset_default_topology()
     cfg = {
         "train_micro_batch_size_per_gpu": 2,
@@ -79,6 +93,9 @@ def engine_for(model, stage, topo, precision="fp32", threshold=0, **extra):
                               "stage3_param_persistence_threshold": threshold},
         "steps_per_print": 10 ** 9,
     }
+    if layers_per_turn is not None:
+        cfg["zero_optimization"]["stage3_prefetch_bucket_size"] = (
+            (layers_per_turn - 1) * ahead(model.config.n_layer))
     if PRECISIONS[precision][1] == BF16:
         cfg["bf16"] = {"enabled": True}
     cfg.update(extra)
@@ -135,15 +152,39 @@ def instructions(text, opcode):
 # ---------------------------------------------------------------------------
 # what the compiled stage-3 step contains
 # ---------------------------------------------------------------------------
+def computations(text):
+    """HLO computations by name, each the text of its instructions."""
+    out, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if m:
+            name = m.group(1)
+            out[name] = []
+        elif name is not None:
+            out[name].append(line)
+    return {name: "\n".join(lines) for name, lines in out.items()}
+
+
+def dims_of(result):
+    return [int(d) for d in re.search(r"\[([\d,]*)\]", result).group(1)
+            .split(",") if d]
+
+
+LAYERS, TURN, SEQ = 4, 2, 32
+
+
 @pytest.fixture(scope="module")
 def stage3_step():
     """The stage-3 step of a small scanned GPT with remat over fsdp=4,
-    float32 parameters and bf16 compute (the four-chip cell in small)."""
+    float32 parameters and bf16 compute (the four-chip cell in small): four
+    layers, two a turn of the layer loop. 32 positions, so that no
+    activation has a weight's shape."""
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 virtual devices")
     with plan_events() as events:
-        engine = engine_for(gpt("fp32-bf16"), 3, fsdp4(), "fp32-bf16")
-        data = batches(engine)
+        engine = engine_for(gpt("fp32-bf16", n_layer=LAYERS), 3, fsdp4(),
+                            "fp32-bf16", layers_per_turn=TURN)
+        data = batches(engine, seq=SEQ)
         losses = train(engine, data)
     compiled = engine.compiled_step_programs()["train_step"]
     lowered = engine._train_step_fn.lower(
@@ -174,16 +215,72 @@ def test_each_layer_gathers_its_weights_inside_the_loop(stage3_step):
     assert any("rematted_computation" not in g[2] for g in gathers)
     # one layer at a time: no gathered result carries the layer axis
     for result, _, _ in gathers:
-        dims = [int(d) for d in re.search(r"\[([\d,]*)\]", result).group(1)
-                .split(",") if d]
+        dims = dims_of(result)
         assert len(dims) <= 2 or dims[0] == 1, result
+
+
+def test_a_turn_gathers_the_next_layers_first_weight(stage3_step):
+    """Each loop, forward and backward, gathers ``c_attn``'s kernel (the
+    first weight a layer reads) once before its first turn and once in
+    its body: turn i issues layer i + 1's (i - 1's, walking down) and
+    carries the result to the turn that reads it. The layer's own gathers
+    of a turn, forward and recomputed, are the other three kernels."""
+    text = stage3_step[3]
+    comps = computations(text)
+    c_attn = [64, 3 * 64]
+
+    def heads(text):
+        return [g for g in instructions(text, "all-gather")
+                if "/%s/" % zero3.SCOPE_ZERO3_GATHER in g[2]
+                and dims_of(g[0])[-2:] == c_attn]
+
+    bodies = {body: heads(comps[body])
+              for body in set(re.findall(r"body=%?([\w.\-]+)", text))}
+    bodies = {body: found for body, found in bodies.items() if found}
+    assert len(bodies) == 2, list(bodies)          # forward, backward
+    assert all(len(found) == 1 for found in bodies.values())
+    assert len(heads(text)) == 4                   # two more ahead of them
+    for result, _, name in heads(text):
+        dims = dims_of(result)
+        assert len(dims) <= 2 or set(dims[:-2]) == {1}, result
+        # not one of a layer's own: those are gathered where it recomputes
+        assert "rematted_computation" not in name
+    # and each loop carries ONE whole c_attn kernel from turn to turn
+    for result, _, name in instructions(text, "while"):
+        if name.endswith("/h/while"):
+            carried = [e for e in re.findall(r"\w+\[[\d,]*\]", result)
+                       if dims_of(e)[-2:] == c_attn]
+            assert len(carried) == 1 and len(dims_of(carried[0])) == 2
+
+
+def test_no_gathered_weight_is_saved_for_the_backward_pass(stage3_step):
+    """What the forward loop hands the backward loop is each layer's input
+    and nothing gathered: no loop carries or stacks an array of a whole
+    matrix's shape for more than one layer (ZeRO-3 keeps a quarter)."""
+    text = stage3_step[3]
+    whole = ([64, 3 * 64], [64, 4 * 64], [4 * 64, 64])
+    loops = [r for r in instructions(text, "while")]
+    assert len(loops) >= 2
+    saved_inputs = 0
+    for result, _, _ in loops:
+        for element in re.findall(r"\w+\[[\d,]*\]", result):
+            dims = dims_of(element)
+            # the inputs of every layer but the first, which is the
+            # stack's own input
+            if dims[-3:] == [2, SEQ, 64] \
+                    and np.prod(dims[:-3]) == LAYERS - 1:
+                saved_inputs += 1
+            if dims[-2:] in whole:
+                assert np.prod(dims[:-2]) == 1, element
+    assert saved_inputs >= 2               # saved by one loop, read by the other
 
 
 def test_the_gather_moves_the_compute_dtype(stage3_step):
     """The CPU backend has no bf16 collectives: its compiled text gathers
     float32 and converts after (the TPU's gathers bf16, PERF.md PR 29). So
-    this reads the lowering: every sharding constraint under the scope, in
-    the layer loop, is on a bf16 matrix or a float32 vector."""
+    this reads the lowering: every sharding constraint under the scope is
+    on a bf16 matrix or on float32 vectors (one, or a leaf's for every
+    layer)."""
     lowered = stage3_step[4]
     seen = set()
     for line in lowered.splitlines():
@@ -191,6 +288,8 @@ def test_the_gather_moves_the_compute_dtype(stage3_step):
             continue
         m = re.search(r"tensor<([\dx]*)x(\w+)>", line)
         dims, dtype = m.group(1).split("x"), m.group(2)
+        if len(dims) == 2 and dims[0] == str(LAYERS):
+            dims = dims[1:]   # every layer's vectors, whole ahead of a loop
         seen.add((len(dims), dtype))
     assert (2, "bf16") in seen
     assert (2, "f32") not in seen and (3, "f32") not in seen, seen
@@ -221,6 +320,11 @@ def test_the_plan_event_carries_what_the_plan_implies(stage3_step):
     assert len(events) == 1            # once per program, not per step
     ev = events[0]
     assert ev["program"] == "train_step" and ev["fsdp"] == 4
+    # a turn gathers for two layers, its own and the next one's first
+    # weight: only the gather ahead of each loop, forward and backward,
+    # has nothing of its loop to run under
+    assert ev["layers_per_turn"] == TURN
+    assert ev["gathers_at_turn_head_per_step"] == 2
     rules, n = engine.sharding_rules, 4
     gathered = persistent = g_bytes = s_bytes = 0
     for path, leaf in flatten_with_paths(engine.params).items():
@@ -242,6 +346,22 @@ def test_the_plan_event_carries_what_the_plan_implies(stage3_step):
     assert ev["leaves_persistent"] == persistent
     assert ev["bytes_gathered_per_step"] == g_bytes
     assert ev["bytes_reduce_scattered_per_step"] == s_bytes
+    # the same collectives in another order: with nothing gathered ahead
+    # every layer's first gather, forward and recomputed, waits at a
+    # turn's head; a larger bucket hands on by one turn all the same; and
+    # all of them gather and scatter the same bytes
+    for turn, heads in ((1, 2 * LAYERS), (3, 2)):
+        with plan_events() as other:
+            one = engine_for(gpt("fp32-bf16", n_layer=LAYERS), 3, fsdp4(),
+                             "fp32-bf16", layers_per_turn=turn)
+            place(one, batches(one, n=1, seq=SEQ)[0])
+            step_lowering(one)
+        assert len(other) == 1
+        assert other[0]["layers_per_turn"] == min(turn, 2)
+        assert other[0]["gathers_at_turn_head_per_step"] == heads
+        for key in ("bytes_gathered_per_step",
+                    "bytes_reduce_scattered_per_step", "leaves_gathered"):
+            assert other[0][key] == ev[key], (turn, key)
 
 
 def test_every_matrix_gather_carries_the_scope(stage3_step):
@@ -266,10 +386,19 @@ TOLERANCE = {"fp32": dict(rtol=2e-5, atol=2e-6),
              "bf16": dict(rtol=4e-2, atol=2e-2)}
 
 
-@pytest.mark.parametrize("scan", [True, False], ids=["scan", "loop"])
+# how the layers run: (scanned, layers a turn gathers for, layers). Over
+# two layers the loop ahead has one turn and the layer after it; with
+# DeepSpeed's own bucket (None: the key left alone) a turn gathers for two.
+LAYER_LOOPS = {"scan-k1": (True, 1, 3), "scan-k2": (True, 2, 4),
+               "scan-k2-odd": (True, 2, 3), "scan-k2-two": (True, 2, 2),
+               "scan-default": (True, None, 3), "loop": (False, None, 3)}
+
+
+@pytest.mark.parametrize("loop", list(LAYER_LOOPS))
 @pytest.mark.parametrize("precision", list(PRECISIONS))
-def test_stage3_matches_stage0(eight_devices, precision, scan):
-    base = engine_for(gpt(precision, scan_layers=scan), 0,
+def test_stage3_matches_stage0(eight_devices, precision, loop):
+    scan, turn, layers = LAYER_LOOPS[loop]
+    base = engine_for(gpt(precision, scan_layers=scan, n_layer=layers), 0,
                       MeshTopology(dp=4, devices=jax.devices()[:4]),
                       precision)
     data = batches(base)
@@ -277,9 +406,11 @@ def test_stage3_matches_stage0(eight_devices, precision, scan):
     ref = {k: np.asarray(v, np.float32)
            for k, v in flatten_with_paths(base.params).items()}
 
-    engine = engine_for(gpt(precision, scan_layers=scan), 3, fsdp4(),
-                        precision)
-    losses = train(engine, data)
+    with plan_events() as events:
+        engine = engine_for(gpt(precision, scan_layers=scan, n_layer=layers),
+                            3, fsdp4(), precision, layers_per_turn=turn)
+        losses = train(engine, data)
+    assert events[0]["layers_per_turn"] == ((turn or 2) if scan else 1)
     tol = TOLERANCE[precision]
     np.testing.assert_allclose(losses, ref_losses, **tol)
     for path, leaf in flatten_with_paths(engine.params).items():
@@ -511,6 +642,127 @@ def test_the_hash_does_tell_programs_apart(eight_devices, monkeypatch):
     assert step_lowering(engine) != with_context
 
 
+def pinned_step(precision, layers_per_turn):
+    """Hash of the lowered stage-3 step of the small scanned GPT over
+    fsdp=4 (``python tests/unit/test_zero3_gather.py`` prints what
+    ``data/zero3_step_hashes.json`` holds: recorded on 9107503, the parent
+    of the PR that made a turn hold more than one layer, where the two
+    keys were parsed and read by nothing)."""
+    engine = engine_for(gpt(precision), 3, fsdp4(), precision,
+                        layers_per_turn=layers_per_turn)
+    place(engine, batches(engine, n=1)[0])
+    return step_lowering(engine)
+
+
+@pytest.mark.parametrize("precision", ["fp32-bf16", "bf16"])
+def test_a_prefetch_bucket_of_zero_is_the_one_layer_turn_as_it_was(
+        eight_devices, precision):
+    """``stage3_prefetch_bucket_size: 0``: nothing may be gathered ahead
+    of use, a turn holds one layer, and the step lowers to the byte as it
+    did before a turn could hold more. The control: two layers a turn do
+    not."""
+    with open(os.path.join(os.path.dirname(__file__), "data",
+                           "zero3_step_hashes.json")) as fh:
+        recorded = json.load(fh)
+    assert pinned_step(precision, 1) == recorded[precision]
+    assert pinned_step(precision, 2) != recorded[precision]
+
+
+# ---------------------------------------------------------------------------
+# how many layers a turn holds
+# ---------------------------------------------------------------------------
+# GPT-2 1.3B's layer as the four-chip cell gathers it: c_attn 2048 x 6144
+# (the first matrix a layer reads), c_proj 2048 x 2048, c_fc 2048 x 8192
+# and its mirror; the vectors are a few thousand elements
+LAYER_1P3B = 2048 * (6144 + 2048 + 8192 + 8192)
+AHEAD_1P3B = 2048 * 6144
+BUCKET, LIVE = 50_000_000, 1_000_000_000        # DeepSpeed's defaults
+
+
+@pytest.mark.parametrize("case, args, want", [
+    ("defaults-1.3b", (24, LAYER_1P3B, AHEAD_1P3B, BUCKET, LIVE), 4),
+    ("bucket-0", (24, LAYER_1P3B, AHEAD_1P3B, 0, LIVE), 1),
+    ("bucket-below-one-leaf", (24, LAYER_1P3B, AHEAD_1P3B,
+                               AHEAD_1P3B - 1, LIVE), 1),
+    ("bucket-of-one-leaf", (24, LAYER_1P3B, AHEAD_1P3B, AHEAD_1P3B, LIVE), 2),
+    ("live-below-two-layers", (24, LAYER_1P3B, AHEAD_1P3B, BUCKET,
+                               2 * LAYER_1P3B - 1), 1),
+    ("live-of-two-layers", (24, LAYER_1P3B, AHEAD_1P3B, BUCKET,
+                            2 * LAYER_1P3B), 2),
+    ("live-0", (24, LAYER_1P3B, AHEAD_1P3B, BUCKET, 0), 1),
+    ("never-more-than-the-loop", (3, 64 * 64 * 12, 64 * 256, BUCKET, LIVE),
+     3),
+    ("one-layer", (1, LAYER_1P3B, AHEAD_1P3B, BUCKET, LIVE), 1),
+    ("nothing-gathered", (24, 0, 0, BUCKET, LIVE), 1),
+])
+def test_turn_length(case, args, want):
+    assert zero3.turn_length(*args) == want
+    n_layers = args[0]
+    assert 1 <= zero3.turn_length(*args) <= n_layers
+
+
+def test_the_engine_hands_the_two_keys_to_the_rules(eight_devices):
+    engine = engine_for(gpt(), 3, fsdp4())
+    assert engine.sharding_rules.prefetch_bucket_size == BUCKET
+    assert engine.sharding_rules.max_live_parameters == LIVE
+    engine = engine_for(gpt(), 3, fsdp4(), zero_optimization={
+        "stage": 3, "stage3_prefetch_bucket_size": 7,
+        "stage3_max_live_parameters": 11})
+    assert engine.sharding_rules.prefetch_bucket_size == 7
+    assert engine.sharding_rules.max_live_parameters == 11
+    # rules built by hand, with no ZeRO section, prefetch nothing
+    assert ZeroShardingRules(fsdp4(), stage=3).prefetch_bucket_size == 0
+
+
+@pytest.mark.parametrize("layers_whole, want", [(2, 2), (1, 1)])
+def test_max_live_parameters_caps_the_turn(eight_devices, layers_whole,
+                                           want):
+    """A tiny layer's gathered elements (the four kernels and the vectors:
+    no persistence threshold here) twice over may be whole at once, or not
+    quite: a turn gathers for two layers or for one, whatever the bucket
+    allows."""
+    layer = 64 * (192 + 64 + 256 + 256) + 192 + 256 + 6 * 64
+    live = 2 * layer - (0 if layers_whole == 2 else 1)
+    with plan_events() as events:
+        engine = engine_for(gpt(n_layer=4), 3, fsdp4(), zero_optimization={
+            "stage": 3, "stage3_param_persistence_threshold": 0,
+            "stage3_max_live_parameters": live})
+        place(engine, batches(engine, n=1)[0])
+        step_lowering(engine)
+    assert events[0]["layers_per_turn"] == want
+
+
+def test_a_loop_the_hand_on_cannot_run_keeps_the_scan(eight_devices):
+    """Selective remat saves a layer's dots for the backward pass and the
+    loop ahead recomputes whole layers: under that policy the scan stays
+    what it was, one layer a turn, and says so."""
+    with plan_events() as events:
+        engine = engine_for(gpt(remat_policy="selective"), 3, fsdp4())
+        losses = train(engine, batches(engine))
+    assert np.isfinite(losses).all()
+    assert events[0]["layers_per_turn"] == 1
+    assert events[0]["gathers_at_turn_head_per_step"] == 2 * 3
+
+
+def test_dropout_and_a_routers_loss_run_through_the_loop_ahead(
+        eight_devices):
+    """The loop ahead gives each layer its own dropout and gating keys and
+    sums the layers' auxiliary losses: a dropless MoE with dropout trains
+    as stage 0 does at the first step (where no rounding has compounded)
+    and goes on falling."""
+    base = engine_for(moe(), 0, MeshTopology(dp=4, devices=jax.devices()[:4]))
+    data = batches(base, n=1) * 4
+    ref = train(base, data)
+    with plan_events() as events:
+        engine = engine_for(moe(), 3, fsdp4())
+        losses = train(engine, data)
+    assert events[0]["layers_per_turn"] == 2
+    np.testing.assert_allclose(losses, ref, rtol=2e-4, atol=2e-5)
+    dropped = engine_for(gpt(dropout=0.1, n_layer=4), 3, fsdp4())
+    losses = train(dropped, batches(dropped, n=1) * 4)
+    assert np.isfinite(losses).all() and losses[-1] < losses[0]
+
+
 def test_serving_enters_no_context(eight_devices):
     """``init_inference`` traces the same modules with no context: the
     class comes back as it went in."""
@@ -518,3 +770,8 @@ def test_serving_enters_no_context(eight_devices):
     assert zero3.gathered_on_use(GPT, ("x",), BF16) is GPT
     tree = {"kernel": jnp.ones((4, 4))}
     assert zero3.gather_tree(tree, ("x",), BF16) is tree
+
+
+if __name__ == "__main__":
+    print(json.dumps({precision: pinned_step(precision, 1)
+                      for precision in ("fp32-bf16", "bf16")}, indent=1))
