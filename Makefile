@@ -59,15 +59,6 @@
 #                multichip(8)) with per-phase wall clock; commits
 #                benchmarks/dryrun_phase_times.json and fails if the
 #                total breaches the 5-minute budget
-#   make mfu-search  CPU-safe live step-config search: tiny GPT over the
-#                (remat x micro x flash) grid with a tight HBM override
-#                (prune path exercised for real), winner trained under
-#                the step profiler (docs/performance.md "Step
-#                autotuner"); artifact + trace to /tmp
-#   make mfu-search-full  the committed 1.3B seq-1024 artifact: avals-
-#                only AOT grid vs the TPU v4 HBM ceiling + calibrated
-#                roofline MFU (benchmarks/mfu_search_results.json,
-#                ~5 min of CPU compiles)
 #   make overlap-measured  wall-clock bucketed-vs-monolithic exchange
 #                deltas (benchmarks/communication/
 #                overlap_measured_results.json); nonzero exit when
@@ -87,17 +78,16 @@ PY ?= python
 # hot paths whose changes call for a run of chip_smoke.py on the chip
 HOT_PATHS := deepspeed_tpu/runtime/engine.py deepspeed_tpu/models \
              deepspeed_tpu/ops deepspeed_tpu/utils/timer.py \
-             deepspeed_tpu/inference/engine.py \
-             deepspeed_tpu/runtime/step_autotune.py
+             deepspeed_tpu/inference/engine.py
 
 .PHONY: quick test smoke chaos chaos-serve chaos-cluster profile \
         blackbox memreport \
         check hooks hot-changed serve-bench serve-bench-uniform \
         serve-bench-disagg data-bench \
-        dryrun mfu-search mfu-search-full overlap-measured \
+        dryrun overlap-measured \
         hierarchical-exchange
 
-# the <5-min smoke tier: config/mesh/kernels plus the comm + autotune +
+# the <5-min smoke tier: config/mesh/kernels plus the comm + flash table +
 # process-group units, with tests marked `slow` (pyproject marker) opted
 # out — mark compile-heavy tests slow rather than dropping whole files
 quick:
@@ -107,13 +97,12 @@ quick:
 	  tests/unit/test_compressed_comm.py tests/unit/test_bucketed_comm.py \
 	  tests/unit/test_grad_exchange_modes.py \
 	  tests/unit/test_pipe_transport.py \
-	  tests/unit/test_flash_autotune.py tests/unit/test_procgroup.py \
+	  tests/unit/test_flash_table.py tests/unit/test_procgroup.py \
 	  tests/unit/test_launcher.py tests/unit/test_serving.py \
 	  tests/unit/test_serving_frontdoor.py \
 	  tests/unit/test_serving_fleet.py \
 	  tests/unit/test_serving_disagg.py \
 	  tests/unit/test_data_pipeline.py tests/unit/test_telemetry.py \
-	  tests/unit/test_step_autotune.py \
 	  tests/unit/test_elastic_reshard.py \
 	  tests/unit/test_health_state.py tests/unit/test_cluster_health.py \
 	  -q -x -m "not slow"
@@ -163,15 +152,6 @@ memreport:
 dryrun:
 	DS_TPU_DRYRUN_TIMES_OUT=benchmarks/dryrun_phase_times.json \
 	  $(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
-
-# CPU-safe seconds-scale search (small model, live prune + profiler
-# trace); the committed 1.3B artifact comes from mfu-search-full
-mfu-search:
-	JAX_PLATFORMS=cpu $(PY) benchmarks/mfu_search.py --mode small \
-	  --out /tmp/mfu_search_small.json
-
-mfu-search-full:
-	JAX_PLATFORMS=cpu $(PY) benchmarks/mfu_search.py --mode full
 
 overlap-measured:
 	JAX_PLATFORMS=cpu $(PY) benchmarks/communication/overlap_measured.py

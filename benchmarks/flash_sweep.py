@@ -64,13 +64,25 @@ def run(model_name, seq, flash, micro, steps=5):
     return round(gb * seq * fpt / dt / 1e12, 2), round(dt * 1e3, 1)
 
 
-def kernel_candidates(t, granules=(128, 256, 512)):
-    """``(block_q, block_k, granule)`` over ``default_candidates``' pairs
-    and strips of the whole sequence; ``fit_blocks`` folds the ones a
-    kernel cannot launch onto those it can, and :func:`sweep_kernels`
-    times each distinct launch once."""
-    from deepspeed_tpu.ops.pallas.autotune import default_candidates
+def default_candidates(t):
+    """Divisor-filtered (block_q, block_k) grid around the MXU-friendly
+    power-of-two sizes, bounded so the f32 score tile stays well under a
+    VMEM core (block_q*block_k <= 512*1024 -> 2 MB)."""
+    from deepspeed_tpu.ops.pallas.common import largest_divisor_block
 
+    sizes = [b for b in (128, 256, 512, 1024) if b <= t and t % b == 0]
+    if not sizes:  # short/odd seq: fall back to the divisor heuristic sizes
+        sizes = sorted({largest_divisor_block(t, w)
+                        for w in (128, 256, 512)})
+    return [(bq, bk) for bq in sizes for bk in sizes
+            if bq * bk <= 512 * 1024]
+
+
+def kernel_candidates(t, granules=(128, 256, 512)):
+    """``(block_q, block_k, granule)`` over :func:`default_candidates`'
+    pairs and strips of the whole sequence; ``fit_blocks`` folds the ones
+    a kernel cannot launch onto those it can, and :func:`sweep_kernels`
+    times each distinct launch once."""
     pairs = default_candidates(t) + [(t, 512), (512, t)]
     return [(bq, bk, g) for bq, bk in dict.fromkeys(pairs)
             for g in granules]

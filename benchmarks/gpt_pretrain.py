@@ -39,13 +39,9 @@ def build(model_name="gpt2-1.3b", seq=1024, micro=6, remat_policy="full",
     """``(engine, batch, cfg)`` for the headline config: the engine through
     ``deepspeed_tpu.initialize`` and one seeded global batch. Shared with
     ``chip_smoke.py`` so the smoke drives exactly what the bench times."""
-    # measured on the v5e chip (micro x policy x flash sweep): flash + full
-    # remat + micro 6 = 102.4 TFLOPS (micro 4: 97.0; micro 7/8 OOM;
-    # selective remat OOMs at any micro). Without flash the best was
-    # micro 4 / full = 81.2 — the kernel's d=128 heads dodge the d=64
-    # attention-dot ceiling AND free the [T,T] score memory, buying two
-    # extra micro batches. 1.3B leaves <2 GB for activations after bf16
-    # params+grads+moments (~10.4 GB).
+    # flash + full remat + micro 6 was chosen by a sweep from before PR 21,
+    # stale: ROADMAP S3 (the memory it ran out of held a tree that is lazy
+    # since PR 21; which policy and micro batch fit now is the chip's to say)
     cfg = gpt2_config(
         model_name, n_positions=seq, dtype=jnp.bfloat16,
         param_dtype=jnp.bfloat16, scan_layers=True, remat=True,

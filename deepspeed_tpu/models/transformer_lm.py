@@ -558,11 +558,6 @@ class GPTConfig:
     # v5e chip — seq 128: 56 vs 45 TFLOPS for XLA; 512: 49 vs 45 flash;
     # 2048: 47 vs 25; 4096: 48 vs 12)
     use_flash_attention: Any = False
-    # opt into LIVE flash block autotuning (ops/pallas/autotune.py): first
-    # compile at a new (seq, head_dim, dtype, device) benchmarks the
-    # candidate grid and persists the winner to the on-disk cache. Off =
-    # cached/pretuned blocks still apply; only the benchmarking is gated.
-    flash_autotune: bool = False
     # chunked online-softmax attention (ops/chunked_attention.py): bounded
     # O(T * chunk) score memory in plain XLA — the long-context path where
     # the flash kernel's VMEM ceiling binds (seq > 8192 on the current
@@ -1214,7 +1209,7 @@ def _vocab_parallel_lookup(ids, embedding, topo, dtype):
     )(ids, embedding)
 
 
-def _mesh_flash_attention(q, k, v, segment_ids, *, causal, autotune):
+def _mesh_flash_attention(q, k, v, segment_ids, *, causal):
     """The Pallas flash kernel as a ``shard_map`` island over the mesh's
     batch and head axes.
 
@@ -1236,8 +1231,7 @@ def _mesh_flash_attention(q, k, v, segment_ids, *, causal, autotune):
     h_ax = "tp" if (tp > 1 and H % tp == 0) else None
 
     def local(q, k, v, seg=None):
-        return flash_attention(q, k, v, causal=causal, segment_ids=seg,
-                               autotune=autotune)
+        return flash_attention(q, k, v, causal=causal, segment_ids=seg)
 
     args = (q, k, v) if segment_ids is None else (q, k, v, segment_ids)
     if (b0 is None and h_ax is None) or topo.size("pp") > 1:
@@ -1743,9 +1737,8 @@ class CausalSelfAttention(nn.Module):
                      and (cfg.dropout == 0.0 or deterministic))
         with jax.named_scope(SCOPE_ATTN_CORE):
             if use_flash:
-                y = _mesh_flash_attention(
-                    q, k, v, segment_ids, causal=cfg.causal,
-                    autotune=True if cfg.flash_autotune else None)
+                y = _mesh_flash_attention(q, k, v, segment_ids,
+                                          causal=cfg.causal)
             else:
                 scale = 1.0 / np.sqrt(D)
                 att = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale

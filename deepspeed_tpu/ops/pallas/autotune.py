@@ -7,49 +7,32 @@ sequence a program (a static schedule, no loop) beats every smaller block
 at 1,024 and at 4,096 positions, and each of the three kernels has its own
 best blocks and granule (``flash_attention.KernelBlocks``).
 
-Resolution order for :func:`get_flash_blocks` (first hit wins):
+:func:`get_flash_schedule` is what ``flash_attention`` asks, and there is
+one way to an answer, from tracked files alone:
 
-1. in-memory cache (one lookup per process per key)
-2. on-disk JSON cache — only where ``$DS_TPU_PALLAS_CACHE`` names a
-   file (no default location: what a program compiles depends on
-   tracked files, not on what an earlier run left in a home directory),
-   keyed by ``device_kind|seq|head_dim|dtype|causal``; written by a
-   previous autotune run. A corrupt/unreadable file falls through (warn
-   once) and is overwritten by the next tuned write.
-3. shipped pretuned table (:data:`PRETUNED`: each kernel's blocks and
-   granule) — the v5e's bf16 entries at 1,024 and 4,096 positions (the
-   1.3B and OLMoE benchmark configs' shapes) are measured on the v5e,
-   PR 45 (``benchmarks/flash_sweep.py --kernels``; PERF.md section 6);
-   every other entry is a seed never run on its chip.
-4. live benchmark at the actual shape, IF enabled (``autotune=True`` or
-   ``DS_TPU_FLASH_AUTOTUNE=1``): times the jitted fwd+bwd over a
-   divisor-filtered candidate grid and persists the winner to (2).
-5. the ``largest_divisor_block`` heuristic — today's default, unchanged.
+1. the shipped table (:data:`PRETUNED`: each kernel's ``(block_q,
+   block_k, granule)``), source ``pretuned`` — the v5e's bf16 entries at
+   1,024 and 4,096 positions (the 1.3B and OLMoE benchmark configs'
+   shapes) are measured on the v5e, PR 45 (``benchmarks/flash_sweep.py
+   --kernels``, which is how an entry is made; PERF.md section 6); every
+   other entry is a seed never run on its chip. A hit is kept in memory,
+   one table lookup per process per key.
+2. the ``largest_divisor_block`` heuristic, source ``heuristic``: one
+   pair for all three kernels, their granules left to the fitting.
 
-:func:`get_flash_schedule` is what ``flash_attention`` asks: each
-kernel's ``(block_q, block_k, granule)`` by that order (a pair from the
-disk cache, the live benchmark or the heuristic goes to all three, their
-granules left to the fitting); :func:`get_flash_blocks` is its forward
-pair. Every cached/pretuned entry is re-validated against the current
-shape (divisibility) before use, and ``flash_attention.fit_blocks`` makes
-the shapes of whatever comes out a valid launch, so a stale or hand-edited
-cache can never produce an invalid one.
+An entry is validated against the current shape (divisibility) before
+use, and ``flash_attention.fit_blocks`` makes the shapes of whatever comes
+out a valid launch.
 """
 
-import json
-import os
 import threading
-import warnings
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from deepspeed_tpu.ops.pallas.common import largest_divisor_block
 
-_CACHE_ENV = "DS_TPU_PALLAS_CACHE"
-_AUTOTUNE_ENV = "DS_TPU_FLASH_AUTOTUNE"
 _DEFAULT_WANT = 512  # flash_attention's historical fixed block default
 
 # one kernel's (block_q, block_k, granule); granule None: the fitting's
@@ -84,57 +67,12 @@ for _kind in ("TPU v5 lite", "TPU v5e"):
             (_t, 512, _g), (_t, 512, _g), (512, _keys, _g))
 
 _lock = threading.Lock()
-_mem_cache: Dict[str, Tuple[Tuple[Blocks, ...], str]] = {}
-_disk_warned = False
-
-
-def cache_path() -> Optional[str]:
-    """The disk cache file, or None when ``$DS_TPU_PALLAS_CACHE`` is unset
-    (then nothing is read from or written to disk)."""
-    return os.environ.get(_CACHE_ENV) or None
-
-
-def cache_key(device_kind: str, t: int, d: int, dtype, causal: bool) -> str:
-    return f"{device_kind}|{int(t)}|{int(d)}|{jnp.dtype(dtype).name}|" \
-           f"{bool(causal)}"
-
-
-def _load_disk_cache() -> Dict[str, List[int]]:
-    global _disk_warned
-    path = cache_path()
-    if path is None or not os.path.exists(path):
-        return {}
-    try:
-        with open(path) as f:
-            data = json.load(f)
-        if not isinstance(data, dict):
-            raise ValueError(f"expected a JSON object, got {type(data)}")
-        return data
-    except (OSError, ValueError) as e:
-        if not _disk_warned:
-            _disk_warned = True
-            warnings.warn(
-                f"ignoring corrupt Pallas autotune cache {path!r} ({e}); "
-                "falling back to the block-size heuristic — the next "
-                "autotune run rewrites it", RuntimeWarning)
-        return {}
-
-
-def _store_disk_cache(key: str, blocks: Tuple[int, int]) -> None:
-    path = cache_path()
-    if path is None:
-        return
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    data = _load_disk_cache()
-    data[key] = [int(blocks[0]), int(blocks[1])]
-    tmp = f"{path}.tmp.{os.getpid()}"
-    with open(tmp, "w") as f:
-        json.dump(data, f, indent=2, sort_keys=True)
-    os.replace(tmp, path)
+# PRETUNED's validated hits, by PRETUNED's key
+_mem_cache: Dict[Tuple[str, int, int, str, bool], Tuple[Blocks, ...]] = {}
 
 
 def _valid(blocks, t: int) -> Optional[Tuple[int, int]]:
-    """Sanity-check a cached/pretuned entry against the current shape."""
+    """Sanity-check a table entry against the current shape."""
     try:
         bq, bk = int(blocks[0]), int(blocks[1])
     except (TypeError, ValueError, IndexError):
@@ -144,144 +82,31 @@ def _valid(blocks, t: int) -> Optional[Tuple[int, int]]:
     return bq, bk
 
 
-def _all_three(pair: Tuple[int, int]) -> Tuple[Blocks, ...]:
-    return ((pair[0], pair[1], None),) * 3
-
-
-def default_candidates(t: int) -> List[Tuple[int, int]]:
-    """Divisor-filtered (block_q, block_k) grid around the MXU-friendly
-    power-of-two sizes, bounded so the f32 score tile stays well under a
-    VMEM core (block_q*block_k <= 512*1024 -> 2 MB)."""
-    sizes = [b for b in (128, 256, 512, 1024) if b <= t and t % b == 0]
-    if not sizes:  # short/odd seq: fall back to the divisor heuristic sizes
-        sizes = sorted({largest_divisor_block(t, w)
-                        for w in (128, 256, 512)})
-    return [(bq, bk) for bq in sizes for bk in sizes
-            if bq * bk <= 512 * 1024]
-
-
-def benchmark_candidates(t: int, d: int, dtype, causal: bool,
-                         candidates: List[Tuple[int, int]],
-                         batch_heads: int = 4, iters: int = 3
-                         ) -> Tuple[int, int]:
-    """Time the jitted flash fwd+bwd at the actual (seq, head_dim) shape
-    for each candidate and return the fastest. One compile + ``iters``
-    timed runs per candidate; called once per (shape, device) ever, the
-    winner is persisted to the disk cache."""
-    import time
-
-    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-
-    rng = np.random.RandomState(0)
-    shape = (1, t, batch_heads, d)
-    q = jnp.asarray(rng.randn(*shape), jnp.dtype(dtype))
-    k = jnp.asarray(rng.randn(*shape), jnp.dtype(dtype))
-    v = jnp.asarray(rng.randn(*shape), jnp.dtype(dtype))
-
-    best, best_dt = None, float("inf")
-    for bq, bk in candidates:
-
-        def loss(q, k, v, bq=bq, bk=bk):
-            return jnp.sum(flash_attention(
-                q, k, v, causal=causal, block_q=bq, block_k=bk))
-
-        try:
-            step = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-            jax.block_until_ready(step(q, k, v))  # compile + warm
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                jax.block_until_ready(step(q, k, v))
-            dt = (time.perf_counter() - t0) / iters
-        except Exception as e:  # candidate failed to compile/run: skip it
-            warnings.warn(
-                f"flash autotune candidate ({bq},{bk}) failed: {e}",
-                RuntimeWarning)
-            continue
-        if dt < best_dt:
-            best, best_dt = (bq, bk), dt
-    if best is None:
-        raise RuntimeError(
-            f"flash autotune: no candidate ran for t={t} d={d}")
-    return best
-
-
-def _resolve(t: int, d: int, dtype, causal: bool, *,
-             want_q: int = _DEFAULT_WANT, want_k: int = _DEFAULT_WANT,
-             autotune: Optional[bool] = None,
-             candidates: Optional[List[Tuple[int, int]]] = None
-             ) -> Tuple[Tuple[Blocks, ...], str]:
-    """The three kernels' blocks and the step of the resolution order that
-    gave them: ``disk``, ``pretuned``, ``autotuned`` or ``heuristic``."""
-    device_kind = jax.devices()[0].device_kind
-    key = cache_key(device_kind, t, d, dtype, causal)
-
-    with _lock:
-        hit = _mem_cache.get(key)
-        if hit is not None:
-            return hit
-        entry = _valid(_load_disk_cache().get(key), t)
-        if entry is not None:
-            _mem_cache[key] = (_all_three(entry), "disk")
-            return _mem_cache[key]
-        pre = PRETUNED.get((device_kind, int(t), int(d),
-                            jnp.dtype(dtype).name, bool(causal)), ())
-        if pre and all(_valid(blocks, t) for blocks in pre):
-            _mem_cache[key] = (pre, "pretuned")
-            return _mem_cache[key]
-
-    if autotune is None:
-        autotune = os.environ.get(_AUTOTUNE_ENV, "0") not in ("", "0")
-    if not autotune:
-        return _all_three((largest_divisor_block(t, want_q),
-                           largest_divisor_block(t, want_k))), "heuristic"
-
-    tuned = benchmark_candidates(
-        t, d, dtype, causal, candidates or default_candidates(t))
-    with _lock:
-        _mem_cache[key] = (_all_three(tuned), "autotuned")
-        try:
-            _store_disk_cache(key, tuned)
-        except OSError as e:
-            warnings.warn(
-                f"flash autotune: could not persist winner to "
-                f"{cache_path()!r} ({e}); it stays in-memory for this "
-                "process", RuntimeWarning)
-    return _mem_cache[key]
-
-
-def get_flash_blocks(t: int, d: int, dtype, causal: bool, *,
-                     want_q: int = _DEFAULT_WANT,
-                     want_k: int = _DEFAULT_WANT,
-                     autotune: Optional[bool] = None,
-                     candidates: Optional[List[Tuple[int, int]]] = None
-                     ) -> Tuple[int, int]:
-    """Resolve the forward's (block_q, block_k) for a flash-attention
-    launch.
-
-    ``autotune=None`` defers to the ``DS_TPU_FLASH_AUTOTUNE`` env flag;
-    ``candidates`` overrides the benchmark grid (tests use tiny ones).
-    """
-    return _resolve(t, d, dtype, causal, want_q=want_q, want_k=want_k,
-                    autotune=autotune, candidates=candidates)[0][0][:2]
-
-
-def get_flash_schedule(t: int, d: int, dtype, causal: bool, *,
-                       autotune: Optional[bool] = None):
-    """What each of the three kernels wants at this shape, and the source:
-    ``{kernel: (block_q, block_k, granule)}`` (granule ``None`` where the
-    kernel's own fitting decides)."""
+def get_flash_schedule(t: int, d: int, dtype, causal: bool):
+    """What each of the three kernels wants at this shape, and the source
+    (``pretuned`` or ``heuristic``): ``{kernel: (block_q, block_k,
+    granule)}`` (granule ``None`` where the kernel's own fitting
+    decides)."""
     from deepspeed_tpu.ops.pallas.flash_attention import KERNELS
 
-    wanted, source = _resolve(t, d, dtype, causal, autotune=autotune)
-    return dict(zip(KERNELS, wanted)), source
+    key = (jax.devices()[0].device_kind, int(t), int(d),
+           jnp.dtype(dtype).name, bool(causal))
+    with _lock:
+        wanted = _mem_cache.get(key)
+        if wanted is None:
+            pre = PRETUNED.get(key, ())
+            if pre and all(_valid(blocks, t) for blocks in pre):
+                wanted = _mem_cache[key] = pre
+    if wanted is not None:
+        return dict(zip(KERNELS, wanted)), "pretuned"
+    block = largest_divisor_block(t, _DEFAULT_WANT)
+    return dict.fromkeys(KERNELS, (block, block, None)), "heuristic"
 
 
 def clear_memory_cache() -> None:
-    """Test hook: drop the per-process memoization (disk cache untouched)."""
-    global _disk_warned
+    """Test hook: drop the per-process memoization."""
     with _lock:
         _mem_cache.clear()
-        _disk_warned = False
 
 
 # ---------------------------------------------------------------------------

@@ -37,9 +37,9 @@ def largest_divisor_block(t: int, want: int = 128) -> int:
 
     Shape-blind FALLBACK: kernels that care about the (seq, head_dim,
     device) trade-off — flash attention's causal block pruning above all —
-    resolve blocks through ``ops/pallas/autotune.get_flash_blocks``
-    (pretuned table / disk cache / live benchmark) and only land here when
-    nothing better is known for the shape."""
+    resolve blocks through ``ops/pallas/autotune.get_flash_schedule``
+    (the pretuned table) and only land here when nothing better is known
+    for the shape."""
     b = min(want, t)
     while t % b:
         b -= 1

@@ -600,7 +600,7 @@ def schedule_plan(t: int, causal: bool, schedule) -> Dict[str, dict]:
 
 def resolve_schedule(t: int, d: int, dtype, causal: bool, *,
                      block_q: int = None, block_k: int = None,
-                     autotune: bool = None, lane_aligned: bool = False
+                     lane_aligned: bool = False
                      ) -> Tuple[Tuple[KernelBlocks, ...], str]:
     """The three kernels' schedules for one launch, and where the blocks
     came from (``explicit``, or ``get_flash_schedule``'s source). Publishes
@@ -609,8 +609,7 @@ def resolve_schedule(t: int, d: int, dtype, causal: bool, *,
     if block_q is None or block_k is None:
         from deepspeed_tpu.ops.pallas.autotune import get_flash_schedule
 
-        wanted, source = get_flash_schedule(t, d, dtype, causal,
-                                            autotune=autotune)
+        wanted, source = get_flash_schedule(t, d, dtype, causal)
     schedule = []
     for kernel in KERNELS:
         bq, bk, granule = wanted.get(kernel, (None,) * 3)
@@ -625,7 +624,7 @@ def resolve_schedule(t: int, d: int, dtype, causal: bool, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
                     segment_ids=None, block_q: int = None,
-                    block_k: int = None, autotune: bool = None):
+                    block_k: int = None):
     """Blockwise attention over ``[batch, seq, heads, head_dim]`` inputs.
 
     Memory is O(seq) per program instead of O(seq^2); the [T, T] score matrix
@@ -638,17 +637,15 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
     per-document unpacked attention (docs/data.md).
 
     ``block_q``/``block_k`` default to the shape-tuned resolution in
-    ``ops/pallas/autotune.py`` (disk cache -> pretuned table, which may
-    name each kernel's own -> optional live benchmark gated by
-    ``autotune``/``DS_TPU_FLASH_AUTOTUNE`` -> the historical want-512
-    divisor heuristic); pass them explicitly to pin all three kernels.
+    ``ops/pallas/autotune.py`` (the pretuned table, which may name each
+    kernel's own -> the historical want-512 divisor heuristic); pass them
+    explicitly to pin all three kernels.
     """
     b, t, h, d = q.shape
     if scale is None:
         scale = 1.0 / np.sqrt(d)
     schedule, _ = resolve_schedule(t, d, q.dtype, causal,
                                    block_q=block_q, block_k=block_k,
-                                   autotune=autotune,
                                    lane_aligned=segment_ids is not None)
     if segment_ids is None:
         of = _flash(q, k, v, float(scale), bool(causal), schedule)

@@ -552,50 +552,6 @@ class TpuPipelineConfig(ConfigModel):
 
 
 @dataclass
-class StepAutotuneConfig(ConfigModel):
-    """Step-config autotuner (``runtime/step_autotune.py``).
-
-    ``enabled=True`` resolves a tuned (remat_policy, micro_batch, flash)
-    for the engine's GPT module through the mem -> disk -> PRETUNED ->
-    live chain and applies the winner's remat policy / flash setting to
-    the module before any program compiles. Default off: the compiled
-    program is bit-identical to today's. ``autotune`` additionally allows
-    the LIVE search on a cache/pretuned miss (otherwise a miss is a
-    no-op); ``apply_micro_batch`` opts into the winner's micro batch
-    overriding ``train_micro_batch_size_per_gpu`` (the engine re-derives
-    the batch triad, so callers must size batches off the engine AFTER
-    init). ``fused_step`` controls the optimizer-tail fusion: "auto"
-    keeps the engine's existing gating, "on" fuses the tail into the step
-    even when ``wall_clock_breakdown`` would have split it (phase
-    attribution collapses into ``compiled_step``), "off" always runs the
-    two-program fwd/bwd + apply split (the A/B baseline
-    ``benchmarks/mfu_search.py`` measures against)."""
-
-    enabled: bool = False
-    autotune: bool = False          # allow the live search on a miss
-    apply_micro_batch: bool = False
-    fused_step: str = "auto"        # auto | on | off
-    hbm_gib: float = 0.0            # HBM ceiling override for the search
-    live_steps: int = 3
-    micro_batches: List[int] = field(default_factory=list)  # [] = default
-    policies: List[str] = field(default_factory=list)
-
-    def __post_init__(self):
-        if self.fused_step not in ("auto", "on", "off"):
-            raise DeepSpeedConfigError(
-                "tpu.step_autotune.fused_step must be auto/on/off, got "
-                f"{self.fused_step!r}")
-        if self.hbm_gib < 0:
-            raise DeepSpeedConfigError(
-                f"tpu.step_autotune.hbm_gib must be >= 0, got "
-                f"{self.hbm_gib}")
-        if self.live_steps < 1:
-            raise DeepSpeedConfigError(
-                f"tpu.step_autotune.live_steps must be >= 1, got "
-                f"{self.live_steps}")
-
-
-@dataclass
 class ClusterHealthConfig(ConfigModel):
     """Cluster health plane (``runtime/health.py``; docs/recovery.md
     "Cluster health & SDC defense"). An out-of-band TCP heartbeat mesh
@@ -705,8 +661,6 @@ class TpuConfig(ConfigModel):
     compressed_grad_norm: bool = False
     # explicit bucketed gradient exchange — see GradExchangeConfig
     grad_exchange: Dict[str, Any] = field(default_factory=dict)
-    # HBM-bounded step-config autotuner — see StepAutotuneConfig
-    step_autotune: Dict[str, Any] = field(default_factory=dict)
     # pipeline stage-to-stage transport — see TpuPipelineConfig
     pipeline: Dict[str, Any] = field(default_factory=dict)
     # out-of-band heartbeat mesh + SDC probes — see ClusterHealthConfig
@@ -727,10 +681,6 @@ class TpuConfig(ConfigModel):
     @property
     def grad_exchange_config(self) -> GradExchangeConfig:
         return GradExchangeConfig.from_dict(self.grad_exchange)
-
-    @property
-    def step_autotune_config(self) -> StepAutotuneConfig:
-        return StepAutotuneConfig.from_dict(self.step_autotune)
 
 
 # ---------------------------------------------------------------------------

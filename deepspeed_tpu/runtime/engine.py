@@ -309,22 +309,6 @@ class DeepSpeedEngine:
                 f"sparse attention enabled: "
                 f"{type(model.config.sparse_attention).__name__}", ranks=[0])
 
-        # HBM-bounded step-config autotuner (runtime/step_autotune.py):
-        # resolve a tuned (remat_policy, micro_batch, flash) for this
-        # model/device through the mem -> disk -> PRETUNED -> live chain
-        # and rebuild the module with the winner BEFORE anything compiles.
-        # Default off; with no winner the module is untouched, so the
-        # compiled program is bit-identical to the un-tuned engine.
-        self._step_autotune_cfg = config.tpu.step_autotune_config
-        self._fused_step_mode = self._step_autotune_cfg.fused_step
-        self.step_autotune_winner = None
-        if self._step_autotune_cfg.enabled:
-            # key the tuner by the device count this engine will actually
-            # run on (elastic resume on a shrunk/grown slice must re-tune,
-            # not reuse the old topology's winner)
-            ndev = (topology.num_devices if topology is not None
-                    else jax.device_count())
-            model = self._apply_step_autotune(model, config, ndev)
         self.module = model
 
         topology = layout.build_topology(config, topology)
@@ -1104,57 +1088,6 @@ class DeepSpeedEngine:
             logger.warning(f"compiled_step_cost unavailable: {e}")
             return None
 
-    def _apply_step_autotune(self, model, config, num_devices=None):
-        """Resolve the tuned step config for this module/device and clone
-        the module with the winner's remat policy / flash setting (the
-        ``apply_sparse_attention`` pattern: the model is rebuilt from
-        config before any state or program exists). With
-        ``apply_micro_batch`` the winner's micro batch replaces the
-        configured one and the batch triad re-derives against the mesh."""
-        from deepspeed_tpu.models.transformer_lm import GPT
-        from deepspeed_tpu.runtime import step_autotune as sa
-
-        if not isinstance(model, GPT):
-            log_dist("step_autotune: module is not a GPT model; skipping",
-                     ranks=[0])
-            return model
-        sac = self._step_autotune_cfg
-        cfg = model.config
-        search_kwargs: Dict[str, Any] = {"live_steps": sac.live_steps}
-        if sac.micro_batches:
-            search_kwargs["micro_batches"] = tuple(sac.micro_batches)
-        if sac.policies:
-            search_kwargs["policies"] = tuple(sac.policies)
-        if sac.hbm_gib:
-            search_kwargs["hbm_override_gib"] = sac.hbm_gib
-        winner = sa.get_step_config(
-            sa.model_key(cfg), cfg.n_positions, cfg.dtype,
-            num_devices=num_devices,
-            autotune=True if sac.autotune else None,
-            search_kwargs=search_kwargs)
-        if winner is None:
-            log_dist("step_autotune: no tuned entry for this model/device; "
-                     "module unchanged", ranks=[0])
-            return model
-        self.step_autotune_winner = winner
-        new_cfg = dataclasses.replace(
-            cfg, remat=True, remat_policy=winner["remat_policy"],
-            use_flash_attention=bool(winner["flash"]))
-        if new_cfg != cfg:
-            model = model.clone(config=new_cfg)
-        if (sac.apply_micro_batch
-                and int(winner["micro_batch"])
-                != config.train_micro_batch_size_per_gpu):
-            config.train_micro_batch_size_per_gpu = int(
-                winner["micro_batch"])
-            config.train_batch_size = None  # re-derived vs the actual mesh
-        log_dist(
-            "step_autotune: applied "
-            f"{winner['remat_policy']}/micro{winner['micro_batch']}/"
-            f"{'flash' if winner['flash'] else 'dense'} "
-            f"(source={winner.get('source', '?')})", ranks=[0])
-        return model
-
     def forward(self, batch: Dict[str, Any]):
         """Compute loss for one micro batch. Gradients are computed fused with
         the forward (JAX has no separate backward graph) and cached until
@@ -1502,21 +1435,14 @@ class DeepSpeedEngine:
         # the step envelope opens before the dataloader pull so input-bound
         # steps show up as a fat `dataloader` phase, not missing time
         self._prof_begin_step()
-        # tpu.step_autotune.fused_step: "off" forces the two-program
-        # fwd/bwd + apply split (the A/B baseline), "on" fuses the
-        # optimizer tail even under wall_clock_breakdown (phase detail
-        # collapses into compiled_step), and "auto" additionally honors a
-        # step-autotune winner whose live benchmark measured the fused
-        # tail faster — the "optimizer tail on the critical path" signal.
-        mode = self._fused_step_mode
-        fusable = (self.gradient_accumulation_steps == 1
-                   and not self._config.flops_profiler.enabled
-                   and self._offload_device == "none"
-                   and mode != "off")
-        winner_fuses = bool(
-            (self.step_autotune_winner or {}).get("fuse_optimizer"))
-        if fusable and (mode == "on" or winner_fuses
-                        or not self.wall_clock_breakdown):
+        # one fused program, unless something reads the two-program split:
+        # accumulation, the FLOPs profiler, an offloaded optimizer, or
+        # wall_clock_breakdown's phases (fused, they collapse into
+        # compiled_step)
+        if (self.gradient_accumulation_steps == 1
+                and not self._config.flops_profiler.enabled
+                and self._offload_device == "none"
+                and not self.wall_clock_breakdown):
             with self._prof_phase("dataloader"):
                 batch = next(data_iter)
             return self._train_batch_fused(batch)

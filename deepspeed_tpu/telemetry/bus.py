@@ -66,7 +66,7 @@ KIND_ELASTIC_RESHARD = "elastic.reshard"
 KIND_ZERO3_GATHER_PLAN = "zero3.gather_plan"
 # the flash-attention kernels' schedule (ops/pallas/flash_attention.py),
 # published once when a call is traced: t, d, causal, source (where the
-# blocks came from: explicit, disk, pretuned, autotuned, heuristic) and per
+# blocks came from: explicit, pretuned, heuristic) and per
 # kernel block_q, block_k, heads (a grid step), granule, tiles_computed,
 # tiles_needed, tiles_masked (a head's, in tiles of block_q x block_k)
 KIND_FLASH_PLAN = "flash.plan"

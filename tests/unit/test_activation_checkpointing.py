@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.models.transformer_lm import GPT, GPTConfig
 from deepspeed_tpu.runtime import activation_checkpointing as ac
 
 
@@ -115,3 +116,51 @@ def test_rng_tracker_state_roundtrip():
     assert np.array_equal(np.asarray(x), np.asarray(y))
     with pytest.raises(KeyError):
         t.fork("never-added")
+
+
+def _gpt(policy, flash, seq):
+    cfg = GPTConfig(
+        vocab_size=256, n_positions=seq, n_embd=64, n_layer=2, n_head=4,
+        dtype=jnp.float32, scan_layers=True, remat=True,
+        remat_policy=policy, use_flash_attention=flash)
+    return GPT(cfg)
+
+
+def _loss_and_grads(model, seq):
+    rng = np.random.RandomState(0)
+    ids = jnp.asarray(rng.randint(0, 256, (2, seq)), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ids, deterministic=True)
+
+    def loss_fn(p):
+        return model.apply(p, ids, labels=ids, deterministic=True)
+
+    return jax.value_and_grad(loss_fn)(params)
+
+
+class TestRematPolicyParity:
+    """Remat changes what is recomputed, never what is computed: every
+    policy must reproduce ``full``'s loss and gradients exactly."""
+
+    @pytest.mark.parametrize("policy",
+                             ["save_dots", "save_nothing_but_flash"])
+    @pytest.mark.slow
+    def test_einsum_path_parity(self, policy):
+        ref_l, ref_g = _loss_and_grads(_gpt("full", False, 64), 64)
+        got_l, got_g = _loss_and_grads(_gpt(policy, False, 64), 64)
+        np.testing.assert_allclose(got_l, ref_l, rtol=1e-6)
+        for a, b in zip(jax.tree.leaves(got_g), jax.tree.leaves(ref_g)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-5)
+
+    @pytest.mark.parametrize("policy",
+                             ["save_dots", "save_nothing_but_flash"])
+    @pytest.mark.slow
+    def test_flash_path_parity(self, policy):
+        # T=128 takes the (interpreted) flash kernel, where the
+        # checkpoint_name-tagged attn_out/attn_lse residuals exist
+        ref_l, ref_g = _loss_and_grads(_gpt("full", True, 128), 128)
+        got_l, got_g = _loss_and_grads(_gpt(policy, True, 128), 128)
+        np.testing.assert_allclose(got_l, ref_l, rtol=1e-6)
+        for a, b in zip(jax.tree.leaves(got_g), jax.tree.leaves(ref_g)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-5)

@@ -29,7 +29,6 @@ import sys
 import textwrap
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -40,7 +39,6 @@ from deepspeed_tpu.parallel.mesh import MeshTopology
 from deepspeed_tpu.runtime import checkpoint_manifest as cm
 from deepspeed_tpu.runtime import constants as ds_constants
 from deepspeed_tpu.runtime import layout, reshard
-from deepspeed_tpu.runtime import step_autotune as sa
 from deepspeed_tpu.runtime.zero.sharding import ZeroShardingRules
 from deepspeed_tpu.telemetry import telemetry_bus
 
@@ -378,19 +376,6 @@ class TestManifestTopology:
         engine3.train_batch(it3)
         with pytest.raises(reshard.ReshardError, match="partition_specs"):
             engine3.load_checkpoint(str(tmp_path))
-
-
-# ---------------------------------------------------------------------------
-# step-autotuner cache key re-keys on device count
-# ---------------------------------------------------------------------------
-class TestAutotuneRekey:
-    def test_cache_key_includes_device_count(self):
-        k8 = sa.cache_key("cpu", "gpt2-125m", 128, jnp.bfloat16,
-                          num_devices=8)
-        k4 = sa.cache_key("cpu", "gpt2-125m", 128, jnp.bfloat16,
-                          num_devices=4)
-        assert k8 != k4
-        assert "|n8|" in k8 and "|n4|" in k4
 
 
 # ---------------------------------------------------------------------------

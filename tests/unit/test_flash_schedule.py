@@ -272,8 +272,7 @@ def plans():
 
 @pytest.mark.parametrize("explicit", [True, False],
                          ids=["explicit", "heuristic"])
-def test_flash_plan_event(plans, explicit, monkeypatch, tmp_path):
-    monkeypatch.setenv(autotune._CACHE_ENV, str(tmp_path / "blocks.json"))
+def test_flash_plan_event(plans, explicit):
     autotune.clear_memory_cache()
     q = jnp.zeros((1, 128, 2, 8), jnp.float32)
     kw = {"block_q": 64, "block_k": 32} if explicit else {}

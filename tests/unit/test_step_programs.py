@@ -13,8 +13,9 @@ import pytest
 
 import deepspeed_tpu.runtime as runtime_pkg
 from deepspeed_tpu.parallel import mesh
+from deepspeed_tpu.runtime import config_utils
 
-from tests.unit.test_engine_compressed import _data, _engine
+from tests.unit.test_engine_compressed import _data, _engine, _lowered_step
 
 ADAMW = {"type": "AdamW", "params": {"lr": 5e-2}}
 ONEBIT = {"type": "OnebitAdam", "params": {"lr": 5e-2, "freeze_step": 2}}
@@ -64,6 +65,57 @@ def test_fused_step_is_fwd_bwd_then_apply(eight_devices, family):
     assert len(runs["fused"][1]) == len(runs["split"][1])
     for fused, split in zip(runs["fused"][1], runs["split"][1]):
         np.testing.assert_allclose(fused, split, rtol=1e-5, atol=1e-7)
+
+
+# what reads the two-program split, each as a user's config turns it on
+SPLIT_ARMS = {
+    "accumulation": {"gradient_accumulation_steps": 2},
+    "wall_clock_breakdown": {"wall_clock_breakdown": True},
+    "flops_profiler": {"flops_profiler": {"enabled": True,
+                                          "profile_step": 10 ** 9}},
+    "offloaded_optimizer": {"zero_optimization": {
+        "stage": 0, "offload_optimizer": {"device": "cpu"}}},
+}
+
+
+@pytest.mark.parametrize("arm", list(SPLIT_ARMS))
+def test_train_batch_leaves_the_fused_step_to_what_reads_the_split(
+        eight_devices, arm):
+    """``train_batch``'s gate: the default arm (the test above) runs one
+    fused program; accumulation, ``wall_clock_breakdown``, the FLOPs
+    profiler and an offloaded optimizer each run ``fwd_bwd`` and the
+    update apart."""
+    mesh.reset_default_topology()
+    eng = _engine(ADAMW, extra=SPLIT_ARMS[arm])
+    batch = dict(zip("xy", _data()))
+    loss = eng.train_batch(iter([batch] * eng.gradient_accumulation_steps))
+    assert np.isfinite(float(loss))
+    assert eng._train_step_fn is None and eng._fwd_bwd_fn is not None
+    assert eng.global_steps == 1
+
+
+def test_a_removed_block_is_an_unknown_key(eight_devices, monkeypatch):
+    """``tpu.step_autotune`` went with its tuner (PR 61): a config that
+    still carries it is warned once, as for any unknown key of a block,
+    and gets the engine it got with the block off: the fused step, the
+    same program."""
+    warned = []
+    monkeypatch.setattr(config_utils.logger, "warning",
+                        lambda *a, **kw: warned.append(a))
+    batch = dict(zip("xy", _data()))
+    texts = []
+    for extra in ({}, {"tpu": {"step_autotune": {
+            "enabled": True, "fused_step": "off", "apply_micro_batch": True,
+            "micro_batches": [4]}}}):
+        mesh.reset_default_topology()
+        eng = _engine(ADAMW, extra=extra)
+        eng.train_batch(iter([batch]))
+        assert eng._train_step_fn is not None and eng._fwd_bwd_fn is None
+        assert eng.train_micro_batch_size_per_gpu == 8
+        texts.append(_lowered_step(eng, batch).as_text())
+    assert texts[0] == texts[1]
+    assert [a[1:] for a in warned if "step_autotune" in a] == [
+        ("TpuConfig", "step_autotune")]
 
 
 @pytest.mark.parametrize("module", ["step", "grad_exchange"])
