@@ -191,6 +191,64 @@ def continuation_chunk_spans(model_cfg, start: int, end: int):
     return [(s, min(s + step, end)) for s in range(start, end, step)]
 
 
+def export_prefill(prefill, params, granule: int, buckets_max: int,
+                   platforms=None):
+    """``jax.export.Exported`` of the jitted ``prefill(params, ids, mask)``
+    over ``[1, granule * b]`` tokens with ``b`` a symbol, ``1 <= b <=
+    buckets_max``: the model's Python runs once, here, and the module it
+    leaves is specialised for a bucket wherever it is called under jit.
+    ``params`` gives the parameters' shapes and dtypes (arrays, tracers or
+    avals); ``platforms`` as ``jax.export.export`` takes them (None: the
+    default backend's). Raises what tracing raises where a shape decision
+    of the model needs a number for ``b``
+    (``GPTConfig.prefill_bucket_dependence`` says which models)."""
+    from jax import export
+
+    b, = export.symbolic_shape(
+        "b", constraints=("b >= 1", f"b <= {buckets_max}"))
+    return export.export(prefill, platforms=platforms)(
+        jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                     params),
+        jax.ShapeDtypeStruct((1, granule * b), jnp.int32),
+        jax.ShapeDtypeStruct((1, granule * b), jnp.bool_))
+
+
+def traced_once(per_bucket, granule: int, buckets_max: int):
+    """``(program, before_first)`` for the admission prefill of a model
+    whose token count decides shapes alone. ``program`` is ``jax.jit`` of a
+    function named ``prefill`` as ``per_bucket``'s is (the compiled module
+    stays ``jit_prefill``) that calls ONE export of ``per_bucket`` for every
+    ``[1, granule * b]`` prompt, ``1 <= b <= buckets_max``: jit specialises
+    the exported module for each bucket it meets in milliseconds, where
+    tracing the model again is most of a second, and the operations and
+    shapes, so the results, are the bucket's own program's to the bit. The
+    export is made by the first call it covers. ``before_first`` makes it
+    ahead of that call's own build (``DispatchedProgram.before_first``),
+    for the build log's sake alone: made inside the bucket's tracing, the
+    export's lowering would be counted as tracing. A call it does not cover
+    (a batch of ``generate()``, a span that is no whole bucket) runs the
+    model's Python under the same jit, as it always did."""
+    made = []
+
+    def once(params, ids, mask):
+        rows, tokens = ids.shape
+        if rows != 1 or tokens % granule or tokens > granule * buckets_max \
+                or (ids.dtype, mask.dtype) != (jnp.int32, jnp.bool_):
+            return None
+        if not made:
+            made.append(export_prefill(per_bucket, params, granule,
+                                       buckets_max))
+        return made[0]
+
+    def prefill(params, ids, mask):
+        exported = once(params, ids, mask)
+        if exported is None:
+            return per_bucket.__wrapped__(params, ids, mask)
+        return exported.call(params, ids, mask)
+
+    return jax.jit(prefill), once
+
+
 def init_inference(model, config: Optional[Dict[str, Any]] = None,
                    mp_size: int = 1, dtype=None, checkpoint: Optional[str] = None,
                    replace_with_kernel_inject: bool = True, seed: int = 0,
@@ -335,6 +393,7 @@ class InferenceEngine:
         self._params = None
         self._host_params = hf_params
         self._prefill_fn = None
+        self._prefill_plan = None   # (granule, buckets_max) last planned
         self._decode_k_fn = None
         self._fwd_fn = None
         self._profile = bool(config.get("profile_model_time", False))
@@ -613,10 +672,12 @@ class InferenceEngine:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
                 vars_out["cache"]
 
+        # plan_prefill keeps this one, or calls one export of it
+        self._prefill_per_bucket = jax.jit(prefill)
         # each remembers the avals of the first dispatch per prompt bucket
         # (ids' shape) or scan length, for program_scopes()
         self._prefill_fn = DispatchedProgram(
-            jax.jit(prefill), key=lambda a: a[1].shape)
+            self._prefill_per_bucket, key=lambda a: a[1].shape)
         self._prefill_more_fn = DispatchedProgram(
             jax.jit(prefill_more, donate_argnums=(3,)),
             key=lambda a: a[1].shape)
@@ -626,6 +687,51 @@ class InferenceEngine:
         self._verify_greedy_fn = DispatchedProgram(
             jax.jit(verify_greedy, donate_argnums=(2,)),
             key=lambda a: a[1].shape)
+
+    def plan_prefill(self, granule: int, positions: int):
+        """How the ``[1, T]`` admission prefill is built for a scheduler
+        that pads prompts to multiples of ``granule`` tokens over lanes of
+        ``positions``: traced ONCE for all its buckets (``traced_once``)
+        where the model's declaration says that the token count decides
+        nothing but shapes (``GPTConfig.prefill_bucket_dependence``) and
+        the parameters are whole on one device, else a bucket at a time as
+        ``jax.jit(prefill)`` always has. Decided from what the engine holds, by no option and by
+        no trial: a tracing that fails costs seconds of the set-up of
+        exactly the models that gain nothing. Publishes
+        ``serve.prefill_plan`` when it decides (once for an engine under
+        one scheduler)."""
+        from deepspeed_tpu.models.transformer_lm import GPTConfig
+        from deepspeed_tpu.telemetry.bus import (
+            KIND_SERVE_PREFILL_PLAN,
+            publish,
+        )
+
+        buckets_max = positions // granule
+        if self._prefill_plan == (granule, buckets_max):
+            return
+        self._prefill_plan = (granule, buckets_max)
+        mcfg = getattr(self.module, "config", None)
+        # replicas (dp) hold the parameters whole
+        split = [a for a, n in self.topology.axis_sizes.items()
+                 if n > 1 and a != "dp"]
+        if not isinstance(mcfg, GPTConfig):
+            why = "the module declares no GPTConfig to ask"
+        elif split:
+            why = ("the parameters are split over the mesh's "
+                   f"{', '.join(split)}: the one tracing is a single "
+                   "device's program")
+        elif buckets_max < 2:
+            why = "one bucket fills a lane: nothing to share"
+        else:
+            why = mcfg.prefill_bucket_dependence
+        per_bucket = self._prefill_per_bucket
+        self._prefill_fn.fn, self._prefill_fn.before_first = \
+            (per_bucket, None) if why \
+            else traced_once(per_bucket, granule, buckets_max)
+        publish(KIND_SERVE_PREFILL_PLAN,
+                traced="per_bucket" if why else "once",
+                why=why or "the token count decides shapes alone",
+                granule=granule, buckets_max=buckets_max)
 
     def step_programs(self):
         """The serving programs built so far (``DispatchedProgram``s)."""

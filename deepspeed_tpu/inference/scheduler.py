@@ -603,6 +603,9 @@ class ContinuousBatchingScheduler:
                 jnp.int32))
         if eng._prefill_fn is None:
             eng._build_decode_fns()
+        # one tracing for all this scheduler's prompt buckets where the
+        # model allows it
+        eng.plan_prefill(self.prompt_bucket, self._max_pos or 0)
         if not self._cache_plan_published:
             from deepspeed_tpu.telemetry.bus import (
                 KIND_SERVE_CACHE_PLAN,

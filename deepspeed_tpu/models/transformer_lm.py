@@ -1057,6 +1057,47 @@ class GPTConfig:
                      if leaf.kind == "recurrent")
 
     @property
+    def prefill_bucket_dependence(self) -> Optional[str]:
+        """What may make the ``[1, T]`` prefill from an empty cache another
+        program at another ``T`` than the same operations over other
+        shapes, None where nothing does: then ONE tracing with the token
+        count a symbol serves every prompt bucket (inference/engine.py
+        ``plan_prefill``), else each bucket is traced. None for dense
+        causal attention over a head's own keys and values with a dense
+        feed-forward, the one kind of model whose program from one tracing
+        has been held to each bucket's own to the bit and measured
+        (PERF.md section 6, PR 62); every other mixer is refused outright,
+        one clause each, with the shape decision it takes from the token
+        count in Python where that is known. A prefill that comes to hold a
+        Pallas call needs a number where it has ``T`` (the kernel's grid,
+        its cost estimate): say so here, and it is traced a bucket."""
+        for declared, why in (
+                (self.sparse_attention,
+                 "a sparse layout's blocks and its ring's length against T "
+                 "decide the passes"),
+                (self.is_moe or None,
+                 "the experts' capacity and their grouped-matmul kernel's "
+                 "rows are numbers computed from T"),
+                (self.layer_types,
+                 "layers of more than one kind: window layers prefill in "
+                 "passes of one size, a short convolution keeps a tail"),
+                (self.ssm,
+                 "ssd_chunked_scan pads T to whole chunks and scans their "
+                 "number: a loop of another length at another T"),
+                (self.retention,
+                 "retention_chunked takes min(chunk, T) and branches on "
+                 "the padding in Python"),
+                (self.indexer,
+                 "the indexer's choice of topk among T keys is another "
+                 "form below and above T = topk"),
+                (self.mla,
+                 "latent attention's program from one tracing has not been "
+                 "held to a bucket's own")):
+            if declared is not None:
+                return why
+        return None
+
+    @property
     def head_dim(self) -> int:
         return self.attn_head_dim or self.n_embd // self.n_head
 

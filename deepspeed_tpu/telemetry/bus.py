@@ -59,6 +59,10 @@ KIND_SERVE_SPEC_ACCEPT = "serve.spec_accept"
 # (kv_bytes_per_lane, state_bytes_per_lane, conv_bytes_per_lane,
 # norm_bytes_per_lane, slots) and how a decode step attends over it
 KIND_SERVE_CACHE_PLAN = "serve.cache_plan"
+# when an engine decides how its admission prefill is built for a
+# scheduler's prompt buckets (inference/engine.py ``plan_prefill``): traced
+# ("once" for all buckets | "per_bucket"), why, granule, buckets_max
+KIND_SERVE_PREFILL_PLAN = "serve.prefill_plan"
 KIND_SHUTDOWN = "shutdown.graceful"
 KIND_ELASTIC_RESHARD = "elastic.reshard"
 # ZeRO-3's gather at the point of use (runtime/zero/gather.py): what a step

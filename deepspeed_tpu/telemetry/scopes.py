@@ -342,14 +342,20 @@ class DispatchedProgram:
     when the compile or load ends, so that ``__call__`` stays the frame it
     was under every jitted call (a ``with`` around the first call, three
     more stack slots, cost the serve ramp 1.2 s of 17.5 on the v5e's host:
-    PERF.md, section 6, PR 37)."""
+    PERF.md, section 6, PR 37). ``before_first`` (settable, called with a
+    first call's arguments inside its window) exists for the build log's
+    attribution alone: what the admission prefill's one tracing for all
+    prompt buckets costs (inference/engine.py ``traced_once``) is timed in
+    the first call that needs it, as stages of its own and not as part of
+    that bucket's tracing."""
 
-    __slots__ = ("fn", "key", "avals")
+    __slots__ = ("fn", "key", "avals", "before_first")
 
     def __init__(self, fn, key):
         self.fn = fn
         self.key = key
         self.avals = {}
+        self.before_first = None
 
     def __call__(self, *args):
         k = self.key(args)
@@ -361,6 +367,8 @@ class DispatchedProgram:
         """The avals to keep for a specialisation's first call, which
         follows; the build log is told what it builds."""
         build_log.first_call(self.fn, self.key(args))
+        if self.before_first is not None:
+            self.before_first(*args)
         return avals_like(args)
 
     def lowered(self):

@@ -14,7 +14,14 @@ were recorded on PR 34's parent, d382f5d. The hybrid, retention, latent and
 speculative entries say in their functions' docstrings where each was
 recorded. The splice, copy and rewind programs are reached through the
 scheduler's ``lane_cache`` (inference/lane_cache.py) since PR 46, which
-moved them there; what they lower to is what it was."""
+moved them there; what they lower to is what it was.
+
+PR 62 traces the admission prefill once for all prompt buckets where the
+model allows it (``InferenceEngine.plan_prefill``): ``jit_prefill[64]`` and
+``jit_prefill[128]`` were recorded again on that PR's tree (the module is
+now a call into the exported one) and nothing else was: the rule
+(``GPTConfig.prefill_bucket_dependence``) refuses every other family, whose
+engines keep ``jax.jit(prefill)``."""
 import hashlib
 import json
 import os

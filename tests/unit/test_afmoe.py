@@ -201,13 +201,16 @@ def test_each_form_gives_the_references_logits(form):
     ids = jnp.asarray(np.stack([tokens(40, seed=s) for s in (0, 1)]))
     want = np.stack([reference.logits(params, np.asarray(row), SIZES)
                      for row in ids])
+    # (each pass one compiled program, as test_dots3.py's: outside
+    # ``jax.jit`` every operation of the model is compiled by itself)
     if form == "train":
-        got = model.apply({"params": params}, ids)
+        got = jax.jit(model.apply)({"params": params}, ids)
     else:
         first, step = {"passes": (4, 4), "steps": (4, 1),
                        "chunk": (4, 3)}[form]
-        got, state = model.apply({"params": params}, ids[:, :first],
-                                 decode=True, mutable=["cache"])
+        got, state = jax.jit(lambda ids: model.apply(
+            {"params": params}, ids, decode=True, mutable=["cache"]))(
+                ids[:, :first])
         parts, at = [got], first
         more = jax.jit(lambda cache, ids: model.apply(
             {"params": params, "cache": cache}, ids, decode=True,
@@ -248,10 +251,12 @@ def attention_of(kind, x, decode_from=None):
     out, state = layer.apply({"params": params}, x[:, :decode_from],
                              decode=True, mutable=["cache"])
     parts = [out]
+    # one compiled step, not the layer's operations one by one at each
+    step = jax.jit(lambda cache, x: layer.apply(
+        {"params": params, "cache": cache}, x, decode=True,
+        mutable=["cache"]))
     for t in range(decode_from, x.shape[1]):
-        out, state = layer.apply(
-            {"params": params, "cache": state["cache"]}, x[:, t:t + 1],
-            decode=True, mutable=["cache"])
+        out, state = step(state["cache"], x[:, t:t + 1])
         parts.append(out)
     return jnp.concatenate(parts, axis=1)
 
@@ -381,9 +386,13 @@ def test_expert_load_says_the_share_of_experts_that_got_a_row(fp32):
 # the scheduler's cache: a ring beside a dense leaf
 # ---------------------------------------------------------------------------
 def reference_greedy(params, prompt, n):
+    """(Padded on the right to one length: a causal model's rows never
+    read the padding, and every length then shares one compile.)"""
     seq = list(prompt)
     for _ in range(n):
-        row = reference.logits(params, np.asarray(seq), SIZES,
+        ids = np.zeros((64,), np.int64)
+        ids[:len(seq)] = seq
+        row = reference.logits(params, ids, SIZES,
                                positions=[len(seq) - 1])[0]
         seq.append(int(row.argmax()))
     return seq[len(prompt):]

@@ -108,16 +108,21 @@ def test_each_form_gives_the_references_logits(scan, form):
     want = reference.logits(params if scan else as_scanned(params, cfg),
                             np.asarray(ids[0]), SIZES)
 
+    # (each pass one compiled program, as test_dots3.py's: outside
+    # ``jax.jit`` every operation of the model is compiled by itself)
+    @jax.jit
+    def apply(variables, ids):
+        return model.apply(variables, ids, decode=True, mutable=["cache"])
+
     def decode(ids, cache=None):
         variables = {"params": params}
         if cache is not None:
             variables["cache"] = cache
-        out, new = model.apply(variables, ids, decode=True,
-                               mutable=["cache"])
+        out, new = apply(variables, ids)
         return np.asarray(out[0]), new["cache"]
 
     if form == "train":
-        got = np.asarray(model.apply({"params": params}, ids)[0])
+        got = np.asarray(jax.jit(model.apply)({"params": params}, ids)[0])
     elif form == "prefill":
         got, _ = decode(ids)
     elif form == "steps":
@@ -413,9 +418,13 @@ def test_expert_load_counts_the_held_experts_alone(fp32):
 # through the scheduler's lane cache
 # ---------------------------------------------------------------------------
 def reference_greedy(params, prompt, n):
+    """(Padded on the right to one length: a causal model's rows never
+    read the padding, and every length then shares one compile.)"""
     seq = list(prompt)
     for _ in range(n):
-        row = reference.logits(params, np.asarray(seq), SIZES,
+        ids = np.zeros((64,), np.int64)
+        ids[:len(seq)] = seq
+        row = reference.logits(params, ids, SIZES,
                                positions=[len(seq) - 1])[0]
         seq.append(int(row.argmax()))
     return seq[len(prompt):]
@@ -867,8 +876,8 @@ def test_a_training_forwards_gradient_is_what_it_was(monkeypatch):
 
     def grads():
         jax.clear_caches()
-        return jax.grad(lambda p: model.apply(
-            {"params": p}, ids, labels=ids))(params)
+        return jax.jit(jax.grad(lambda p: model.apply(
+            {"params": p}, ids, labels=ids)))(params)
 
     routes = gmm_routes(monkeypatch)
     got = grads()

@@ -106,12 +106,15 @@ def test_prefill_then_decode_through_the_lane_cache_gives_the_reference_logits(
         deterministic=True, decode=True, mutable=["cache"])
     got = [np.asarray(logits, np.float32)[0, -n_prompt:]]
     cache = sched._splice(sched._empty_cache(), sub["cache"], lane)
+    # one compiled step, as the served decode program is, and not the
+    # model's operations dispatched one by one at each of sixteen steps
+    step = jax.jit(lambda params, cache, tok: model.apply(
+        {"params": params, "cache": cache}, tok,
+        deterministic=True, decode=True, mutable=["cache"]))
     for t in range(n_prompt, len(seq)):
         tok = np.zeros((sched.slots, 1), np.int32)
         tok[lane, 0] = seq[t]
-        logits, out = model.apply(
-            {"params": eng.params, "cache": cache}, jnp.asarray(tok),
-            deterministic=True, decode=True, mutable=["cache"])
+        logits, out = step(eng.params, cache, jnp.asarray(tok))
         cache = out["cache"]
         got.append(np.asarray(logits, np.float32)[lane])
     got = np.concatenate(got, 0)
@@ -142,8 +145,12 @@ def test_the_scheduler_serves_the_references_greedy_tokens_with_lanes_reused(
         telemetry_bus.unsubscribe(plans.append)
     for rid, p in zip(rids, prompts):
         toks = list(done[rid].tokens)
+        # (padded on the right to one length: a causal model's rows never
+        # read the padding, and every request then shares one compile)
+        seq = np.zeros((64,), np.int64)
+        seq[:len(p) + len(toks) - 1] = p + toks[:-1]
         logits = reference.logits(
-            eng.params, np.asarray(p + toks[:-1]), SIZES,
+            eng.params, seq, SIZES,
             positions=range(len(p) - 1, len(p) + len(toks) - 1))
         assert logits.argmax(-1).tolist() == toks
     plan = [ev for ev in plans if ev["kind"] == "serve.cache_plan"]

@@ -530,13 +530,16 @@ def test_where_the_matrices_are_read_is_told_from_what_the_call_shows(
     assert experts_mod.expert_matrices(cfg, rows) == want
     params = model.init(jax.random.PRNGKey(0), IDS)["params"]
     routes = gmm_routes(monkeypatch)
+    # (each one compiled program, as a step is: outside ``jax.jit`` every
+    # operation of the model is compiled by itself)
     if decode:
-        _, out = model.apply({"params": params}, IDS, decode=True,
-                             mutable=["cache", MOE_STATS])
+        _, out = jax.jit(lambda p: model.apply(
+            {"params": p}, IDS, decode=True,
+            mutable=["cache", MOE_STATS]))(params)
     else:
-        grads, out = jax.grad(lambda p: model.apply(
+        grads, out = jax.jit(jax.grad(lambda p: model.apply(
             {"params": p}, IDS, labels=IDS, mutable=[MOE_STATS]),
-            has_aux=True)(params)
+            has_aux=True))(params)
         assert all(np.isfinite(np.asarray(g, np.float32)).all()
                    and np.asarray(g, np.float32).any()
                    for g in jax.tree.leaves(grads))

@@ -121,12 +121,15 @@ def test_each_form_gives_the_references_logits(scan, form, prompt):
     tree = params if scan else as_scanned(params, cfg)
     want = reference.logits(tree, ids, SIZES)
     batch = jnp.asarray(ids)[None]
+    # each pass one compiled program, as test_dots3.py's, and not the
+    # model's operations dispatched (and compiled) one by one
     if form == "no_cache":
-        got = model.apply({"params": params}, batch)[0]
+        got = jax.jit(model.apply)({"params": params}, batch)[0]
         np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
         return
-    got, var = model.apply({"params": params}, batch[:, :prompt],
-                           decode=True, mutable=["cache"])
+    got, var = jax.jit(lambda ids: model.apply(
+        {"params": params}, ids, decode=True, mutable=["cache"]))(
+            batch[:, :prompt])
     np.testing.assert_allclose(got[0], want[:prompt], atol=ATOL, rtol=0)
     if form == "steps":
         step = one_token_step(model, params)
@@ -134,9 +137,9 @@ def test_each_form_gives_the_references_logits(scan, form, prompt):
             got, var = step(var["cache"], batch[:, t:t + 1])
             np.testing.assert_allclose(got[0, 0], want[t], atol=ATOL, rtol=0)
     elif form == "chunk":           # a continuation of many query tokens
-        got, var = model.apply(
-            {"params": params, "cache": var["cache"]}, batch[:, prompt:],
-            decode=True, mutable=["cache"])
+        got, var = jax.jit(lambda cache, ids: model.apply(
+            {"params": params, "cache": cache}, ids,
+            decode=True, mutable=["cache"]))(var["cache"], batch[:, prompt:])
         np.testing.assert_allclose(got[0], want[prompt:], atol=ATOL, rtol=0)
 
 
@@ -377,10 +380,14 @@ def test_the_scheduler_serves_the_references_tokens_and_keeps_its_rows(fp32):
         sched.run(poll_fn=stop_late)
     for rid, prompt in zip(rids, prompts):
         served_tokens = out[rid]
-        seq = np.asarray(prompt + served_tokens[:-1])
+        # (padded on the right to one length: a causal model's rows never
+        # read the padding, and every request then shares one compile)
+        n = len(prompt) + len(served_tokens) - 1
+        seq = np.zeros((64,), np.int64)
+        seq[:n] = prompt + served_tokens[:-1]
         logits = reference.logits(
             eng.params, seq, SIZES,
-            positions=list(range(len(prompt) - 1, len(seq))))
+            positions=list(range(len(prompt) - 1, n)))
         assert (logits.argmax(-1) == np.asarray(served_tokens)).all()
     kept = sched.lanes_at_exit
     assert kept.live
@@ -433,15 +440,17 @@ def test_only_a_decode_step_says_which_rows_it_chose():
     def chosen(var):
         return np.asarray(var["cache"]["h_0"]["attn"][indexer.CHOSEN_ROWS])
 
-    _, var = model.apply({"params": params}, batch[:, :5], decode=True,
-                         mutable=["cache"])
+    # (each pass one compiled program: outside ``jax.jit`` every operation
+    # of the model is compiled by itself)
+    _, var = jax.jit(lambda ids: model.apply(
+        {"params": params}, ids, decode=True, mutable=["cache"]))(
+            batch[:, :5])
     assert (chosen(var) == -1).all()
     step = one_token_step(model, params)
     _, var = step(var["cache"], batch[:, 5:6])
     assert sorted(chosen(var)[0]) == [-1, -1, 0, 1, 2, 3, 4, 5]
     before = chosen(var)
-    _, var = model.apply({"params": params, "cache": var["cache"]},
-                         batch[:, 6:29], decode=True, mutable=["cache"])
+    _, var = step(var["cache"], batch[:, 6:29])     # a pass of 23 tokens
     np.testing.assert_array_equal(chosen(var), before)
     _, var = step(var["cache"], batch[:, 29:30])
     assert (chosen(var) >= 0).all() and len(set(chosen(var)[0])) == TOPK
@@ -486,14 +495,16 @@ def test_rewind_steps_the_index_key_back_with_keys_and_values(fp32):
     model, params = eng.module, eng.params
     ids = tokens(30, seed=7)
     batch = jnp.asarray(ids)[None]
-    _, var = model.apply({"params": params}, batch[:, :20], decode=True,
-                         mutable=["cache"])
+    # (each pass one compiled program: outside ``jax.jit`` every operation
+    # of the model is compiled by itself)
+    _, var = jax.jit(lambda ids: model.apply(
+        {"params": params}, ids, decode=True, mutable=["cache"]))(
+            batch[:, :20])
+    more = one_token_step(model, params)    # traced again for four tokens
     snapshot = lanes.copy(var["cache"])
-    _, one = model.apply({"params": params, "cache": lanes.copy(snapshot)},
-                         batch[:, 20:21], decode=True, mutable=["cache"])
+    _, one = more(lanes.copy(snapshot), batch[:, 20:21])
     wrong = jnp.asarray([[ids[20], 1, 2, 3]], jnp.int32)
-    _, four = model.apply({"params": params, "cache": var["cache"]}, wrong,
-                          decode=True, mutable=["cache"])
+    _, four = more(var["cache"], wrong)
     after_pass = jax.tree.map(np.asarray, four["cache"])   # rewind donates
     back = lanes.rewind(snapshot, four["cache"], jnp.asarray([3], jnp.int32))
     for (path, got), want, passed in zip(
@@ -508,8 +519,7 @@ def test_rewind_steps_the_index_key_back_with_keys_and_values(fp32):
                 -1 if path[-1].key == indexer.CHOSEN_ROWS else 0)).all()
         np.testing.assert_allclose(got, want, atol=ATOL, rtol=0,
                                    err_msg=jax.tree_util.keystr(path))
-    got, _ = model.apply({"params": params, "cache": back}, batch[:, 21:22],
-                         decode=True, mutable=["cache"])
+    got, _ = more(back, batch[:, 21:22])
     want = reference.logits(params, ids[:22], SIZES, positions=[21])
     np.testing.assert_allclose(got[0, -1], want[0], atol=ATOL, rtol=0)
 

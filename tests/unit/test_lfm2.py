@@ -173,12 +173,15 @@ def test_each_form_gives_the_references_logits(form):
     ids = jnp.asarray(np.stack([tokens(24, seed=s) for s in (0, 1)]))
     want = np.stack([reference.logits(params, np.asarray(row), SIZES)
                      for row in ids])
+    # (each pass one compiled program, as test_dots3.py's: outside
+    # ``jax.jit`` every operation of the model is compiled by itself)
     if form == "train":
-        got = model.apply({"params": params}, ids)
+        got = jax.jit(model.apply)({"params": params}, ids)
     else:
         first = {"prefill": 24, "steps": 9, "chunk": 16}[form]
-        got, state = model.apply({"params": params}, ids[:, :first],
-                                 decode=True, mutable=["cache"])
+        got, state = jax.jit(lambda ids: model.apply(
+            {"params": params}, ids, decode=True, mutable=["cache"]))(
+                ids[:, :first])
         parts, at = [got], first
         step = 8 if form == "chunk" else 1
         more = jax.jit(lambda cache, ids: model.apply(
@@ -270,7 +273,7 @@ def test_the_gradients_are_the_references():
         return jnp.sum(reference.mm(
             hidden, reference.head_of(p)["lm_head"]) * reading)
 
-    got, want = jax.grad(served_reading)(params), \
+    got, want = jax.jit(jax.grad(served_reading))(params), \
         jax.grad(reference_reading)(params)
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
                             jax.tree.leaves(want)):
@@ -729,8 +732,8 @@ def test_training_through_the_runs_reads_the_stacks_in_place_too(
     def step():
         jax.clear_caches()
         del routes[:]
-        return jax.value_and_grad(lambda p: model.apply(
-            {"params": p}, ids, labels=ids))(params)
+        return jax.jit(jax.value_and_grad(lambda p: model.apply(
+            {"params": p}, ids, labels=ids)))(params)
 
     got = step()
     assert routes and all(routes)

@@ -98,9 +98,11 @@ def both(request):
         cfg.moe_num_experts, cfg.dtype) is not None) == (
             len(request.param) > 2)
     model, params, ids = seeded(cfg)
-    sys_logits = model.apply({"params": params}, ids)
-    sys_loss, sys_grads = jax.value_and_grad(
-        lambda p: model.apply({"params": p}, ids, labels=ids))(params)
+    # each one compiled program, as the engine's step is, and not the
+    # model's operations dispatched (and compiled) one by one
+    sys_logits = jax.jit(model.apply)({"params": params}, ids)
+    sys_loss, sys_grads = jax.jit(jax.value_and_grad(
+        lambda p: model.apply({"params": p}, ids, labels=ids)))(params)
     _, ref_logits, ref_chosen, _, _ = olmoe.forward(params, ids,
                                                     **ref_kw(cfg))
     ref_loss, (_, _), ref_grads = olmoe.loss_and_grads(
@@ -196,8 +198,8 @@ def test_nothing_is_dropped_when_every_token_picks_the_same_experts(
     the same k experts, 6-8 times any capacity a balanced load would set.
     Forward and backward still equal the dense reference."""
     moe, params, x = layer_on(experts, top_k, jnp.zeros_like)
-    (y, _, _, counts), stats = moe.apply({"params": params}, x,
-                                         mutable=["moe_stats"])
+    (y, _, _, counts), stats = jax.jit(lambda p, x: moe.apply(
+        {"params": p}, x, mutable=["moe_stats"]))(params, x)
     assert counts.tolist() == [48] * top_k + [0] * (experts - top_k)
     # counted from the experts' output, not from the routing
     assert stats["moe_stats"]["computed"][0].tolist() == counts.tolist()
@@ -211,9 +213,9 @@ def test_nothing_is_dropped_when_every_token_picks_the_same_experts(
     want = ref(params["experts"], flat)
     assert rel(y.reshape(48, 16), want) < TOL
     cot = jax.random.normal(jax.random.PRNGKey(3), want.shape)
-    got_g = jax.grad(lambda p, x: jnp.sum(
+    got_g = jax.jit(jax.grad(lambda p, x: jnp.sum(
         moe.apply({"params": p}, x)[0].reshape(48, 16) * cot),
-        argnums=(0, 1))(params, x)
+        argnums=(0, 1)))(params, x)
     want_g = jax.grad(lambda p, x: jnp.sum(ref(p, x) * cot),
                       argnums=(0, 1))(params["experts"], flat)
     assert rel(got_g[1].reshape(48, 16), want_g[1]) < TOL
