@@ -28,9 +28,13 @@ kernel ``decode_attn`` (ops/pallas/decode_attention.py) for both kinds, out
 of the stacked leaf where it lies: a window layer hands it ``valid &`` what
 its window holds, as ops/indexed_attention.py hands ``valid & chosen``, and
 the kernel reads the ring's blocks. More tokens on a cache (the passes of a
-prefill) and a call without a cache take two einsums a KV head, one head
-after another, so that a pass of ``T`` tokens holds ``[heads / kv_heads, T,
-rows]`` scores at a time.
+prefill) take two einsums a KV head, one head after another, so that a pass
+of ``T`` tokens holds ``[heads / kv_heads, T, rows]`` scores at a time. A
+call without a cache (training) runs the flash kernels under the kind's
+window where :func:`flash_takes` says the shapes allow
+(ops/pallas/flash_attention.py: no ``[T, T]`` array anywhere, tiles outside
+a window layer's band not computed, K and V read at their own head count),
+and the same two einsums a KV head otherwise.
 
 What a kind declares HERE is its window, its ring and whether q and k are
 rotated; heads, head size and ``rope_theta`` are the model's one set. A
@@ -71,6 +75,23 @@ def attend_by_kv_head(qg, k, v, visible):
     out = jax.lax.map(one, (jnp.moveaxis(qg, 2, 0), jnp.moveaxis(k, 2, 0),
                             jnp.moveaxis(v, 2, 0)))
     return jnp.moveaxis(out, 0, 2)
+
+
+def flash_takes(cfg, kind, T: int, mask) -> bool:
+    """Whether a call without a cache runs the flash kernels
+    (ops/pallas/flash_attention.py, under the kind's window), chosen as
+    ``CausalSelfAttention`` chooses: ``use_flash_attention`` True, or
+    "auto" from the measured crossover up (no ceiling here: the kernels
+    compile at 16,384 positions with and without a window, and two einsums
+    a KV head hold ``[heads / kv_heads, T, T]`` scores); no padding mask;
+    whole lane tiles of positions, and of the window. Everything else keeps
+    the einsum form."""
+    from deepspeed_tpu.models.transformer_lm import FLASH_AUTO_MIN_SEQ
+
+    want = T >= FLASH_AUTO_MIN_SEQ if cfg.use_flash_attention == "auto" \
+        else cfg.use_flash_attention
+    return bool(want and mask is None and T % 128 == 0
+                and (kind.window is None or kind.window % 128 == 0))
 
 
 class KindCache:
@@ -215,6 +236,16 @@ class KindAttention(nn.Module):
             # no cache: the tokens at hand, position t at row t
             pos = jnp.arange(T)[None, :]
             q, k = rope(q, pos), rope(k, pos)
+            if flash_takes(cfg, kind, T, mask):
+                # no [T, T] scores anywhere: the kernels under the kind's
+                # window, K and V read at their own head count
+                from deepspeed_tpu.models.transformer_lm import \
+                    _mesh_flash_attention
+
+                with jax.named_scope(kind.scope):
+                    y = close(_mesh_flash_attention(
+                        q, k, v, None, causal=True, window=kind.window))
+                return dense(C, "c_proj")(y)
             ahead = pos[0][:, None] - pos[0][None, :]        # i - j
             visible = (ahead >= 0) if kind.window is None \
                 else (ahead >= 0) & (ahead < kind.window)

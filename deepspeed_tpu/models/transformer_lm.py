@@ -688,6 +688,13 @@ class GPTConfig:
     # what sigmoid scoring adds to the chosen scores' sum before it
     # divides by it; None = the 1e-6 of ``topk_routing``
     moe_renorm_eps: Optional[float] = None
+    # what the router's logits are computed from: "mlp", what the experts
+    # read (``ln_2``'s output), or "block", the block's own input, before
+    # its first norm and its mixer (a router placed before attention)
+    moe_router_input: str = "mlp"
+    # the experts' activation by name (``activation``'s names); None = gelu,
+    # or silu where the experts are gated
+    moe_expert_activation: Optional[str] = None
     # --- which token mixer a layer runs --------------------------------------
     # Declared once, here, and read by ``Block`` (its ``mixer`` field), by
     # whoever runs the layers and by ``cache_leaves``. ``layer_types`` None
@@ -859,6 +866,14 @@ class GPTConfig:
                     "an indexer sits beside causal attention with keys "
                     "and values per head and plain rotary positions")
             self.indexer.sections(self)     # raises for sections it lacks
+        if self.moe_router_input not in ("mlp", "block"):
+            raise ValueError(
+                f"moe_router_input must be 'mlp' or 'block'; got "
+                f"{self.moe_router_input!r}")
+        if self.moe_expert_activation not in (None, *_ACTIVATIONS):
+            raise ValueError(
+                f"unknown moe_expert_activation "
+                f"{self.moe_expert_activation!r}")
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"moe_scoring must be 'softmax' or 'sigmoid'; got "
@@ -1250,7 +1265,7 @@ def _vocab_parallel_lookup(ids, embedding, topo, dtype):
     )(ids, embedding)
 
 
-def _mesh_flash_attention(q, k, v, segment_ids, *, causal):
+def _mesh_flash_attention(q, k, v, segment_ids, *, causal, window=None):
     """The Pallas flash kernel as a ``shard_map`` island over the mesh's
     batch and head axes.
 
@@ -1259,7 +1274,9 @@ def _mesh_flash_attention(q, k, v, segment_ids, *, causal):
     whole global batch. Attention is independent per (row, head), so each
     device runs it on its local ``[B/b, T, H/tp, D]`` block with no
     collective. Dims the mesh does not divide stay unsharded (batch-1
-    serving on a dp>1 mesh, where the array is replicated anyway)."""
+    serving on a dp>1 mesh, where the array is replicated anyway).
+    ``window`` and K / V of fewer heads than q (whole groups a device) are
+    the kernel's own."""
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
@@ -1269,10 +1286,12 @@ def _mesh_flash_attention(q, k, v, segment_ids, *, causal):
     B, _, H, _ = q.shape
     b0 = _island_batch_axes(topo, B)
     tp = topo.size("tp")
-    h_ax = "tp" if (tp > 1 and H % tp == 0) else None
+    h_ax = "tp" if (tp > 1 and H % tp == 0
+                    and k.shape[2] % tp == 0) else None
 
     def local(q, k, v, seg=None):
-        return flash_attention(q, k, v, causal=causal, segment_ids=seg)
+        return flash_attention(q, k, v, causal=causal, segment_ids=seg,
+                               window=window)
 
     args = (q, k, v) if segment_ids is None else (q, k, v, segment_ids)
     if (b0 is None and h_ax is None) or topo.size("pp") > 1:
@@ -1941,6 +1960,7 @@ class Block(nn.Module):
                 drop_tokens=cfg.moe_drop_tokens,
                 use_rts=cfg.moe_use_rts,
                 gated_experts=cfg.moe_gated_experts,
+                expert_activation=_ACTIVATIONS.get(cfg.moe_expert_activation),
                 norm_topk_prob=cfg.moe_norm_topk_prob,
                 n_shared=cfg.moe_n_shared,
                 n_group=cfg.moe_n_group,
@@ -1954,7 +1974,8 @@ class Block(nn.Module):
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 name="mlp",
-            )(h, deterministic=deterministic)
+            )(h, deterministic=deterministic,
+              router_input=x_in if cfg.moe_router_input == "block" else None)
             l_aux = cfg.moe_aux_loss_coef * l_aux
             if cfg.moe_z_loss_coef:
                 l_aux = l_aux + cfg.moe_z_loss_coef * l_z
@@ -1981,9 +2002,13 @@ class Block(nn.Module):
 # (benchmarks/flash_sweep.py, v5e chip): XLA einsum attention wins below
 # this sequence length, the Pallas flash kernel at and above it
 FLASH_AUTO_MIN_SEQ = 512
-# above this, the flash kernel's per-head VMEM working set exceeds the
-# 16 MB scoped-vmem ceiling (measured at 16384); "auto" falls back to the
-# chunked online-softmax path (ops/chunked_attention.py)
+# above this "auto" falls back to the chunked online-softmax path
+# (ops/chunked_attention.py) in ``CausalSelfAttention``: at 16384 the flash
+# kernel's per-head working set exceeded the 16 MB a kernel gets unasked.
+# Since PR 45 a launch asks for the VMEM its blocks need, and the kernels
+# compile and run at 16384 x 128 with and without a window (PR 63, which
+# the layers by kind use: models/kind_attention.py ``flash_takes``); what
+# "auto" chooses here is left as it was measured
 FLASH_MAX_SEQ = 8192
 CHUNKED_AUTO_CHUNK = 1024
 

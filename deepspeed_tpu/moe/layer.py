@@ -208,10 +208,15 @@ class MoE(nn.Module):
                 self.sow(MOE_STATS, name, value)
 
     @nn.compact
-    def __call__(self, x, *, deterministic: bool = True):
+    def __call__(self, x, *, deterministic: bool = True, router_input=None):
+        """``router_input`` (``x``'s shape; None = ``x``): what the gate's
+        logits are computed from where that is not what the experts read
+        (``GPTConfig.moe_router_input``)."""
         orig_shape = x.shape
         d_model = orig_shape[-1]
         tokens = x.reshape(-1, d_model)
+        scored = tokens if router_input is None \
+            else router_input.reshape(-1, d_model)
 
         # gate in fp32 (reference TopKGate casts input to float, wg fp32),
         # and a true float32 product: the TPU's default rounds float32
@@ -243,7 +248,7 @@ class MoE(nn.Module):
                     if self.expert_bias_init else nn.initializers.zeros,
                     (self.num_experts,), jnp.float32)
             with jax.named_scope(SCOPE_MOE_ROUTER):
-                route = topk_routing(gate(tokens.astype(jnp.float32)),
+                route = topk_routing(gate(scored.astype(jnp.float32)),
                                      self.k, self.norm_topk_prob,
                                      self.n_group, self.topk_group,
                                      self.routed_scale, **corrected)
@@ -291,7 +296,7 @@ class MoE(nn.Module):
             return (y.reshape(orig_shape), route.l_aux, route.l_z,
                     route.exp_counts)
 
-        gate_logits = gate(tokens.astype(jnp.float32))
+        gate_logits = gate(scored.astype(jnp.float32))
 
         rng = None
         if not deterministic and self.has_rng("gating"):

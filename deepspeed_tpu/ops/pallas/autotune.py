@@ -43,7 +43,8 @@ Blocks = Tuple[int, int, Optional[int]]
 # Seeds for the 1.3B/seq-1024 shape (n_embd=2048 / 16 heads -> d=128),
 # never run on their chips: block_k at seq/4, block_q at seq/2 up to 2048,
 # (512, 512) past it. The v5e's bf16 entries below are measured.
-PRETUNED: Dict[Tuple[str, int, int, str, bool], Tuple[Blocks, ...]] = {}
+# A launch under a window has the window after ``causal``.
+PRETUNED: Dict[tuple, Tuple[Blocks, ...]] = {}
 for _kind in ("TPU v4", "TPU v5 lite", "TPU v5e", "TPU v5p", "TPU v6 lite",
               "TPU v6e"):
     for _dt in ("bfloat16", "float32"):
@@ -66,9 +67,24 @@ for _kind in ("TPU v5 lite", "TPU v5e"):
         PRETUNED[(_kind, _t, 128, "bfloat16", True)] = (
             (_t, 512, _g), (_t, 512, _g), (512, _keys, _g))
 
+# Measured on the v5e, PR 63 (each kernel alone over 28 query heads on 4 KV
+# heads at 16,384 positions, ms a call; PERF.md section 6 has the sweep).
+# Without a window: forward 14.97 at (1024, 1024, 512) (16.6 at block_k
+# 512), dQ 16.86 at (2048, 1024, 256), dK/dV 23.81 at strips of 2,048 keys
+# over tiles of 1,024 rows. Under a window of 4,096 (the ``window_flash_*``
+# kernels, keyed with the window last): forward 7.87 at (2048, 1024, 512),
+# where the heuristic's (512, 512, 256) reads 13.77; dQ 8.56 (9.32); dK/dV
+# 11.91 (14.99): long strips, whose edge and diagonal squares are few, over
+# tiles of 1,024.
+for _kind in ("TPU v5 lite", "TPU v5e"):
+    PRETUNED[(_kind, 16384, 128, "bfloat16", True)] = (
+        (1024, 1024, 512), (2048, 1024, 256), (1024, 2048, 256))
+    PRETUNED[(_kind, 16384, 128, "bfloat16", True, 4096)] = (
+        (2048, 1024, 512), (2048, 1024, 256), (1024, 2048, 256))
+
 _lock = threading.Lock()
 # PRETUNED's validated hits, by PRETUNED's key
-_mem_cache: Dict[Tuple[str, int, int, str, bool], Tuple[Blocks, ...]] = {}
+_mem_cache: Dict[tuple, Tuple[Blocks, ...]] = {}
 
 
 def _valid(blocks, t: int) -> Optional[Tuple[int, int]]:
@@ -82,15 +98,19 @@ def _valid(blocks, t: int) -> Optional[Tuple[int, int]]:
     return bq, bk
 
 
-def get_flash_schedule(t: int, d: int, dtype, causal: bool):
-    """What each of the three kernels wants at this shape, and the source
-    (``pretuned`` or ``heuristic``): ``{kernel: (block_q, block_k,
-    granule)}`` (granule ``None`` where the kernel's own fitting
-    decides)."""
+def get_flash_schedule(t: int, d: int, dtype, causal: bool,
+                       window: Optional[int] = None):
+    """What each of the three kernels wants at this shape (under a
+    ``window``: the ``window_flash_*`` kernels, which have rows of their
+    own), and the source (``pretuned`` or ``heuristic``): ``{kernel:
+    (block_q, block_k, granule)}`` (granule ``None`` where the kernel's own
+    fitting decides)."""
     from deepspeed_tpu.ops.pallas.flash_attention import KERNELS
 
     key = (jax.devices()[0].device_kind, int(t), int(d),
            jnp.dtype(dtype).name, bool(causal))
+    if window is not None:
+        key += (int(window),)
     with _lock:
         wanted = _mem_cache.get(key)
         if wanted is None:

@@ -30,6 +30,15 @@ twice that. A strip of the whole sequence has no loop at all: on the v5e
 that static schedule is what the kernels gain most from (PERF.md section
 6, PR 45).
 
+A window (``window``: key j for query i where ``0 <= i - j < window``) is
+three kernels of their own (``window_flash_fwd``, ``window_flash_bwd_dq``,
+``window_flash_bwd_dkv``; the section "under a window" below): the loop's
+tiles are bounded on both sides, the square the window's edge crosses is
+cut in ``granule`` slices as the diagonal's is, and the loop's operands
+reach VMEM as the band a strip reads. With or without a window K and V may
+hold fewer heads than q (grouped queries): a group's query heads read
+their KV head where it lies, through the BlockSpec's index map.
+
 On non-TPU backends the kernels run in Pallas interpreter mode, so the CPU
 test mesh exercises the exact same code path.
 
@@ -79,6 +88,14 @@ KERNEL_FWD = "flash_fwd"
 KERNEL_BWD_DQ = "flash_bwd_dq"
 KERNEL_BWD_DKV = "flash_bwd_dkv"
 KERNELS = (KERNEL_FWD, KERNEL_BWD_DQ, KERNEL_BWD_DKV)
+# a launch under a window carries this before its kernel's name
+# (``window_flash_fwd``, ...): other kernels, counted by other functions
+WINDOW_PREFIX = "window_"
+
+
+def kernel_name(kernel: str, window: Optional[int] = None) -> str:
+    """The name a launch of ``kernel`` has in a trace and in the HLO."""
+    return kernel if window is None else WINDOW_PREFIX + kernel
 
 # past this much of blocks and tiles a call asks for more VMEM than the
 # 16 MB a kernel may use unasked (a whole strip of 4,096 positions does)
@@ -135,7 +152,8 @@ def _compiler_params(kernel, t, d, itemsize, blocks):
 
 def fit_blocks(kernel: str, t: int, causal: bool, block_q: int, block_k: int,
                granule: Optional[int] = None,
-               lane_aligned: bool = False) -> KernelBlocks:
+               lane_aligned: bool = False,
+               window: Optional[int] = None) -> KernelBlocks:
     """``wanted`` made a launch of ``kernel`` whose shapes are valid (its
     VMEM is :func:`_compiler_params`'s to ask for): blocks that divide
     ``t``; under the causal mask the loop's tile dividing the program's
@@ -144,13 +162,20 @@ def fit_blocks(kernel: str, t: int, causal: bool, block_q: int, block_k: int,
     the v5e slices of 128 leave least above the diagonal and feed the
     matrix unit worst, PERF.md section 6, PR 45). ``lane_aligned`` (segment
     ids: the key side's row of them is sliced along lanes) keeps blocks of
-    a 128-aligned ``t`` multiples of 128."""
+    a 128-aligned ``t`` multiples of 128. Under a ``window`` the strip
+    divides the window too: a strip's window then starts at a strip's edge,
+    and its lower edge is one square, cut as the diagonal's is."""
     block_q, block_k = _block(t, block_q), _block(t, block_k)
     if lane_aligned and t % 128 == 0:
         block_q, block_k = (
             b if b % 128 == 0 else 128 * _block(t // 128, max(1, b // 128))
             for b in (block_q, block_k))
     dkv = kernel == KERNEL_BWD_DKV
+    if window is not None:
+        if dkv:
+            block_k = math.gcd(block_k, window)
+        else:
+            block_q = math.gcd(block_q, window)
     if causal:
         if dkv and block_k % block_q:
             block_q = math.gcd(block_q, block_k)
@@ -162,12 +187,26 @@ def fit_blocks(kernel: str, t: int, causal: bool, block_q: int, block_k: int,
     return KernelBlocks(block_q, block_k, granule)
 
 
-def tile_counts(kernel: str, t: int, causal: bool,
-                blocks: KernelBlocks) -> Dict[str, float]:
-    """Scores one head computes, needs (the causal half) and runs mask
-    arithmetic on, in tiles of ``block_q x block_k``."""
+def tile_counts(kernel: str, t: int, causal: bool, blocks: KernelBlocks,
+                window: Optional[int] = None) -> Dict[str, float]:
+    """Scores one head computes, needs (the causal half; under a ``window``
+    the band's part of it) and runs mask arithmetic on, in tiles of
+    ``block_q x block_k``."""
     bq, bk, g = blocks
     tile = float(bq * bk)
+    if window is not None:
+        # strips whose window reaches past the sequence's end (forward, dQ:
+        # its start) are causal strips; the others hold the window's edge
+        # square, cut as the diagonal's is, and the tiles between the two
+        strip = blocks.strip(kernel)
+        n, r = t // strip, strip // g
+        square = g * g * r * (r + 1) / 2
+        short, whole = window // strip, n - window // strip
+        return {"tiles_computed": (
+                    n * square + whole * (square + strip * (window - strip))
+                    + strip * strip * short * (short - 1) / 2) / tile,
+                "tiles_needed": window * (t - window / 2) / tile,
+                "tiles_masked": (n + whole) * r * g * g / tile}
     if not causal:
         n = t * t / tile
         return {"tiles_computed": n, "tiles_needed": n, "tiles_masked": 0.0}
@@ -214,6 +253,14 @@ def _mask_corner(s, keep, axis):
         [jnp.where(keep, s[:g], NEG_INF), s[g:]], axis=0)
 
 
+def _least(a, b):
+    return min(a, b) if isinstance(a, int) else jnp.minimum(a, b)
+
+
+def _most(a, b):
+    return max(a, b) if isinstance(a, int) else jnp.maximum(a, b)
+
+
 def _walk(n, body, init):
     """The loop over a strip's interior tiles; ``n`` None: a strip of the
     whole sequence under the causal mask has none, and no loop is emitted
@@ -222,15 +269,23 @@ def _walk(n, body, init):
 
 
 def interior_tiles(kernel: str, t: int, causal: bool, blocks: KernelBlocks,
-                   start):
+                   start, window: Optional[int] = None):
     """``(first, n)``: the tiles a strip's loop walks with no mask, ``n``
     of the loop's tile from position ``first`` of the loop's axis on, for
     the strip that starts at ``start`` (a program's offset, or a Python
     int). Under the causal mask they are the keys before the strip's own
     square (forward, dQ) or the rows after it (dK/dV); ``n`` None: a strip
-    of the whole sequence has none, and its kernel no loop."""
+    of the whole sequence has none, and its kernel no loop. Under a
+    ``window`` they stop short of the square its far edge crosses: ``window
+    - strip`` positions at most, fewer where the sequence ends first."""
     strip = blocks.strip(kernel)
     tile = blocks.block_q + blocks.block_k - strip
+    if window is not None:
+        if kernel == KERNEL_BWD_DKV:
+            first = start + strip
+            return first, (_least(start + window, t) - first) // tile
+        first = _most(start + strip - window, 0)
+        return first, (start - first) // tile
     if not causal:
         return 0, t // tile
     if strip == t:
@@ -303,12 +358,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, blocks, has_seg):
                           (r + 1) * granule, corner))
 
 
-def _specs(block, d, t):
+def _specs(block, d, t, groups=1):
     """BlockSpecs of one kernel: a strip's block of a ``[bh, t, d]`` tensor,
     a whole one, the same two of a ``[bh, t, LSE_LANES]`` row tensor, and
-    a whole ``[bh, LSE_LANES, t]`` column tensor."""
+    a whole ``[bh, LSE_LANES, t]`` column tensor. ``groups`` query heads to
+    a KV head: the whole tensor (K, V) is then the ``[bh / groups, t, d]``
+    one, read where it lies by every head of its group."""
     return (pl.BlockSpec((None, block, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, t, d), (lambda i, j: (i, 0, 0)) if groups == 1
+                         else (lambda i, j: (i // groups, 0, 0))),
             pl.BlockSpec((None, block, LSE_LANES), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, t, LSE_LANES), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((None, LSE_LANES, t), lambda i, j: (i, 0, 0)))
@@ -326,10 +384,12 @@ _jit_call = functools.partial(
 
 @_jit_call
 def _call_fwd(qf, kf, vf, seg, scale, causal, blocks, interpret=None):
-    """``flash_fwd`` on ``[bh, t, d]`` operands: ``(o, lse)``."""
+    """``flash_fwd`` on ``[bh, t, d]`` operands: ``(o, lse)`` (K and V of
+    ``[bh / groups, t, d]``: grouped queries)."""
     bh, t, d = qf.shape
     interpret = _interpret() if interpret is None else interpret
-    strip, whole, strip_rows, _, whole_cols = _specs(blocks.block_q, d, t)
+    strip, whole, strip_rows, _, whole_cols = _specs(
+        blocks.block_q, d, t, bh // kf.shape[0])
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           blocks=blocks, has_seg=seg is not None),
@@ -468,7 +528,8 @@ def _call_dq(operands, seg, scale, causal, blocks, interpret=None):
     """``flash_bwd_dq`` on ``(q, k, v, do, lse, delta)``, flat: dq."""
     bh, t, d = operands[0].shape
     interpret = _interpret() if interpret is None else interpret
-    strip, whole, strip_rows, _, whole_cols = _specs(blocks.block_q, d, t)
+    strip, whole, strip_rows, _, whole_cols = _specs(
+        blocks.block_q, d, t, bh // operands[1].shape[0])
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           blocks=blocks, has_seg=seg is not None),
@@ -486,10 +547,14 @@ def _call_dq(operands, seg, scale, causal, blocks, interpret=None):
 
 @_jit_call
 def _call_dkv(operands, seg, scale, causal, blocks, interpret=None):
-    """``flash_bwd_dkv`` on ``(q, k, v, do, lse, delta)``, flat: (dk, dv)."""
+    """``flash_bwd_dkv`` on ``(q, k, v, do, lse, delta)``, flat: (dk, dv),
+    a QUERY head's each (grouped queries: the group's sum is the caller's)."""
     bh, t, d = operands[0].shape
     interpret = _interpret() if interpret is None else interpret
     strip, whole, _, whole_rows, _ = _specs(blocks.block_k, d, t)
+    groups = bh // operands[1].shape[0]
+    kv_strip = strip if groups == 1 else pl.BlockSpec(
+        (None, blocks.block_k, d), lambda i, j: (i // groups, j, 0))
     # dkv slices the segments' row layout by q tile in-kernel and takes its
     # own k block from the column layout
     seg_specs = [] if seg is None else [
@@ -500,7 +565,7 @@ def _call_dkv(operands, seg, scale, causal, blocks, interpret=None):
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           blocks=blocks, has_seg=seg is not None),
         grid=(bh, t // blocks.block_k),
-        in_specs=[whole, strip, strip, whole, whole_rows, whole_rows]
+        in_specs=[whole, kv_strip, kv_strip, whole, whole_rows, whole_rows]
         + seg_specs,
         out_specs=[strip, strip],
         out_shape=[out, out],
@@ -535,6 +600,343 @@ def _bwd_impl(scale, causal, schedule, q, k, v, o, lse, do, seg=None):
 def _bwd(scale, causal, schedule, res, g):
     q, k, v, o, lse = res
     return _bwd_impl(scale, causal, schedule, q, k, v, o, lse, g)
+
+
+# ---------------------------------------------------------------------------
+# under a window: key j is seen by query i where 0 <= i - j < window
+# ---------------------------------------------------------------------------
+# A strip divides the window (``fit_blocks``), so the keys a strip of
+# queries at ``q0`` sees are, in order: the square the window's edge
+# crosses (at ``q0 - window``; row r of it sees the columns AFTER r: the
+# diagonal's mask, negated), ``window - strip`` keys that every row sees
+# (the loop's tiles), and the strip's own square under the diagonal. Both
+# squares are cut in ``granule`` slices and only a slice's corner is
+# masked. A strip before ``window`` has no edge yet and is a causal strip.
+# dK/dV mirrors it: a strip of keys at ``k0`` is seen by its own square's
+# rows, by ``window - strip`` rows whole, and by the square at ``k0 +
+# window``, whose row r sees the columns after r; a strip whose window runs
+# past the sequence's end is a causal strip.
+# K and V (dK/dV: q, do, lse and delta) reach VMEM as the BAND a strip
+# reads, ``window + strip`` positions, not whole: an element-indexed block
+# that starts where the strip's window does, held to the sequence's ends.
+def band_rows(kernel: str, blocks: KernelBlocks, window: int) -> int:
+    """Positions of the loop's operands a strip under a window holds."""
+    return window + blocks.strip(kernel)
+
+
+def _band_start(kernel, t, blocks, window, start):
+    """Where the band of the strip at ``start`` begins: at the window's
+    start (forward, dQ) or at the strip (dK/dV), held inside the
+    sequence."""
+    if kernel == KERNEL_BWD_DKV:
+        return _least(start, t - band_rows(kernel, blocks, window))
+    return _most(start - window, 0)
+
+
+def _band_spec(kernel, t, width, blocks, window, groups=1):
+    """The element-indexed block of a ``[bh / groups, t, width]`` tensor
+    that holds a strip's band."""
+    strip = blocks.strip(kernel)
+    return pl.BlockSpec(
+        (None, pl.Element(band_rows(kernel, blocks, window)),
+         pl.Element(width)),
+        lambda i, j: (i // groups, pl.multiple_of(_band_start(
+            kernel, t, blocks, window, j * strip), strip), 0))
+
+
+def _upper_triangle(g):
+    """``[g, g]`` bool: row i of a corner on the window's edge sees its
+    column j."""
+    return jnp.logical_not(_lower_triangle(g))
+
+
+def _mask_edge(s, keep, axis):
+    """Mask the corner of a slice that the window's edge crosses: its first
+    ``keep.shape[1]`` columns (``axis`` 1) or last ``keep.shape[0]`` rows
+    (``axis`` 0): where :func:`_mask_corner` masks the other end."""
+    if keep.shape == s.shape:
+        return jnp.where(keep, s, NEG_INF)
+    if axis == 1:
+        g = keep.shape[1]
+        return jnp.concatenate(
+            [jnp.where(keep, s[:, :g], NEG_INF), s[:, g:]], axis=1)
+    h = s.shape[0] - keep.shape[0]
+    return jnp.concatenate(
+        [s[:h], jnp.where(keep, s[h:], NEG_INF)], axis=0)
+
+
+def _by_slice(granule, n, carry, one):
+    """``one(r, rows, carry's rows)`` for each ``granule`` slice of a
+    strip's ``n`` rows (columns), put together again."""
+    parts = []
+    for r in range(n // granule):
+        rows = slice(r * granule, (r + 1) * granule)
+        parts.append(one(r, rows, tuple(x[rows] for x in carry)))
+    return tuple(jnp.concatenate(xs, axis=0) for xs in zip(*parts))
+
+
+def _window_strip(kernel, blocks, window, t):
+    """Where a program's strip finds its squares in its band: ``(edge,
+    diagonal, first, n)``: whether the window's edge square is there (at
+    the band's start, forward and dQ; at ``window``, dK/dV), the offset of
+    the strip's own square, and the loop's tiles between them
+    (:func:`interior_tiles`, from the band's start)."""
+    strip = blocks.strip(kernel)
+    start = pl.program_id(1) * strip
+    band = _band_start(kernel, t, blocks, window, start)
+    first, n = interior_tiles(kernel, t, True, blocks, start, window)
+    edge = start + window + strip <= t if kernel == KERNEL_BWD_DKV \
+        else start >= window
+    return edge, pl.multiple_of(start - band, strip), first - band, n
+
+
+def _fwd_window_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, window,
+                       blocks, t):
+    bq, block_k, granule = blocks
+    d = q_ref.shape[-1]
+    edge, diagonal, first, n_interior = _window_strip(
+        KERNEL_FWD, blocks, window, t)
+    q = _scaled(q_ref[...], scale)
+
+    def tile(rows, carry, k0, width, mask):
+        m, l, acc = carry
+        k_blk = k_ref[pl.ds(k0, width), :]
+        v_blk = v_ref[pl.ds(k0, width), :]
+        s = mask(_dot(q[rows], k_blk, (1, 1)))
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + _dot(p.astype(v_blk.dtype), v_blk, (1, 0))
+        return m_new, l_new, acc_new
+
+    def interior(j, carry):
+        k0 = pl.multiple_of(first + j * block_k, block_k)
+        return tile(slice(None), carry, k0, block_k, lambda s: s)
+
+    upper, corner = _upper_triangle(granule), _lower_triangle(granule)
+    # the window's edge: slice r's rows see the square's columns from their
+    # own corner on (a row that sees none of them is put right by its
+    # diagonal, as a row of another segment is)
+    carry = jax.lax.cond(
+        edge, lambda carry: _by_slice(
+            granule, bq, carry, lambda r, rows, c: tile(
+                rows, c, r * granule, bq - r * granule,
+                lambda s: _mask_edge(s, upper, 1))),
+        lambda carry: carry,
+        (jnp.full((bq, 1), NEG_INF, jnp.float32),
+         jnp.zeros((bq, 1), jnp.float32), jnp.zeros((bq, d), jnp.float32)))
+    carry = jax.lax.fori_loop(0, n_interior, interior, carry)
+    for r in range(bq // granule):
+        rows = slice(r * granule, (r + 1) * granule)
+        m, l, acc = tile(rows, tuple(x[rows] for x in carry), diagonal,
+                         (r + 1) * granule,
+                         lambda s: _mask_corner(s, corner, 1))
+        o_ref[rows, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[rows, :] = jnp.broadcast_to(m + jnp.log(l),
+                                            (m.shape[0], LSE_LANES))
+
+
+def _bwd_dq_window_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          dq_ref, *, scale, window, blocks, t):
+    bq, block_k, granule = blocks
+    d = q_ref.shape[-1]
+    edge, diagonal, first, n_interior = _window_strip(
+        KERNEL_BWD_DQ, blocks, window, t)
+    q = _scaled(q_ref[...], scale)
+    do = do_ref[...]
+    lse = lse_ref[...][:, :1]
+    delta = delta_ref[...][:, :1]
+
+    def tile(rows, dq, k0, width, mask):
+        k_blk = k_ref[pl.ds(k0, width), :]
+        v_blk = v_ref[pl.ds(k0, width), :]
+        p = jnp.exp(mask(_dot(q[rows], k_blk, (1, 1))) - lse[rows])
+        dp = _dot(do[rows], v_blk, (1, 1))
+        ds = (p * (dp - delta[rows])).astype(k_blk.dtype)
+        return dq + _dot(ds, k_blk, (1, 0))
+
+    def interior(j, dq):
+        k0 = pl.multiple_of(first + j * block_k, block_k)
+        return tile(slice(None), dq, k0, block_k, lambda s: s)
+
+    upper, corner = _upper_triangle(granule), _lower_triangle(granule)
+    (dq,) = jax.lax.cond(
+        edge, lambda carry: _by_slice(
+            granule, bq, carry, lambda r, rows, c: (tile(
+                rows, c[0], r * granule, bq - r * granule,
+                lambda s: _mask_edge(s, upper, 1)),)),
+        lambda carry: carry, (jnp.zeros((bq, d), jnp.float32),))
+    dq = jax.lax.fori_loop(0, n_interior, interior, dq)
+    for r in range(bq // granule):
+        rows = slice(r * granule, (r + 1) * granule)
+        dq_r = tile(rows, dq[rows], diagonal, (r + 1) * granule,
+                    lambda s: _mask_corner(s, corner, 1))
+        dq_ref[rows, :] = (dq_r * scale).astype(dq_ref.dtype)
+
+
+def _bwd_dkv_window_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                           dk_ref, dv_ref, *, scale, window, blocks, t):
+    block_q, bk, granule = blocks
+    d = k_ref.shape[-1]
+    edge, diagonal, first, n_interior = _window_strip(
+        KERNEL_BWD_DKV, blocks, window, t)
+    k = _scaled(k_ref[...], scale)
+    v = v_ref[...]
+
+    def tile(cols, carry, q0, height, mask):
+        dk, dv = carry
+        q_blk = q_ref[pl.ds(q0, height), :]
+        do_blk = do_ref[pl.ds(q0, height), :]
+        lse_blk = lse_ref[pl.ds(q0, height), :1]
+        delta_blk = delta_ref[pl.ds(q0, height), :1]
+        p = jnp.exp(mask(_dot(q_blk, k[cols], (1, 1))) - lse_blk)
+        dv = dv + _dot(p.astype(do_blk.dtype), do_blk, (0, 0))
+        dp = _dot(do_blk, v[cols], (1, 1))
+        ds = (p * (dp - delta_blk)).astype(q_blk.dtype)
+        return dk + _dot(ds, q_blk, (0, 0)), dv
+
+    def interior(i, carry):
+        q0 = pl.multiple_of(first + i * block_q, block_q)
+        return tile(slice(None), carry, q0, block_q, lambda s: s)
+
+    upper, corner = _upper_triangle(granule), _lower_triangle(granule)
+    # the window's edge: slice c's columns are seen by the square's rows up
+    # to their own corner
+    carry = jax.lax.cond(
+        edge, lambda carry: _by_slice(
+            granule, bk, carry, lambda c, cols, x: tile(
+                cols, x, window, (c + 1) * granule,
+                lambda s: _mask_edge(s, upper, 0))),
+        lambda carry: carry,
+        (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32)))
+    carry = jax.lax.fori_loop(0, n_interior, interior, carry)
+    for c in range(bk // granule):
+        cols = slice(c * granule, (c + 1) * granule)
+        dk, dv = tile(cols, tuple(x[cols] for x in carry),
+                      diagonal + c * granule, bk - c * granule,
+                      lambda s: _mask_corner(s, corner, 0))
+        dk_ref[cols, :] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[cols, :] = dv.astype(dv_ref.dtype)
+
+
+def _window_call(kernel, body, operands, banded, outs, scale, window, blocks,
+                 interpret):
+    """One launch under a window. ``operands``: flat ``[bh, t, ...]`` (K
+    and V ``[bh / groups, t, d]``); ``banded``: which of them a program
+    holds as its band, the others as its strip; ``outs``: the last
+    dimension of each result, a strip's block of ``[bh, t, ...]``."""
+    bh, t, d = operands[0].shape
+    groups = bh // operands[1].shape[0]
+    strip = blocks.strip(kernel)
+
+    def spec(x, band, kv):
+        if band:
+            return _band_spec(kernel, t, x.shape[-1], blocks, window,
+                              groups if kv else 1)
+        return pl.BlockSpec(
+            (None, strip, x.shape[-1]),
+            (lambda i, j: (i // groups, j, 0)) if kv and groups > 1
+            else (lambda i, j: (i, j, 0)))
+
+    out_specs = [pl.BlockSpec((None, strip, w), lambda i, j: (i, j, 0))
+                 for w, _ in outs]
+    out_shape = [jax.ShapeDtypeStruct((bh, t, w), dtype) for w, dtype in outs]
+    return pl.pallas_call(
+        functools.partial(body, scale=scale, window=window, blocks=blocks,
+                          t=t),
+        grid=(bh, t // strip),
+        in_specs=[spec(x, band, n in (1, 2))
+                  for n, (x, band) in enumerate(zip(operands, banded))],
+        out_specs=out_specs, out_shape=out_shape, interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_asked()),
+        name=kernel_name(kernel, window),
+    )(*operands)
+
+
+_jit_window_call = functools.partial(
+    jax.jit, static_argnames=("scale", "window", "blocks", "interpret"))
+
+
+@_jit_window_call
+def _call_fwd_window(qf, kf, vf, scale, window, blocks, interpret):
+    """``window_flash_fwd``: ``(o, lse)``."""
+    return _window_call(
+        KERNEL_FWD, _fwd_window_kernel, (qf, kf, vf), (False, True, True),
+        ((qf.shape[-1], qf.dtype), (LSE_LANES, jnp.float32)), scale, window,
+        blocks, interpret)
+
+
+@_jit_window_call
+def _call_dq_window(operands, scale, window, blocks, interpret):
+    """``window_flash_bwd_dq`` on ``(q, k, v, do, lse, delta)``: dq."""
+    q = operands[0]
+    return _window_call(
+        KERNEL_BWD_DQ, _bwd_dq_window_kernel, operands,
+        (False, True, True, False, False, False),
+        ((q.shape[-1], q.dtype),), scale, window, blocks, interpret)[0]
+
+
+@_jit_window_call
+def _call_dkv_window(operands, scale, window, blocks, interpret):
+    """``window_flash_bwd_dkv`` on ``(q, k, v, do, lse, delta)``: (dk,
+    dv), a query head's each."""
+    q = operands[0]
+    return _window_call(
+        KERNEL_BWD_DKV, _bwd_dkv_window_kernel, operands,
+        (True, False, False, True, True, True),
+        ((q.shape[-1], q.dtype),) * 2, scale, window, blocks, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_kv(q, k, v, scale, window, schedule):
+    """Causal attention of ``q [b, t, h, d]`` over ``k`` / ``v`` ``[b, t,
+    h_kv, d]`` (``h / h_kv`` query heads read one KV head where it lies),
+    under a ``window`` or (None) none: ``o [b * h, t, d]``."""
+    return _flash_kv_fwd(q, k, v, scale, window, schedule)[0]
+
+
+def _flash_kv_fwd(q, k, v, scale, window, schedule):
+    from jax.ad_checkpoint import checkpoint_name
+
+    if window is None:
+        o, lse = _call_fwd(_flat(q), _flat(k), _flat(v), None, scale, True,
+                           schedule[0], interpret=_interpret())
+    else:
+        o, lse = _call_fwd_window(_flat(q), _flat(k), _flat(v), scale,
+                                  window, schedule[0], _interpret())
+    o = checkpoint_name(o, "attn_out")
+    lse = checkpoint_name(lse, "attn_lse")
+    return o, (q, k, v, o, lse)
+
+
+def _flash_kv_bwd(scale, window, schedule, res, do):
+    q, k, v, o, lse = res
+    b, t, h, d = q.shape
+    operands = (_flat(q), _flat(k), _flat(v), do, lse, row_delta(o, do))
+    if window is None:
+        dq = _call_dq(operands, None, scale, True, schedule[1],
+                      interpret=_interpret())
+        dk, dv = _call_dkv(operands, None, scale, True, schedule[2],
+                           interpret=_interpret())
+    else:
+        dq = _call_dq_window(operands, scale, window, schedule[1],
+                             _interpret())
+        dk, dv = _call_dkv_window(operands, scale, window, schedule[2],
+                                  _interpret())
+
+    def of_kv_head(x):
+        """A query head's each to their KV head's sum, ``[b, t, h_kv, d]``."""
+        x = x.reshape(b, k.shape[2], h // k.shape[2], t, d)
+        return x.astype(jnp.float32).sum(2).astype(x.dtype).transpose(
+            0, 2, 1, 3)
+
+    return (dq.reshape(b, h, t, d).transpose(0, 2, 1, 3), of_kv_head(dk),
+            of_kv_head(dv))
+
+
+_flash_kv.defvjp(_flash_kv_fwd, _flash_kv_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -589,42 +991,46 @@ def _flash_seg_bwd(scale, causal, schedule, res, g):
 _flash_seg.defvjp(_flash_seg_fwd, _flash_seg_bwd)
 
 
-def schedule_plan(t: int, causal: bool, schedule) -> Dict[str, dict]:
+def schedule_plan(t: int, causal: bool, schedule,
+                  window: Optional[int] = None) -> Dict[str, dict]:
     """What the ``flash.plan`` event says of each kernel: its blocks and
-    its tile counts. ``heads``: a grid step holds one head; several were
-    swept on the v5e and bought nothing (PERF.md section 6, PR 45)."""
+    its tile counts (under a ``window``: the band's). ``heads``: a grid
+    step holds one head; several were swept on the v5e and bought nothing
+    (PERF.md section 6, PR 45)."""
     return {kernel: {**blocks._asdict(), "heads": 1,
-                     **tile_counts(kernel, t, causal, blocks)}
+                     **tile_counts(kernel, t, causal, blocks, window)}
             for kernel, blocks in zip(KERNELS, schedule)}
 
 
 def resolve_schedule(t: int, d: int, dtype, causal: bool, *,
                      block_q: int = None, block_k: int = None,
-                     lane_aligned: bool = False
+                     lane_aligned: bool = False, window: int = None
                      ) -> Tuple[Tuple[KernelBlocks, ...], str]:
     """The three kernels' schedules for one launch, and where the blocks
     came from (``explicit``, or ``get_flash_schedule``'s source). Publishes
-    the ``flash.plan`` event: once per trace of a call, never per step."""
+    the ``flash.plan`` event (with the ``window``, where there is one):
+    once per trace of a call, never per step."""
     wanted, source = {}, "explicit"
     if block_q is None or block_k is None:
         from deepspeed_tpu.ops.pallas.autotune import get_flash_schedule
 
-        wanted, source = get_flash_schedule(t, d, dtype, causal)
+        wanted, source = get_flash_schedule(t, d, dtype, causal, window)
     schedule = []
     for kernel in KERNELS:
         bq, bk, granule = wanted.get(kernel, (None,) * 3)
         schedule.append(fit_blocks(
             kernel, t, causal, bq if block_q is None else block_q,
             bk if block_k is None else block_k, granule,
-            lane_aligned=lane_aligned))
+            lane_aligned=lane_aligned, window=window))
     publish(KIND_FLASH_PLAN, t=t, d=d, causal=bool(causal), source=source,
-            kernels=schedule_plan(t, causal, schedule))
+            kernels=schedule_plan(t, causal, schedule, window),
+            **({} if window is None else {"window": window}))
     return tuple(schedule), source
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
                     segment_ids=None, block_q: int = None,
-                    block_k: int = None):
+                    block_k: int = None, window: int = None):
     """Blockwise attention over ``[batch, seq, heads, head_dim]`` inputs.
 
     Memory is O(seq) per program instead of O(seq^2); the [T, T] score matrix
@@ -640,14 +1046,37 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
     ``ops/pallas/autotune.py`` (the pretuned table, which may name each
     kernel's own -> the historical want-512 divisor heuristic); pass them
     explicitly to pin all three kernels.
+
+    ``window``: position i attends j iff ``0 <= i - j < window`` (causal,
+    no segments); tiles wholly outside that band are not computed, and K
+    and V reach a program as the band its strip reads (the
+    ``window_flash_*`` kernels). ``k`` and ``v`` may hold fewer heads than
+    ``q`` (``[batch, seq, kv_heads, head_dim]``, causal, no segments): the
+    ``heads / kv_heads`` query heads of a group read their KV head where it
+    lies, and its gradient is the group's sum.
     """
     b, t, h, d = q.shape
     if scale is None:
         scale = 1.0 / np.sqrt(d)
+    if window is not None and window >= t:
+        window = None               # every earlier position: the causal mask
+    grouped = k.shape[2] != h
+    if window is not None or grouped:
+        if not causal or segment_ids is not None or h % k.shape[2] \
+                or (window is not None and window < 1):
+            raise ValueError(
+                "a window and grouped queries are built under the causal "
+                "mask without segment_ids, for whole groups of heads and a "
+                f"window >= 1; got causal={causal}, segment_ids "
+                f"{'given' if segment_ids is not None else 'None'}, "
+                f"{h} over {k.shape[2]} heads, window={window}")
     schedule, _ = resolve_schedule(t, d, q.dtype, causal,
                                    block_q=block_q, block_k=block_k,
-                                   lane_aligned=segment_ids is not None)
-    if segment_ids is None:
+                                   lane_aligned=segment_ids is not None,
+                                   window=window)
+    if window is not None or grouped:
+        of = _flash_kv(q, k, v, float(scale), window, schedule)
+    elif segment_ids is None:
         of = _flash(q, k, v, float(scale), bool(causal), schedule)
     else:
         if segment_ids.shape != (b, t):

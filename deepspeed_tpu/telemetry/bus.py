@@ -72,7 +72,9 @@ KIND_ZERO3_GATHER_PLAN = "zero3.gather_plan"
 # published once when a call is traced: t, d, causal, source (where the
 # blocks came from: explicit, pretuned, heuristic) and per
 # kernel block_q, block_k, heads (a grid step), granule, tiles_computed,
-# tiles_needed, tiles_masked (a head's, in tiles of block_q x block_k)
+# tiles_needed, tiles_masked (a head's, in tiles of block_q x block_k);
+# under a window (the window_flash_* kernels) also window, and the tile
+# counts are the band's
 KIND_FLASH_PLAN = "flash.plan"
 # a program's backend compile, or its load from the persistent cache, ended
 # (telemetry/builds.py): program, key, trace_s, lower_s, compile_or_load_s,

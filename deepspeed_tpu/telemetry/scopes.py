@@ -124,7 +124,10 @@ SCOPE_DSA_ATTN = "dsa_attn"
 # ``GPTConfig.attention_kind``): scores, softmax, weighted sum and the
 # output's gate of the layers that see a window of positions, and of those
 # that see every position. Their row writes are ``kv_cache_write``'s and a
-# whole leaf that no scope owns is ``kv_cache_carry``'s
+# whole leaf that no scope owns is ``kv_cache_carry``'s. A call without a
+# cache (a training step) times its flash kernels under them too: the
+# ``window_flash_*`` calls under ``window_attn``, the ``flash_*`` calls of
+# the layers that see every position under ``full_attn``
 SCOPE_WINDOW_ATTN = "window_attn"
 SCOPE_FULL_ATTN = "full_attn"
 # the same stack with LATENT attention as each kind's mixer (models/

@@ -49,6 +49,7 @@ import falcon_h1_tiny  # noqa: E402
 import keye_vl_tiny  # noqa: E402
 import lfm2_tiny  # noqa: E402
 import rehearsal  # noqa: E402
+import smallthinker_tiny  # noqa: E402
 import trinity_tiny  # noqa: E402
 
 falcon_h1_tiny.register(rehearsal)
@@ -58,6 +59,7 @@ keye_vl_tiny.register(rehearsal)
 lfm2_tiny.register(rehearsal)
 trinity_tiny.register(rehearsal)
 dots3_tiny.register(rehearsal)
+smallthinker_tiny.register(rehearsal)
 _PREDATE_REDUCED = {
     falcon_h1_tiny.PREDATES_REDUCED: "test_perfbench_falcon_h1.py",
     brumby_tiny.PREDATES_REDUCED: "test_perfbench_brumby.py",
@@ -65,7 +67,8 @@ _PREDATE_REDUCED = {
     keye_vl_tiny.PREDATES_REDUCED: "test_perfbench_keye_vl.py",
     lfm2_tiny.PREDATES_REDUCED: "test_perfbench_lfm2.py",
     trinity_tiny.PREDATES_REDUCED: "test_perfbench_trinity.py",
-    dots3_tiny.PREDATES_REDUCED: "test_perfbench_dots3.py"}
+    dots3_tiny.PREDATES_REDUCED: "test_perfbench_dots3.py",
+    smallthinker_tiny.PREDATES_REDUCED: "test_perfbench_smallthinker.py"}
 
 
 def pytest_collection_modifyitems(items):

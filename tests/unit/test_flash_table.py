@@ -119,11 +119,15 @@ class TestTable:
         assert set(wanted.values()) == {(512, 256, None)}
 
     def test_entries_are_valid_launches(self):
-        for (kind, seq, d, dt, causal), kernels in PRETUNED.items():
-            assert len(kernels) == 3
+        for (kind, seq, d, dt, causal, *window), kernels in PRETUNED.items():
+            assert len(kernels) == 3 and len(window) <= 1
             for blocks in kernels:
                 assert autotune._valid(blocks, seq) == blocks[:2], (kind,
                                                                     seq)
+            for kernel, blocks in zip(KERNELS, kernels):
+                # a row under a window is launched as it is written
+                assert not window or tuple(fit_blocks(
+                    kernel, seq, True, *blocks, window=window[0])) == blocks
 
     def test_an_entry_that_does_not_divide_the_shape_is_not_launched(
             self, monkeypatch):
@@ -148,7 +152,7 @@ class TestTable:
 
 # 1,024 at a head of 64 and the unmasked 1,024 are on no chip's rows either
 LACKING = [(128, 128, True), (384, 128, True), (640, 128, True),
-           (1536, 128, True), (16384, 128, True), (777, 128, True),
+           (1536, 128, True), (32768, 128, True), (777, 128, True),
            (1024, 64, True), (1024, 128, False)]
 
 
@@ -205,7 +209,7 @@ class TestNothingElseHasASay:
 
     def test_the_shape_is_all_the_resolver_takes(self):
         assert list(inspect.signature(get_flash_schedule).parameters) == [
-            "t", "d", "dtype", "causal"]
+            "t", "d", "dtype", "causal", "window"]
         for removed in ("get_flash_blocks", "benchmark_candidates",
                         "cache_path", "cache_key"):
             assert not hasattr(autotune, removed)

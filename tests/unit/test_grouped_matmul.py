@@ -869,6 +869,63 @@ def test_the_flash_kernels_compile_for_the_chip_under_the_tables_schedule(
         fa.KERNEL_BWD_DKV: (2, 0)}[kernel]
 
 
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"])
+@pytest.mark.parametrize("window", [4096, None], ids=["window", "full"])
+def test_the_flash_kernels_compile_at_16k_over_grouped_queries(
+        one_chip, window, kernel, monkeypatch):
+    """The window-and-full training cell's shape (28 query heads over 4 KV
+    heads of 128, 16,384 positions): with and without the window each
+    kernel compiles for the chip under the schedule the resolver gives,
+    keeps the name a reader tells the two families apart by, and holds K
+    and V at their own head count; under the window the loop's operands
+    are bands of ``window + strip`` positions, not the sequence."""
+    import importlib
+
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(autotune.jax, "devices", lambda: [
+        type("D", (), {"device_kind": "TPU v5 lite"})()])
+    autotune.clear_memory_cache()
+    h, kv, t, d = 28, 4, 16384, 128
+    wanted, _ = autotune.get_flash_schedule(t, d, jnp.bfloat16, True, window)
+    blocks = fa.fit_blocks(kernel, t, True, *wanted[kernel], window=window)
+    autotune.clear_memory_cache()
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, k = spec(h, t, d), spec(kv, t, d)
+    rows = spec(h, t, fa.LSE_LANES, dtype=jnp.float32)
+    scale = d ** -0.5
+    if window is None:
+        run = {fa.KERNEL_FWD: lambda q, k, v, do, lse, delta: fa._call_fwd(
+                   q, k, v, None, scale, True, blocks),
+               fa.KERNEL_BWD_DQ: lambda *ops: fa._call_dq(
+                   ops, None, scale, True, blocks),
+               fa.KERNEL_BWD_DKV: lambda *ops: fa._call_dkv(
+                   ops, None, scale, True, blocks)}[kernel]
+    else:
+        run = {fa.KERNEL_FWD: lambda q, k, v, do, lse, delta:
+               fa._call_fwd_window(q, k, v, scale, window, blocks, False),
+               fa.KERNEL_BWD_DQ: lambda *ops: fa._call_dq_window(
+                   ops, scale, window, blocks, False),
+               fa.KERNEL_BWD_DKV: lambda *ops: fa._call_dkv_window(
+                   ops, scale, window, blocks, False)}[kernel]
+        assert fa.band_rows(kernel, blocks, window) \
+            == window + blocks.strip(kernel) < t
+    text = compiled_for_the_chip(jax.jit(run), q, k, k, q, rows, rows)
+    (call,) = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "custom-call(" in line]
+    assert f"%{fa.kernel_name(kernel, window)}" in call
+    assert ("%window_" in call) == (window is not None)
+    operands = call.split(" custom-call(")[1]
+    assert operands.count(f"bf16[{kv},{t},{d}]") == 2       # K and V
+    results = call.split(" custom-call(")[0].split(" = ", 1)[1]
+    assert results.count(f"bf16[{h},{t},{d}]") == {
+        fa.KERNEL_FWD: 1, fa.KERNEL_BWD_DQ: 1, fa.KERNEL_BWD_DKV: 2}[kernel]
+
+
 def test_the_selected_decode_step_compiles_for_the_chip_at_the_cells_shape(
         one_chip, monkeypatch):
     """``ops/indexed_attention.py`` ``decode_step`` inside a scan over the
