@@ -147,6 +147,13 @@ def reading_in_place() -> bool:
     return _STACKED.get() is not None
 
 
+def serving_call() -> bool:
+    """Whether the layer traced now is one turn of a serving call's layer
+    scan, as the scan's owner said (:func:`matrices_in_place`)."""
+    stacked = _STACKED.get()
+    return stacked is not None and bool(stacked[2])
+
+
 class StackedExperts(nn.Module):
     """[E, C, M] -> [E, C, M] two-layer FFN, vectorized over experts; or,
     with ``group_sizes`` ([E], summing to R), [R, M] -> [R, M] over rows

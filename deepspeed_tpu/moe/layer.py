@@ -67,6 +67,7 @@ from deepspeed_tpu.moe.sharded_moe import (
     combine_tokens,
     dispatch_rows,
     dispatch_tokens,
+    fetches_live_rows,
     router_z_loss,
     rows_computed,
     sort_by_expert,
@@ -199,7 +200,9 @@ class MoE(nn.Module):
         a layer's own tensor (moe/experts.py ``expert_matrices``),
         ``held``, how many experts' matrices the layer
         holds (``computed`` is of those), ``routed_here``, the pairs
-        routed to them, and, of a layer with an ``expert_bias``,
+        routed to them, ``rows_moved``, the sorted rows the dispatch
+        fetched (the live ones under the row-fetch kernels, every pair
+        under XLA's gather), and, of a layer with an ``expert_bias``,
         ``bias_changed``, the tokens whose chosen experts are not their k
         largest uncorrected scores. (``init`` makes every collection
         mutable and would return them beside the parameters.)"""
@@ -263,11 +266,24 @@ class MoE(nn.Module):
                     weights = jnp.where(here, weights, 0.0)
                     sizes = jax.lax.dynamic_slice_in_dim(sizes, first, held)
                     routed_here = jnp.sum(here, dtype=jnp.int32)
+            tiles = grouped_matmul_tiles(tokens.shape[0] * self.k, d_model,
+                                         self.d_hidden, held, self.dtype)
+            # a share moves the rows routed here alone where the row-fetch
+            # kernels take the call (the grouped-matmul kernel reads no
+            # row past them; ``ragged_dot``'s gradient is not known to). A
+            # layer that holds all its experts keeps XLA's gather: every
+            # row is live there, and what the kernels gain it (5% of
+            # OLMoE's step) they cost its set-up in tracing (PERF.md,
+            # section 6, PR 64)
+            live = {}
+            if self.experts_held is not None and tiles \
+                    and not self.is_initializing() and fetches_live_rows(
+                        tokens.shape[0], self.k, d_model, self.dtype):
+                live = {"n_live": jnp.sum(sizes, dtype=jnp.int32),
+                        "zero_to": tiles[0]}
             with jax.named_scope(SCOPE_MOE_DISPATCH):
                 order, inverse = sort_by_expert(groups)
-                rows = dispatch_rows(tokens, order, inverse, self.k)
-            tiles = grouped_matmul_tiles(rows.shape[0], d_model,
-                                         self.d_hidden, held, self.dtype)
+                rows = dispatch_rows(tokens, order, inverse, self.k, **live)
             with jax.named_scope(SCOPE_MOE_EXPERTS):
                 rows = self._experts()(rows, sizes)
                 if self.experts_held is not None and not tiles:
@@ -278,7 +294,7 @@ class MoE(nn.Module):
                         rows, jnp.zeros((), rows.dtype))
             with jax.named_scope(SCOPE_MOE_COMBINE):
                 y = combine_rows(rows, weights, order, inverse,
-                                 dtype=x.dtype)
+                                 dtype=x.dtype, **live)
             if self.n_shared:
                 with jax.named_scope(SCOPE_MOE_SHARED):
                     y = y + SharedExperts(
@@ -291,6 +307,7 @@ class MoE(nn.Module):
                         gmm_tiles=jnp.asarray(tiles or (0, 0, 0), jnp.int32),
                         in_place=jnp.int32(bool(tiles) and reading_in_place()),
                         held=jnp.int32(held), routed_here=routed_here,
+                        rows_moved=live.get("n_live", routed),
                         **({} if route.bias_changed is None
                            else {"bias_changed": route.bias_changed}))
             return (y.reshape(orig_shape), route.l_aux, route.l_z,

@@ -345,26 +345,100 @@ def sort_by_expert(experts: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return order, jnp.argsort(order).astype(jnp.int32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def dispatch_rows(tokens, order, inverse, k):
+# The crossover of the row-fetch kernels (ops/pallas/row_fetch.py): a layer
+# that holds a share of its experts moves its live rows through them where
+# it sorts at least this many pairs. Measured INSIDE the step program of
+# ``smallthinker-21b-train-16k`` on the v5e (98,304 pairs of 2,560 bf16,
+# 42-50% live: `moe_dispatch_share_of_step` 27.2 -> 12.2, chiprun_out/p64d
+# to p64f, PERF.md section 6, PR 64) and alone at that shape and at 65,536
+# pairs of 2,048 (chiprun_out/p64b); no program under it was measured with
+# the kernels, and the serving programs' passes stay on XLA's gather
+# whatever they sort (:func:`fetches_live_rows`)
+ROW_FETCH_MIN_PAIRS = 32768
+
+
+def fetches_live_rows(tokens: int, k: int, width: int, dtype) -> bool:
+    """Whether a dropless layer that holds a share of its experts, over
+    ``tokens`` tokens of ``width`` and ``k`` experts a token, moves its
+    live rows alone (those routed to the experts it holds), through the
+    row-fetch kernels (``n_live`` given to
+    :func:`dispatch_rows` and :func:`combine_rows`): at least
+    :data:`ROW_FETCH_MIN_PAIRS` pairs, shapes the kernels take
+    (``row_fetch.supported``), the rows whole on one device (a Pallas call
+    is opaque to the partitioner), and a call that something may
+    differentiate: a serving call keeps the gather it was lowered with
+    (moe/experts.py ``serving_call``: a long prompt's pass sorts more
+    pairs than a training step, and its programs are held to their text).
+    Static shapes and what the scan's owner says of the call, no option."""
+    if tokens * k < ROW_FETCH_MIN_PAIRS:
+        return False
+    from deepspeed_tpu.moe.experts import serving_call
+    from deepspeed_tpu.ops.pallas import row_fetch
+    from deepspeed_tpu.parallel.mesh import get_default_topology
+
+    topo = get_default_topology()
+    return (not serving_call() and topo.size("ep") == 1
+            and topo.size("tp") == 1
+            and row_fetch.supported(tokens, k, width, dtype))
+
+
+def dispatch_rows(tokens, order, inverse, k, n_live=None, zero_to=None):
     """[T, M] -> [T * k, M]: the rows of the pairs in sorted order (a gather
     by ``order // k``). Its transpose is a scatter-add of k rows into each
     token; ``inverse`` turns that into a gather and a sum over k, which the
-    TPU does at memory speed where it serialises a scatter."""
+    TPU does at memory speed where it serialises a scatter.
+
+    With ``n_live`` (an int32 scalar: the sorted rows before it are the
+    live ones, ``sum(group_sizes)``) the rows are moved by the row-fetch
+    kernels, which issue a copy for a live row alone: the rows from
+    ``n_live`` to the end of the block of ``zero_to`` rows (the consumer's
+    row tile) that holds row ``n_live`` are zeros, those past it are NOT
+    WRITTEN, and the transpose sums the live pairs of a token alone."""
+    if n_live is None:
+        return _take_rows(tokens, order, inverse, k)
+    return _fetch_dispatch(tokens, order, inverse, n_live, k, zero_to)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(tokens, order, inverse, k):
     return jnp.take(tokens, order // k, axis=0)
 
 
-def _dispatch_fwd(tokens, order, inverse, k):
-    return dispatch_rows(tokens, order, inverse, k), inverse
+def _take_rows_fwd(tokens, order, inverse, k):
+    return _take_rows(tokens, order, inverse, k), inverse
 
 
-def _dispatch_bwd(k, inverse, g):
+def _take_rows_bwd(k, inverse, g):
     pairs = jnp.take(g, inverse, axis=0).reshape(-1, k, g.shape[-1])
     return (jnp.sum(pairs.astype(jnp.float32), axis=1).astype(g.dtype),
             None, None)
 
 
-dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _fetch_dispatch(tokens, order, inverse, n_live, k, zero_to):
+    from deepspeed_tpu.ops.pallas import row_fetch
+
+    return row_fetch.fetch_rows(tokens, order // k, n_live, zero_to=zero_to)
+
+
+def _fetch_dispatch_fwd(tokens, order, inverse, n_live, k, zero_to):
+    return (_fetch_dispatch(tokens, order, inverse, n_live, k, zero_to),
+            (inverse, n_live))
+
+
+def _fetch_dispatch_bwd(k, zero_to, residuals, g):
+    from deepspeed_tpu.ops.pallas import row_fetch
+
+    inverse, n_live = residuals
+    return (row_fetch.fetch_sum_rows(
+        row_fetch.as_words(g, n_live), inverse.reshape(-1, k), None, n_live,
+        dtype=g.dtype), None, None, None)
+
+
+_fetch_dispatch.defvjp(_fetch_dispatch_fwd, _fetch_dispatch_bwd)
 
 
 @jax.custom_vjp
@@ -386,14 +460,60 @@ def _unsort_bwd(order, g):
 unsort_rows.defvjp(_unsort_fwd, _unsort_bwd)
 
 
-def combine_rows(rows, weights, order, inverse, dtype=None):
+def combine_rows(rows, weights, order, inverse, dtype=None, n_live=None,
+                 zero_to=None):
     """The weighted scatter-add of the expert outputs back onto their
     tokens, ``out[t] = sum_j weights[t, j] * rows[inverse[t * k + j]]``,
-    as a gather and a sum over k in float32."""
+    as a gather and a sum over k in float32.
+
+    With ``n_live`` (:func:`dispatch_rows`) through the row-fetch kernels:
+    the sum is over the pairs whose row lies before ``n_live``, in the
+    order j = 0 .. k-1, and no row from ``n_live`` on is read; the rows'
+    gradient is written as :func:`dispatch_rows` writes its rows (zeros to
+    ``zero_to``, nothing past it), a pair elsewhere gets no weight
+    gradient."""
     num_tokens, k = weights.shape
+    dtype = jnp.dtype(dtype or rows.dtype)
+    if n_live is not None:
+        return _fetch_combine(rows, weights, order, inverse, n_live, dtype,
+                              zero_to)
     pairs = unsort_rows(rows, order, inverse).reshape(num_tokens, k, -1)
     out = jnp.sum(pairs.astype(jnp.float32) * weights[..., None], axis=1)
-    return out.astype(dtype or rows.dtype)
+    return out.astype(dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _fetch_combine(rows, weights, order, inverse, n_live, dtype, zero_to):
+    return _fetch_combine_fwd(rows, weights, order, inverse, n_live, dtype,
+                              zero_to)[0]
+
+
+def _fetch_combine_fwd(rows, weights, order, inverse, n_live, dtype, zero_to):
+    from deepspeed_tpu.ops.pallas import row_fetch
+
+    # the rows as the copies read them are the residual, in the rows' place
+    words = row_fetch.as_words(rows, n_live)
+    out = row_fetch.fetch_sum_rows(words, inverse.reshape(weights.shape),
+                                   weights, n_live, dtype=dtype)
+    return out, (words, weights, order, inverse, n_live)
+
+
+def _fetch_combine_bwd(dtype, zero_to, residuals, g):
+    from deepspeed_tpu.ops.pallas import row_fetch
+
+    words, weights, order, inverse, n_live = residuals
+    k = weights.shape[1]
+    # (bf16 rows are read as uint32 words, float32 rows as themselves)
+    rows_dtype = jnp.bfloat16 if words.dtype == jnp.uint32 else words.dtype
+    d_rows = row_fetch.fetch_rows(
+        g, order // k, n_live, scale=jnp.take(weights.reshape(-1), order),
+        zero_to=zero_to).astype(rows_dtype)
+    d_weights = row_fetch.fetch_dot_rows(
+        words, inverse.reshape(weights.shape), g, n_live)
+    return d_rows, d_weights.astype(weights.dtype), None, None, None
+
+
+_fetch_combine.defvjp(_fetch_combine_fwd, _fetch_combine_bwd)
 
 
 def rows_computed(rows, experts, order, num_experts):

@@ -36,7 +36,7 @@ def split_moe_params(params) -> Tuple[Any, Any]:
 # leads it (a scan's layer axis, none for a layer on its own) is flattened
 _COUNTER_RANK = {"routed": 0, "computed": 1, "chosen": 2, "gmm_tiles": 1,
                  "in_place": 0, "held": 0, "routed_here": 0,
-                 "bias_changed": 0}
+                 "rows_moved": 0, "bias_changed": 0}
 
 
 def routing_stats(model, params, batch):
@@ -50,8 +50,9 @@ def routing_stats(model, params, batch):
     traced ``ragged_dot``), ``in_place`` [layers] (1 where the kernel read
     the stacked parameters with the layer as an index),
     ``held`` [layers] (the experts whose matrices
-    the layer holds: ``computed`` is of those) and ``routed_here``
-    [layers] (the pairs routed to them), and of layers with a correction
+    the layer holds: ``computed`` is of those), ``routed_here``
+    [layers] (the pairs routed to them) and ``rows_moved`` [layers] (the
+    sorted rows the dispatch fetched), and of layers with a correction
     bias ``bias_changed`` [layers] (the tokens whose chosen experts are not
     their k largest uncorrected scores). One forward program of its own,
     off the step."""
@@ -85,8 +86,11 @@ def publish_expert_load(model, params, batch):
     dropless path computes every pair). A layer that holds a share of its
     experts (``MoE.experts_held``) counts the held ones alone: ``held``
     (how many a layer), ``routed_here`` (the pairs routed to them, all
-    layers; ``tokens_dropped`` is then of those) and ``routed`` (all the
-    routers asked for); ``experts_with_rows_share`` is the share of the
+    layers; ``tokens_dropped`` is then of those), ``routed`` (all the
+    routers asked for) and ``rows_moved`` (the sorted rows the layers'
+    dispatch fetched, all layers: ``routed_here`` where the row-fetch
+    kernels move a share's rows, ``routed`` where XLA's gather moves every
+    pair); ``experts_with_rows_share`` is the share of the
     counted experts that computed at least one pair, the layers' mean (at
     a decode step's few rows the matrices of the others need not be
     read). Of the dropless path's grouped
@@ -136,6 +140,8 @@ def publish_expert_load(model, params, batch):
         held=int(stats["held"][0]) if "held" in stats else counts.shape[1],
         experts_with_rows_share=float((counts > 0).mean()),
         routed=int(stats["routed"].sum()), routed_here=int(here.sum()),
+        rows_moved=int(stats["rows_moved"].sum())
+        if "rows_moved" in stats else None,
         grouped_matmul=path, grouped_matmul_tiles=tiles,
         row_tile_visits_over_least=over_least,
         expert_matrices=("in_place" if stats["in_place"].all() else "slice")

@@ -1153,3 +1153,52 @@ def test_the_olmoe_train_step_slices_no_expert_tensor_out_of_the_stack(
              if "tpu_custom_call" in line and "custom-call(" in line]
     assert sum(f"%{gm.GMM_NAME}" in line for line in calls) == 6 + 3
     assert sum(f"%{gm.TGMM_NAME}" in line for line in calls) == 3
+
+
+# --- the row-fetch kernels at the window-and-full cell's shapes -------------
+ROW_FETCH_CALLS = ["rows", "rows_scaled", "words", "sum", "dot"]
+
+
+@pytest.mark.parametrize("call", ROW_FETCH_CALLS)
+def test_the_row_fetch_kernels_compile_for_the_chip_at_the_cells_shape(
+        one_chip, call, monkeypatch):
+    """98,304 sorted rows of 2,560 bf16 from 16,384 tokens, the live count
+    a run-time scalar: Mosaic takes the row copies out of the row-major
+    words, the strided loads and stores and the dynamic grid (interpret
+    mode refuses none of them), and XLA copies no ``[rows, width]``
+    operand on the way in or out."""
+    from deepspeed_tpu.ops.pallas import row_fetch as rf
+
+    monkeypatch.setattr(rf, "_interpret", lambda: False)
+    tokens, k, width = 16384, 6, 2560
+    rows = tokens * k
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    live = spec(dtype=jnp.int32)
+    run, args, name = {
+        "rows": (lambda s, i, n: rf.fetch_rows(s, i, n, zero_to=512),
+                 (spec(tokens, width), spec(rows, dtype=jnp.int32), live),
+                 rf.FETCH_NAME),
+        "rows_scaled": (
+            lambda s, i, n, w: rf.fetch_rows(s, i, n, w, zero_to=512),
+            (spec(tokens, width), spec(rows, dtype=jnp.int32), live,
+             spec(rows, dtype=jnp.float32)), rf.FETCH_NAME),
+        "words": (rf.as_words, (spec(rows, width), live), rf.WORDS_NAME),
+        "sum": (lambda w, i, p, n: rf.fetch_sum_rows(
+            w, i, p, n, dtype=jnp.bfloat16),
+            (spec(rows * width // 256, 128, dtype=jnp.uint32),
+             spec(tokens, k, dtype=jnp.int32),
+             spec(tokens, k, dtype=jnp.float32), live), rf.FETCH_SUM_NAME),
+        "dot": (rf.fetch_dot_rows,
+                (spec(rows * width // 256, 128, dtype=jnp.uint32),
+                 spec(tokens, k, dtype=jnp.int32), spec(tokens, width),
+                 live), rf.FETCH_DOT_NAME)}[call]
+    text = compiled_for_the_chip(jax.jit(run), *args)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line]
+    assert [c for c in calls if f"%{name}." in c or f"%{name} " in c]
+    assert not [line for line in text.splitlines()
+                if ("bf16[" in line or "u32[" in line)
+                and (" transpose(" in line or " copy(" in line)]

@@ -351,3 +351,154 @@ ENTRY %main (p: bf16[8,4]) -> bf16[8,4] {
         assert scopes.has_scope(table[name], scopes.SCOPE_MOE_EXPERTS)
     assert scopes.has_scope(table["add.1"], scopes.SCOPE_MOE_COMBINE)
     assert not scopes.has_scope(table["add.1"], scopes.SCOPE_MOE_EXPERTS)
+
+
+# --- a share moves the rows routed here alone (ops/pallas/row_fetch.py) ------
+def share_layer(dtype=jnp.float32):
+    """One layer that holds experts 4-7 of 16 at widths the kernels take,
+    its input and a cotangent."""
+    width = 128 if dtype == jnp.float32 else 256
+    moe = MoE(d_model=width, d_hidden=128, num_experts=16, k=4,
+              drop_tokens=False, gated_experts=True, dtype=dtype,
+              experts_held=(4, 4))
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 32, width)).astype(dtype)
+    params = moe.init(jax.random.PRNGKey(2), x)["params"]
+    params["gate"]["kernel"] = params["gate"]["kernel"] * 8.0
+    return moe, params, x, jax.random.normal(jax.random.PRNGKey(3), x.shape)
+
+
+def value_and_grads(moe, params, x, cot):
+    def loss(p, x):
+        y, l_aux, _, _ = moe.apply({"params": p}, x)
+        return jnp.sum(y.astype(jnp.float32) * cot) + l_aux, y
+
+    (_, y), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True))(params, x)
+    return [y] + jax.tree_util.tree_leaves(grads)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_a_share_under_the_row_fetch_kernels_is_the_share_under_xla(
+        dtype, monkeypatch):
+    """The layer's output and every gradient with the live rows moved by
+    the kernels against the same layer under XLA's gathers of all pairs
+    (the crossover out of reach): the same rows, the same sums."""
+    moe, params, x, cot = share_layer(dtype)
+    want = value_and_grads(moe, params, x, cot)
+    monkeypatch.setattr(sharded_moe, "ROW_FETCH_MIN_PAIRS", 0)
+    got = value_and_grads(moe, params, x, cot)
+    for g, w in zip(got, want):
+        assert np.isfinite(np.asarray(g, np.float32)).all()
+        assert rel(g.astype(jnp.float32), w.astype(jnp.float32)) < 1e-6
+
+
+@pytest.mark.parametrize("routing", ["seeded", "edge"])
+def test_nothing_reads_a_row_the_fetch_did_not_write(routing, monkeypatch):
+    """Between dispatch and experts every row the fetch left unwritten
+    (those past the consumer's tile that holds row ``n_live``: the rows
+    from ``n_live`` to that tile's end are the fetch's zeros, which the
+    matrices' gradient multiplies by) is replaced by NaN, and so is the
+    rows' cotangent on its way back: output and gradients stay finite and
+    are the unpoisoned ones. ``edge``: the live rows end on a tile's edge
+    and the last two held experts get none, so their one visit each (the
+    matrices' gradient stores its zeros there) lies in a tile of no live
+    row: the first chip run of PR 64 trained into NaN there."""
+    from deepspeed_tpu.moe import layer
+    from deepspeed_tpu.moe.experts import grouped_matmul_tiles
+
+    moe, params, x, cot = share_layer()
+    monkeypatch.setattr(sharded_moe, "ROW_FETCH_MIN_PAIRS", 0)
+    tm = grouped_matmul_tiles(512, 128, 128, 4, jnp.float32)[0]
+    if routing == "edge":
+        def by_hand(logits, k, *rest, **kw):
+            route = sharded_moe.topk_routing(logits, k, *rest, **kw)
+            # the first tm / 2 tokens send two pairs here (experts 4 and 5
+            # of the held 4..7), every other pair goes elsewhere
+            here = (jnp.arange(128) < tm // 2)[:, None]
+            experts = jnp.where(here, jnp.array([4, 5, 0, 1]),
+                                jnp.array([0, 1, 2, 3])).astype(jnp.int32)
+            return route._replace(
+                experts=experts,
+                exp_counts=jnp.bincount(experts.reshape(-1), length=16)
+                .astype(jnp.int32))
+
+        monkeypatch.setattr(layer, "topk_routing", by_hand)
+    want = value_and_grads(moe, params, x, cot)
+    seen = []
+
+    @jax.custom_vjp
+    def poison(rows, unwritten):
+        return jnp.where(unwritten, jnp.nan, rows)
+
+    poison.defvjp(lambda rows, unwritten: (poison(rows, unwritten),
+                                           unwritten),
+                  lambda unwritten, g: (jnp.where(unwritten, jnp.nan, g),
+                                        None))
+
+    def dispatch(tokens, order, inverse, k, n_live, zero_to):
+        rows = sharded_moe.dispatch_rows(tokens, order, inverse, k, n_live,
+                                         zero_to)
+        written = (n_live // zero_to + 1) * zero_to
+        seen.append(zero_to)
+        return poison(rows, jnp.arange(rows.shape[0])[:, None] >= written)
+
+    monkeypatch.setattr(layer, "dispatch_rows", dispatch)
+    got = value_and_grads(moe, params, x, cot)
+    assert seen == [tm] * len(seen) and tm < 512
+    for g, w in zip(got, want):
+        assert np.isfinite(np.asarray(g)).all()
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+    if routing == "edge":
+        stats = jax.jit(lambda p, x: moe.apply(
+            {"params": p}, x, mutable=["moe_stats"]))(params, x)[1]
+        assert int(stats["moe_stats"]["rows_moved"][0]) == tm
+
+
+@pytest.mark.parametrize("held,kernels", [((0, 2), True), ((0, 2), False),
+                                          (None, True)])
+def test_the_load_event_says_how_many_rows_the_dispatch_moved(
+        held, kernels, monkeypatch):
+    """``rows_moved`` is ``routed_here`` where the kernels move a share's
+    rows and ``routed`` where XLA's gather moves every pair: a share under
+    the crossover, and a layer that holds all its experts."""
+    if kernels:
+        monkeypatch.setattr(sharded_moe, "ROW_FETCH_MIN_PAIRS", 0)
+    cfg = config(8, 3, moe_experts_held=held, **WIDE)
+    model, params, ids = seeded(cfg)
+    load = publish_expert_load(model, params, {"input_ids": ids})
+    assert load["tokens_dropped"] == 0
+    if held and kernels:
+        assert load["rows_moved"] == load["routed_here"] < load["routed"]
+    else:
+        assert load["rows_moved"] == load["routed"] == 2 * 64 * 3
+
+
+@pytest.mark.parametrize("call", ["serving", "training", "counters"])
+def test_a_serving_call_keeps_the_gather_it_was_lowered_with(
+        call, monkeypatch):
+    """With the crossover out of the way a share's training step and its
+    counter pass move the live rows through the kernels; a serving call of
+    the same model (``decode=True``: the scan's owner says so to the
+    experts) traces none of them, so no serving program changes however
+    many pairs a prompt's pass sorts."""
+    from deepspeed_tpu.ops.pallas import row_fetch
+
+    monkeypatch.setattr(sharded_moe, "ROW_FETCH_MIN_PAIRS", 0)
+    fetched = []
+    real = row_fetch.fetch_rows
+    monkeypatch.setattr(row_fetch, "fetch_rows", lambda *a, **kw: (
+        fetched.append(1), real(*a, **kw))[1])
+    cfg = config(8, 3, moe_experts_held=(0, 2), **WIDE)
+    model, params, ids = seeded(cfg)
+    if call == "serving":
+        jax.jit(lambda p: model.apply({"params": p}, ids, decode=True,
+                                      mutable=["cache"]))(params)
+        assert not fetched
+    elif call == "training":
+        jax.jit(jax.grad(lambda p: model.apply(
+            {"params": p}, ids, labels=ids)))(params)
+        assert fetched
+    else:
+        routing_stats(model, params, {"input_ids": ids})
+        assert fetched
